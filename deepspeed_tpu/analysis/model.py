@@ -52,7 +52,6 @@ _TRANSFORM_CALLABLE_ARGS: Dict[str, Tuple[Optional[int], ...]] = {
     "remat": (0,),
     "checkpoint": (0,),
     "shard_map": (0,),
-    "shard_map_compat": (0,),   # parallel.mesh version-skew wrapper
     "pallas_call": (0,),
     "custom_vjp": (0,),
     "custom_jvp": (0,),

@@ -176,9 +176,7 @@ class PallasFusedBackend(CollectiveBackend):
                           out_dtype=None, op="qwz_all_gather", stats=None):
         from ..ops.pallas.fused_collectives import (dequant_matmul,
                                                     matmul_pallas)
-        from ..parallel.mesh import collective_axis_size
-
-        world = collective_axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
         if world <= 1:
             return self.fallback.all_gather_matmul(
                 h, w_shard, axis_name, dim=dim, qspec=qspec,
@@ -283,9 +281,7 @@ class PallasFusedBackend(CollectiveBackend):
                           stats=None):
         from ..ops.pallas.fused_collectives import (matmul_pallas,
                                                     matmul_quantize)
-        from ..parallel.mesh import collective_axis_size
-
-        world = collective_axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
         if not (x.ndim == 2 and w_shard.ndim == 2
                 and x.shape[1] == w_shard.shape[0]):
             cc._note_fallback(op)

@@ -114,7 +114,7 @@ def _quant_roundtrip(x: jnp.ndarray, spec: QuantSpec,
                      dtype=jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray,
                                                  jnp.ndarray]:
     """(q int8, scales, deq) of a flat tensor — the pack/unpack bracket
-    every quantized hop pays (what tpu_quant_comm_bench times)."""
+    every quantized hop pays."""
     q, s, _ = quantize_blockwise(x, bits=spec.bits, block=spec.block,
                                  manual_sharding=True)
     deq = dequantize_blockwise(q, s, block=spec.block, dtype=dtype,
@@ -146,9 +146,7 @@ def quantized_all_gather(x: jnp.ndarray, axis_name: str, *, dim: int = 0,
     logical. Must run inside a shard_map region where ``axis_name`` is
     manual. ``stats`` (optional list) receives the traced max relative
     quantization error of the local round trip."""
-    from ..parallel.mesh import collective_axis_size
-
-    world = collective_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     if world <= 1:
         return x if out_dtype is None else x.astype(out_dtype)
     out_dtype = out_dtype or x.dtype
@@ -451,9 +449,7 @@ def chunked_all_reduce(y: jnp.ndarray, axis_name: str, *,
     ``psum``'s accumulation order is the compiler's choice). Tensors
     whose size does not chunk-divide fall back to the plain dense
     ``psum``/``pmean`` (metered)."""
-    from ..parallel.mesh import collective_axis_size
-
-    world = collective_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     if world <= 1:
         return y
     n = y.size

@@ -495,7 +495,8 @@ class CompileConfig:
 
     ``cache_dir`` enables JAX's persistent compilation cache there (time-
     to-first-step across process restarts drops to cache-deserialize
-    time). ``aot_warmup`` makes ``initialize()`` AOT-compile the fused
+    time); a set ``JAX_COMPILATION_CACHE_DIR`` overrides it
+    (runtime/compile_cache.py). ``aot_warmup`` makes ``initialize()`` AOT-compile the fused
     train step (``lower().compile()``) in a background thread, overlapped
     with the input pipeline's warm fill; the resulting executable serves
     the steady-state steps directly. ``warn_on_recompile`` logs (once)
@@ -504,7 +505,6 @@ class CompileConfig:
     it either way."""
 
     cache_dir: Optional[str] = None
-    min_compile_time_s: float = 0.0
     aot_warmup: bool = True
     warn_on_recompile: bool = True
 
@@ -515,7 +515,6 @@ class CompileConfig:
         d = dict(d)
         out = cls(
             cache_dir=_take(d, "cache_dir", None),
-            min_compile_time_s=float(_take(d, "min_compile_time_s", 0.0)),
             aot_warmup=bool(_take(d, "aot_warmup", True)),
             warn_on_recompile=bool(_take(d, "warn_on_recompile", True)),
         )
@@ -588,8 +587,8 @@ class CommCompressionConfig:
     ``enabled`` is tri-state: ``"auto"`` (default) turns compression on
     exactly when the ZeRO data-parallel group reaches
     ``mesh_size_threshold`` ranks — small meshes keep the dense path
-    (the pack/unpack bracket only pays for itself across slow links, see
-    scripts/tpu_quant_comm_bench.py break-even analysis); ``true``/
+    (the pack/unpack bracket only pays for itself across slow links,
+    docs/communication.md); ``true``/
     ``false`` force it. The explicit ZeRO++ knobs
     (``zero_optimization.zero_quantized_weights`` / ``_gradients``)
     still opt individual legs in regardless of the threshold.

@@ -43,10 +43,11 @@ from ..utils.logging import log_dist
 from .engine import _sample
 
 
-def _use_pallas_paged(head_dim: int, block: int, dtype,
-                      scalar_ints: int = 0) -> bool:
-    """Pallas paged kernel eligibility: real TPU + tileable page shape +
-    prefetched scalars (per-seq tables, slots, positions) fitting in SMEM
+def _paged_kernel_blocker(head_dim: int, block: int, dtype,
+                          scalar_ints: int = 0) -> Optional[str]:
+    """Why the compiled Pallas paged kernel cannot serve this engine, or
+    None when it can: it needs a real TPU, a tileable page shape and
+    prefetched scalars (per-seq tables, slots, positions) that fit SMEM
     (1 MB/core; keep them under half). DST_RAGGED_FORCE_GATHER=1 pins the
     XLA gather path (serve-bench A/B lever)."""
     import os
@@ -54,13 +55,18 @@ def _use_pallas_paged(head_dim: int, block: int, dtype,
     from ..ops.attention import _on_tpu
 
     if os.environ.get("DST_RAGGED_FORCE_GATHER") == "1":
-        return False
+        return "DST_RAGGED_FORCE_GATHER=1"
     if not _on_tpu():
-        return False
+        return "not on a TPU"
     if scalar_ints * 4 > 512 * 1024:
-        return False
+        return (f"prefetched scalars ({scalar_ints * 4} B) exceed the "
+                "512 KiB SMEM bound")
     sublane = 32 // jnp.dtype(dtype).itemsize  # 8 fp32 / 16 any 16-bit dtype
-    return head_dim in (64, 128, 256) and block % sublane == 0
+    if head_dim not in (64, 128, 256):
+        return f"head_dim {head_dim} not in (64, 128, 256)"
+    if block % sublane:
+        return f"kv_block_size {block} not a multiple of {sublane}"
+    return None
 
 
 # ----------------------------------------------------------------------
@@ -578,6 +584,30 @@ class RaggedInferenceEngine:
             raise ValueError(
                 f"kv_quant='int4' packs two channels per byte and needs an "
                 f"even head_dim, got {c.head_dim}")
+        # which paged-attention implementation the compiled step traces,
+        # decided (and said) once at construction: "pallas" (compiled
+        # kernel), "pallas_interpret" (DST_RAGGED_FORCE_PALLAS=interpret —
+        # the CPU-lane token-exactness tests for the sharded kernel ride
+        # this) or "gather" (XLA gather formulation, the off-TPU oracle)
+        import os as _os
+
+        cfg = self.config
+        self.max_pages = cfg.max_context // cfg.kv_block_size
+        blocker = _paged_kernel_blocker(
+            c.head_dim, cfg.kv_block_size, cfg.dtype,
+            scalar_ints=cfg.max_seqs * self.max_pages + 2 * cfg.token_budget)
+        if _os.environ.get("DST_RAGGED_FORCE_PALLAS", "") == "interpret":
+            self.attention_path = "pallas_interpret"
+        elif blocker is None:
+            self.attention_path = "pallas"
+        else:
+            self.attention_path = "gather"
+            log_dist(f"RaggedInferenceEngine: paged attention on the XLA "
+                     f"gather path ({blocker})")
+        if self._kv_bits == 4 and self.attention_path == "pallas":
+            from ..ops.pallas.paged_attention import Int4KVKernelUnsupported
+
+            raise Int4KVKernelUnsupported()
         self.params = params if params is not None else model.init(
             rng if rng is not None else jax.random.PRNGKey(0))
         self.params = jax.tree_util.tree_map(
@@ -599,7 +629,6 @@ class RaggedInferenceEngine:
                 jax.tree_util.tree_map(
                     lambda sp: NamedSharding(topology.mesh, sp), specs,
                     is_leaf=lambda x: isinstance(x, PartitionSpec)))
-        cfg = self.config
         self.allocator = BlockedAllocator(cfg.n_kv_blocks)
         self.prefix_cache = (PrefixCache(cfg.kv_block_size)
                              if cfg.enable_prefix_cache else None)
@@ -609,7 +638,6 @@ class RaggedInferenceEngine:
         # their fresh descriptors must not re-record TTFT/latency — the
         # serving layer's request spans carry the true end-to-end numbers
         self._resume_uids: set = set()
-        self.max_pages = cfg.max_context // cfg.kv_block_size
         # paged KV pool: per-layer tuples of [n_blocks + 1, hkv, block, hd]
         # (last page = scratch sink for masked-out batch lanes; duplicate
         # scatters with mixed old/new values are undefined — inactive lanes
@@ -2030,19 +2058,11 @@ class RaggedInferenceEngine:
         # Binding sliding windows ride the kernel too: the per-layer window
         # is STATIC (the python layer loop is unrolled), and the kernel
         # skips + DMA-dedups chunks below the band (O(window) traffic).
-        # DST_RAGGED_FORCE_PALLAS=interpret pins the kernel path in Pallas
-        # interpret mode — the CPU-lane token-exactness tests for the
-        # sharded kernel ride this.
-        import os as _os
-
-        _force = _os.environ.get("DST_RAGGED_FORCE_PALLAS", "")
-        interp = _force == "interpret"
         # (no indivisible-heads fallback needed here: __init__ rejects
         # n_kv_heads % tp != 0 outright, and n_heads is a multiple of
         # n_kv_heads, so any engine that reaches this point shards cleanly)
-        use_pallas = interp or _use_pallas_paged(
-            c.head_dim, bs, self.config.dtype,
-            scalar_ints=cfg.max_seqs * self.max_pages + 2 * cfg.token_budget)
+        interp = self.attention_path == "pallas_interpret"
+        use_pallas = self.attention_path != "gather"
 
         kv_bits = self._kv_bits
 
@@ -2057,8 +2077,6 @@ class RaggedInferenceEngine:
             pspec = P_(None, "model", None, None)
             sspec = P_(None, "model", None)
 
-            from ..parallel.mesh import shard_map_compat
-
             if ks is not None:
                 def local_q(q, kp, vp, tb, pos, sl, ks, vs):
                     return paged_attention(q, kp, vp, tb, pos, seq_slots=sl,
@@ -2067,7 +2085,7 @@ class RaggedInferenceEngine:
                                            v_scale=vs, kv_bits=kv_bits,
                                            interpret=interp)
 
-                mapped = shard_map_compat(
+                mapped = jax.shard_map(
                     local_q, mesh=self.topo.mesh, axis_names={"model"},
                     in_specs=(hspec, pspec, pspec, P_(None, None), P_(None),
                               P_(None), sspec, sspec),
@@ -2081,7 +2099,7 @@ class RaggedInferenceEngine:
 
             in_specs = (hspec, pspec, pspec, P_(None, None), P_(None),
                         P_(None))
-            mapped = shard_map_compat(
+            mapped = jax.shard_map(
                 local, mesh=self.topo.mesh, axis_names={"model"},
                 in_specs=in_specs, out_specs=hspec, check_vma=False)
             return mapped(q, kp, vp, tables, positions, slots)

@@ -319,7 +319,6 @@ class Transformer:
             if a in mesh.shape)
         if not multi:
             return fa(q, k, v, **kw)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P_
 
         batch_axes = getattr(self, "_batch_axes", None) or ()
@@ -339,9 +338,9 @@ class Transformer:
                 q, k, v, causal=causal, scale=scale, window=window)
         ha = "model" if tp > 1 else None
         spec = P_(tuple(batch_axes) or None, None, ha, None)
-        return shard_map(lambda q, k, v: fa(q, k, v, **kw), mesh=mesh,
-                         in_specs=(spec, spec, spec),
-                         out_specs=spec, check_rep=False)(q, k, v)
+        return jax.shard_map(lambda q, k, v: fa(q, k, v, **kw), mesh=mesh,
+                             in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
 
     def _sp_attention(self, q, k, v, window=None, causal=True):
         """Sequence-parallel attention over the bound mesh's seq axis."""
@@ -575,7 +574,6 @@ class Transformer:
         d = w_down.shape[-1]
         if (dp > 1 and b % dp) or f % tp:
             return up @ w_down
-        from ..parallel.mesh import shard_map_compat
         from jax.sharding import PartitionSpec as P_
 
         def fused(u, w):
@@ -583,7 +581,7 @@ class Transformer:
                                           "model", out_dtype=u.dtype)
             return y.reshape(u.shape[0], 1, d)
 
-        return shard_map_compat(
+        return jax.shard_map(
             fused, mesh=mesh,
             in_specs=(P_(batch_axes or None, None, "model"),
                       P_("model", None)),

@@ -16,13 +16,20 @@ TPU-first surface:
 
 from __future__ import annotations
 
-import functools
+import collections
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# Trace-time ledger of which implementation each dispatcher call picked
+# ("flash_pallas" | "flash_pallas_padded" | "flash_jnp"): the Python below
+# only runs while JAX traces, so a count is a program construction, not a
+# step. chip_smoke.py reads it to fail when the kernel path did not run.
+DISPATCH: "collections.Counter[str]" = collections.Counter()
 
 
 def _repeat_kv(k, n_rep: int):
@@ -86,23 +93,24 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = No
     if _use_pallas(q, k, block_q, block_k):
         from .pallas.flash_attention import flash_attention as _pallas_flash
 
+        DISPATCH["flash_pallas"] += 1
         return _pallas_flash(q, k, v, causal, scale, block_q, block_k,
                              window=window)
     if _use_pallas_padded(q, k, causal):
         from .pallas.flash_attention import flash_attention_padded
 
+        DISPATCH["flash_pallas_padded"] += 1
         return flash_attention_padded(q, k, v, causal, scale,
                                       block_q, block_k, window=window)
+    DISPATCH["flash_jnp"] += 1
     return dot_product_attention(q, k, v, causal=causal, scale=scale,
                                  window=window)
 
 
 def _on_tpu() -> bool:
-    """Shared platform probe for Pallas kernel dispatch."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """Shared platform probe for Pallas kernel dispatch. A backend that
+    fails to initialize raises here — it is not "not a TPU"."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _use_pallas(q, k, block_q: int, block_k: int) -> bool:

@@ -43,6 +43,15 @@ NEG_INF = -1e30  # large-negative instead of -inf: avoids NaN from (-inf)-(-inf)
 LANES = 128
 
 
+def _dot(a, b, dims):
+    """MXU matmul, fp32 accumulation, at the operands' own precision.
+    Pinned rather than left to ``jax_default_matmul_precision``: under
+    "highest" every dot here would ask for an fp32 contract precision, and
+    Mosaic refuses that on bf16 operands ("Bad lhs type")."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.DEFAULT)
+
+
 def _causal_mask(qi, ki, block_q: int, block_k: int, sq: int, skv: int,
                  window: int = 0):
     """[block_q, block_k] bool mask for the (qi, ki) tile; query positions are
@@ -93,8 +102,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # upcasting first would force fp32 MXU passes (~8x slower)
         q = q_ref[0, 0]                              # [bq, d]
         k = k_ref[0, 0]                              # [bk, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
         if causal and window > 0:
             # banded tiles can be partial on both edges — mask every
             # running tile (windowed models only pay this)
@@ -114,8 +122,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         corr = jnp.exp(m_prev - m_new)               # [bq, 1]
         l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0, 0]                              # [bk, d]
-        pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        pv = _dot(p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
         acc_scr[:] = acc_scr[:] * corr + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -252,17 +259,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]                   # [bq, 1]
         delta = delta_ref[0, 0][:, :1]               # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, ki, block_q, block_k, sq, skv,
                                        window), s, NEG_INF)
         p = jnp.exp(s - lse)                         # [bq, bk] fp32
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do, v, (((1,), (1,)), ((), ())))
         ds = (p * (dp - delta) * scale).astype(k.dtype)
-        acc_scr[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
+        acc_scr[:] += _dot(ds, k, (((1,), (0,)), ((), ())))
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -296,22 +300,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]
         delta = delta_ref[0, 0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, ki, block_q, block_k, sq, skv,
                                        window), s, NEG_INF)
         p = jnp.exp(s - lse)                         # [bq, bk] fp32
         # dv += P^T @ dO
-        dv_scr[:] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dv_scr[:] += _dot(p.astype(do.dtype), do, (((0,), (0,)), ((), ())))
+        dp = _dot(do, v, (((1,), (1,)), ((), ())))
         ds = (p * (dp - delta) * scale).astype(q.dtype)  # [bq, bk]
         # dk += dS^T @ Q
-        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        dk_scr[:] += _dot(ds, q, (((0,), (0,)), ((), ())))
 
     @pl.when(gq == n_gq - 1)
     def _final():

@@ -51,6 +51,29 @@ NEG_INF = -1e30
 LANES = 128
 
 
+def _dot(a, b, dims):
+    """MXU matmul, fp32 accumulation, at the operands' own precision.
+    Pinned rather than left to ``jax_default_matmul_precision``: under
+    "highest" every dot here would ask for an fp32 contract precision, and
+    Mosaic refuses that on bf16 operands ("Bad lhs type")."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.DEFAULT)
+
+
+class Int4KVKernelUnsupported(NotImplementedError):
+    """The compiled paged kernel cannot read an int4 KV pool."""
+
+    def __init__(self):
+        super().__init__(
+            "kv_quant='int4' is not supported by the compiled Pallas paged-"
+            "attention kernel: unpacking two nibbles per byte interleaves "
+            "the lane (head_dim) axis in VMEM, and the v5e compiler spends "
+            "minutes on it before failing with RESOURCE_EXHAUSTED (vmem, "
+            "allocating on stack) even at T=16 (ROADMAP.md S4). Use "
+            "kv_quant='int8' or 'none' on TPU; int4 runs in interpret mode "
+            "and on the gather path only.")
+
+
 def _kernel(*refs,
             scale: float, block: int, hkv: int, group: int, ppc: int,
             num_scalars: int, window: int = 0, kv_bits: int = 0):
@@ -104,8 +127,7 @@ def _kernel(*refs,
             v = v.astype(jnp.float32) * vs[..., None]
             q = q.astype(jnp.float32)
         # batched-over-heads MXU matmul: [hkv, group, span]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, (((2,), (2,)), ((0,), (0,)))) * scale
         s = s.reshape(hkv * group, span)
         row_pos = c * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         visible = row_pos <= pos
@@ -119,10 +141,8 @@ def _kernel(*refs,
         l_scr[:] = jnp.broadcast_to(l_scr[:, :1] * corr +
                                     jnp.sum(pr, axis=-1, keepdims=True),
                                     l_scr.shape)
-        pv = jax.lax.dot_general(
-            pr.reshape(hkv, group, span).astype(v.dtype), v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)      # [hkv, group, hd]
+        pv = _dot(pr.reshape(hkv, group, span).astype(v.dtype), v,
+                  (((2,), (1,)), ((0,), (0,))))      # [hkv, group, hd]
         acc_scr[:] = acc_scr[:] * corr + pv.reshape(hkv * group, -1)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
@@ -191,16 +211,16 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     nibble-packed uint8 [..., hd//2] at ``kv_bits=4``): scales ride the
     same per-page BlockSpec pipeline as the payloads (half/quarter the
     page DMA bytes vs an fp pool) and the payload dequantizes in VMEM
-    right before the QK^T matmul. NB: the f32 scale tile's lane dim is
-    ``block`` (< 128 for typical pools) — fine in interpret mode and on
-    current Mosaic via padding, but on-TPU validation of the quantized
-    kernel outside interpret mode is a follow-up (same status the fused
-    collective kernels shipped with)."""
+    right before the QK^T matmul. The int8 variant compiles for the v5e
+    (tests/test_tpu_compile.py); the int4 variant does not and raises
+    :class:`Int4KVKernelUnsupported` outside interpret mode."""
     T, hq, hd = q.shape
     n_pages, hkv, block, _ = k_pool.shape
     quant = k_scale is not None
     if quant:
         _check_quant_geometry(k_pool, hd, kv_bits)
+        if kv_bits == 4 and not interpret:
+            raise Int4KVKernelUnsupported()
     max_pages = tables.shape[1]
     group = hq // hkv
     assert hq % hkv == 0

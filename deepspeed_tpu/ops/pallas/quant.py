@@ -5,8 +5,7 @@ SURVEY §2.4 parity target: the reference's CUDA quantizer suite
 absmax + scale + pack at memory bandwidth). The XLA path in
 ``ops/quantizer.py`` stays the reference semantics (and the fallback);
 these kernels fuse the scale reduction and the pack/unpack into single
-VMEM passes so the qwZ/qgZ bracket cost is one HBM read + one write —
-the quantity ``scripts/tpu_quant_comm_bench.py`` measures.
+VMEM passes so the qwZ/qgZ bracket cost is one HBM read + one write.
 
 Layout: values as [rows, block] with ``block`` a lane multiple (256
 default = 2 lanes); scales are emitted lane-replicated [rows, 128] (the

@@ -36,6 +36,12 @@ def _lib():
     return _LIB
 
 
+def packer() -> str:
+    """Which implementation builds the step batches: "native" (the C++
+    library, built on first use) or "numpy" (the fallback)."""
+    return "native" if _lib() is not None else "numpy"
+
+
 def _i32p(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
 

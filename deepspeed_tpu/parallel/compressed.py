@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map_compat
-
 
 def _sign_compress(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-row 1-bit compression: x [rows, m] -> (sign int8, scale [rows]).
@@ -202,7 +200,7 @@ def make_onebit_grad_fn(loss_fn, mesh: Mesh, axis_name: str = "data"):
         red, nwe, nse = tree_onebit_allreduce(grads, we, se, axis_name, world)
         return red, jax.lax.pmean(loss, axis_name), nwe, nse
 
-    return shard_map_compat(
+    return jax.shard_map(
         spmd, mesh=mesh, axis_names={axis_name},
         in_specs=(P(), P(axis_name), P(axis_name), P(axis_name)),
         out_specs=(P(), P(), P(axis_name), P(axis_name)),

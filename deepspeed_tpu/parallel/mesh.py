@@ -174,20 +174,14 @@ class Topology:
         """Batch sharding: leading dim over the data axes — and 'seq' folds
         into batch for the dataloader when sequence parallelism is off."""
         spec: list = [None] * ndim
-        axes = self.data_axes()
-        # bare name for a single axis: 0.4.x PartitionSpec does not
-        # normalize 1-tuples, so ('data',) and 'data' compare unequal
-        spec[0] = axes[0] if len(axes) == 1 else axes
+        spec[0] = self.data_axes()
         return NamedSharding(self.mesh, PartitionSpec(*spec))
 
     def batch_sharding(self, ndim: int = 2) -> NamedSharding:
         """[batch, seq, ...] sharding: batch over the data axes, seq over
         'seq'."""
         spec: list = [None] * ndim
-        axes = self.data_axes()
-        # bare name for a single axis: 0.4.x PartitionSpec does not
-        # normalize 1-tuples, so ('data',) and 'data' compare unequal
-        spec[0] = axes[0] if len(axes) == 1 else axes
+        spec[0] = self.data_axes()
         if ndim > 1 and self._sizes["seq"] > 1:
             spec[1] = "seq"
         return NamedSharding(self.mesh, PartitionSpec(*spec))
@@ -224,52 +218,3 @@ def set_topology(topo: Topology) -> None:
 def reset_topology() -> None:
     global _TOPOLOGY
     _TOPOLOGY = None
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs,
-                     axis_names=None, check_vma=None):
-    """``jax.shard_map`` with a jax 0.4.x fallback.
-
-    The public ``jax.shard_map`` (and its ``axis_names=``/``check_vma=``
-    kwargs) only exists on jax >= 0.5; 0.4.x ships
-    ``jax.experimental.shard_map.shard_map`` where the same contract is
-    spelled ``auto = mesh axes NOT in axis_names`` and
-    ``check_rep = check_vma``. Every shard_map in the package goes
-    through here so version skew lives in exactly one place.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    # NB: axis_names is deliberately DROPPED on 0.4.x (fully-manual
-    # mode). The experimental API spells partial-manual as
-    # ``auto = complement(axis_names)``, but on 0.4.37 that path is
-    # broken for our programs: size-1 auto axes abort XLA CPU outright,
-    # and >1 auto axes hit "PartitionId instruction is not supported
-    # for SPMD partitioning" wherever the body takes an axis_index.
-    # Fully-manual is semantically equivalent for these call sites —
-    # unnamed axes appear in no in/out spec and no body collective —
-    # and is the spelling the ragged engine's fallback already proved.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def collective_axis_size(axis_name: str) -> int:
-    """Static size of a named axis INSIDE a shard_map/pmap body.
-
-    ``jax.lax.axis_size`` only exists on jax >= 0.5; on 0.4.x,
-    ``psum(1, axis)`` of the literal constant folds to a plain Python
-    int under shard_map — the same static value, nothing on the wire.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)

@@ -36,8 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map_compat
-
 # stage_fn(stage_params, x, consts, rng, valid) -> (y, aux_scalar)
 StageFn = Callable[[Any, jnp.ndarray, Any, jnp.ndarray, jnp.ndarray],
                    Tuple[jnp.ndarray, jnp.ndarray]]
@@ -117,7 +115,7 @@ def pipeline_apply(stage_fn: StageFn,
         aux = jax.lax.psum(aux_acc, axis) / jnp.maximum(n_mb, 1)
         return ys, aux
 
-    return shard_map_compat(
+    return jax.shard_map(
         spmd, mesh=mesh, axis_names={axis},
         in_specs=(P(axis), P(), P(), P()),
         out_specs=(P(), P()),

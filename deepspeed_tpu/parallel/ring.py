@@ -18,8 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import collective_axis_size, shard_map_compat
-
 
 def _block_attn(q, k, v, q_off, k_off, causal, scale):
     """Partial attention of a q block vs one k/v block with global-position
@@ -55,7 +53,7 @@ def ring_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True,
                    scale: Optional[float] = None):
     """Call INSIDE shard_map. q/k/v: local shards [b, s/P, h, d] where the
     global sequence is contiguously sharded over ``axis_name``."""
-    P_ = collective_axis_size(axis_name)   # 0.4.x: no jax.lax.axis_size
+    P_ = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -98,6 +96,6 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, *, axis_name: str = "seq",
     def inner(q, k, v):
         return ring_attention(q, k, v, axis_name=axis_name, causal=causal)
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False)(q, k, v)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
-from .mesh import collective_axis_size, shard_map_compat
 
 
 def _a2a(x, axis_name: str, split_axis: int, concat_axis: int):
@@ -52,7 +51,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True,
     # enough to scatter — numerics-identical, it's the GQA broadcast done
     # before the a2a instead of inside attention (reference Ulysses does
     # the same for GQA models, sequence/layer.py head-repeat path)
-    P_ = collective_axis_size(axis_name)   # 0.4.x: no jax.lax.axis_size
+    P_ = jax.lax.axis_size(axis_name)
     kvh = k.shape[2]
     if kvh % P_ != 0:
         r = P_ // math.gcd(kvh, P_)
@@ -101,6 +100,6 @@ class DistributedAttention:
                 attn_fn=partial(self.local_attn, causal=causal),
                 comm_dtype=self.comm_dtype)
 
-        return shard_map_compat(
+        return jax.shard_map(
             inner, mesh=self.mesh, in_specs=(spec, spec, spec),
             out_specs=spec, check_vma=False)(q, k, v)

@@ -65,8 +65,16 @@ class FlopsProfiler:
     standalone around any function.
     """
 
-    def __init__(self, peak_flops: Optional[float] = None):
-        self.peak_flops = peak_flops or _peak_flops_per_device() * len(jax.devices())
+    def __init__(self, peak_flops: Optional[float] = None, mesh: Any = None):
+        # the peak is that of the mesh the profiled function runs on
+        # (default: the current topology's), not of every local device
+        if not peak_flops:
+            if mesh is None:
+                from ..parallel.mesh import get_topology
+
+                mesh = get_topology().mesh
+            peak_flops = mesh_peak_flops(mesh.devices.size)
+        self.peak_flops = peak_flops
         self._flops: Optional[float] = None
         self._params: int = 0
         self._t0: Optional[float] = None
@@ -158,20 +166,37 @@ def get_model_profile(model, batch, rng=None, params=None,
                         analytic_flops=analytic, params=params)
 
 
-def _peak_flops_per_device() -> float:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return 0.0
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v6" in kind or "trillium" in kind:
-        return 918e12
-    return 0.0  # unknown (CPU): MFU reported as 0
+# Published per-chip peaks keyed by jax's ``device_kind`` — the one table
+# bench.py, chip_smoke.py, this profiler and the engine's MFU read. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s per chip); jax names that chip "TPU v5 lite".
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9,
+                    "source": "Google Cloud documentation, TPU v5e"},
+}
+
+
+def device_peaks(device: Any = None) -> Optional[Dict[str, Any]]:
+    """Published peaks of ``device`` (default: the first local device).
+    None off-TPU — the CPU has no peak and MFU is not reported; a TPU
+    ``device_kind`` missing from :data:`DEVICE_PEAKS` is an error, never a
+    default."""
+    device = device if device is not None else jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peaks for TPU device_kind {device.device_kind!r}: "
+            f"add it to profiling/flops_profiler.DEVICE_PEAKS with its source "
+            f"(have {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device.device_kind]
+
+
+def mesh_peak_flops(n_devices: int) -> float:
+    """bf16 peak of ``n_devices`` local chips; 0.0 off-TPU."""
+    peaks = device_peaks()
+    return peaks["bf16_flops"] * n_devices if peaks else 0.0
 
 
 def _num_to_string(num: float, unit: str) -> str:

@@ -142,7 +142,6 @@ def overlap_report(engine, batch, *, repeats: int = 3,
 
     from ..comm import compressed as ccomm
     from ..comm.comm import configure_comms_logger, get_comms_logger
-    from ..parallel.mesh import shard_map_compat
     from ..parallel.zero import Zero3BlockSchedule
     from ..resilience.clock import get_clock
     from ..telemetry.tracing import get_tracer
@@ -179,7 +178,7 @@ def overlap_report(engine, batch, *, repeats: int = 3,
                     qspec=env["wq"]),
                 blk, block_specs[i], is_leaf=is_spec)
 
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             g, mesh=engine.topo.mesh, axis_names=set(env["axes"]),
             in_specs=(block_specs[i],), out_specs=rep_tree(i),
             check_vma=False))
@@ -191,7 +190,7 @@ def overlap_report(engine, batch, *, repeats: int = 3,
                 outer_world=env["outer_world"], inner_axis=env["inner"],
                 inner_world=env["inner_world"], qspec=env["gq"])
 
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             r, mesh=engine.topo.mesh, axis_names=set(env["axes"]),
             in_specs=(rep_tree(i),), out_specs=rep_tree(i),
             check_vma=False))

@@ -84,9 +84,10 @@ def checkpoint_wrapper(fn: Callable, policy: Optional[str] = None,
     if offload:
         from .engine import host_memory_kind
 
-        pol = jax.checkpoint_policies.offload_dot_products(
-            "device", host_memory_kind()) \
-            if hasattr(jax.checkpoint_policies, "offload_dot_products") else None
+        # matmul outputs (no batch dims) are saved to host memory instead
+        # of being recomputed; everything else rematerializes
+        pol = jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+            "device", host_memory_kind())
         return jax.checkpoint(fn, policy=pol)
     if policy not in _POLICIES:
         raise ValueError(f"unknown remat policy {policy!r}; have {sorted(_POLICIES)}")

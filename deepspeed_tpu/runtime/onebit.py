@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.compressed import init_error_feedback, tree_onebit_allreduce
-from ..parallel.mesh import shard_map_compat
 from ..utils.logging import log_dist
 
 
@@ -113,7 +112,7 @@ class OnebitAdam:
                 params, m_new, v_new)
             return params_new, m_new, v_new, we, se, loss
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             spmd, mesh=self.mesh, axis_names={axis},
             in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P()),
             out_specs=(P(), P(), P(), P(axis), P(axis), P()),
@@ -260,7 +259,7 @@ class ZeroOneAdam:
                     .astype(p.dtype), params_new)
             return params_new, m_new, v_new, we, se, loss
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             spmd, mesh=self.mesh, axis_names={axis},
             in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P()),
             out_specs=(P(), P(), P(), P(axis), P(axis), P()),

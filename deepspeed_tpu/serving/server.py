@@ -2,8 +2,8 @@
 
 ``ServingEngine`` is the production surface the FastGen/MII blogs
 describe — live request arrival, SLO-aware continuous batching,
-streaming responses — promoted out of the benchmark script's throwaway
-loop (scripts/tpu_serve_bench.py pre-PR5) into a real subsystem:
+streaming responses — promoted out of a benchmark script's throwaway
+loop into a real subsystem:
 
 * ``submit()`` with bounded-queue backpressure: a full queue rejects
   explicitly (state REJECTED) instead of buffering unboundedly while

@@ -45,6 +45,37 @@ def test_get_model_profile():
     assert res.params > 0 and res.duration_s > 0
 
 
+def test_peak_is_that_of_the_engines_mesh_not_of_every_device(monkeypatch):
+    """A process may hold a sub-mesh (chip_smoke.py --chips 4 trains on one
+    of four devices): the MFU denominator is per-chip peak x the devices
+    of the mesh the engine runs on. Off-TPU there is no peak at all, and a
+    TPU kind without published peaks is an error, never a default."""
+    from types import SimpleNamespace
+
+    from deepspeed_tpu.parallel.mesh import Topology
+    from deepspeed_tpu.profiling import flops_profiler as fp
+    from simple_model import init_mlp_params, mlp_loss
+
+    topo = Topology.build_virtual({"data": 2})   # 2 of the 8 virtual devices
+    engine, _, _, _ = dst.initialize(
+        loss_fn=mlp_loss, params=init_mlp_params(jax.random.PRNGKey(0)),
+        config={"train_batch_size": 4,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}},
+        topology=topo)
+    assert fp.device_peaks() is None            # the CPU has no peak
+    assert engine._get_peak_flops() == 0.0      # ... and MFU is not reported
+    monkeypatch.setattr(fp, "device_peaks", lambda device=None: {"bf16_flops": 1e12})
+    engine._peak_flops = None
+    assert engine._get_peak_flops() == 2e12
+    assert fp.FlopsProfiler(mesh=topo.mesh).peak_flops == 2e12
+    assert fp.FlopsProfiler().peak_flops == 2e12    # the current topology's
+    monkeypatch.undo()
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert fp.device_peaks(v5e)["bf16_flops"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        fp.device_peaks(SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+
+
 # ----------------------------------------------------------------------
 # activation checkpointing
 def test_activation_checkpointing_policies():

@@ -12,7 +12,6 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu import comm
 from deepspeed_tpu.parallel.mesh import Topology, set_topology
-from deepspeed_tpu.parallel.mesh import shard_map_compat
 
 
 @pytest.fixture()
@@ -33,7 +32,7 @@ def _run_collectives(topo):
         s = comm.reduce_scatter(y, "data")
         return s + 1e-9 * jnp.sum(g)
 
-    f = shard_map_compat(spmd, mesh=mesh, axis_names={"data"},
+    f = jax.shard_map(spmd, mesh=mesh, axis_names={"data"},
                       in_specs=P("data"), out_specs=P("data"),
                       check_vma=False)
     x = jnp.arange(64 * 8, dtype=jnp.float32)
@@ -83,7 +82,7 @@ def test_sparse_allreduce_matches_dense(logger_on):
     def spmd(rows, idx):
         return comm.sparse_allreduce(rows[0], idx[0], "data", V)[None]
 
-    got = jax.jit(shard_map_compat(
+    got = jax.jit(jax.shard_map(
         spmd, mesh=topo.mesh, axis_names={"data"},
         in_specs=(P("data"), P("data")), out_specs=P("data"),
         check_vma=False))(rows, idx)
@@ -166,7 +165,7 @@ def test_reduce_gather_scatter(logger_on):
         s = comm.scatter(x[0], "data", src_index=2)
         return r[None], g[None], s[None]
 
-    r, g, s = jax.jit(shard_map_compat(
+    r, g, s = jax.jit(jax.shard_map(
         spmd, mesh=topo.mesh, axis_names={"data"},
         in_specs=P("data"), out_specs=(P("data"), P("data"), P("data")),
         check_vma=False))(x)

@@ -24,7 +24,7 @@ from deepspeed_tpu.comm.comm import (CommsLogger, configure_comms_logger,
 from deepspeed_tpu.ops.quantizer import (dequantize_blockwise, pack_int4,
                                          quantize_blockwise, unpack_int4)
 from deepspeed_tpu.parallel import mesh as mesh_mod
-from deepspeed_tpu.parallel.mesh import Topology, shard_map_compat
+from deepspeed_tpu.parallel.mesh import Topology
 from deepspeed_tpu.parallel.zero import (BlockProgram, SequentialBlockModel,
                                          Zero3BlockSchedule)
 
@@ -106,7 +106,7 @@ def test_quant_spec_validation():
 
 # ------------------------------------------------------------ collectives
 def _run_spmd(topo, fn, *args, axes={"data"}, in_specs=None, out_specs=None):
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         fn, mesh=topo.mesh, axis_names=axes,
         in_specs=in_specs, out_specs=out_specs, check_vma=False))(*args)
 

@@ -14,7 +14,6 @@ from deepspeed_tpu.ops.quantizer import (
     quantized_nbytes,
 )
 from deepspeed_tpu.parallel import mesh as mesh_mod
-from deepspeed_tpu.parallel.mesh import shard_map_compat
 
 
 # ----------------------------------------------------------------------
@@ -68,7 +67,7 @@ def test_onebit_allreduce_matches_dense_in_expectation():
         red, nwe, nse = onebit_allreduce(xs[0], we[0], se[0], "data")
         return red[None], nwe[None], nse[None]
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         spmd, mesh=topo.mesh, axis_names={"data"},
         in_specs=(P("data"), P("data"), P("data")),
         out_specs=(P("data"), P("data"), P("data")), check_vma=False))
@@ -101,7 +100,7 @@ def test_int8_allreduce_close_to_dense():
         red, nerr = int8_allreduce(xs[0], err[0], "data", block=256)
         return red[None], nerr[None]
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         spmd, mesh=topo.mesh, axis_names={"data"},
         in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
         check_vma=False))

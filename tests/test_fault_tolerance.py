@@ -353,9 +353,8 @@ def _spmd_all_reduce(topo, fn):
     """One facade all_reduce inside shard_map (version-tolerant wrapper)."""
     from jax.sharding import PartitionSpec as P
 
-    from deepspeed_tpu.parallel.mesh import shard_map_compat
 
-    smapped = shard_map_compat(fn, mesh=topo.mesh, axis_names={"data"},
+    smapped = jax.shard_map(fn, mesh=topo.mesh, axis_names={"data"},
                                in_specs=P("data"), out_specs=P(),
                                check_vma=False)
     return jax.jit(smapped)(jnp.ones((8,), jnp.float32))

@@ -34,7 +34,7 @@ from deepspeed_tpu.comm.backends import (CollectiveBackend,
 from deepspeed_tpu.ops.quantizer import (pack_int4, quantize_blockwise,
                                          quantized_nbytes, unpack_int4)
 from deepspeed_tpu.parallel import mesh as mesh_mod
-from deepspeed_tpu.parallel.mesh import Topology, shard_map_compat
+from deepspeed_tpu.parallel.mesh import Topology
 from deepspeed_tpu.parallel.zero import (SequentialBlockModel,
                                          Zero3BlockSchedule)
 from deepspeed_tpu.telemetry import (MetricsRegistry, get_registry,
@@ -57,7 +57,7 @@ def reg():
 
 
 def _spmd(topo, fn, *args, in_specs, out_specs, axes={"data"}):
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         fn, mesh=topo.mesh, axis_names=axes,
         in_specs=in_specs, out_specs=out_specs, check_vma=False))(*args)
 

@@ -270,15 +270,6 @@ def test_hf_gpt_neo_decode_matches_torch(tmp_path):
     np.testing.assert_array_equal(out[0], ref[0])
 
 
-@pytest.mark.skipif(
-    jax.__version__.startswith("0.4."),
-    reason="pre-existing under jax 0.4.37: the model=4 TP forward "
-           "drifts at bf16 magnitude (~1e-2 on ~0.3 logits) from the "
-           "unsharded reference despite highest matmul precision — the "
-           "0.4.x GSPMD partitioner computes the sharded matmuls at a "
-           "lower effective precision. Unsharded ingestion parity and "
-           "the TP placement specs themselves are covered by the "
-           "passing tests in this file.")
 def test_hf_sharded_load_tp(tmp_path):
     """topology= places ingested params under TP PartitionSpecs; sharded
     forward matches the unsharded one."""

@@ -379,3 +379,58 @@ def test_host_overhead_ledger_in_step_records(tmp_path):
         assert rec["data_wait_ms"] is not None
     assert [r["n_steps"] for r in records] == [1, 1, 1, 2]
     assert records[-1]["step"] == 5
+
+
+# ----------------------------------------------------------------------
+# compile-cache placement (runtime/compile_cache.py)
+
+_PLACE_CACHE = """
+import os, sys
+import jax, jax.numpy as jnp
+from deepspeed_tpu.runtime import compile_cache
+
+set_dirs = []
+real_update = jax.config.update
+def spy(name, value):
+    if name == "jax_compilation_cache_dir":
+        set_dirs.append(value)
+    return real_update(name, value)
+jax.config.update = spy
+config_dir, default_dir = (a or None for a in sys.argv[1:3])
+placed = compile_cache.place_compile_cache(config_dir, default_dir)
+jax.jit(lambda x: x * 2 + 1)(jnp.ones((8,))).block_until_ready()
+print(repr((placed, set_dirs)))
+"""
+
+
+@pytest.mark.parametrize("env_dir,config_dir,default_dir,want,code_sets", [
+    ("env", "cfg", "dflt", "env", False),   # the environment wins, untouched
+    (None, "cfg", "dflt", "cfg", True),     # then compile.cache_dir
+    (None, None, "dflt", "dflt", True),     # then the entry point's fixed path
+    (None, None, None, None, False),        # else no cache
+])
+def test_compile_cache_placed_from_outside(tmp_path, env_dir, config_dir,
+                                           default_dir, want, code_sets):
+    """``JAX_COMPILATION_CACHE_DIR`` stands and no code sets the directory;
+    unset, the config's dir, else the caller's default. Cache files appear
+    in the chosen directory and in no other."""
+    import subprocess
+    import sys
+
+    dirs = {n: str(tmp_path / n) for n in ("env", "cfg", "dflt")}
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = dirs[env_dir]
+    proc = subprocess.run(
+        [sys.executable, "-c", _PLACE_CACHE,
+         dirs.get(config_dir, ""), dirs.get(default_dir, "")],
+        env=env, capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    placed, set_dirs = eval(proc.stdout.strip().splitlines()[-1])
+    assert placed == dirs.get(want)
+    assert set_dirs == ([dirs[want]] if code_sets else [])
+    for name, d in dirs.items():
+        filled = os.path.isdir(d) and bool(os.listdir(d))
+        assert filled == (name == want), (name, want)

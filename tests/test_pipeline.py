@@ -414,17 +414,6 @@ def test_pipeline_trunk_detection():
     assert (start, end) == (1, 5)
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="pre-existing under jax 0.4.37: the hetero pipeline runs on "
-           "a data>1 x pipe>1 mesh, which needs shard_map partial-auto "
-           "(axis_names) semantics — 0.4.x's experimental auto= path "
-           "aborts XLA CPU ('PartitionId instruction is not supported') "
-           "so shard_map_compat falls back to fully-manual mode, where "
-           "the data-axis interaction shifts the loss a few percent. "
-           "Homogeneous-pipe and single-axis legs pass; revisit on "
-           "jax >= 0.5.",
-    strict=False)
 def test_hetero_pipeline_loss_matches_sequential():
     """pipeline_loss over pipe=4 must equal the plain sequential loss —
     the embed/head-asymmetric case the reference handles via
@@ -444,17 +433,6 @@ def test_hetero_pipeline_loss_matches_sequential():
     np.testing.assert_allclose(pipe, seq, rtol=1e-5)
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="pre-existing under jax 0.4.37: the hetero pipeline runs on "
-           "a data>1 x pipe>1 mesh, which needs shard_map partial-auto "
-           "(axis_names) semantics — 0.4.x's experimental auto= path "
-           "aborts XLA CPU ('PartitionId instruction is not supported') "
-           "so shard_map_compat falls back to fully-manual mode, where "
-           "the data-axis interaction shifts the loss a few percent. "
-           "Homogeneous-pipe and single-axis legs pass; revisit on "
-           "jax >= 0.5.",
-    strict=False)
 def test_hetero_pipeline_grads_match_sequential():
     topo = mesh_mod.Topology.build_virtual({"data": 2, "pipe": 4})
     mesh_mod.set_topology(topo)
@@ -502,17 +480,6 @@ def test_trunk_not_merged_across_different_behavior():
     assert end - start == 2  # the tanh pair or the relu pair, never all 4
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="pre-existing under jax 0.4.37: the hetero pipeline runs on "
-           "a data>1 x pipe>1 mesh, which needs shard_map partial-auto "
-           "(axis_names) semantics — 0.4.x's experimental auto= path "
-           "aborts XLA CPU ('PartitionId instruction is not supported') "
-           "so shard_map_compat falls back to fully-manual mode, where "
-           "the data-axis interaction shifts the loss a few percent. "
-           "Homogeneous-pipe and single-axis legs pass; revisit on "
-           "jax >= 0.5.",
-    strict=False)
 def test_trunk_uses_bound_pipe_size_not_num_stages():
     """Partitioning hint (num_stages) and executing pipe size may differ;
     the trunk must divide by the EXECUTING size."""

@@ -459,17 +459,6 @@ def test_ragged_tp_rejects_indivisible_heads():
                               topology=topo)
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="pre-existing under jax 0.4.37 (CHANGES.md PR 6): the "
-           "experimental shard_map fallback reorders the expert-combine "
-           "reductions, so EP+TP logits drift ~1e-6 vs the unsharded "
-           "engine and greedy argmax flips on near-ties — the streams "
-           "diverge token-for-token. Functional behavior (routing, KV "
-           "accounting, shapes) is covered by the passing MoE/ragged "
-           "tests; revisit when jax.shard_map (>=0.5) replaces the "
-           "fallback.",
-    strict=False)
 @pytest.mark.parametrize("kernel_path", [False, True])
 def test_ragged_expert_parallel_serving(kernel_path, monkeypatch):
     """MoE serving over a TP x EP mesh (the reference's Mixtral serving

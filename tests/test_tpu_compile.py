@@ -1,0 +1,288 @@
+"""Compiles for a described (not attached) TPU v5e, on the CPU test host.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, so what Mosaic or XLA:TPU would refuse on the machine with the
+chip is refused here first, at no chip time (on-chip-measurement guide §2,
+rehearsal 3): a slice off the tiling, too much VMEM, a program over 16 GB,
+a kernel that cannot be partitioned. Interpret mode shows none of that.
+Nothing runs, so these say nothing about results or speed — the numeric
+halves live in ``chip_smoke.py``'s kernels phase, on the chip.
+
+Covered: the kernels of the main path at Mistral-7B widths (flash fwd/bwd;
+paged attention bf16, windowed and int8-KV, at a prefill-chunk and a
+decode shape; the int4-KV refusal), one whole train step and one ragged
+serving step of the smoke model at reduced depth, and the four-chip
+ZeRO-3 step ``chip_smoke.py --chips 4`` runs.
+
+One file on purpose: only the xdist worker that gets this file loads
+libtpu, inside the module-scoped ``topo`` fixture — never at import.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+
+HBM_BYTES = 16e9  # one v5e chip (profiling/flops_profiler.DEVICE_PEAKS)
+SZ = chip_smoke.Sizes()
+HQ, HKV, HD, BLK = SZ.n_heads, SZ.n_kv_heads, SZ.head_dim, SZ.kv_block_size
+N_PAGES, PAGES_PER_SEQ = 8192, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described 2x2 v5e host, with the persistent compile cache off
+    around the module: an entry written for a described chip cannot be
+    read back without one, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Dispatch code asks ``jax.devices()`` and sees the CPU; the test
+    steers it onto its TPU branch (guide §2) — not an option of the
+    program."""
+    monkeypatch.setattr("deepspeed_tpu.ops.attention._on_tpu", lambda: True)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _place(tree, sharding):
+    """Shapes of ``tree`` with ``sharding`` (one, or a matching tree)."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, sharding), tree)
+    return jax.tree_util.tree_map(
+        lambda x, s: _sds(x.shape, x.dtype, s), tree, sharding)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+# ----------------------------------------------------------------------
+# kernels
+def test_flash_forward_compiles(one_chip):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = _sds((1, SZ.kernel_seq, HQ, HD), jnp.bfloat16, one_chip)
+    k = _sds((1, SZ.kernel_seq, HKV, HD), jnp.bfloat16, one_chip)
+    c = jax.jit(lambda q, k, v: flash_attention(q, k, v, True, None)) \
+        .lower(q, k, k).compile()
+    assert c.as_text().count("tpu_custom_call") == 1
+
+
+def test_flash_backward_compiles(one_chip):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = _sds((1, SZ.kernel_seq, HQ, HD), jnp.bfloat16, one_chip)
+    k = _sds((1, SZ.kernel_seq, HKV, HD), jnp.bfloat16, one_chip)
+    loss = lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, True, None).astype(jnp.float32) ** 2)
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k).compile()
+    assert c.as_text().count("tpu_custom_call") == 3  # fwd, dq, dkv
+
+
+def _paged_args(T, sharding, pool_dtype=jnp.bfloat16, hd_packed=HD):
+    return dict(
+        q=_sds((T, HQ, HD), jnp.bfloat16, sharding),
+        pool=_sds((N_PAGES + 1, HKV, BLK, hd_packed), pool_dtype, sharding),
+        scale=_sds((N_PAGES + 1, HKV, BLK), jnp.float32, sharding),
+        tables=_sds((SZ.max_seqs, PAGES_PER_SEQ), jnp.int32, sharding),
+        lanes=_sds((T,), jnp.int32, sharding))
+
+
+@pytest.mark.parametrize("T", [SZ.token_budget, 64],
+                         ids=["prefill_chunk", "decode"])
+@pytest.mark.parametrize("variant", ["bf16", "windowed", "int8_kv"])
+def test_paged_attention_compiles(one_chip, variant, T):
+    """Per-sequence tables + slot indirection, the ragged engine's call
+    shape, at a SplitFuse prefill-chunk width and a decode width."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    quant = variant == "int8_kv"
+    a = _paged_args(T, one_chip, jnp.int8 if quant else jnp.bfloat16)
+    window = 1024 if variant == "windowed" else 0
+
+    def fn(q, kp, vp, tables, pos, slots, ks, vs):
+        scales = dict(k_scale=ks, v_scale=vs, kv_bits=8) if quant else {}
+        return paged_attention(q, kp, vp, tables, pos, seq_slots=slots,
+                               live_pages=PAGES_PER_SEQ, window=window,
+                               **scales)
+
+    c = jax.jit(fn).lower(a["q"], a["pool"], a["pool"], a["tables"],
+                          a["lanes"], a["lanes"], a["scale"],
+                          a["scale"]).compile()
+    assert c.as_text().count("tpu_custom_call") == 1
+
+
+def test_paged_int4_kv_refuses_before_the_compiler(one_chip):
+    """The v5e compiler spends minutes on the int4 unpack and then fails
+    with RESOURCE_EXHAUSTED (vmem) even at T=16 (ROADMAP.md S4): the
+    kernel refuses by name while tracing, without invoking it."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        Int4KVKernelUnsupported, paged_attention)
+
+    a = _paged_args(64, one_chip, jnp.uint8, HD // 2)
+    fn = lambda q, kp, vp, t, p, s, ks, vs: paged_attention(
+        q, kp, vp, t, p, seq_slots=s, k_scale=ks, v_scale=vs, kv_bits=4)
+    with pytest.raises(Int4KVKernelUnsupported, match="RESOURCE_EXHAUSTED"):
+        jax.jit(fn).lower(a["q"], a["pool"], a["pool"], a["tables"],
+                          a["lanes"], a["lanes"], a["scale"], a["scale"])
+
+
+def test_int4_kv_engine_refuses_at_construction_on_tpu(on_tpu):
+    """No minutes-long hang at server start, no silent gather path."""
+    from deepspeed_tpu.inference.ragged import (RaggedConfig,
+                                                RaggedInferenceEngine)
+    from deepspeed_tpu.models import Llama
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        Int4KVKernelUnsupported
+
+    model = Llama("tiny", n_layers=1, d_model=256, n_heads=2, n_kv_heads=2,
+                  vocab_size=64, max_seq_len=64)
+    cfg = lambda q: RaggedConfig(token_budget=16, max_seqs=2, n_kv_blocks=4,
+                                 max_context=64, kv_quant=q)
+    with pytest.raises(Int4KVKernelUnsupported):
+        RaggedInferenceEngine(model, cfg("int4"), params={})
+    assert RaggedInferenceEngine(model, cfg("int8"),
+                                 params={}).attention_path == "pallas"
+
+
+# ----------------------------------------------------------------------
+# whole programs of the smoke model
+def compile_train_step(devices, n_layers: int, batch: int, zero_stage: int):
+    """The engine's fused GSPMD train step (``TrainEngine._build_train_step``
+    with ``_update``: bf16 compute copy of fp32 masters, grads constrained
+    to the ZeRO grad shardings, global-norm clip, AdamW, state donated and
+    pinned to its shardings) rebuilt from the engine's own parts over
+    ``jax.eval_shape`` shapes — the engine itself places real arrays on
+    real devices, which a described chip cannot hold."""
+    from deepspeed_tpu.config import Config, MeshConfig
+    from deepspeed_tpu.parallel.mesh import Topology
+    from deepspeed_tpu.parallel.zero import ZeroShardingRules
+    from deepspeed_tpu.runtime.engine import _cast_tree, global_norm
+    from deepspeed_tpu.runtime.optimizers import build_optimizer
+
+    cfg = Config.from_any(chip_smoke.train_config(SZ, batch, zero_stage))
+    topo = Topology.build(MeshConfig(data=len(devices)), devices=devices)
+    model = chip_smoke.smoke_model(SZ, n_layers).bind_topology(topo)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    rules = ZeroShardingRules(topo, cfg.zero)
+    tp_specs = model.partition_specs(shapes, topo)
+    p_sh = rules.param_shardings(shapes, tp_specs)
+    g_sh = rules.grad_shardings(shapes, tp_specs)
+    opt = build_optimizer(cfg.optimizer.type, cfg.optimizer.params)
+    o_shapes = jax.eval_shape(opt.init, shapes)
+    o_sh = rules.opt_state_shardings(o_shapes)
+    repl = topo.replicated()
+
+    def train_step(params, opt_state, rng, batch):
+        def loss_fn(p):
+            return model.loss(_cast_tree(p, jnp.bfloat16), batch,
+                              rng).astype(jnp.float32)
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        grads = jax.lax.with_sharding_constraint(grads, g_sh)
+        gnorm = global_norm(grads)
+        factor = jnp.minimum(1.0, cfg.gradient_clipping / (gnorm + 1e-6))
+        grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
+        updates, new_opt = opt.update(grads, opt_state, params)
+        new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                            updates)
+        return new_params, new_opt, loss
+
+    tokens = _sds((batch, SZ.train_seq), jnp.int32, topo.batch_sharding(2))
+    return jax.jit(train_step, donate_argnums=(0, 1),
+                   out_shardings=(p_sh, o_sh, repl)).lower(
+        _place(shapes, p_sh), _place(o_shapes, o_sh),
+        _sds((2,), jnp.uint32, repl), {"input_ids": tokens}).compile()
+
+
+def test_train_step_compiles_and_fits_one_chip(topo, on_tpu):
+    """The step ``chip_smoke.py``'s train phase takes, depth and batch as
+    it runs them: flash fwd + bwd kernels inside scan + remat, within one
+    chip's HBM."""
+    c = compile_train_step(topo.devices[:1], SZ.train_layers,
+                           SZ.train_batch, zero_stage=0)
+    assert c.as_text().count("tpu_custom_call") >= 3
+    assert _device_bytes(c) < HBM_BYTES, _device_bytes(c)
+
+
+def test_zero3_step_on_four_chips_compiles(topo, on_tpu):
+    """``chip_smoke.py --chips 4``'s program: ZeRO-3 over data=4 is GSPMD
+    placement — gathers for the weights, reduce-scatters for the
+    gradients — with the flash kernel inside the model's shard_map, and a
+    quarter of the one-chip step's state on each device."""
+    four = compile_train_step(topo.devices, SZ.zero3_layers, SZ.zero3_batch,
+                              zero_stage=3)
+    hlo = four.as_text()
+    assert "all-gather" in hlo and "reduce-scatter" in hlo
+    assert hlo.count("tpu_custom_call") >= 3
+    one = compile_train_step(topo.devices[:1], SZ.zero3_layers,
+                             SZ.zero3_batch, zero_stage=0)
+    assert _device_bytes(one) < HBM_BYTES, _device_bytes(one)
+    state = lambda c: c.memory_analysis().argument_size_in_bytes
+    assert state(four) < 0.30 * state(one), (state(four), state(one))
+
+
+def compile_ragged_step(device_sharding, n_layers: int, T: int,
+                        live_pages: int, n_kv_blocks: int = 1024):
+    """``RaggedInferenceEngine``'s own jitted SplitFuse step, lowered
+    against shapes: the engine is built with no weights (the step takes
+    them as an argument) and a small host-side pool."""
+    from deepspeed_tpu.inference.ragged import (RaggedConfig,
+                                                RaggedInferenceEngine)
+
+    model = chip_smoke.smoke_model(SZ, n_layers)
+    eng = RaggedInferenceEngine(
+        model, RaggedConfig(token_budget=SZ.token_budget,
+                            max_seqs=SZ.max_seqs, kv_block_size=BLK,
+                            n_kv_blocks=n_kv_blocks,
+                            max_context=SZ.max_context), params={})
+    assert eng.attention_path == "pallas"
+    params = jax.eval_shape(partial(model.init, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    lanes = _sds((T,), jnp.int32, device_sharding)
+    return eng._build_step().lower(
+        _place(params, device_sharding),
+        _place(eng.kv_pool, device_sharding), lanes, lanes, lanes,
+        _sds((SZ.max_seqs, eng.max_pages), jnp.int32, device_sharding),
+        _sds((SZ.max_seqs,), jnp.int32, device_sharding),
+        live_pages).compile()
+
+
+@pytest.mark.parametrize("T,live_pages", [(SZ.token_budget, 128), (64, 128)],
+                         ids=["prefill_chunk", "decode"])
+def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages):
+    n_layers = 2  # reduced from chip_smoke's serve depth: compile time
+    c = compile_ragged_step(one_chip, n_layers, T, live_pages)
+    assert c.as_text().count("tpu_custom_call") == n_layers
