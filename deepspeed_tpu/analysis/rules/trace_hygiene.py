@@ -153,6 +153,17 @@ class TraceHygieneRule(Rule):
                         f"inside traced code would fire once at trace "
                         f"time with a trace-time clock stamp — span on "
                         f"the host, around the step call{why}")
+        elif name == "annotate" and (src_mod or "").endswith(
+                "profiling.trace"):
+            # the one path to the profiler's host track (PR 25): a host
+            # span in a traced body would cover the trace, not the step
+            yield Finding(
+                rule=self.id, code="tracer-call", path=mod.key,
+                line=node.lineno, col=node.col_offset, symbol=f.qualname,
+                message=f"annotate() (profiler host span) inside traced "
+                        f"code spans the trace, not the step — annotate "
+                        f"on the host around the call and name device "
+                        f"work with jax.named_scope{why}")
         elif isinstance(node.func, ast.Attribute) \
                 and name in _REGISTRY_OPS:
             # x.inc(...) / x.observe(...): registry series mutation

@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.ragged_host import build_batch, fill_tables
 from ..ops.rotary import apply_rotary, rope_frequencies
+from ..profiling.trace import annotate
 from ..utils.logging import log_dist
 from .engine import _sample
 
@@ -1379,35 +1380,44 @@ class RaggedInferenceEngine:
         """Admit new tokens into sequence descriptors — put()'s first
         phase, shared with :meth:`put_spec`: fresh uids get a slot (and
         adopt the longest cached full-block prefix), existing ones append
-        their chunk."""
-        for uid, toks in zip(uids, tokens):
-            new = uid not in self.seqs
-            if new:
-                if not self._free_slots:
-                    raise RuntimeError("no free sequence slots; flush() first")
-                now = time.perf_counter()
-                resumed = uid in self._resume_uids
-                self._resume_uids.discard(uid)
-                self.seqs[uid] = SequenceDescriptor(
-                    uid=uid, slot=self._free_slots.pop(),
-                    t_admitted=None if resumed else now,
-                    t_created=None if resumed else now)
-            seq = self.seqs[uid]
-            seq.tokens.extend(int(t) for t in toks)
-            if new:
-                seq.prompt_len = len(seq.tokens)
-            if new and self.prefix_cache is not None and seq.tokens:
-                if self._cold_tier is not None:
-                    # cold-tier re-admission first, so the match below
-                    # can adopt a spilled prefix the device pool lost
-                    self._cold_readmit(seq.tokens)
-                # adopt the longest cached full-block prefix: its KV pages
-                # are shared (retained), and prefill starts past them
-                shared, blocks = self.prefix_cache.match(seq.tokens)
-                if shared:
-                    self.allocator.retain(blocks)
-                    seq.blocks = list(blocks)
-                    seq.seen = shared
+        their chunk. Span ``ragged.admit``: ``prompt`` tokens admitted
+        for fresh uids, ``matched`` of them adopted from the prefix
+        cache."""
+        with annotate("ragged.admit") as span:
+            matched = prompt = 0
+            for uid, toks in zip(uids, tokens):
+                new = uid not in self.seqs
+                if new:
+                    if not self._free_slots:
+                        raise RuntimeError(
+                            "no free sequence slots; flush() first")
+                    now = time.perf_counter()
+                    resumed = uid in self._resume_uids
+                    self._resume_uids.discard(uid)
+                    self.seqs[uid] = SequenceDescriptor(
+                        uid=uid, slot=self._free_slots.pop(),
+                        t_admitted=None if resumed else now,
+                        t_created=None if resumed else now)
+                seq = self.seqs[uid]
+                seq.tokens.extend(int(t) for t in toks)
+                if new:
+                    seq.prompt_len = len(seq.tokens)
+                    prompt += seq.prompt_len
+                if new and self.prefix_cache is not None and seq.tokens:
+                    if self._cold_tier is not None:
+                        # cold-tier re-admission first, so the match below
+                        # can adopt a spilled prefix the device pool lost
+                        self._cold_readmit(seq.tokens)
+                    # adopt the longest cached full-block prefix: its KV
+                    # pages are shared (retained), and prefill starts past
+                    # them
+                    shared, blocks = self.prefix_cache.match(seq.tokens)
+                    if shared:
+                        self.allocator.retain(blocks)
+                        seq.blocks = list(blocks)
+                        seq.seen = shared
+                        matched += shared
+            span.set_metadata(matched=matched, prompt=prompt)
 
     def _pack_splitfuse(self) -> List[Tuple[SequenceDescriptor, int]]:
         """Dynamic SplitFuse packing: decodes (and short prompt tails)
@@ -1432,55 +1442,83 @@ class RaggedInferenceEngine:
         processed token; rows are NaN while a long prompt is still
         mid-prefill (call put(uid, []) again to continue it).
         """
+        with annotate("ragged.put") as span:
+            return self._put(span, uids, tokens)
+
+    def _put(self, span, uids, tokens) -> np.ndarray:
+        """:meth:`put` under its span; the phases are spans of their own
+        (docs/observability.md "Program spans and device scopes")."""
         cfg = self.config
         self._admit_tokens(uids, tokens)
-        sched = self._pack_splitfuse()
-        if not sched:
-            raise ValueError("put() called with no pending tokens")
+        with annotate("ragged.pack"):
+            sched = self._pack_splitfuse()
+            if not sched:
+                raise ValueError("put() called with no pending tokens")
 
-        # ---- validate + allocate for the WHOLE schedule before mutating any
-        # sequence state, so an exhausted pool leaves every descriptor
-        # consistent (seen never advances without its KV being written)
-        needs = self._validate_sched(sched)
-        flat_tokens, flat_slot, flat_pos, last_idx = \
-            self._allocate_and_build(sched, needs)
-        last_index = {}  # uid -> index in flat batch of its last token
-        for (seq, take), li in zip(sched, last_idx):
-            seq.seen += take
-            last_index[seq.uid] = int(li)
+            # ---- validate + allocate for the WHOLE schedule before mutating
+            # any sequence state, so an exhausted pool leaves every descriptor
+            # consistent (seen never advances without its KV being written)
+            needs = self._validate_sched(sched)
+            flat_tokens, flat_slot, flat_pos, last_idx = \
+                self._allocate_and_build(sched, needs)
+            live_pages = self._live_pages_bucket()
+            span.set_metadata(**self._sched_attrs(sched, len(flat_tokens),
+                                                  live_pages))
+            last_index = {}  # uid -> index in flat batch of its last token
+            for (seq, take), li in zip(sched, last_idx):
+                seq.seen += take
+                last_index[seq.uid] = int(li)
 
-        block_tables = self._host_tables()
+            block_tables = self._host_tables()
 
-        # per-slot index of the row whose logits we need (sequences not in
-        # this schedule keep a harmless 0 — their rows are never read)
-        sel_idx = np.zeros((cfg.max_seqs,), np.int32)
-        for uid, idx in last_index.items():
-            sel_idx[self.seqs[uid].slot] = idx
+            # per-slot index of the row whose logits we need (sequences not
+            # in this schedule keep a harmless 0 — their rows are never read)
+            sel_idx = np.zeros((cfg.max_seqs,), np.int32)
+            for uid, idx in last_index.items():
+                sel_idx[self.seqs[uid].slot] = idx
 
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
-        logits, self.kv_pool = self._step_fn(
-            self.params, self.kv_pool, jnp.asarray(flat_tokens),
-            jnp.asarray(flat_slot), jnp.asarray(flat_pos),
-            jnp.asarray(block_tables), jnp.asarray(sel_idx),
-            self._live_pages_bucket())
-        logits = np.asarray(logits)                    # [max_seqs, vocab]
+        with annotate("ragged.dispatch"):
+            if self._step_fn is None:
+                self._step_fn = self._build_step()
+            logits, self.kv_pool = self._step_fn(
+                self.params, self.kv_pool, jnp.asarray(flat_tokens),
+                jnp.asarray(flat_slot), jnp.asarray(flat_pos),
+                jnp.asarray(block_tables), jnp.asarray(sel_idx), live_pages)
+        with annotate("ragged.fetch", bytes=int(logits.nbytes)):
+            logits = np.asarray(logits)                # [max_seqs, vocab]
 
-        out = np.full((len(uids), logits.shape[-1]), np.nan, np.float32)
-        now = time.perf_counter()
-        for i, uid in enumerate(uids):
-            seq = self.seqs[uid]
-            if seq.pending == 0 and uid in last_index:
-                out[i] = logits[seq.slot]
-                if seq.t_admitted is not None:
-                    # prompt fully prefilled and first logits on host: TTFT.
-                    # End-to-end latency is reported at flush(), when the
-                    # request actually completes.
-                    self._telemetry.record_request(
-                        ttft_s=now - seq.t_admitted)
-                    seq.t_admitted = None
-        self._record_step_telemetry(sched)
+        with annotate("ragged.rows"):
+            out = np.full((len(uids), logits.shape[-1]), np.nan, np.float32)
+            now = time.perf_counter()
+            for i, uid in enumerate(uids):
+                seq = self.seqs[uid]
+                if seq.pending == 0 and uid in last_index:
+                    out[i] = logits[seq.slot]
+                    if seq.t_admitted is not None:
+                        # prompt fully prefilled and first logits on host:
+                        # TTFT. End-to-end latency is reported at flush(),
+                        # when the request actually completes.
+                        self._telemetry.record_request(
+                            ttft_s=now - seq.t_admitted)
+                        seq.t_admitted = None
+            self._record_step_telemetry(sched)
         return out
+
+    def _sched_attrs(self, sched, lanes: int, live_pages: int
+                     ) -> Dict[str, int]:
+        """``ragged.put``'s attributes, read after allocation and before
+        ``seen`` advances: the lane bucket, the live-page bucket, entries
+        scheduled, lanes given to sequences still inside their prompt,
+        single-token entries past it, and pages left free."""
+        prefill = decode = 0
+        for seq, take in sched:
+            if seq.seen < seq.prompt_len:
+                prefill += take
+            elif take == 1:
+                decode += 1
+        return {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
+                "prefill": prefill, "decode": decode,
+                "free": self.allocator.free_blocks}
 
     def put_spec(self, uids: Sequence[int], tokens: Sequence[Sequence[int]],
                  drafts: Sequence[Sequence[int]]
@@ -1507,93 +1545,107 @@ class RaggedInferenceEngine:
         context. On PoolExhausted every remaining draft token is
         stripped before the raise, so the recovery retry (plain ``put``
         with empty chunks) sees exactly put()'s admitted state."""
+        with annotate("ragged.put") as span:
+            return self._put_spec(span, uids, tokens, drafts)
+
+    def _put_spec(self, span, uids, tokens, drafts):
+        """:meth:`put_spec` under its span, in :meth:`_put`'s phases."""
         cfg = self.config
         self._admit_tokens(uids, tokens)
-        # validate EVERY chain before appending ANY draft token: a raise
-        # mid-append would leave earlier uids' unverified drafts in their
-        # streams, and the next plain put() would schedule them as real
-        # context
-        for uid, d in zip(uids, drafts):
-            if d and self.seqs[uid].pending != 1:
-                raise ValueError(
-                    f"uid {uid}: a draft chain continues exactly one "
-                    f"pending decode token, found "
-                    f"pending={self.seqs[uid].pending}")
-        appended: Dict[int, int] = {}     # uid -> draft tokens on the stream
-        for uid, d in zip(uids, drafts):
-            if not d:
-                continue
-            self.seqs[uid].tokens.extend(int(t) for t in d)
-            appended[uid] = len(d)
-        try:
-            sched = self._pack_splitfuse()
-            if not sched:
-                raise ValueError("put_spec() called with no pending tokens")
-            # all-or-strip: drop draft proposals the budget left behind
-            take_of = {seq.uid: take for seq, take in sched}
-            for uid in list(appended):
-                seq = self.seqs[uid]
-                chain_len = 1 + appended[uid]
-                take = take_of.get(uid, 0)
-                if take < chain_len:
-                    strip = chain_len - max(take, 1)
-                    if strip:
-                        del seq.tokens[len(seq.tokens) - strip:]
-                        appended[uid] -= strip
-                    if appended[uid] <= 0:
-                        appended.pop(uid)
-            sched = [(seq, min(take, seq.pending))
-                     for seq, take in sched if seq.pending > 0]
-            needs = self._validate_sched(sched)
-        except BaseException:
-            for uid, n in appended.items():
-                seq = self.seqs[uid]
-                del seq.tokens[len(seq.tokens) - n:]
-            raise
-        flat_tokens, flat_slot, flat_pos, last_idx = \
-            self._allocate_and_build(sched, needs)
-        k_max = 1
-        for seq, take in sched:
-            if seq.uid in appended:
-                while k_max < take:
-                    k_max *= 2
-        sel_rows = np.zeros((cfg.max_seqs, k_max), np.int32)
-        last_index: Dict[int, int] = {}
-        for (seq, take), li in zip(sched, last_idx):
-            li = int(li)
-            sel_rows[seq.slot, :] = li        # padding rows: never read
-            if seq.uid in appended:
-                sel_rows[seq.slot, :take] = np.arange(li - take + 1, li + 1)
-            seq.seen += take
-            last_index[seq.uid] = li
-        if self._verify_fn is None:
-            self._verify_fn = self._build_verify()
-        logits, self.kv_pool = self._verify_fn(
-            self.params, self.kv_pool, jnp.asarray(flat_tokens),
-            jnp.asarray(flat_slot), jnp.asarray(flat_pos),
-            jnp.asarray(self._host_tables()), jnp.asarray(sel_rows),
-            self._live_pages_bucket())
-        logits = np.asarray(logits)           # [max_seqs, k_max, vocab]
+        with annotate("ragged.pack"):
+            # validate EVERY chain before appending ANY draft token: a raise
+            # mid-append would leave earlier uids' unverified drafts in their
+            # streams, and the next plain put() would schedule them as real
+            # context
+            for uid, d in zip(uids, drafts):
+                if d and self.seqs[uid].pending != 1:
+                    raise ValueError(
+                        f"uid {uid}: a draft chain continues exactly one "
+                        f"pending decode token, found "
+                        f"pending={self.seqs[uid].pending}")
+            appended: Dict[int, int] = {}  # uid -> draft tokens on the stream
+            for uid, d in zip(uids, drafts):
+                if not d:
+                    continue
+                self.seqs[uid].tokens.extend(int(t) for t in d)
+                appended[uid] = len(d)
+            try:
+                sched = self._pack_splitfuse()
+                if not sched:
+                    raise ValueError(
+                        "put_spec() called with no pending tokens")
+                # all-or-strip: drop draft proposals the budget left behind
+                take_of = {seq.uid: take for seq, take in sched}
+                for uid in list(appended):
+                    seq = self.seqs[uid]
+                    chain_len = 1 + appended[uid]
+                    take = take_of.get(uid, 0)
+                    if take < chain_len:
+                        strip = chain_len - max(take, 1)
+                        if strip:
+                            del seq.tokens[len(seq.tokens) - strip:]
+                            appended[uid] -= strip
+                        if appended[uid] <= 0:
+                            appended.pop(uid)
+                sched = [(seq, min(take, seq.pending))
+                         for seq, take in sched if seq.pending > 0]
+                needs = self._validate_sched(sched)
+            except BaseException:
+                for uid, n in appended.items():
+                    seq = self.seqs[uid]
+                    del seq.tokens[len(seq.tokens) - n:]
+                raise
+            flat_tokens, flat_slot, flat_pos, last_idx = \
+                self._allocate_and_build(sched, needs)
+            live_pages = self._live_pages_bucket()
+            span.set_metadata(**self._sched_attrs(sched, len(flat_tokens),
+                                                  live_pages))
+            k_max = 1
+            for seq, take in sched:
+                if seq.uid in appended:
+                    while k_max < take:
+                        k_max *= 2
+            sel_rows = np.zeros((cfg.max_seqs, k_max), np.int32)
+            last_index: Dict[int, int] = {}
+            for (seq, take), li in zip(sched, last_idx):
+                li = int(li)
+                sel_rows[seq.slot, :] = li        # padding rows: never read
+                if seq.uid in appended:
+                    sel_rows[seq.slot, :take] = np.arange(li - take + 1,
+                                                          li + 1)
+                seq.seen += take
+                last_index[seq.uid] = li
+            block_tables = self._host_tables()
+        with annotate("ragged.dispatch"):
+            if self._verify_fn is None:
+                self._verify_fn = self._build_verify()
+            logits, self.kv_pool = self._verify_fn(
+                self.params, self.kv_pool, jnp.asarray(flat_tokens),
+                jnp.asarray(flat_slot), jnp.asarray(flat_pos),
+                jnp.asarray(block_tables), jnp.asarray(sel_rows), live_pages)
+        with annotate("ragged.fetch", bytes=int(logits.nbytes)):
+            logits = np.asarray(logits)       # [max_seqs, k_max, vocab]
 
-        out = np.full((len(uids), logits.shape[-1]), np.nan, np.float32)
-        now = time.perf_counter()
-        for i, uid in enumerate(uids):
-            seq = self.seqs[uid]
-            if seq.pending == 0 and uid in last_index:
-                # sel_rows[slot, -1] is the last scheduled row whether or
-                # not the slot carried a chain — put()'s contract holds
-                out[i] = logits[seq.slot, -1]
-                if seq.t_admitted is not None:
-                    self._telemetry.record_request(
-                        ttft_s=now - seq.t_admitted)
-                    seq.t_admitted = None
-        verified: Dict[int, Tuple[List[int], np.ndarray]] = {}
-        for seq, take in sched:
-            if seq.uid in appended:
-                chain = [int(t) for t in seq.tokens[seq.seen - take:
-                                                    seq.seen]]
-                verified[seq.uid] = (chain, logits[seq.slot, :take])
-        self._record_step_telemetry(sched)
+        with annotate("ragged.rows"):
+            out = np.full((len(uids), logits.shape[-1]), np.nan, np.float32)
+            now = time.perf_counter()
+            for i, uid in enumerate(uids):
+                seq = self.seqs[uid]
+                if seq.pending == 0 and uid in last_index:
+                    # sel_rows[slot, -1] is the last scheduled row whether or
+                    # not the slot carried a chain — put()'s contract holds
+                    out[i] = logits[seq.slot, -1]
+                    if seq.t_admitted is not None:
+                        self._telemetry.record_request(
+                            ttft_s=now - seq.t_admitted)
+                        seq.t_admitted = None
+            verified: Dict[int, Tuple[List[int], np.ndarray]] = {}
+            for seq, take in sched:
+                if seq.uid in appended:
+                    chain = [int(t) for t in seq.tokens[seq.seen - take:
+                                                        seq.seen]]
+                    verified[seq.uid] = (chain, logits[seq.slot, :take])
+            self._record_step_telemetry(sched)
         return out, verified
 
     def kv_occupancy(self) -> float:
@@ -1703,8 +1755,9 @@ class RaggedInferenceEngine:
                  sel_rows, live_pages):
             x, pools = core(params, pools, tokens, slots, positions,
                             block_tables, live_pages)
-            x_sel = x[sel_rows.reshape(-1)]                 # [S*k, d]
-            logits = model._head(params, x_sel[None, :])[0]
+            with jax.named_scope("head"):
+                x_sel = x[sel_rows.reshape(-1)]             # [S*k, d]
+                logits = model._head(params, x_sel[None, :])[0]
             return logits.reshape(sel_rows.shape + (-1,)), pools
 
         return jax.jit(step, donate_argnums=(1,), static_argnums=(7,))
@@ -2112,6 +2165,10 @@ class RaggedInferenceEngine:
                  live_pages):
             # live_pages: static python int — bounds the kernel's page walk
             # tokens/slots/positions: [T]; embeddings via the model's path
+            # device scopes (jax.named_scope: metadata only) name the
+            # step's parts in a profiler trace: embed, weights, attn with
+            # paged_attention around the kernel, ffn, head
+            # (docs/observability.md); _embed, _mlp and _head bring theirs
             x = model._embed(params, tokens[None, :],
                              positions=positions[None, :])[0]  # [T, d]
             angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta) \
@@ -2128,97 +2185,102 @@ class RaggedInferenceEngine:
             vs_list = list(pools[3]) if kv_bits else None
 
             def block(x, li, lp):
-                kp, vp = k_list[li], v_list[li]
-                h = norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"))
-                q = (h @ lp["wq"]).reshape(-1, c.n_heads, c.head_dim)
-                kk = (h @ lp["wk"]).reshape(-1, c.n_kv_heads, c.head_dim)
-                vv = (h @ lp["wv"]).reshape(-1, c.n_kv_heads, c.head_dim)
-                if c.qkv_bias:
-                    q = q + lp["bq"].reshape(c.n_heads, c.head_dim)
-                    kk = kk + lp["bk"].reshape(c.n_kv_heads, c.head_dim)
-                    vv = vv + lp["bv"].reshape(c.n_kv_heads, c.head_dim)
-                if c.position == "rope":
-                    q = apply_rotary(q[:, None], angles, positions[:, None],
-                                     rotary_dim=c.rotary_dim,
-                                     interleaved=c.rope_interleaved)[:, 0]
-                    kk = apply_rotary(kk[:, None], angles, positions[:, None],
-                                      rotary_dim=c.rotary_dim,
-                                      interleaved=c.rope_interleaved)[:, 0]
-                # scatter new K/V into this layer's pages — one in-place
-                # scatter of the touched pages into this layer's leaf:
-                # page = table[pos // bs], row = pos % bs
-                page = block_tables[safe_slot, positions // bs]   # [T]
-                row = positions % bs
-                # inactive lanes — and any lane past the context window
-                # (possible in the tail of a multi-step decode) — scatter
-                # into the scratch sink page, never a live one
-                page = jnp.where(active & (positions < cfg.max_context),
-                                 page, cfg.n_kv_blocks)
-                # pool layout [pages, hkv, block, hd]; kk [T, hkv, hd].
-                # kv_quant: quantize each head-vector on the way in (one
-                # fp32 scale per row, ops/quantizer.quantize_kv) and
-                # scatter payload + scale; reads below dequantize inside
-                # the paged-attention path, so fp K/V never round-trips
-                # through HBM at full width
-                if kv_bits:
-                    from ..ops.quantizer import quantize_kv
+                with jax.named_scope("attn"):
+                    kp, vp = k_list[li], v_list[li]
+                    h = norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"))
+                    q = (h @ lp["wq"]).reshape(-1, c.n_heads, c.head_dim)
+                    kk = (h @ lp["wk"]).reshape(-1, c.n_kv_heads, c.head_dim)
+                    vv = (h @ lp["wv"]).reshape(-1, c.n_kv_heads, c.head_dim)
+                    if c.qkv_bias:
+                        q = q + lp["bq"].reshape(c.n_heads, c.head_dim)
+                        kk = kk + lp["bk"].reshape(c.n_kv_heads, c.head_dim)
+                        vv = vv + lp["bv"].reshape(c.n_kv_heads, c.head_dim)
+                    if c.position == "rope":
+                        q = apply_rotary(q[:, None], angles, positions[:, None],
+                                         rotary_dim=c.rotary_dim,
+                                         interleaved=c.rope_interleaved)[:, 0]
+                        kk = apply_rotary(kk[:, None], angles, positions[:, None],
+                                          rotary_dim=c.rotary_dim,
+                                          interleaved=c.rope_interleaved)[:, 0]
+                    # scatter new K/V into this layer's pages — one in-place
+                    # scatter of the touched pages into this layer's leaf:
+                    # page = table[pos // bs], row = pos % bs
+                    page = block_tables[safe_slot, positions // bs]   # [T]
+                    row = positions % bs
+                    # inactive lanes — and any lane past the context window
+                    # (possible in the tail of a multi-step decode) — scatter
+                    # into the scratch sink page, never a live one
+                    page = jnp.where(active & (positions < cfg.max_context),
+                                     page, cfg.n_kv_blocks)
+                    # pool layout [pages, hkv, block, hd]; kk [T, hkv, hd].
+                    # kv_quant: quantize each head-vector on the way in (one
+                    # fp32 scale per row, ops/quantizer.quantize_kv) and
+                    # scatter payload + scale; reads below dequantize inside
+                    # the paged-attention path, so fp K/V never round-trips
+                    # through HBM at full width
+                    if kv_bits:
+                        from ..ops.quantizer import quantize_kv
 
-                    qk, sk = quantize_kv(kk, kv_bits)
-                    qv, sv = quantize_kv(vv, kv_bits)
-                    kp = kp.at[page, :, row].set(qk)
-                    vp = vp.at[page, :, row].set(qv)
-                    ksl = ks_list[li].at[page, :, row].set(sk)
-                    vsl = vs_list[li].at[page, :, row].set(sv)
-                    k_list[li], v_list[li] = kp, vp
-                    ks_list[li], vs_list[li] = ksl, vsl
-                else:
-                    ksl = vsl = None
-                    kp = kp.at[page, :, row].set(kk.astype(kp.dtype))
-                    vp = vp.at[page, :, row].set(vv.astype(vp.dtype))
-                    k_list[li], v_list[li] = kp, vp
-                # paged attention: Pallas kernel on TPU (scalar-prefetched
-                # block tables, zero gather); jnp gather path elsewhere.
-                # (positions <= ctx-1 always, so the causal mask subsumes the
-                # context-length mask; inactive lanes produce ignored junk)
-                if use_pallas and self._tp_size > 1:
-                    attn = _paged_attn_sharded(q, kp, vp, block_tables,
-                                               positions, safe_slot,
-                                               live_pages, windows[li],
-                                               ks=ksl, vs=vsl)
-                elif use_pallas:
-                    attn = paged_attention(q, kp, vp, block_tables,
-                                           positions, seq_slots=safe_slot,
-                                           live_pages=live_pages,
-                                           window=windows[li],
-                                           k_scale=ksl, v_scale=vsl,
-                                           kv_bits=kv_bits,
-                                           interpret=interp)
-                else:
-                    attn = paged_attention_reference(q, kp, vp, tables,
-                                                     positions,
-                                                     window=windows[li],
-                                                     k_scale=ksl,
-                                                     v_scale=vsl,
-                                                     kv_bits=kv_bits)
-                attn = attn.astype(x.dtype)
-                attn = attn.reshape(-1, c.n_heads * c.head_dim) @ lp["wo"]
-                # attn_o_bias, not use_bias: InternLM has use_bias=False
-                # with a real o_proj bias (models/transformer.py:500)
-                if c.attn_o_bias:
-                    attn = attn + lp["bo"]
-                x = x + attn
-                h = norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
-                # the model's own MLP: honors relu/gelu/gelu_exact/silu_glu
-                # and the MoE override (top-k routed experts) uniformly
-                down, _ = model._mlp(h[None], lp, None, False)
-                return x + down[0]
+                        qk, sk = quantize_kv(kk, kv_bits)
+                        qv, sv = quantize_kv(vv, kv_bits)
+                        kp = kp.at[page, :, row].set(qk)
+                        vp = vp.at[page, :, row].set(qv)
+                        ksl = ks_list[li].at[page, :, row].set(sk)
+                        vsl = vs_list[li].at[page, :, row].set(sv)
+                        k_list[li], v_list[li] = kp, vp
+                        ks_list[li], vs_list[li] = ksl, vsl
+                    else:
+                        ksl = vsl = None
+                        kp = kp.at[page, :, row].set(kk.astype(kp.dtype))
+                        vp = vp.at[page, :, row].set(vv.astype(vp.dtype))
+                        k_list[li], v_list[li] = kp, vp
+                    # paged attention: Pallas kernel on TPU (scalar-prefetched
+                    # block tables, zero gather); jnp gather path elsewhere.
+                    # (positions <= ctx-1 always, so the causal mask subsumes the
+                    # context-length mask; inactive lanes produce ignored junk)
+                    with jax.named_scope("paged_attention"):
+                        if use_pallas and self._tp_size > 1:
+                            attn = _paged_attn_sharded(q, kp, vp, block_tables,
+                                                       positions, safe_slot,
+                                                       live_pages, windows[li],
+                                                       ks=ksl, vs=vsl)
+                        elif use_pallas:
+                            attn = paged_attention(q, kp, vp, block_tables,
+                                                   positions, seq_slots=safe_slot,
+                                                   live_pages=live_pages,
+                                                   window=windows[li],
+                                                   k_scale=ksl, v_scale=vsl,
+                                                   kv_bits=kv_bits,
+                                                   interpret=interp)
+                        else:
+                            attn = paged_attention_reference(q, kp, vp, tables,
+                                                             positions,
+                                                             window=windows[li],
+                                                             k_scale=ksl,
+                                                             v_scale=vsl,
+                                                             kv_bits=kv_bits)
+                    attn = attn.astype(x.dtype)
+                    attn = attn.reshape(-1, c.n_heads * c.head_dim) @ lp["wo"]
+                    # attn_o_bias, not use_bias: InternLM has use_bias=False
+                    # with a real o_proj bias (models/transformer.py:500)
+                    if c.attn_o_bias:
+                        attn = attn + lp["bo"]
+                    x = x + attn
+                with jax.named_scope("ffn"):
+                    h = norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
+                    # the model's own MLP: honors relu/gelu/gelu_exact/silu_glu
+                    # and the MoE override (top-k routed experts) uniformly
+                    down, _ = model._mlp(h[None], lp, None, False)
+                    return x + down[0]
 
             # python-unrolled layer loop, NOT lax.scan: a scan would carry
             # the whole pool and either re-slice it per layer (stacked
             # layout) or double-buffer it (flat layout) — see the pool_shape
             # comment in __init__
             for li in range(c.n_layers):
-                lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+                with jax.named_scope("weights"):
+                    lp = jax.tree_util.tree_map(lambda a: a[li],
+                                                params["layers"])
                 x = block(x, li, lp)
             out_pools = (tuple(k_list), tuple(v_list))
             if kv_bits:
@@ -2245,8 +2307,9 @@ class RaggedInferenceEngine:
             # [token_budget, vocab] fp32 logits are 512 MB at T=4096 v=32k
             # and were previously fetched to host every step — select the
             # [max_seqs] rows on-device before the (remote) host transfer
-            x_sel = x[sel_idx]                                     # [S, d]
-            logits = model._head(params, x_sel[None, :])[0]        # [S, vocab]
+            with jax.named_scope("head"):
+                x_sel = x[sel_idx]                                 # [S, d]
+                logits = model._head(params, x_sel[None, :])[0]    # [S, vocab]
             return logits, pools
 
         return jax.jit(step, donate_argnums=(1,), static_argnums=(7,))

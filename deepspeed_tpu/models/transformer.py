@@ -308,8 +308,11 @@ class Transformer:
         axes, heads over 'model'), so each device runs the kernel on its
         local shard with zero collectives. Single-device (the bench) and
         unbound-mesh paths call the dispatcher directly."""
-        from ..ops.attention import flash_attention as fa
+        from ..ops.attention import flash_attention
 
+        # the scope names the Pallas custom calls flash_attention.N in a
+        # profiler trace, forward and backward
+        fa = jax.named_scope("flash_attention")(flash_attention)
         kw = {"causal": causal, "scale": scale}
         if window:
             kw["window"] = window
@@ -397,136 +400,144 @@ class Transformer:
                 "masks are an encoder feature; causal batches should pack "
                 "or left-trim instead)")
 
-        # pre-LN normalizes the branch input; post-LN (BERT-era,
-        # prenorm=False) runs the branch on x and norms AFTER the residual
-        h = self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
-            if c.prenorm else x
-        q = h @ lp["wq"]
-        kk = h @ lp["wk"]
-        vv = h @ lp["wv"]
-        if c.qkv_bias:
-            q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
-        q = q.reshape(b, s, c.n_heads, hd)
-        kk = kk.reshape(b, s, c.n_kv_heads, hd)
-        vv = vv.reshape(b, s, c.n_kv_heads, hd)
-        if c.position == "rope":
-            # apply_rotary no-ops the partial slice when rotary_dim == hd
-            q = apply_rotary(q, angles, positions, rotary_dim=c.rotary_dim,
-                             interleaved=c.rope_interleaved)
-            kk = apply_rotary(kk, angles, positions, rotary_dim=c.rotary_dim,
-                              interleaved=c.rope_interleaved)
+        # device scopes (jax.named_scope: metadata only) name the block's
+        # parts in a profiler trace: attn (flash_attention around the
+        # kernel), ffn (docs/observability.md)
+        with jax.named_scope("attn"):
+            # pre-LN normalizes the branch input; post-LN (BERT-era,
+            # prenorm=False) runs the branch on x and norms AFTER the residual
+            h = self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
+                if c.prenorm else x
+            q = h @ lp["wq"]
+            kk = h @ lp["wk"]
+            vv = h @ lp["wv"]
+            if c.qkv_bias:
+                q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
+            q = q.reshape(b, s, c.n_heads, hd)
+            kk = kk.reshape(b, s, c.n_kv_heads, hd)
+            vv = vv.reshape(b, s, c.n_kv_heads, hd)
+            if c.position == "rope":
+                # apply_rotary no-ops the partial slice when rotary_dim == hd
+                q = apply_rotary(q, angles, positions, rotary_dim=c.rotary_dim,
+                                 interleaved=c.rope_interleaved)
+                kk = apply_rotary(kk, angles, positions, rotary_dim=c.rotary_dim,
+                                  interleaved=c.rope_interleaved)
 
-        def _alibi_bias(skv):
-            # ALiBi (Bloom): logits += slopes * (k_pos - q_pos); the per-row
-            # -slopes*q_pos shift is constant along the softmax axis and
-            # cancels, so slopes * k_pos alone is exact under row softmax
-            slopes = alibi_slopes(c.n_heads)
-            return (slopes[:, None, None]
-                    * jnp.arange(skv, dtype=jnp.float32)[None, None, :])
+            def _alibi_bias(skv):
+                # ALiBi (Bloom): logits += slopes * (k_pos - q_pos); the per-row
+                # -slopes*q_pos shift is constant along the softmax axis and
+                # cancels, so slopes * k_pos alone is exact under row softmax
+                slopes = alibi_slopes(c.n_heads)
+                return (slopes[:, None, None]
+                        * jnp.arange(skv, dtype=jnp.float32)[None, None, :])
 
-        new_kv = None
-        if kv_cache is not None:
-            ck, cv, cache_pos = kv_cache
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, kk, cache_pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, vv, cache_pos, axis=1)
-            new_kv = (ck, cv)
-            # query i sits at absolute position cache_pos + i: it may attend
-            # every cache slot up to and including itself (this also masks
-            # the unwritten zero tail of the cache)
-            q_abs = cache_pos + jnp.arange(s)                   # [s]
-            k_pos = jnp.arange(ck.shape[1])                     # [max_len]
-            mask = k_pos[None, :] <= q_abs[:, None]             # [s, max_len]
-            if attn_window is not None:  # local layers trim the left edge
-                mask = mask & ((attn_window <= 0)
-                               | (k_pos[None, :] > q_abs[:, None] - attn_window))
-            bias = _alibi_bias(ck.shape[1]) if c.position == "alibi" else None
-            attn = dot_product_attention(q, ck, cv, causal=False,
-                                         mask=mask[None, None], bias=bias,
-                                         scale=c.attn_scale)
-        elif self._seq_size > 1:
-            if c.position == "alibi":
-                raise NotImplementedError(
-                    "ALiBi + sequence-parallel attention not supported yet")
-            # attn_window is None here whenever no window binds at this
-            # length (_encode elides them). Ulysses supports static
-            # (uniform) binding windows, scale overrides, and
-            # bidirectional encoders — the a2a yields full local
-            # sequences; traced per-layer windows and the (causal-only)
-            # ring path do not.
-            if attn_window is not None and not isinstance(attn_window, int):
-                raise NotImplementedError(
-                    "per-layer-varying attention windows + sequence-"
-                    "parallel attention not supported")
-            if (attn_window is not None or c.attn_scale is not None
-                    or not c.causal) and self._sp_impl != "ulysses":
-                raise NotImplementedError(
-                    "binding attention windows / scale overrides / "
-                    "bidirectional encoders require ulysses sequence "
-                    "parallelism (ring is causal-only)")
-            if not c.causal and attn_mask is not None:
-                raise NotImplementedError(
-                    "encoder padding masks not threaded through sequence-"
-                    "parallel attention yet — drop the seq axis or pack "
-                    "unpadded batches")
-            attn = self._sp_attention(q, kk, vv, window=attn_window,
-                                      causal=c.causal)
-        elif c.position == "alibi":
-            # flash kernel carries no additive bias — use the jnp path
-            attn = dot_product_attention(q, kk, vv, causal=True,
-                                         bias=_alibi_bias(s))
-        elif not c.causal and attn_mask is not None:
-            # encoder with padding: keys at padded positions are masked for
-            # every query ([b, 1, 1, s] broadcast)
-            key_mask = attn_mask.astype(bool)[:, None, None, :]
-            attn = dot_product_attention(q, kk, vv, causal=False, mask=key_mask,
-                                         scale=c.attn_scale)
-        elif attn_window is not None and isinstance(attn_window, int):
-            # uniform static window (Mistral/Mixtral): banded flash kernel
-            # on TPU (tiles below the band skipped), banded jnp otherwise
-            if c.use_flash:
-                attn = self._local_flash(q, kk, vv, causal=True,
-                                         scale=c.attn_scale,
-                                         window=attn_window)
-            else:
+            new_kv = None
+            if kv_cache is not None:
+                ck, cv, cache_pos = kv_cache
+                ck = jax.lax.dynamic_update_slice_in_dim(ck, kk, cache_pos, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(cv, vv, cache_pos, axis=1)
+                new_kv = (ck, cv)
+                # query i sits at absolute position cache_pos + i: it may attend
+                # every cache slot up to and including itself (this also masks
+                # the unwritten zero tail of the cache)
+                q_abs = cache_pos + jnp.arange(s)                   # [s]
+                k_pos = jnp.arange(ck.shape[1])                     # [max_len]
+                mask = k_pos[None, :] <= q_abs[:, None]             # [s, max_len]
+                if attn_window is not None:  # local layers trim the left edge
+                    mask = mask & ((attn_window <= 0)
+                                   | (k_pos[None, :] > q_abs[:, None] - attn_window))
+                bias = _alibi_bias(ck.shape[1]) if c.position == "alibi" else None
+                attn = dot_product_attention(q, ck, cv, causal=False,
+                                             mask=mask[None, None], bias=bias,
+                                             scale=c.attn_scale)
+            elif self._seq_size > 1:
+                if c.position == "alibi":
+                    raise NotImplementedError(
+                        "ALiBi + sequence-parallel attention not supported yet")
+                # attn_window is None here whenever no window binds at this
+                # length (_encode elides them). Ulysses supports static
+                # (uniform) binding windows, scale overrides, and
+                # bidirectional encoders — the a2a yields full local
+                # sequences; traced per-layer windows and the (causal-only)
+                # ring path do not.
+                if attn_window is not None and not isinstance(attn_window, int):
+                    raise NotImplementedError(
+                        "per-layer-varying attention windows + sequence-"
+                        "parallel attention not supported")
+                if (attn_window is not None or c.attn_scale is not None
+                        or not c.causal) and self._sp_impl != "ulysses":
+                    raise NotImplementedError(
+                        "binding attention windows / scale overrides / "
+                        "bidirectional encoders require ulysses sequence "
+                        "parallelism (ring is causal-only)")
+                if not c.causal and attn_mask is not None:
+                    raise NotImplementedError(
+                        "encoder padding masks not threaded through sequence-"
+                        "parallel attention yet — drop the seq axis or pack "
+                        "unpadded batches")
+                attn = self._sp_attention(q, kk, vv, window=attn_window,
+                                          causal=c.causal)
+            elif c.position == "alibi":
+                # flash kernel carries no additive bias — use the jnp path
                 attn = dot_product_attention(q, kk, vv, causal=True,
+                                             bias=_alibi_bias(s))
+            elif not c.causal and attn_mask is not None:
+                # encoder with padding: keys at padded positions are masked for
+                # every query ([b, 1, 1, s] broadcast)
+                key_mask = attn_mask.astype(bool)[:, None, None, :]
+                attn = dot_product_attention(q, kk, vv, causal=False, mask=key_mask,
+                                             scale=c.attn_scale)
+            elif attn_window is not None and isinstance(attn_window, int):
+                # uniform static window (Mistral/Mixtral): banded flash kernel
+                # on TPU (tiles below the band skipped), banded jnp otherwise
+                if c.use_flash:
+                    attn = self._local_flash(q, kk, vv, causal=True,
                                              scale=c.attn_scale,
                                              window=attn_window)
-        elif attn_window is not None:
-            # per-layer-varying (traced) windows — alternating global/local
-            # causal attention (GPT-Neo): numeric banded mask
-            q_pos = jnp.arange(s)[:, None]
-            k_pos = jnp.arange(s)[None, :]
-            m = (k_pos <= q_pos) & ((attn_window <= 0)
-                                    | (k_pos > q_pos - attn_window))
-            attn = dot_product_attention(q, kk, vv, causal=False,
-                                         mask=m[None, None], scale=c.attn_scale)
-        elif c.use_flash:
-            attn = self._local_flash(q, kk, vv, causal=c.causal,
-                                     scale=c.attn_scale)
-        else:
-            attn = dot_product_attention(q, kk, vv, causal=c.causal,
+                else:
+                    attn = dot_product_attention(q, kk, vv, causal=True,
+                                                 scale=c.attn_scale,
+                                                 window=attn_window)
+            elif attn_window is not None:
+                # per-layer-varying (traced) windows — alternating global/local
+                # causal attention (GPT-Neo): numeric banded mask
+                q_pos = jnp.arange(s)[:, None]
+                k_pos = jnp.arange(s)[None, :]
+                m = (k_pos <= q_pos) & ((attn_window <= 0)
+                                        | (k_pos > q_pos - attn_window))
+                attn = dot_product_attention(q, kk, vv, causal=False,
+                                             mask=m[None, None], scale=c.attn_scale)
+            elif c.use_flash:
+                attn = self._local_flash(q, kk, vv, causal=c.causal,
                                          scale=c.attn_scale)
+            else:
+                attn = dot_product_attention(q, kk, vv, causal=c.causal,
+                                             scale=c.attn_scale)
 
-        attn = attn.reshape(b, s, c.n_heads * hd) @ lp["wo"]
-        if c.attn_o_bias:
-            attn = attn + lp["bo"]
+            attn = attn.reshape(b, s, c.n_heads * hd) @ lp["wo"]
+            if c.attn_o_bias:
+                attn = attn + lp["bo"]
 
         if c.parallel_residual:
             # GPT-J / GPT-NeoX: both branches read the SAME input x
             # (GPT-J's single shared LN arrives as duplicated norm params)
-            h2 = self._norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
-            down, aux = self._mlp(h2, lp, rng, training)
+            with jax.named_scope("ffn"):
+                h2 = self._norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
+                down, aux = self._mlp(h2, lp, rng, training)
             return x + attn + down, new_kv, aux
 
         if not c.prenorm:  # post-LN: norm AFTER each residual add
             x = self._norm(x + attn, lp["attn_norm_w"], lp.get("attn_norm_b"))
-            down, aux = self._mlp(x, lp, rng, training)
-            return self._norm(x + down, lp["mlp_norm_w"], lp.get("mlp_norm_b")), new_kv, aux
+            with jax.named_scope("ffn"):
+                down, aux = self._mlp(x, lp, rng, training)
+                x = self._norm(x + down, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
+            return x, new_kv, aux
 
         x = x + attn
-        h = self._norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
-        down, aux = self._mlp(h, lp, rng, training)
-        return x + down, new_kv, aux
+        with jax.named_scope("ffn"):
+            h = self._norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
+            down, aux = self._mlp(h, lp, rng, training)
+            return x + down, new_kv, aux
 
     def _mlp(self, h, lp, rng=None, training=False):
         """Dense FFN. Subclasses (MoE) override; returns (out, aux_loss)."""
@@ -749,17 +760,17 @@ class Transformer:
         if self.config.type_vocab_size > 0 and "token_type_ids" in batch:
             fwd_kw["token_type_ids"] = batch["token_type_ids"]
         cs = self.config.loss_chunk_size
-        if cs > 0:
-            x, aux = self.apply(params, inputs, rng=rng, training=True,
-                                return_aux=True, return_hidden=True, **fwd_kw)
-            nll_sum, denom, z_sum = self._ce_chunked(params, x, targets, mask, cs)
-        else:
-            logits, aux = self.apply(params, inputs, rng=rng, training=True,
-                                     return_aux=True, **fwd_kw)
-            nll_sum, denom, z_sum = self._ce_terms(logits, targets, mask)
-        loss = nll_sum / jnp.maximum(denom, 1.0)
-        if self.config.z_loss > 0:
-            loss = loss + self.config.z_loss * z_sum / jnp.maximum(denom, 1.0)
+        # chunked: apply returns the hidden states and the scan runs the head
+        out, aux = self.apply(params, inputs, rng=rng, training=True,
+                              return_aux=True, return_hidden=cs > 0, **fwd_kw)
+        with jax.named_scope("head"):  # the output head with the loss
+            nll_sum, denom, z_sum = \
+                self._ce_chunked(params, out, targets, mask, cs) if cs > 0 \
+                else self._ce_terms(out, targets, mask)
+            loss = nll_sum / jnp.maximum(denom, 1.0)
+            if self.config.z_loss > 0:
+                loss = loss + self.config.z_loss * z_sum \
+                    / jnp.maximum(denom, 1.0)
         return loss + aux
 
     def _ce_chunked(self, params, x, targets, mask, chunk):
@@ -796,6 +807,7 @@ class Transformer:
 
     # ------------------------------------------------------------------
     # pipeline-parallel path (reference: runtime/pipe/engine.py train_batch)
+    @jax.named_scope("embed")
     def _embed(self, params, tokens, positions=None, token_type_ids=None):
         """Token (+ learned position) embedding: [b, s] -> [b, s, d] in the
         compute dtype.
@@ -833,6 +845,7 @@ class Transformer:
                            c.norm_eps)
         return x
 
+    @jax.named_scope("head")
     def _head(self, params, x):
         """Final norm + LM head: [..., s, d] -> fp32 logits [..., s, vocab].
 

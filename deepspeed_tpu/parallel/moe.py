@@ -198,51 +198,58 @@ class MoELayer:
         Eval / no-drop uses the sort-based grouped-GEMM path."""
         b, s, d = x.shape
         cfg = self.gate
+        # device scopes (metadata only): ``router`` and ``experts`` name the
+        # layer's two parts in a profiler trace (docs/observability.md)
         if not training or not cfg.drop_tokens:
-            logits = x.astype(jnp.float32) @ params["wg"].astype(jnp.float32)
-            probs = jax.nn.softmax(logits.reshape(b * s, -1), axis=-1)
-            topw, topi = jax.lax.top_k(probs, cfg.top_k)
-            topw = topw / jnp.maximum(jnp.sum(topw, axis=-1, keepdims=True),
-                                      1e-9)
-            out = no_drop_moe(x.reshape(b * s, d), topw, topi, params,
-                              self.activation)
-            # same load-balance diagnostic as the drop path
-            assign = jnp.mean(jax.nn.one_hot(topi[:, 0], cfg.n_experts), axis=0)
-            aux = cfg.n_experts * jnp.sum(jnp.mean(probs, axis=0) * assign)
+            with jax.named_scope("router"):
+                logits = x.astype(jnp.float32) @ params["wg"].astype(jnp.float32)
+                probs = jax.nn.softmax(logits.reshape(b * s, -1), axis=-1)
+                topw, topi = jax.lax.top_k(probs, cfg.top_k)
+                topw = topw / jnp.maximum(
+                    jnp.sum(topw, axis=-1, keepdims=True), 1e-9)
+                # same load-balance diagnostic as the drop path
+                assign = jnp.mean(jax.nn.one_hot(topi[:, 0], cfg.n_experts),
+                                  axis=0)
+                aux = cfg.n_experts * jnp.sum(jnp.mean(probs, axis=0) * assign)
+            with jax.named_scope("experts"):
+                out = no_drop_moe(x.reshape(b * s, d), topw, topi, params,
+                                  self.activation)
             return out.reshape(b, s, d), aux
-        cap = capacity(s, cfg, training)
-        if cfg.noisy_gate_policy == "Jitter" and training and rng is not None:
-            # multiplicative input jitter (reference multiplicative_jitter,
-            # sharded_moe.py): x * U(1-eps, 1+eps) for the router only
-            rng, jkey = jax.random.split(rng)
-            x_r = x * jax.random.uniform(jkey, x.shape, x.dtype, 0.99, 1.01)
-        else:
-            x_r = x
-        logits = x_r.astype(jnp.float32) @ params["wg"].astype(jnp.float32)  # [b, s, E]
+        with jax.named_scope("router"):
+            cap = capacity(s, cfg, training)
+            if cfg.noisy_gate_policy == "Jitter" and training and rng is not None:
+                # multiplicative input jitter (reference multiplicative_jitter,
+                # sharded_moe.py): x * U(1-eps, 1+eps) for the router only
+                rng, jkey = jax.random.split(rng)
+                x_r = x * jax.random.uniform(jkey, x.shape, x.dtype, 0.99, 1.01)
+            else:
+                x_r = x
+            logits = x_r.astype(jnp.float32) @ params["wg"].astype(jnp.float32)  # [b, s, E]
 
-        def per_group(lg, r):
-            return top_k_gating(lg, cfg, cap, r, training)
+            def per_group(lg, r):
+                return top_k_gating(lg, cfg, cap, r, training)
 
-        rngs = jax.random.split(rng, b) if rng is not None else None
-        combine, dispatch, aux = jax.vmap(per_group)(
-            logits, rngs) if rngs is not None else jax.vmap(lambda lg: per_group(lg, None))(logits)
-        aux = jnp.mean(aux)
+            rngs = jax.random.split(rng, b) if rng is not None else None
+            combine, dispatch, aux = jax.vmap(per_group)(
+                logits, rngs) if rngs is not None else jax.vmap(lambda lg: per_group(lg, None))(logits)
+            aux = jnp.mean(aux)
 
-        # dispatch: [b, s, E, C] x [b, s, d] -> [E, b, C, d]
-        disp = dispatch.astype(x.dtype)
-        expert_in = jnp.einsum("bsec,bsd->ebcd", disp, x)
-        if self.activation == "silu_glu":
-            h = jax.nn.silu(jnp.einsum("ebcd,edf->ebcf", expert_in, params["w_gate"])) * \
-                jnp.einsum("ebcd,edf->ebcf", expert_in, params["w_up"])
-        else:
-            h = jnp.einsum("ebcd,edf->ebcf", expert_in, params["w_up"])
-            if "b_up" in params:
-                h = h + params["b_up"][:, None, None, :].astype(h.dtype)
-            h = jax.nn.gelu(h)
-        expert_out = jnp.einsum("ebcf,efd->ebcd", h, params["w_down"])
-        if "b_down" in params:
-            expert_out = expert_out + params["b_down"][:, None, None, :].astype(expert_out.dtype)
-        out = jnp.einsum("bsec,ebcd->bsd", combine.astype(x.dtype), expert_out)
+        with jax.named_scope("experts"):
+            # dispatch: [b, s, E, C] x [b, s, d] -> [E, b, C, d]
+            disp = dispatch.astype(x.dtype)
+            expert_in = jnp.einsum("bsec,bsd->ebcd", disp, x)
+            if self.activation == "silu_glu":
+                h = jax.nn.silu(jnp.einsum("ebcd,edf->ebcf", expert_in, params["w_gate"])) * \
+                    jnp.einsum("ebcd,edf->ebcf", expert_in, params["w_up"])
+            else:
+                h = jnp.einsum("ebcd,edf->ebcf", expert_in, params["w_up"])
+                if "b_up" in params:
+                    h = h + params["b_up"][:, None, None, :].astype(h.dtype)
+                h = jax.nn.gelu(h)
+            expert_out = jnp.einsum("ebcf,efd->ebcd", h, params["w_down"])
+            if "b_down" in params:
+                expert_out = expert_out + params["b_down"][:, None, None, :].astype(expert_out.dtype)
+            out = jnp.einsum("bsec,ebcd->bsd", combine.astype(x.dtype), expert_out)
         return out, aux
 
     def partition_specs(self, n_layers: Optional[int] = None,

@@ -34,9 +34,9 @@ the report carries the physical volume behind every measured duration.
 Timelines land in the tracer (telemetry/tracing.py) on two tracks —
 the real serial drive as it executed, and the accounted overlapped
 schedule at its computed offsets — exportable as Chrome-trace JSON next
-to the serving request trees. When a ``jax.profiler`` capture is
-active, the measured phases also appear on the profiler host track
-(``profiling/trace.py`` bridge).
+to the serving request trees. Under any ``jax.profiler`` session the
+measured phases also appear on the profiler host track (scoped tracer
+spans go through ``profiling.trace.annotate``).
 """
 
 from __future__ import annotations

@@ -1,21 +1,31 @@
-"""Profiler traces + range annotations.
+"""Profiler traces + range annotations: the one path from the program to
+the profiler's trace.
 
 Parity surface: the reference's NVTX instrumentation
 (``deepspeed/utils/nvtx.py`` ``instrument_w_nvtx``, used throughout
 ZeRO-3) and ``accelerator.range_push/range_pop``. TPU-native form: the
-XLA profiler — ``trace()`` captures a TensorBoard-loadable trace
-(HLO timelines, per-op device time, memory viewer), ``annotate``/
-``instrument`` put named ranges on the host track exactly where the
-reference put NVTX ranges, and ``step`` marks step boundaries so the
-profiler's step view groups ops per training step.
+XLA profiler. :func:`annotate` is the ONLY way the package writes a host
+span: a ``jax.profiler.TraceAnnotation`` (a TraceMe) with plain-int
+attributes, returned unconditionally. A TraceMe is inert unless a profiler
+session is active, so nothing decides it but the session itself: no flag,
+no config field, no environment variable; the path reads no clock, takes
+no lock and never touches the device. Device-side names come from
+``jax.named_scope`` in the jitted code (metadata only).
 
-The request tracer (``telemetry/tracing.py``) bridges onto the same
-host track: while :func:`trace` is active (:func:`trace_active`), every
-scoped tracer span also opens a profiler annotation with the same name,
-so tracer timelines line up with the device timeline in
-TensorBoard/Perfetto. This module must stay import-safe with profiling
-off — jax is imported lazily and every entry point degrades to a no-op
-when it is unavailable.
+How an operator gets the spans and scopes (catalogue:
+docs/observability.md "Program spans and device scopes"): any profiler
+session does -- ``with profiling.trace.trace(logdir):`` around the work,
+``jax.profiler.start_trace``, or ``jax.profiler.start_server(port)`` and
+a capture from TensorBoard. Then open the ``.xplane.pb`` under
+``<logdir>/plugins/profile/`` in TensorBoard's profile plugin, or reduce
+it with ``python benchmarks/trace_reduce.py <file>``. Host spans sit on
+the profiler's clock beside the device's operations; their attributes are
+the event's stats. Scoped spans of the request tracer
+(``telemetry/tracing.py`` ``Tracer.span``) go through :func:`annotate`
+too while that tracer is enabled, under any session.
+
+This module stays import-safe without jax: the handle is resolved once,
+and every entry point degrades to a no-op when jax cannot be imported.
 """
 
 from __future__ import annotations
@@ -25,68 +35,66 @@ import functools
 from typing import Iterator, Optional
 
 
-_warned_no_jax = False
+_JAX = None          # the jax module, False once an import has failed
 
 
 def _jax():
-    """Lazy jax handle; None when jax is not installed (profiling off /
-    stripped environments — annotations degrade to no-ops, with one
+    """The jax handle, resolved once; None when jax is not installed
+    (stripped environments: annotations degrade to no-ops, with one
     warning so a requested capture never fails silently). A jax that is
-    installed but BROKEN still raises loudly — only a clean ImportError
+    installed but BROKEN still raises loudly: only a clean ImportError
     is the degrade path."""
-    global _warned_no_jax
-    try:
-        import jax
+    global _JAX
+    if _JAX is None:
+        try:
+            import jax
 
-        return jax
-    except ImportError:
-        if not _warned_no_jax:
-            _warned_no_jax = True
+            _JAX = jax
+        except ImportError:
+            _JAX = False
             import logging
 
             logging.getLogger(__name__).warning(
                 "jax unavailable: profiler traces/annotations are no-ops")
-        return None
-
-
-# nesting depth of active profiler captures (trace() is re-entrant in
-# principle; the tracer bridge only needs "is anything capturing")
-_ACTIVE = 0
-
-
-def trace_active() -> bool:
-    """True while a :func:`trace` capture is running — the signal the
-    request tracer uses to bridge spans onto the profiler host track."""
-    return _ACTIVE > 0
+    return _JAX or None
 
 
 @contextlib.contextmanager
 def trace(logdir: str, create_perfetto_link: bool = False) -> Iterator[None]:
     """Capture an XLA profiler trace into ``logdir`` (view with
     TensorBoard's profile plugin)."""
-    global _ACTIVE
     jax = _jax()
     if jax is None:
         yield
         return
     jax.profiler.start_trace(logdir,
                              create_perfetto_link=create_perfetto_link)
-    _ACTIVE += 1
     try:
         yield
     finally:
-        _ACTIVE -= 1
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
+def annotate(name: str, **attrs: int):
     """Named range on the profiler's host track (the range_push/range_pop
-    analog). Usable as a context manager; a no-op context when jax is
-    unavailable."""
+    analog), with plain-int attributes that become the event's stats.
+    A context manager; attributes known only at the end are added with
+    ``set_metadata(**attrs)`` on the object ``with`` yields. Inert without
+    a profiler session; a no-op context when jax is unavailable."""
     jax = _jax()
     if jax is None:
-        return contextlib.nullcontext()
-    return jax.profiler.TraceAnnotation(name)
+        return _NoAnnotation()
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+class _NoAnnotation(contextlib.nullcontext):
+    """``annotate``'s stand-in without jax: same surface, does nothing."""
+
+    def __enter__(self):
+        return self
+
+    def set_metadata(self, **attrs: int) -> None:
+        pass
 
 
 def step(step_num: int):

@@ -57,6 +57,13 @@ def place_compile_cache(config_dir: Optional[str] = None,
             jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # the operations' metadata (jax.named_scope: embed, attn, ffn, ...)
+        # is part of the key, a fixed setting: by default JAX leaves it
+        # out, and a cache that holds the same program from before a scope
+        # was added or renamed hands back an executable whose operations
+        # carry the old names into every profiler trace (PERF.md, PR 25)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         # JAX latches the cache as initialized-disabled at the FIRST compile
         # of the process; any compile before this call (sharded param init,
         # another engine) would make the updates above a silent no-op.

@@ -42,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..config import Config
 from ..parallel.mesh import Topology
 from ..parallel.zero import ZeroShardingRules
+from ..profiling.trace import annotate
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from . import loss_scaler as ls
@@ -1040,6 +1041,7 @@ class TrainEngine:
         t.start()
         return t
 
+    @jax.named_scope("optimizer")   # names clip + update in a device trace
     def _update(self, params, opt_state, scaler_state, grads, scale, *,
                 clip, fp16, dynamic, optimizer, nan_skip=False):
         """Unscale, clip, step — shared by fused and compat paths.
@@ -1086,88 +1088,97 @@ class TrainEngine:
         """One full optimizer step over a global batch of
         ``train_batch_size`` samples (parity with PipelineEngine.train_batch
         semantics for the non-pipelined engine)."""
-        t_entry = time.perf_counter()
-        for hook in self._step_hooks:
-            hook(self, self.global_steps)
-        fn = self._ensure_train_step_fn()
-        self._note_batch_sig(batch)
-        self.tput.start()
-        if self._offload_device == "nvme":
-            # disk -> host staging via the aio engine (reference
-            # pipelined_optimizer_swapper), then host -> device
-            self.opt_state = self._nvme_swapper.swap_in(self.opt_state_shardings)  # dslint: disable=races -- warmup-join synchronization: warmup only READS engine state, and train_batch joined it (via _ensure_train_step_fn above) before this write; offload engines additionally skip AOT warmup entirely
-        elif self._offload_device == "cpu":
-            # pinned host -> device upload (the reference offload engine's
-            # per-step copy-in)
-            self.opt_state = jax.device_put(self.opt_state, self.opt_state_shardings)
-        self._params_to_device()
-        if self.telemetry.wants_step_records and self._step_flops is None:
-            # MFU numerator from HLO cost analysis of the lowered step,
-            # measured BEFORE the donated call while the argument buffers
-            # are alive (no XLA compile — see _measure_step_flops)
-            self._measure_step_flops(batch)
-        out = None
-        if self._train_step_aot is not None:
-            # warmup's AOT executable: same program, dispatched without the
-            # jit cache lookup. Any argument mismatch (new batch signature,
-            # different sharding) falls back to the lazy jit path for good.
-            try:
-                out = self._train_step_aot(
-                    self.params, self.opt_state, self.scaler_state, self.rng,
-                    batch)
-            except Exception as e:  # noqa: BLE001 — aval check precedes execution
-                logger.warning(f"AOT train step no longer matches the inputs "
-                               f"({e}); using the jit path")
-                self._train_step_aot = None
-        if out is None:
-            out = fn(self.params, self.opt_state, self.scaler_state, self.rng,
-                     batch)
-        self.params, self.opt_state, self.scaler_state, self.rng, metrics = out  # dslint: disable=races -- warmup-join synchronization: the warmup thread's reads of params/opt_state/scaler/rng happen strictly before _ensure_train_step_fn's join at the top of train_batch; after the join, main is the only toucher
-        self._params_to_offload()
-        if self._offload_device == "nvme":
-            self._nvme_swapper.swap_out(self.opt_state)
-            self.opt_state = None
-        elif self._offload_device == "cpu":
-            self.opt_state = jax.device_put(self.opt_state, self._opt_host_shardings)
-        # host ledger: everything from entry to here ran on the host while
-        # the device was free to execute (dispatch is async) — the per-step
-        # dispatch tax the async pipeline + train_steps(k) amortize
-        t_dispatched = time.perf_counter()
-        self.global_steps += 1
-        self.micro_steps += self.gradient_accumulation_steps
-        # sync_obj blocks the host until the step completes — honest per-step
-        # timing, but it forbids dispatch-ahead pipelining. Only pay for it
-        # when the user asked for timing (wall_clock_breakdown), when a
-        # telemetry sink will fetch the metrics anyway (so the fetch lands
-        # inside the timed region, not the untimed gap), or at the report
-        # boundary. Telemetry off + monitor off => same sync points as seed.
-        report_boundary = self.tput.will_report_next()
-        want_stats = self.telemetry.wants_step_records
-        sync = metrics["loss"] if (
-            self.config.wall_clock_breakdown or want_stats
-            or report_boundary) else None
-        step_dt = self.tput.stop(sync_obj=sync, report_speed=True)
-        host = None
-        if want_stats:
-            host = {"host_ms": (t_dispatched - t_entry) * 1e3,
-                    "data_wait_ms": self._consume_data_wait_ms(),
-                    "dispatch_gap_ms": ((t_entry - self._last_call_end_t) * 1e3
-                                        if self._last_call_end_t is not None
-                                        else None)}
-        self._emit_step(metrics, wall_time_s=step_dt, log_step=report_boundary,
-                        host=host)
-        self._last_call_end_t = time.perf_counter()
-        self._note_skipped(metrics["skipped"])
-        self._last_loss = metrics["loss"]
-        if self._ft_active or self.preemption_guard is not None:
-            self._after_step(metrics)
-        if self.config.memory_breakdown and report_boundary:
-            # reference see_memory_usage at engine phase boundaries
-            # (runtime/utils.py); boundary-only so it never adds a host
-            # sync to the steady-state step
-            from ..utils.memory import see_memory_usage
+        with annotate("train.step", step=self.global_steps, k=1):
+            return self._train_batch(batch)
 
-            see_memory_usage(f"step {self.global_steps}")
+    def _train_batch(self, batch: Any) -> Dict[str, Any]:
+        """:meth:`train_batch` under its ``train.step`` span; the phases
+        are spans of their own (docs/observability.md)."""
+        with annotate("train.pre"):
+            t_entry = time.perf_counter()
+            for hook in self._step_hooks:
+                hook(self, self.global_steps)
+            fn = self._ensure_train_step_fn()
+            self._note_batch_sig(batch)
+            self.tput.start()
+            if self._offload_device == "nvme":
+                # disk -> host staging via the aio engine (reference
+                # pipelined_optimizer_swapper), then host -> device
+                self.opt_state = self._nvme_swapper.swap_in(self.opt_state_shardings)  # dslint: disable=races -- warmup-join synchronization: warmup only READS engine state, and train_batch joined it (via _ensure_train_step_fn above) before this write; offload engines additionally skip AOT warmup entirely
+            elif self._offload_device == "cpu":
+                # pinned host -> device upload (the reference offload engine's
+                # per-step copy-in)
+                self.opt_state = jax.device_put(self.opt_state, self.opt_state_shardings)
+            self._params_to_device()
+            if self.telemetry.wants_step_records and self._step_flops is None:
+                # MFU numerator from HLO cost analysis of the lowered step,
+                # measured BEFORE the donated call while the argument buffers
+                # are alive (no XLA compile — see _measure_step_flops)
+                self._measure_step_flops(batch)
+        with annotate("train.dispatch"):
+            out = None
+            if self._train_step_aot is not None:
+                # warmup's AOT executable: same program, dispatched without the
+                # jit cache lookup. Any argument mismatch (new batch signature,
+                # different sharding) falls back to the lazy jit path for good.
+                try:
+                    out = self._train_step_aot(
+                        self.params, self.opt_state, self.scaler_state, self.rng,
+                        batch)
+                except Exception as e:  # noqa: BLE001 — aval check precedes execution
+                    logger.warning(f"AOT train step no longer matches the inputs "
+                                   f"({e}); using the jit path")
+                    self._train_step_aot = None
+            if out is None:
+                out = fn(self.params, self.opt_state, self.scaler_state, self.rng,
+                         batch)
+        with annotate("train.post"):
+            self.params, self.opt_state, self.scaler_state, self.rng, metrics = out  # dslint: disable=races -- warmup-join synchronization: the warmup thread's reads of params/opt_state/scaler/rng happen strictly before _ensure_train_step_fn's join at the top of train_batch; after the join, main is the only toucher
+            self._params_to_offload()
+            if self._offload_device == "nvme":
+                self._nvme_swapper.swap_out(self.opt_state)
+                self.opt_state = None
+            elif self._offload_device == "cpu":
+                self.opt_state = jax.device_put(self.opt_state, self._opt_host_shardings)
+            # host ledger: everything from entry to here ran on the host while
+            # the device was free to execute (dispatch is async) — the per-step
+            # dispatch tax the async pipeline + train_steps(k) amortize
+            t_dispatched = time.perf_counter()
+            self.global_steps += 1
+            self.micro_steps += self.gradient_accumulation_steps
+            # sync_obj blocks the host until the step completes — honest per-step
+            # timing, but it forbids dispatch-ahead pipelining. Only pay for it
+            # when the user asked for timing (wall_clock_breakdown), when a
+            # telemetry sink will fetch the metrics anyway (so the fetch lands
+            # inside the timed region, not the untimed gap), or at the report
+            # boundary. Telemetry off + monitor off => same sync points as seed.
+            report_boundary = self.tput.will_report_next()
+            want_stats = self.telemetry.wants_step_records
+            sync = metrics["loss"] if (
+                self.config.wall_clock_breakdown or want_stats
+                or report_boundary) else None
+            step_dt = self.tput.stop(sync_obj=sync, report_speed=True)
+            host = None
+            if want_stats:
+                host = {"host_ms": (t_dispatched - t_entry) * 1e3,
+                        "data_wait_ms": self._consume_data_wait_ms(),
+                        "dispatch_gap_ms": ((t_entry - self._last_call_end_t) * 1e3
+                                            if self._last_call_end_t is not None
+                                            else None)}
+            self._emit_step(metrics, wall_time_s=step_dt, log_step=report_boundary,
+                            host=host)
+            self._last_call_end_t = time.perf_counter()
+            self._note_skipped(metrics["skipped"])
+            self._last_loss = metrics["loss"]
+            if self._ft_active or self.preemption_guard is not None:
+                self._after_step(metrics)
+            if self.config.memory_breakdown and report_boundary:
+                # reference see_memory_usage at engine phase boundaries
+                # (runtime/utils.py); boundary-only so it never adds a host
+                # sync to the steady-state step
+                from ..utils.memory import see_memory_usage
+
+                see_memory_usage(f"step {self.global_steps}")
         return metrics
 
     # ==================================================================
@@ -1250,57 +1261,67 @@ class TrainEngine:
                 out["losses"] = jnp.stack([jnp.asarray(metrics["loss"])])
                 return out
 
-        t_entry = time.perf_counter()
-        gap_ms = ((t_entry - self._last_call_end_t) * 1e3
-                  if self._last_call_end_t is not None else None)
-        self._ensure_train_step_fn()  # also builds _train_step_raw
-        fn = self._train_steps_fns.get(k)
-        if fn is None:
-            fn = self._build_train_steps(k)
-            self._train_steps_fns[k] = fn
-        # the k batches enter the program as a tuple and are stacked INTO
-        # the scan's leading dim inside the compiled program — stacking on
-        # the host side would pay one dispatch per leaf per block, exactly
-        # the tax this driver exists to amortize
-        batch_tuple = tuple(batches)
-        self._note_batch_sig(batch_tuple, program=f"train_steps_{k}")
-        want_stats = self.telemetry.wants_step_records
-        if want_stats and self._step_flops is None:
-            self._measure_step_flops(batches[0])
-        prev_steps = self.global_steps
-        self.tput.start()
-        self.params, self.opt_state, self.scaler_state, self.rng, ms = fn(
-            self.params, self.opt_state, self.scaler_state, self.rng,
-            batch_tuple)
-        t_dispatched = time.perf_counter()
-        self.global_steps += k
-        self.micro_steps += k * self.gradient_accumulation_steps
-        metrics = {"loss": ms["loss"][-1], "grad_norm": ms["grad_norm"][-1],
-                   "loss_scale": ms["loss_scale"][-1],
-                   "skipped": ms["skipped"][-1]}
-        sync = metrics["loss"] if (self.config.wall_clock_breakdown
-                                   or want_stats) else None
-        block_dt = self.tput.stop(sync_obj=sync, report_speed=False)
-        # keep the throughput aggregates honest: stop() booked one step of
-        # batch_size; this block ran k of them
-        self.tput.step_count = self.global_steps
-        self.tput.total_samples += self.train_batch_size * (k - 1)
-        host = None
-        if want_stats:
-            host = {"host_ms": (t_dispatched - t_entry) * 1e3,
-                    "data_wait_ms": self._consume_data_wait_ms(),
-                    "dispatch_gap_ms": gap_ms}
-        self._emit_step(metrics, wall_time_s=block_dt, log_step=False,
-                        host=host, n_steps=k)
-        self._last_call_end_t = time.perf_counter()
-        self._note_skipped(ms["skipped"].sum())
-        self._last_loss = metrics["loss"]
-        # periodic auto-save: a block can cross (or land on) a save
-        # boundary; preemption/divergence never reach here (ineligible)
-        iv = self.config.checkpoint.save_interval
-        if (self._ckpt_save_dir and iv > 0
-                and self.global_steps // iv != prev_steps // iv):
-            self.save_checkpoint(self._ckpt_save_dir)
+        with annotate("train.step", step=self.global_steps, k=k):
+            return self._train_steps_fused(batches, k)
+
+    def _train_steps_fused(self, batches: List[Any], k: int
+                           ) -> Dict[str, Any]:
+        """The fused path of :meth:`train_steps` under its one
+        ``train.step`` span, in :meth:`_train_batch`'s phases."""
+        with annotate("train.pre"):
+            t_entry = time.perf_counter()
+            gap_ms = ((t_entry - self._last_call_end_t) * 1e3
+                      if self._last_call_end_t is not None else None)
+            self._ensure_train_step_fn()  # also builds _train_step_raw
+            fn = self._train_steps_fns.get(k)
+            if fn is None:
+                fn = self._build_train_steps(k)
+                self._train_steps_fns[k] = fn
+            # the k batches enter the program as a tuple and are stacked INTO
+            # the scan's leading dim inside the compiled program — stacking on
+            # the host side would pay one dispatch per leaf per block, exactly
+            # the tax this driver exists to amortize
+            batch_tuple = tuple(batches)
+            self._note_batch_sig(batch_tuple, program=f"train_steps_{k}")
+            want_stats = self.telemetry.wants_step_records
+            if want_stats and self._step_flops is None:
+                self._measure_step_flops(batches[0])
+            prev_steps = self.global_steps
+            self.tput.start()
+        with annotate("train.dispatch"):
+            self.params, self.opt_state, self.scaler_state, self.rng, ms = fn(
+                self.params, self.opt_state, self.scaler_state, self.rng,
+                batch_tuple)
+        with annotate("train.post"):
+            t_dispatched = time.perf_counter()
+            self.global_steps += k
+            self.micro_steps += k * self.gradient_accumulation_steps
+            metrics = {"loss": ms["loss"][-1], "grad_norm": ms["grad_norm"][-1],
+                       "loss_scale": ms["loss_scale"][-1],
+                       "skipped": ms["skipped"][-1]}
+            sync = metrics["loss"] if (self.config.wall_clock_breakdown
+                                       or want_stats) else None
+            block_dt = self.tput.stop(sync_obj=sync, report_speed=False)
+            # keep the throughput aggregates honest: stop() booked one step of
+            # batch_size; this block ran k of them
+            self.tput.step_count = self.global_steps
+            self.tput.total_samples += self.train_batch_size * (k - 1)
+            host = None
+            if want_stats:
+                host = {"host_ms": (t_dispatched - t_entry) * 1e3,
+                        "data_wait_ms": self._consume_data_wait_ms(),
+                        "dispatch_gap_ms": gap_ms}
+            self._emit_step(metrics, wall_time_s=block_dt, log_step=False,
+                            host=host, n_steps=k)
+            self._last_call_end_t = time.perf_counter()
+            self._note_skipped(ms["skipped"].sum())
+            self._last_loss = metrics["loss"]
+            # periodic auto-save: a block can cross (or land on) a save
+            # boundary; preemption/divergence never reach here (ineligible)
+            iv = self.config.checkpoint.save_interval
+            if (self._ckpt_save_dir and iv > 0
+                    and self.global_steps // iv != prev_steps // iv):
+                self.save_checkpoint(self._ckpt_save_dir)
         out = dict(metrics)
         out["losses"] = ms["loss"]
         return out

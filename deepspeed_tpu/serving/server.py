@@ -47,6 +47,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..inference.ragged import PoolExhausted
+from ..profiling.trace import annotate
 from ..resilience.clock import Clock, get_clock
 from ..resilience.locksan import named_rlock
 from ..telemetry.tracing import (begin_request_segment, end_request_segment,
@@ -252,6 +253,10 @@ class ServingEngine:
         self._queue: List[Request] = []
         self._live: Dict[int, Request] = {}
         self._requests: Dict[int, Request] = {}   # uid -> non-terminal req
+        # running totals (lock held); the serve.admit span reports a
+        # tick's share of each
+        self._n_admitted = 0
+        self._n_preempted = 0
         self._accepting = True
         self._span_backlog: List[Request] = []   # retired, span not yet emitted
         self._adoptions: List[tuple] = []        # (req, KVExport) to import
@@ -949,7 +954,8 @@ class ServingEngine:
             finally:
                 self._in_tick = False
             if not did_work:
-                self._clock.wait_event(self._stop_evt, poll)
+                with annotate("serve.wait"):
+                    self._clock.wait_event(self._stop_evt, poll)
 
     def _watch(self) -> None:
         timeout = self.config.stuck_tick_timeout_s
@@ -1094,10 +1100,32 @@ class ServingEngine:
             return True
         self._import_adoptions()
         self._service_kv_tier()
+        # program spans (docs/observability.md): serve.tick holds a tick
+        # that found requests queued or live, and its phases nest inside
+        # it; an idle poll writes none. The peek is lock-free, as nothing
+        # on the span path may take a lock: a stale one leaves the admission
+        # of one tick without its span
+        queued, live = len(self._queue), len(self._live)  # dslint: disable=races -- span attributes only: len() of a list/dict is atomic, and a stale value mislabels one profiler span, never serving state
+        if not (queued or live):
+            return self._tick_run(*self._tick_feed())
+        with annotate("serve.tick", tick=self._tick_count + 1,
+                      queued=queued, live=live):
+            with annotate("serve.admit") as span:
+                admitted, preempted = self._n_admitted, self._n_preempted  # dslint: disable=races -- driver-thread reads of totals only this thread writes (under the lock, in _admit/_preempt)
+                feed = self._tick_feed()
+                span.set_metadata(admitted=self._n_admitted - admitted,
+                                  preempted=self._n_preempted - preempted)
+            return self._tick_run(*feed)
+
+    def _tick_feed(self):
+        """Cancellations, admission and this tick's feed (the lock held)."""
         with self._lock:
             self._process_cancellations()
             capacity = self._admit()
-            uids, toks, drafts = self._build_feed(capacity)
+            return self._build_feed(capacity)
+
+    def _tick_run(self, uids, toks, drafts) -> bool:
+        """The tick from its feed to retirement, each phase a span."""
         if not uids:
             self._flush_spans()
             self._update_gauges()
@@ -1119,30 +1147,33 @@ class ServingEngine:
             self._on_tick_fault(uids, e)
             self._flush_spans()
             return True
-        accepted = self._verify_drafts(verified)
-        with self._lock:
-            handoffs, emissions, finished = self._dispatch(uids, logits,
-                                                           accepted)
-        # user callbacks run OUTSIDE the serving lock (dslint
-        # lock-discipline): caller code under our lock could re-enter
-        # submit()/cancel() or stall every client of this replica.
-        # Ordering contract for stream(): tokens are delivered BEFORE
-        # the request turns terminal below, so the post-sentinel drain
-        # in stream_tokens() still sees every token.
-        for req, tok in emissions:
-            try:
-                req.on_token(tok)
-            except Exception:  # dslint: disable=exception-discipline -- user-callback isolation: a caller bug cancels only its own stream, never the tick
-                logger.exception(
-                    f"ServingEngine: on_token callback failed "
-                    f"(request {req.uid}); cancelling its stream")
-                req._cancel_requested = True
-        with self._lock:
-            self._finish(finished)
-        self._export_handoffs(handoffs)
-        self._flush_handoffs()
-        self._flush_spans()
-        self._update_gauges()
+        with annotate("serve.emit") as span:
+            accepted = self._verify_drafts(verified)
+            with self._lock:
+                handoffs, emissions, finished = self._dispatch(uids, logits,
+                                                               accepted)
+            span.set_metadata(tokens=len(emissions))
+            # user callbacks run OUTSIDE the serving lock (dslint
+            # lock-discipline): caller code under our lock could re-enter
+            # submit()/cancel() or stall every client of this replica.
+            # Ordering contract for stream(): tokens are delivered BEFORE
+            # the request turns terminal below, so the post-sentinel drain
+            # in stream_tokens() still sees every token.
+            for req, tok in emissions:
+                try:
+                    req.on_token(tok)
+                except Exception:  # dslint: disable=exception-discipline -- user-callback isolation: a caller bug cancels only its own stream, never the tick
+                    logger.exception(
+                        f"ServingEngine: on_token callback failed "
+                        f"(request {req.uid}); cancelling its stream")
+                    req._cancel_requested = True
+            with self._lock:
+                self._finish(finished)
+        with annotate("serve.retire"):
+            self._export_handoffs(handoffs)
+            self._flush_handoffs()
+            self._flush_spans()
+            self._update_gauges()
         return True
 
     # -- tick phases (driver thread; engine work OUTSIDE the lock) -------
@@ -1382,6 +1413,7 @@ class ServingEngine:
                                   policy=self.policy.name,
                                   resume_tokens=len(req.tokens))
             self._count("admitted")
+            self._n_admitted += 1
         return capacity
 
     def _preempt(self, victim: Request) -> None:
@@ -1394,6 +1426,7 @@ class ServingEngine:
                       tokens_in=len(victim.tokens))
         self._enqueue_locked(victim, requeue=True)
         self._count("preempted")
+        self._n_preempted += 1
         logger.info(f"ServingEngine: preempted request {victim.uid} "
                     f"(priority {victim.priority}, "
                     f"{len(victim.tokens)} tokens in)")
@@ -1467,40 +1500,44 @@ class ServingEngine:
         (``put_spec``); a PoolExhausted there strips every draft token
         before raising, so the retry degrades to a PLAIN put of the
         already-admitted feed — speculation is never worth an eviction."""
-        uids, toks = list(uids), list(toks)
-        use_spec = drafts is not None and any(drafts)
-        drafts = list(drafts) if use_spec else None
-        attempts = 0
-        while True:
-            try:
-                if use_spec:
-                    out, verified = self._engine.put_spec(uids, toks, drafts)
+        with annotate("serve.put") as span:
+            uids, toks = list(uids), list(toks)
+            use_spec = drafts is not None and any(drafts)
+            drafts = list(drafts) if use_spec else None
+            attempts = 0
+            while True:
+                try:
+                    if use_spec:
+                        out, verified = self._engine.put_spec(uids, toks,
+                                                              drafts)
+                    else:
+                        out, verified = self._engine.put(uids, toks), {}
+                    span.set_metadata(retries=attempts)
                     return uids, out, verified
-                return uids, self._engine.put(uids, toks), {}
-            except PoolExhausted:
-                # the typed catch matters: a generic device RuntimeError
-                # (e.g. XLA 'Resource exhausted' OOM) must take the
-                # tick-fault path once, not preempt healthy decodes and
-                # re-run the failing program live-count times
-                use_spec = False       # drafts were stripped on the raise
-                with self._lock:
-                    # the attempt bound reads _live under the lock: an
-                    # unlocked len() raced concurrent submit/cancel
-                    # mutations (dsrace finding, PR 15)
-                    if attempts >= len(self._live):
-                        raise
-                    attempts += 1
-                    victim = self._pool_pressure_victim(set(uids))
-                    if victim is None:
-                        raise
-                    self._preempt(victim)
-                    if victim.uid in uids:
-                        i = uids.index(victim.uid)
-                        uids.pop(i)
-                        toks.pop(i)
-                    if not uids:
-                        raise
-                toks = [[] for _ in uids]   # already admitted: continue only
+                except PoolExhausted:
+                    # the typed catch matters: a generic device RuntimeError
+                    # (e.g. XLA 'Resource exhausted' OOM) must take the
+                    # tick-fault path once, not preempt healthy decodes and
+                    # re-run the failing program live-count times
+                    use_spec = False   # drafts were stripped on the raise
+                    with self._lock:
+                        # the attempt bound reads _live under the lock: an
+                        # unlocked len() raced concurrent submit/cancel
+                        # mutations (dsrace finding, PR 15)
+                        if attempts >= len(self._live):
+                            raise
+                        attempts += 1
+                        victim = self._pool_pressure_victim(set(uids))
+                        if victim is None:
+                            raise
+                        self._preempt(victim)
+                        if victim.uid in uids:
+                            i = uids.index(victim.uid)
+                            uids.pop(i)
+                            toks.pop(i)
+                        if not uids:
+                            raise
+                    toks = [[] for _ in uids]  # already admitted: continue only
 
     def _pool_pressure_victim(self, feed_uids) -> Optional[Request]:
         """Mid-tick eviction pick when the pool runs dry despite admission

@@ -69,7 +69,7 @@ class Span:
     """One node of a trace tree. Mutated only through its Tracer."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "track",
-                 "t_start", "t_end", "attrs", "events", "_annotation")
+                 "t_start", "t_end", "attrs", "events")
 
     def __init__(self, trace_id: str, span_id: str,
                  parent_id: Optional[str], name: str,
@@ -84,9 +84,6 @@ class Span:
         self.t_end: Optional[float] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.events: List[Tuple[float, str, Dict[str, Any]]] = []
-        # open jax.profiler.TraceAnnotation when the XLA bridge wrapped
-        # this span (scoped spans only — annotations are thread-bound)
-        self._annotation = None
 
     @property
     def is_noop(self) -> bool:
@@ -218,24 +215,22 @@ class Tracer:
 
     * :meth:`span` — a context manager for HOST-scoped work (one
       thread, begin and end in one frame). Nested ``span()`` calls on
-      the same thread parent automatically. When a ``jax.profiler``
-      trace is active (``profiling/trace.py``), the same name is
-      emitted as a profiler host-track annotation so tracer spans line
-      up with TensorBoard/Perfetto device timelines.
+      the same thread parent automatically. The same name is also
+      written through ``profiling.trace.annotate``, so under any
+      profiler session tracer spans line up with the device timeline
+      in TensorBoard/Perfetto.
     * :meth:`begin_span` / :meth:`finish_span` — explicit segments for
       state machines whose phases start and end in different frames
       (or threads, or replicas): the serving request path. Explicit
       segments never touch the thread-local stack and are never
-      bridged to the profiler (annotations are thread-bound).
+      written to the profiler (annotations are thread-bound).
     """
 
     def __init__(self, enabled: bool = False, ring_size: int = 4096,
                  flight_capacity: int = 512,
-                 flight_dump_dir: Optional[str] = None,
-                 xla_bridge: bool = True):
+                 flight_dump_dir: Optional[str] = None):
         self.enabled = bool(enabled)
         self.ring_size = max(1, int(ring_size))
-        self.xla_bridge = bool(xla_bridge)
         self.flight = FlightRecorder(flight_capacity, flight_dump_dir)
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.ring_size)
@@ -280,9 +275,6 @@ class Tracer:
         into the ring and the flight recorder."""
         if span is None or span.is_noop or not self.enabled:
             return
-        ann, span._annotation = span._annotation, None
-        if ann is not None:
-            ann.__exit__(None, None, None)
         with self._lock:
             if span.t_end is not None:      # double-finish: keep first
                 return
@@ -328,8 +320,9 @@ class Tracer:
     def span(self, name: str, parent: Optional[Span] = None,
              track: Optional[str] = None, **attrs: Any) -> Iterator[Span]:
         """Scoped span for same-thread work; nests via a thread-local
-        stack and bridges to the XLA profiler host track when a
-        profiler trace is active."""
+        stack and is written to the profiler's host track too
+        (``profiling.trace.annotate``: inert without a session, and it
+        carries no state into the canonical hash)."""
         if not self.enabled:
             yield _NOOP_SPAN
             return
@@ -338,15 +331,12 @@ class Tracer:
         sp = (self.begin_span(name, parent, track=track, **attrs)
               if parent is not None
               else self.new_trace(name, track=track, **attrs))
-        if self.xla_bridge:
-            from ..profiling import trace as xla_trace
+        from ..profiling.trace import annotate
 
-            if xla_trace.trace_active():
-                sp._annotation = xla_trace.annotate(name)
-                sp._annotation.__enter__()
         self._tls.stack.append(sp)
         try:
-            yield sp
+            with annotate(name):
+                yield sp
         finally:
             self._tls.stack.pop()
             self.finish_span(sp)

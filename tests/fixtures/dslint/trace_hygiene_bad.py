@@ -4,6 +4,8 @@ import time
 import jax
 import numpy as np
 
+from deepspeed_tpu.profiling.trace import annotate
+
 _CALLS = 0
 
 
@@ -24,6 +26,8 @@ class Layer:
             tracer.event(None, "tick")       # PLANT: tracer-call (event)
             flight.note("step", x=1)         # PLANT: tracer-call (note)
             with tracer.span("block"):       # PLANT: tracer-call (span)
+                carry = carry + x
+            with annotate("block", lanes=1):  # PLANT: tracer-call (annotate)
                 carry = carry + x
             return carry, x
 

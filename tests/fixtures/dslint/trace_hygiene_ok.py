@@ -4,6 +4,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.profiling.trace import annotate
+
 
 class Layer:
     def apply(self, registry, xs, key):
@@ -22,9 +24,10 @@ class Layer:
     def host_traced_step(self, tracer, flight, xs):
         # tracer spans / flight-recorder appends AROUND the traced call,
         # on the host: exactly the contract the rule enforces
-        with tracer.span("step"):
+        with tracer.span("step"), annotate("step", lanes=1):
             def body(carry, x):
-                return carry + jnp.tanh(x), x
+                with jax.named_scope("attn"):   # device names: metadata
+                    return carry + jnp.tanh(x), x
 
             out = jax.lax.scan(body, 0.0, xs)
         tracer.event(None, "step_done")

@@ -1,0 +1,303 @@
+"""The program's host spans and device scopes (docs/observability.md
+"Program spans and device scopes"), on the CPU.
+
+One module fixture drives a tiny ragged engine behind a ``ServingEngine``
+and a tiny trainer three times: under a ``jax.profiler`` session (the
+``.xplane.pb`` must hold every span of the catalogue, nested as it says,
+with integer attributes that agree with the engines' state), then twice with
+no session: once with the seam in place and once with it taken out, which
+must make the same device calls, read the clock as often and compile
+nothing.
+"""
+
+import os
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as dst
+from deepspeed_tpu.inference import ragged as ragged_mod
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
+from deepspeed_tpu.models import Llama
+from deepspeed_tpu.parallel import mesh as mesh_mod
+from deepspeed_tpu.profiling import trace as seam
+from deepspeed_tpu.runtime import engine as engine_mod
+from deepspeed_tpu.serving import ServingEngine
+from deepspeed_tpu.serving import server as server_mod
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks import program_trace, trace_reduce  # noqa: E402
+
+#: the catalogue: span -> the span it nests in (None: a thread's outermost)
+PARENT = {
+    "ragged.put": "serve.put", "ragged.admit": "ragged.put",
+    "ragged.pack": "ragged.put", "ragged.dispatch": "ragged.put",
+    "ragged.fetch": "ragged.put", "ragged.rows": "ragged.put",
+    "serve.tick": None, "serve.admit": "serve.tick",
+    "serve.put": "serve.tick", "serve.emit": "serve.tick",
+    "serve.retire": "serve.tick", "serve.wait": None,
+    "train.step": None, "train.pre": "train.step",
+    "train.dispatch": "train.step", "train.post": "train.step",
+}
+ATTRS = {
+    "ragged.put": {"lanes", "pages", "seqs", "prefill", "decode", "free"},
+    "ragged.admit": {"matched", "prompt"}, "ragged.fetch": {"bytes"},
+    "serve.tick": {"tick", "queued", "live"},
+    "serve.admit": {"admitted", "preempted"}, "serve.put": {"retries"},
+    "serve.emit": {"tokens"}, "train.step": {"step", "k"},
+}
+VOCAB, MAX_SEQS, BLOCK, N_BLOCKS = 128, 4, 8, 64
+PROMPTS = [40, 10, 5]          # 40 > the 32-token budget: a chunked prefill
+NEW_TOKENS = 4
+
+
+def _prompt(seed, n):
+    return [int(t) for t in
+            np.random.default_rng(seed).integers(1, VOCAB, n)]
+
+
+class Counters:
+    """Clock reads (every ``time`` function the package reads), step
+    programs launched, and programs compiled, while installed."""
+
+    CLOCKS = ("perf_counter", "time", "monotonic")
+
+    def __init__(self):
+        self.reset()
+        jax.monitoring.register_event_duration_secs_listener(self._compiled)
+        self._on = False
+
+    def _compiled(self, event, seconds, **kw):
+        if self._on and event == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+
+    def counting(self, fn, field):
+        def inner(*a, **k):
+            if self._on:
+                setattr(self, field, getattr(self, field) + 1)
+            return fn(*a, **k)
+        return inner
+
+    def reset(self):
+        self.clock_reads = self.device_calls = self.compiles = 0
+
+    def snapshot(self):
+        return (self.clock_reads, self.device_calls, self.compiles)
+
+
+def serve(engine, start, tokens_out):
+    """The serving workload: three requests together, then the longest
+    prompt once more (its prefix is cached by then)."""
+    srv = ServingEngine(engine, {"policy": "fcfs", "max_queue": 16},
+                        start=start)
+
+    def offer(prompts):
+        reqs = [srv.submit(p, max_new_tokens=NEW_TOKENS,
+                           on_token=tokens_out.append) for p in prompts]
+        for _ in range(400):
+            if all(r.is_terminal for r in reqs):
+                return
+            if start:
+                time.sleep(0.01)
+            else:
+                srv._tick()
+        raise AssertionError("the requests did not finish")
+
+    first = [_prompt(i, n) for i, n in enumerate(PROMPTS)]
+    offer(first)
+    offer(first[:1])
+    if start:
+        time.sleep(0.05)               # idle polls: serve.wait
+    srv.close()
+
+
+def train(engine, batch):
+    engine.train_batch(batch)
+    engine.train_batch(batch)
+    jax.block_until_ready(engine.train_steps([batch, batch])["loss"])
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    mesh_mod.reset_topology()
+    model = Llama("tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+                  vocab_size=VOCAB, max_seq_len=256, use_flash=False,
+                  remat=False)
+    params = model.init(jax.random.PRNGKey(5))
+    ragged = RaggedInferenceEngine(model, RaggedConfig(
+        token_budget=32, max_seqs=MAX_SEQS, kv_block_size=BLOCK,
+        n_kv_blocks=N_BLOCKS, max_context=128, dtype=jnp.float32,
+        enable_prefix_cache=True), params=params)
+    trainer, _, _, _ = dst.initialize(     # donates its own parameters
+        model=model, params=model.init(jax.random.PRNGKey(6)),
+        topology=mesh_mod.Topology.build_virtual({"data": 1}),
+        config={"train_batch_size": 2, "steps_per_print": 1_000_000,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "gradient_clipping": 1.0})
+    batch = {"input_ids": jnp.asarray(
+        np.random.default_rng(0).integers(1, VOCAB, (2, 32)), jnp.int32)}
+
+    # 1. under a profiler session (the first run also compiles everything)
+    logdir = str(tmp_path_factory.mktemp("program_spans"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    traced_tokens = []
+    step0 = trainer.global_steps
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    try:
+        serve(ragged, True, traced_tokens)
+        train(trainer, batch)
+    finally:
+        jax.profiler.stop_trace()
+    trace = program_trace.load(trace_reduce.find_xplane(logdir))
+    hlo = {"step": ragged._step_fn.lower(
+        ragged.params, ragged.kv_pool, *(jnp.zeros((8,), jnp.int32),) * 3,
+        jnp.zeros((MAX_SEQS, ragged.max_pages), jnp.int32),
+        jnp.zeros((MAX_SEQS,), jnp.int32), 1).compile().as_text(),
+        "train": trainer._train_step_fn.lower(
+            trainer.params, trainer.opt_state, trainer.scaler_state,
+            trainer.rng, batch).compile().as_text()}
+
+    # 2. no session: the seam in place, then taken out of every module
+    counters = Counters()
+    ragged._step_fn = counters.counting(ragged._step_fn, "device_calls")
+    trainer._train_step_fn = counters.counting(trainer._train_step_fn,
+                                               "device_calls")
+    for k, fn in list(trainer._train_steps_fns.items()):
+        trainer._train_steps_fns[k] = counters.counting(fn, "device_calls")
+    users = (ragged_mod, server_mod, engine_mod)
+    real_clocks = {n: getattr(time, n) for n in Counters.CLOCKS}
+    off = {}
+    try:
+        for n, fn in real_clocks.items():
+            setattr(time, n, counters.counting(fn, "clock_reads"))
+        counters._on = True
+        for label, annotate in (("seam", seam.annotate),
+                                ("without", lambda name, **a:
+                                 seam._NoAnnotation())):
+            for mod in users:
+                mod.annotate = annotate
+            counters.reset()
+            tokens = []
+            serve(ragged, False, tokens)
+            train(trainer, batch)
+            off[label] = counters.snapshot() + (len(tokens),)
+        counters.reset()
+        for i in range(100):           # the seam alone
+            with seam.annotate("probe", lanes=i) as span:
+                span.set_metadata(free=i)
+        off["probe"] = counters.snapshot()
+    finally:
+        counters._on = False
+        for n, fn in real_clocks.items():
+            setattr(time, n, fn)
+        for mod in users:
+            mod.annotate = seam.annotate
+    trainer.close()
+    mesh_mod.reset_topology()
+    return {"spans": trace.spans, "tokens": len(traced_tokens), "off": off,
+            "hlo": hlo, "buckets": list(ragged._buckets),
+            "max_pages": ragged.max_pages, "step0": step0}
+
+
+def named(runs, name):
+    return [s for s in runs["spans"] if s.name == name]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_span_is_recorded_and_nested(runs, name):
+    spans = runs["spans"]
+    mine = named(runs, name)
+    assert mine, f"no {name} span in the trace"
+    for s in mine:
+        parent = spans[s.parent].name if s.parent is not None else None
+        assert parent == PARENT[name], (name, parent)
+        if s.parent is not None:
+            assert spans[s.parent].start <= s.start \
+                and s.end <= spans[s.parent].end
+        assert set(s.attrs) == ATTRS.get(name, set()), (name, s.attrs)
+        assert all(type(v) is int for v in s.attrs.values())
+
+
+def test_put_attributes_agree_with_the_engine(runs):
+    puts = named(runs, "ragged.put")
+    for s in puts:
+        a = s.attrs
+        assert a["lanes"] in runs["buckets"]
+        assert a["pages"] <= runs["max_pages"] \
+            and a["pages"] & (a["pages"] - 1) == 0
+        assert 1 <= a["seqs"] <= MAX_SEQS
+        assert a["prefill"] + a["decode"] <= a["lanes"]
+        assert 0 <= a["free"] <= N_BLOCKS
+    assert puts[0].attrs["prefill"] > 0
+    decode_only = [s.attrs for s in puts if s.attrs["prefill"] == 0]
+    assert decode_only and all(a["decode"] == a["seqs"] for a in decode_only)
+    # the 40-token prompt took two ticks of a 32-lane budget
+    assert sum(s.attrs["prefill"] for s in puts) >= sum(PROMPTS)
+    for s in named(runs, "ragged.fetch"):
+        assert s.attrs["bytes"] == MAX_SEQS * VOCAB * 4
+
+
+def test_admit_attributes_count_prompts_and_prefix_hits(runs):
+    admits = named(runs, "ragged.admit")
+    assert sum(s.attrs["prompt"] for s in admits) == sum(PROMPTS) + PROMPTS[0]
+    matched = [s.attrs["matched"] for s in admits if s.attrs["matched"]]
+    # the repeated prompt adopts its cached full blocks, and nothing else does
+    assert len(matched) == 1 and matched[0] % BLOCK == 0 \
+        and 0 < matched[0] < PROMPTS[0]
+
+
+def test_serve_attributes_agree_with_the_server(runs):
+    ticks = [s.attrs["tick"] for s in named(runs, "serve.tick")
+             if any(c.name == "serve.put" and runs["spans"][c.parent] is s
+                    for c in named(runs, "serve.put"))]
+    assert ticks == sorted(set(ticks)) and ticks[0] >= 1
+    assert sum(s.attrs["admitted"] for s in named(runs, "serve.admit")) \
+        == len(PROMPTS) + 1
+    assert all(s.attrs["preempted"] == 0 for s in named(runs, "serve.admit"))
+    assert all(s.attrs["retries"] == 0 for s in named(runs, "serve.put"))
+    assert sum(s.attrs["tokens"] for s in named(runs, "serve.emit")) \
+        == runs["tokens"] == (len(PROMPTS) + 1) * NEW_TOKENS
+
+
+def test_train_steps_carry_their_number(runs):
+    steps = [(s.attrs["step"], s.attrs["k"]) for s in named(runs,
+                                                            "train.step")]
+    s0 = runs["step0"]
+    assert steps == [(s0, 1), (s0 + 1, 1), (s0 + 2, 2)]
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("step", ("embed", "weights", "attn", "paged_attention", "ffn", "head")),
+    ("train", ("embed", "attn", "ffn", "head", "optimizer")),
+])
+def test_device_scopes_are_in_the_program(runs, program, scopes):
+    """Every scope names operations of the compiled program, in the forms
+    the trace's reader knows (``/attn/``, ``jvp(head)``); in the train
+    step also under ``transpose(``, which the reader counts as backward."""
+    names = set(re.findall(r'op_name="([^"]+)"', runs["hlo"][program]))
+    for scope in scopes:
+        mine = [n for n in names if scope in
+                program_trace.scope_of(n)[1].split("/")]
+        assert mine, f"no operation under {scope!r}"
+        if program == "train" and scope != "optimizer":
+            assert any(program_trace.scope_of(n)[2] for n in mine), scope
+    assert not any(program_trace.scope_of(n)[2] for n in names
+                   if program_trace.scope_of(n)[0] == "optimizer")
+
+
+def test_without_a_session_the_seam_changes_nothing(runs):
+    off = runs["off"]
+    # clock reads, step programs launched, compiles, tokens delivered
+    assert off["seam"] == off["without"]
+    assert off["seam"][2] == 0 and off["seam"][1] > 0
+    assert off["seam"][3] == (len(PROMPTS) + 1) * NEW_TOKENS
+    assert off["probe"] == (0, 0, 0)
