@@ -642,15 +642,17 @@ class RaggedInferenceEngine:
         # paged KV pool: per-layer tuples of [n_blocks + 1, hkv, block, hd]
         # (last page = scratch sink for masked-out batch lanes; duplicate
         # scatters with mixed old/new values are undefined — inactive lanes
-        # must never alias a live page). One array PER LAYER, not a stacked
-        # [L, pages, ...] tensor: stacked, every layer's update is a
-        # pool-sized dynamic-slice copy-out/copy-in (the whole KV pool
-        # re-written L times per step — measured 100 ms/decode-step); flat
-        # [(L)*(P+1), ...] with offset tables avoids the slices but XLA then
-        # materializes pool-sized scatter copies (measured 16-18 GB compile
-        # OOM on a 4.3 GB pool). Per-layer leaves keep every scatter's
-        # worst-case transient to one leaf. (block, hd) stay minor-most so
-        # each page is a native VMEM tile for the Pallas kernel
+        # must never alias a live page). (block, hd) stay minor-most so
+        # each page is a native VMEM tile for the Pallas kernel, which pins
+        # this row-major layout; every write into a leaf must keep it
+        # (ops/pallas/paged_attention.write_kv_rows), or XLA:TPU
+        # transposes the whole leaf and back, every tick. One array PER
+        # LAYER: earlier rounds measured pool-sized copies under a stacked
+        # [L, pages, ...] tensor (100 ms a decode step) and a flat
+        # [L*(P+1), ...] one (16-18 GB compile OOM) and blamed the shapes,
+        # but the per-layer leaves were copied too, by the row scatter's
+        # (hkv, hd) window (PR 26). Stacked and flat were not tried again;
+        # per-layer leaves keep any transient to one leaf.
         # kv_quant stores pages as blockwise payload + per-row fp32 scales
         # (scale block = one K/V head-vector): int8 payload [.., hd] or
         # int4 nibble-packed uint8 [.., hd//2], scale leaf [P+1, hkv, bs].
@@ -2086,7 +2088,8 @@ class RaggedInferenceEngine:
         positions, block_tables) -> (hidden [T, d], pools). Traced inside
         both the SplitFuse ``put`` step and the multi-step decode loop."""
         from ..ops.pallas.paged_attention import (paged_attention,
-                                                  paged_attention_reference)
+                                                  paged_attention_reference,
+                                                  write_kv_rows)
 
         model = self.model
         c = model.config
@@ -2122,40 +2125,27 @@ class RaggedInferenceEngine:
         def _paged_attn_sharded(q, kp, vp, tables, positions, slots,
                                 live_pages, window, ks=None, vs=None):
             """shard_map the paged kernel over the bound mesh: heads and
-            pool (payload AND scale leaves) sharded on 'model', scalars
-            replicated."""
+            pool (payload AND scale leaves; dim 1 is heads) sharded on
+            'model', scalars replicated. Every mesh axis is manual: Mosaic
+            refuses to lower a kernel under a partly automatic mesh."""
             from jax.sharding import PartitionSpec as P_
 
-            hspec = P_(None, "model", None)
-            pspec = P_(None, "model", None, None)
-            sspec = P_(None, "model", None)
+            sharded = (q, kp, vp) + (() if ks is None else (ks, vs))
+            heads = lambda a: P_(None, "model", *(None,) * (a.ndim - 2))
 
-            if ks is not None:
-                def local_q(q, kp, vp, tb, pos, sl, ks, vs):
-                    return paged_attention(q, kp, vp, tb, pos, seq_slots=sl,
-                                           live_pages=live_pages,
-                                           window=window, k_scale=ks,
-                                           v_scale=vs, kv_bits=kv_bits,
-                                           interpret=interp)
-
-                mapped = jax.shard_map(
-                    local_q, mesh=self.topo.mesh, axis_names={"model"},
-                    in_specs=(hspec, pspec, pspec, P_(None, None), P_(None),
-                              P_(None), sspec, sspec),
-                    out_specs=hspec, check_vma=False)
-                return mapped(q, kp, vp, tables, positions, slots, ks, vs)
-
-            def local(q, kp, vp, tb, pos, sl):
+            def local(q, kp, vp, *rest):
+                *sc, tb, pos, sl = rest
+                quant = dict(k_scale=sc[0], v_scale=sc[1],
+                             kv_bits=kv_bits) if sc else {}
                 return paged_attention(q, kp, vp, tb, pos, seq_slots=sl,
                                        live_pages=live_pages, window=window,
-                                       interpret=interp)
+                                       interpret=interp, **quant)
 
-            in_specs = (hspec, pspec, pspec, P_(None, None), P_(None),
-                        P_(None))
-            mapped = jax.shard_map(
-                local, mesh=self.topo.mesh, axis_names={"model"},
-                in_specs=in_specs, out_specs=hspec, check_vma=False)
-            return mapped(q, kp, vp, tables, positions, slots)
+            return jax.shard_map(
+                local, mesh=self.topo.mesh,
+                in_specs=tuple(map(heads, sharded)) + (P_(),) * 3,
+                out_specs=heads(q), check_vma=False)(
+                    *sharded, tables, positions, slots)
 
         def norm(x, w, b=None):
             return rms_norm(x, w, c.norm_eps) if c.norm == "rms" \
@@ -2202,8 +2192,8 @@ class RaggedInferenceEngine:
                         kk = apply_rotary(kk[:, None], angles, positions[:, None],
                                           rotary_dim=c.rotary_dim,
                                           interleaved=c.rope_interleaved)[:, 0]
-                    # scatter new K/V into this layer's pages — one in-place
-                    # scatter of the touched pages into this layer's leaf:
+                    # write the new K/V rows into this layer's pages, in
+                    # place and in the kernel's layout (write_kv_rows):
                     # page = table[pos // bs], row = pos % bs
                     page = block_tables[safe_slot, positions // bs]   # [T]
                     row = positions % bs
@@ -2223,16 +2213,16 @@ class RaggedInferenceEngine:
 
                         qk, sk = quantize_kv(kk, kv_bits)
                         qv, sv = quantize_kv(vv, kv_bits)
-                        kp = kp.at[page, :, row].set(qk)
-                        vp = vp.at[page, :, row].set(qv)
-                        ksl = ks_list[li].at[page, :, row].set(sk)
-                        vsl = vs_list[li].at[page, :, row].set(sv)
+                        kp = write_kv_rows(kp, page, row, qk)
+                        vp = write_kv_rows(vp, page, row, qv)
+                        ksl = write_kv_rows(ks_list[li], page, row, sk)
+                        vsl = write_kv_rows(vs_list[li], page, row, sv)
                         k_list[li], v_list[li] = kp, vp
                         ks_list[li], vs_list[li] = ksl, vsl
                     else:
                         ksl = vsl = None
-                        kp = kp.at[page, :, row].set(kk.astype(kp.dtype))
-                        vp = vp.at[page, :, row].set(vv.astype(vp.dtype))
+                        kp = write_kv_rows(kp, page, row, kk)
+                        vp = write_kv_rows(vp, page, row, vv)
                         k_list[li], v_list[li] = kp, vp
                     # paged attention: Pallas kernel on TPU (scalar-prefetched
                     # block tables, zero gather); jnp gather path elsewhere.
@@ -2274,9 +2264,9 @@ class RaggedInferenceEngine:
                     return x + down[0]
 
             # python-unrolled layer loop, NOT lax.scan: a scan would carry
-            # the whole pool and either re-slice it per layer (stacked
-            # layout) or double-buffer it (flat layout) — see the pool_shape
-            # comment in __init__
+            # the whole pool, stacked or flat, and both were measured with
+            # pool-sized copies before the row write kept the kernel's
+            # layout (the pool comment in __init__); not tried since
             for li in range(c.n_layers):
                 with jax.named_scope("weights"):
                     lp = jax.tree_util.tree_map(lambda a: a[li],

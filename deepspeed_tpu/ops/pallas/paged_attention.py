@@ -303,6 +303,26 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     return out.reshape(T, hq, hd)
 
 
+def write_kv_rows(leaf, page, row, new):
+    """Write one new row per ragged lane into a pool leaf, in place and in
+    the layout the kernel above reads.
+
+    ``leaf`` is a payload leaf [n_pages, hkv, block, hd] (``new`` [T, hkv,
+    hd]) or a ``kv_quant`` scale leaf [n_pages, hkv, block] (``new`` [T,
+    hkv]); ``page``/``row`` [T] say where lane t's row lands. The KV-head
+    axis is an *index* of the scatter beside page and row, so the update
+    window is ``hd`` alone (empty for a scale leaf), already minor-most.
+    Left as a window (``leaf.at[page, :, row]``, same values, same
+    places) XLA:TPU's layout assignment transposes the whole leaf to
+    [pages, block, hkv, hd] to make the (hkv, hd) window contiguous and
+    back again for the kernel, which pins the default layout: two
+    pool-sized copies a leaf a tick to write T rows
+    (``tests/test_tpu_compile.py`` guards the compiled step)."""
+    heads = jnp.arange(leaf.shape[1], dtype=page.dtype)
+    return leaf.at[page[:, None], heads[None, :], row[:, None]].set(
+        new.astype(leaf.dtype))
+
+
 def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
                               scale=None, window: int = 0,
                               k_scale=None, v_scale=None, kv_bits: int = 8):

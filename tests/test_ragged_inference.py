@@ -848,3 +848,66 @@ def test_stream_composes_with_prefix_cache():
     b = list(eng.stream(2, list(P), max_new_tokens=8))
     assert a == want and b == want
     assert eng.prefix_cache.hits >= 1
+
+
+def _head_window_write(leaf, page, row, new):
+    """The row write as it was before ``write_kv_rows``: the KV-head axis a
+    *window* of the scatter (XLA:TPU answers with a layout round trip of
+    the whole leaf). Lives on only here, as the oracle."""
+    return leaf.at[page, :, row].set(new.astype(leaf.dtype))
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("entry", ["put", "put_spec", "decode_steps"])
+def test_row_write_lands_where_the_head_window_scatter_did(entry, kv_quant,
+                                                           monkeypatch):
+    """One compiled call of each entry point over hand-made lanes — a
+    prefill chunk crossing pages, decode lanes, inactive lanes and a lane
+    at or past ``max_context`` (the tail of a multi-step decode) — leaves
+    every page but the sink bit-equal to what the old formulation wrote,
+    payload and scale leaves, on a pool that held other values before."""
+    model = _llama()
+    cfg = _cfg(max_context=64, n_kv_blocks=32, kv_quant=kv_quant)
+    S, n, mp = cfg.max_seqs, cfg.n_kv_blocks, 64 // cfg.kv_block_size
+    tables = jnp.arange(S * mp, dtype=jnp.int32).reshape(S, mp)
+    i32 = lambda xs: jnp.asarray(xs, jnp.int32)
+    if entry == "decode_steps":          # one lane a slot, four steps
+        slots, pos = i32([0, 1, -1, 3]), i32([20, 62, 0, 40])  # 62 -> 65
+    else:                                # T = 32 lanes
+        chunk = list(range(5, 21))       # slot 0: 16 tokens over 3 pages
+        slots = i32([0] * 16 + [1, 2, 3] + [-1] * 13)
+        pos = i32(chunk + [30, 64, 63] + [0] * 13)   # slot 2 is past
+    toks = (jnp.arange(slots.shape[0], dtype=jnp.int32) * 7 + 3) % 128
+
+    def run(write):
+        with monkeypatch.context() as m:
+            if write is not None:
+                m.setattr("deepspeed_tpu.ops.pallas.paged_attention."
+                          "write_kv_rows", write)
+            eng = RaggedInferenceEngine(model, cfg, rng=jax.random.PRNGKey(5))
+            rng = np.random.default_rng(9)
+            fill = lambda x: jnp.asarray(
+                rng.integers(-100, 100, x.shape).astype(x.dtype)
+                if jnp.issubdtype(x.dtype, jnp.integer)
+                else rng.uniform(0.01, 1.0, x.shape).astype(x.dtype))
+            pools = jax.tree_util.tree_map(fill, eng.kv_pool)
+            if entry == "put":
+                out = eng._build_step()(eng.params, pools, toks, slots, pos,
+                                        tables, i32([15, 16, 18, 18]), mp)
+            elif entry == "put_spec":
+                out = eng._build_verify()(
+                    eng.params, pools, toks, slots, pos, tables,
+                    i32([[12, 13, 14, 15], [16] * 4, [18] * 4, [18] * 4]), mp)
+            else:
+                out = eng._build_decode()(
+                    eng.params, pools, toks, pos, slots, tables,
+                    i32([0, 1, 2, 3]), jax.random.PRNGKey(0), mp, -1)
+        return jax.tree_util.tree_map(np.asarray, out)
+
+    (got, got_pools), (want, want_pools) = run(None), run(_head_window_write)
+    leaves = jax.tree_util.tree_leaves(got_pools)
+    assert len(leaves) == (4 if kv_quant == "int8" else 2) * 2
+    for a, b in zip(leaves, jax.tree_util.tree_leaves(want_pools)):
+        assert a.shape[0] == n + 1 and a.dtype == b.dtype
+        np.testing.assert_array_equal(a[:n], b[:n])
+    np.testing.assert_array_equal(got, want)

@@ -11,18 +11,23 @@ halves live in ``chip_smoke.py``'s kernels phase, on the chip.
 Covered: the kernels of the main path at Mistral-7B widths (flash fwd/bwd;
 paged attention bf16, windowed and int8-KV, at a prefill-chunk and a
 decode shape; the int4-KV refusal), one whole train step and one ragged
-serving step of the smoke model at reduced depth, and the four-chip
-ZeRO-3 step ``chip_smoke.py --chips 4`` runs.
+serving step of the smoke model at reduced depth, the four-chip
+ZeRO-3 step ``chip_smoke.py --chips 4`` runs, and what the compiled
+serving step does to its KV pool (rows written in place, on one chip and
+with the pool sharded over two).
 
 One file on purpose: only the xdist worker that gets this file loads
 libtpu, inside the module-scoped ``topo`` fixture — never at import.
 """
 
 import os
+import re
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -255,10 +260,16 @@ def test_zero3_step_on_four_chips_compiles(topo, on_tpu):
 
 
 def compile_ragged_step(device_sharding, n_layers: int, T: int,
-                        live_pages: int, n_kv_blocks: int = 1024):
+                        live_pages: int, n_kv_blocks: int = 1024,
+                        kv_quant: str = "none", tp_topo=None):
     """``RaggedInferenceEngine``'s own jitted SplitFuse step, lowered
     against shapes: the engine is built with no weights (the step takes
-    them as an argument) and a small host-side pool."""
+    them as an argument) and a small host-side pool. ``tp_topo`` (a
+    ``Topology`` over described chips with a 'model' axis) gives the
+    tensor-parallel step: the engine cannot place a pool on a described
+    chip, so it is built unsharded and handed the mesh before it traces."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                                 RaggedInferenceEngine)
 
@@ -267,15 +278,28 @@ def compile_ragged_step(device_sharding, n_layers: int, T: int,
         model, RaggedConfig(token_budget=SZ.token_budget,
                             max_seqs=SZ.max_seqs, kv_block_size=BLK,
                             n_kv_blocks=n_kv_blocks,
-                            max_context=SZ.max_context), params={})
+                            max_context=SZ.max_context, kv_quant=kv_quant),
+        params={})
     assert eng.attention_path == "pallas"
     params = jax.eval_shape(partial(model.init, dtype=jnp.bfloat16),
                             jax.random.PRNGKey(0))
+    if tp_topo is None:
+        p_sh = pool_sh = device_sharding
+    else:
+        eng.topo, eng._tp_size = tp_topo, tp_topo.model_parallel_size
+        mesh = tp_topo.mesh
+        p_sh = jax.tree_util.tree_map(
+            lambda sp: NamedSharding(mesh, sp),
+            model.partition_specs(params, tp_topo),
+            is_leaf=lambda x: isinstance(x, P))
+        pool_sh = jax.tree_util.tree_map(
+            lambda x: NamedSharding(
+                mesh, P(None, "model", *(None,) * (x.ndim - 2))),
+            eng.kv_pool)
     lanes = _sds((T,), jnp.int32, device_sharding)
     return eng._build_step().lower(
-        _place(params, device_sharding),
-        _place(eng.kv_pool, device_sharding), lanes, lanes, lanes,
-        _sds((SZ.max_seqs, eng.max_pages), jnp.int32, device_sharding),
+        _place(params, p_sh), _place(eng.kv_pool, pool_sh), lanes, lanes,
+        lanes, _sds((SZ.max_seqs, eng.max_pages), jnp.int32, device_sharding),
         _sds((SZ.max_seqs,), jnp.int32, device_sharding),
         live_pages).compile()
 
@@ -286,3 +310,123 @@ def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages):
     n_layers = 2  # reduced from chip_smoke's serve depth: compile time
     c = compile_ragged_step(one_chip, n_layers, T, live_pages)
     assert c.as_text().count("tpu_custom_call") == n_layers
+
+
+# ----------------------------------------------------------------------
+# the ragged step writes its new K/V rows into the pool in place
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\](?:\{([\d,]*))?\S* "
+                    r"([\w\-]+)\(([^)]*)\)")
+
+
+class Instr(NamedTuple):
+    """One array-valued instruction of a compiled module's text."""
+    dtype: str          # "bf16"
+    dims: str           # "4097,8,16,128"
+    layout: str         # minor to major, "3,2,1,0"
+    op: str             # opcode
+    operands: list      # names
+    line: str
+
+
+def _instructions(hlo: str) -> dict:
+    out = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            name, dtype, dims, layout, op, args = m.groups()
+            out[name] = Instr(dtype, dims, layout or "", op,
+                              re.findall(r"%([\w.\-]+)", args), line)
+    return out
+
+
+POOL_PAGES = 4096  # the benchmark's serving cells hold 4096 pages
+
+
+def _pool_faults(hlo: str, dtype: str = "bf16", heads: int = HKV) -> list:
+    """What a compiled ragged step does to its KV pool's payload leaves
+    (``dtype[POOL_PAGES + 1, heads, BLK, HD]`` on a device) beyond writing
+    rows into them. Empty when every value of that shape keeps the layout
+    of the donated parameter (the kernel's, row-major), nothing copies or
+    gathers a leaf, and every leaf the paged kernel reads is the row
+    scatter's own result on that parameter, or a bitcast of it."""
+    shape = (POOL_PAGES + 1, heads, BLK, HD)
+    dims, n_elements = ",".join(map(str, shape)), int(np.prod(shape))
+    ins = _instructions(hlo)
+    is_leaf = lambda i: (i.dtype, i.dims) == (dtype, dims)
+    faults = []
+    for name, i in ins.items():
+        if is_leaf(i) and i.layout != "3,2,1,0":
+            faults.append(f"{i.op} {name}: a pool leaf in layout "
+                          f"{{{i.layout}}}")
+        if i.op in ("copy", "all-gather", "all-to-all", "collective-permute") \
+                and i.dtype == dtype and i.dims \
+                and np.prod(list(map(int, i.dims.split(",")))) == n_elements:
+            faults.append(f"{i.op} {name}: moves a whole pool leaf")
+
+    def source(name):  # through bitcasts, to what made the bytes
+        while name in ins and ins[name].op in ("bitcast",
+                                               "get-tuple-element"):
+            name = ins[name].operands[0]
+        return name
+
+    opcode = lambda name: ins[name].op if name in ins else "tuple"
+    kernels = [i for i in ins.values()
+               if i.op == "custom-call" and "tpu_custom_call" in i.line]
+    for k in kernels:
+        for o in set(k.operands):
+            if o not in ins or not is_leaf(ins[o]):
+                continue
+            writer = source(o)
+            origin = source(ins[writer].operands[0]) \
+                if ins[writer].operands else writer
+            if opcode(writer) != "fusion" or opcode(origin) != "parameter":
+                faults.append(f"kernel reads {o}: made by {opcode(writer)} "
+                              f"{writer} from {opcode(origin)} {origin}")
+    return faults + ([] if kernels else ["no paged kernel in the module"])
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("T", [64, SZ.token_budget],
+                         ids=["decode", "prefill_chunk"])
+def test_ragged_step_writes_pool_in_place(one_chip, on_tpu, T, kv_quant):
+    """No operation of the compiled step costs what the pool weighs: the
+    new rows are scattered into the donated leaves in the kernel's own
+    layout (``write_kv_rows``). With the KV-head axis a window of the
+    scatter the same compile holds two transposing copies a leaf (eight
+    at two layers, 187 MB of temporaries at the decode shape)."""
+    c = compile_ragged_step(one_chip, 2, T, 128, n_kv_blocks=POOL_PAGES,
+                            kv_quant=kv_quant)
+    hlo = c.as_text()
+    assert _pool_faults(hlo, "s8" if kv_quant == "int8" else "bf16") == []
+    if kv_quant == "int8":
+        # a scale leaf [pages, hkv, block] (2 MB) is laid out {0,2,1} in
+        # HBM by the TPU runtime itself (16 minor elements would pad to
+        # 128): its rows are written in that layout, and ONE copy a leaf
+        # brings it to the kernel's. With the head axis a window it was
+        # three (in, to the kernel, back out). PERF.md section 7.
+        scale = ("f32", f"{POOL_PAGES + 1},{HKV},{BLK}")
+        copies = [i for i in _instructions(hlo).values()
+                  if i.op == "copy" and (i.dtype, i.dims) == scale]
+        assert len(copies) <= 2 * 2, [i.line[:120] for i in copies]
+    if T == 64:
+        leaf_bytes = (POOL_PAGES + 1) * HKV * BLK * HD \
+            * (1 if kv_quant == "int8" else 2)
+        assert c.memory_analysis().temp_size_in_bytes < leaf_bytes
+
+
+def test_tp2_ragged_step_gathers_no_pool_leaf(topo, on_tpu):
+    """Pool sharded over KV heads on two chips: GSPMD partitions the row
+    write along its head index (an iota, so each chip writes its local
+    heads) and the kernel runs in its shard_map: no collective and no copy
+    moves a leaf, whole or per shard. Also the only place the
+    tensor-parallel kernel path meets Mosaic's lowering, which wants
+    every mesh axis manual."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.parallel.mesh import Topology
+
+    tp = Topology.build(MeshConfig(model=2), devices=topo.devices[:2])
+    c = compile_ragged_step(NamedSharding(tp.mesh, P()), 2, 64, 128,
+                            n_kv_blocks=POOL_PAGES, tp_topo=tp)
+    assert _pool_faults(c.as_text(), heads=HKV // 2) == []
