@@ -112,6 +112,46 @@ def _uniform_windows(window, max_seq: int, n_layers: int):
     return tuple([int(window)] * n_layers)
 
 
+def olmo_hybrid_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
+    """``model_type: olmo_hybrid`` -> TransformerConfig: OLMo-2/3 blocks
+    (``x + norm(sub(x))``, QK-norm) whose mixer is by ``layer_types``
+    softmax attention or a gated delta-rule layer (``linear_*`` keys,
+    ops/gated_delta.py). ``n_layers`` keeps the first layers only (a
+    deployment split by layers holds such a cut). The published config.json
+    carries no modelling code; assumed with the family: no rotary embedding
+    where ``rope_parameters.rope_theta`` is null (else plain RoPE at that
+    theta), and FLA's names for the checkpoint's tensors
+    (:func:`_map_olmo_hybrid`)."""
+    from ..models.transformer import TransformerConfig
+
+    if hc.get("attention_bias"):
+        raise NotImplementedError("olmo_hybrid attention_bias=true not "
+                                  "supported")
+    n = int(n_layers or hc["num_hidden_layers"])
+    kinds = {"linear_attention": "linear", "full_attention": "full"}
+    theta = (hc.get("rope_parameters") or {}).get("rope_theta") \
+        or hc.get("rope_theta")
+    return TransformerConfig(
+        vocab_size=hc["vocab_size"], d_model=hc["hidden_size"], n_layers=n,
+        n_heads=hc["num_attention_heads"],
+        n_kv_heads=hc.get("num_key_value_heads", hc["num_attention_heads"]),
+        d_ff=hc["intermediate_size"],
+        max_seq_len=hc.get("max_position_embeddings", 2048),
+        norm="rms", activation="silu_glu",
+        position="rope" if theta else "none",
+        rope_theta=float(theta or 10000.0),
+        tie_embeddings=hc.get("tie_word_embeddings", False), use_bias=False,
+        norm_eps=hc.get("rms_norm_eps", 1e-6),
+        layer_types=tuple(kinds[k] for k in hc["layer_types"][:n]),
+        linear_n_k_heads=hc["linear_num_key_heads"],
+        linear_n_v_heads=hc["linear_num_value_heads"],
+        linear_k_dim=hc["linear_key_head_dim"],
+        linear_v_dim=hc["linear_value_head_dim"],
+        linear_conv_kernel=hc.get("linear_conv_kernel_dim", 4),
+        linear_neg_eigval=bool(hc.get("linear_allow_neg_eigval", False)),
+        branch_norm=True, qk_norm=True)
+
+
 def hf_config(model_dir: str):
     """Parse HF config.json -> (family, TransformerConfig)."""
     from ..models.transformer import TransformerConfig
@@ -119,6 +159,8 @@ def hf_config(model_dir: str):
     with open(os.path.join(str(model_dir), "config.json")) as f:
         hc = json.load(f)
     family = hc.get("model_type", "")
+    if family == "olmo_hybrid":
+        return family, olmo_hybrid_config(hc)
     if family in ("llama", "mistral"):
         # loud failure beats silently-wrong logits for unsupported variants
         if hc.get("rope_scaling"):
@@ -418,7 +460,7 @@ def hf_config(model_dir: str):
         raise ValueError(f"unsupported HF model_type '{family}' "
                          f"(supported: llama, mistral, gpt2, opt, bloom, "
                          f"gptj, gpt_neo, gpt_neox, falcon, mixtral, bert, "
-                         f"distilbert, clip, qwen2)")
+                         f"distilbert, clip, qwen2, olmo_hybrid)")
     return family, cfg
 
 
@@ -465,6 +507,57 @@ def _map_llama(state, c) -> Dict[str, Any]:
         params["lm_head"] = (state["lm_head.weight"]
                              if "lm_head.weight" in state
                              else state[pre + "embed_tokens.weight"]).T
+    return params
+
+
+def _map_olmo_hybrid(state, c) -> Dict[str, Any]:
+    """OLMo-2's names for what every layer has and for the full layers
+    (``post_attention_layernorm`` / ``post_feedforward_layernorm`` are the
+    two branch norms), FLA's ``GatedDeltaNet`` names under ``linear_attn``
+    for the linear layers: q/k/v/a/b/g/o projections, a depthwise
+    ``conv1d`` a stream ([channels, 1, K], laid side by side here as
+    ``conv_w`` [K, channels]), ``A_log``, ``dt_bias``, ``o_norm``."""
+    pre = "model." if "model.embed_tokens.weight" in state else ""
+    L = pre + "layers.{}."
+
+    def stack(fmt, layers, transpose=False):
+        arrs = [state.pop((L + fmt).format(i)) for i in layers]
+        return np.stack([a.T if transpose else a for a in arrs])
+
+    every = range(c.n_layers)
+    layers: Dict[str, Any] = {
+        "attn_norm_w": stack("post_attention_layernorm.weight", every),
+        "mlp_norm_w": stack("post_feedforward_layernorm.weight", every),
+        "w_gate": stack("mlp.gate_proj.weight", every, True),
+        "w_up": stack("mlp.up_proj.weight", every, True),
+        "w_down": stack("mlp.down_proj.weight", every, True),
+    }
+    full, lin = c.layers_of("full"), c.layers_of("linear")
+    if full:
+        layers["full"] = {
+            **{"w" + x: stack(f"self_attn.{x}_proj.weight", full, True)
+               for x in "qkvo"},
+            "q_norm_w": stack("self_attn.q_norm.weight", full),
+            "k_norm_w": stack("self_attn.k_norm.weight", full)}
+    if lin:
+        A = "linear_attn."
+        conv = [stack(A + f"{x}_conv1d.weight", lin) for x in "qkv"]
+        layers["linear"] = {
+            **{"w" + x: stack(A + f"{x}_proj.weight", lin, True)
+               for x in "qkvo"},
+            # [n, channels, 1, K] a stream -> [n, K, all channels]
+            "conv_w": np.concatenate(
+                [np.transpose(w[:, :, 0, :], (0, 2, 1)) for w in conv], -1),
+            "w_a": stack(A + "a_proj.weight", lin, True),
+            "w_beta": stack(A + "b_proj.weight", lin, True),
+            "w_z": stack(A + "g_proj.weight", lin, True),
+            "A_log": stack(A + "A_log", lin),
+            "dt_bias": stack(A + "dt_bias", lin),
+            "o_norm_w": stack(A + "o_norm.weight", lin)}
+    params = {"tok_embed": state[pre + "embed_tokens.weight"],
+              "layers": layers, "final_norm_w": state[pre + "norm.weight"]}
+    if not c.tie_embeddings:
+        params["lm_head"] = state["lm_head.weight"].T
     return params
 
 
@@ -930,7 +1023,7 @@ _MAPPERS: Dict[str, Callable] = {
     "gpt_neo": _map_gpt_neo,
     "falcon": _map_falcon, "mixtral": _map_mixtral,
     "bert": _map_bert, "distilbert": _map_distilbert,
-    "clip": _map_clip,
+    "clip": _map_clip, "olmo_hybrid": _map_olmo_hybrid,
 }
 
 

@@ -27,6 +27,7 @@ formulation with identical semantics serves as fallback and oracle.
 from __future__ import annotations
 
 import functools
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -307,8 +308,42 @@ def assert_block_balance(engine, expect_free: Optional[int] = None) -> None:
             f"({rep['held']} pages still referenced)")
 
 
+def _layers_of(model_config, kind: str) -> Tuple[int, ...]:
+    """Indices of the layers that hold KV pages ("full") or a recurrent
+    state ("linear"). The sizing functions take any object with the KV
+    geometry (n_layers, n_kv_heads, head_dim): one without ``layers_of``
+    is all full."""
+    if hasattr(model_config, "layers_of"):
+        return model_config.layers_of(kind)
+    return tuple(range(model_config.n_layers)) if kind == "full" else ()
+
+
+def state_pool_bytes(model_config, ragged_config) -> int:
+    """Bytes of the recurrent-state pool: for every linear layer and every
+    slot (and the sink), the float32 state and the convolution's rows. A
+    fixed cost of ``max_seqs``, whatever the contexts' lengths."""
+    import jax.numpy as _jnp
+
+    n = len(_layers_of(model_config, "linear"))
+    if not n:
+        return 0
+    from ..ops.gated_delta import state_shapes
+
+    state, rows = state_shapes(model_config)
+    return n * (ragged_config.max_seqs + 1) * (
+        4 * int(np.prod(state))
+        + _jnp.dtype(ragged_config.dtype).itemsize * int(np.prod(rows)))
+
+
+#: what a model with recurrent layers refuses, and why, in one place
+_NO_SNAPSHOT = (
+    "{what} needs a snapshot of the recurrent state: a linear layer's state "
+    "cannot be rewound to, or rebuilt from, a token position the way KV "
+    "pages can, and the engine keeps no state snapshot yet")
+
+
 def kv_page_bytes(model_config, ragged_config) -> int:
-    """Bytes ONE KV page (K + V, all layers) occupies in the pool under
+    """Bytes ONE KV page (K + V, all layers that hold pages) occupies in the pool under
     ``ragged_config.kv_quant`` — payload plus per-row fp32 scales. The
     capacity arithmetic behind "quantization roughly doubles concurrent
     sequences per pool": size two pools to the same byte budget with
@@ -316,7 +351,8 @@ def kv_page_bytes(model_config, ragged_config) -> int:
     import jax.numpy as _jnp
 
     c, cfg = model_config, ragged_config
-    rows = c.n_layers * c.n_kv_heads * cfg.kv_block_size      # per K or V
+    rows = len(_layers_of(c, "full")) * c.n_kv_heads * cfg.kv_block_size
+    # (per K or V; a recurrent layer holds no pages)
     bits = {"none": 0, "int8": 8, "int4": 4}[cfg.kv_quant]
     if bits == 0:
         return 2 * rows * c.head_dim * _jnp.dtype(cfg.dtype).itemsize
@@ -331,9 +367,10 @@ def kv_blocks_for_bytes(budget_bytes: int, model_config,
                         ragged_config) -> int:
     """Pages a ``budget_bytes`` KV pool holds under the config's
     ``kv_quant`` mode (the fixed-byte-budget sizing the serve bench's
-    kv-quant leg and capacity tests use)."""
-    return max(1, int(budget_bytes)
-               // kv_page_bytes(model_config, ragged_config))
+    kv-quant leg and capacity tests use). The recurrent-state pool, a
+    fixed cost, comes out of the budget first."""
+    left = int(budget_bytes) - state_pool_bytes(model_config, ragged_config)
+    return max(1, left // max(1, kv_page_bytes(model_config, ragged_config)))
 
 
 def _prompt_lookup(ctx: Sequence[int], ngram: int, k: int) -> List[int]:
@@ -569,6 +606,16 @@ class RaggedInferenceEngine:
             raise NotImplementedError(
                 "RaggedInferenceEngine does not support attention-scale "
                 "overrides (GPT-Neo); use InferenceEngine (dense KV cache)")
+        # layers that hold KV pages / a recurrent state (ops/gated_delta.py)
+        self._page_layers = c.layers_of("full")
+        self._state_layers = c.layers_of("linear")
+        if self._state_layers and self.config.enable_prefix_cache:
+            raise NotImplementedError(_NO_SNAPSHOT.format(
+                what="enable_prefix_cache (a new prompt adopting a cached "
+                     "prefix's pages)"))
+        if self._state_layers and tp > 1:
+            raise NotImplementedError(
+                "recurrent layers are not sharded over the model axis yet")
         if c.window_binds(self.config.max_context):
             log_dist("RaggedInferenceEngine: binding sliding window — "
                      "banded paged kernel on TPU, banded gather elsewhere")
@@ -693,12 +740,32 @@ class RaggedInferenceEngine:
             def _zero_scales(_):
                 return jnp.zeros(scale_shape, jnp.float32)
         self.kv_pool = (
-            tuple(_zeros(i) for i in range(c.n_layers)),
-            tuple(_zeros(i) for i in range(c.n_layers)))
+            tuple(_zeros(i) for i in self._page_layers),
+            tuple(_zeros(i) for i in self._page_layers))
         if self._kv_bits:
             self.kv_pool = self.kv_pool + (
-                tuple(_zero_scales(i) for i in range(c.n_layers)),
-                tuple(_zero_scales(i) for i in range(c.n_layers)))
+                tuple(_zero_scales(i) for i in self._page_layers),
+                tuple(_zero_scales(i) for i in self._page_layers))
+        if self._state_layers:
+            # the second kind of cache, the pool's last two entries: for
+            # each linear layer a float32 state leaf [max_seqs + 1, H, dk,
+            # dv] and the convolution's last inputs [max_seqs + 1, K - 1,
+            # channels], keyed by SLOT (the last one the sink of lanes
+            # that are not live, as the scratch page is for KV). Nothing
+            # here zeroes a slot: the step starts a run at position 0 from
+            # zeros (a fresh admission, a resume after preempt and a
+            # reused slot all re-prefill from position 0), carries the
+            # state over ticks when a prompt is split, and never rewinds
+            from ..ops.gated_delta import state_shapes
+
+            st, rows = state_shapes(c)
+            S = cfg.max_seqs + 1
+            self.kv_pool = self.kv_pool + (
+                tuple(jnp.zeros((S,) + st, jnp.float32)
+                      for _ in self._state_layers),
+                tuple(jnp.zeros((S,) + rows, cfg.dtype)
+                      for _ in self._state_layers))
+        self._rows_buf: Optional[np.ndarray] = None    # _rows_out
         self._step_fn = None
         self._core_fn = None
         self._decode_fn = None
@@ -748,6 +815,12 @@ class RaggedInferenceEngine:
         from ..telemetry import get_telemetry
 
         return get_telemetry()
+
+    def _refuse_if_recurrent(self, what: str) -> None:
+        """Loud failure for what cannot be done without a state snapshot
+        (as ALiBi fails at construction)."""
+        if self._state_layers:
+            raise NotImplementedError(_NO_SNAPSHOT.format(what=what))
 
     # -- scheduling API (parity engine_v2.query/can_schedule) -----------
     def query(self, uid: int) -> Tuple[int, int]:
@@ -910,6 +983,7 @@ class RaggedInferenceEngine:
         export does NOT release anything — the caller decides whether to
         ``preempt`` (publish into this engine's prefix cache) or
         ``discard`` the local copy afterwards."""
+        self._refuse_if_recurrent("export_kv (handing a sequence to another engine)")
         seq = self.seqs.get(uid)
         if seq is None:
             raise KeyError(f"uid {uid} has no live sequence to export")
@@ -970,6 +1044,7 @@ class RaggedInferenceEngine:
         Raises :class:`PoolExhausted` (recoverable — the caller can fall
         back to the re-prefill resume path) or ``ValueError`` on geometry
         mismatch. On any failure nothing is mutated."""
+        self._refuse_if_recurrent("import_kv (adopting a sequence without its prefill)")
         cfg = self.config
         c = self.model.config
         if uid in self.seqs:
@@ -1055,6 +1130,7 @@ class RaggedInferenceEngine:
         at eviction time (an entry must never outlive its pages). Both
         hooks are leaf-locked, so firing them under the driver's
         serving lock is legal in the documented lock order."""
+        self._refuse_if_recurrent("the KV tier (prefixes adopted across replicas)")
         self._kv_tier_member = str(member)
         self._cold_tier = cold_tier
         self._on_prefix_invalidate = on_invalidate
@@ -1326,6 +1402,7 @@ class RaggedInferenceEngine:
         Use after observing EOS inside a ``decode_steps`` chunk when the
         sequence will keep being served (post-EOS tokens were admitted by
         that chunk and would otherwise pollute further continuations)."""
+        self._refuse_if_recurrent("trim (rewinding a sequence)")
         seq = self.seqs[uid]
         if not 0 <= length <= seq.seen:
             raise ValueError(
@@ -1400,6 +1477,11 @@ class RaggedInferenceEngine:
                         uid=uid, slot=self._free_slots.pop(),
                         t_admitted=None if resumed else now,
                         t_created=None if resumed else now)
+                    if self._state_layers and self._telemetry.enabled:
+                        # the slot's recurrent state starts again from
+                        # zeros (the step does it, at position 0)
+                        self._telemetry.registry.counter(
+                            "inference/state_resets").inc()
                 seq = self.seqs[uid]
                 seq.tokens.extend(int(t) for t in toks)
                 if new:
@@ -1490,7 +1572,7 @@ class RaggedInferenceEngine:
             logits = np.asarray(logits)                # [max_seqs, vocab]
 
         with annotate("ragged.rows"):
-            out = np.full((len(uids), logits.shape[-1]), np.nan, np.float32)
+            out = self._rows_out(len(uids), logits.shape[-1])
             now = time.perf_counter()
             for i, uid in enumerate(uids):
                 seq = self.seqs[uid]
@@ -1503,8 +1585,25 @@ class RaggedInferenceEngine:
                         self._telemetry.record_request(
                             ttft_s=now - seq.t_admitted)
                         seq.t_admitted = None
+                else:
+                    out[i] = np.nan
             self._record_step_telemetry(sched)
         return out
+
+    def _rows_out(self, n: int, width: int) -> np.ndarray:
+        """[n, width] float32 for the rows a step hands back: the last
+        call's buffer again once the caller has let go of it (nothing else
+        refers to it or to a view of it), else a new one. A new
+        [n, vocab] array every tick is megabytes of pages touched for the
+        first time (20 MB at a vocabulary of 100k), which on the chip's
+        host took 28 ms a tick in most processes (PERF.md, PR 28)."""
+        buf = self._rows_buf
+        # references when free: the attribute, this local, the argument
+        if buf is None or buf.shape[1] != width or n > buf.shape[0] \
+                or sys.getrefcount(buf) > 3:
+            buf = self._rows_buf = np.empty(
+                (max(n, self.config.max_seqs), width), np.float32)
+        return buf[:n]
 
     def _sched_attrs(self, sched, lanes: int, live_pages: int
                      ) -> Dict[str, int]:
@@ -1518,9 +1617,12 @@ class RaggedInferenceEngine:
                 prefill += take
             elif take == 1:
                 decode += 1
-        return {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
-                "prefill": prefill, "decode": decode,
-                "free": self.allocator.free_blocks}
+        attrs = {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
+                 "prefill": prefill, "decode": decode,
+                 "free": self.allocator.free_blocks}
+        if self._state_layers:    # slots whose recurrent state is live
+            attrs["state_slots"] = len(self.seqs)
+        return attrs
 
     def put_spec(self, uids: Sequence[int], tokens: Sequence[Sequence[int]],
                  drafts: Sequence[Sequence[int]]
@@ -1547,6 +1649,7 @@ class RaggedInferenceEngine:
         context. On PoolExhausted every remaining draft token is
         stripped before the raise, so the recovery retry (plain ``put``
         with empty chunks) sees exactly put()'s admitted state."""
+        self._refuse_if_recurrent("put_spec (rejected draft tokens are rewound)")
         with annotate("ragged.put") as span:
             return self._put_spec(span, uids, tokens, drafts)
 
@@ -1629,7 +1732,7 @@ class RaggedInferenceEngine:
             logits = np.asarray(logits)       # [max_seqs, k_max, vocab]
 
         with annotate("ragged.rows"):
-            out = np.full((len(uids), logits.shape[-1]), np.nan, np.float32)
+            out = self._rows_out(len(uids), logits.shape[-1])
             now = time.perf_counter()
             for i, uid in enumerate(uids):
                 seq = self.seqs[uid]
@@ -1641,6 +1744,8 @@ class RaggedInferenceEngine:
                         self._telemetry.record_request(
                             ttft_s=now - seq.t_admitted)
                         seq.t_admitted = None
+                else:
+                    out[i] = np.nan
             verified: Dict[int, Tuple[List[int], np.ndarray]] = {}
             for seq, take in sched:
                 if seq.uid in appended:
@@ -1675,6 +1780,8 @@ class RaggedInferenceEngine:
             sum(take for _, take in sched))
         r.gauge("inference/kv_occupancy").set(self.kv_occupancy())
         r.gauge("inference/live_sequences").set(len(self.seqs))
+        if self._state_layers:
+            r.gauge("inference/state_slots_live").set(len(self.seqs))
 
     def _validate_sched(self, sched) -> List[int]:
         """Validate a (seq, take) schedule WITHOUT mutating anything:
@@ -1721,6 +1828,7 @@ class RaggedInferenceEngine:
         last). One device call verifies all proposals; the caller accepts
         the longest matching prefix and trims the rest. k is pow2-bucketed
         so the jit cache stays O(log k) wide."""
+        self._refuse_if_recurrent("speculative verification (rejected draft tokens are rewound)")
         cfg = self.config
         sched = [(self.seqs[u], len(c)) for u, c in zip(uids, chains)]
         # validate BEFORE touching seq.tokens: a failed round must not
@@ -2102,6 +2210,7 @@ class RaggedInferenceEngine:
         windows = tuple(int(w) if 0 < int(w) < cfg.max_context else 0
                         for w in aw) if aw is not None \
             else (0,) * c.n_layers
+        page_layers, state_layers = self._page_layers, self._state_layers
         # TP shards the pool/heads. GSPMD cannot partition a pallas_call,
         # so under TP the kernel runs INSIDE a shard_map whose specs name
         # the operands' existing sharding (heads/pool over 'model', tables/
@@ -2170,17 +2279,43 @@ class RaggedInferenceEngine:
             # only the gather fallback expands to per-token [T, max_pages]
             tables = None if use_pallas else block_tables[safe_slot]
 
+            # K/V (and scale) leaves are indexed by a layer's place among
+            # the layers that hold pages; the recurrent leaves, the pool's
+            # last two entries, by its place among the linear layers
             k_list, v_list = list(pools[0]), list(pools[1])
             ks_list = list(pools[2]) if kv_bits else None
             vs_list = list(pools[3]) if kv_bits else None
+            if state_layers:
+                from ..ops import gated_delta
 
-            def block(x, li, lp):
+                st_list, rows_list = list(pools[-2]), list(pools[-1])
+                runs = gated_delta.runs_of(slots, positions, cfg.max_seqs)
+
+            def after_mixer(x, attn, lp):
+                """Residual wiring and the feed-forward: the model's own
+                (its _mlp honors relu/gelu/gelu_exact/silu_glu and the MoE
+                override, top-k routed experts, uniformly)."""
+                return model._after_mixer(x[None], attn[None], None, lp,
+                                          None, False)[0][0]
+
+            def linear_block(x, at, lp):
+                with jax.named_scope("linear_attn"):
+                    attn, st_list[at], rows_list[at] = gated_delta.mix_ragged(
+                        x, lp, c, st_list[at], rows_list[at], runs)
+                return after_mixer(x, attn, lp)
+
+            def block(x, li, lp, window):
                 with jax.named_scope("attn"):
                     kp, vp = k_list[li], v_list[li]
-                    h = norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"))
-                    q = (h @ lp["wq"]).reshape(-1, c.n_heads, c.head_dim)
-                    kk = (h @ lp["wk"]).reshape(-1, c.n_kv_heads, c.head_dim)
-                    vv = (h @ lp["wv"]).reshape(-1, c.n_kv_heads, c.head_dim)
+                    h = x if c.branch_norm else \
+                        norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"))
+                    q, kk, vv = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+                    if c.qk_norm:   # over the whole projection, heads unsplit
+                        q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
+                        kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
+                    q = q.reshape(-1, c.n_heads, c.head_dim)
+                    kk = kk.reshape(-1, c.n_kv_heads, c.head_dim)
+                    vv = vv.reshape(-1, c.n_kv_heads, c.head_dim)
                     if c.qkv_bias:
                         q = q + lp["bq"].reshape(c.n_heads, c.head_dim)
                         kk = kk + lp["bk"].reshape(c.n_kv_heads, c.head_dim)
@@ -2232,20 +2367,20 @@ class RaggedInferenceEngine:
                         if use_pallas and self._tp_size > 1:
                             attn = _paged_attn_sharded(q, kp, vp, block_tables,
                                                        positions, safe_slot,
-                                                       live_pages, windows[li],
+                                                       live_pages, window,
                                                        ks=ksl, vs=vsl)
                         elif use_pallas:
                             attn = paged_attention(q, kp, vp, block_tables,
                                                    positions, seq_slots=safe_slot,
                                                    live_pages=live_pages,
-                                                   window=windows[li],
+                                                   window=window,
                                                    k_scale=ksl, v_scale=vsl,
                                                    kv_bits=kv_bits,
                                                    interpret=interp)
                         else:
                             attn = paged_attention_reference(q, kp, vp, tables,
                                                              positions,
-                                                             window=windows[li],
+                                                             window=window,
                                                              k_scale=ksl,
                                                              v_scale=vsl,
                                                              kv_bits=kv_bits)
@@ -2255,13 +2390,7 @@ class RaggedInferenceEngine:
                     # with a real o_proj bias (models/transformer.py:500)
                     if c.attn_o_bias:
                         attn = attn + lp["bo"]
-                    x = x + attn
-                with jax.named_scope("ffn"):
-                    h = norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
-                    # the model's own MLP: honors relu/gelu/gelu_exact/silu_glu
-                    # and the MoE override (top-k routed experts) uniformly
-                    down, _ = model._mlp(h[None], lp, None, False)
-                    return x + down[0]
+                return after_mixer(x, attn, lp)
 
             # python-unrolled layer loop, NOT lax.scan: a scan would carry
             # the whole pool, stacked or flat, and both were measured with
@@ -2269,12 +2398,16 @@ class RaggedInferenceEngine:
             # layout (the pool comment in __init__); not tried since
             for li in range(c.n_layers):
                 with jax.named_scope("weights"):
-                    lp = jax.tree_util.tree_map(lambda a: a[li],
-                                                params["layers"])
-                x = block(x, li, lp)
+                    kind, lp = model.layer_params(params["layers"], li)
+                if kind == "linear":
+                    x = linear_block(x, state_layers.index(li), lp)
+                else:
+                    x = block(x, page_layers.index(li), lp, windows[li])
             out_pools = (tuple(k_list), tuple(v_list))
             if kv_bits:
                 out_pools += (tuple(ks_list), tuple(vs_list))
+            if state_layers:
+                out_pools += (tuple(st_list), tuple(rows_list))
             return x, out_pools
 
         return core

@@ -94,6 +94,22 @@ class TransformerConfig:
     # InternLM: attention projections carry biases (incl. o_proj) while the
     # gated MLP does not — reference module_inject/containers/internlm.py:20
     attn_o_bias: Optional[bool] = None  # None -> follow use_bias
+    # Hybrid stacks (Olmo-Hybrid): a kind per layer, "full" (softmax
+    # attention) or "linear" (gated delta rule, ops/gated_delta.py) with the
+    # linear_* sizes below; None = every layer full. Two kinds of layer sit
+    # in the parameter tree as two stacks beside the common one
+    # (Transformer.init), and the depth loop is unrolled.
+    layer_types: Optional[Tuple[str, ...]] = None
+    linear_n_k_heads: int = 0
+    linear_n_v_heads: int = 0
+    linear_k_dim: int = 0
+    linear_v_dim: int = 0
+    linear_conv_kernel: int = 4
+    linear_neg_eigval: bool = False   # beta in (0, 2): negative eigenvalues
+    # OLMo-2/3 wiring: x + norm(sub(x)), no norm before a branch (not
+    # prenorm=False, which is norm(x + sub(x))); the final norm stays
+    branch_norm: bool = False
+    qk_norm: bool = False             # RMSNorm over the whole projected q, k
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -114,6 +130,18 @@ class TransformerConfig:
                 raise ValueError(
                     "attn_windows requires a causal model "
                     "(sliding windows are a decoder feature)")
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            assert len(self.layer_types) == self.n_layers, (
+                f"layer_types has {len(self.layer_types)} entries for "
+                f"{self.n_layers} layers")
+            assert set(self.layer_types) <= {"full", "linear"}, self.layer_types
+            if "linear" in self.layer_types:
+                assert self.linear_n_k_heads and self.linear_k_dim \
+                    and self.linear_v_dim, "linear layers need linear_* sizes"
+                self.linear_n_v_heads = self.linear_n_v_heads \
+                    or self.linear_n_k_heads
+                assert self.linear_n_v_heads % self.linear_n_k_heads == 0
         if self.d_ff is None:
             if self.activation == "silu_glu":
                 self.d_ff = int(8 * self.d_model / 3 / 128 + 1) * 128
@@ -131,6 +159,12 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        """Indices of the layers of that kind ("full" holds the KV pages,
+        "linear" a recurrent state), in depth order."""
+        kinds = self.layer_types or ("full",) * self.n_layers
+        return tuple(i for i, k in enumerate(kinds) if k == kind)
+
     @property
     def rotary_dim(self) -> int:
         """Rotated dims per head (GPT-NeoX rope_pct), even-rounded."""
@@ -145,6 +179,16 @@ class TransformerConfig:
             attn += self.n_heads * hd + 2 * self.n_kv_heads * hd
         if self.attn_o_bias:
             attn += d
+        if self.qk_norm:
+            attn += (self.n_heads + self.n_kv_heads) * hd
+        mixers = len(self.layers_of("full")) * attn
+        n_lin = len(self.layers_of("linear"))
+        if n_lin:   # the gated delta rule's leaves (_init_linear)
+            hk, hv = self.linear_n_k_heads, self.linear_n_v_heads
+            ch = 2 * hk * self.linear_k_dim + hv * self.linear_v_dim
+            mixers += n_lin * (
+                d * ch + self.linear_conv_kernel * ch + 2 * d * hv + 2 * hv
+                + 2 * d * hv * self.linear_v_dim + self.linear_v_dim)
         norms = (2 * d) * n + (d if self.prenorm else 0)
         if self.norm == "layer":
             norms *= 2  # weights + biases
@@ -159,7 +203,7 @@ class TransformerConfig:
             head += d * d + d + 2 * d + v  # transform + LN + decoder bias
         if self.pooler:
             head += d * d + d
-        return n * attn + norms + emb + head
+        return mixers + norms + emb + head
 
     def param_count(self) -> int:
         d, f, n = self.d_model, self.d_ff, self.n_layers
@@ -175,7 +219,7 @@ class TransformerConfig:
         if self.attn_windows is not None:
             return sum(min(seq_len, w) if w > 0 else seq_len
                        for w in self.attn_windows)
-        return self.n_layers * seq_len
+        return len(self.layers_of("full")) * seq_len
 
     def flops_per_token(self, seq_len: int) -> float:
         """Forward+backward FLOPs/token (standard 6N + attention term)."""
@@ -239,12 +283,18 @@ class Transformer:
             return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
         n = c.n_layers
+        # the mixers' leaves are stacked over the layers of their kind: with
+        # layer_types, attention's under "full" and the delta rule's under
+        # "linear"; what every layer has stays stacked over all n
+        nf = len(c.layers_of("full"))
+        attn: Dict[str, Any] = {
+            "wq": dense(next(k), (nf, c.d_model, c.n_heads * hd)),
+            "wk": dense(next(k), (nf, c.d_model, c.n_kv_heads * hd)),
+            "wv": dense(next(k), (nf, c.d_model, c.n_kv_heads * hd)),
+            "wo": dense(next(k), (nf, c.n_heads * hd, c.d_model), scale=1.0 / np.sqrt(c.d_model * 2 * n)),
+        }
         layers: Dict[str, Any] = {
             "attn_norm_w": jnp.ones((n, c.d_model), dtype),
-            "wq": dense(next(k), (n, c.d_model, c.n_heads * hd)),
-            "wk": dense(next(k), (n, c.d_model, c.n_kv_heads * hd)),
-            "wv": dense(next(k), (n, c.d_model, c.n_kv_heads * hd)),
-            "wo": dense(next(k), (n, c.n_heads * hd, c.d_model), scale=1.0 / np.sqrt(c.d_model * 2 * n)),
             "mlp_norm_w": jnp.ones((n, c.d_model), dtype),
             "w_up": dense(next(k), (n, c.d_model, c.d_ff)),
             "w_down": dense(next(k), (n, c.d_ff, c.d_model), scale=1.0 / np.sqrt(c.d_ff * 2 * n)),
@@ -255,14 +305,26 @@ class Transformer:
             layers["attn_norm_b"] = jnp.zeros((n, c.d_model), dtype)
             layers["mlp_norm_b"] = jnp.zeros((n, c.d_model), dtype)
         if c.qkv_bias:
-            layers["bq"] = jnp.zeros((n, c.n_heads * hd), dtype)
-            layers["bk"] = jnp.zeros((n, c.n_kv_heads * hd), dtype)
-            layers["bv"] = jnp.zeros((n, c.n_kv_heads * hd), dtype)
+            attn["bq"] = jnp.zeros((nf, c.n_heads * hd), dtype)
+            attn["bk"] = jnp.zeros((nf, c.n_kv_heads * hd), dtype)
+            attn["bv"] = jnp.zeros((nf, c.n_kv_heads * hd), dtype)
         if c.attn_o_bias:
-            layers["bo"] = jnp.zeros((n, c.d_model), dtype)
+            attn["bo"] = jnp.zeros((nf, c.d_model), dtype)
+        if c.qk_norm:
+            attn["q_norm_w"] = jnp.ones((nf, c.n_heads * hd), dtype)
+            attn["k_norm_w"] = jnp.ones((nf, c.n_kv_heads * hd), dtype)
         if c.use_bias:
             layers["b_up"] = jnp.zeros((n, c.d_ff), dtype)
             layers["b_down"] = jnp.zeros((n, c.d_model), dtype)
+        if c.layer_types is None:
+            layers.update(attn)
+        else:
+            if nf:
+                layers["full"] = attn
+            nl = n - nf
+            if nl:
+                layers["linear"] = self._init_linear(
+                    jax.random.fold_in(rng, 1), nl, dense, dtype)
 
         params: Dict[str, Any] = {
             "tok_embed": dense(next(k), (c.vocab_size, c.d_model), scale=0.02),
@@ -291,6 +353,49 @@ class Transformer:
             params["pooler_w"] = dense(next(k), (c.d_model, c.d_model))
             params["pooler_b"] = jnp.zeros((c.d_model,), dtype)
         return params
+
+    def _init_linear(self, rng, nl: int, dense, dtype) -> Dict[str, Any]:
+        """The gated-delta-rule mixer's leaves (ops/gated_delta.py), stacked
+        over the ``nl`` linear layers. ``A_log`` and ``dt_bias`` follow the
+        layer's published initialisation: A uniform in (0, 16), the step
+        softplus(dt_bias) log-uniform in (1e-3, 1e-1)."""
+        c = self.config
+        hk, hv = c.linear_n_k_heads, c.linear_n_v_heads
+        dk, dv, d = c.linear_k_dim, c.linear_v_dim, c.d_model
+        k = iter(jax.random.split(rng, 10))
+        dt = jnp.exp(jax.random.uniform(next(k), (nl, hv), jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        return {
+            "wq": dense(next(k), (nl, d, hk * dk)),
+            "wk": dense(next(k), (nl, d, hk * dk)),
+            "wv": dense(next(k), (nl, d, hv * dv)),
+            "conv_w": dense(next(k), (nl, c.linear_conv_kernel,
+                                      2 * hk * dk + hv * dv)),
+            "w_a": dense(next(k), (nl, d, hv)),
+            "w_beta": dense(next(k), (nl, d, hv)),
+            "A_log": jnp.log(jax.random.uniform(
+                next(k), (nl, hv), jnp.float32, 1e-3, 16.0)).astype(dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "w_z": dense(next(k), (nl, d, hv * dv)),
+            "o_norm_w": jnp.ones((nl, dv), dtype),
+            "wo": dense(next(k), (nl, hv * dv, d),
+                        scale=1.0 / np.sqrt(d * 2 * c.n_layers)),
+        }
+
+    def layer_params(self, layers, li: int) -> Tuple[str, Dict[str, Any]]:
+        """(kind, the leaves of layer ``li``) out of the stacked tree: the
+        common stack at ``li`` and, with layer_types, its kind's stack at
+        the layer's index among its kind. ``li`` is a python int: the slice
+        is static, and XLA reads it in place."""
+        c = self.config
+        if c.layer_types is None:
+            return "full", jax.tree_util.tree_map(lambda a: a[li], layers)
+        kind = c.layer_types[li]
+        at = c.layers_of(kind).index(li)
+        lp = {k: v[li] for k, v in layers.items()
+              if k not in ("full", "linear")}
+        lp.update({k: v[at] for k, v in layers[kind].items()})
+        return kind, lp
 
     # ------------------------------------------------------------------
     def _norm(self, x, w, b=None):
@@ -381,7 +486,7 @@ class Transformer:
                                                          causal=causal)
 
     def _block(self, x, lp, angles, positions, kv_cache=None, rng=None, training=False,
-               attn_mask=None, attn_window=None):
+               attn_mask=None, attn_window=None, kind: str = "full"):
         """One transformer block. x: [b, s, d]. Returns (x, new_kv, aux).
 
         ``attn_mask``: optional [b, s] padding mask (1 = attend) for the
@@ -399,6 +504,14 @@ class Transformer:
                 "attn_mask with a causal model is not supported (padding "
                 "masks are an encoder feature; causal batches should pack "
                 "or left-trim instead)")
+        if kind == "linear":
+            # a recurrent layer: the gated delta rule takes attention's
+            # place, under a scope of its own (conv, delta_chunk inside)
+            from ..ops import gated_delta
+
+            with jax.named_scope("linear_attn"):
+                attn = gated_delta.mix_dense(x, lp, c)
+            return self._after_mixer(x, attn, None, lp, rng, training)
 
         # device scopes (jax.named_scope: metadata only) name the block's
         # parts in a profiler trace: attn (flash_attention around the
@@ -407,12 +520,15 @@ class Transformer:
             # pre-LN normalizes the branch input; post-LN (BERT-era,
             # prenorm=False) runs the branch on x and norms AFTER the residual
             h = self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
-                if c.prenorm else x
+                if c.prenorm and not c.branch_norm else x
             q = h @ lp["wq"]
             kk = h @ lp["wk"]
             vv = h @ lp["wv"]
             if c.qkv_bias:
                 q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
+            if c.qk_norm:
+                q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
+                kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
             q = q.reshape(b, s, c.n_heads, hd)
             kk = kk.reshape(b, s, c.n_kv_heads, hd)
             vv = vv.reshape(b, s, c.n_kv_heads, hd)
@@ -518,6 +634,19 @@ class Transformer:
             if c.attn_o_bias:
                 attn = attn + lp["bo"]
 
+        return self._after_mixer(x, attn, new_kv, lp, rng, training)
+
+    def _after_mixer(self, x, attn, new_kv, lp, rng, training):
+        """The block from its mixer's output on: residual wiring and the
+        feed-forward. Returns (x, new_kv, aux)."""
+        c = self.config
+        if c.branch_norm:  # OLMo-2/3: the norm sits on the branch's output
+            x = x + self._norm(attn, lp["attn_norm_w"], lp.get("attn_norm_b"))
+            with jax.named_scope("ffn"):
+                down, aux = self._mlp(x, lp, rng, training)
+                return x + self._norm(down, lp["mlp_norm_w"],
+                                      lp.get("mlp_norm_b")), new_kv, aux
+
         if c.parallel_residual:
             # GPT-J / GPT-NeoX: both branches read the SAME input x
             # (GPT-J's single shared LN arrives as duplicated norm params)
@@ -618,14 +747,32 @@ class Transformer:
             static_window, aw = aw[0], None
         windows = jnp.asarray(aw, jnp.int32) if aw is not None else None
 
-        def block(x, lp, r, w):
-            return self._block(x, lp, angles, positions, None, r, training,
-                               attn_mask, static_window if w is None else w)
+        def block_of(kind):
+            def block(x, lp, r, w):
+                return self._block(x, lp, angles, positions, None, r,
+                                   training, attn_mask,
+                                   static_window if w is None else w, kind)
 
-        if c.remat:
-            from ..runtime.activation_checkpointing import checkpoint_wrapper
+            if c.remat:
+                from ..runtime.activation_checkpointing import \
+                    checkpoint_wrapper
 
-            block = checkpoint_wrapper(block, policy=c.remat_policy)
+                return checkpoint_wrapper(block, policy=c.remat_policy)
+            return block
+
+        if c.layer_types is not None:
+            # two kinds of layer have two shapes of leaves: no one scan body
+            # fits both, so depth is unrolled (compile time grows with it)
+            blocks = {kind: block_of(kind) for kind in set(c.layer_types)}
+            aux_total = jnp.zeros((), jnp.float32)
+            for li in range(c.n_layers):
+                kind, lp = self.layer_params(params["layers"], li)
+                layer_rng, sub = jax.random.split(layer_rng)
+                x, _, aux = blocks[kind](
+                    x, lp, sub, None if windows is None else windows[li])
+                aux_total = aux_total + aux
+            return x, aux_total
+        block = block_of("full")
 
         def scan_fn(carry, xs):
             y, r = carry
@@ -656,6 +803,10 @@ class Transformer:
         c = self.config
         if kv_caches is not None and not c.causal:
             raise ValueError("KV-cache decode requires a causal model")
+        if kv_caches is not None and c.layer_types is not None:
+            raise NotImplementedError(
+                "the dense KV cache holds no recurrent state: serve a model "
+                "with linear layers through RaggedInferenceEngine")
         x = self._embed(params, tokens, positions, token_type_ids)  # [b, s, d]
         angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta) \
             if c.position == "rope" else None
@@ -821,7 +972,7 @@ class Transformer:
         expressed as a matmul instead of masked gather + allreduce).
         """
         c = self.config
-        compute_dtype = params["layers"]["wq"].dtype
+        compute_dtype = params["layers"]["w_up"].dtype
         if self._tp_size > 1:
             # clip for parity with the gather branch (jnp indexing clamps
             # out-of-range ids; unclipped one_hot would zero them instead)
@@ -897,6 +1048,10 @@ class Transformer:
         c = self.config
         assert self._pipe_size > 1 and self._mesh is not None, \
             "pipeline_loss requires a bound topology with pipe axis > 1"
+        if c.layer_types is not None:
+            raise NotImplementedError(
+                "the pipeline stage scan runs one kind of layer; a hybrid "
+                "stack (layer_types) is not plumbed through it")
         if self._seq_size > 1:
             raise NotImplementedError(
                 "pipe x seq parallel composition not supported yet; "
@@ -1018,6 +1173,27 @@ class Transformer:
             layer_specs.update({
                 "b_up": P(pipe, "model"), "b_down": P(pipe, None),
             })
+        if c.qk_norm:
+            layer_specs.update({"q_norm_w": P(pipe, None),
+                                "k_norm_w": P(pipe, None)})
+        if c.layer_types is not None:
+            # the mixers' stacks: attention's leaves move under "full", the
+            # delta rule's are column-parallel in, row-parallel out, and
+            # its small per-head leaves replicated
+            attn_keys = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+                         "q_norm_w", "k_norm_w")
+            full = {k: layer_specs.pop(k) for k in attn_keys
+                    if k in layer_specs}
+            if c.layers_of("full"):
+                layer_specs["full"] = full
+            if c.layers_of("linear"):
+                col, rep = P(pipe, None, "model"), P(pipe, None)
+                layer_specs["linear"] = {
+                    "wq": col, "wk": col, "wv": col, "w_z": col,
+                    "conv_w": P(pipe, None, None), "w_a": P(pipe, None, None),
+                    "w_beta": P(pipe, None, None), "A_log": rep,
+                    "dt_bias": rep, "o_norm_w": rep,
+                    "wo": P(pipe, "model", None)}
         specs: Dict[str, Any] = {
             "tok_embed": P("model", None),
             "layers": layer_specs,

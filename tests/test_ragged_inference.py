@@ -911,3 +911,22 @@ def test_row_write_lands_where_the_head_window_scatter_did(entry, kv_quant,
         assert a.shape[0] == n + 1 and a.dtype == b.dtype
         np.testing.assert_array_equal(a[:n], b[:n])
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("held", ["rows", "one_row", "nothing"])
+def test_put_reuses_its_rows_buffer_only_once_released(held):
+    """put() hands back a view of a buffer it keeps (a new [n, vocab]
+    array every tick is megabytes of first-touched pages): the next call
+    writes into the same buffer only when nothing refers to the last
+    call's rows, or to a row of them, any more."""
+    eng = RaggedInferenceEngine(_llama(), _cfg())
+    rows = eng.put([1, 2], [[1, 2, 3], [4, 5]])
+    was, before = id(rows.base), rows.copy()   # an id holds no reference
+    keep = {"rows": rows, "one_row": rows[1], "nothing": None}[held]
+    del rows
+    again = eng.put([1, 2], [[7], [8]])
+    assert (id(again.base) == was) == (held == "nothing")
+    if keep is not None:     # what the caller still holds is untouched
+        np.testing.assert_array_equal(keep, before if held == "rows"
+                                      else before[1])
+    assert not np.isnan(again).any() and again.shape == (2, 128)

@@ -14,7 +14,9 @@ decode shape; the int4-KV refusal), one whole train step and one ragged
 serving step of the smoke model at reduced depth, the four-chip
 ZeRO-3 step ``chip_smoke.py --chips 4`` runs, and what the compiled
 serving step does to its KV pool (rows written in place, on one chip and
-with the pool sharded over two).
+with the pool sharded over two), and the 12-layer Olmo-Hybrid step of the
+benchmark's cell with both of its caches (fits, copies no leaf and no
+weight).
 
 One file on purpose: only the xdist worker that gets this file loads
 libtpu, inside the module-scoped ``topo`` fixture — never at import.
@@ -412,6 +414,104 @@ def test_ragged_step_writes_pool_in_place(one_chip, on_tpu, T, kv_quant):
         leaf_bytes = (POOL_PAGES + 1) * HKV * BLK * HD \
             * (1 if kv_quant == "int8" else 2)
         assert c.memory_analysis().temp_size_in_bytes < leaf_bytes
+
+
+# ----------------------------------------------------------------------
+# Olmo-Hybrid-7B as the benchmark serves it: 12 layers, two kinds of cache
+def compile_olmo_hybrid_step(device_sharding, T: int, live_pages: int):
+    """The SplitFuse step of ``benchmarks/configs/olmo-hybrid-7b.json``
+    with the pools its ``engine`` asks for (4096 pages over the 3 full
+    layers, the state pool of 64 slots over the 9 linear ones). The engine
+    is built under ``eval_shape``: its pools are shapes, no byte is held."""
+    import json
+
+    from benchmarks import harness
+    from deepspeed_tpu.inference.ragged import (RaggedConfig,
+                                                RaggedInferenceEngine)
+
+    cfg = json.load(open(os.path.join(harness.HERE, "configs",
+                                      "olmo-hybrid-7b.json")))
+    e = cfg["engine"]
+    model = harness.find("architectures", cfg["architecture"]).build(
+        cfg, cfg["num_hidden_layers"])
+    made = {}
+
+    def build():
+        made["engine"] = RaggedInferenceEngine(
+            model, RaggedConfig(token_budget=e["token_budget"],
+                                max_seqs=e["max_seqs"],
+                                kv_block_size=e["kv_block_size"],
+                                n_kv_blocks=e["max_kv_blocks"],
+                                max_context=e["max_context"]), params={})
+        return made["engine"].kv_pool
+
+    pool = jax.eval_shape(build)
+    eng = made["engine"]
+    assert eng.attention_path == "pallas"
+    params = jax.eval_shape(partial(model.init, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    lanes = _sds((T,), jnp.int32, device_sharding)
+    compiled = eng._build_step().lower(
+        _place(params, device_sharding), _place(pool, device_sharding),
+        lanes, lanes, lanes,
+        _sds((e["max_seqs"], eng.max_pages), jnp.int32, device_sharding),
+        _sds((e["max_seqs"],), jnp.int32, device_sharding),
+        live_pages).compile()
+    return compiled, model.config, e
+
+
+def _entry_instructions(hlo: str):
+    """(name, dtype, dims, opcode, line) of the ENTRY computation's
+    array-valued instructions (what runs as an operation of its own, not
+    inside a fusion)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    pat = re.compile(r"^\s*(?:ROOT )?%(\S+) = \(?(\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(")
+    for line in entry.splitlines():
+        m = pat.match(line)
+        if m:
+            yield m.groups() + (line,)
+
+
+@pytest.mark.parametrize("T", [64, 1024], ids=["decode", "prefill_chunk"])
+def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
+    """Rehearsal 3 for the cell ``olmo-hybrid-7b.reason``: the 12-layer
+    step at the decode shape and at a 1024-lane shape fits one chip beside
+    its pools; the paged kernel runs in the 3 full layers only; each state
+    leaf is written by one fusion a layer and nothing copies, transposes
+    or slices a pool leaf or a state leaf; and no weight matrix is copied
+    out of its stack (the per-layer slices of the three stacks, common,
+    ``full`` and ``linear``, are read in place by the products that use
+    them: what Mixtral's expert stacks pay 17 ms a tick for, PERF.md
+    section 5, this layout does not pay)."""
+    compiled, c, e = compile_olmo_hybrid_step(one_chip, T, 128)
+    assert _device_bytes(compiled) < 15.75e9
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == len(c.layers_of("full")) == 3
+    state = f"{e['max_seqs'] + 1},30,96,192"
+    rows = f"{e['max_seqs'] + 1},3,11520"
+    page = f"{e['max_kv_blocks'] + 1},30,{e['kv_block_size']},128"
+    moved = [(op, dims, name) for name, dt, dims, op, line
+             in _entry_instructions(hlo)
+             if op in ("copy", "transpose", "slice", "dynamic-slice",
+                       "gather", "concatenate")
+             and dims in (state, rows, page)]
+    assert not moved, moved
+    writes = [name for name, dt, dims, op, _ in _entry_instructions(hlo)
+              if (dt, dims, op) == ("f32", state, "fusion")]
+    assert len(writes) == len(c.layers_of("linear")) == 9, writes
+    # a weight matrix has at least hidden x (linear heads x key dim)
+    # elements; S(1) marks the compiler's own prefetch of an operand into
+    # on-chip memory, which is no copy of the layout's making
+    weight = c.d_model * c.linear_n_k_heads * c.linear_k_dim
+    copied = [(op, dt, dims, name) for name, dt, dims, op, line
+              in _entry_instructions(hlo)
+              if op in ("copy", "slice", "dynamic-slice") and dims
+              and "S(1)" not in line.split(" " + op + "(")[0]
+              and dt == "bf16" and T not in map(int, dims.split(","))
+              and np.prod(list(map(int, dims.split(",")))) >= weight]
+    assert not copied, copied
 
 
 def test_tp2_ragged_step_gathers_no_pool_leaf(topo, on_tpu):
