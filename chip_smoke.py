@@ -87,6 +87,8 @@ class Sizes:
     kernel_seq: int = 2048
     kernel_pages_per_seq: int = 128
     kernel_n_seqs: int = 64
+    # a second head shape for the paged kernel: Olmo-Hybrid's full layers
+    kernel_alt_heads: Tuple[int, int] = (30, 30)
     # --chips 4: global batch, split four ways under ZeRO-3
     zero3_layers: int = 1
     zero3_batch: int = 4
@@ -264,16 +266,19 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
         errs[f"flash_bwd_{name}"] = _rel_err(g, r)
 
     # paged attention, per-sequence tables + slot indirection (the ragged
-    # engine's call shape): a SplitFuse prefill chunk and a decode step
+    # engine's call shape) at the serving cells' head shapes: a SplitFuse
+    # prefill chunk, a decode step, and a tick that holds both; then the
+    # sliding-window band and the int8-KV pool on the Mistral shape
+    from deepspeed_tpu.ops.quantizer import quantize_kv
+
     blk, mp, ns = sz.kv_block_size, sz.kernel_pages_per_seq, sz.kernel_n_seqs
     n_pages = ns * mp
     rng = np.random.default_rng(seed)
-    kp1, kp2 = jax.random.split(kp)
-    k_pool = jax.random.normal(kp1, (n_pages + 1, hkv, blk, hd), jnp.bfloat16)
-    v_pool = jax.random.normal(kp2, (n_pages + 1, hkv, blk, hd), jnp.bfloat16)
     tables = jnp.asarray(rng.permutation(n_pages).reshape(ns, mp), jnp.int32)
     ctx = mp * blk
     half = sz.token_budget // 2
+    n_dec = ns // 3
+    chunk = half - n_dec - half // 8       # lanes left over are not live
     shapes = {
         # two sequences, one mid-context chunk and one from position 0
         "paged_prefill": (
@@ -283,27 +288,57 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
         # one token per sequence at mixed context lengths
         "paged_decode": (np.arange(ns, dtype=np.int32),
                          rng.integers(0, ctx, (ns,)).astype(np.int32)),
+        # decode lanes, then a prompt chunk mid-context, then lanes of no
+        # sequence: what most ticks with a prompt in them look like
+        "paged_mixed": (
+            np.concatenate([rng.permutation(ns - 1)[:n_dec] + 1,
+                            np.zeros(chunk), -np.ones(half - n_dec - chunk)]
+                           ).astype(np.int32),
+            np.concatenate([rng.integers(0, ctx, (n_dec,)),
+                            ctx // 3 + np.arange(chunk),
+                            np.zeros(half - n_dec - chunk)]).astype(np.int32)),
     }
-    kernel = jax.jit(lambda q, s, p, kp, vp, tb: paged_attention(
-        q, kp, vp, tb, p, seq_slots=s, live_pages=mp, interpret=interpret))
-    oracle = jax.jit(lambda q, s, p, kp, vp, tb: paged_attention_reference(
-        q, kp, vp, tb[s], p))
-    for name, (slots, pos) in shapes.items():
-        T = len(slots)
-        qd = jax.random.normal(jax.random.fold_in(kq, T), (T, hq, hd),
-                               jnp.bfloat16)
-        got = kernel(qd, jnp.asarray(slots), jnp.asarray(pos), k_pool,
-                     v_pool, tables)
-        # the gather oracle materializes [lanes, ctx, heads, hd] in fp32:
-        # check a 32-lane sample spread over the batch
-        lanes = np.linspace(0, T - 1, 32).astype(np.int32)
-        want = oracle(qd[lanes], jnp.asarray(slots[lanes]),
-                      jnp.asarray(pos[lanes]), k_pool, v_pool, tables)
-        errs[name] = _rel_err(got[lanes], want)
-        _check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
-               f"{name}: non-finite kernel output")
+    window = min(1024, ctx // 2)
+    variants = {"": (hq, hkv, 0, False), "_h30": (*sz.kernel_alt_heads, 0, False),
+                f"_w{window}": (hq, hkv, window, False),
+                "_int8": (hq, hkv, 0, True)}
+    for tag, (nq, nkv, win, quant) in variants.items():
+        kp1, kp2 = jax.random.split(jax.random.fold_in(kp, nkv))
+        k_pool = jax.random.normal(kp1, (n_pages + 1, nkv, blk, hd), jnp.bfloat16)
+        v_pool = jax.random.normal(kp2, (n_pages + 1, nkv, blk, hd), jnp.bfloat16)
+        scales = ()
+        if quant:
+            (k_pool, ks), (v_pool, vs) = (quantize_kv(k_pool, 8),
+                                          quantize_kv(v_pool, 8))
+            scales = (ks, vs)
+        kw = lambda sc: dict(k_scale=sc[0], v_scale=sc[1], kv_bits=8) \
+            if sc else {}
+        kernel = jax.jit(lambda q, s, p, kp, vp, tb, sc, win=win: paged_attention(
+            q, kp, vp, tb, p, seq_slots=s, live_pages=mp, window=win,
+            interpret=interpret, **kw(sc)))
+        oracle = jax.jit(lambda q, s, p, kp, vp, tb, sc, win=win:
+                         paged_attention_reference(q, kp, vp, tb[s], p,
+                                                   window=win, **kw(sc)))
+        for name, (slots, pos) in shapes.items():
+            T = len(slots)
+            qd = jax.random.normal(jax.random.fold_in(kq, T), (T, nq, hd),
+                                   jnp.bfloat16)
+            got = kernel(qd, jnp.asarray(slots), jnp.asarray(pos), k_pool,
+                         v_pool, tables, scales)
+            # the gather oracle materializes [lanes, ctx, heads, hd] in
+            # fp32: check a 32-lane sample spread over the live lanes
+            live = np.flatnonzero(slots >= 0)
+            lanes = live[np.linspace(0, len(live) - 1, 32).astype(np.int32)]
+            want = oracle(qd[lanes], jnp.asarray(slots[lanes]),
+                          jnp.asarray(pos[lanes]), k_pool, v_pool, tables,
+                          scales)
+            errs[name + tag] = _rel_err(got[lanes], want)
+            _check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
+                   f"{name + tag}: non-finite kernel output")
     rec.update(shape={"flash": [1, S, f"{hq}/{hkv}", hd],
                       "paged": {n: len(s[0]) for n, s in shapes.items()},
+                      "paged_variants": {t or "bf16": list(v[:2])
+                                         for t, v in variants.items()},
                       "pages_per_seq": mp, "kv_block": blk},
                rel_err={n: round(e, 5) for n, e in errs.items()},
                tolerance=KERNEL_REL_TOL)

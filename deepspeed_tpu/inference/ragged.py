@@ -1610,16 +1610,25 @@ class RaggedInferenceEngine:
         """``ragged.put``'s attributes, read after allocation and before
         ``seen`` advances: the lane bucket, the live-page bucket, entries
         scheduled, lanes given to sequences still inside their prompt,
-        single-token entries past it, and pages left free."""
+        single-token entries past it, pages left free, and the work the
+        paged kernel is asked for in each layer: query tiles, and KV steps
+        (a tile's live chunks, summed), to set beside the ``lanes * pages
+        / 16`` steps of a grid over lanes and the page bucket."""
+        from ..ops.pallas.paged_attention import query_tile, tile_counts
+
         prefill = decode = 0
         for seq, take in sched:
             if seq.seen < seq.prompt_len:
                 prefill += take
             elif take == 1:
                 decode += 1
+        q_tiles, kv_steps = tile_counts(
+            [(take, seq.seen) for seq, take in sched], query_tile(lanes),
+            self.config.kv_block_size)
         attrs = {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
                  "prefill": prefill, "decode": decode,
-                 "free": self.allocator.free_blocks}
+                 "free": self.allocator.free_blocks,
+                 "q_tiles": q_tiles, "kv_steps": kv_steps}
         if self._state_layers:    # slots whose recurrent state is live
             attrs["state_slots"] = len(self.seqs)
         return attrs
@@ -2197,7 +2206,7 @@ class RaggedInferenceEngine:
         both the SplitFuse ``put`` step and the multi-step decode loop."""
         from ..ops.pallas.paged_attention import (paged_attention,
                                                   paged_attention_reference,
-                                                  write_kv_rows)
+                                                  work_list, write_kv_rows)
 
         model = self.model
         c = model.config
@@ -2231,30 +2240,32 @@ class RaggedInferenceEngine:
 
         kv_bits = self._kv_bits
 
-        def _paged_attn_sharded(q, kp, vp, tables, positions, slots,
+        def _paged_attn_sharded(q, kp, vp, tables, positions, slots, work,
                                 live_pages, window, ks=None, vs=None):
             """shard_map the paged kernel over the bound mesh: heads and
             pool (payload AND scale leaves; dim 1 is heads) sharded on
-            'model', scalars replicated. Every mesh axis is manual: Mosaic
-            refuses to lower a kernel under a partly automatic mesh."""
+            'model', scalars (the step's work list among them) replicated.
+            Every mesh axis is manual: Mosaic refuses to lower a kernel
+            under a partly automatic mesh."""
             from jax.sharding import PartitionSpec as P_
 
             sharded = (q, kp, vp) + (() if ks is None else (ks, vs))
             heads = lambda a: P_(None, "model", *(None,) * (a.ndim - 2))
 
             def local(q, kp, vp, *rest):
-                *sc, tb, pos, sl = rest
+                *sc, tb, pos, sl, wk = rest
                 quant = dict(k_scale=sc[0], v_scale=sc[1],
                              kv_bits=kv_bits) if sc else {}
                 return paged_attention(q, kp, vp, tb, pos, seq_slots=sl,
-                                       live_pages=live_pages, window=window,
-                                       interpret=interp, **quant)
+                                       work=wk, live_pages=live_pages,
+                                       window=window, interpret=interp,
+                                       **quant)
 
             return jax.shard_map(
                 local, mesh=self.topo.mesh,
-                in_specs=tuple(map(heads, sharded)) + (P_(),) * 3,
+                in_specs=tuple(map(heads, sharded)) + (P_(),) * 4,
                 out_specs=heads(q), check_vma=False)(
-                    *sharded, tables, positions, slots)
+                    *sharded, tables, positions, slots, work)
 
         def norm(x, w, b=None):
             return rms_norm(x, w, c.norm_eps) if c.norm == "rms" \
@@ -2278,6 +2289,10 @@ class RaggedInferenceEngine:
             # directly (scalar prefetch stays O(seqs * pages), SMEM-sized);
             # only the gather fallback expands to per-token [T, max_pages]
             tables = None if use_pallas else block_tables[safe_slot]
+            # the kernel's query tiles, from slots and positions: once a
+            # step, for every layer's call
+            work = work_list(slots, positions, cfg.max_seqs) \
+                if use_pallas else None
 
             # K/V (and scale) leaves are indexed by a layer's place among
             # the layers that hold pages; the recurrent leaves, the pool's
@@ -2362,16 +2377,18 @@ class RaggedInferenceEngine:
                     # paged attention: Pallas kernel on TPU (scalar-prefetched
                     # block tables, zero gather); jnp gather path elsewhere.
                     # (positions <= ctx-1 always, so the causal mask subsumes the
-                    # context-length mask; inactive lanes produce ignored junk)
+                    # context-length mask; inactive lanes produce ignored
+                    # rows: zeros from the kernel's tiles, junk elsewhere)
                     with jax.named_scope("paged_attention"):
                         if use_pallas and self._tp_size > 1:
                             attn = _paged_attn_sharded(q, kp, vp, block_tables,
-                                                       positions, safe_slot,
+                                                       positions, slots, work,
                                                        live_pages, window,
                                                        ks=ksl, vs=vsl)
                         elif use_pallas:
                             attn = paged_attention(q, kp, vp, block_tables,
-                                                   positions, seq_slots=safe_slot,
+                                                   positions, seq_slots=slots,
+                                                   work=work,
                                                    live_pages=live_pages,
                                                    window=window,
                                                    k_scale=ksl, v_scale=vsl,

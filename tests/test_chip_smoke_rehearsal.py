@@ -25,6 +25,7 @@ def _tiny() -> chip_smoke.Sizes:
         prompt_lens=(9, 40, 100), shared_prefix=32, probe_len=20,
         new_tokens=4,
         kernel_seq=256, kernel_pages_per_seq=8, kernel_n_seqs=8,
+        kernel_alt_heads=(3, 3),
         zero3_layers=2, zero3_batch=4, zero3_steps=2)
 
 
@@ -38,9 +39,11 @@ def test_kernels_phase_interpret(ledger, capsys):
         chip_smoke.phase_kernels(_tiny(), 0, rec, interpret=True)
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["phase"] == "kernels"
+    paged = {f"paged_{shape}{variant}"
+             for shape in ("prefill", "decode", "mixed")
+             for variant in ("", "_h30", "_w64", "_int8")}
     assert set(line["rel_err"]) == {"flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dk", "flash_bwd_dv",
-                                    "paged_prefill", "paged_decode"}
+                                    "flash_bwd_dk", "flash_bwd_dv"} | paged
 
 
 def test_train_phase(ledger):

@@ -220,3 +220,159 @@ def test_paged_quantized_int4_packed_shape():
                                     k_scale=sk, v_scale=sv, kv_bits=4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# the grid over query tiles (head_dim a whole number of 128-lane tiles):
+# one grid step a (stretch of one sequence's lanes, that stretch's chunks)
+# ----------------------------------------------------------------------
+from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+TQ = 8          # tile rows in these tests (the engine's come from query_tile)
+PPC = 2         # pages a chunk: 32-token chunks, so short contexts span many
+
+
+def _ragged_batch(runs, T, n_seqs, *, hq=4, hkv=1, hd=128, block=16,
+                  max_pages=12, seed=0, dtype=jnp.float32):
+    """runs: (slot, first position, lanes) in batch order; lanes past the
+    last run are not live. Per-sequence tables over distinct pages."""
+    rng = np.random.default_rng(seed)
+    slots = np.full(T, -1, np.int32)
+    pos = np.zeros(T, np.int32)
+    c = 0
+    for s, p0, n in runs:
+        slots[c:c + n] = s
+        pos[c:c + n] = np.arange(p0, p0 + n)
+        c += n
+    n_pages = n_seqs * max_pages
+    q = jnp.asarray(rng.standard_normal((T, hq, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((n_pages + 1, hkv, block, hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((n_pages + 1, hkv, block, hd)), dtype)
+    tables = jnp.asarray(rng.permutation(n_pages).reshape(n_seqs, max_pages),
+                         jnp.int32)
+    return q, kp, vp, tables, slots, pos
+
+
+def _check_tiled(runs, T, n_seqs, *, window=0, tol=2e-5, quant=None, **kw):
+    q, kp, vp, tables, slots, pos = _ragged_batch(runs, T, n_seqs, **kw)
+    scales = {}
+    if quant:
+        kp, vp, sk, sv = _quantize_pools(kp, vp, quant)
+        scales = dict(k_scale=sk, v_scale=sv, kv_bits=quant)
+    got = np.asarray(paged_attention(
+        q, kp, vp, tables, jnp.asarray(pos), seq_slots=jnp.asarray(slots),
+        window=window, tile_rows=TQ, pages_per_chunk=PPC, interpret=True,
+        **scales))
+    live = slots >= 0
+    assert np.isfinite(got).all()
+    assert not got[~live].any()            # lanes of no sequence: zeros
+    if live.any():
+        want = paged_attention_reference(
+            q[live], kp, vp, tables[slots[live]], jnp.asarray(pos[live]),
+            window=window, **scales)
+        np.testing.assert_allclose(got[live], np.asarray(want), rtol=tol,
+                                   atol=tol)
+    return pa.work_list(jnp.asarray(slots), jnp.asarray(pos), n_seqs, TQ)
+
+
+@pytest.mark.parametrize("length", [1, TQ - 1, TQ, TQ + 1, 3 * TQ + 5])
+@pytest.mark.parametrize("lead", [0, 3], ids=["aligned", "offset"])
+def test_tiled_runs_straddle_tile_edges(length, lead):
+    """A run of every length around a tile's, behind ``lead`` decode lanes
+    so that it starts on a block's edge or inside a block."""
+    runs = [(1 + i, 20 + 7 * i, 1) for i in range(lead)] + [(0, 5, length)]
+    _check_tiled(runs, 48, 4)
+
+
+@pytest.mark.parametrize("group,hkv", [(4, 2), (1, 3)], ids=["gqa4", "mha"])
+def test_tiled_decode_lanes_and_a_prompt_chunk(group, hkv):
+    """Decode lanes, a (k + 1)-lane speculative run and a prompt chunk in
+    one batch, slots out of slot order, inactive lanes behind them."""
+    runs = [(3, 150, 1), (0, 31, 1), (5, 64, 4), (2, 17, 37), (4, 0, 1)]
+    _check_tiled(runs, 64, 6, hq=group * hkv, hkv=hkv)
+
+
+@pytest.mark.parametrize("first", [0, 21, 40, 95],
+                         ids=["position0", "mid_page", "mid_chunk",
+                              "chunk_last_row"])
+def test_tiled_run_start_positions(first):
+    _check_tiled([(1, first, 19)], 32, 2)
+
+
+def test_tiled_all_inactive_batch():
+    """The warm-up's batch: no lane live, so no tile; zeros come back."""
+    work = _check_tiled([], 32, 4)
+    assert not np.asarray(work)[2].any()
+
+
+@pytest.mark.parametrize("window", [16, 33, 64, 4096],
+                         ids=["w16", "w33", "w64", "not_binding"])
+def test_tiled_window(window):
+    """The band by ``pos0 + i`` inside a tile: a tile starts at the chunk
+    its first row's band reaches, later rows see nothing of it."""
+    runs = [(0, 170, 1), (2, 100, 21), (1, 3, 9)]
+    _check_tiled(runs, 40, 3, window=window)
+
+
+@pytest.mark.parametrize("window", [0, 48])
+def test_tiled_int8_kv(window):
+    """Quantized pools ride the tiles where the scale rows are whole lanes
+    (block 128); at block 16 they keep the lane grid (tests above)."""
+    runs = [(1, 300, 1), (0, 120, 11)]
+    _check_tiled(runs, 16, 2, block=128, max_pages=4, quant=8, window=window)
+
+
+def test_tiled_bf16():
+    runs = [(1, 77, 1), (0, 0, 20), (2, 130, 9)]
+    _check_tiled(runs, 32, 3, dtype=jnp.bfloat16, tol=2e-2, hq=8, hkv=2)
+
+
+def test_tiled_work_list_at_its_bound():
+    """Runs that start off every block edge and cross every one: the most
+    tiles a legal batch makes, one under the static bound (lane 0 is both
+    a run's start and a block's)."""
+    runs = [(0, 9, 5), (1, 40, 6), (2, 0, 10), (3, 100, 11)]
+    T, n_seqs = 32, 4
+    work = np.asarray(_check_tiled(runs, T, n_seqs))
+    assert work.shape == (5, T // TQ + n_seqs)
+    assert (work[2] > 0).sum() == T // TQ + n_seqs - 1
+    assert work[2].sum() == T
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_tile_counts_agree_with_the_work_list(seed):
+    """``ragged.put``'s host counters (q_tiles, kv_steps) count what the
+    device builds."""
+    rng = np.random.default_rng(seed)
+    T, n_seqs, block = 64, 8, 16
+    takes = rng.integers(1, 14, n_seqs)
+    firsts = rng.integers(0, 300, n_seqs)
+    runs = [(int(s), int(p), int(n)) for s, p, n in
+            zip(rng.permutation(n_seqs), firsts, takes)]
+    _, _, _, _, slots, pos = _ragged_batch(runs, T, n_seqs, max_pages=1)
+    work = np.asarray(pa.work_list(jnp.asarray(slots), jnp.asarray(pos),
+                                   n_seqs, TQ))
+    live = work[2] > 0
+    span = pa.chunk_pages(block) * block
+    steps = ((work[4] + work[2] - 1) // span + 1)[live].sum()
+    assert pa.tile_counts([(n, p) for _, p, n in runs], TQ, block) \
+        == (live.sum(), steps)
+
+
+@pytest.mark.parametrize("hd,grid", [(64, "_lane_grid"), (128, "_tiled")])
+def test_grid_is_chosen_on_head_dim(monkeypatch, hd, grid):
+    """Mosaic refuses a hand-rolled copy under 128 lanes, so head_dim 64
+    keeps the (lane, chunk) grid: chosen on the pool's shape alone."""
+    called = []
+    for name in ("_lane_grid", "_tiled"):
+        real = getattr(pa, name)
+        monkeypatch.setattr(pa, name, lambda *a, _n=name, _f=real, **k:
+                            called.append(_n) or _f(*a, **k))
+    rng = np.random.default_rng(5)
+    q, kp, vp, tables, positions = _random_paged(
+        rng, 4, 4, 2, hd, 16, 16, 4, jnp.float32)
+    got = paged_attention(q, kp, vp, tables, positions, interpret=True)
+    assert called == [grid]
+    ref = paged_attention_reference(q, kp, vp, tables, positions)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)

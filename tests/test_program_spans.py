@@ -48,7 +48,8 @@ PARENT = {
     "train.dispatch": "train.step", "train.post": "train.step",
 }
 ATTRS = {
-    "ragged.put": {"lanes", "pages", "seqs", "prefill", "decode", "free"},
+    "ragged.put": {"lanes", "pages", "seqs", "prefill", "decode", "free",
+                   "q_tiles", "kv_steps"},
     "ragged.admit": {"matched", "prompt"}, "ragged.fetch": {"bytes"},
     "serve.tick": {"tick", "queued", "live"},
     "serve.admit": {"admitted", "preempted"}, "serve.put": {"retries"},
@@ -102,7 +103,10 @@ def serve(engine, start, tokens_out):
     def offer(prompts):
         reqs = [srv.submit(p, max_new_tokens=NEW_TOKENS,
                            on_token=tokens_out.append) for p in prompts]
-        for _ in range(400):
+        # a started server works on its own thread: wait up to a minute for
+        # it (the suite's other workers share the host's cores, and the
+        # traced run carries the profiler); by hand, 400 ticks are plenty
+        for _ in range(6000 if start else 400):
             if all(r.is_terminal for r in reqs):
                 return
             if start:
@@ -237,6 +241,10 @@ def test_put_attributes_agree_with_the_engine(runs):
         assert 1 <= a["seqs"] <= MAX_SEQS
         assert a["prefill"] + a["decode"] <= a["lanes"]
         assert 0 <= a["free"] <= N_BLOCKS
+        # a tile a sequence at least, and a chunk a tile at least; far
+        # fewer steps than a grid over every lane and the page bucket
+        assert a["seqs"] <= a["q_tiles"] <= a["kv_steps"] \
+            <= a["lanes"] * a["pages"]
     assert puts[0].attrs["prefill"] > 0
     decode_only = [s.attrs for s in puts if s.attrs["prefill"] == 0]
     assert decode_only and all(a["decode"] == a["seqs"] for a in decode_only)

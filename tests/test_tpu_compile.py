@@ -118,25 +118,42 @@ def test_flash_backward_compiles(one_chip):
     assert c.as_text().count("tpu_custom_call") == 3  # fwd, dq, dkv
 
 
-def _paged_args(T, sharding, pool_dtype=jnp.bfloat16, hd_packed=HD):
+OLMO_HEADS = (30, 30)   # Olmo-Hybrid-7B's full layers: 30 KV heads, group 1
+# the kernel's result as the benchmark's readers know it: a 4-D bf16 array
+# from one custom call (benchmarks/readers/paged_attention_roofline.py)
+_KERNEL_RESULT = re.compile(r"= bf16\[\d+,\d+,\d+,\d+\]\S* custom-call\(")
+
+
+def _kernel_calls(hlo: str) -> int:
+    calls = [l for l in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert all(_KERNEL_RESULT.search(l) for l in calls), calls
+    return len(calls)
+
+
+def _paged_args(T, sharding, pool_dtype=jnp.bfloat16, hd_packed=HD,
+                heads=(HQ, HKV)):
+    hq, hkv = heads
     return dict(
-        q=_sds((T, HQ, HD), jnp.bfloat16, sharding),
-        pool=_sds((N_PAGES + 1, HKV, BLK, hd_packed), pool_dtype, sharding),
-        scale=_sds((N_PAGES + 1, HKV, BLK), jnp.float32, sharding),
+        q=_sds((T, hq, HD), jnp.bfloat16, sharding),
+        pool=_sds((N_PAGES + 1, hkv, BLK, hd_packed), pool_dtype, sharding),
+        scale=_sds((N_PAGES + 1, hkv, BLK), jnp.float32, sharding),
         tables=_sds((SZ.max_seqs, PAGES_PER_SEQ), jnp.int32, sharding),
         lanes=_sds((T,), jnp.int32, sharding))
 
 
-@pytest.mark.parametrize("T", [SZ.token_budget, 64],
-                         ids=["prefill_chunk", "decode"])
-@pytest.mark.parametrize("variant", ["bf16", "windowed", "int8_kv"])
+@pytest.mark.parametrize("T", [SZ.token_budget, 1024, 64],
+                         ids=["prefill_chunk", "lanes1024", "decode"])
+@pytest.mark.parametrize("variant", ["bf16", "windowed", "int8_kv", "olmo"])
 def test_paged_attention_compiles(one_chip, variant, T):
     """Per-sequence tables + slot indirection, the ragged engine's call
-    shape, at a SplitFuse prefill-chunk width and a decode width."""
+    shape, at every lane bucket the cells run: Mistral's head shape (and
+    its windowed and int8-KV forms), and Olmo-Hybrid's."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
 
     quant = variant == "int8_kv"
-    a = _paged_args(T, one_chip, jnp.int8 if quant else jnp.bfloat16)
+    a = _paged_args(T, one_chip, jnp.int8 if quant else jnp.bfloat16,
+                    heads=OLMO_HEADS if variant == "olmo" else (HQ, HKV))
     window = 1024 if variant == "windowed" else 0
 
     def fn(q, kp, vp, tables, pos, slots, ks, vs):
@@ -148,7 +165,7 @@ def test_paged_attention_compiles(one_chip, variant, T):
     c = jax.jit(fn).lower(a["q"], a["pool"], a["pool"], a["tables"],
                           a["lanes"], a["lanes"], a["scale"],
                           a["scale"]).compile()
-    assert c.as_text().count("tpu_custom_call") == 1
+    assert _kernel_calls(c.as_text()) == 1
 
 
 def test_paged_int4_kv_refuses_before_the_compiler(one_chip):
@@ -263,7 +280,7 @@ def test_zero3_step_on_four_chips_compiles(topo, on_tpu):
 
 def compile_ragged_step(device_sharding, n_layers: int, T: int,
                         live_pages: int, n_kv_blocks: int = 1024,
-                        kv_quant: str = "none", tp_topo=None):
+                        kv_quant: str = "none", tp_topo=None, sz=SZ):
     """``RaggedInferenceEngine``'s own jitted SplitFuse step, lowered
     against shapes: the engine is built with no weights (the step takes
     them as an argument) and a small host-side pool. ``tp_topo`` (a
@@ -275,12 +292,12 @@ def compile_ragged_step(device_sharding, n_layers: int, T: int,
     from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                                 RaggedInferenceEngine)
 
-    model = chip_smoke.smoke_model(SZ, n_layers)
+    model = chip_smoke.smoke_model(sz, n_layers)
     eng = RaggedInferenceEngine(
-        model, RaggedConfig(token_budget=SZ.token_budget,
-                            max_seqs=SZ.max_seqs, kv_block_size=BLK,
+        model, RaggedConfig(token_budget=sz.token_budget,
+                            max_seqs=sz.max_seqs, kv_block_size=BLK,
                             n_kv_blocks=n_kv_blocks,
-                            max_context=SZ.max_context, kv_quant=kv_quant),
+                            max_context=sz.max_context, kv_quant=kv_quant),
         params={})
     assert eng.attention_path == "pallas"
     params = jax.eval_shape(partial(model.init, dtype=jnp.bfloat16),
@@ -306,12 +323,60 @@ def compile_ragged_step(device_sharding, n_layers: int, T: int,
         live_pages).compile()
 
 
-@pytest.mark.parametrize("T,live_pages", [(SZ.token_budget, 128), (64, 128)],
-                         ids=["prefill_chunk", "decode"])
-def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages):
+@pytest.mark.parametrize("heads", [(HQ, HKV), OLMO_HEADS],
+                         ids=["mistral", "olmo"])
+@pytest.mark.parametrize("T,live_pages",
+                         [(SZ.token_budget, 128), (1024, 128), (64, 128)],
+                         ids=["prefill_chunk", "lanes1024", "decode"])
+def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages, heads):
+    """One kernel call a layer, its result 4-D bf16, at both head shapes
+    the cells serve (Olmo's: d_model 3840 = 30 x 128, group 1)."""
+    from dataclasses import replace
+
     n_layers = 2  # reduced from chip_smoke's serve depth: compile time
-    c = compile_ragged_step(one_chip, n_layers, T, live_pages)
-    assert c.as_text().count("tpu_custom_call") == n_layers
+    sz = replace(SZ, n_heads=heads[0], n_kv_heads=heads[1],
+                 d_model=heads[0] * HD)
+    c = compile_ragged_step(one_chip, n_layers, T, live_pages, sz=sz)
+    assert _kernel_calls(c.as_text()) == n_layers
+
+
+def test_step_fn_takes_the_benchmark_warm_up_call(monkeypatch):
+    """``benchmarks/runners/serve_open_loop.py::warm`` calls the engine's
+    jitted step with eight positional arguments on a batch with no live
+    lane, once a (lanes, live pages) shape, and counts on ``put`` finding
+    every program compiled: a PR may not edit that file, and a program
+    compiled inside a measured window fails the run. Run here on the CPU
+    (kernel in interpret mode, head_dim 128: the grid over query tiles
+    with no tile) with the runner's own function."""
+    from benchmarks.runners.serve_open_loop import warm
+    from deepspeed_tpu.inference.ragged import (RaggedConfig,
+                                                RaggedInferenceEngine)
+    from deepspeed_tpu.models import Llama
+
+    monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+    model = Llama("tiny", n_layers=2, d_model=256, n_heads=2, n_kv_heads=1,
+                  vocab_size=128, max_seq_len=128, use_flash=False,
+                  remat=False)
+    eng = RaggedInferenceEngine(
+        model, RaggedConfig(token_budget=96, max_seqs=4, kv_block_size=16,
+                            n_kv_blocks=32, max_context=128,
+                            dtype=jnp.float32), rng=jax.random.PRNGKey(0))
+    assert eng.attention_path == "pallas_interpret"
+    assert eng._buckets == [64, 96]
+    pool_before = jax.tree_util.tree_map(np.asarray, eng.kv_pool)
+    shapes = [(lanes, pages) for lanes in eng._buckets for pages in (1, 2, 4)]
+    warm(eng, shapes)
+    # nothing live: every pool page but the scratch sink is as it was
+    for a, b in zip(jax.tree_util.tree_leaves(pool_before),
+                    jax.tree_util.tree_leaves(eng.kv_pool)):
+        np.testing.assert_array_equal(a[:-1], np.asarray(b)[:-1])
+    compiled = eng._step_fn._cache_size()
+    assert compiled == len(shapes)
+    rows = eng.put([1, 2], [list(range(1, 40)), [5, 6, 7]])   # 64 lanes, 4 pages
+    assert np.isfinite(rows).all()
+    rows = eng.put([1, 2], [[9], [9]])                          # 64 lanes, 4 pages
+    assert np.isfinite(rows).all()
+    assert eng._step_fn._cache_size() == compiled
 
 
 # ----------------------------------------------------------------------
