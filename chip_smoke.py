@@ -448,9 +448,11 @@ def _serve_once(sz: Sizes, model, params, n_kv_blocks: int,
                 prompts: List[List[int]]) -> Dict[str, Any]:
     """A fresh engine and server over ``params``: every request through
     submit (one through stream), greedy, then drain and the page audit."""
-    from deepspeed_tpu.inference.ragged import (RaggedConfig,
-                                                RaggedInferenceEngine,
-                                                assert_block_balance)
+    from deepspeed_tpu.inference.kv_cache import assert_block_balance
+    from deepspeed_tpu.inference.ragged import (
+        RaggedConfig,
+        RaggedInferenceEngine,
+    )
     from deepspeed_tpu.serving import ServingEngine
 
     engine = RaggedInferenceEngine(
@@ -487,9 +489,11 @@ def phase_serve(sz: Sizes, seed: int, rec: Dict[str, Any]) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from deepspeed_tpu.inference.ragged import (RaggedConfig,
-                                                RaggedInferenceEngine,
-                                                kv_blocks_for_bytes)
+    from deepspeed_tpu.inference.kv_cache import kv_blocks_for_bytes
+    from deepspeed_tpu.inference.ragged import (
+        RaggedConfig,
+        RaggedInferenceEngine,
+    )
     from deepspeed_tpu.ops import ragged_host
     from deepspeed_tpu.parallel import mesh as mesh_mod
 

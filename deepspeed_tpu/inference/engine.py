@@ -36,6 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import MESH_AXES, Topology, set_topology
 from ..utils.logging import log_dist
+from .sampling import sample
 
 
 @dataclass
@@ -259,7 +260,7 @@ class InferenceEngine:
                 params, last_tokens[:, None], positions=positions,
                 kv_caches=caches, cache_pos=cache_pos)
             logits = logits[:, 0, :]
-            next_tok = _sample(logits, rng, cfg.temperature, cfg.top_k, cfg.top_p)
+            next_tok = sample(logits, rng, cfg.temperature, cfg.top_k, cfg.top_p)
             return caches, next_tok
 
         return jax.jit(decode, donate_argnums=(1,))
@@ -477,8 +478,8 @@ class InferenceEngine:
         self._rng, rng = jax.random.split(self._rng)
         rng, sub = jax.random.split(rng)
         logits, caches = self._prefill_fn(self.params, input_ids, caches)
-        next_tok = _sample(logits, sub, self.config.temperature,
-                           self.config.top_k, self.config.top_p)
+        next_tok = sample(logits, sub, self.config.temperature,
+                          self.config.top_k, self.config.top_p)
         # per-row EOS: finished rows emit eos (padding) from then on
         finished = np.zeros((b,), bool)
         if eos_token_id is not None:
@@ -522,20 +523,3 @@ class InferenceEngine:
 
     __call__ = forward
 
-
-def _sample(logits, rng, temperature: float, top_k: int, top_p: float):
-    """Greedy when temperature==0, else temperature/top-k/top-p sampling."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / jnp.maximum(temperature, 1e-6)
-    if top_k > 0:
-        kth = jnp.sort(logits, axis=-1)[:, -top_k][:, None]
-        logits = jnp.where(logits < kth, -jnp.inf, logits)
-    if top_p < 1.0:
-        sorted_logits = jnp.sort(logits, axis=-1)[:, ::-1]
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        cutoff_idx = jnp.sum(cum < top_p, axis=-1, keepdims=True)
-        cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx, axis=-1)
-        logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-    return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)

@@ -26,7 +26,7 @@ formulation with identical semantics serves as fallback and oracle.
 
 from __future__ import annotations
 
-import functools
+import os
 import sys
 import time
 from dataclasses import dataclass, field
@@ -37,12 +37,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from ..ops.norms import layer_norm, rms_norm
 from ..ops.ragged_host import build_batch, fill_tables
-from ..ops.rotary import apply_rotary, rope_frequencies
+from ..ops.rotary import rope_frequencies
 from ..profiling.trace import annotate
 from ..utils.logging import log_dist
-from .engine import _sample
+from . import kv_cache
+from .drafter import NgramIndex
+# re-exported for the benchmark, which imports five names from here: these
+# three, RaggedConfig and RaggedInferenceEngine. Everything else of the
+# cache is imported from its home, kv_cache.py
+from .kv_cache import (assert_block_balance,  # noqa: F401
+                       kv_blocks_for_bytes, kv_page_bytes)
+from .sampling import sample
 
 
 def _paged_kernel_blocker(head_dim: int, block: int, dtype,
@@ -50,14 +56,9 @@ def _paged_kernel_blocker(head_dim: int, block: int, dtype,
     """Why the compiled Pallas paged kernel cannot serve this engine, or
     None when it can: it needs a real TPU, a tileable page shape and
     prefetched scalars (per-seq tables, slots, positions) that fit SMEM
-    (1 MB/core; keep them under half). DST_RAGGED_FORCE_GATHER=1 pins the
-    XLA gather path (serve-bench A/B lever)."""
-    import os
-
+    (1 MB/core; keep them under half)."""
     from ..ops.attention import _on_tpu
 
-    if os.environ.get("DST_RAGGED_FORCE_GATHER") == "1":
-        return "DST_RAGGED_FORCE_GATHER=1"
     if not _on_tpu():
         return "not on a TPU"
     if scalar_ints * 4 > 512 * 1024:
@@ -69,452 +70,6 @@ def _paged_kernel_blocker(head_dim: int, block: int, dtype,
     if block % sublane:
         return f"kv_block_size {block} not a multiple of {sublane}"
     return None
-
-
-# ----------------------------------------------------------------------
-# host-side state (reference: ragged/blocked_allocator.py, ragged_manager.py)
-
-class PoolExhausted(RuntimeError):
-    """The KV page pool cannot satisfy a schedule's block demand.
-    A dedicated type so recovery code (the serving driver preempts a
-    decode and retries) can distinguish this RECOVERABLE condition from
-    arbitrary device RuntimeErrors — substring-matching the message
-    would misfire on e.g. XLA's 'Resource exhausted' device OOM."""
-
-class BlockedAllocator:
-    """Refcounted free-list allocator over ``n_blocks`` KV pages
-    (reference blocked_allocator.py — same capability, python list instead
-    of a torch tensor free-list; refcounts added for prefix-cache block
-    sharing: a page returns to the free list only when every holder —
-    sequences and the cache — has released it)."""
-
-    def __init__(self, n_blocks: int):
-        self.n_blocks = n_blocks
-        self._free: List[int] = list(range(n_blocks))
-        self._ref: Dict[int, int] = {}
-
-    @property
-    def free_blocks(self) -> int:
-        return len(self._free)
-
-    def allocate(self, n: int) -> List[int]:
-        if n > len(self._free):
-            raise PoolExhausted(
-                f"KV pool exhausted: need {n}, have {len(self._free)}")
-        out, self._free = self._free[:n], self._free[n:]
-        for b in out:
-            self._ref[b] = 1
-        return out
-
-    def retain(self, blocks: Sequence[int]) -> None:
-        for b in blocks:
-            self._ref[int(b)] += 1
-
-    def release(self, blocks: Sequence[int]) -> None:
-        for b in blocks:
-            b = int(b)
-            self._ref[b] -= 1
-            if self._ref[b] == 0:
-                del self._ref[b]
-                self._free.append(b)
-
-    def refcount(self, block: int) -> int:
-        return self._ref.get(int(block), 0)
-
-    # historical name used throughout the engine/tests: a release, not an
-    # unconditional free — shared pages survive until the last holder
-    free = release
-
-
-class PrefixCache:
-    """LRU cache of computed KV pages keyed by full-block token prefixes.
-
-    Beyond-reference capability (FastGen recomputes every prompt; vLLM
-    calls this automatic prefix caching): when a sequence is flushed, its
-    full KV blocks are published under the token prefix they encode; a
-    new prompt sharing that prefix adopts the pages (refcounted via
-    :class:`BlockedAllocator`) and skips their prefill. Correctness rests
-    on immutability of shared pages: sharing covers FULL blocks only and
-    is capped at ``len(prompt) - 1`` tokens, so the engine's scatters only
-    ever write positions at-or-after the shared region's end — except the
-    benign case of re-writing the final shared position with bit-identical
-    K/V (same tokens, same absolute positions, same params)."""
-
-    def __init__(self, block_size: int, on_evict=None):
-        import collections
-
-        self.block_size = block_size
-        # prefix tuple -> list of block ids (cache holds one retain each);
-        # ordered oldest-used first: O(1) LRU via move_to_end/popitem
-        self._entries: "collections.OrderedDict[Tuple[int, ...], List[int]]" \
-            = collections.OrderedDict()
-        # per-block count of CACHE references (across nested entries) —
-        # lets reclaimable_blocks() tell cache-only pages from shared ones
-        self._block_refs: Dict[int, int] = {}
-        self.hits = 0
-        self.misses = 0
-        # optional eviction hook ``(key_tuple, blocks) -> None`` fired
-        # BEFORE the evicted entry's refs release (its pages are still
-        # valid to read) — the global KV tier's directory-invalidate +
-        # cold-spill seam. None (the default) changes nothing.
-        self.on_evict = on_evict
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-    def match(self, prompt: Sequence[int]) -> Tuple[int, List[int]]:
-        """Longest cached full-block prefix of ``prompt``, capped so at
-        least one prompt token remains to prefill (its logits seed
-        generation). Returns (shared_token_count, blocks) — blocks are NOT
-        yet retained for the caller."""
-        bs = self.block_size
-        for k in range((len(prompt) - 1) // bs, 0, -1):
-            key = tuple(int(t) for t in prompt[: k * bs])
-            ent = self._entries.get(key)
-            if ent is not None:
-                self._entries.move_to_end(key)
-                self.hits += 1
-                return k * bs, ent
-        self.misses += 1
-        return 0, []
-
-    def lookup(self, tokens: Sequence[int]) -> Tuple[Optional[Tuple[int, ...]],
-                                                     List[int]]:
-        """Longest full-block prefix ENTRY covering ``tokens`` — unlike
-        :meth:`match` there is no leave-one-token-to-prefill cap, because
-        adoption/export wants whole cache entries (the requester's
-        routing key is already a full-block prefix). Refreshes LRU
-        recency (a donor should not evict what it is donating) but does
-        not count hits/misses. Returns (key, blocks) or (None, [])."""
-        bs = self.block_size
-        for k in range(len(tokens) // bs, 0, -1):
-            key = tuple(int(t) for t in tokens[: k * bs])
-            ent = self._entries.get(key)
-            if ent is not None:
-                self._entries.move_to_end(key)
-                return key, ent
-        return None, []
-
-    def _hold(self, key, blocks, allocator: BlockedAllocator) -> None:
-        allocator.retain(blocks)
-        for b in blocks:
-            self._block_refs[b] = self._block_refs.get(b, 0) + 1
-        self._entries[key] = blocks
-
-    def publish(self, tokens: Sequence[int], blocks: Sequence[int], seen: int,
-                allocator: BlockedAllocator) -> None:
-        """Offer a flushed sequence's full blocks to the cache (the cache
-        retains them; the sequence's own refs are released separately)."""
-        bs = self.block_size
-        k = min(seen, len(tokens)) // bs
-        if k <= 0:
-            return
-        key = tuple(int(t) for t in tokens[: k * bs])
-        if key in self._entries:
-            self._entries.move_to_end(key)
-            return
-        held = [int(b) for b in blocks[:k]]
-        self._hold(key, held, allocator)
-        # keys are exact tuples, so a shorter shared prefix needs its own
-        # entry — publish every nested full-block level too (same pages,
-        # one retain per level)
-        for kk in range(k - 1, 0, -1):
-            kkey = key[: kk * bs]
-            if kkey in self._entries:
-                break
-            self._hold(kkey, held[:kk], allocator)
-
-    def _evict_one(self, allocator: BlockedAllocator) -> None:
-        key, blocks = self._entries.popitem(last=False)   # LRU
-        if self.on_evict is not None:
-            # hook runs while the entry's pages are still referenced:
-            # the cold-spill copy must read them before they can return
-            # to the free list and be overwritten
-            self.on_evict(key, blocks)
-        allocator.release(blocks)
-        for b in blocks:
-            self._block_refs[b] -= 1
-            if self._block_refs[b] == 0:
-                del self._block_refs[b]
-
-    def evict_for(self, allocator: BlockedAllocator, need: int) -> None:
-        """LRU-evict entries until ``need`` blocks are free (or empty)."""
-        while allocator.free_blocks < need and self._entries:
-            self._evict_one(allocator)
-
-    def reclaimable_blocks(self, allocator: BlockedAllocator) -> int:
-        """Distinct pages that would return to the free list if the whole
-        cache dropped: pages whose every reference is the cache's own.
-        Admission checks (can_schedule/query) count these as available —
-        without this, a cache that has absorbed the pool starves admission
-        forever while _check_pool could evict its way out."""
-        return sum(1 for b, n in self._block_refs.items()
-                   if allocator.refcount(b) == n)
-
-    def drop_all(self, allocator: BlockedAllocator) -> None:
-        while self._entries:
-            self._evict_one(allocator)
-
-
-def block_balance_report(engine) -> Dict[str, Any]:
-    """Audit the engine's KV-page accounting: every page must be exactly
-    one of free / sequence-held / cache-held, and the allocator's
-    refcount for each held page must equal the number of holders
-    (sequence occurrences + prefix-cache entry references).
-
-    Returns ``{"free": int, "held": int, "problems": [str, ...]}`` —
-    ``problems`` empty means zero leaks and exact refcount balance. The
-    serving drain check and the cancellation tests assert on this; it is
-    pure host-side dict walking (never touches the device)."""
-    alloc = engine.allocator
-    free = set(alloc._free)
-    held = set(alloc._ref)
-    problems: List[str] = []
-    if len(free) != len(alloc._free):
-        problems.append("duplicate pages in the free list")
-    overlap = free & held
-    if overlap:
-        problems.append(f"pages both free and referenced: "
-                        f"{sorted(overlap)[:8]}")
-    vanished = set(range(alloc.n_blocks)) - free - held
-    if vanished:
-        problems.append(f"pages leaked (not free, not referenced): "
-                        f"{sorted(vanished)[:8]}")
-    expected: Dict[int, int] = {}
-    for seq in engine.seqs.values():
-        for b in seq.blocks:
-            expected[int(b)] = expected.get(int(b), 0) + 1
-    if engine.prefix_cache is not None:
-        for b, n in engine.prefix_cache._block_refs.items():
-            expected[int(b)] = expected.get(int(b), 0) + n
-    for b in sorted(held | set(expected)):
-        have, want = alloc._ref.get(b, 0), expected.get(b, 0)
-        if have != want:
-            problems.append(f"page {b}: allocator refcount {have} != "
-                            f"{want} holders")
-    return {"free": len(free), "held": len(held), "problems": problems}
-
-
-def assert_block_balance(engine, expect_free: Optional[int] = None) -> None:
-    """Raise AssertionError on any block-accounting imbalance (and, when
-    given, on ``free != expect_free``)."""
-    rep = block_balance_report(engine)
-    if rep["problems"]:
-        raise AssertionError("KV block balance violated: "
-                             + "; ".join(rep["problems"]))
-    if expect_free is not None and rep["free"] != expect_free:
-        raise AssertionError(
-            f"KV free-page count {rep['free']} != expected {expect_free} "
-            f"({rep['held']} pages still referenced)")
-
-
-def _layers_of(model_config, kind: str) -> Tuple[int, ...]:
-    """Indices of the layers that hold KV pages ("full") or a recurrent
-    state ("linear"). The sizing functions take any object with the KV
-    geometry (n_layers, n_kv_heads, head_dim): one without ``layers_of``
-    is all full."""
-    if hasattr(model_config, "layers_of"):
-        return model_config.layers_of(kind)
-    return tuple(range(model_config.n_layers)) if kind == "full" else ()
-
-
-def state_pool_bytes(model_config, ragged_config) -> int:
-    """Bytes of the recurrent-state pool: for every linear layer and every
-    slot (and the sink), the float32 state and the convolution's rows. A
-    fixed cost of ``max_seqs``, whatever the contexts' lengths."""
-    import jax.numpy as _jnp
-
-    n = len(_layers_of(model_config, "linear"))
-    if not n:
-        return 0
-    from ..ops.gated_delta import state_shapes
-
-    state, rows = state_shapes(model_config)
-    return n * (ragged_config.max_seqs + 1) * (
-        4 * int(np.prod(state))
-        + _jnp.dtype(ragged_config.dtype).itemsize * int(np.prod(rows)))
-
-
-#: what a model with recurrent layers refuses, and why, in one place
-_NO_SNAPSHOT = (
-    "{what} needs a snapshot of the recurrent state: a linear layer's state "
-    "cannot be rewound to, or rebuilt from, a token position the way KV "
-    "pages can, and the engine keeps no state snapshot yet")
-
-
-def kv_page_bytes(model_config, ragged_config) -> int:
-    """Bytes ONE KV page (K + V, all layers that hold pages) occupies in the pool under
-    ``ragged_config.kv_quant`` — payload plus per-row fp32 scales. The
-    capacity arithmetic behind "quantization roughly doubles concurrent
-    sequences per pool": size two pools to the same byte budget with
-    :func:`kv_blocks_for_bytes` and the int8 pool holds ~2x the pages."""
-    import jax.numpy as _jnp
-
-    c, cfg = model_config, ragged_config
-    rows = len(_layers_of(c, "full")) * c.n_kv_heads * cfg.kv_block_size
-    # (per K or V; a recurrent layer holds no pages)
-    bits = {"none": 0, "int8": 8, "int4": 4}[cfg.kv_quant]
-    if bits == 0:
-        return 2 * rows * c.head_dim * _jnp.dtype(cfg.dtype).itemsize
-    # payload + per-head-vector scale bytes: the ONE audited byte
-    # arithmetic (ops/quantizer.quantized_nbytes, block = head_dim)
-    from ..ops.quantizer import quantized_nbytes
-
-    return 2 * quantized_nbytes(rows * c.head_dim, bits, c.head_dim)
-
-
-def kv_blocks_for_bytes(budget_bytes: int, model_config,
-                        ragged_config) -> int:
-    """Pages a ``budget_bytes`` KV pool holds under the config's
-    ``kv_quant`` mode (the fixed-byte-budget sizing the serve bench's
-    kv-quant leg and capacity tests use). The recurrent-state pool, a
-    fixed cost, comes out of the budget first."""
-    left = int(budget_bytes) - state_pool_bytes(model_config, ragged_config)
-    return max(1, left // max(1, kv_page_bytes(model_config, ragged_config)))
-
-
-def _prompt_lookup(ctx: Sequence[int], ngram: int, k: int) -> List[int]:
-    """Prompt-lookup drafting: if the trailing ``ngram`` of ``ctx`` occurred
-    earlier, propose the (up to ``k``) tokens that followed its most recent
-    earlier occurrence. The zero-cost draft model of prompt-lookup /
-    n-gram speculative decoding — strong on the summarization/code/RAG
-    workloads where outputs quote their inputs."""
-    if k <= 0 or ngram <= 0 or len(ctx) <= ngram:
-        return []
-    arr = np.asarray(ctx, np.int32)
-    pat = arr[-ngram:]
-    win = np.lib.stride_tricks.sliding_window_view(arr[:-1], ngram)
-    hits = np.nonzero((win == pat).all(axis=1))[0]
-    if len(hits) == 0:
-        return []
-    # prefer the most recent occurrence that still has k continuation
-    # tokens; fall back to whichever hit offers the longest continuation
-    cont_len = np.minimum(len(arr) - (hits + ngram), k)
-    full = np.nonzero(cont_len == k)[0]
-    j = int(hits[full[-1]] if len(full) else hits[np.argmax(cont_len)])
-    return arr[j + ngram: j + ngram + k].tolist()
-
-
-class NgramIndex:
-    """Incremental n-gram position index over one sequence's token stream
-    — the memoized form of :func:`_prompt_lookup`, bit-identical in what
-    it proposes but O(new tokens) per draft round instead of O(context):
-    every fully-formed window's start position is recorded once (dict
-    key -> ascending position list) as the stream grows, and a trim of
-    the stream's tail pops exactly the invalidated entries off an
-    append-ordered stack. ``lookup`` then answers "most recent earlier
-    occurrence of the trailing n-gram with a k-token continuation, else
-    the earliest occurrence" with two bisects plus an O(ngram + extra)
-    scan of the windows that overlap the virtual ``extra`` suffix."""
-
-    def __init__(self, ngram: int):
-        self.ngram = int(ngram)
-        self._toks: List[int] = []
-        self._pos: Dict[Tuple[int, ...], List[int]] = {}
-        self._order: List[Tuple[int, Tuple[int, ...]]] = []  # (start, key)
-
-    def sync(self, tokens: Sequence[int]) -> None:
-        """Index tokens appended since the last call. The caller
-        guarantees the previously-indexed prefix is unchanged — the
-        engine's only tail mutation (``trim``) calls :meth:`truncate`."""
-        n = self.ngram
-        if len(tokens) < len(self._toks):        # untracked truncation
-            self.truncate(len(tokens))
-        self._toks.extend(int(t) for t in tokens[len(self._toks):])
-        start = self._order[-1][0] + 1 if self._order else 0
-        for h in range(start, len(self._toks) - n + 1):
-            key = tuple(self._toks[h:h + n])
-            self._pos.setdefault(key, []).append(h)
-            self._order.append((h, key))
-
-    def truncate(self, length: int) -> None:
-        """Drop the stream's tail: O(removed) — pops only entries whose
-        window extends past ``length``."""
-        del self._toks[length:]
-        n = self.ngram
-        while self._order and self._order[-1][0] + n > length:
-            h, key = self._order.pop()
-            lst = self._pos[key]
-            lst.pop()                            # ascending: h is last
-            if not lst:
-                del self._pos[key]
-
-    def lookup(self, extra: Sequence[int], k: int) -> List[int]:
-        """Draft proposal for the stream + virtual ``extra`` suffix —
-        exactly :func:`_prompt_lookup`'s answer for
-        ``ctx = tokens + extra`` without rescanning ``tokens``."""
-        import bisect
-
-        n = self.ngram
-        toks = self._toks
-        ctx_len = len(toks) + len(extra)
-        if k <= 0 or n <= 0 or ctx_len <= n:
-            return []
-
-        def at(i: int) -> int:
-            return toks[i] if i < len(toks) else int(extra[i - len(toks)])
-
-        pat = tuple(at(ctx_len - n + j) for j in range(n))
-        limit = ctx_len - 1 - n          # last admissible window start
-        base = self._pos.get(pat, [])
-        hi = bisect.bisect_right(base, min(limit, len(toks) - n))
-        # windows overlapping ``extra`` (or the trailing pattern region)
-        # are not in the index — check the handful directly
-        manual = [h for h in range(max(0, len(toks) - n + 1), limit + 1)
-                  if all(at(h + j) == pat[j] for j in range(n))]
-        if hi == 0 and not manual:
-            return []
-        # prefer the most recent start with a full k-token continuation;
-        # manual starts are all later than indexed ones
-        full_limit = ctx_len - n - k
-        j = next((h for h in reversed(manual) if h <= full_limit), None)
-        if j is None:
-            idx = bisect.bisect_right(base, full_limit, 0, hi)
-            if idx:
-                j = base[idx - 1]
-        if j is None:                    # no full hit: longest continuation
-            j = base[0] if hi else manual[0]
-        return [at(i) for i in range(j + n, min(j + n + k, ctx_len))]
-
-
-@dataclass
-class KVExport:
-    """Host-side snapshot of one sequence's KV state, the unit of the
-    disaggregated prefill→decode hand-off (``export_kv``/``import_kv``).
-    Today the pages travel as numpy arrays (CPU copy); the dataclass is
-    the explicit seam where an ICI transfer replaces the host hop later —
-    importers validate geometry, never provenance."""
-
-    uid: int
-    tokens: List[int]          # fed context (prompt + any decoded tokens)
-    seen: int                  # tokens whose KV the pages actually hold
-    prompt_len: int
-    kv_block_size: int
-    n_layers: int
-    n_kv_heads: int
-    head_dim: int
-    dtype: str
-    k_pages: np.ndarray        # [n_layers, n_pages, hkv, block, hd]
-    v_pages: np.ndarray
-    # quantized hand-off (kv_quant != "none"): k/v_pages hold the POOL's
-    # quantized payload (int8, or int4 nibble-packed uint8 [.., hd//2])
-    # and the per-row fp32 scales ride along — the wire moves ~half
-    # (int8) / ~quarter (int4) the fp bytes, and the importer adopts the
-    # payload bit-identically (no re-quantization, no extra error)
-    kv_quant: str = "none"
-    k_scales: Optional[np.ndarray] = None   # [n_layers, n_pages, hkv, block]
-    v_scales: Optional[np.ndarray] = None
-
-    @property
-    def n_pages(self) -> int:
-        return int(self.k_pages.shape[1])
-
-    @property
-    def nbytes(self) -> int:
-        n = int(self.k_pages.nbytes + self.v_pages.nbytes)
-        if self.k_scales is not None:
-            n += int(self.k_scales.nbytes + self.v_scales.nbytes)
-        return n
 
 
 @dataclass
@@ -551,7 +106,7 @@ class RaggedConfig:
     n_kv_blocks: int = 256
     max_context: int = 2048
     dtype: Any = jnp.bfloat16
-    # sampling (parity: FastGen sampler / v1 engine _sample); 0.0 = greedy
+    # sampling (parity: FastGen sampler, inference/sampling.py); 0.0 = greedy
     temperature: float = 0.0
     top_k: int = 0
     top_p: float = 1.0
@@ -606,13 +161,12 @@ class RaggedInferenceEngine:
             raise NotImplementedError(
                 "RaggedInferenceEngine does not support attention-scale "
                 "overrides (GPT-Neo); use InferenceEngine (dense KV cache)")
-        # layers that hold KV pages / a recurrent state (ops/gated_delta.py)
-        self._page_layers = c.layers_of("full")
+        # layers that hold a recurrent state (ops/gated_delta.py)
         self._state_layers = c.layers_of("linear")
-        if self._state_layers and self.config.enable_prefix_cache:
-            raise NotImplementedError(_NO_SNAPSHOT.format(
-                what="enable_prefix_cache (a new prompt adopting a cached "
-                     "prefix's pages)"))
+        if self.config.enable_prefix_cache:
+            kv_cache.refuse_without_snapshot(
+                c, "enable_prefix_cache (a new prompt adopting a cached "
+                   "prefix's pages)")
         if self._state_layers and tp > 1:
             raise NotImplementedError(
                 "recurrent layers are not sharded over the model axis yet")
@@ -627,7 +181,7 @@ class RaggedInferenceEngine:
             raise ValueError(
                 f"kv_quant must be 'none', 'int8' or 'int4', got "
                 f"'{self.config.kv_quant}'")
-        self._kv_bits = {"none": 0, "int8": 8, "int4": 4}[self.config.kv_quant]
+        self._kv_bits = kv_cache.KV_BITS[self.config.kv_quant]
         if self._kv_bits == 4 and c.head_dim % 2:
             raise ValueError(
                 f"kv_quant='int4' packs two channels per byte and needs an "
@@ -637,14 +191,12 @@ class RaggedInferenceEngine:
         # kernel), "pallas_interpret" (DST_RAGGED_FORCE_PALLAS=interpret —
         # the CPU-lane token-exactness tests for the sharded kernel ride
         # this) or "gather" (XLA gather formulation, the off-TPU oracle)
-        import os as _os
-
         cfg = self.config
         self.max_pages = cfg.max_context // cfg.kv_block_size
         blocker = _paged_kernel_blocker(
             c.head_dim, cfg.kv_block_size, cfg.dtype,
             scalar_ints=cfg.max_seqs * self.max_pages + 2 * cfg.token_budget)
-        if _os.environ.get("DST_RAGGED_FORCE_PALLAS", "") == "interpret":
+        if os.environ.get("DST_RAGGED_FORCE_PALLAS", "") == "interpret":
             self.attention_path = "pallas_interpret"
         elif blocker is None:
             self.attention_path = "pallas"
@@ -677,100 +229,21 @@ class RaggedInferenceEngine:
                 jax.tree_util.tree_map(
                     lambda sp: NamedSharding(topology.mesh, sp), specs,
                     is_leaf=lambda x: isinstance(x, PartitionSpec)))
-        self.allocator = BlockedAllocator(cfg.n_kv_blocks)
-        self.prefix_cache = (PrefixCache(cfg.kv_block_size)
-                             if cfg.enable_prefix_cache else None)
+        # the cache (kv_cache.py): the host's books of pages, prefixes and
+        # slots, and the device leaves by name
+        self.cache = kv_cache.KVLedger(cfg)
+        self.allocator = self.cache.allocator
+        self.prefix_cache = self.cache.prefix_cache
         self.seqs: Dict[int, SequenceDescriptor] = {}
-        self._free_slots = list(range(cfg.max_seqs))
         # uids whose next admission is a RESUME (post-preempt/discard):
         # their fresh descriptors must not re-record TTFT/latency — the
         # serving layer's request spans carry the true end-to-end numbers
         self._resume_uids: set = set()
-        # paged KV pool: per-layer tuples of [n_blocks + 1, hkv, block, hd]
-        # (last page = scratch sink for masked-out batch lanes; duplicate
-        # scatters with mixed old/new values are undefined — inactive lanes
-        # must never alias a live page). (block, hd) stay minor-most so
-        # each page is a native VMEM tile for the Pallas kernel, which pins
-        # this row-major layout; every write into a leaf must keep it
-        # (ops/pallas/paged_attention.write_kv_rows), or XLA:TPU
-        # transposes the whole leaf and back, every tick. One array PER
-        # LAYER: earlier rounds measured pool-sized copies under a stacked
-        # [L, pages, ...] tensor (100 ms a decode step) and a flat
-        # [L*(P+1), ...] one (16-18 GB compile OOM) and blamed the shapes,
-        # but the per-layer leaves were copied too, by the row scatter's
-        # (hkv, hd) window (PR 26). Stacked and flat were not tried again;
-        # per-layer leaves keep any transient to one leaf.
-        # kv_quant stores pages as blockwise payload + per-row fp32 scales
-        # (scale block = one K/V head-vector): int8 payload [.., hd] or
-        # int4 nibble-packed uint8 [.., hd//2], scale leaf [P+1, hkv, bs].
-        # The sink page's zeros dequantize to zeros, so masked-lane
-        # scatters stay harmless exactly as in the fp layout.
-        if self._kv_bits == 4:
-            leaf_shape = (cfg.n_kv_blocks + 1, c.n_kv_heads,
-                          cfg.kv_block_size, c.head_dim // 2)
-            leaf_dtype = jnp.uint8
-        elif self._kv_bits == 8:
-            leaf_shape = (cfg.n_kv_blocks + 1, c.n_kv_heads,
-                          cfg.kv_block_size, c.head_dim)
-            leaf_dtype = jnp.int8
-        else:
-            leaf_shape = (cfg.n_kv_blocks + 1, c.n_kv_heads,
-                          cfg.kv_block_size, c.head_dim)
-            leaf_dtype = cfg.dtype
-        scale_shape = (cfg.n_kv_blocks + 1, c.n_kv_heads, cfg.kv_block_size)
-        if tp > 1:
-            from jax.sharding import NamedSharding
-
-            pool_sh = NamedSharding(topology.mesh,
-                                    PartitionSpec(None, "model", None, None))
-            scale_sh = NamedSharding(topology.mesh,
-                                     PartitionSpec(None, "model", None))
-
-            def _zeros(_):
-                return jax.device_put(jnp.zeros(leaf_shape, leaf_dtype),
-                                      pool_sh)
-
-            def _zero_scales(_):
-                return jax.device_put(jnp.zeros(scale_shape, jnp.float32),
-                                      scale_sh)
-        else:
-            def _zeros(_):
-                return jnp.zeros(leaf_shape, leaf_dtype)
-
-            def _zero_scales(_):
-                return jnp.zeros(scale_shape, jnp.float32)
-        self.kv_pool = (
-            tuple(_zeros(i) for i in self._page_layers),
-            tuple(_zeros(i) for i in self._page_layers))
-        if self._kv_bits:
-            self.kv_pool = self.kv_pool + (
-                tuple(_zero_scales(i) for i in self._page_layers),
-                tuple(_zero_scales(i) for i in self._page_layers))
-        if self._state_layers:
-            # the second kind of cache, the pool's last two entries: for
-            # each linear layer a float32 state leaf [max_seqs + 1, H, dk,
-            # dv] and the convolution's last inputs [max_seqs + 1, K - 1,
-            # channels], keyed by SLOT (the last one the sink of lanes
-            # that are not live, as the scratch page is for KV). Nothing
-            # here zeroes a slot: the step starts a run at position 0 from
-            # zeros (a fresh admission, a resume after preempt and a
-            # reused slot all re-prefill from position 0), carries the
-            # state over ticks when a prompt is split, and never rewinds
-            from ..ops.gated_delta import state_shapes
-
-            st, rows = state_shapes(c)
-            S = cfg.max_seqs + 1
-            self.kv_pool = self.kv_pool + (
-                tuple(jnp.zeros((S,) + st, jnp.float32)
-                      for _ in self._state_layers),
-                tuple(jnp.zeros((S,) + rows, cfg.dtype)
-                      for _ in self._state_layers))
+        self.kv_pool = kv_cache.new_pool(c, cfg, topology)
         self._rows_buf: Optional[np.ndarray] = None    # _rows_out
         self._step_fn = None
         self._core_fn = None
         self._decode_fn = None
-        self._copy_page_fn = None
-        self._import_fn = None
         self._verify_fn = None
         # speculative-decoding acceptance stats (generate_speculative and
         # the serving tick's verify rounds; mirrored into the shared
@@ -816,12 +289,6 @@ class RaggedInferenceEngine:
 
         return get_telemetry()
 
-    def _refuse_if_recurrent(self, what: str) -> None:
-        """Loud failure for what cannot be done without a state snapshot
-        (as ALiBi fails at construction)."""
-        if self._state_layers:
-            raise NotImplementedError(_NO_SNAPSHOT.format(what=what))
-
     # -- scheduling API (parity engine_v2.query/can_schedule) -----------
     def query(self, uid: int) -> Tuple[int, int]:
         """(max new tokens schedulable for uid now, free kv blocks) —
@@ -831,19 +298,10 @@ class RaggedInferenceEngine:
         owned = len(self.seqs[uid].blocks) if uid in self.seqs else 0
         ctx_room = self.config.max_context - seen
         slack_in_blocks = owned * self.config.kv_block_size - seen
-        avail = self._available_blocks()
+        avail = self.cache.available_blocks()
         kv_room = slack_in_blocks + avail * self.config.kv_block_size
         return (max(0, min(self.config.token_budget, ctx_room, kv_room)),
                 avail)
-
-    def _available_blocks(self) -> int:
-        """Free pages plus cache-only-held pages (_check_pool evicts those
-        on demand, so admission must count them or it starves once the
-        prefix cache has absorbed the pool)."""
-        free = self.allocator.free_blocks
-        if self.prefix_cache is not None:
-            free += self.prefix_cache.reclaimable_blocks(self.allocator)
-        return free
 
     def blocks_needed(self, n_tokens: int) -> int:
         """KV pages a fresh sequence of ``n_tokens`` is charged at
@@ -867,8 +325,8 @@ class RaggedInferenceEngine:
                 need_blocks += max(0, -(-total // bs) - len(seq.blocks))
             else:
                 need_blocks += self.blocks_needed(length)
-        return (len(new) <= len(self._free_slots)
-                and need_blocks <= self._available_blocks())
+        return (len(new) <= self.cache.free_slots
+                and need_blocks <= self.cache.available_blocks())
 
     def flush(self, uids: Sequence[int]) -> None:
         """Release sequence state + KV blocks (reference engine_v2.flush :228).
@@ -889,7 +347,7 @@ class RaggedInferenceEngine:
                     self.prefix_cache.publish(seq.tokens, seq.blocks,
                                               seq.seen, self.allocator)
                 self.allocator.free(seq.blocks)
-                self._free_slots.append(seq.slot)
+                self.cache.give_slot(seq.slot)
 
     def preempt(self, uid: int) -> List[int]:
         """Release ``uid``'s slot + KV blocks WITHOUT retiring it as a
@@ -918,7 +376,7 @@ class RaggedInferenceEngine:
         if seq is None:
             return
         self.allocator.free(seq.blocks)
-        self._free_slots.append(seq.slot)
+        self.cache.give_slot(seq.slot)
         self._resume_uids.add(uid)
 
     def clear_resume(self, uid: int) -> None:
@@ -971,7 +429,7 @@ class RaggedInferenceEngine:
                 s["accepted"] / s["proposed"])
 
     # -- KV export/import (disaggregated prefill/decode hand-off) --------
-    def export_kv(self, uid: int) -> "KVExport":
+    def export_kv(self, uid: int) -> "kv_cache.KVExport":
         """Snapshot ``uid``'s KV pages + token stream for hand-off to
         ANOTHER engine (disaggregated serving: a prefill replica computes
         the KV, a decode replica continues the stream). Host copy today —
@@ -983,7 +441,7 @@ class RaggedInferenceEngine:
         export does NOT release anything — the caller decides whether to
         ``preempt`` (publish into this engine's prefix cache) or
         ``discard`` the local copy afterwards."""
-        self._refuse_if_recurrent("export_kv (handing a sequence to another engine)")
+        kv_cache.refuse_without_snapshot(self.model.config, "export_kv")
         seq = self.seqs.get(uid)
         if seq is None:
             raise KeyError(f"uid {uid} has no live sequence to export")
@@ -994,24 +452,16 @@ class RaggedInferenceEngine:
         if seq.seen == 0 or not seq.blocks:
             raise ValueError(f"uid {uid}: nothing prefilled yet")
         c = self.model.config
-        idx = jnp.asarray(np.asarray(seq.blocks, np.int32))
-        # one device gather per layer leaf, then host transfer; rows past
-        # ``seen`` in the last page are never-read scratch and ride along
-        k = np.stack([np.asarray(leaf[idx]) for leaf in self.kv_pool[0]])
-        v = np.stack([np.asarray(leaf[idx]) for leaf in self.kv_pool[1]])
-        ks = vs = None
-        if self._kv_bits:
-            ks = np.stack([np.asarray(leaf[idx]) for leaf in self.kv_pool[2]])
-            vs = np.stack([np.asarray(leaf[idx]) for leaf in self.kv_pool[3]])
-        export = KVExport(uid=uid, tokens=list(seq.tokens), seen=seq.seen,
-                          prompt_len=seq.prompt_len,
-                          kv_block_size=self.config.kv_block_size,
-                          n_layers=c.n_layers, n_kv_heads=c.n_kv_heads,
-                          head_dim=c.head_dim,
-                          dtype=str(jnp.dtype(self.config.dtype)),
-                          k_pages=k, v_pages=v,
-                          kv_quant=self.config.kv_quant,
-                          k_scales=ks, v_scales=vs)
+        # rows past ``seen`` in the last page are never-read scratch and
+        # ride along
+        k, v, ks, vs = kv_cache.gather_pages(self.kv_pool, seq.blocks)
+        export = kv_cache.KVExport(
+            uid=uid, tokens=list(seq.tokens), seen=seq.seen,
+            prompt_len=seq.prompt_len,
+            kv_block_size=self.config.kv_block_size, n_layers=c.n_layers,
+            n_kv_heads=c.n_kv_heads, head_dim=c.head_dim,
+            dtype=str(jnp.dtype(self.config.dtype)), k_pages=k, v_pages=v,
+            kv_quant=self.config.kv_quant, k_scales=ks, v_scales=vs)
         t = self._telemetry
         if t.enabled:
             t.registry.counter("inference/kv_exports").inc()
@@ -1032,7 +482,7 @@ class RaggedInferenceEngine:
         record_collective("kv_handoff", logical, export.nbytes)
         return export
 
-    def import_kv(self, uid: int, export: "KVExport") -> None:
+    def import_kv(self, uid: int, export: "kv_cache.KVExport") -> None:
         """Adopt an exported sequence: allocate pages from THIS engine's
         pool (evicting cached prefixes under pressure, same discipline as
         admission), scatter the pages in, and create a live descriptor at
@@ -1044,7 +494,7 @@ class RaggedInferenceEngine:
         Raises :class:`PoolExhausted` (recoverable — the caller can fall
         back to the re-prefill resume path) or ``ValueError`` on geometry
         mismatch. On any failure nothing is mutated."""
-        self._refuse_if_recurrent("import_kv (adopting a sequence without its prefill)")
+        kv_cache.refuse_without_snapshot(self.model.config, "import_kv")
         cfg = self.config
         c = self.model.config
         if uid in self.seqs:
@@ -1072,47 +522,22 @@ class RaggedInferenceEngine:
         if need != -(-export.seen // cfg.kv_block_size):
             raise ValueError(
                 f"export carries {need} pages for {export.seen} tokens")
-        if not self._free_slots:
+        if not self.cache.free_slots:
             raise RuntimeError("no free sequence slots; flush() first")
-        if need > self.allocator.free_blocks and self.prefix_cache is not None:
-            self.prefix_cache.evict_for(self.allocator, need)
-        blocks = self.allocator.allocate(need)        # may raise PoolExhausted
+        self.cache.make_room(need)                    # may raise PoolExhausted
+        blocks = self.allocator.allocate(need)
         try:
-            # pow2-bucket the page count (one compiled writer per bucket,
-            # not one per hand-off length); padding lanes scatter zeros
-            # into the sink page, which is never read
-            B = 1
-            while B < need:
-                B *= 2
-            B = min(B, self.max_pages)
-            dst = np.full((B,), cfg.n_kv_blocks, np.int32)
-            dst[:need] = blocks
-            k, v = export.k_pages, export.v_pages
-            ks, vs = export.k_scales, export.v_scales
-            if B > need:
-                pad = np.zeros((k.shape[0], B - need) + k.shape[2:], k.dtype)
-                k = np.concatenate([k, pad], axis=1)
-                v = np.concatenate([v, pad], axis=1)
-                if self._kv_bits:
-                    spad = np.zeros((ks.shape[0], B - need) + ks.shape[2:],
-                                    ks.dtype)
-                    ks = np.concatenate([ks, spad], axis=1)
-                    vs = np.concatenate([vs, spad], axis=1)
-            if self._kv_bits:
-                self.kv_pool = self._write_pages(
-                    self.kv_pool, jnp.asarray(dst), jnp.asarray(k),
-                    jnp.asarray(v), jnp.asarray(ks), jnp.asarray(vs))
-            else:
-                self.kv_pool = self._write_pages(
-                    self.kv_pool, jnp.asarray(dst), jnp.asarray(k),
-                    jnp.asarray(v))
+            self.kv_pool = kv_cache.write_pages(
+                self.kv_pool, blocks,
+                (export.k_pages, export.v_pages, export.k_scales,
+                 export.v_scales), self.max_pages)
         except BaseException:
             self.allocator.release(blocks)
             raise
         # telemetry suppressed like a resume: the serving layer's request
         # span owns the end-to-end TTFT/latency story for handed-off work
         self.seqs[uid] = SequenceDescriptor(
-            uid=uid, slot=self._free_slots.pop(),
+            uid=uid, slot=self.cache.take_slot(),
             tokens=[int(t) for t in export.tokens], seen=int(export.seen),
             blocks=blocks, t_admitted=None, t_created=None,
             prompt_len=int(export.prompt_len))
@@ -1130,7 +555,7 @@ class RaggedInferenceEngine:
         at eviction time (an entry must never outlive its pages). Both
         hooks are leaf-locked, so firing them under the driver's
         serving lock is legal in the documented lock order."""
-        self._refuse_if_recurrent("the KV tier (prefixes adopted across replicas)")
+        kv_cache.refuse_without_snapshot(self.model.config, "the KV tier")
         self._kv_tier_member = str(member)
         self._cold_tier = cold_tier
         self._on_prefix_invalidate = on_invalidate
@@ -1162,7 +587,7 @@ class RaggedInferenceEngine:
             return []
         from ..serving.kvtier import prefix_hash
 
-        return [prefix_hash(k) for k in self.prefix_cache._entries]
+        return [prefix_hash(k) for k in self.prefix_cache.keys()]
 
     def _gather_prefix_export(self, key: Tuple[int, ...],
                               blocks: List[int]):
@@ -1173,19 +598,9 @@ class RaggedInferenceEngine:
 
         c = self.model.config
         cfg = self.config
-        idx = jnp.asarray(np.asarray(blocks, np.int32))
-        k = np.stack([np.asarray(leaf[idx]) for leaf in self.kv_pool[0]])
-        v = np.stack([np.asarray(leaf[idx]) for leaf in self.kv_pool[1]])
-        scales = None
-        if self._kv_bits:
-            ks = np.stack([np.asarray(leaf[idx])
-                           for leaf in self.kv_pool[2]])
-            vs = np.stack([np.asarray(leaf[idx])
-                           for leaf in self.kv_pool[3]])
-            scales = (ks, vs)
-        wire = int(k.nbytes + v.nbytes)
-        if scales is not None:
-            wire += int(scales[0].nbytes + scales[1].nbytes)
+        k, v, *scales = kv_cache.gather_pages(self.kv_pool, blocks)
+        scales = tuple(scales) if self._kv_bits else None
+        wire = sum(int(a.nbytes) for a in (k, v) + (scales or ()))
         # logical = the dense (unquantized) bytes the same pages would
         # move — the CommsLogger compression-ratio denominator
         logical = (2 * len(blocks) * c.n_layers * c.n_kv_heads
@@ -1284,40 +699,15 @@ class RaggedInferenceEngine:
             raise ValueError(
                 f"prefix length {len(export.tokens)} exceeds max_context "
                 f"{cfg.max_context}")
-        if tuple(export.tokens) in self.prefix_cache._entries:
+        if export.tokens in self.prefix_cache:
             return False            # already resident
-        if need > self.allocator.free_blocks:
-            self.prefix_cache.evict_for(self.allocator, need)
-        blocks = self.allocator.allocate(need)    # may raise PoolExhausted
+        self.cache.make_room(need)                # may raise PoolExhausted
+        blocks = self.allocator.allocate(need)
         try:
-            B = 1
-            while B < need:
-                B *= 2
-            B = min(B, self.max_pages)
-            dst = np.full((B,), cfg.n_kv_blocks, np.int32)
-            dst[:need] = blocks
-            k, v = export.pages
-            ks = vs = None
-            if self._kv_bits:
-                ks, vs = export.scales
-            if B > need:
-                pad = np.zeros((k.shape[0], B - need) + k.shape[2:],
-                               k.dtype)
-                k = np.concatenate([k, pad], axis=1)
-                v = np.concatenate([v, pad], axis=1)
-                if self._kv_bits:
-                    spad = np.zeros((ks.shape[0], B - need) + ks.shape[2:],
-                                    ks.dtype)
-                    ks = np.concatenate([ks, spad], axis=1)
-                    vs = np.concatenate([vs, spad], axis=1)
-            if self._kv_bits:
-                self.kv_pool = self._write_pages(
-                    self.kv_pool, jnp.asarray(dst), jnp.asarray(k),
-                    jnp.asarray(v), jnp.asarray(ks), jnp.asarray(vs))
-            else:
-                self.kv_pool = self._write_pages(
-                    self.kv_pool, jnp.asarray(dst), jnp.asarray(k),
-                    jnp.asarray(v))
+            self.kv_pool = kv_cache.write_pages(
+                self.kv_pool, blocks,
+                tuple(export.pages) + tuple(export.scales or (None, None)),
+                self.max_pages)
         except BaseException:
             self.allocator.release(blocks)
             raise
@@ -1343,7 +733,7 @@ class RaggedInferenceEngine:
         bs = self.config.kv_block_size
         for k in range((len(tokens) - 1) // bs, 0, -1):
             key = tuple(int(t) for t in tokens[: k * bs])
-            if key in self.prefix_cache._entries:
+            if key in self.prefix_cache:
                 return              # device cache already at least as good
             export = self._cold_tier.get(key)
             if export is None:
@@ -1360,41 +750,6 @@ class RaggedInferenceEngine:
                 pass
             return
 
-    def _write_pages(self, pools, dst, k, v, ks=None, vs=None):
-        """Scatter imported pages into every layer's K/V leaf (one jitted
-        donated program; the import-side half of the hand-off seam). With
-        kv_quant on, the quantized payload AND its scale pages scatter in
-        the same program — the import is bit-identical pool state, never
-        a requantization."""
-        if self._import_fn is None:
-            if self._kv_bits:
-                @functools.partial(jax.jit, donate_argnums=(0,))
-                def imp_q(pools, dst, k, v, ks, vs):
-                    kp = tuple(leaf.at[dst].set(k[i].astype(leaf.dtype))
-                               for i, leaf in enumerate(pools[0]))
-                    vp = tuple(leaf.at[dst].set(v[i].astype(leaf.dtype))
-                               for i, leaf in enumerate(pools[1]))
-                    ksp = tuple(leaf.at[dst].set(ks[i])
-                                for i, leaf in enumerate(pools[2]))
-                    vsp = tuple(leaf.at[dst].set(vs[i])
-                                for i, leaf in enumerate(pools[3]))
-                    return (kp, vp, ksp, vsp)
-
-                self._import_fn = imp_q
-            else:
-                @functools.partial(jax.jit, donate_argnums=(0,))
-                def imp(pools, dst, k, v):
-                    kp = tuple(leaf.at[dst].set(k[i].astype(leaf.dtype))
-                               for i, leaf in enumerate(pools[0]))
-                    vp = tuple(leaf.at[dst].set(v[i].astype(leaf.dtype))
-                               for i, leaf in enumerate(pools[1]))
-                    return (kp, vp)
-
-                self._import_fn = imp
-        if self._kv_bits:
-            return self._import_fn(pools, dst, k, v, ks, vs)
-        return self._import_fn(pools, dst, k, v)
-
     def trim(self, uid: int, length: int) -> None:
         """Rewind ``uid`` to its first ``length`` tokens, freeing now-unused
         KV blocks. Attention reads are position-bounded, so stale KV past
@@ -1402,7 +757,7 @@ class RaggedInferenceEngine:
         Use after observing EOS inside a ``decode_steps`` chunk when the
         sequence will keep being served (post-EOS tokens were admitted by
         that chunk and would otherwise pollute further continuations)."""
-        self._refuse_if_recurrent("trim (rewinding a sequence)")
+        kv_cache.refuse_without_snapshot(self.model.config, "trim")
         seq = self.seqs[uid]
         if not 0 <= length <= seq.seen:
             raise ValueError(
@@ -1437,21 +792,9 @@ class RaggedInferenceEngine:
             del seq.blocks[keep:]
         if cow_new is not None:
             old = seq.blocks[keep - 1]
-            self.kv_pool = self._copy_page(self.kv_pool, old, cow_new)
+            self.kv_pool = kv_cache.copy_page(self.kv_pool, old, cow_new)
             self.allocator.release([old])
             seq.blocks[keep - 1] = cow_new
-
-    def _copy_page(self, pools, src: int, dst: int):
-        """Device-side page copy across every layer's K/V leaf (one jitted
-        donated program; used by trim's copy-on-write)."""
-        if self._copy_page_fn is None:
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def cp(pools, src, dst):
-                return jax.tree_util.tree_map(
-                    lambda p: p.at[dst].set(p[src]), pools)
-
-            self._copy_page_fn = cp
-        return self._copy_page_fn(pools, jnp.int32(src), jnp.int32(dst))
 
     # -- step ------------------------------------------------------------
     def _admit_tokens(self, uids: Sequence[int],
@@ -1467,14 +810,12 @@ class RaggedInferenceEngine:
             for uid, toks in zip(uids, tokens):
                 new = uid not in self.seqs
                 if new:
-                    if not self._free_slots:
-                        raise RuntimeError(
-                            "no free sequence slots; flush() first")
+                    slot = self.cache.take_slot()    # may raise: none free
                     now = time.perf_counter()
                     resumed = uid in self._resume_uids
                     self._resume_uids.discard(uid)
                     self.seqs[uid] = SequenceDescriptor(
-                        uid=uid, slot=self._free_slots.pop(),
+                        uid=uid, slot=slot,
                         t_admitted=None if resumed else now,
                         t_created=None if resumed else now)
                     if self._state_layers and self._telemetry.enabled:
@@ -1658,7 +999,7 @@ class RaggedInferenceEngine:
         context. On PoolExhausted every remaining draft token is
         stripped before the raise, so the recovery retry (plain ``put``
         with empty chunks) sees exactly put()'s admitted state."""
-        self._refuse_if_recurrent("put_spec (rejected draft tokens are rewound)")
+        kv_cache.refuse_without_snapshot(self.model.config, "put_spec")
         with annotate("ragged.put") as span:
             return self._put_spec(span, uids, tokens, drafts)
 
@@ -1764,19 +1105,6 @@ class RaggedInferenceEngine:
             self._record_step_telemetry(sched)
         return out, verified
 
-    def kv_occupancy(self) -> float:
-        """Fraction of the paged KV pool currently held by live sequences
-        or the prefix cache (1.0 = exhausted)."""
-        return 1.0 - self.allocator.free_blocks / self.allocator.n_blocks
-
-    def kv_demand(self) -> float:
-        """Fraction of the pool that live DEMAND holds: pages the cache
-        could reclaim on allocation pressure don't count. This is the
-        capacity-planning signal (a warm LRU cache legitimately absorbs
-        the whole pool at idle — raw ``kv_occupancy`` would read that as
-        permanent pressure and an autoscaler could never scale down)."""
-        return 1.0 - self._available_blocks() / self.allocator.n_blocks
-
     def _record_step_telemetry(self, sched) -> None:
         """Per-ragged-step series: scheduled tokens + pool occupancy. Host
         dict updates only — nothing here touches the device."""
@@ -1787,7 +1115,7 @@ class RaggedInferenceEngine:
         r.counter("inference/ragged_steps").inc()
         r.counter("inference/scheduled_tokens").inc(
             sum(take for _, take in sched))
-        r.gauge("inference/kv_occupancy").set(self.kv_occupancy())
+        r.gauge("inference/kv_occupancy").set(self.cache.occupancy())
         r.gauge("inference/live_sequences").set(len(self.seqs))
         if self._state_layers:
             r.gauge("inference/state_slots_live").set(len(self.seqs))
@@ -1805,7 +1133,9 @@ class RaggedInferenceEngine:
                     f"uid {seq.uid}: context {new_total} exceeds "
                     f"max_context {cfg.max_context}")
             needs.append(-(-new_total // cfg.kv_block_size) - len(seq.blocks))
-        self._check_pool(needs)
+        # the whole schedule's new-block demand must fit the pool before
+        # ANY uid is granted blocks (validate, then allocate)
+        self.cache.make_room(sum(n for n in needs if n > 0))
         scheduled = sum(take for _, take in sched)
         if scheduled > cfg.token_budget:
             raise ValueError(f"scheduled tokens {scheduled} exceed "
@@ -1837,7 +1167,7 @@ class RaggedInferenceEngine:
         last). One device call verifies all proposals; the caller accepts
         the longest matching prefix and trims the rest. k is pow2-bucketed
         so the jit cache stays O(log k) wide."""
-        self._refuse_if_recurrent("speculative verification (rejected draft tokens are rewound)")
+        kv_cache.refuse_without_snapshot(self.model.config, "speculative verification")
         cfg = self.config
         sched = [(self.seqs[u], len(c)) for u, c in zip(uids, chains)]
         # validate BEFORE touching seq.tokens: a failed round must not
@@ -1880,20 +1210,6 @@ class RaggedInferenceEngine:
             return logits.reshape(sel_rows.shape + (-1,)), pools
 
         return jax.jit(step, donate_argnums=(1,), static_argnums=(7,))
-
-    def _check_pool(self, needs) -> None:
-        """Admission check shared by put()/decode_steps(): the whole
-        schedule's new-block demand must fit the pool before ANY uid is
-        granted blocks (two-phase validate-then-allocate). Cache-held
-        pages are reclaimable: evict LRU prefixes before giving up."""
-        short = sum(n for n in needs if n > 0)
-        if short > self.allocator.free_blocks and self.prefix_cache is not None:
-            self.prefix_cache.evict_for(self.allocator, short)
-        if short > self.allocator.free_blocks:
-            raise PoolExhausted(
-                f"KV pool exhausted: need {short} blocks, have "
-                f"{self.allocator.free_blocks}; flush() finished "
-                "sequences first")
 
     def _host_tables(self) -> np.ndarray:
         live = list(self.seqs.values())
@@ -1945,7 +1261,7 @@ class RaggedInferenceEngine:
                     f"uid {uid}: decode chunk to {total} exceeds "
                     f"max_context {cfg.max_context}")
             needs.append(-(-total // cfg.kv_block_size) - len(seq.blocks))
-        self._check_pool(needs)
+        self.cache.make_room(sum(n for n in needs if n > 0))
         for uid, need in zip(first_tokens, needs):
             if need > 0:
                 self.seqs[uid].blocks.extend(self.allocator.allocate(need))
@@ -2008,7 +1324,7 @@ class RaggedInferenceEngine:
         key = jax.random.fold_in(self._rng_prefill,
                                  self._prefill_round_counter)
         self._prefill_round_counter += 1
-        toks = np.asarray(_sample(jnp.asarray(np.stack(rows)), key,
+        toks = np.asarray(sample(jnp.asarray(np.stack(rows)), key,
                                   self.config.temperature,
                                   self.config.top_k, self.config.top_p))
         return [int(t) for t in toks]
@@ -2219,7 +1535,7 @@ class RaggedInferenceEngine:
         windows = tuple(int(w) if 0 < int(w) < cfg.max_context else 0
                         for w in aw) if aw is not None \
             else (0,) * c.n_layers
-        page_layers, state_layers = self._page_layers, self._state_layers
+        state_layers = self._state_layers
         # TP shards the pool/heads. GSPMD cannot partition a pallas_call,
         # so under TP the kernel runs INSIDE a shard_map whose specs name
         # the operands' existing sharding (heads/pool over 'model', tables/
@@ -2241,7 +1557,8 @@ class RaggedInferenceEngine:
         kv_bits = self._kv_bits
 
         def _paged_attn_sharded(q, kp, vp, tables, positions, slots, work,
-                                live_pages, window, ks=None, vs=None):
+                                live_pages, window, k_scale=None,
+                                v_scale=None, kv_bits=0):
             """shard_map the paged kernel over the bound mesh: heads and
             pool (payload AND scale leaves; dim 1 is heads) sharded on
             'model', scalars (the step's work list among them) replicated.
@@ -2249,7 +1566,7 @@ class RaggedInferenceEngine:
             under a partly automatic mesh."""
             from jax.sharding import PartitionSpec as P_
 
-            sharded = (q, kp, vp) + (() if ks is None else (ks, vs))
+            sharded = (q, kp, vp) + ((k_scale, v_scale) if kv_bits else ())
             heads = lambda a: P_(None, "model", *(None,) * (a.ndim - 2))
 
             def local(q, kp, vp, *rest):
@@ -2266,10 +1583,6 @@ class RaggedInferenceEngine:
                 in_specs=tuple(map(heads, sharded)) + (P_(),) * 4,
                 out_specs=heads(q), check_vma=False)(
                     *sharded, tables, positions, slots, work)
-
-        def norm(x, w, b=None):
-            return rms_norm(x, w, c.norm_eps) if c.norm == "rms" \
-                else layer_norm(x, w, b, c.norm_eps)
 
         def core(params, pools, tokens, slots, positions, block_tables,
                  live_pages):
@@ -2293,17 +1606,9 @@ class RaggedInferenceEngine:
             # step, for every layer's call
             work = work_list(slots, positions, cfg.max_seqs) \
                 if use_pallas else None
-
-            # K/V (and scale) leaves are indexed by a layer's place among
-            # the layers that hold pages; the recurrent leaves, the pool's
-            # last two entries, by its place among the linear layers
-            k_list, v_list = list(pools[0]), list(pools[1])
-            ks_list = list(pools[2]) if kv_bits else None
-            vs_list = list(pools[3]) if kv_bits else None
             if state_layers:
                 from ..ops import gated_delta
 
-                st_list, rows_list = list(pools[-2]), list(pools[-1])
                 runs = gated_delta.runs_of(slots, positions, cfg.max_seqs)
 
             def after_mixer(x, attn, lp):
@@ -2313,35 +1618,21 @@ class RaggedInferenceEngine:
                 return model._after_mixer(x[None], attn[None], None, lp,
                                           None, False)[0][0]
 
-            def linear_block(x, at, lp):
-                with jax.named_scope("linear_attn"):
-                    attn, st_list[at], rows_list[at] = gated_delta.mix_ragged(
-                        x, lp, c, st_list[at], rows_list[at], runs)
-                return after_mixer(x, attn, lp)
+            # the step's mixers, one a kind of layer: each takes its
+            # layer's leaves of the pool by name and returns them written
 
-            def block(x, li, lp, window):
+            def linear_block(x, lp, own):
+                with jax.named_scope("linear_attn"):
+                    attn, state, rows = gated_delta.mix_ragged(
+                        x, lp, c, own["state"], own["conv_rows"], runs)
+                return after_mixer(x, attn, lp), \
+                    {"state": state, "conv_rows": rows}
+
+            def block(x, lp, own, window):
                 with jax.named_scope("attn"):
-                    kp, vp = k_list[li], v_list[li]
-                    h = x if c.branch_norm else \
-                        norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"))
-                    q, kk, vv = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-                    if c.qk_norm:   # over the whole projection, heads unsplit
-                        q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
-                        kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
-                    q = q.reshape(-1, c.n_heads, c.head_dim)
-                    kk = kk.reshape(-1, c.n_kv_heads, c.head_dim)
-                    vv = vv.reshape(-1, c.n_kv_heads, c.head_dim)
-                    if c.qkv_bias:
-                        q = q + lp["bq"].reshape(c.n_heads, c.head_dim)
-                        kk = kk + lp["bk"].reshape(c.n_kv_heads, c.head_dim)
-                        vv = vv + lp["bv"].reshape(c.n_kv_heads, c.head_dim)
-                    if c.position == "rope":
-                        q = apply_rotary(q[:, None], angles, positions[:, None],
-                                         rotary_dim=c.rotary_dim,
-                                         interleaved=c.rope_interleaved)[:, 0]
-                        kk = apply_rotary(kk[:, None], angles, positions[:, None],
-                                          rotary_dim=c.rotary_dim,
-                                          interleaved=c.rope_interleaved)[:, 0]
+                    # the model's own projection (norm, q / k / v, bias,
+                    # QK-norm, heads, rotary), a position a lane
+                    q, kk, vv = model._qkv(x, lp, angles, positions)
                     # write the new K/V rows into this layer's pages, in
                     # place and in the kernel's layout (write_kv_rows):
                     # page = table[pos // bs], row = pos % bs
@@ -2358,22 +1649,17 @@ class RaggedInferenceEngine:
                     # scatter payload + scale; reads below dequantize inside
                     # the paged-attention path, so fp K/V never round-trips
                     # through HBM at full width
+                    new = {"k": kk, "v": vv}
                     if kv_bits:
                         from ..ops.quantizer import quantize_kv
 
-                        qk, sk = quantize_kv(kk, kv_bits)
-                        qv, sv = quantize_kv(vv, kv_bits)
-                        kp = write_kv_rows(kp, page, row, qk)
-                        vp = write_kv_rows(vp, page, row, qv)
-                        ksl = write_kv_rows(ks_list[li], page, row, sk)
-                        vsl = write_kv_rows(vs_list[li], page, row, sv)
-                        k_list[li], v_list[li] = kp, vp
-                        ks_list[li], vs_list[li] = ksl, vsl
-                    else:
-                        ksl = vsl = None
-                        kp = write_kv_rows(kp, page, row, kk)
-                        vp = write_kv_rows(vp, page, row, vv)
-                        k_list[li], v_list[li] = kp, vp
+                        new["k"], new["k_scale"] = quantize_kv(kk, kv_bits)
+                        new["v"], new["v_scale"] = quantize_kv(vv, kv_bits)
+                    own = {f: write_kv_rows(leaf, page, row, new[f])
+                           for f, leaf in own.items()}
+                    quant = dict(k_scale=own["k_scale"],
+                                 v_scale=own["v_scale"],
+                                 kv_bits=kv_bits) if kv_bits else {}
                     # paged attention: Pallas kernel on TPU (scalar-prefetched
                     # block tables, zero gather); jnp gather path elsewhere.
                     # (positions <= ctx-1 always, so the causal mask subsumes the
@@ -2381,51 +1667,43 @@ class RaggedInferenceEngine:
                     # rows: zeros from the kernel's tiles, junk elsewhere)
                     with jax.named_scope("paged_attention"):
                         if use_pallas and self._tp_size > 1:
-                            attn = _paged_attn_sharded(q, kp, vp, block_tables,
-                                                       positions, slots, work,
-                                                       live_pages, window,
-                                                       ks=ksl, vs=vsl)
+                            attn = _paged_attn_sharded(
+                                q, own["k"], own["v"], block_tables,
+                                positions, slots, work, live_pages, window,
+                                **quant)
                         elif use_pallas:
-                            attn = paged_attention(q, kp, vp, block_tables,
-                                                   positions, seq_slots=slots,
-                                                   work=work,
-                                                   live_pages=live_pages,
-                                                   window=window,
-                                                   k_scale=ksl, v_scale=vsl,
-                                                   kv_bits=kv_bits,
-                                                   interpret=interp)
+                            attn = paged_attention(
+                                q, own["k"], own["v"], block_tables,
+                                positions, seq_slots=slots, work=work,
+                                live_pages=live_pages, window=window,
+                                interpret=interp, **quant)
                         else:
-                            attn = paged_attention_reference(q, kp, vp, tables,
-                                                             positions,
-                                                             window=window,
-                                                             k_scale=ksl,
-                                                             v_scale=vsl,
-                                                             kv_bits=kv_bits)
-                    attn = attn.astype(x.dtype)
-                    attn = attn.reshape(-1, c.n_heads * c.head_dim) @ lp["wo"]
-                    # attn_o_bias, not use_bias: InternLM has use_bias=False
-                    # with a real o_proj bias (models/transformer.py:500)
-                    if c.attn_o_bias:
-                        attn = attn + lp["bo"]
-                return after_mixer(x, attn, lp)
+                            attn = paged_attention_reference(
+                                q, own["k"], own["v"], tables, positions,
+                                window=window, **quant)
+                    attn = model._attn_out(attn.astype(x.dtype), lp)
+                return after_mixer(x, attn, lp), own
 
             # python-unrolled layer loop, NOT lax.scan: a scan would carry
             # the whole pool, stacked or flat, and both were measured with
             # pool-sized copies before the row write kept the kernel's
-            # layout (the pool comment in __init__); not tried since
+            # layout (KVPool's docstring); not tried since. A leaf is
+            # indexed by its layer's place among the layers of its kind
+            leaves = {f: list(ls) for f, ls in pools._asdict().items()}
             for li in range(c.n_layers):
                 with jax.named_scope("weights"):
                     kind, lp = model.layer_params(params["layers"], li)
+                at = c.layers_of(kind).index(li)
+                own = {f: leaves[f][at] for f in kv_cache.OWNS[kind]
+                       if leaves[f]}
                 if kind == "linear":
-                    x = linear_block(x, state_layers.index(li), lp)
+                    x, own = linear_block(x, lp, own)
                 else:
-                    x = block(x, page_layers.index(li), lp, windows[li])
-            out_pools = (tuple(k_list), tuple(v_list))
-            if kv_bits:
-                out_pools += (tuple(ks_list), tuple(vs_list))
-            if state_layers:
-                out_pools += (tuple(st_list), tuple(rows_list))
-            return x, out_pools
+                    x, own = block(x, lp, own, windows[li])
+                for f, leaf in own.items():
+                    leaves[f][at] = leaf
+            return x, kv_cache.KVPool(**{f: tuple(ls)
+                                         for f, ls in leaves.items()})
 
         return core
 
@@ -2489,7 +1767,7 @@ class RaggedInferenceEngine:
                 x, pools = core(params, pools, toks, slots_eff, pos,
                                 block_tables, live_pages)
                 logits = model._head(params, x[None, :])[0]    # [S, vocab]
-                nxt = _sample(logits, jax.random.fold_in(rng_key, step_i),
+                nxt = sample(logits, jax.random.fold_in(rng_key, step_i),
                               cfg.temperature, cfg.top_k, cfg.top_p)
                 if eos_id >= 0:
                     nxt = jnp.where(alive, nxt, eos_id)

@@ -497,8 +497,7 @@ class Transformer:
         traced scalar silently falls to the O(s^2) masked path reserved
         for per-layer-varying windows (GPT-Neo)."""
         c = self.config
-        hd = c.head_dim
-        b, s, _ = x.shape
+        s = x.shape[1]
         if attn_mask is not None and c.causal:
             raise NotImplementedError(
                 "attn_mask with a causal model is not supported (padding "
@@ -517,27 +516,7 @@ class Transformer:
         # parts in a profiler trace: attn (flash_attention around the
         # kernel), ffn (docs/observability.md)
         with jax.named_scope("attn"):
-            # pre-LN normalizes the branch input; post-LN (BERT-era,
-            # prenorm=False) runs the branch on x and norms AFTER the residual
-            h = self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
-                if c.prenorm and not c.branch_norm else x
-            q = h @ lp["wq"]
-            kk = h @ lp["wk"]
-            vv = h @ lp["wv"]
-            if c.qkv_bias:
-                q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
-            if c.qk_norm:
-                q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
-                kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
-            q = q.reshape(b, s, c.n_heads, hd)
-            kk = kk.reshape(b, s, c.n_kv_heads, hd)
-            vv = vv.reshape(b, s, c.n_kv_heads, hd)
-            if c.position == "rope":
-                # apply_rotary no-ops the partial slice when rotary_dim == hd
-                q = apply_rotary(q, angles, positions, rotary_dim=c.rotary_dim,
-                                 interleaved=c.rope_interleaved)
-                kk = apply_rotary(kk, angles, positions, rotary_dim=c.rotary_dim,
-                                  interleaved=c.rope_interleaved)
+            q, kk, vv = self._qkv(x, lp, angles, positions)
 
             def _alibi_bias(skv):
                 # ALiBi (Bloom): logits += slopes * (k_pos - q_pos); the per-row
@@ -630,11 +609,46 @@ class Transformer:
                 attn = dot_product_attention(q, kk, vv, causal=c.causal,
                                              scale=c.attn_scale)
 
-            attn = attn.reshape(b, s, c.n_heads * hd) @ lp["wo"]
-            if c.attn_o_bias:
-                attn = attn + lp["bo"]
+            attn = self._attn_out(attn, lp)
 
         return self._after_mixer(x, attn, new_kv, lp, rng, training)
+
+    def _qkv(self, x, lp, angles, positions):
+        """The attention block from its input to (q, k, v) split by heads
+        and rotated: x [..., s, d] -> q [..., s, h, hd], k and v [..., s,
+        hkv, hd]. The half of the block that does not depend on where K
+        and V live, written once: :meth:`_block` (x [b, s, d]) and the
+        ragged step (inference/ragged.py, x [T, d] with a position a lane)
+        call it around their own attention. Order: bias, then QK-norm,
+        then rotary."""
+        c = self.config
+        heads = lambda a, n: a.reshape(a.shape[:-1] + (n, c.head_dim))
+        # pre-LN normalizes the branch input; post-LN (BERT-era,
+        # prenorm=False) runs the branch on x and norms AFTER the residual
+        h = self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
+            if c.prenorm and not c.branch_norm else x
+        q, kk, vv = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+        if c.qkv_bias:
+            q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
+        if c.qk_norm:   # over the whole projection, heads unsplit
+            q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
+            kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
+        q, kk, vv = (heads(q, c.n_heads), heads(kk, c.n_kv_heads),
+                     heads(vv, c.n_kv_heads))
+        if c.position == "rope":
+            # apply_rotary no-ops the partial slice when rotary_dim == hd
+            q = apply_rotary(q, angles, positions, rotary_dim=c.rotary_dim,
+                             interleaved=c.rope_interleaved)
+            kk = apply_rotary(kk, angles, positions, rotary_dim=c.rotary_dim,
+                              interleaved=c.rope_interleaved)
+        return q, kk, vv
+
+    def _attn_out(self, attn, lp):
+        """Attention's output [..., s, h, hd] through the output
+        projection. attn_o_bias, not use_bias: InternLM has use_bias=False
+        with a real o_proj bias."""
+        attn = attn.reshape(attn.shape[:-2] + (-1,)) @ lp["wo"]
+        return attn + lp["bo"] if self.config.attn_o_bias else attn
 
     def _after_mixer(self, x, attn, new_kv, lp, rng, training):
         """The block from its mixer's output on: residual wiring and the

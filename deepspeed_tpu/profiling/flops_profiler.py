@@ -167,7 +167,7 @@ def get_model_profile(model, batch, rng=None, params=None,
 
 
 # Published per-chip peaks keyed by jax's ``device_kind`` — the one table
-# bench.py, chip_smoke.py, this profiler and the engine's MFU read. Source:
+# chip_smoke.py, this profiler and the engine's MFU read. Source:
 # Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
 # 819 GB/s per chip); jax names that chip "TPU v5 lite".
 DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {

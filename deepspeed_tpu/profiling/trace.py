@@ -1,9 +1,8 @@
 """Profiler traces + range annotations: the one path from the program to
 the profiler's trace.
 
-Parity surface: the reference's NVTX instrumentation
-(``deepspeed/utils/nvtx.py`` ``instrument_w_nvtx``, used throughout
-ZeRO-3) and ``accelerator.range_push/range_pop``. TPU-native form: the
+Parity surface: the reference's NVTX ranges (``deepspeed/utils/nvtx.py``,
+used throughout ZeRO-3) and ``accelerator.range_push/range_pop``. TPU-native form: the
 XLA profiler. :func:`annotate` is the ONLY way the package writes a host
 span: a ``jax.profiler.TraceAnnotation`` (a TraceMe) with plain-int
 attributes, returned unconditionally. A TraceMe is inert unless a profiler
@@ -31,8 +30,7 @@ and every entry point degrades to a no-op when jax cannot be imported.
 from __future__ import annotations
 
 import contextlib
-import functools
-from typing import Iterator, Optional
+from typing import Iterator
 
 
 _JAX = None          # the jax module, False once an import has failed
@@ -95,28 +93,3 @@ class _NoAnnotation(contextlib.nullcontext):
 
     def set_metadata(self, **attrs: int) -> None:
         pass
-
-
-def step(step_num: int):
-    """Step-boundary annotation: groups device ops under one training step
-    in the profiler's step view."""
-    jax = _jax()
-    if jax is None:
-        return contextlib.nullcontext()
-    return jax.profiler.StepTraceAnnotation("train", step_num=step_num)
-
-
-def instrument(fn=None, *, name: Optional[str] = None):
-    """Decorator putting a named range around every call (reference
-    ``instrument_w_nvtx``)."""
-    def wrap(f):
-        label = name or getattr(f, "__qualname__", getattr(f, "__name__", "fn"))
-
-        @functools.wraps(f)
-        def inner(*args, **kwargs):
-            with annotate(label):
-                return f(*args, **kwargs)
-
-        return inner
-
-    return wrap(fn) if fn is not None else wrap

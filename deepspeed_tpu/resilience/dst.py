@@ -32,8 +32,8 @@ docs/dst.md "Region-scale events".
 
 The device is replaced by :class:`SimEngine` — a host-only model of the
 ragged engine's serving contract that *reuses the real*
-:class:`~deepspeed_tpu.inference.ragged.BlockedAllocator`,
-:class:`~deepspeed_tpu.inference.ragged.PrefixCache` and
+:class:`~deepspeed_tpu.inference.kv_cache.BlockedAllocator`,
+:class:`~deepspeed_tpu.inference.kv_cache.PrefixCache` and
 :class:`~deepspeed_tpu.inference.ragged.SequenceDescriptor`, so the
 block-balance audit exercises the actual refcount accounting the
 serving layer must keep balanced; only the model math is replaced by a
@@ -51,9 +51,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..inference.ragged import (BlockedAllocator, NgramIndex, PoolExhausted,
-                                PrefixCache, SequenceDescriptor,
-                                block_balance_report)
+from ..inference.drafter import NgramIndex
+from ..inference.kv_cache import KVLedger, block_balance_report
+from ..inference.ragged import SequenceDescriptor
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.telemetry import Telemetry, set_telemetry
 from ..telemetry.tracing import Tracer, trace_tree_problems, use_tracer
@@ -101,7 +101,7 @@ class SimConfig:
 @dataclass
 class SimKVExport:
     """The simulation's stand-in for
-    :class:`~deepspeed_tpu.inference.ragged.KVExport`: same bookkeeping
+    :class:`~deepspeed_tpu.inference.kv_cache.KVExport`: same bookkeeping
     fields and import-side validation, no page payload (there are no
     pages to copy — the importer re-charges the allocator exactly like
     the real importer does)."""
@@ -139,11 +139,10 @@ class SimEngine:
     def __init__(self, config: Optional[SimConfig] = None):
         self.config = config if config is not None else SimConfig()
         cfg = self.config
-        self.allocator = BlockedAllocator(cfg.n_kv_blocks)
-        self.prefix_cache = (PrefixCache(cfg.kv_block_size)
-                             if cfg.enable_prefix_cache else None)
+        self.cache = KVLedger(cfg)
+        self.allocator = self.cache.allocator
+        self.prefix_cache = self.cache.prefix_cache
         self.seqs: Dict[int, SequenceDescriptor] = {}
-        self._free_slots: List[int] = list(range(cfg.max_seqs))
         self._resume_uids: set = set()
         self.tick_count = 0
         # speculative-decoding surface (mirrors the ragged engine):
@@ -161,12 +160,6 @@ class SimEngine:
         self.kvtier_corrupt_landed = 0
 
     # -- capacity queries (formulas identical to the ragged engine) -----
-    def _available_blocks(self) -> int:
-        free = self.allocator.free_blocks
-        if self.prefix_cache is not None:
-            free += self.prefix_cache.reclaimable_blocks(self.allocator)
-        return free
-
     def blocks_needed(self, n_tokens: int) -> int:
         return -(-int(n_tokens) // self.config.kv_block_size) + 1
 
@@ -182,14 +175,8 @@ class SimEngine:
                 need_blocks += max(0, -(-total // bs) - len(seq.blocks))
             else:
                 need_blocks += self.blocks_needed(length)
-        return (len(new) <= len(self._free_slots)
-                and need_blocks <= self._available_blocks())
-
-    def kv_occupancy(self) -> float:
-        return 1.0 - self.allocator.free_blocks / self.allocator.n_blocks
-
-    def kv_demand(self) -> float:
-        return 1.0 - self._available_blocks() / self.allocator.n_blocks
+        return (len(new) <= self.cache.free_slots
+                and need_blocks <= self.cache.available_blocks())
 
     # -- lifecycle -------------------------------------------------------
     def flush(self, uids: Sequence[int]) -> None:
@@ -201,7 +188,7 @@ class SimEngine:
                     self.prefix_cache.publish(seq.tokens, seq.blocks,
                                               seq.seen, self.allocator)
                 self.allocator.free(seq.blocks)
-                self._free_slots.append(seq.slot)
+                self.cache.give_slot(seq.slot)
 
     def preempt(self, uid: int) -> List[int]:
         seq = self.seqs.get(uid)
@@ -218,7 +205,7 @@ class SimEngine:
         if seq is None:
             return
         self.allocator.free(seq.blocks)
-        self._free_slots.append(seq.slot)
+        self.cache.give_slot(seq.slot)
         self._resume_uids.add(uid)
 
     def trim(self, uid: int, length: int) -> None:
@@ -319,13 +306,12 @@ class SimEngine:
             raise ValueError(
                 f"export carries {export.n_pages} pages for "
                 f"{export.seen} tokens")
-        if not self._free_slots:
+        if not self.cache.free_slots:
             raise RuntimeError("no free sequence slots; flush() first")
-        if need > self.allocator.free_blocks and self.prefix_cache is not None:
-            self.prefix_cache.evict_for(self.allocator, need)
-        blocks = self.allocator.allocate(need)    # may raise PoolExhausted
+        self.cache.make_room(need)                # may raise PoolExhausted
+        blocks = self.allocator.allocate(need)
         self.seqs[uid] = SequenceDescriptor(
-            uid=uid, slot=self._free_slots.pop(),
+            uid=uid, slot=self.cache.take_slot(),
             tokens=[int(t) for t in export.tokens], seen=int(export.seen),
             blocks=blocks, t_admitted=None, t_created=None,
             prompt_len=int(export.prompt_len))
@@ -377,7 +363,7 @@ class SimEngine:
             return []
         from ..serving.kvtier import prefix_hash
 
-        return [prefix_hash(k) for k in self.prefix_cache._entries]
+        return [prefix_hash(k) for k in self.prefix_cache.keys()]
 
     def export_prefix(self, tokens: Sequence[int]):
         """Donor side of cross-replica adoption: longest resident
@@ -423,11 +409,10 @@ class SimEngine:
                 f"{len(export.tokens)} tokens (full blocks required)")
         if len(export.tokens) > cfg.max_context:
             raise ValueError("prefix length exceeds max_context")
-        if tuple(export.tokens) in self.prefix_cache._entries:
+        if export.tokens in self.prefix_cache:
             return False
-        if need > self.allocator.free_blocks:
-            self.prefix_cache.evict_for(self.allocator, need)
-        blocks = self.allocator.allocate(need)    # may raise PoolExhausted
+        self.cache.make_room(need)                # may raise PoolExhausted
+        blocks = self.allocator.allocate(need)
         self.prefix_cache.publish(list(export.tokens), blocks,
                                   len(export.tokens), self.allocator)
         self.allocator.release(blocks)
@@ -438,7 +423,7 @@ class SimEngine:
         bs = self.config.kv_block_size
         for k in range((len(tokens) - 1) // bs, 0, -1):
             key = tuple(int(t) for t in tokens[:k * bs])
-            if key in self.prefix_cache._entries:
+            if key in self.prefix_cache:
                 return
             export = self._cold_tier.get(key)
             if export is None:
@@ -459,11 +444,9 @@ class SimEngine:
         for uid, toks in zip(uids, tokens):
             new = uid not in self.seqs
             if new:
-                if not self._free_slots:
-                    raise RuntimeError("no free sequence slots; flush() first")
+                slot = self.cache.take_slot()        # may raise: none free
                 self._resume_uids.discard(uid)
-                self.seqs[uid] = SequenceDescriptor(
-                    uid=uid, slot=self._free_slots.pop())
+                self.seqs[uid] = SequenceDescriptor(uid=uid, slot=slot)
             seq = self.seqs[uid]
             seq.tokens.extend(int(t) for t in toks)
             if new:
@@ -509,14 +492,7 @@ class SimEngine:
                     f"uid {seq.uid}: context {total} exceeds max_context")
             needs.append(max(0, -(-total // cfg.kv_block_size)
                              - len(seq.blocks)))
-        need_total = sum(needs)
-        if (need_total > self.allocator.free_blocks
-                and self.prefix_cache is not None):
-            self.prefix_cache.evict_for(self.allocator, need_total)
-        if need_total > self.allocator.free_blocks:
-            raise PoolExhausted(
-                f"KV pool exhausted: need {need_total}, have "
-                f"{self.allocator.free_blocks}")
+        self.cache.make_room(sum(needs))
         return needs
 
     def put(self, uids: Sequence[int],

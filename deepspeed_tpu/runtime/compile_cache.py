@@ -34,7 +34,7 @@ def place_compile_cache(config_dir: Optional[str] = None,
     sets the directory and ``config_dir`` (``compile.cache_dir``) is
     ignored with one log line. Otherwise ``config_dir`` if given, else
     ``default_dir`` — the fixed ``<checkout>/.jax_cache`` the repo's own
-    entry points (``chip_smoke.py``, ``bench.py``) pass. Every program is
+    entry points (``chip_smoke.py``, ``benchmarks/run.py``) pass. Every program is
     cached, however small or quick to compile. Idempotent: the first
     placement of the process stands."""
     global _PLACED_DIR

@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..inference.engine import InferenceConfig, InferenceEngine, _sample
+from ..inference.engine import InferenceConfig, InferenceEngine
+from ..inference.sampling import sample
 from ..utils.logging import log_dist
 from .engine import TrainEngine
 
@@ -88,8 +89,8 @@ class HybridEngine:
                     params, last_tokens[:, None],
                     positions=cache_pos[None, None],
                     kv_caches=caches, cache_pos=cache_pos)
-                nxt = _sample(logits[:, 0, :], rng, self.icfg.temperature,
-                              self.icfg.top_k, self.icfg.top_p)
+                nxt = sample(logits[:, 0, :], rng, self.icfg.temperature,
+                             self.icfg.top_k, self.icfg.top_p)
                 return caches, nxt
 
             self._prefill_fn = jax.jit(prefill, donate_argnums=(2,))
@@ -102,8 +103,8 @@ class HybridEngine:
                   jnp.zeros(shape, self.icfg.jnp_dtype))
         rng = jax.random.PRNGKey(self.icfg.seed + self.engine.global_steps)
         logits, caches = self._prefill_fn(params, input_ids, caches)
-        next_tok = _sample(logits, rng, self.icfg.temperature,
-                           self.icfg.top_k, self.icfg.top_p)
+        next_tok = sample(logits, rng, self.icfg.temperature,
+                          self.icfg.top_k, self.icfg.top_p)
         out = [np.asarray(next_tok)]
         finished = np.zeros((b,), bool)
         if eos_token_id is not None:

@@ -14,12 +14,12 @@ replicas behind the same call surface (least-loaded or
 prefix-cache-affinity routing, failover via bit-exact resume,
 disaggregated prefill/decode KV hand-off, telemetry-driven
 autoscaling). The KV leak audit (:func:`block_balance_report` /
-:func:`assert_block_balance`, re-exported from the ragged engine) is
+:func:`assert_block_balance`, re-exported from the cache, inference/kv_cache.py) is
 part of the public serving contract: zero leaked pages after drain on
 every replica. See docs/serving.md.
 """
 
-from ..inference.ragged import (  # noqa: F401
+from ..inference.kv_cache import (  # noqa: F401
     assert_block_balance,
     block_balance_report,
 )

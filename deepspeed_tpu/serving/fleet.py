@@ -894,7 +894,7 @@ class ServingFleet:
         (empty list = zero leaks everywhere, dead replicas included —
         evacuation discards their sequences, so their allocators must
         balance too). Valid when idle; mid-tick reads race drivers."""
-        from ..inference.ragged import block_balance_report
+        from ..inference.kv_cache import block_balance_report
 
         problems: List[str] = []
         for r in self.replicas:
@@ -926,7 +926,7 @@ class ServingFleet:
             pending += pw
             if r.state == ReplicaState.HEALTHY:
                 healthy += 1
-                kv = max(kv, float(r.engine.kv_demand()))
+                kv = max(kv, float(r.engine.cache.demand()))
         return {"queue_depth": queue, "live": live, "pending_work": pending,
                 "healthy_replicas": healthy, "kv_demand": kv,
                 "in_sla": self.in_sla_ratio(),
@@ -1764,7 +1764,7 @@ class ServingFleet:
             # demand, not raw occupancy: cache-reclaimable pages are
             # capacity, and counting them would ratchet the fleet to
             # max_replicas after any warm-cache burst
-            kv = (max(r.engine.kv_demand() for r in healthy)
+            kv = (max(r.engine.cache.demand() for r in healthy)
                   if healthy else 0.0)
         target = compute_serving_replicas(
             max(1, len(healthy)), queue_depth=queue_depth, kv_occupancy=kv,

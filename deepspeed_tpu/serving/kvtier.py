@@ -2,7 +2,7 @@
 
 At scale the hot KV working set (system prompts, few-shot preambles,
 multi-turn histories) is massively shared, yet each replica's
-:class:`~deepspeed_tpu.inference.ragged.PrefixCache` is private. This
+:class:`~deepspeed_tpu.inference.kv_cache.PrefixCache` is private. This
 module promotes prefix residency to a fleet/region resource with three
 cooperating pieces (docs/serving.md "Global KV tier"):
 

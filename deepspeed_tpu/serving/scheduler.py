@@ -72,7 +72,7 @@ class CapacityView:
 
     @property
     def free_slots(self) -> int:
-        return (len(self._engine._free_slots)
+        return (self._engine.cache.free_slots
                 - len(self._admitted_uids))
 
     def fits(self, req: Request) -> bool:
@@ -104,7 +104,7 @@ class CapacityView:
         for length in self._admitted_lens:  # dslint: disable=races -- CapacityView is tick-local: built, charged and read on the single ticking thread inside one _admit pass, then dropped; it is never published to another thread (dsrace sees both driving roles, not the one-tick confinement)
             need += self._engine.blocks_needed(length)
         need += sum(self._live_reserved.values())
-        return max(0, need - self._engine._available_blocks())
+        return max(0, need - self._engine.cache.available_blocks())
 
     # -- speculative token-credit math (docs/serving.md "Speculative
     # scheduling"): drafting consumes only token-budget SLACK, sized by
@@ -137,24 +137,14 @@ class CapacityView:
         return max(1, min(int(lookahead), int(c * lookahead + 0.5)))
 
     def evictable_blocks(self, seq) -> int:
-        """Pages that actually become schedulable if ``seq`` is evicted:
-        those whose every non-cache reference is this sequence's own
-        (they end up free, or cache-only-held — which admission reclaims
-        on demand). Pages shared with another live sequence stay held
-        and must not be credited, or preemption evicts decodes without
-        making the candidate fit."""
-        alloc = self._engine.allocator
-        cache = self._engine.prefix_cache
-        cache_refs = cache._block_refs if cache is not None else {}
-        counts: dict = {}
-        for b in seq.blocks:
-            counts[int(b)] = counts.get(int(b), 0) + 1
-        return sum(1 for b, n in counts.items()
-                   if alloc.refcount(b) <= n + cache_refs.get(b, 0))
+        """Pages that actually become schedulable if ``seq`` is evicted
+        (pages shared with another live sequence are not credited): the
+        cache's count, :meth:`KVLedger.evictable_blocks`."""
+        return self._engine.cache.evictable_blocks(seq.blocks)
 
     @property
     def occupancy(self) -> float:
-        return self._engine.kv_occupancy()
+        return self._engine.cache.occupancy()
 
 
 class SchedulerPolicy:

@@ -46,7 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..inference.ragged import PoolExhausted
+from ..inference.kv_cache import PoolExhausted
 from ..profiling.trace import annotate
 from ..resilience.clock import Clock, get_clock
 from ..resilience.locksan import named_rlock
@@ -923,7 +923,7 @@ class ServingEngine:
     def block_leaks(self) -> List[str]:
         """Allocator block-balance problems (empty = zero leak). Valid
         when idle (post-drain); mid-tick reads race the driver."""
-        from ..inference.ragged import block_balance_report
+        from ..inference.kv_cache import block_balance_report
 
         return block_balance_report(self._engine)["problems"]
 
@@ -1206,7 +1206,7 @@ class ServingEngine:
                 with self._lock:
                     self._enqueue_locked(req, requeue=True)
                 continue
-            if not self._engine._free_slots:
+            if not self._engine.cache.free_slots:
                 # slot exhaustion is TRANSIENT (a live decode finishing
                 # frees one, and adoptions run before admission each
                 # tick): defer rather than burn the export on a
@@ -1887,7 +1887,7 @@ class ServingEngine:
             # the unlocked check-then-write raced them (dsrace finding,
             # PR 15). kv_occupancy is host-side allocator arithmetic —
             # same class of locked engine read as _admit's CapacityView.
-            snap = (depth, live, self._engine.kv_occupancy())
+            snap = (depth, live, self._engine.cache.occupancy())
             if snap == self._last_gauges:   # idle loop: don't re-publish
                 return                      # unchanged values every poll
             self._last_gauges = snap

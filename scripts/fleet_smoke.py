@@ -116,7 +116,7 @@ def _reset(eng) -> None:
 
 
 def _leak_check(engines) -> dict:
-    from deepspeed_tpu.inference.ragged import block_balance_report
+    from deepspeed_tpu.inference.kv_cache import block_balance_report
 
     problems = []
     free_ok = True

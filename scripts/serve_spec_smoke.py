@@ -95,7 +95,7 @@ def _drive(srv, clock, reqs) -> int:
 
 
 def _leak_check(eng) -> bool:
-    from deepspeed_tpu.inference.ragged import block_balance_report
+    from deepspeed_tpu.inference.kv_cache import block_balance_report
 
     rep = block_balance_report(eng)
     if eng.prefix_cache is not None:
@@ -144,7 +144,7 @@ def _run_capacity_leg(model, params, kv_quant: str, budget: int) -> dict:
     """Admission pressure against a pool sized to ``budget`` BYTES under
     ``kv_quant``: every request submitted at t=0, the measured figure is
     the peak number of concurrently-live decode sequences."""
-    from deepspeed_tpu.inference.ragged import kv_blocks_for_bytes
+    from deepspeed_tpu.inference.kv_cache import kv_blocks_for_bytes
     from deepspeed_tpu.resilience import SimClock, use_clock
     from deepspeed_tpu.serving import ServingEngine
 
@@ -190,7 +190,7 @@ def _run_handoff_leg(model, params) -> dict:
     prefill one sequence on an int8 engine, export, and read the
     ``kv_handoff`` row (logical = fp bytes, wire = payload + scales)."""
     from deepspeed_tpu.comm.comm import get_comms_logger
-    from deepspeed_tpu.inference.ragged import assert_block_balance
+    from deepspeed_tpu.inference.kv_cache import assert_block_balance
 
     ledger = get_comms_logger()
     ledger.reset()
@@ -229,7 +229,7 @@ def _run_handoff_leg(model, params) -> dict:
 
 
 def main() -> int:
-    from deepspeed_tpu.inference.ragged import kv_page_bytes
+    from deepspeed_tpu.inference.kv_cache import kv_page_bytes
 
     model, params = _model()
 

@@ -103,7 +103,7 @@ def _workload(rng: np.random.Generator):
 def _leak_check(eng) -> dict:
     """Post-drain block accounting: zero problems, and with the prefix
     cache dropped every page back on the free list."""
-    from deepspeed_tpu.inference.ragged import block_balance_report
+    from deepspeed_tpu.inference.kv_cache import block_balance_report
 
     rep = block_balance_report(eng)
     eng.prefix_cache.drop_all(eng.allocator)

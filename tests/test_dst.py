@@ -354,7 +354,7 @@ class _LeakyEngine(SimEngine):
         seq = self.seqs.pop(uid, None)
         if seq is None:
             return
-        self._free_slots.append(seq.slot)     # slot back, blocks leaked
+        self.cache.give_slot(seq.slot)        # slot back, blocks leaked
         self._resume_uids.add(uid)
 
 

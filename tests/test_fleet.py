@@ -21,12 +21,11 @@ from deepspeed_tpu.elasticity import (
     compute_serving_replicas,
     serving_replica_candidates,
 )
-from deepspeed_tpu.inference.ragged import (
+from deepspeed_tpu.inference.kv_cache import (
     PoolExhausted,
-    RaggedConfig,
-    RaggedInferenceEngine,
     assert_block_balance,
 )
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import Llama
 from deepspeed_tpu.resilience import FaultInjector, install_fault_injector
 from deepspeed_tpu.serving import (
@@ -592,8 +591,8 @@ def test_kv_demand_ignores_reclaimable_cache(model_and_params):
     t0 = int(np.argmax(logits[0]))
     eng.put([4], [[t0]])
     eng.flush([4])                      # publishes full blocks into cache
-    assert eng.kv_occupancy() > 0.0     # cache holds pages
-    assert eng.kv_demand() == 0.0       # ...all reclaimable: zero demand
+    assert eng.cache.occupancy() > 0.0     # cache holds pages
+    assert eng.cache.demand() == 0.0       # ...all reclaimable: zero demand
     eng.prefix_cache.drop_all(eng.allocator)
     assert_block_balance(eng, expect_free=eng.config.n_kv_blocks)
 

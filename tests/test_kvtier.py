@@ -350,9 +350,11 @@ def test_eviction_races_inflight_export_and_adoption_real_engine():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
 
-    from deepspeed_tpu.inference.ragged import (RaggedConfig,
-                                                RaggedInferenceEngine,
-                                                block_balance_report)
+    from deepspeed_tpu.inference.kv_cache import block_balance_report
+    from deepspeed_tpu.inference.ragged import (
+        RaggedConfig,
+        RaggedInferenceEngine,
+    )
     from deepspeed_tpu.models import Llama
     from deepspeed_tpu.serving import ServingFleet
     from deepspeed_tpu.serving.router import prefix_key

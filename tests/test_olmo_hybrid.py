@@ -17,10 +17,12 @@ import pytest
 from benchmarks import weights
 from benchmarks.reference import olmo_hybrid as ref
 from deepspeed_tpu.checkpoint import hf
-from deepspeed_tpu.inference.ragged import (RaggedConfig,
-                                            RaggedInferenceEngine,
-                                            kv_blocks_for_bytes,
-                                            kv_page_bytes, state_pool_bytes)
+from deepspeed_tpu.inference.kv_cache import (
+    kv_blocks_for_bytes,
+    kv_page_bytes,
+    state_pool_bytes,
+)
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models.transformer import Transformer
 from deepspeed_tpu.ops import gated_delta as gd
 

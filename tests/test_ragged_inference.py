@@ -5,11 +5,8 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.engine import InferenceConfig, InferenceEngine
-from deepspeed_tpu.inference.ragged import (
-    BlockedAllocator,
-    RaggedConfig,
-    RaggedInferenceEngine,
-)
+from deepspeed_tpu.inference.kv_cache import BlockedAllocator
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import Llama
 import jax
 import jax.numpy as jnp
@@ -148,7 +145,7 @@ def test_flush_releases_resources():
     assert eng.allocator.free_blocks < free0
     eng.flush([1])
     assert eng.allocator.free_blocks == free0
-    assert len(eng._free_slots) == eng.config.max_seqs
+    assert eng.cache.free_slots == eng.config.max_seqs
 
 
 def test_max_context_rejected():
@@ -814,7 +811,7 @@ def test_speculative_rejects_sampling():
 
 
 def test_prompt_lookup_drafting():
-    from deepspeed_tpu.inference.ragged import _prompt_lookup
+    from deepspeed_tpu.inference.drafter import _prompt_lookup
 
     ctx = [1, 2, 3, 9, 9, 1, 2, 3]
     assert _prompt_lookup(ctx, 3, 2) == [9, 9]     # follows [1,2,3]

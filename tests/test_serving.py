@@ -14,12 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference.ragged import (
-    RaggedConfig,
-    RaggedInferenceEngine,
+from deepspeed_tpu.inference.kv_cache import (
     assert_block_balance,
     block_balance_report,
 )
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import Llama
 from deepspeed_tpu.resilience import FaultInjector, install_fault_injector
 from deepspeed_tpu.serving import (

@@ -26,15 +26,13 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.config import ServingConfig
-from deepspeed_tpu.inference.ragged import (
-    NgramIndex,
-    RaggedConfig,
-    RaggedInferenceEngine,
-    _prompt_lookup,
+from deepspeed_tpu.inference.drafter import NgramIndex, _prompt_lookup
+from deepspeed_tpu.inference.kv_cache import (
     assert_block_balance,
     kv_blocks_for_bytes,
     kv_page_bytes,
 )
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import Llama
 from deepspeed_tpu.ops.quantizer import dequantize_kv, quantize_kv
 from deepspeed_tpu.serving import Request, ServingEngine
@@ -279,7 +277,7 @@ def test_verify_trim_failure_takes_tick_fault_path(model_and_params):
     per-request tick fault — engine state discarded, request requeued,
     stream still token-identical — never an escaped exception that
     leaves trimmed/untrimmed streams diverged from their requests."""
-    from deepspeed_tpu.inference.ragged import PoolExhausted
+    from deepspeed_tpu.inference.kv_cache import PoolExhausted
 
     t_plain, _, _, _ = _serve_one(model_and_params, spec=False)
 
@@ -338,7 +336,7 @@ def test_quantized_pool_admits_more_sequences(model_and_params):
     """At a FIXED byte budget, the int8 pool admits >= 1.8x the
     concurrent sequences (same prompt workload, count admissions until
     PoolExhausted)."""
-    from deepspeed_tpu.inference.ragged import PoolExhausted
+    from deepspeed_tpu.inference.kv_cache import PoolExhausted
 
     model, _ = model_and_params
     fp_cfg = _cfg(max_seqs=32, n_kv_blocks=1, enable_prefix_cache=False)
