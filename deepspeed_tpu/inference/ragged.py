@@ -229,6 +229,21 @@ class RaggedInferenceEngine:
                 jax.tree_util.tree_map(
                     lambda sp: NamedSharding(topology.mesh, sp), specs,
                     is_leaf=lambda x: isinstance(x, PartitionSpec)))
+        # the expert stacks stay whole in the step and ragged_dot indexes
+        # the layer (Transformer.layer_params); under expert parallelism
+        # the layer axis cannot merge with the sharded expert axis, and
+        # the step slices as it does for every other leaf
+        self._experts_in_place = (topology is None
+                                  or topology.expert_parallel_size == 1)
+        whole = [self.params["layers"][k] for k in model.stacked_operands
+                 if self._experts_in_place
+                 and k in self.params.get("layers", {})]
+        self.expert_bytes_in_place = sum(
+            a.size * a.dtype.itemsize for a in whole)
+        if self._telemetry.enabled:
+            self._telemetry.registry.gauge(
+                "inference/expert_bytes_in_place").set(
+                    self.expert_bytes_in_place)
         # the cache (kv_cache.py): the host's books of pages, prefixes and
         # slots, and the device leaves by name
         self.cache = kv_cache.KVLedger(cfg)
@@ -279,7 +294,8 @@ class RaggedInferenceEngine:
         self._buckets = [b for b in (64, 256, 1024) if b < cfg.token_budget] \
             + [cfg.token_budget]
         log_dist(f"RaggedInferenceEngine: budget={cfg.token_budget} "
-                 f"blocks={cfg.n_kv_blocks}x{cfg.kv_block_size}")
+                 f"blocks={cfg.n_kv_blocks}x{cfg.kv_block_size} "
+                 f"expert_bytes_in_place={self.expert_bytes_in_place}")
 
     @property
     def _telemetry(self):
@@ -1692,7 +1708,8 @@ class RaggedInferenceEngine:
             leaves = {f: list(ls) for f, ls in pools._asdict().items()}
             for li in range(c.n_layers):
                 with jax.named_scope("weights"):
-                    kind, lp = model.layer_params(params["layers"], li)
+                    kind, lp = model.layer_params(
+                        params["layers"], li, self._experts_in_place)
                 at = c.layers_of(kind).index(li)
                 own = {f: leaves[f][at] for f in kv_cache.OWNS[kind]
                        if leaves[f]}

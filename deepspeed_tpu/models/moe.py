@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.moe import GateConfig, MoELayer
+from ..parallel.moe import RAGGED_OPERANDS, GateConfig, MoELayer
 from .transformer import Transformer, TransformerConfig
 
 
@@ -62,6 +62,8 @@ class MoETransformerConfig(TransformerConfig):
 class MoETransformer(Transformer):
     """Transformer with MoE FFN in every block."""
 
+    stacked_operands = RAGGED_OPERANDS
+
     def __init__(self, config: MoETransformerConfig):
         super().__init__(config)
         self.moe = MoELayer(config.d_model, config.d_ff, config.gate_config(),
@@ -81,7 +83,8 @@ class MoETransformer(Transformer):
     def _mlp(self, h, lp, rng=None, training=False):
         moe_params = {k: lp[k] for k in ("wg", "w_up", "w_down", "w_gate",
                                          "b_up", "b_down") if k in lp}
-        out, aux = self.moe.apply(moe_params, h, rng=rng, training=training)
+        out, aux = self.moe.apply(moe_params, h, rng=rng, training=training,
+                                  layer=lp.get("layer"))
         return out, aux * self.config.aux_loss_weight
 
     def partition_specs(self, params, topo=None) -> Dict[str, Any]:

@@ -382,18 +382,36 @@ class Transformer:
                         scale=1.0 / np.sqrt(d * 2 * c.n_layers)),
         }
 
-    def layer_params(self, layers, li: int) -> Tuple[str, Dict[str, Any]]:
+    #: leaves of the common stack that are operands of an operation which
+    #: indexes the stack itself (layer_params, ``in_place``)
+    stacked_operands: Tuple[str, ...] = ()
+
+    def layer_params(self, layers, li: int, in_place: bool = False
+                     ) -> Tuple[str, Dict[str, Any]]:
         """(kind, the leaves of layer ``li``) out of the stacked tree: the
         common stack at ``li`` and, with layer_types, its kind's stack at
-        the layer's index among its kind. ``li`` is a python int: the slice
-        is static, and XLA reads it in place."""
+        the layer's index among its kind. ``li`` is a python int, so the
+        slice is static. A dense product fuses it into its own operand
+        read where the compiler keeps the leaf's layout, and copies the
+        slice first where it chooses another (Mistral's ``wo`` / ``wk`` /
+        ``wv`` on the TPU). An operation that cannot fuse a slice (the
+        experts' ``ragged_dot``) has the layer's matrices copied on every
+        call: with ``in_place`` the ``stacked_operands`` are handed over
+        whole, with ``lp["layer"] = li`` beside them, for the operation to
+        index the stack itself. A caller passes ``in_place`` only where
+        those leaves' leading two axes are unsharded (merging the layer
+        axis with a sharded expert axis is no bitcast)."""
         c = self.config
+        whole = self.stacked_operands if in_place else ()
+        lp = jax.tree_util.tree_map(
+            lambda a: a[li], {k: v for k, v in layers.items()
+                              if k not in whole + ("full", "linear")})
+        if whole:
+            lp.update({k: layers[k] for k in whole if k in layers}, layer=li)
         if c.layer_types is None:
-            return "full", jax.tree_util.tree_map(lambda a: a[li], layers)
+            return "full", lp
         kind = c.layer_types[li]
         at = c.layers_of(kind).index(li)
-        lp = {k: v[li] for k, v in layers.items()
-              if k not in ("full", "linear")}
         lp.update({k: v[at] for k, v in layers[kind].items()})
         return kind, lp
 

@@ -112,8 +112,13 @@ def top_k_gating(logits: jnp.ndarray, cfg: GateConfig, cap: int,
     return combine.astype(jnp.float32), dispatch, aux
 
 
+#: the expert leaves that are operands of ``ragged_dot`` in no_drop_moe
+RAGGED_OPERANDS = ("w_gate", "w_up", "w_down")
+
+
 def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
-                params: Dict[str, Any], activation: str) -> jnp.ndarray:
+                params: Dict[str, Any], activation: str,
+                layer: Optional[int] = None) -> jnp.ndarray:
     """Sort-based NO-DROP expert dispatch on grouped GEMMs.
 
     The TPU analog of FastGen's ``moe_gather``/``moe_scatter`` +
@@ -127,29 +132,45 @@ def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
     output is independent of co-scheduled traffic.
 
     x_flat: [S, d]; probs/idx: [S, k] top-k gate weights / expert ids.
+
+    ``layer``: the layer's place in the stack when the ``RAGGED_OPERANDS``
+    arrive unsliced, ``[L, E, K, N]`` (biases stay a layer's own). On the
+    TPU ``ragged_dot`` is an operation of its own whose operand has to be
+    a whole buffer, so a ``w[layer]`` in front of it is a copy of the
+    layer's expert matrices on every call. Instead the stack is viewed as
+    ``L*E`` groups (a bitcast) and the layer's E group sizes sit at
+    ``[layer*E, layer*E + E)`` of a zero vector: the product walks only
+    the tiles that hold rows, so the other layers' matrices are not read
+    (on a v5e within 0.4% of the layer's own leaf at 16 to 4096 rows;
+    PERF.md, PR 31).
     """
     S, k = idx.shape
-    E = params["w_up"].shape[0]
+    w = {n: params[n] for n in RAGGED_OPERANDS if n in params}
+    E = w["w_up"].shape[-3]
     flat_e = idx.reshape(-1)                          # [S*k]
     order = jnp.argsort(flat_e)                       # stable: tokens in order
     tok = jnp.repeat(jnp.arange(S), k)[order]         # source token per pair
     xs = x_flat[tok]                                  # moe_gather
     group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    if layer is not None:
+        L = w["w_up"].shape[0]
+        w = {n: a.reshape((L * E,) + a.shape[2:]) for n, a in w.items()}
+        group_sizes = jnp.pad(group_sizes, (layer * E, (L - 1 - layer) * E))
 
     e_sorted = flat_e[order]                          # expert id per row
     if activation == "silu_glu":
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, params["w_gate"], group_sizes)) \
-            * jax.lax.ragged_dot(xs, params["w_up"], group_sizes)
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, w["w_gate"], group_sizes)) \
+            * jax.lax.ragged_dot(xs, w["w_up"], group_sizes)
     else:
-        h = jax.lax.ragged_dot(xs, params["w_up"], group_sizes)
+        h = jax.lax.ragged_dot(xs, w["w_up"], group_sizes)
         if "b_up" in params:
             h = h + params["b_up"][e_sorted].astype(h.dtype)
         h = jax.nn.gelu(h)
-    ys = jax.lax.ragged_dot(h, params["w_down"], group_sizes)  # [S*k, d]
+    ys = jax.lax.ragged_dot(h, w["w_down"], group_sizes)  # [S*k, d]
     if "b_down" in params:
         ys = ys + params["b_down"][e_sorted].astype(ys.dtype)
-    w = probs.reshape(-1)[order][:, None].astype(ys.dtype)
-    return jnp.zeros_like(x_flat).at[tok].add((ys * w).astype(x_flat.dtype))
+    gate = probs.reshape(-1)[order][:, None].astype(ys.dtype)
+    return jnp.zeros_like(x_flat).at[tok].add((ys * gate).astype(x_flat.dtype))
 
 
 class MoELayer:
@@ -191,11 +212,13 @@ class MoELayer:
         return p
 
     def apply(self, params: Dict[str, Any], x: jnp.ndarray,
-              rng: Optional[jax.Array] = None, training: bool = True
+              rng: Optional[jax.Array] = None, training: bool = True,
+              layer: Optional[int] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """x: [b, s, d] -> (out [b, s, d], aux_loss). Token groups = batch
         rows (group-limited routing like the reference's per-group capacity).
-        Eval / no-drop uses the sort-based grouped-GEMM path."""
+        Eval / no-drop uses the sort-based grouped-GEMM path; ``layer`` is
+        no_drop_moe's (the expert matrices arrive as the whole stack)."""
         b, s, d = x.shape
         cfg = self.gate
         # device scopes (metadata only): ``router`` and ``experts`` name the
@@ -213,7 +236,7 @@ class MoELayer:
                 aux = cfg.n_experts * jnp.sum(jnp.mean(probs, axis=0) * assign)
             with jax.named_scope("experts"):
                 out = no_drop_moe(x.reshape(b * s, d), topw, topi, params,
-                                  self.activation)
+                                  self.activation, layer)
             return out.reshape(b, s, d), aux
         with jax.named_scope("router"):
             cap = capacity(s, cfg, training)

@@ -119,3 +119,101 @@ def test_moe_param_count():
     params = model.init(jax.random.PRNGKey(0))
     actual = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params))
     assert actual == model.config.param_count()
+
+
+# ----------------------------------------------------------------------
+# the experts' products on the stored stack (no_drop_moe's ``layer``)
+def _stacked_experts(activation, L=3, E=4, d=16, f=32, S=24, k=2):
+    """A stack of expert banks, rows for one layer and a routing in which
+    expert 2 gets no row."""
+    moe = MoELayer(d, f, GateConfig(n_experts=E, top_k=k),
+                   activation=activation, use_bias=activation == "gelu")
+    stack = moe.init(jax.random.PRNGKey(3), n_layers=L)
+    for i, b in enumerate(("b_up", "b_down")):     # init's biases are zeros
+        if b in stack:
+            stack[b] = 0.1 * jax.random.normal(jax.random.PRNGKey(40 + i),
+                                               stack[b].shape)
+    kx, kp, ki = jax.random.split(jax.random.PRNGKey(5), 3)
+    idx = jax.random.randint(ki, (S, k), 0, E - 1)
+    idx = jnp.where(idx >= 2, idx + 1, idx)        # never expert 2
+    return (stack, jax.random.normal(kx, (S, d)),
+            jax.nn.softmax(jax.random.normal(kp, (S, k)), -1), idx)
+
+
+@pytest.mark.parametrize("li", [0, 1, 2], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("activation", ["silu_glu", "gelu"])
+def test_no_drop_moe_on_the_stack_is_the_sliced_call(activation, li):
+    """The expert matrices unsliced and the layer's place beside them give
+    bit for bit what the layer's own slices give (the other layers' groups
+    are empty), biases and an expert with no row included."""
+    from deepspeed_tpu.parallel.moe import RAGGED_OPERANDS, no_drop_moe
+
+    stack, x, probs, idx = _stacked_experts(activation)
+    assert 2 not in np.asarray(idx)
+    sliced = {n: a[li] for n, a in stack.items()}
+    whole = {n: a if n in RAGGED_OPERANDS else a[li]
+             for n, a in stack.items()}
+    want = jax.jit(lambda p: no_drop_moe(x, probs, idx, p, activation))(sliced)
+    got = jax.jit(lambda p: no_drop_moe(x, probs, idx, p, activation,
+                                        layer=li))(whole)
+    assert float(jnp.max(jnp.abs(want))) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _tiny_hybrid():
+    from deepspeed_tpu.checkpoint import hf
+    from deepspeed_tpu.models.transformer import Transformer
+
+    hc = {"model_type": "olmo_hybrid", "vocab_size": 64, "hidden_size": 32,
+          "intermediate_size": 64, "num_hidden_layers": 4,
+          "num_attention_heads": 4, "num_key_value_heads": 2,
+          "max_position_embeddings": 64, "attention_bias": False,
+          "rms_norm_eps": 1e-6, "tie_word_embeddings": False,
+          "layer_types": ["linear_attention"] * 3 + ["full_attention"],
+          "linear_num_key_heads": 2, "linear_num_value_heads": 2,
+          "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+          "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+          "rope_parameters": {"rope_theta": None}}
+    return Transformer(hf.olmo_hybrid_config(hc, 4))
+
+
+@pytest.mark.parametrize("family", ["dense", "layer_types", "moe",
+                                    "moe_in_place"])
+def test_layer_params_slices_every_leaf_but_the_named_operands(family):
+    """A dense tree and a ``layer_types`` tree come back as they always
+    did, whatever ``in_place`` says; only a model that names
+    ``stacked_operands`` keeps those leaves whole, with the layer beside
+    them, and only when asked."""
+    from deepspeed_tpu.models import Llama
+
+    in_place = family != "moe"
+    if family == "dense":
+        model = Llama("tiny", n_layers=3, d_model=32, n_heads=4,
+                      vocab_size=64, max_seq_len=32)
+    elif family == "layer_types":
+        model = _tiny_hybrid()
+    else:
+        model = GPTMoE("tiny", n_experts=4, n_layers=3, d_model=32,
+                       n_heads=4, vocab_size=64, max_seq_len=32)
+    layers = model.init(jax.random.PRNGKey(0))["layers"]
+    c = model.config
+    for li in range(c.n_layers):
+        kind, lp = model.layer_params(layers, li, in_place)
+        whole = model.stacked_operands if family == "moe_in_place" else ()
+        want = {k: v[li] for k, v in layers.items()
+                if k not in ("full", "linear") and k not in whole}
+        if c.layer_types is not None:
+            assert kind == c.layer_types[li]
+            at = c.layers_of(kind).index(li)
+            want.update({k: v[at] for k, v in layers[kind].items()})
+        else:
+            assert kind == "full"
+        if whole:
+            assert lp.pop("layer") == li
+            assert whole == ("w_gate", "w_up", "w_down")
+            for k in ("w_up", "w_down"):           # gelu experts: no w_gate
+                assert lp.pop(k) is layers[k]
+        assert sorted(lp) == sorted(want)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(lp[k]),
+                                          np.asarray(want[k]))

@@ -234,6 +234,86 @@ def test_ragged_serves_moe_model():
         model, params, {7: list(range(1, 9)), 9: list(range(20, 30))}, 6)
 
 
+def _tiny_moe(activation):
+    from deepspeed_tpu.models.moe import MoETransformer, MoETransformerConfig
+
+    glu = activation == "silu_glu"
+    return MoETransformer(MoETransformerConfig(
+        vocab_size=64, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,
+        d_ff=64, max_seq_len=64, n_experts=4, top_k=2, activation=activation,
+        norm="rms" if glu else "layer", position="rope" if glu else "learned",
+        use_bias=not glu, tie_embeddings=not glu, use_flash=False,
+        remat=False))
+
+
+@pytest.mark.parametrize("activation", ["silu_glu", "gelu"])
+def test_put_reads_expert_stacks_in_place_and_matches_apply(activation):
+    """A Mixtral-shaped and a GPT-MoE-shaped (gelu, expert biases) model
+    through ``put``, a prompt and then three decode steps: the step hands
+    ``ragged_dot`` the whole expert stacks and the layer's place
+    (``no_drop_moe``), and every logit row is ``model.apply``'s."""
+    from deepspeed_tpu.parallel.mesh import reset_topology
+
+    reset_topology()
+    model = _tiny_moe(activation)
+    params = model.init(jax.random.PRNGKey(2))
+    if activation == "gelu":
+        for i, b in enumerate(("b_up", "b_down")):
+            params["layers"][b] = 0.1 * jax.random.normal(
+                jax.random.PRNGKey(i), params["layers"][b].shape)
+    eng = RaggedInferenceEngine(
+        model, RaggedConfig(token_budget=32, max_seqs=2, kv_block_size=8,
+                            n_kv_blocks=16, max_context=64,
+                            dtype=jnp.float32), params=params)
+    assert eng.expert_bytes_in_place == sum(
+        params["layers"][k].nbytes for k in model.stacked_operands
+        if k in params["layers"]) > 0
+    toks = [int(t) for t in
+            np.random.default_rng(3).integers(1, 64, (14,))]
+    fed = toks[:10]
+    got = [eng.put([1], [fed])[0]]
+    for t in toks[10:13]:
+        fed = fed + [t]
+        got.append(eng.put([1], [[t]])[0])
+    want = np.asarray(model.apply(params, jnp.asarray([fed], jnp.int32)))[0]
+    np.testing.assert_allclose(np.asarray(got), want[9:13], rtol=2e-4,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("family", ["dense", "moe", "moe_expert_parallel"])
+def test_expert_bytes_in_place_gauge(family, tmp_path):
+    """``inference/expert_bytes_in_place``: the expert stacks' bytes where
+    the step indexes them by layer, 0 for a dense model and under expert
+    parallelism (there the step slices, as for every other leaf)."""
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.telemetry import Telemetry, set_telemetry
+
+    class Cfg:
+        enabled = True
+        output_dir = str(tmp_path)
+
+    mesh_mod.reset_topology()
+    model = _llama() if family == "dense" else _tiny_moe("silu_glu")
+    topo = mesh_mod.Topology.build_virtual({"expert": 2}) \
+        if family == "moe_expert_parallel" else None
+    t = Telemetry(config=Cfg())
+    set_telemetry(t)
+    try:
+        eng = RaggedInferenceEngine(
+            model, RaggedConfig(token_budget=16, max_seqs=2, kv_block_size=8,
+                                n_kv_blocks=16, max_context=32,
+                                dtype=jnp.float32), topology=topo)
+        want = 3 * 3 * 4 * 32 * 64 * 4 if family == "moe" else 0
+        assert eng.expert_bytes_in_place == want
+        assert t.registry.gauge(
+            "inference/expert_bytes_in_place").value == want
+        assert eng._experts_in_place == (family != "moe_expert_parallel")
+    finally:
+        t.close()
+        set_telemetry(None)
+        mesh_mod.reset_topology()
+
+
 def test_ragged_serves_windowed_moe():
     """Mixtral-class serving: routed experts + a BINDING sliding window
     in the ragged engine, token-exact vs the dense-KV engine."""
@@ -495,6 +575,9 @@ def test_ragged_expert_parallel_serving(kernel_path, monkeypatch):
                                    topology=topo)
     got = eng_ep.generate(dict(prompts), max_new_tokens=6)
     assert got == want, (got, want)
+    # the layer axis cannot merge with a sharded expert axis: the sharded
+    # step slices its expert leaves, the unsharded one reads them in place
+    assert eng._experts_in_place and not eng_ep._experts_in_place
 
 
 @HEAD_SHAPES

@@ -14,9 +14,9 @@ decode shape; the int4-KV refusal), one whole train step and one ragged
 serving step of the smoke model at reduced depth, the four-chip
 ZeRO-3 step ``chip_smoke.py --chips 4`` runs, and what the compiled
 serving step does to its KV pool (rows written in place, on one chip and
-with the pool sharded over two), and the 12-layer Olmo-Hybrid step of the
+with the pool sharded over two), the 12-layer Olmo-Hybrid step of the
 benchmark's cell with both of its caches (fits, copies no leaf and no
-weight).
+weight), and the Mixtral cell's step (copies no expert matrix).
 
 One file on purpose: only the xdist worker that gets this file loads
 libtpu, inside the module-scoped ``topo`` fixture — never at import.
@@ -483,11 +483,12 @@ def test_ragged_step_writes_pool_in_place(one_chip, on_tpu, T, kv_quant):
 
 # ----------------------------------------------------------------------
 # Olmo-Hybrid-7B as the benchmark serves it: 12 layers, two kinds of cache
-def compile_olmo_hybrid_step(device_sharding, T: int, live_pages: int):
-    """The SplitFuse step of ``benchmarks/configs/olmo-hybrid-7b.json``
-    with the pools its ``engine`` asks for (4096 pages over the 3 full
-    layers, the state pool of 64 slots over the 9 linear ones). The engine
-    is built under ``eval_shape``: its pools are shapes, no byte is held."""
+def compile_cell_step(config: str, device_sharding, T: int, live_pages: int):
+    """The SplitFuse step of ``benchmarks/configs/<config>.json`` at its
+    file's depth, with the pools its ``engine`` asks for (Olmo-Hybrid:
+    4096 pages over the 3 full layers, the state pool of 64 slots over the
+    9 linear ones). The engine is built under ``eval_shape``: its pools
+    are shapes, no byte is held."""
     import json
 
     from benchmarks import harness
@@ -495,7 +496,7 @@ def compile_olmo_hybrid_step(device_sharding, T: int, live_pages: int):
                                                 RaggedInferenceEngine)
 
     cfg = json.load(open(os.path.join(harness.HERE, "configs",
-                                      "olmo-hybrid-7b.json")))
+                                      config + ".json")))
     e = cfg["engine"]
     model = harness.find("architectures", cfg["architecture"]).build(
         cfg, cfg["num_hidden_layers"])
@@ -550,7 +551,7 @@ def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
     ``full`` and ``linear``, are read in place by the products that use
     them: what Mixtral's expert stacks pay 17 ms a tick for, PERF.md
     section 5, this layout does not pay)."""
-    compiled, c, e = compile_olmo_hybrid_step(one_chip, T, 128)
+    compiled, c, e = compile_cell_step("olmo-hybrid-7b", one_chip, T, 128)
     assert _device_bytes(compiled) < 15.75e9
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == len(c.layers_of("full")) == 3
@@ -577,6 +578,36 @@ def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
               and dt == "bf16" and T not in map(int, dims.split(","))
               and np.prod(list(map(int, dims.split(",")))) >= weight]
     assert not copied, copied
+
+
+@pytest.mark.parametrize("T", [64, 2048], ids=["decode", "prefill_chunk"])
+def test_mixtral_step_reads_expert_stacks_in_place(one_chip, on_tpu, T):
+    """Rehearsal 3 for the cell ``mixtral-8x7b.chat``: no operation of the
+    2-layer step yields a layer's expert matrices (8 x 4096 x 14336 bf16)
+    or more. ``ragged_dot`` cannot fuse a ``w[li]`` into its operand read,
+    so a sliced leaf is copied on every tick (three multi-output
+    ``slice_bitcast_fusion``s, 5.6 GB written and read again: 17 of the
+    tick's 28 ms of device time before PR 31, and 5.6 GB of the program's
+    temporaries); the products index the whole stack by group instead
+    (``no_drop_moe``'s ``layer``)."""
+    # the cell runs at JAX's default matmul precision; under conftest's
+    # "highest" XLA:TPU's ragged_dot kernel refuses bf16 operands
+    with jax.default_matmul_precision("default"):
+        compiled, c, e = compile_cell_step("mixtral-8x7b", one_chip, T, 128)
+    hlo = compiled.as_text()
+    assert hlo.count("ragged-dot") >= 3 * c.n_layers
+    leaf = c.n_experts * c.d_model * c.d_ff
+    entry = hlo[hlo.index("\nENTRY "):]
+    # results, tuples too (the parent's copies are multi-output fusions)
+    made = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) (copy|slice|dynamic-slice"
+                      r"|fusion)\(", re.M)
+    copied = [(op, name) for name, results, op
+              in made.findall(entry[:entry.index("\n}")])
+              if any(np.prod(list(map(int, dims.split(",")))) >= leaf
+                     for dims in re.findall(r"bf16\[([\d,]+)\]", results))]
+    assert not copied, copied
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert _device_bytes(compiled) < 15.75 * 2 ** 30
 
 
 def test_tp2_ragged_step_gathers_no_pool_leaf(topo, on_tpu):
