@@ -126,13 +126,35 @@ class RaggedConfig:
     kv_quant: str = "none"
 
 
+class _Step:
+    """The jitted SplitFuse step behind the two-result call it has always
+    answered: ``(logits, pools)``. The program has a third result, each
+    slot's greedy token id; a call leaves it on the engine (``_step_ids``)
+    for ``_put``, so whoever calls, wraps or warms ``_step_fn`` compiles
+    and runs the one program the serving tick runs. Everything else
+    (``lower``, ``_cache_size``) is the jitted function's."""
+
+    def __init__(self, engine, jitted):
+        self._engine = engine
+        self._jitted = jitted
+
+    def __call__(self, *args):
+        logits, self._engine._step_ids, pools = self._jitted(*args)
+        return logits, pools
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 class RaggedInferenceEngine:
     """Continuous-batching engine over a deepspeed_tpu Transformer.
 
     ``put(uids, tokens)`` runs ONE compiled ragged step mixing prefill
     chunks and decodes (Dynamic SplitFuse); returns next-token logits per
     uid (NaN rows for uids whose prompt is still being prefilled across
-    steps). ``generate`` drives put/flush to completion.
+    steps), or, for a caller that has declared greedy decoding
+    (``return_token_ids``), the token ids the step chose (``-1`` there).
+    ``generate`` drives put/flush to completion.
     """
 
     def __init__(self, model, config: Optional[RaggedConfig] = None,
@@ -256,7 +278,9 @@ class RaggedInferenceEngine:
         self._resume_uids: set = set()
         self.kv_pool = kv_cache.new_pool(c, cfg, topology)
         self._rows_buf: Optional[np.ndarray] = None    # _rows_out
+        self._token_ids = False     # return_token_ids
         self._step_fn = None
+        self._step_ids = None       # the last step's ids, on the device
         self._core_fn = None
         self._decode_fn = None
         self._verify_fn = None
@@ -876,17 +900,37 @@ class RaggedInferenceEngine:
             budget -= take
         return sched
 
+    def return_token_ids(self, on: bool = True) -> None:
+        """Declared by a caller that decodes greedily (``ServingEngine``
+        does, at construction): from now on the first result of
+        :meth:`put` and :meth:`put_spec` is ``[len(uids)]`` int32, each
+        sequence's greedy next token as the step computed it on the
+        device (``argmax`` of its float32 logits, first index on a tie,
+        as ``np.argmax``), and ``-1`` where the logits form has a NaN row.
+        A tick then brings back ``4 * max_seqs`` bytes, not ``max_seqs *
+        vocab * 4`` (span ``ragged.fetch``'s ``bytes``, counter
+        ``inference/fetch_bytes``). The engine's own ``generate`` /
+        ``stream`` read logits whatever is declared here."""
+        self._token_ids = bool(on)
+
     def put(self, uids: Sequence[int], tokens: Sequence[Sequence[int]]) -> np.ndarray:
         """Admit new tokens for ``uids`` and run one ragged step.
 
         Returns [len(uids), vocab] fp32 logits of each sequence's latest
         processed token; rows are NaN while a long prompt is still
-        mid-prefill (call put(uid, []) again to continue it).
+        mid-prefill (call put(uid, []) again to continue it). After
+        :meth:`return_token_ids`: [len(uids)] int32 greedy token ids,
+        ``-1`` for those rows.
         """
         with annotate("ragged.put") as span:
-            return self._put(span, uids, tokens)
+            return self._put(span, uids, tokens, self._token_ids)
 
-    def _put(self, span, uids, tokens) -> np.ndarray:
+    def _put_logits(self, uids, tokens) -> np.ndarray:
+        """:meth:`put` in the logits form, whatever a server declared."""
+        with annotate("ragged.put") as span:
+            return self._put(span, uids, tokens, False)
+
+    def _put(self, span, uids, tokens, as_ids: bool) -> np.ndarray:
         """:meth:`put` under its span; the phases are spans of their own
         (docs/observability.md "Program spans and device scopes")."""
         cfg = self.config
@@ -921,39 +965,57 @@ class RaggedInferenceEngine:
         with annotate("ragged.dispatch"):
             if self._step_fn is None:
                 self._step_fn = self._build_step()
+            self._step_ids = None    # never a stale step's, whatever runs
             logits, self.kv_pool = self._step_fn(
                 self.params, self.kv_pool, jnp.asarray(flat_tokens),
                 jnp.asarray(flat_slot), jnp.asarray(flat_pos),
                 jnp.asarray(block_tables), jnp.asarray(sel_idx), live_pages)
-        with annotate("ragged.fetch", bytes=int(logits.nbytes)):
-            logits = np.asarray(logits)                # [max_seqs, vocab]
-
+        # only what the caller reads comes back: [max_seqs] ids, or the
+        # [max_seqs, vocab] logits (which otherwise never leave the device)
+        got = self._step_ids if as_ids else logits
+        with annotate("ragged.fetch", bytes=int(got.nbytes)):
+            got = np.asarray(got)
         with annotate("ragged.rows"):
-            out = self._rows_out(len(uids), logits.shape[-1])
-            now = time.perf_counter()
-            for i, uid in enumerate(uids):
-                seq = self.seqs[uid]
-                if seq.pending == 0 and uid in last_index:
-                    out[i] = logits[seq.slot]
-                    if seq.t_admitted is not None:
-                        # prompt fully prefilled and first logits on host:
-                        # TTFT. End-to-end latency is reported at flush(),
-                        # when the request actually completes.
-                        self._telemetry.record_request(
-                            ttft_s=now - seq.t_admitted)
-                        seq.t_admitted = None
-                else:
-                    out[i] = np.nan
-            self._record_step_telemetry(sched)
+            out = self._hand_back(uids, last_index, got.__getitem__,
+                                  None if as_ids else got.shape[-1])
+            self._record_step_telemetry(sched, got.nbytes)
+        return out
+
+    def _hand_back(self, uids, last_index, pick,
+                   width: Optional[int]) -> np.ndarray:
+        """A step's first result in the form its caller declared: float32
+        rows of ``width``, or with no ``width`` int32 ids (nothing is
+        copied but the ids). A sequence whose prompt is through gets what
+        ``pick(slot)`` reads of the step's result on the host (and its
+        TTFT is recorded, once); any other NaN, or ``-1``."""
+        if width is None:
+            out = np.full((len(uids),), -1, np.int32)
+        else:
+            out = self._rows_out(len(uids), width)
+        now = time.perf_counter()
+        for i, uid in enumerate(uids):
+            seq = self.seqs[uid]
+            if seq.pending == 0 and uid in last_index:
+                out[i] = pick(seq.slot)
+                if seq.t_admitted is not None:
+                    # prompt fully prefilled and its first result on host:
+                    # TTFT. End-to-end latency is reported at flush(),
+                    # when the request actually completes.
+                    self._telemetry.record_request(
+                        ttft_s=now - seq.t_admitted)
+                    seq.t_admitted = None
+            elif width is not None:
+                out[i] = np.nan
         return out
 
     def _rows_out(self, n: int, width: int) -> np.ndarray:
-        """[n, width] float32 for the rows a step hands back: the last
-        call's buffer again once the caller has let go of it (nothing else
-        refers to it or to a view of it), else a new one. A new
-        [n, vocab] array every tick is megabytes of pages touched for the
-        first time (20 MB at a vocabulary of 100k), which on the chip's
-        host took 28 ms a tick in most processes (PERF.md, PR 28)."""
+        """[n, width] float32 for the rows a step hands back in the logits
+        form (the ids form copies no row): the last call's buffer again
+        once the caller has let go of it (nothing else refers to it or to
+        a view of it), else a new one. A new [n, vocab] array every tick
+        is megabytes of pages touched for the first time (20 MB at a
+        vocabulary of 100k), which on the chip's host took 28 ms a tick in
+        most processes (PERF.md, PR 28)."""
         buf = self._rows_buf
         # references when free: the attribute, this local, the argument
         if buf is None or buf.shape[1] != width or n > buf.shape[0] \
@@ -1002,10 +1064,12 @@ class RaggedInferenceEngine:
         ``drafts[i]`` proposes continuation tokens AFTER ``tokens[i]``
         (which must then be exactly one pending decode token). Returns
         ``(out, verified)``: ``out`` is put()'s [len(uids), vocab]
-        last-row logits (NaN mid-prefill rows unchanged); ``verified``
+        last-row logits (NaN mid-prefill rows unchanged), or after
+        :meth:`return_token_ids` put()'s [len(uids)] ids; ``verified``
         maps each drafted uid to ``(chain, rows)`` — the chain actually
         scheduled (first element = the fed next token) and fp32 logits
-        [len(chain), vocab] for every chain position. The caller accepts
+        [len(chain), vocab] for every chain position, in either form
+        (the one place logits still come back). The caller accepts
         the longest greedy-matching prefix and MUST ``trim`` the
         rejected tail before the uid's next step.
 
@@ -1094,41 +1158,39 @@ class RaggedInferenceEngine:
                 self.params, self.kv_pool, jnp.asarray(flat_tokens),
                 jnp.asarray(flat_slot), jnp.asarray(flat_pos),
                 jnp.asarray(block_tables), jnp.asarray(sel_rows), live_pages)
+        # the chains' rows are read as logits by the caller, so the whole
+        # result comes back in either form
         with annotate("ragged.fetch", bytes=int(logits.nbytes)):
             logits = np.asarray(logits)       # [max_seqs, k_max, vocab]
 
         with annotate("ragged.rows"):
-            out = self._rows_out(len(uids), logits.shape[-1])
-            now = time.perf_counter()
-            for i, uid in enumerate(uids):
-                seq = self.seqs[uid]
-                if seq.pending == 0 and uid in last_index:
-                    # sel_rows[slot, -1] is the last scheduled row whether or
-                    # not the slot carried a chain — put()'s contract holds
-                    out[i] = logits[seq.slot, -1]
-                    if seq.t_admitted is not None:
-                        self._telemetry.record_request(
-                            ttft_s=now - seq.t_admitted)
-                        seq.t_admitted = None
-                else:
-                    out[i] = np.nan
+            # sel_rows[slot, -1] is the last scheduled row whether or not
+            # the slot carried a chain — put()'s contract holds
+            as_ids = self._token_ids
+            last = lambda slot: logits[slot, -1]
+            out = self._hand_back(
+                uids, last_index,
+                (lambda slot: np.argmax(last(slot))) if as_ids else last,
+                None if as_ids else logits.shape[-1])
             verified: Dict[int, Tuple[List[int], np.ndarray]] = {}
             for seq, take in sched:
                 if seq.uid in appended:
                     chain = [int(t) for t in seq.tokens[seq.seen - take:
                                                         seq.seen]]
                     verified[seq.uid] = (chain, logits[seq.slot, :take])
-            self._record_step_telemetry(sched)
+            self._record_step_telemetry(sched, logits.nbytes)
         return out, verified
 
-    def _record_step_telemetry(self, sched) -> None:
-        """Per-ragged-step series: scheduled tokens + pool occupancy. Host
-        dict updates only — nothing here touches the device."""
+    def _record_step_telemetry(self, sched, fetched: int) -> None:
+        """Per-ragged-step series: scheduled tokens, bytes of the step's
+        result brought to the host, pool occupancy. Host dict updates
+        only — nothing here touches the device."""
         t = self._telemetry
         if not t.enabled:
             return
         r = t.registry
         r.counter("inference/ragged_steps").inc()
+        r.counter("inference/fetch_bytes").inc(fetched)
         r.counter("inference/scheduled_tokens").inc(
             sum(take for _, take in sched))
         r.gauge("inference/kv_occupancy").set(self.cache.occupancy())
@@ -1354,10 +1416,10 @@ class RaggedInferenceEngine:
         same put()/decode_steps machinery as generate(); the uid is
         flushed when the stream ends — including early consumer breaks
         and mid-prefill failures (no slot/block leak)."""
-        logits = self.put([uid], [list(prompt)])
+        logits = self._put_logits([uid], [list(prompt)])
         try:
             while np.isnan(logits[0]).any():
-                logits = self.put([uid], [[]])
+                logits = self._put_logits([uid], [[]])
             tok = self._sample_first([logits[0]])[0]
             produced = 0
             yield tok
@@ -1427,7 +1489,7 @@ class RaggedInferenceEngine:
         generate_speculative() (identical under greedy; sampled first
         tokens ride the seeded prefill stream)."""
         uids = list(prompts)
-        logits = self.put(uids, [list(p) for p in prompts.values()])
+        logits = self._put_logits(uids, [list(p) for p in prompts.values()])
         first: Dict[int, int] = {}
         while True:
             pending, resolved = [], []
@@ -1443,7 +1505,7 @@ class RaggedInferenceEngine:
             if not pending:
                 break
             uids = pending
-            logits = self.put(pending, [[] for _ in pending])
+            logits = self._put_logits(pending, [[] for _ in pending])
         for u, t in first.items():
             done[u].append(t)
         return first
@@ -1741,13 +1803,18 @@ class RaggedInferenceEngine:
             # head only on each sequence's selected (last) token: the full
             # [token_budget, vocab] fp32 logits are 512 MB at T=4096 v=32k
             # and were previously fetched to host every step — select the
-            # [max_seqs] rows on-device before the (remote) host transfer
+            # [max_seqs] rows on-device before the (remote) host transfer,
+            # and make the greedy choice here too: a caller that decodes
+            # greedily (return_token_ids) fetches [max_seqs] int32, and
+            # the logits it never converts stay in HBM
             with jax.named_scope("head"):
                 x_sel = x[sel_idx]                                 # [S, d]
                 logits = model._head(params, x_sel[None, :])[0]    # [S, vocab]
-            return logits, pools
+                ids = sample(logits, None, 0.0, 0, 1.0)            # [S] int32
+            return logits, ids, pools
 
-        return jax.jit(step, donate_argnums=(1,), static_argnums=(7,))
+        return _Step(self, jax.jit(step, donate_argnums=(1,),
+                                   static_argnums=(7,)))
 
     def _build_decode(self):
         """Multi-step decode entirely on device: one token per live slot

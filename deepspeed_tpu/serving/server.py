@@ -27,10 +27,15 @@ loop into a real subsystem:
 * a watchdog thread flags stuck ticks (``serving/stuck_ticks``) when a
   device call wedges past ``stuck_tick_timeout_s``.
 
-Serving decodes greedily (argmax on the engine's returned logits):
-bit-exact preempt-resume and fault-retry require the continuation to be
-a pure function of the token stream. Sampling belongs in the engine's
-own ``generate``/``stream`` paths.
+Serving decodes greedily: bit-exact preempt-resume and fault-retry
+require the continuation to be a pure function of the token stream. It
+says so to an engine that can be told (``return_token_ids``), and a tick
+then returns each sequence's token id, chosen by argmax inside the
+engine's jitted step, ``-1`` while a prompt is mid-prefill; any other
+engine returns float logits rows (NaN mid-prefill) and the argmax is
+taken here. ``_greedy_tokens`` reads either form, told apart by the
+result's rank (docs/serving.md "The engine's result"). Sampling belongs
+in the engine's own ``generate``/``stream`` paths.
 
 Telemetry: per-request spans (queue_wait, TTFT, tokens/s — see
 :class:`~deepspeed_tpu.telemetry.spans.RequestStats`) plus queue-depth /
@@ -236,6 +241,13 @@ class ServingEngine:
                 f"serving.kv_quant='{want_quant}' but the engine stores "
                 f"KV as '{have_quant}' — configure both from one source")
         self._kv_quant = have_quant
+        # greedy decoding, declared to an engine that can make the choice
+        # on the device: its ticks then bring back token ids, not a
+        # [max_seqs, vocab] float32 matrix (a method reached by name, so a
+        # wrapper that delegates attribute reads passes it on)
+        declare = getattr(engine, "return_token_ids", None)
+        if declare is not None:
+            declare()
         # model-version ledger (docs/serving.md "Rollout, canary, and
         # migration"): the version of the weights this engine serves.
         # Monotonic ints, bumped by hot_swap(); requests are stamped at
@@ -305,7 +317,9 @@ class ServingEngine:
                  + (f" speculative=on(lookahead={config.spec_lookahead})"
                     if self._spec_on else "")
                  + (f" kv_quant={self._kv_quant}"
-                    if self._kv_quant != "none" else ""))
+                    if self._kv_quant != "none" else "")
+                 + (" engine_result=token_ids" if declare is not None
+                    else " engine_result=logits_rows"))
         if start:
             self.start()
 
@@ -1141,16 +1155,17 @@ class ServingEngine:
             inj = get_fault_injector()
             if inj is not None:
                 inj.on_serving_tick(self._tick_count)
-            uids, logits, verified = self._put_with_recovery(uids, toks,
-                                                             drafts)
+            uids, out, verified = self._put_with_recovery(uids, toks,
+                                                          drafts)
         except Exception as e:   # InjectedFault crashes (BaseException) pass
             self._on_tick_fault(uids, e)
             self._flush_spans()
             return True
         with annotate("serve.emit") as span:
             accepted = self._verify_drafts(verified)
+            chosen = self._greedy_tokens(out)
             with self._lock:
-                handoffs, emissions, finished = self._dispatch(uids, logits,
+                handoffs, emissions, finished = self._dispatch(uids, chosen,
                                                                accepted)
             span.set_metadata(tokens=len(emissions))
             # user callbacks run OUTSIDE the serving lock (dslint
@@ -1699,13 +1714,25 @@ class ServingEngine:
                                 next(iter(failed.values())))
         return accepted
 
-    def _dispatch(self, uids, logits: np.ndarray,
+    @staticmethod
+    def _greedy_tokens(out) -> List[int]:
+        """The tick's greedy token for each fed uid, ``-1`` where there is
+        none yet (a prompt mid-prefill), from either form of the engine's
+        first result: a 1-D integer array is the ids themselves; 2-D float
+        rows are logits (NaN while mid-prefill) and take the argmax."""
+        out = np.asarray(out)
+        if out.ndim == 1:
+            return out.tolist()
+        return [-1 if np.isnan(row[0]) else int(np.argmax(row))
+                for row in out]
+
+    def _dispatch(self, uids, chosen: List[int],
                   accepted: Optional[Dict[int, List[int]]] = None
                   ) -> Tuple[List[Request], List[Tuple[Request, int]],
                              List[int]]:
-        """Turn the tick's logits into emitted tokens, completions and
-        telemetry. Returns (handoff requests, (request, token) pairs for
-        ``on_token`` delivery, finished uids) — the KV exports, the user
+        """Turn the tick's greedy tokens (:meth:`_greedy_tokens`) into
+        emitted tokens, completions and telemetry. Returns (handoff
+        requests, (request, token) pairs for ``on_token`` delivery, finished uids) — the KV exports, the user
         callbacks and the FINISHED retirements all happen back in
         ``_tick`` AFTER this lock-held pass: callbacks must not run
         under the serving lock, and retirement must come after delivery
@@ -1715,9 +1742,9 @@ class ServingEngine:
         finished: List[int] = []
         handoffs: List[Request] = []
         emissions: List[Tuple[Request, int]] = []
-        for row, uid in zip(logits, uids):
+        for tok, uid in zip(chosen, uids):
             req = self._live.get(uid)
-            if req is None or np.isnan(row[0]):
+            if req is None or tok < 0:
                 continue                      # evicted mid-tick / prefilling
             if accepted and uid in accepted:
                 # speculative chain: apply the whole accepted run (tokens
@@ -1735,7 +1762,6 @@ class ServingEngine:
                             and emitted[-1] == req.eos_token_id)):
                     finished.append(uid)
                 continue
-            tok = int(np.argmax(row))
             if req.state is RequestState.PREFILL:
                 req.transition(RequestState.DECODE)
                 if req.t_first_token is None:

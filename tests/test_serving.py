@@ -527,3 +527,175 @@ def test_soak_random_lifecycle_zero_leak(model_and_params):
     assert_block_balance(eng)
     eng.prefix_cache.drop_all(eng.allocator)
     assert_block_balance(eng, expect_free=eng.allocator.n_blocks)
+
+
+# ----------------------------------------------------------------------
+# the engine's result in its two forms: token ids chosen inside the jitted
+# step, or logits rows and the server's argmax (docs/serving.md)
+def _served(eng, prompts, max_new_tokens, as_ids, cfg=None):
+    """Serves ``prompts`` to the end by hand on ``eng``. Returns the token
+    streams, every first result the engine handed the server, and the
+    server."""
+    results = []
+    inner = eng.put
+
+    def put(uids, toks):
+        results.append(inner(uids, toks))
+        return results[-1]
+
+    eng.put = put
+    srv = ServingEngine(eng, cfg or {"policy": "fcfs"}, start=False)
+    if hasattr(eng, "return_token_ids"):     # the server declared ids
+        eng.return_token_ids(as_ids)
+    reqs = [srv.submit(p, max_new_tokens=max_new_tokens) for p in prompts]
+    _tick_until(srv, lambda: all(r.is_terminal for r in reqs), limit=400)
+    assert all(r.state is RequestState.FINISHED for r in reqs)
+    srv.close()
+    return [list(r.tokens) for r in reqs], results, srv
+
+
+def _tied_head(model_and_params):
+    """Every logit has an exact twin 64 columns on: argmax meets a tie at
+    every token, and the first index wins on the host and on the device."""
+    model, params = model_and_params
+    head = np.asarray(params["lm_head"]).copy()
+    head[:, 64:] = head[:, :64]
+    return model, dict(params, lm_head=jnp.asarray(head))
+
+
+class _IdsSim:
+    """``SimEngine`` (one-hot rows, no ``return_token_ids``) behind the
+    declaring method and the ids form: what the server sees of a real
+    engine, from a fake."""
+
+    def __init__(self, sim):
+        self._sim = sim
+        self.declared = False
+
+    def __getattr__(self, name):
+        return getattr(self._sim, name)
+
+    def return_token_ids(self, on=True):
+        self.declared = on
+
+    def put(self, uids, tokens):
+        rows = self._sim.put(uids, tokens)
+        if not self.declared:
+            return rows
+        return np.asarray([-1 if np.isnan(r[0]) else int(np.argmax(r))
+                           for r in rows], np.int32)
+
+
+IDS_CASES = {
+    # name: (engine kwargs, prompt lengths, max_new_tokens, serving config)
+    "one_tick": (dict(), [9], 6, None),
+    "prompt_split_over_ticks": (dict(), [70], 5, None),
+    "two_sequences": (dict(), [9, 17], 6, None),
+    "max_seqs_sequences": (dict(), [9, 17, 5, 30], 6, None),
+    "preempt_resume_pool_exhausted": (
+        dict(n_kv_blocks=6, enable_prefix_cache=False), [16, 16], 20,
+        {"policy": "fcfs", "reserve_output_blocks": False}),
+    "tie_first_index_wins": (dict(), [9, 17], 8, None),
+    "spy_around_step_fn": (dict(), [9, 40], 5, None),
+    "tp2": (dict(), [9, 17], 6, None),
+    "fake_engine_one_hot_rows": (None, [9, 17, 40], 6, None),
+}
+
+
+@pytest.mark.parametrize("case", list(IDS_CASES))
+def test_ids_form_streams_equal_rows_form(model_and_params, case):
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.resilience.dst import SimConfig, SimEngine
+
+    kw, lens, max_new, scfg = IDS_CASES[case]
+    prompts = [_prompt(40 + i, n) for i, n in enumerate(lens)]
+    if case == "tie_first_index_wins":
+        model_and_params = _tied_head(model_and_params)
+
+    def build():
+        if kw is None:
+            return SimEngine(SimConfig())
+        if case == "tp2":
+            mesh_mod.reset_topology()
+            model, params = model_and_params
+            return RaggedInferenceEngine(
+                model, _cfg(**kw), params=params,
+                topology=mesh_mod.Topology.build_virtual({"model": 2}))
+        eng = _engine(model_and_params, **kw)
+        if case == "spy_around_step_fn":
+            # what benchmarks/sweep.py and the span tests put there: a
+            # plain function around the step, two results
+            step = eng._build_step()
+            eng._step_fn = lambda *a: step(*a)
+        return eng
+
+    preempted = lambda srv: srv._telemetry.registry.counter(
+        "serving/preempted").value
+    before = preempted(ServingEngine(build(), start=False))
+    if kw is None:
+        sim = build()
+        assert not hasattr(sim, "return_token_ids")
+        rows_streams, rows_results, _ = _served(sim, prompts, max_new, False)
+        ids_streams, ids_results, _ = _served(_IdsSim(build()), prompts,
+                                              max_new, True)
+    else:
+        rows_streams, rows_results, _ = _served(build(), prompts, max_new,
+                                                False, scfg)
+        ids_streams, ids_results, srv = _served(build(), prompts, max_new,
+                                                True, scfg)
+        assert_block_balance(srv._engine)
+    assert ids_streams == rows_streams
+    assert all(len(s) == max_new for s in ids_streams)
+    assert all(r.ndim == 2 and r.dtype == np.float32 for r in rows_results)
+    assert all(r.ndim == 1 and r.dtype == np.int32 for r in ids_results)
+    assert len(ids_results) == len(rows_results)
+    # -1 exactly where the rows form has a NaN row
+    for got, want in zip(ids_results, rows_results):
+        np.testing.assert_array_equal(got < 0, np.isnan(want[:, 0]))
+    if case in ("prompt_split_over_ticks", "fake_engine_one_hot_rows"):
+        assert any((r < 0).any() for r in ids_results)
+    if case == "preempt_resume_pool_exhausted":
+        assert preempted(srv) >= before + 2      # once a form at least
+    if case == "tie_first_index_wins":
+        assert all(t < 64 for s in ids_streams for t in s)
+        ties = [r for r in rows_results for r in r if not np.isnan(r[0])]
+        assert all(row[np.argmax(row) + 64] == row.max() for row in ties)
+
+
+def test_put_default_form_is_logits_and_fetch_bytes_count(model_and_params,
+                                                          tmp_path):
+    """``put`` without a declaration returns float32 rows (NaN while a
+    prompt is mid-prefill), the same values whether or not another engine
+    serves ids, and the ids are their argmax; ``inference/fetch_bytes``
+    counts what each form brings back."""
+    from deepspeed_tpu.telemetry import Telemetry, set_telemetry
+
+    class Cfg:
+        enabled = True
+        output_dir = str(tmp_path)
+
+    tel = Telemetry(config=Cfg())
+    set_telemetry(tel)
+    try:
+        fetched = tel.registry.counter("inference/fetch_bytes")
+        a, b = _engine(model_and_params), _engine(model_and_params)
+        b.return_token_ids()
+        long, short = _prompt(7, 40), _prompt(8, 5)
+        rows = a.put([1, 2], [short, long]).copy()
+        assert fetched.value == 4 * 128 * 4        # [max_seqs, vocab] f32
+        ids = b.put([1, 2], [short, long])
+        assert fetched.value == 4 * 128 * 4 + 4 * 4    # [max_seqs] int32
+        assert rows.dtype == np.float32 and rows.shape == (2, 128)
+        assert np.isfinite(rows[0]).all() and np.isnan(rows[1]).all()
+        assert ids.dtype == np.int32 and ids.tolist() == [
+            int(np.argmax(rows[0])), -1]
+        rows = a.put([1, 2], [[int(ids[0])], []])
+        ids = b.put([1, 2], [[int(ids[0])], []])
+        assert np.isfinite(rows).all()
+        assert ids.tolist() == np.argmax(rows, -1).tolist()
+        # the engine's own generate reads logits whatever was declared
+        assert b.generate({9: short}, max_new_tokens=4) \
+            == a.generate({9: short}, max_new_tokens=4)
+    finally:
+        tel.close()
+        set_telemetry(None)

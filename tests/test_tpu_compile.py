@@ -24,7 +24,7 @@ libtpu, inside the module-scoped ``topo`` fixture — never at import.
 
 import os
 import re
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
@@ -343,15 +343,26 @@ def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages, heads):
 def test_step_fn_takes_the_benchmark_warm_up_call(monkeypatch):
     """``benchmarks/runners/serve_open_loop.py::warm`` calls the engine's
     jitted step with eight positional arguments on a batch with no live
-    lane, once a (lanes, live pages) shape, and counts on ``put`` finding
-    every program compiled: a PR may not edit that file, and a program
-    compiled inside a measured window fails the run. Run here on the CPU
-    (kernel in interpret mode, head_dim 128: the grid over query tiles
-    with no tile) with the runner's own function."""
-    from benchmarks.runners.serve_open_loop import warm
+    lane, once a (lanes, live pages) shape, unpacks two results, and counts
+    on ``put`` finding every program compiled: a PR may not edit that file,
+    and a program compiled inside a measured window fails the run. Run
+    here on the CPU (kernel in interpret mode, head_dim 128: the grid over
+    query tiles with no tile) with the runner's own function.
+
+    The window's ticks are the server's, which asks the engine for token
+    ids: they must run the very programs that call compiled. So the
+    runner's ``TracedEngine`` goes around the warmed engine and a
+    ``ServingEngine`` on it, as ``Served`` builds them, and two requests
+    are served to the end under the event ``harness.CompileCounter``
+    counts: nothing compiles, every tick is a ``put`` the wrapper
+    recorded, and each brings back ``4 * max_seqs`` bytes."""
+    from benchmarks.harness import BACKEND_COMPILE_EVENT
+    from benchmarks.runners.serve_open_loop import TracedEngine, warm
+    from deepspeed_tpu.inference import ragged as ragged_mod
     from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                                 RaggedInferenceEngine)
     from deepspeed_tpu.models import Llama
+    from deepspeed_tpu.serving import ServingEngine
 
     monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
     model = Llama("tiny", n_layers=2, d_model=256, n_heads=2, n_kv_heads=1,
@@ -377,6 +388,40 @@ def test_step_fn_takes_the_benchmark_warm_up_call(monkeypatch):
     rows = eng.put([1, 2], [[9], [9]])                          # 64 lanes, 4 pages
     assert np.isfinite(rows).all()
     assert eng._step_fn._cache_size() == compiled
+    eng.flush([1, 2])
+
+    # the window: the server's ticks, in the ids form, on the same programs
+    compiles, fetched = [], []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(kw.get("fun_name"))
+        if event == BACKEND_COMPILE_EVENT else None)
+    real = ragged_mod.annotate
+
+    def annotate(name, **attrs):
+        if name == "ragged.fetch":
+            fetched.append(attrs["bytes"])
+        return real(name, **attrs)
+
+    monkeypatch.setattr(ragged_mod, "annotate", annotate)
+    traced = TracedEngine(eng)
+    srv = ServingEngine(traced, {"policy": "fcfs", "max_queue": 8},
+                        start=False)
+    del compiles[:]
+    # 105 prompt tokens against a budget of 96: one prompt is split over two
+    # ticks (a -1 in the first); no context passes the 4 warmed pages
+    reqs = [srv.submit(list(range(1, 56)), max_new_tokens=5),
+            srv.submit(list(range(1, 51)), max_new_tokens=5)]
+    ticks = 0
+    while not all(r.is_terminal for r in reqs):
+        srv._tick()
+        ticks += 1
+        assert ticks < 50
+    srv.close()
+    assert [len(r.tokens) for r in reqs] == [5, 5]
+    assert compiles == []
+    assert eng._step_fn._cache_size() == compiled
+    assert len(traced.calls) == len(fetched) == srv._tick_count
+    assert set(fetched) == {4 * eng.config.max_seqs}
 
 
 # ----------------------------------------------------------------------
@@ -483,6 +528,7 @@ def test_ragged_step_writes_pool_in_place(one_chip, on_tpu, T, kv_quant):
 
 # ----------------------------------------------------------------------
 # Olmo-Hybrid-7B as the benchmark serves it: 12 layers, two kinds of cache
+@lru_cache(maxsize=None)
 def compile_cell_step(config: str, device_sharding, T: int, live_pages: int):
     """The SplitFuse step of ``benchmarks/configs/<config>.json`` at its
     file's depth, with the pools its ``engine`` asks for (Olmo-Hybrid:
@@ -578,6 +624,33 @@ def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
               and dt == "bf16" and T not in map(int, dims.split(","))
               and np.prod(list(map(int, dims.split(",")))) >= weight]
     assert not copied, copied
+
+
+def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
+    """The greedy choice is a result of the step's own program (PR 34):
+    at the cell's decode shape the entry's tuple holds ``s32[64]`` beside
+    the ``f32[64, 100352]`` logits, which a server never fetches (25.7 MB
+    a tick). The logits are an operand now as well as a result, and the
+    compiler answers by computing them into on-chip memory (``S(1)``) for
+    the reduction and writing them out with an asynchronous
+    ``copy-start`` / ``copy-done`` in place of the head fusion's own
+    write: the same bytes to HBM once, no ``copy`` operation of that
+    shape, and temporaries within 1 MB of the 108,547,584 bytes the
+    step had without the ids (my described-chip compile of PR 31's tree;
+    108,579,328 with them)."""
+    compiled, c, e = compile_cell_step("olmo-hybrid-7b", one_chip, 64, 128)
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    root = next(l for l in entry.splitlines() if l.lstrip().startswith("ROOT"))
+    results = root[root.index("= (") + 3:root.index(") tuple(")]
+    logits = f"f32[{e['max_seqs']},{c.vocab_size}]"
+    assert results.startswith(logits + "{") and results.count(logits) == 1
+    assert results.count(f"s32[{e['max_seqs']}]{{") == 1, results[:200]
+    copies = [name for name, dt, dims, op, _ in _entry_instructions(hlo)
+              if op == "copy" and (dt, dims) == ("f32", logits[4:-1])]
+    assert not copies, copies
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp - 108_547_584) < 1e6, temp
 
 
 @pytest.mark.parametrize("T", [64, 2048], ids=["decode", "prefill_chunk"])
