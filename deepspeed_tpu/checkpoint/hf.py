@@ -152,6 +152,45 @@ def olmo_hybrid_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
         branch_norm=True, qk_norm=True)
 
 
+def ouro_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
+    """``model_type: ouro`` (LoopLM) -> TransformerConfig: Llama-shaped
+    attention and SwiGLU in sandwich-norm blocks (a norm before and after
+    each branch), the whole stack run ``total_ut_steps`` times a token over
+    one set of weights with the final norm after every pass, and an exit
+    gate with ``early_exit_threshold`` (TransformerConfig's looped-stack
+    fields). ``n_layers`` keeps the first layers only. The published
+    config.json carries no modelling code; the wiring is the release's as
+    :func:`_map_ouro` names it."""
+    from ..models.transformer import TransformerConfig
+
+    if hc.get("rope_scaling"):
+        raise NotImplementedError(
+            f"ouro rope_scaling={hc['rope_scaling']} not supported "
+            "(plain RoPE only)")
+    n = int(n_layers or hc["num_hidden_layers"])
+    if set(hc.get("layer_types") or ["full_attention"]) != {"full_attention"}:
+        raise NotImplementedError("ouro layer_types other than "
+                                  "full_attention not supported")
+    d, heads = hc["hidden_size"], hc["num_attention_heads"]
+    if hc.get("head_dim", d // heads) != d // heads:
+        raise NotImplementedError(
+            f"ouro head_dim {hc['head_dim']} != hidden_size / heads")
+    max_seq = hc.get("max_position_embeddings", 2048)
+    window = hc.get("sliding_window") if hc.get("use_sliding_window") \
+        else None
+    return TransformerConfig(
+        vocab_size=hc["vocab_size"], d_model=d, n_layers=n, n_heads=heads,
+        n_kv_heads=hc.get("num_key_value_heads", heads),
+        d_ff=hc["intermediate_size"], max_seq_len=max_seq,
+        attn_windows=_uniform_windows(window, max_seq, n),
+        norm="rms", activation="silu_glu", position="rope",
+        rope_theta=float(hc.get("rope_theta", 10000.0)),
+        tie_embeddings=hc.get("tie_word_embeddings", False), use_bias=False,
+        norm_eps=hc.get("rms_norm_eps", 1e-6), sandwich_norm=True,
+        total_ut_steps=int(hc.get("total_ut_steps", 1)),
+        early_exit_threshold=float(hc.get("early_exit_threshold", 1.0)))
+
+
 def hf_config(model_dir: str):
     """Parse HF config.json -> (family, TransformerConfig)."""
     from ..models.transformer import TransformerConfig
@@ -161,6 +200,8 @@ def hf_config(model_dir: str):
     family = hc.get("model_type", "")
     if family == "olmo_hybrid":
         return family, olmo_hybrid_config(hc)
+    if family == "ouro":
+        return family, ouro_config(hc)
     if family in ("llama", "mistral"):
         # loud failure beats silently-wrong logits for unsupported variants
         if hc.get("rope_scaling"):
@@ -460,7 +501,7 @@ def hf_config(model_dir: str):
         raise ValueError(f"unsupported HF model_type '{family}' "
                          f"(supported: llama, mistral, gpt2, opt, bloom, "
                          f"gptj, gpt_neo, gpt_neox, falcon, mixtral, bert, "
-                         f"distilbert, clip, qwen2, olmo_hybrid)")
+                         f"distilbert, clip, qwen2, olmo_hybrid, ouro)")
     return family, cfg
 
 
@@ -507,6 +548,25 @@ def _map_llama(state, c) -> Dict[str, Any]:
         params["lm_head"] = (state["lm_head.weight"]
                              if "lm_head.weight" in state
                              else state[pre + "embed_tokens.weight"]).T
+    return params
+
+
+def _map_ouro(state, c) -> Dict[str, Any]:
+    """Llama's names plus the sandwich's second norms
+    (``input_layernorm_2`` on attention's output,
+    ``post_attention_layernorm_2`` on the feed-forward's) and the exit
+    gate ``model.early_exit_gate`` (a torch ``Linear(d, 1)``)."""
+    params = _map_llama(state, c)
+    n = c.n_layers
+    pre = "model." if "model.embed_tokens.weight" in state else ""
+    L = pre + "layers.{}."
+    params["layers"]["attn_post_norm_w"] = _stack(
+        state, L + "input_layernorm_2.weight", n)
+    params["layers"]["mlp_post_norm_w"] = _stack(
+        state, L + "post_attention_layernorm_2.weight", n)
+    if c.total_ut_steps > 1:
+        params["exit_gate_w"] = state[pre + "early_exit_gate.weight"].T
+        params["b_exit_gate"] = state[pre + "early_exit_gate.bias"]
     return params
 
 
@@ -1023,7 +1083,7 @@ _MAPPERS: Dict[str, Callable] = {
     "gpt_neo": _map_gpt_neo,
     "falcon": _map_falcon, "mixtral": _map_mixtral,
     "bert": _map_bert, "distilbert": _map_distilbert,
-    "clip": _map_clip, "olmo_hybrid": _map_olmo_hybrid,
+    "clip": _map_clip, "olmo_hybrid": _map_olmo_hybrid, "ouro": _map_ouro,
 }
 
 

@@ -7,9 +7,14 @@ out, what such a cache refuses, and the host's books of pages and slots.
   description (shape, dtype, sharding a kind) from which :func:`new_pool`
   builds them and :func:`kv_page_bytes` / :func:`state_pool_bytes` count
   them. A new kind of layer cache is a field here and an entry there.
-* :func:`gather_pages`, :func:`write_pages`, :func:`copy_page` move pages
-  between the pool and the host; the engine's export / import / adopt keep
-  the bookkeeping and call them.
+* :class:`PageMoves` (``gather``, ``write``, ``copy``) moves pages between
+  the pool and the host; the engine's export / import / adopt keep the
+  bookkeeping and call it.
+* A looped stack (``total_ut_steps`` passes over one set of layers) keeps
+  K/V for every pass: :func:`cache_passes` is how many cache layers a
+  weight layer has, and a page id then names one page a pass in every leaf
+  (:class:`Leaves`' ``passes``; :class:`PageMoves` moves them together).
+  The books below never learn of it.
 * :class:`KVLedger` holds the allocator, the prefix cache and the slots, and
   answers the capacity questions the scheduler asks.
 * :func:`refuse_without_snapshot` is what a cache with recurrent state
@@ -384,6 +389,9 @@ class KVPool(NamedTuple):
     OOM) and blamed the shapes, but the per-layer leaves were copied too,
     by the row scatter's (hkv, hd) window (PR 26). Stacked and flat were
     not tried again; per-layer leaves keep any transient to one leaf.
+    A looped stack's leaf holds one such run of pages a pass, end to end
+    (:class:`Leaves`), and the step's loop over the passes carries the
+    leaves and copies none (PR 35; ``tests/test_tpu_compile.py``).
 
     ``k_scale`` / ``v_scale`` (kv_quant): pages are stored as blockwise
     payload + per-row fp32 scales (scale block = one K/V head-vector): int8
@@ -411,17 +419,22 @@ class KVPool(NamedTuple):
 class Leaves(NamedTuple):
     """One field of the pool: ``n`` leaves (one a layer that has the kind)
     of ``shape``, whose axis 0 is pages or slots plus the sink, ``dtype``
-    and, under a model axis, ``spec`` (K/V shard by head)."""
+    and, under a model axis, ``spec`` (K/V shard by head). Under a looped
+    stack axis 0 holds ``passes`` such runs end to end: pass ``t`` reads
+    and writes page ``p`` at ``p + t * (shape[0] // passes)``, its own sink
+    at the end of its run, so one page id is ``passes`` physical pages a
+    leaf."""
 
     n: int
     shape: Tuple[int, ...]
     dtype: Any
     spec: PartitionSpec = PartitionSpec()
+    passes: int = 1
 
     @property
     def unit_bytes(self) -> int:
         """Bytes one page or slot takes across the ``n`` leaves."""
-        return (self.n * int(np.prod(self.shape[1:]))
+        return (self.n * self.passes * int(np.prod(self.shape[1:]))
                 * jnp.dtype(self.dtype).itemsize)
 
 
@@ -435,6 +448,18 @@ def _layers_of(model_config, kind: str) -> Tuple[int, ...]:
     return tuple(range(model_config.n_layers)) if kind == "full" else ()
 
 
+def cache_passes(model_config) -> int:
+    """Cache layers a weight layer has: the passes of a looped stack, each
+    with K/V of its own; 1 for any other model."""
+    return int(getattr(model_config, "total_ut_steps", 1))
+
+
+def cache_layers(model_config) -> int:
+    """Layers of K/V the cache holds for a token: what an export's
+    ``n_layers`` counts."""
+    return len(_layers_of(model_config, "full")) * cache_passes(model_config)
+
+
 def pool_leaves(model_config, ragged_config) -> KVPool:
     """The pool's description, one :class:`Leaves` a field: the one place
     a leaf's shape, dtype and sharding are written. :func:`new_pool`
@@ -442,13 +467,14 @@ def pool_leaves(model_config, ragged_config) -> KVPool:
     c, cfg = model_config, ragged_config
     bits = KV_BITS[cfg.kv_quant]
     full, linear = (len(_layers_of(c, kind)) for kind in ("full", "linear"))
-    rows = (cfg.n_kv_blocks + 1, c.n_kv_heads, cfg.kv_block_size)
+    passes = cache_passes(c)
+    rows = (passes * (cfg.n_kv_blocks + 1), c.n_kv_heads, cfg.kv_block_size)
     payload = Leaves(
         full, rows + (c.head_dim // 2 if bits == 4 else c.head_dim,),
         {0: cfg.dtype, 8: jnp.int8, 4: jnp.uint8}[bits],
-        PartitionSpec(None, "model", None, None))
+        PartitionSpec(None, "model", None, None), passes)
     scale = Leaves(full if bits else 0, rows, jnp.float32,
-                   PartitionSpec(None, "model", None))
+                   PartitionSpec(None, "model", None), passes)
     state = conv = ()
     if linear:
         from ..ops.gated_delta import state_shapes
@@ -518,49 +544,18 @@ def refuse_without_snapshot(model_config, what: str) -> None:
 # page moves (the half of export / import / copy-on-write that knows the
 # format; descriptors, refcounts and telemetry stay with the engine)
 
-def gather_pages(pool: KVPool, blocks: Sequence[int]) -> Tuple:
-    """Host copies of pages ``blocks``, one ``[layers, len(blocks), ...]``
-    array a :data:`PAGED` field (None where the pool has no such leaves):
-    one device gather per layer leaf, then the transfer. The quantized
-    payload and its scales travel exactly as pooled."""
-    idx = jnp.asarray(np.asarray(blocks, np.int32))
-    return tuple(
-        np.stack([np.asarray(leaf[idx]) for leaf in getattr(pool, f)])
-        if getattr(pool, f) else None for f in PAGED)
-
-
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_pages(pool: KVPool, dst, pages) -> KVPool:
-    return pool._replace(**{
-        f: tuple(leaf.at[dst].set(new[i].astype(leaf.dtype))
-                 for i, leaf in enumerate(getattr(pool, f)))
-        for f, new in zip(PAGED, pages) if new is not None})
+    """``dst`` [passes, B] physical pages; ``pages`` [passes * layers, B,
+    ...] a field, as :meth:`PageMoves.gather` lays them."""
+    def put(leaves, new):
+        new = new.reshape((dst.shape[0], len(leaves)) + new.shape[1:])
+        return tuple(leaf.at[dst].set(new[:, i].astype(leaf.dtype))
+                     for i, leaf in enumerate(leaves))
 
-
-def write_pages(pool: KVPool, blocks: Sequence[int], pages: Tuple,
-                max_pages: int) -> KVPool:
-    """Scatter ``pages`` (:func:`gather_pages`' tuple) into pages
-    ``blocks`` of every layer's leaves: one jitted donated program over
-    the named leaves present, so the quantized payload AND its scale pages
-    land together — bit-identical pool state, never a requantization. The
-    page count is pow2-bucketed (one compiled writer per bucket, not one
-    per hand-off length); padding lanes scatter zeros into the sink page,
-    which is never read."""
-    need = len(blocks)
-    B = 1
-    while B < need:
-        B *= 2
-    B = min(B, max_pages)
-    dst = np.full((B,), pool.k[0].shape[0] - 1, np.int32)
-    dst[:need] = blocks
-
-    def padded(a):
-        if a is None or B == need:
-            return a
-        pad = np.zeros((a.shape[0], B - need) + a.shape[2:], a.dtype)
-        return np.concatenate([a, pad], axis=1)
-
-    return _scatter_pages(pool, jnp.asarray(dst), tuple(map(padded, pages)))
+    return pool._replace(**{f: put(getattr(pool, f), new)
+                            for f, new in zip(PAGED, pages)
+                            if new is not None})
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -570,10 +565,71 @@ def _copy_page(pool: KVPool, src, dst) -> KVPool:
         for f in PAGED})
 
 
-def copy_page(pool: KVPool, src: int, dst: int) -> KVPool:
-    """Device-side copy of page ``src`` onto ``dst`` across every layer's
-    leaves (one jitted donated program; trim's copy-on-write)."""
-    return _copy_page(pool, jnp.int32(src), jnp.int32(dst))
+class PageMoves:
+    """Moves whole pages of one model's pool by page id. It holds what a
+    page id means physically (:class:`Leaves`' ``passes``: one page a pass
+    of a looped stack, each pass's run of pages end to end in a leaf's
+    axis 0), so the engine that calls it names pages as the allocator
+    does and nothing else."""
+
+    def __init__(self, model_config):
+        self.passes = cache_passes(model_config)
+
+    def _physical(self, pool: KVPool, ids) -> np.ndarray:
+        """[passes, len(ids)]: each pass's page of every id."""
+        run = pool.k[0].shape[0] // self.passes
+        starts = np.arange(self.passes, dtype=np.int32) * run
+        return np.asarray(ids, np.int32)[None, :] + starts[:, None]
+
+    def gather(self, pool: KVPool, blocks: Sequence[int]) -> Tuple:
+        """Host copies of pages ``blocks``, one ``[cache layers,
+        len(blocks), ...]`` array a :data:`PAGED` field (None where the
+        pool has no such leaves): one device gather per layer leaf, then
+        the transfer. The quantized payload and its scales travel exactly
+        as pooled. Under a looped stack a page id brings every pass's
+        page: cache layer ``t * layers + l`` is pass ``t`` of layer
+        ``l``."""
+        idx = jnp.asarray(self._physical(pool, blocks))
+
+        def field(leaves):
+            got = np.stack([np.asarray(leaf[idx]) for leaf in leaves], 1)
+            return got.reshape((-1,) + got.shape[2:])  # [passes * layers, ..]
+
+        return tuple(field(getattr(pool, f)) if getattr(pool, f) else None
+                     for f in PAGED)
+
+    def write(self, pool: KVPool, blocks: Sequence[int], pages: Tuple,
+              max_pages: int) -> KVPool:
+        """Scatter ``pages`` (:meth:`gather`'s tuple) into pages ``blocks``
+        of every layer's leaves: one jitted donated program over the named
+        leaves present, so the quantized payload AND its scale pages land
+        together — bit-identical pool state, never a requantization. The
+        page count is pow2-bucketed (one compiled writer per bucket, not
+        one per hand-off length); padding lanes scatter zeros into the
+        sink page (each pass's own), which is never read."""
+        need = len(blocks)
+        B = 1
+        while B < need:
+            B *= 2
+        B = min(B, max_pages)
+        dst = np.full((B,), pool.k[0].shape[0] // self.passes - 1, np.int32)
+        dst[:need] = blocks
+
+        def padded(a):
+            if a is None or B == need:
+                return a
+            pad = np.zeros((a.shape[0], B - need) + a.shape[2:], a.dtype)
+            return np.concatenate([a, pad], axis=1)
+
+        return _scatter_pages(pool, jnp.asarray(self._physical(pool, dst)),
+                              tuple(map(padded, pages)))
+
+    def copy(self, pool: KVPool, src: int, dst: int) -> KVPool:
+        """Device-side copy of page ``src`` onto ``dst`` across every
+        layer's leaves, every pass's page of it (one jitted donated
+        program; trim's copy-on-write)."""
+        return _copy_page(pool, jnp.asarray(self._physical(pool, [src])[:, 0]),
+                          jnp.asarray(self._physical(pool, [dst])[:, 0]))
 
 
 @dataclass
@@ -593,7 +649,8 @@ class KVExport:
     n_kv_heads: int
     head_dim: int
     dtype: str
-    k_pages: np.ndarray        # [n_layers, n_pages, hkv, block, hd]
+    k_pages: np.ndarray        # [n_layers, n_pages, hkv, block, hd]; n_layers
+    #                            counts cache layers (kv_cache.cache_layers)
     v_pages: np.ndarray
     # quantized hand-off (kv_quant != "none"): k/v_pages hold the POOL's
     # quantized payload (int8, or int4 nibble-packed uint8 [.., hd//2])

@@ -185,6 +185,12 @@ class RaggedInferenceEngine:
                 "overrides (GPT-Neo); use InferenceEngine (dense KV cache)")
         # layers that hold a recurrent state (ops/gated_delta.py)
         self._state_layers = c.layers_of("linear")
+        # whole-page moves (export, import, copy-on-write); a looped
+        # stack's passes each have K/V of their own under one page id
+        self._pages = kv_cache.PageMoves(c)
+        self._passes = self._pages.passes
+        # layers of K/V the cache holds for a token (an export's n_layers)
+        self._kv_layers = kv_cache.cache_layers(c)
         if self.config.enable_prefix_cache:
             kv_cache.refuse_without_snapshot(
                 c, "enable_prefix_cache (a new prompt adopting a cached "
@@ -277,6 +283,11 @@ class RaggedInferenceEngine:
         # serving layer's request spans carry the true end-to-end numbers
         self._resume_uids: set = set()
         self.kv_pool = kv_cache.new_pool(c, cfg, topology)
+        self.kv_bytes_per_token = \
+            kv_cache.kv_page_bytes(c, cfg) // cfg.kv_block_size
+        if self._telemetry.enabled:
+            self._telemetry.registry.gauge(
+                "inference/kv_bytes_per_token").set(self.kv_bytes_per_token)
         self._rows_buf: Optional[np.ndarray] = None    # _rows_out
         self._token_ids = False     # return_token_ids
         self._step_fn = None
@@ -319,7 +330,9 @@ class RaggedInferenceEngine:
             + [cfg.token_budget]
         log_dist(f"RaggedInferenceEngine: budget={cfg.token_budget} "
                  f"blocks={cfg.n_kv_blocks}x{cfg.kv_block_size} "
-                 f"expert_bytes_in_place={self.expert_bytes_in_place}")
+                 f"expert_bytes_in_place={self.expert_bytes_in_place} "
+                 f"passes={self._passes} "
+                 f"kv_bytes_per_token={self.kv_bytes_per_token}")
 
     @property
     def _telemetry(self):
@@ -494,11 +507,12 @@ class RaggedInferenceEngine:
         c = self.model.config
         # rows past ``seen`` in the last page are never-read scratch and
         # ride along
-        k, v, ks, vs = kv_cache.gather_pages(self.kv_pool, seq.blocks)
+        k, v, ks, vs = self._pages.gather(self.kv_pool, seq.blocks)
         export = kv_cache.KVExport(
             uid=uid, tokens=list(seq.tokens), seen=seq.seen,
             prompt_len=seq.prompt_len,
-            kv_block_size=self.config.kv_block_size, n_layers=c.n_layers,
+            kv_block_size=self.config.kv_block_size,
+            n_layers=self._kv_layers,
             n_kv_heads=c.n_kv_heads, head_dim=c.head_dim,
             dtype=str(jnp.dtype(self.config.dtype)), k_pages=k, v_pages=v,
             kv_quant=self.config.kv_quant, k_scales=ks, v_scales=vs)
@@ -516,7 +530,7 @@ class RaggedInferenceEngine:
         # compression ratio is auditable next to the collective ops'
         from ..comm.comm import record_collective
 
-        logical = (2 * len(seq.blocks) * c.n_layers * c.n_kv_heads
+        logical = (2 * len(seq.blocks) * export.n_layers * c.n_kv_heads
                    * self.config.kv_block_size * c.head_dim
                    * jnp.dtype(self.config.dtype).itemsize)
         record_collective("kv_handoff", logical, export.nbytes)
@@ -539,8 +553,8 @@ class RaggedInferenceEngine:
         c = self.model.config
         if uid in self.seqs:
             raise ValueError(f"uid {uid} already live in this engine")
-        want = (cfg.kv_block_size, c.n_layers, c.n_kv_heads, c.head_dim,
-                str(jnp.dtype(cfg.dtype)), cfg.kv_quant)
+        want = (cfg.kv_block_size, self._kv_layers, c.n_kv_heads,
+                c.head_dim, str(jnp.dtype(cfg.dtype)), cfg.kv_quant)
         have = (export.kv_block_size, export.n_layers, export.n_kv_heads,
                 export.head_dim, export.dtype, export.kv_quant)
         if want != have:
@@ -567,7 +581,7 @@ class RaggedInferenceEngine:
         self.cache.make_room(need)                    # may raise PoolExhausted
         blocks = self.allocator.allocate(need)
         try:
-            self.kv_pool = kv_cache.write_pages(
+            self.kv_pool = self._pages.write(
                 self.kv_pool, blocks,
                 (export.k_pages, export.v_pages, export.k_scales,
                  export.v_scales), self.max_pages)
@@ -638,17 +652,18 @@ class RaggedInferenceEngine:
 
         c = self.model.config
         cfg = self.config
-        k, v, *scales = kv_cache.gather_pages(self.kv_pool, blocks)
+        k, v, *scales = self._pages.gather(self.kv_pool, blocks)
         scales = tuple(scales) if self._kv_bits else None
         wire = sum(int(a.nbytes) for a in (k, v) + (scales or ()))
+        n_layers = self._kv_layers
         # logical = the dense (unquantized) bytes the same pages would
         # move — the CommsLogger compression-ratio denominator
-        logical = (2 * len(blocks) * c.n_layers * c.n_kv_heads
+        logical = (2 * len(blocks) * n_layers * c.n_kv_heads
                    * cfg.kv_block_size * c.head_dim
                    * jnp.dtype(cfg.dtype).itemsize)
         return PrefixExport(
             tokens=key, n_pages=len(blocks),
-            block_size=cfg.kv_block_size, n_layers=c.n_layers,
+            block_size=cfg.kv_block_size, n_layers=n_layers,
             n_kv_heads=c.n_kv_heads, head_dim=c.head_dim,
             dtype=str(jnp.dtype(cfg.dtype)), kv_quant=cfg.kv_quant,
             pages=(k, v), scales=scales,
@@ -719,8 +734,8 @@ class RaggedInferenceEngine:
             # planted-bug seam (tests/DST only): verification disabled —
             # the landed-corruption counter is invariant #19's witness
             self.kvtier_corrupt_landed += 1
-        want = (cfg.kv_block_size, c.n_layers, c.n_kv_heads, c.head_dim,
-                str(jnp.dtype(cfg.dtype)), cfg.kv_quant)
+        want = (cfg.kv_block_size, self._kv_layers, c.n_kv_heads,
+                c.head_dim, str(jnp.dtype(cfg.dtype)), cfg.kv_quant)
         if want != export.geometry():
             raise ValueError(
                 f"prefix KV geometry mismatch: engine (block,layers,hkv,"
@@ -744,7 +759,7 @@ class RaggedInferenceEngine:
         self.cache.make_room(need)                # may raise PoolExhausted
         blocks = self.allocator.allocate(need)
         try:
-            self.kv_pool = kv_cache.write_pages(
+            self.kv_pool = self._pages.write(
                 self.kv_pool, blocks,
                 tuple(export.pages) + tuple(export.scales or (None, None)),
                 self.max_pages)
@@ -832,7 +847,7 @@ class RaggedInferenceEngine:
             del seq.blocks[keep:]
         if cow_new is not None:
             old = seq.blocks[keep - 1]
-            self.kv_pool = kv_cache.copy_page(self.kv_pool, old, cow_new)
+            self.kv_pool = self._pages.copy(self.kv_pool, old, cow_new)
             self.allocator.release([old])
             seq.blocks[keep - 1] = cow_new
 
@@ -1032,7 +1047,10 @@ class RaggedInferenceEngine:
         single-token entries past it, pages left free, and the work the
         paged kernel is asked for in each layer: query tiles, and KV steps
         (a tile's live chunks, summed), to set beside the ``lanes * pages
-        / 16`` steps of a grid over lanes and the page bucket."""
+        / 16`` steps of a grid over lanes and the page bucket; the passes
+        the stack makes over a token (1 unless the model is looped) and
+        the paged kernel's calls a step (``kv_layers``: the layers that
+        hold pages, times the passes)."""
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
         prefill = decode = 0
@@ -1047,7 +1065,9 @@ class RaggedInferenceEngine:
         attrs = {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
                  "prefill": prefill, "decode": decode,
                  "free": self.allocator.free_blocks,
-                 "q_tiles": q_tiles, "kv_steps": kv_steps}
+                 "q_tiles": q_tiles, "kv_steps": kv_steps,
+                 "passes": self._passes,
+                 "kv_layers": self._kv_layers}
         if self._state_layers:    # slots whose recurrent state is live
             attrs["state_slots"] = len(self.seqs)
         return attrs
@@ -1600,7 +1620,8 @@ class RaggedInferenceEngine:
         both the SplitFuse ``put`` step and the multi-step decode loop."""
         from ..ops.pallas.paged_attention import (paged_attention,
                                                   paged_attention_reference,
-                                                  work_list, write_kv_rows)
+                                                  work_list, write_kv_rows,
+                                                  write_kv_rows_flat)
 
         model = self.model
         c = model.config
@@ -1614,6 +1635,10 @@ class RaggedInferenceEngine:
                         for w in aw) if aw is not None \
             else (0,) * c.n_layers
         state_layers = self._state_layers
+        passes = self._passes
+        # under the loop over passes the row write takes the form that
+        # keeps its scope in the compiled step (write_kv_rows_flat)
+        write_rows = write_kv_rows if passes == 1 else write_kv_rows_flat
         # TP shards the pool/heads. GSPMD cannot partition a pallas_call,
         # so under TP the kernel runs INSIDE a shard_map whose specs name
         # the operands' existing sharding (heads/pool over 'model', tables/
@@ -1706,7 +1731,10 @@ class RaggedInferenceEngine:
                 return after_mixer(x, attn, lp), \
                     {"state": state, "conv_rows": rows}
 
-            def block(x, lp, own, window):
+            def block(x, lp, own, window, at):
+                # ``at``: this pass's pages (the tables, their per-lane
+                # form for the gather path, the sink page)
+                block_tables, tables, sink = at
                 with jax.named_scope("attn"):
                     # the model's own projection (norm, q / k / v, bias,
                     # QK-norm, heads, rotary), a position a lane
@@ -1720,7 +1748,7 @@ class RaggedInferenceEngine:
                     # (possible in the tail of a multi-step decode) — scatter
                     # into the scratch sink page, never a live one
                     page = jnp.where(active & (positions < cfg.max_context),
-                                     page, cfg.n_kv_blocks)
+                                     page, sink)
                     # pool layout [pages, hkv, block, hd]; kk [T, hkv, hd].
                     # kv_quant: quantize each head-vector on the way in (one
                     # fp32 scale per row, ops/quantizer.quantize_kv) and
@@ -1733,7 +1761,7 @@ class RaggedInferenceEngine:
 
                         new["k"], new["k_scale"] = quantize_kv(kk, kv_bits)
                         new["v"], new["v_scale"] = quantize_kv(vv, kv_bits)
-                    own = {f: write_kv_rows(leaf, page, row, new[f])
+                    own = {f: write_rows(leaf, page, row, new[f])
                            for f, leaf in own.items()}
                     quant = dict(k_scale=own["k_scale"],
                                  v_scale=own["v_scale"],
@@ -1762,27 +1790,59 @@ class RaggedInferenceEngine:
                     attn = model._attn_out(attn.astype(x.dtype), lp)
                 return after_mixer(x, attn, lp), own
 
-            # python-unrolled layer loop, NOT lax.scan: a scan would carry
-            # the whole pool, stacked or flat, and both were measured with
-            # pool-sized copies before the row write kept the kernel's
-            # layout (KVPool's docstring); not tried since. A leaf is
-            # indexed by its layer's place among the layers of its kind
-            leaves = {f: list(ls) for f, ls in pools._asdict().items()}
-            for li in range(c.n_layers):
-                with jax.named_scope("weights"):
-                    kind, lp = model.layer_params(
-                        params["layers"], li, self._experts_in_place)
-                at = c.layers_of(kind).index(li)
-                own = {f: leaves[f][at] for f in kv_cache.OWNS[kind]
-                       if leaves[f]}
-                if kind == "linear":
-                    x, own = linear_block(x, lp, own)
-                else:
-                    x, own = block(x, lp, own, windows[li])
-                for f, leaf in own.items():
-                    leaves[f][at] = leaf
-            return x, kv_cache.KVPool(**{f: tuple(ls)
-                                         for f, ls in leaves.items()})
+            def stack(x, leaves, at):
+                """The stack's blocks once, each on its layer's leaves of
+                the pool; python-unrolled over depth (two kinds of layer
+                have two shapes of leaves, and a scanned depth would want
+                the pool stacked: KVPool's docstring). A leaf is indexed
+                by its layer's place among the layers of its kind."""
+                leaves = {f: list(ls) for f, ls in leaves.items()}
+                for li in range(c.n_layers):
+                    with jax.named_scope("weights"):
+                        kind, lp = model.layer_params(
+                            params["layers"], li, self._experts_in_place)
+                    i = c.layers_of(kind).index(li)
+                    own = {f: leaves[f][i] for f in kv_cache.OWNS[kind]
+                           if leaves[f]}
+                    if kind == "linear":
+                        x, own = linear_block(x, lp, own)
+                    else:
+                        x, own = block(x, lp, own, windows[li], at)
+                    for f, leaf in own.items():
+                        leaves[f][i] = leaf
+                return x, {f: tuple(ls) for f, ls in leaves.items()}
+
+            leaves = pools._asdict()
+            if passes == 1:
+                x, leaves = stack(x, leaves,
+                                  (block_tables, tables, cfg.n_kv_blocks))
+                return x, kv_cache.KVPool(**leaves)
+
+            # a looped stack: the same blocks under ONE rolled loop over
+            # the passes (``passes`` unrolled bodies of n_layers blocks
+            # would multiply the program and its compile time), which
+            # carries x and the pool's leaves; each is written in place by
+            # its row scatter, so the loop copies none
+            # (tests/test_tpu_compile.py). Pass t reads and writes page p
+            # at p + t * stride (kv_cache.Leaves), its own sink too: the
+            # tables, the kernel's work list, the allocator's ids and the
+            # prefix hashes are one pass's, as for any other model
+            stride = cfg.n_kv_blocks + 1
+
+            def one_pass(t, carry):
+                x, leaves, leaving = carry
+                off = t * stride
+                x, leaves = stack(
+                    x, leaves,
+                    (block_tables + off,
+                     None if tables is None else tables + off,
+                     cfg.n_kv_blocks + off))
+                x, leaving = model.end_pass(params, x, t, leaving)
+                return x, leaves, leaving
+
+            x, leaves, leaving = jax.lax.fori_loop(
+                0, passes, one_pass, (x, leaves, model.exit_init(x)))
+            return model.exit_hidden(x, leaving), kv_cache.KVPool(**leaves)
 
         return core
 

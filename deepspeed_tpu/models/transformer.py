@@ -110,6 +110,19 @@ class TransformerConfig:
     # prenorm=False, which is norm(x + sub(x))); the final norm stays
     branch_norm: bool = False
     qk_norm: bool = False             # RMSNorm over the whole projected q, k
+    # Looped stacks (Ouro / LoopLM): the whole stack of n_layers blocks runs
+    # total_ut_steps times a token over ONE set of weights, the final norm
+    # after every pass (the normed output of a pass is the next pass's
+    # input, and the head's), each pass with K/V of its own. An exit gate
+    # (one Linear(d, 1) on a pass's normed output, float32) gives each
+    # token the pass whose output feeds the head: the first whose
+    # cumulative exit probability reaches early_exit_threshold, else the
+    # last; at a threshold of 1 or more the gate is not computed. Every
+    # pass is always run. sandwich_norm: a norm before AND after each
+    # branch, x + norm(sub(norm(x))) (four gains a layer)
+    sandwich_norm: bool = False
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -142,6 +155,20 @@ class TransformerConfig:
                 self.linear_n_v_heads = self.linear_n_v_heads \
                     or self.linear_n_k_heads
                 assert self.linear_n_v_heads % self.linear_n_k_heads == 0
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
+        if self.total_ut_steps > 1 and (
+                self.layers_of("linear") or not self.prenorm
+                or not self.causal):
+            raise ValueError(
+                "a looped stack (total_ut_steps > 1) needs a causal pre-norm "
+                "model of softmax-attention layers: the final norm closes "
+                "every pass, and a recurrent state a pass is not kept")
+        if self.sandwich_norm and (self.branch_norm or not self.prenorm
+                                   or self.parallel_residual):
+            raise ValueError("sandwich_norm is pre-norm wiring with a second "
+                             "norm on each branch's output: not with "
+                             "branch_norm, post-LN or parallel_residual")
         if self.d_ff is None:
             if self.activation == "silu_glu":
                 self.d_ff = int(8 * self.d_model / 3 / 128 + 1) * 128
@@ -189,9 +216,16 @@ class TransformerConfig:
             mixers += n_lin * (
                 d * ch + self.linear_conv_kernel * ch + 2 * d * hv + 2 * hv
                 + 2 * d * hv * self.linear_v_dim + self.linear_v_dim)
-        norms = (2 * d) * n + (d if self.prenorm else 0)
+        norms = (4 if self.sandwich_norm else 2) * d * n \
+            + (d if self.prenorm else 0)
         if self.norm == "layer":
             norms *= 2  # weights + biases
+        return mixers + norms + self._outside_stack_param_count()
+
+    def _outside_stack_param_count(self) -> int:
+        """Embeddings, heads and the exit gate: what a looped stack runs
+        once a token, not once a pass."""
+        d, v = self.d_model, self.vocab_size
         emb = v * d * (1 if self.tie_embeddings else 2)
         if self.position == "learned":
             emb += self.max_seq_len * d
@@ -203,7 +237,9 @@ class TransformerConfig:
             head += d * d + d + 2 * d + v  # transform + LN + decoder bias
         if self.pooler:
             head += d * d + d
-        return mixers + norms + emb + head
+        if self.total_ut_steps > 1:
+            head += d + 1                  # the exit gate
+        return emb + head
 
     def param_count(self) -> int:
         d, f, n = self.d_model, self.d_ff, self.n_layers
@@ -222,9 +258,12 @@ class TransformerConfig:
         return len(self.layers_of("full")) * seq_len
 
     def flops_per_token(self, seq_len: int) -> float:
-        """Forward+backward FLOPs/token (standard 6N + attention term)."""
-        return 6.0 * self.param_count() \
+        """Forward+backward FLOPs/token (standard 6N + attention term); a
+        looped stack runs its layers' share of both once a pass."""
+        once = 6.0 * self._outside_stack_param_count()
+        a_pass = 6.0 * self.param_count() - once \
             + 12.0 * self.d_model * self._attn_flop_len(seq_len)
+        return once + self.total_ut_steps * a_pass
 
 
 class Transformer:
@@ -304,6 +343,11 @@ class Transformer:
         if c.norm == "layer":
             layers["attn_norm_b"] = jnp.zeros((n, c.d_model), dtype)
             layers["mlp_norm_b"] = jnp.zeros((n, c.d_model), dtype)
+        if c.sandwich_norm:   # the norms on the two branches' outputs
+            for name in ("attn_post_norm", "mlp_post_norm"):
+                layers[name + "_w"] = jnp.ones((n, c.d_model), dtype)
+                if c.norm == "layer":
+                    layers[name + "_b"] = jnp.zeros((n, c.d_model), dtype)
         if c.qkv_bias:
             attn["bq"] = jnp.zeros((nf, c.n_heads * hd), dtype)
             attn["bk"] = jnp.zeros((nf, c.n_kv_heads * hd), dtype)
@@ -343,6 +387,9 @@ class Transformer:
             params["embed_norm_b"] = jnp.zeros((c.d_model,), dtype)
         if not c.tie_embeddings:
             params["lm_head"] = dense(next(k), (c.d_model, c.vocab_size))
+        if c.total_ut_steps > 1:   # the exit gate, Linear(d, 1)
+            params["exit_gate_w"] = dense(next(k), (c.d_model, 1))
+            params["b_exit_gate"] = jnp.zeros((1,), dtype)
         if c.mlm_head:
             params["mlm_dense_w"] = dense(next(k), (c.d_model, c.d_model))
             params["mlm_dense_b"] = jnp.zeros((c.d_model,), dtype)
@@ -672,6 +719,15 @@ class Transformer:
         """The block from its mixer's output on: residual wiring and the
         feed-forward. Returns (x, new_kv, aux)."""
         c = self.config
+        if c.sandwich_norm:  # Ouro: a norm before the branch and after it
+            x = x + self._norm(attn, lp["attn_post_norm_w"],
+                               lp.get("attn_post_norm_b"))
+            with jax.named_scope("ffn"):
+                h = self._norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
+                down, aux = self._mlp(h, lp, rng, training)
+                return x + self._norm(down, lp["mlp_post_norm_w"],
+                                      lp.get("mlp_post_norm_b")), new_kv, aux
+
         if c.branch_norm:  # OLMo-2/3: the norm sits on the branch's output
             x = x + self._norm(attn, lp["attn_norm_w"], lp.get("attn_norm_b"))
             with jax.named_scope("ffn"):
@@ -817,6 +873,70 @@ class Transformer:
         (x, _), auxes = jax.lax.scan(scan_fn, (x, layer_rng), xs)
         return x, jnp.sum(auxes)
 
+    # -- a looped stack: what closes a pass -----------------------------
+    def exit_init(self, x) -> Tuple:
+        """The exit's running state before the first pass, for hidden
+        states shaped like ``x`` [..., d]: (the rows chosen so far, the
+        probability of having stayed, who has left); empty where no gate
+        is computed (one pass, or a threshold no cumulative probability
+        short of the last pass's reaches)."""
+        c = self.config
+        if c.total_ut_steps == 1 or c.early_exit_threshold >= 1.0:
+            return ()
+        return (jnp.zeros_like(x), jnp.ones(x.shape[:-1], jnp.float32),
+                jnp.zeros(x.shape[:-1], bool))
+
+    @jax.named_scope("loop")
+    def end_pass(self, params, x, t, state: Tuple):
+        """What the recurrence adds to pass ``t`` (its index from 0, traced
+        or not), under the scope ``loop``: the final norm on the pass's
+        output ``x`` [..., d], which is the next pass's input, and, with a
+        running ``state`` (:meth:`exit_init`), the gate in float32, the
+        product and the select: a token leaves at the first pass whose
+        cumulative exit probability ``1 - prod_j (1 - lambda_j)`` reaches
+        the threshold, else at the last. Returns (x, state)."""
+        c = self.config
+        x = self._norm(x, params["final_norm_w"], params.get("final_norm_b"))
+        if not state:
+            return x, state
+        chosen, stay, left = state
+        f32 = jnp.float32
+        # a multiply and a sum, not a product on the MXU: float32 as stated
+        lam = jax.nn.sigmoid(
+            jnp.sum(x.astype(f32) * params["exit_gate_w"].astype(f32)[:, 0],
+                    axis=-1) + params["b_exit_gate"].astype(f32)[0])
+        stay = stay * (1.0 - lam)
+        leave = ~left & ((1.0 - stay >= c.early_exit_threshold)
+                         | (t == c.total_ut_steps - 1))
+        return x, (jnp.where(leave[..., None], x, chosen), stay,
+                   left | leave)
+
+    @staticmethod
+    def exit_hidden(x, state: Tuple):
+        """The rows that feed the head: each token's chosen pass's, or the
+        last pass's ``x`` where no gate ran."""
+        return state[0] if state else x
+
+    def _encode_looped(self, params, x, angles, positions, rng, training,
+                       attn_mask):
+        """:meth:`_encode` under an outer scan over the passes: the same
+        blocks and the same final norm every pass."""
+        c = self.config
+        rng = rng if rng is not None else jax.random.PRNGKey(0)
+
+        def one_pass(carry, t):
+            x, r, state = carry
+            r, sub = jax.random.split(r)
+            x, aux = self._encode(params, x, angles, positions, sub,
+                                  training, attn_mask)
+            x, state = self.end_pass(params, x, t, state)
+            return (x, r, state), aux
+
+        (x, _, state), auxes = jax.lax.scan(
+            one_pass, (x, rng, self.exit_init(x)),
+            jnp.arange(c.total_ut_steps))
+        return self.exit_hidden(x, state), jnp.sum(auxes)
+
     def apply(self, params, tokens, positions=None, kv_caches=None, cache_pos=None,
               rng=None, training=False, return_aux=False, last_token_only=False,
               return_hidden=False, token_type_ids=None, attn_mask=None):
@@ -839,14 +959,21 @@ class Transformer:
             raise NotImplementedError(
                 "the dense KV cache holds no recurrent state: serve a model "
                 "with linear layers through RaggedInferenceEngine")
+        if kv_caches is not None and c.total_ut_steps > 1:
+            raise NotImplementedError(
+                "the dense KV cache holds one K/V a layer and a looped "
+                "stack writes one a layer a pass: serve it through "
+                "RaggedInferenceEngine")
         x = self._embed(params, tokens, positions, token_type_ids)  # [b, s, d]
         angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta) \
             if c.position == "rope" else None
 
         aux_total = jnp.zeros((), jnp.float32)
         if kv_caches is None:
-            x, aux_total = self._encode(params, x, angles, positions, rng,
-                                        training, attn_mask)
+            encode = self._encode_looped if c.total_ut_steps > 1 \
+                else self._encode
+            x, aux_total = encode(params, x, angles, positions, rng,
+                                  training, attn_mask)
             new_caches = None
         else:
             ks, vs = kv_caches
@@ -1031,11 +1158,15 @@ class Transformer:
     @jax.named_scope("head")
     def _head(self, params, x):
         """Final norm + LM head: [..., s, d] -> fp32 logits [..., s, vocab].
+        (A looped stack hands over the rows its passes chose, already
+        normed.)
 
         Encoder MLM head (mlm_head): dense + gelu + LN transform before the
         tied decoder, plus a vocab bias (BertLMPredictionHead)."""
         c = self.config
-        if c.prenorm:
+        # a looped stack's final norm closes every pass (end_pass): its
+        # hidden states arrive here normed
+        if c.prenorm and c.total_ut_steps == 1:
             x = self._norm(x, params["final_norm_w"], params.get("final_norm_b"))
         if c.mlm_head:
             x = x @ params["mlm_dense_w"].astype(x.dtype) + params["mlm_dense_b"].astype(x.dtype)
@@ -1084,6 +1215,10 @@ class Transformer:
             raise NotImplementedError(
                 "the pipeline stage scan runs one kind of layer; a hybrid "
                 "stack (layer_types) is not plumbed through it")
+        if c.total_ut_steps > 1:
+            raise NotImplementedError(
+                "the pipeline runs its stages once a micro-batch; a looped "
+                "stack (total_ut_steps > 1) is not plumbed through it")
         if self._seq_size > 1:
             raise NotImplementedError(
                 "pipe x seq parallel composition not supported yet; "
@@ -1208,6 +1343,11 @@ class Transformer:
         if c.qk_norm:
             layer_specs.update({"q_norm_w": P(pipe, None),
                                 "k_norm_w": P(pipe, None)})
+        if c.sandwich_norm:
+            for name in ("attn_post_norm", "mlp_post_norm"):
+                layer_specs[name + "_w"] = P(pipe, None)
+                if c.norm == "layer":
+                    layer_specs[name + "_b"] = P(pipe, None)
         if c.layer_types is not None:
             # the mixers' stacks: attention's leaves move under "full", the
             # delta rule's are column-parallel in, row-parallel out, and
@@ -1245,6 +1385,9 @@ class Transformer:
             specs["lm_head"] = P(None, "model")
             if isinstance(params, dict) and "lm_head_b" in params:
                 specs["lm_head_b"] = P("model")  # GPT-J ingests carry one
+        if c.total_ut_steps > 1:
+            specs["exit_gate_w"] = P(None, None)
+            specs["b_exit_gate"] = P(None)
         if c.mlm_head:
             # transform stays replicated (its output feeds a LayerNorm over
             # full d); the vocab bias follows the vocab-sharded embedding

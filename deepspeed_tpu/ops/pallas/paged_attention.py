@@ -593,6 +593,23 @@ def write_kv_rows(leaf, page, row, new):
         new.astype(leaf.dtype))
 
 
+def write_kv_rows_flat(leaf, page, row, new):
+    """:func:`write_kv_rows` — same rows, same places — as a scatter over
+    the leaf seen as ``[n_pages * hkv * block, ...]`` rows with one index a
+    row: the form XLA:TPU itself rewrites the three-index scatter to when
+    the leaf is carried by a ``while`` (a looped stack's passes). Its
+    rewrite drops the operation's name, so the 96 row writes of a pass ran
+    under no scope but the loop's and no per-layer metric read them (a
+    third of Ouro's tick, PR 35); written so here, the compiler keeps the
+    scatter and its ``attn`` scope, and the fused operation is the same
+    (``tests/test_tpu_compile.py`` holds both)."""
+    n_pages, hkv, block = leaf.shape[:3]
+    heads = jnp.arange(hkv, dtype=page.dtype)
+    at = (page[:, None] * hkv + heads[None, :]) * block + row[:, None]
+    rows = leaf.reshape((n_pages * hkv * block,) + leaf.shape[3:])
+    return rows.at[at].set(new.astype(leaf.dtype)).reshape(leaf.shape)
+
+
 def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
                               scale=None, window: int = 0,
                               k_scale=None, v_scale=None, kv_bits: int = 8):

@@ -95,20 +95,21 @@ def test_the_bytes_are_counted_from_the_leaves_that_are_built(kind):
 
 
 def test_pages_moved_out_and_in_are_the_same_pages():
-    """gather_pages / write_pages / copy_page over the named leaves of an
+    """PageMoves' gather / write / copy over the named leaves of an
     int8 pool: payload and scales land together, bit for bit, and nothing
     else in the pool changes."""
     model = _llama()
     eng = RaggedInferenceEngine(model, _cfg(kv_quant="int8"))
     eng.put([1], [list(range(1, 20))])                    # three pages
     src = eng.seqs[1].blocks
-    pages = kv_cache.gather_pages(eng.kv_pool, src)
+    moves = kv_cache.PageMoves(model.config)
+    pages = moves.gather(eng.kv_pool, src)
     assert [a.shape[:2] for a in pages] == [(2, 3)] * 4
     before = jax.tree_util.tree_map(np.asarray, eng.kv_pool)
     dst = eng.allocator.allocate(3)
-    eng.kv_pool = kv_cache.write_pages(eng.kv_pool, dst, pages, eng.max_pages)
-    eng.kv_pool = kv_cache.copy_page(eng.kv_pool, src[0], dst[2])
-    again = kv_cache.gather_pages(eng.kv_pool, dst)
+    eng.kv_pool = moves.write(eng.kv_pool, dst, pages, eng.max_pages)
+    eng.kv_pool = moves.copy(eng.kv_pool, src[0], dst[2])
+    again = moves.gather(eng.kv_pool, dst)
     for a, b in zip(pages, again):
         np.testing.assert_array_equal(a[:, :2], b[:, :2])
         np.testing.assert_array_equal(a[:, 0], b[:, 2])

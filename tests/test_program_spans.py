@@ -49,7 +49,7 @@ PARENT = {
 }
 ATTRS = {
     "ragged.put": {"lanes", "pages", "seqs", "prefill", "decode", "free",
-                   "q_tiles", "kv_steps"},
+                   "q_tiles", "kv_steps", "passes", "kv_layers"},
     "ragged.admit": {"matched", "prompt"}, "ragged.fetch": {"bytes"},
     "serve.tick": {"tick", "queued", "live"},
     "serve.admit": {"admitted", "preempted"}, "serve.put": {"retries"},
@@ -245,6 +245,8 @@ def test_put_attributes_agree_with_the_engine(runs):
         # fewer steps than a grid over every lane and the page bucket
         assert a["seqs"] <= a["q_tiles"] <= a["kv_steps"] \
             <= a["lanes"] * a["pages"]
+        # one pass over two layers that hold pages: two kernel calls a tick
+        assert (a["passes"], a["kv_layers"]) == (1, 2)
     assert puts[0].attrs["prefill"] > 0
     decode_only = [s.attrs for s in puts if s.attrs["prefill"] == 0]
     assert decode_only and all(a["decode"] == a["seqs"] for a in decode_only)

@@ -16,7 +16,8 @@ ZeRO-3 step ``chip_smoke.py --chips 4`` runs, and what the compiled
 serving step does to its KV pool (rows written in place, on one chip and
 with the pool sharded over two), the 12-layer Olmo-Hybrid step of the
 benchmark's cell with both of its caches (fits, copies no leaf and no
-weight), and the Mixtral cell's step (copies no expert matrix).
+weight), the Mixtral cell's step (copies no expert matrix), and the Ouro
+cell's 48-layer step of four passes (one rolled loop, no pool leaf copied).
 
 One file on purpose: only the xdist worker that gets this file loads
 libtpu, inside the module-scoped ``topo`` fixture — never at import.
@@ -529,12 +530,14 @@ def test_ragged_step_writes_pool_in_place(one_chip, on_tpu, T, kv_quant):
 # ----------------------------------------------------------------------
 # Olmo-Hybrid-7B as the benchmark serves it: 12 layers, two kinds of cache
 @lru_cache(maxsize=None)
-def compile_cell_step(config: str, device_sharding, T: int, live_pages: int):
+def compile_cell_step(config: str, device_sharding, T: int, live_pages: int,
+                      n_kv_blocks: int = 0):
     """The SplitFuse step of ``benchmarks/configs/<config>.json`` at its
     file's depth, with the pools its ``engine`` asks for (Olmo-Hybrid:
     4096 pages over the 3 full layers, the state pool of 64 slots over the
-    9 linear ones). The engine is built under ``eval_shape``: its pools
-    are shapes, no byte is held."""
+    9 linear ones), or with ``n_kv_blocks`` pages where the file's
+    ``max_kv_blocks`` is a cap the chip's memory cuts (Ouro). The engine
+    is built under ``eval_shape``: its pools are shapes, no byte is held."""
     import json
 
     from benchmarks import harness
@@ -553,7 +556,8 @@ def compile_cell_step(config: str, device_sharding, T: int, live_pages: int):
             model, RaggedConfig(token_budget=e["token_budget"],
                                 max_seqs=e["max_seqs"],
                                 kv_block_size=e["kv_block_size"],
-                                n_kv_blocks=e["max_kv_blocks"],
+                                n_kv_blocks=n_kv_blocks
+                                or e["max_kv_blocks"],
                                 max_context=e["max_context"]), params={})
         return made["engine"].kv_pool
 
@@ -651,6 +655,54 @@ def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
     assert not copies, copies
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert abs(temp - 108_547_584) < 1e6, temp
+
+
+OURO_PAGES = 320   # what the chip's memory leaves of the file's cap of 512
+
+
+def test_ouro_step_is_one_loop_and_copies_no_leaf(one_chip, on_tpu):
+    """Rehearsal 3 for the cell ``ouro-2.6b.think``: the 48-layer step at
+    64 lanes with four passes is ONE ``while`` whose body holds the 48
+    blocks once (48 kernel calls in the text, 192 a tick), not four
+    bodies; the loop carries the 96 pool leaves of ``4 x (pages + 1)``
+    pages and nothing copies, transposes, slices or relays one: each is
+    written in place by its row scatter, which keeps the scope ``attn``
+    (so ``attn_device_ms.serve`` counts the writes as in every other
+    cell). It fits the chip beside 320
+    pages (8.05 GB of pool, 5.34 GB of weights). Temporaries, stated:
+    1.62 GB at 320 pages, of which 1.21 GB are the stacked ``wq``, ``wk``,
+    ``wv`` leaves copied whole to a transposed layout before the loop (the
+    compiler's layout for a dense product at 64 rows: PERF.md section 7,
+    question 4) and the rest the loop body's per-layer slices of them; the
+    same at 160 pages, so they do not grow with the pool."""
+    compiled, c, e = compile_cell_step("ouro-2.6b", one_chip, 64, 64,
+                                       OURO_PAGES)
+    assert c.total_ut_steps == 4 and c.n_layers == 48
+    assert _device_bytes(compiled) < 15.75e9
+    hlo = compiled.as_text()
+    assert len(re.findall(r" while\(", hlo)) == 1
+    assert _kernel_calls(hlo) == 48
+    ins = _instructions(hlo)
+    leaf = ("bf16", f"{4 * (OURO_PAGES + 1)},{c.n_kv_heads},"
+                    f"{e['kv_block_size']},{c.head_dim}")
+    leaves = {n: i for n, i in ins.items() if (i.dtype, i.dims) == leaf}
+    assert len([i for i in leaves.values() if i.op == "parameter"]) == 96
+    moved = [(i.op, n) for n, i in leaves.items()
+             if i.op in ("copy", "transpose", "slice", "dynamic-slice",
+                         "gather", "concatenate", "copy-start")
+             or i.layout != "3,2,1,0"]
+    assert not moved, moved
+    # the 96 row writes run under the block's scope (write_kv_rows_flat)
+    rows = 4 * (OURO_PAGES + 1) * c.n_kv_heads * e["kv_block_size"]
+    writes = re.findall(
+        rf"^\s*%\S+ = bf16\[{rows},{c.head_dim}\]\S* fusion\(.*$", hlo, re.M)
+    assert len(writes) == 96
+    assert all('/while/body/closed_call/attn/scatter"' in w for w in writes)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.75e9, temp
+    smaller, _, _ = compile_cell_step("ouro-2.6b", one_chip, 64, 64,
+                                      OURO_PAGES // 2)
+    assert abs(smaller.memory_analysis().temp_size_in_bytes - temp) < 2e6
 
 
 @pytest.mark.parametrize("T", [64, 2048], ids=["decode", "prefill_chunk"])
