@@ -89,6 +89,9 @@ class Sizes:
     kernel_n_seqs: int = 64
     # a second head shape for the paged kernel: Olmo-Hybrid's full layers
     kernel_alt_heads: Tuple[int, int] = (30, 30)
+    # KV heads of the leaves the row writer is checked at: Mistral's,
+    # Ouro's, Olmo-Hybrid's
+    kernel_writer_heads: Tuple[int, ...] = (8, 16, 30)
     # --chips 4: global batch, split four ways under ZeRO-3
     zero3_layers: int = 1
     zero3_batch: int = 4
@@ -235,7 +238,8 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     from deepspeed_tpu.ops.attention import dot_product_attention
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        paged_attention, paged_attention_reference)
+        paged_attention, paged_attention_reference, work_list,
+        write_kv_pages, write_kv_rows)
 
     hq, hkv, hd, S = sz.n_heads, sz.n_kv_heads, sz.head_dim, sz.kernel_seq
     kq, kk, kv, kw, kp = jax.random.split(jax.random.PRNGKey(seed), 5)
@@ -335,15 +339,49 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
             errs[name + tag] = _rel_err(got[lanes], want)
             _check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
                    f"{name + tag}: non-finite kernel output")
+    # the row writer against the scatter it replaced, on a pool that held
+    # other values, at the cells' KV-head counts and the same three kinds
+    # of lanes: every page but the sink (which only the scatter writes)
+    # bit for bit, K and V
+    @jax.jit
+    def scattered(k, v, nk, nv, s, p, tb):
+        page = jnp.where(s >= 0, tb[jnp.maximum(s, 0), p // blk], n_pages)
+        return (write_kv_rows(k, page, p % blk, nk),
+                write_kv_rows(v, page, p % blk, nv))
+
+    @jax.jit
+    def written(k, v, nk, nv, s, p, tb):
+        return write_kv_pages(k, v, nk, nv, tb, work_list(s, p, ns),
+                              interpret=interpret)
+
+    @jax.jit
+    def unequal(got, want, was):
+        return sum(jnp.sum(g[:n_pages] != w[:n_pages])
+                   + jnp.sum(g[n_pages] != o[n_pages])
+                   for g, w, o in zip(got, want, was))
+
+    rows_off = {}
+    for nkv in sz.kernel_writer_heads:
+        keys = jax.random.split(jax.random.fold_in(kp, 1000 + nkv), 4)
+        pools = [jax.random.normal(a, (n_pages + 1, nkv, blk, hd),
+                                   jnp.bfloat16) for a in keys[:2]]
+        for name, (slots, pos) in shapes.items():
+            new = [jax.random.normal(a, (len(slots), nkv, hd), jnp.bfloat16)
+                   for a in keys[2:]]
+            args = (*pools, *new, jnp.asarray(slots), jnp.asarray(pos), tables)
+            rows_off[f"{name.replace('paged', 'rows')}_h{nkv}"] = int(
+                unequal(written(*args), scattered(*args), pools))
     rec.update(shape={"flash": [1, S, f"{hq}/{hkv}", hd],
                       "paged": {n: len(s[0]) for n, s in shapes.items()},
                       "paged_variants": {t or "bf16": list(v[:2])
                                          for t, v in variants.items()},
                       "pages_per_seq": mp, "kv_block": blk},
                rel_err={n: round(e, 5) for n, e in errs.items()},
-               tolerance=KERNEL_REL_TOL)
+               tolerance=KERNEL_REL_TOL, rows_unequal=rows_off)
     bad = {n: e for n, e in errs.items() if not e <= KERNEL_REL_TOL}
     _check(not bad, f"kernels off their jnp reference: {bad}")
+    _check(not any(rows_off.values()),
+           f"write_kv_pages is not bit-equal to the scatter: {rows_off}")
 
 
 # ----------------------------------------------------------------------

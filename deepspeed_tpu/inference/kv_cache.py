@@ -377,13 +377,19 @@ class KVPool(NamedTuple):
     field a tuple of per-layer leaves, empty where the model has none.
 
     ``k`` / ``v``: one ``[n_blocks + 1, hkv, block, hd]`` leaf a layer that
-    holds pages (last page = scratch sink for masked-out batch lanes;
-    duplicate scatters with mixed old/new values are undefined — inactive
-    lanes must never alias a live page). (block, hd) stay minor-most so
-    each page is a native VMEM tile for the Pallas kernel, which pins this
-    row-major layout; every write into a leaf must keep it
-    (ops/pallas/paged_attention.write_kv_rows), or XLA:TPU transposes the
-    whole leaf and back, every tick. One array PER LAYER: earlier rounds
+    holds pages (last page = scratch sink for masked-out batch lanes where
+    a scatter writes the rows; duplicate scatters with mixed old/new
+    values are undefined — inactive lanes must never alias a live page).
+    (block, hd) stay minor-most so each page is a native VMEM tile for the
+    Pallas kernel, which pins this row-major layout; every write into a
+    leaf must keep it, or XLA:TPU transposes the whole leaf and back, every
+    tick. Who writes a step's new rows: on the TPU one Pallas call a layer,
+    K and V together, over the step's live query tiles
+    (ops/pallas/paged_attention.write_kv_pages: a tile's page slabs
+    [hkv, block, hd] come into VMEM, take the rows and go back; a lane
+    that is not live costs nothing and the sink is not written); off the
+    TPU, under tensor parallelism and for a quantized pool a scatter a
+    leaf with the head an index (write_kv_rows). One array PER LAYER: earlier rounds
     measured pool-sized copies under a stacked [L, pages, ...] tensor
     (100 ms a decode step) and a flat [L*(P+1), ...] one (16-18 GB compile
     OOM) and blamed the shapes, but the per-layer leaves were copied too,

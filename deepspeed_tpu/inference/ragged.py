@@ -335,6 +335,21 @@ class RaggedInferenceEngine:
                  f"kv_bytes_per_token={self.kv_bytes_per_token}")
 
     @property
+    def _writes_pages(self) -> bool:
+        """Who writes a step's new K/V rows: one Pallas call a layer over
+        the live tiles (``write_kv_pages``) wherever the paged kernel walks
+        its tiles on one chip; else a scatter a leaf (``write_kv_rows``):
+        off the TPU, under tensor parallelism (GSPMD partitions the scatter
+        by its head index), for a quantized pool (its scale rows are 16
+        elements wide) and for a page slab under 128 lanes wide, the two
+        shapes Mosaic refuses a copy of."""
+        from ..ops.pallas.paged_attention import LANES
+
+        return (self.attention_path != "gather" and self._tp_size == 1
+                and not self._kv_bits
+                and self.model.config.head_dim % LANES == 0)
+
+    @property
     def _telemetry(self):
         # resolved per call: the global pipeline may be installed after
         # this engine is constructed
@@ -962,8 +977,8 @@ class RaggedInferenceEngine:
             flat_tokens, flat_slot, flat_pos, last_idx = \
                 self._allocate_and_build(sched, needs)
             live_pages = self._live_pages_bucket()
-            span.set_metadata(**self._sched_attrs(sched, len(flat_tokens),
-                                                  live_pages))
+            attrs = self._sched_attrs(sched, len(flat_tokens), live_pages)
+            span.set_metadata(**attrs)
             last_index = {}  # uid -> index in flat batch of its last token
             for (seq, take), li in zip(sched, last_idx):
                 seq.seen += take
@@ -993,7 +1008,7 @@ class RaggedInferenceEngine:
         with annotate("ragged.rows"):
             out = self._hand_back(uids, last_index, got.__getitem__,
                                   None if as_ids else got.shape[-1])
-            self._record_step_telemetry(sched, got.nbytes)
+            self._record_step_telemetry(sched, got.nbytes, attrs)
         return out
 
     def _hand_back(self, uids, last_index, pick,
@@ -1050,7 +1065,10 @@ class RaggedInferenceEngine:
         / 16`` steps of a grid over lanes and the page bucket; the passes
         the stack makes over a token (1 unless the model is looped) and
         the paged kernel's calls a step (``kv_layers``: the layers that
-        hold pages, times the passes)."""
+        hold pages, times the passes); and what the row writer
+        (``write_kv_pages``) serves in each of those layers: its live
+        tiles and the page slabs it moves (0 where a scatter writes the
+        rows)."""
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
         prefill = decode = 0
@@ -1059,7 +1077,7 @@ class RaggedInferenceEngine:
                 prefill += take
             elif take == 1:
                 decode += 1
-        q_tiles, kv_steps = tile_counts(
+        q_tiles, kv_steps, pages = tile_counts(
             [(take, seq.seen) for seq, take in sched], query_tile(lanes),
             self.config.kv_block_size)
         attrs = {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
@@ -1067,7 +1085,9 @@ class RaggedInferenceEngine:
                  "free": self.allocator.free_blocks,
                  "q_tiles": q_tiles, "kv_steps": kv_steps,
                  "passes": self._passes,
-                 "kv_layers": self._kv_layers}
+                 "kv_layers": self._kv_layers,
+                 "write_tiles": q_tiles if self._writes_pages else 0,
+                 "write_pages": pages if self._writes_pages else 0}
         if self._state_layers:    # slots whose recurrent state is live
             attrs["state_slots"] = len(self.seqs)
         return attrs
@@ -1153,8 +1173,8 @@ class RaggedInferenceEngine:
             flat_tokens, flat_slot, flat_pos, last_idx = \
                 self._allocate_and_build(sched, needs)
             live_pages = self._live_pages_bucket()
-            span.set_metadata(**self._sched_attrs(sched, len(flat_tokens),
-                                                  live_pages))
+            attrs = self._sched_attrs(sched, len(flat_tokens), live_pages)
+            span.set_metadata(**attrs)
             k_max = 1
             for seq, take in sched:
                 if seq.uid in appended:
@@ -1198,19 +1218,23 @@ class RaggedInferenceEngine:
                     chain = [int(t) for t in seq.tokens[seq.seen - take:
                                                         seq.seen]]
                     verified[seq.uid] = (chain, logits[seq.slot, :take])
-            self._record_step_telemetry(sched, logits.nbytes)
+            self._record_step_telemetry(sched, logits.nbytes, attrs)
         return out, verified
 
-    def _record_step_telemetry(self, sched, fetched: int) -> None:
+    def _record_step_telemetry(self, sched, fetched: int,
+                               attrs: Dict[str, int]) -> None:
         """Per-ragged-step series: scheduled tokens, bytes of the step's
-        result brought to the host, pool occupancy. Host dict updates
-        only — nothing here touches the device."""
+        result brought to the host, page slabs the row writer moved (the
+        span's ``attrs``), pool occupancy. Host dict updates only —
+        nothing here touches the device."""
         t = self._telemetry
         if not t.enabled:
             return
         r = t.registry
         r.counter("inference/ragged_steps").inc()
         r.counter("inference/fetch_bytes").inc(fetched)
+        r.counter("inference/kv_pages_written").inc(
+            attrs["write_pages"] * attrs["kv_layers"])
         r.counter("inference/scheduled_tokens").inc(
             sum(take for _, take in sched))
         r.gauge("inference/kv_occupancy").set(self.cache.occupancy())
@@ -1620,8 +1644,8 @@ class RaggedInferenceEngine:
         both the SplitFuse ``put`` step and the multi-step decode loop."""
         from ..ops.pallas.paged_attention import (paged_attention,
                                                   paged_attention_reference,
-                                                  work_list, write_kv_rows,
-                                                  write_kv_rows_flat)
+                                                  work_list, write_kv_pages,
+                                                  write_kv_rows)
 
         model = self.model
         c = model.config
@@ -1636,9 +1660,6 @@ class RaggedInferenceEngine:
             else (0,) * c.n_layers
         state_layers = self._state_layers
         passes = self._passes
-        # under the loop over passes the row write takes the form that
-        # keeps its scope in the compiled step (write_kv_rows_flat)
-        write_rows = write_kv_rows if passes == 1 else write_kv_rows_flat
         # TP shards the pool/heads. GSPMD cannot partition a pallas_call,
         # so under TP the kernel runs INSIDE a shard_map whose specs name
         # the operands' existing sharding (heads/pool over 'model', tables/
@@ -1658,6 +1679,7 @@ class RaggedInferenceEngine:
         use_pallas = self.attention_path != "gather"
 
         kv_bits = self._kv_bits
+        use_writer = self._writes_pages
 
         def _paged_attn_sharded(q, kp, vp, tables, positions, slots, work,
                                 live_pages, window, k_scale=None,
@@ -1731,6 +1753,34 @@ class RaggedInferenceEngine:
                 return after_mixer(x, attn, lp), \
                     {"state": state, "conv_rows": rows}
 
+            def write_pages(own, kk, vv, block_tables, sink):
+                """This layer's leaves with the step's new rows in them;
+                pool layout [pages, hkv, block, hd], kk / vv [T, hkv, hd]."""
+                if use_writer:
+                    k, v = write_kv_pages(own["k"], own["v"], kk, vv,
+                                          block_tables, work, interpret=interp)
+                    return {"k": k, "v": v}
+                page = block_tables[safe_slot, positions // bs]       # [T]
+                row = positions % bs
+                # inactive lanes — and any lane past the context window
+                # (possible in the tail of a multi-step decode) — scatter
+                # into the scratch sink page, never a live one
+                page = jnp.where(active & (positions < cfg.max_context),
+                                 page, sink)
+                # kv_quant: quantize each head-vector on the way in (one
+                # fp32 scale per row, ops/quantizer.quantize_kv) and
+                # scatter payload + scale; reads dequantize inside the
+                # paged-attention path, so fp K/V never round-trips
+                # through HBM at full width
+                new = {"k": kk, "v": vv}
+                if kv_bits:
+                    from ..ops.quantizer import quantize_kv
+
+                    new["k"], new["k_scale"] = quantize_kv(kk, kv_bits)
+                    new["v"], new["v_scale"] = quantize_kv(vv, kv_bits)
+                return {f: write_kv_rows(leaf, page, row, new[f])
+                        for f, leaf in own.items()}
+
             def block(x, lp, own, window, at):
                 # ``at``: this pass's pages (the tables, their per-lane
                 # form for the gather path, the sink page)
@@ -1740,29 +1790,11 @@ class RaggedInferenceEngine:
                     # QK-norm, heads, rotary), a position a lane
                     q, kk, vv = model._qkv(x, lp, angles, positions)
                     # write the new K/V rows into this layer's pages, in
-                    # place and in the kernel's layout (write_kv_rows):
-                    # page = table[pos // bs], row = pos % bs
-                    page = block_tables[safe_slot, positions // bs]   # [T]
-                    row = positions % bs
-                    # inactive lanes — and any lane past the context window
-                    # (possible in the tail of a multi-step decode) — scatter
-                    # into the scratch sink page, never a live one
-                    page = jnp.where(active & (positions < cfg.max_context),
-                                     page, sink)
-                    # pool layout [pages, hkv, block, hd]; kk [T, hkv, hd].
-                    # kv_quant: quantize each head-vector on the way in (one
-                    # fp32 scale per row, ops/quantizer.quantize_kv) and
-                    # scatter payload + scale; reads below dequantize inside
-                    # the paged-attention path, so fp K/V never round-trips
-                    # through HBM at full width
-                    new = {"k": kk, "v": vv}
-                    if kv_bits:
-                        from ..ops.quantizer import quantize_kv
-
-                        new["k"], new["k_scale"] = quantize_kv(kk, kv_bits)
-                        new["v"], new["v_scale"] = quantize_kv(vv, kv_bits)
-                    own = {f: write_rows(leaf, page, row, new[f])
-                           for f, leaf in own.items()}
+                    # place and in the kernel's layout: page =
+                    # table[pos // bs], row = pos % bs (_writes_pages says
+                    # by whom; the scope is what loop_device_ms.serve reads)
+                    with jax.named_scope("scatter"):
+                        own = write_pages(own, kk, vv, block_tables, sink)
                     quant = dict(k_scale=own["k_scale"],
                                  v_scale=own["v_scale"],
                                  kv_bits=kv_bits) if kv_bits else {}

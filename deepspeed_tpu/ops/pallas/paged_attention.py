@@ -49,6 +49,12 @@ grid, (T, chunks of the page bucket) with every page a BlockSpec input
 (:func:`_lane_grid`): about 2.4 us a step whether the chunk is live or
 not, so its time follows lanes x bucket, not the work. The choice is made
 on those shapes alone. PERF.md (PR 29) has both grids' timings.
+
+The step's new K/V rows reach the pool through :func:`write_kv_pages`,
+one Pallas call a layer over the same list of tiles, which returns both
+leaves written in place (PERF.md, PR 38); :func:`write_kv_rows`, an XLA
+scatter a leaf, is the form off the TPU, under tensor parallelism and for
+a quantized pool, and the tests' oracle.
 """
 
 from __future__ import annotations
@@ -250,19 +256,22 @@ def work_list(slots, positions, n_seqs: int, tile_rows: int | None = None):
 
 
 def tile_counts(runs, tile_rows: int, block: int) -> tuple:
-    """(tiles, KV steps) :func:`work_list` and the kernel make of a packed
-    batch, on the host: ``runs`` is (lanes, first position) of each
-    sequence in batch order. A KV step is one chunk of one tile (counted
-    without a window)."""
+    """(tiles, KV steps, pages written) :func:`work_list` and the two
+    kernels make of a packed batch, on the host: ``runs`` is (lanes, first
+    position) of each sequence in batch order. A KV step is one chunk of
+    one tile of the paged kernel (counted without a window); a page
+    written is one page slab, K and V, that :func:`write_kv_pages` moves
+    for a tile (a page two tiles share counts for each)."""
     span = chunk_pages(block) * block
-    tiles = steps = lane = 0
+    tiles = steps = pages = lane = 0
     for take, pos in runs:
         while take > 0:
             n = min(take, tile_rows - lane % tile_rows)
             tiles += 1
             steps += (pos + n - 1) // span + 1
+            pages += (pos + n - 1) // block - pos // block + 1
             lane, pos, take = lane + n, pos + n, take - n
-    return tiles, steps
+    return tiles, steps, pages
 
 
 def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
@@ -575,7 +584,11 @@ def _lane_grid(q, k_pool, v_pool, tables, positions, seq_slots, *, scale, ppc,
 
 def write_kv_rows(leaf, page, row, new):
     """Write one new row per ragged lane into a pool leaf, in place and in
-    the layout the kernel above reads.
+    the layout the kernel above reads: an XLA scatter. The step's form off
+    the TPU, under tensor parallelism (GSPMD partitions it by the head
+    index) and for a quantized pool's leaves, and the oracle
+    :func:`write_kv_pages` is held to; it costs an index a (lane, head)
+    whatever is live (71 ns each on a v5e: PERF.md, PR 38).
 
     ``leaf`` is a payload leaf [n_pages, hkv, block, hd] (``new`` [T, hkv,
     hd]) or a ``kv_quant`` scale leaf [n_pages, hkv, block] (``new`` [T,
@@ -593,21 +606,185 @@ def write_kv_rows(leaf, page, row, new):
         new.astype(leaf.dtype))
 
 
-def write_kv_rows_flat(leaf, page, row, new):
-    """:func:`write_kv_rows` — same rows, same places — as a scatter over
-    the leaf seen as ``[n_pages * hkv * block, ...]`` rows with one index a
-    row: the form XLA:TPU itself rewrites the three-index scatter to when
-    the leaf is carried by a ``while`` (a looped stack's passes). Its
-    rewrite drops the operation's name, so the 96 row writes of a pass ran
-    under no scope but the loop's and no per-layer metric read them (a
-    third of Ouro's tick, PR 35); written so here, the compiler keeps the
-    scatter and its ``attn`` scope, and the fused operation is the same
-    (``tests/test_tpu_compile.py`` holds both)."""
-    n_pages, hkv, block = leaf.shape[:3]
-    heads = jnp.arange(hkv, dtype=page.dtype)
-    at = (page[:, None] * hkv + heads[None, :]) * block + row[:, None]
-    rows = leaf.reshape((n_pages * hkv * block,) + leaf.shape[3:])
-    return rows.at[at].set(new.astype(leaf.dtype)).reshape(leaf.shape)
+# tiles the writer keeps in flight: a tile's page reads are started this
+# many tiles, less one, ahead of the tile that changes them (2, 4 and 8
+# read the same on the v5e within 7%: PERF.md, PR 38)
+WRITE_DEPTH = 4
+
+
+def _write_kernel(work_ref, tbl_ref, nk_ref, nv_ref, k_in, v_in, k_out, v_out,
+                  kbuf, vbuf, plan, rsem, wsem, *, tq: int):
+    """One call = every live tile of the step's work list, in a loop with
+    the list's own trip count: a tile's page slabs come from the leaf in
+    HBM into a ring of ``WRITE_DEPTH`` buffers, the tile's rows go in, the
+    slabs go back. Reads run ``WRITE_DEPTH - 1`` tiles ahead of the tile
+    that changes them; a buffer is read into again once its write-back has
+    landed. Little code on purpose: tracing and lowering it is paid once a
+    step program, at every start of a server."""
+    del k_in, v_in          # the outputs are the same buffers (aliased)
+    depth = WRITE_DEPTH
+    _, hkv, block, hd = k_out.shape
+    nt = work_ref.shape[1]
+    limit = tbl_ref.shape[1] * block      # the context's end
+    i32, lax = jnp.int32, jax.lax
+
+    def lay_out(i, carry):
+        """Tile i's plan, once a call: its rows inside the context (rows at
+        or past its end, the tail of ``decode_steps``, write nothing), its
+        first page, its pages, and whether its first page is the tile
+        before's last (a tile boundary inside a page): that page is not
+        read from HBM, where the earlier tile's rows have not landed; the
+        earlier tile hands its slab on in VMEM."""
+        live, slot_was, last_was = carry
+        slot, pos0 = work_ref[3, i], work_ref[4, i]
+        n = lax.max(lax.min(work_ref[2, i], limit - pos0), 0)
+        pg0 = lax.div(pos0, i32(block))
+        npg = lax.select(n > 0, lax.div(pos0 + n - 1, i32(block)) - pg0 + 1,
+                         i32(0))
+        plan[0, i], plan[1, i], plan[2, i] = n, pg0, npg
+        plan[3, i] = ((npg > 0) & (slot == slot_was)
+                      & (pg0 == last_was)).astype(i32)
+        return (live + (work_ref[2, i] > 0).astype(i32), slot,
+                lax.select(npg > 0, pg0 + npg - 1, i32(-1)))
+
+    n_live, _, _ = lax.fori_loop(0, nt, lay_out, (i32(0), i32(-1), i32(-1)))
+
+    def pages(i, out: bool, act):
+        """``act`` on the copy of each of tile i's pages, K and V: pool ->
+        its buffer, or (``out``) back. A loop, as ``_tile_kernel.copies``."""
+        slot, pg0, buf = work_ref[3, i], plan[1, i], lax.rem(i, i32(depth))
+
+        def page(j, carry):
+            at = tbl_ref[slot, pg0 + j]
+            for pool, vm in ((k_out, kbuf), (v_out, vbuf)):
+                ends = (vm.at[buf, j], pool.at[at]) if out \
+                    else (pool.at[at], vm.at[buf, j])
+                act(pltpu.make_async_copy(*ends,
+                                          (wsem if out else rsem).at[buf]))
+            return carry
+
+        lax.fori_loop(0 if out else plan[3, i], plan[2, i], page, 0)
+
+    start, wait = (lambda d: d.start()), (lambda d: d.wait())
+
+    def put_rows(i):
+        r0, n, pg0, npg = work_ref[1, i], plan[0, i], plan[1, i], plan[2, i]
+        buf = lax.rem(i, i32(depth))
+        lanes = pl.ds(pl.multiple_of(work_ref[0, i] * tq, tq), tq)
+        first = pg0 * block - work_ref[4, i] + r0
+
+        def page(j, carry):
+            # the page's row p holds the tile's row p + d
+            d = first + j * block
+            r = d + lax.broadcasted_iota(i32, (hkv, block, hd), 1)
+            mine = (r >= r0) & (r < r0 + n)
+            for new, vm in ((nk_ref, kbuf), (nv_ref, vbuf)):
+                # a sublane rotation is a 32-bit operation: through float32
+                # and back, which keeps every bit of a 16-bit float
+                rows = new[:, lanes, :].astype(jnp.float32)
+                if block > tq:      # a page longer than a tile
+                    rows = jnp.concatenate(
+                        [rows, jnp.zeros((hkv, block - tq, hd), rows.dtype)], 1)
+                rows = pltpu.roll(rows, (-d) % max(tq, block), 1)[:, :block, :]
+                vm[buf, j] = lax.select(mine, rows.astype(vm.dtype), vm[buf, j])
+            return carry
+
+        lax.fori_loop(0, npg, page, 0)
+
+        @pl.when(plan[3, jnp.minimum(i + 1, nt - 1)] > 0)
+        def _hand_on():     # only a live tile's flag is ever set
+            nxt = lax.rem(i + 1, i32(depth))
+            kbuf[nxt, 0] = kbuf[buf, npg - 1]
+            vbuf[nxt, 0] = vbuf[buf, npg - 1]
+
+    lax.fori_loop(0, lax.min(n_live, i32(depth - 1)),
+                  lambda i, c: pages(i, False, start) or c, 0)
+
+    def one(i, carry):
+        @pl.when(i > 0)
+        def _landed():          # frees the buffer the next read takes
+            pages(i - 1, True, wait)
+
+        @pl.when(i < n_live)    # the last round only waits
+        def _tile():
+            @pl.when(i + depth - 1 < n_live)
+            def _ahead():
+                pages(i + depth - 1, False, start)
+
+            pages(i, False, wait)
+            put_rows(i)
+            pages(i, True, start)
+
+        return carry
+
+    lax.fori_loop(0, n_live + 1, one, 0)
+
+
+# jitted on its own, as ``_tiled`` and for its reason
+@functools.partial(jax.jit, static_argnames=("tq", "interpret"))
+def _write_pages(k_leaf, v_leaf, new_k, new_v, tables, work, *, tq, interpret):
+    T, hkv, hd = new_k.shape
+    block = k_leaf.shape[2]
+    pad = (-T) % tq
+    # [hkv, T, hd]: a tile's rows of one head are the sublanes of one slab
+    rows = lambda a: jnp.pad(a.astype(k_leaf.dtype), ((0, pad), (0, 0), (0, 0))) \
+        .transpose(1, 0, 2)
+    # a tile's rows, wherever its first lies in a page, reach this many
+    # pages at most (tq / block + 1 where a page is no longer than a tile)
+    slabs = (WRITE_DEPTH, (tq + block - 2) // block + 1, hkv, block, hd)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_write_kernel, tq=tq),
+        out_shape=(jax.ShapeDtypeStruct(k_leaf.shape, k_leaf.dtype),
+                   jax.ShapeDtypeStruct(v_leaf.shape, v_leaf.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[vmem, vmem, hbm, hbm], out_specs=[hbm, hbm],
+            scratch_shapes=[pltpu.VMEM(slabs, k_leaf.dtype),
+                            pltpu.VMEM(slabs, v_leaf.dtype),
+                            pltpu.SMEM((4, work.shape[1]), jnp.int32),
+                            pltpu.SemaphoreType.DMA((WRITE_DEPTH,)),
+                            pltpu.SemaphoreType.DMA((WRITE_DEPTH,))]),
+        # both leaves are written where they lie: operands 4 and 5 (after
+        # the two prefetched scalars and the new rows) are results 0 and 1
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="write_kv_pages",
+        interpret=interpret,
+    )(work, tables, rows(new_k), rows(new_v), k_leaf, v_leaf)
+
+
+def write_kv_pages(k_leaf, v_leaf, new_k, new_v, tables, work, *,
+                   tile_rows: int | None = None, interpret: bool = False):
+    """Write the step's new K and V rows into one layer's two payload
+    leaves [n_pages, hkv, block, hd], in place and in the layout the kernel
+    above reads: ONE Pallas call returns both leaves, over the same
+    ``work`` list (:func:`work_list`) the paged kernel walks.
+
+    ``new_k`` / ``new_v`` [T, hkv, hd] hold a row a lane; lane t's row
+    lands in page ``tables[slot, pos // block]`` at row ``pos % block``. A
+    tile is a stretch of one sequence's lanes at consecutive positions, so
+    its rows land in at most ``tile_rows / block + 1`` pages of that
+    sequence: the call brings each such page's [hkv, block, hd] slab (one
+    contiguous piece of the leaf) into VMEM, puts the tile's rows in and
+    sends it back, :data:`WRITE_DEPTH` tiles in flight. A bf16 row shares
+    its 32-bit words with its neighbour in the leaf's tiled layout, so the
+    slab, not the row, is what a copy can move. What the call costs follows
+    the live tiles alone: a lane that is not live belongs to no tile, and a
+    lane at or past the context's end (``tables.shape[1] * block``: the
+    tail of ``decode_steps``) writes nothing, where the scatter
+    (:func:`write_kv_rows`) pays one index a (lane, head) whatever is live
+    and sends those lanes to a sink page.
+
+    ``tables`` is the pass's own for a looped stack (``block_tables + t *
+    stride``). ``tile_rows`` must be what ``work`` was cut with."""
+    T = new_k.shape[0]
+    return _write_pages(k_leaf, v_leaf, new_k, new_v,
+                        tables.astype(jnp.int32), work,
+                        tq=tile_rows or query_tile(T), interpret=interpret)
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,

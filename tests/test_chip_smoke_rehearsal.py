@@ -44,6 +44,10 @@ def test_kernels_phase_interpret(ledger, capsys):
              for variant in ("", "_h30", "_w64", "_int8")}
     assert set(line["rel_err"]) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dk", "flash_bwd_dv"} | paged
+    # the row writer against the scatter: no element differs
+    assert line["rows_unequal"] == {
+        f"rows_{shape}_h{heads}": 0
+        for shape in ("prefill", "decode", "mixed") for heads in (8, 16, 30)}
 
 
 def test_train_phase(ledger):

@@ -341,8 +341,8 @@ def test_tiled_work_list_at_its_bound():
 
 @pytest.mark.parametrize("seed", range(4))
 def test_tile_counts_agree_with_the_work_list(seed):
-    """``ragged.put``'s host counters (q_tiles, kv_steps) count what the
-    device builds."""
+    """``ragged.put``'s host counters (q_tiles, kv_steps; write_tiles,
+    write_pages) count what the device builds."""
     rng = np.random.default_rng(seed)
     T, n_seqs, block = 64, 8, 16
     takes = rng.integers(1, 14, n_seqs)
@@ -354,9 +354,11 @@ def test_tile_counts_agree_with_the_work_list(seed):
                                    n_seqs, TQ))
     live = work[2] > 0
     span = pa.chunk_pages(block) * block
-    steps = ((work[4] + work[2] - 1) // span + 1)[live].sum()
+    last = work[4] + work[2] - 1
+    steps = (last // span + 1)[live].sum()
+    pages = (last // block - work[4] // block + 1)[live].sum()
     assert pa.tile_counts([(n, p) for _, p, n in runs], TQ, block) \
-        == (live.sum(), steps)
+        == (live.sum(), steps, pages)
 
 
 @pytest.mark.parametrize("hd,grid", [(64, "_lane_grid"), (128, "_tiled")])
@@ -376,3 +378,79 @@ def test_grid_is_chosen_on_head_dim(monkeypatch, hd, grid):
     ref = paged_attention_reference(q, kp, vp, tables, positions)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# the row writer: one call writes K and V over the step's live tiles
+# ----------------------------------------------------------------------
+WRITER_LANES = {
+    # decode lanes of several sequences, slots out of order, at a page's
+    # first and last row; dead lanes behind them
+    "decode": [(3, 150, 1), (0, 31, 1), (5, 64, 1), (2, 17, 1), (4, 0, 1)],
+    # a chunk of one sequence crossing three pages from a row inside a page
+    "chunk_three_pages": [(1, 21, 30)],
+    # tile boundaries (every 16 lanes) that fall inside a page: the page
+    # is handed from one tile to the next, behind two decode lanes
+    "tile_edge_in_page": [(0, 7, 1), (2, 90, 1), (1, 37, 41)],
+    # a run that starts on a page's edge and fills whole pages
+    "aligned_chunk": [(1, 32, 32)],
+    # a lane at, and lanes past, the context's end (the tail of
+    # decode_steps): they write nothing, the sink page included
+    "past_max_context": [(0, 190, 5), (1, 192, 1), (2, 250, 1), (3, 5, 1)],
+    "no_live_lane": [],
+}
+
+
+def _check_writer(runs, T, n_seqs, *, hkv, max_pages, pass_offset=0,
+                  block=16):
+    """``write_kv_pages`` (interpret mode) against ``write_kv_rows`` on a
+    bf16 pool that held other values: every page but the sink bit-equal, K
+    and V; the sink page, which only the scatter writes, as it was."""
+    _, kp, vp, tables, slots, pos = _ragged_batch(
+        runs, T, n_seqs, hkv=hkv, max_pages=max_pages, block=block,
+        dtype=jnp.bfloat16)
+    rng = np.random.default_rng(3)
+    pad = lambda a: jnp.concatenate(      # pages of the earlier passes
+        [jnp.asarray(rng.standard_normal((pass_offset,) + a.shape[1:]),
+                     a.dtype), a])
+    kp, vp, tables = pad(kp), pad(vp), tables + pass_offset
+    sink = kp.shape[0] - 1
+    nk = jnp.asarray(rng.standard_normal((T, hkv, 128)), jnp.bfloat16)
+    nv = jnp.asarray(rng.standard_normal((T, hkv, 128)), jnp.bfloat16)
+    slots, pos = jnp.asarray(slots), jnp.asarray(pos)
+    live = (slots >= 0) & (pos < max_pages * block)
+    page = jnp.where(live, tables[jnp.maximum(slots, 0),
+                                  jnp.minimum(pos // block, max_pages - 1)],
+                     sink)
+    got = pa.write_kv_pages(kp, vp, nk, nv, tables,
+                            pa.work_list(slots, pos, n_seqs), interpret=True)
+    bits = lambda a: np.asarray(a).view(np.uint16)
+    for g, was, new in zip(got, (kp, vp), (nk, nv)):
+        want = pa.write_kv_rows(was, page, pos % block, new)
+        np.testing.assert_array_equal(bits(g)[:sink], bits(want)[:sink])
+        np.testing.assert_array_equal(bits(g)[sink], bits(was)[sink])
+        assert (bits(g) != bits(was)).any() == bool(live.any())
+
+
+@pytest.mark.parametrize("pass_offset", [0, 97], ids=["pass0", "pass_offset"])
+@pytest.mark.parametrize("lanes", sorted(WRITER_LANES))
+@pytest.mark.parametrize("hkv", [8, 16, 30])
+def test_writer_lands_rows_where_the_scatter_did(hkv, lanes, pass_offset):
+    """At the three cells' KV-head counts (Mistral 8, Ouro 16, Olmo 30;
+    head_dim 128) and 64 lanes (tiles of 16 rows). ``pass_offset``: a
+    looped stack's later pass, whose tables are the first pass's plus a
+    stride."""
+    _check_writer(WRITER_LANES[lanes], 64, 6, hkv=hkv, max_pages=12,
+                  pass_offset=pass_offset)
+
+
+@pytest.mark.parametrize("T,block,runs", [
+    (256, 16, [(0, 3, 200), (1, 77, 1), (2, 300, 1)]),
+    (1024, 16, [(2, 130, 1), (0, 37, 900), (1, 5, 70)]),
+    (64, 32, [(2, 130, 1), (0, 25, 40), (1, 63, 2)])],
+    ids=["tile32", "tile64", "page32"])
+def test_writer_at_the_wider_tiles(T, block, runs):
+    """The lane buckets whose tiles are 32 and 64 rows: three and five
+    pages a tile, the first handed on from the tile before; and pages
+    longer than a tile, which a tile still straddles."""
+    _check_writer(runs, T, 4, hkv=2, max_pages=1024 // block, block=block)

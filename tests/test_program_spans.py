@@ -49,7 +49,8 @@ PARENT = {
 }
 ATTRS = {
     "ragged.put": {"lanes", "pages", "seqs", "prefill", "decode", "free",
-                   "q_tiles", "kv_steps", "passes", "kv_layers"},
+                   "q_tiles", "kv_steps", "passes", "kv_layers",
+                   "write_tiles", "write_pages"},
     "ragged.admit": {"matched", "prompt"}, "ragged.fetch": {"bytes"},
     "serve.tick": {"tick", "queued", "live"},
     "serve.admit": {"admitted", "preempted"}, "serve.put": {"retries"},
@@ -247,6 +248,8 @@ def test_put_attributes_agree_with_the_engine(runs):
             <= a["lanes"] * a["pages"]
         # one pass over two layers that hold pages: two kernel calls a tick
         assert (a["passes"], a["kv_layers"]) == (1, 2)
+        # off the TPU a scatter writes the rows: the writer serves nothing
+        assert (a["write_tiles"], a["write_pages"]) == (0, 0)
     assert puts[0].attrs["prefill"] > 0
     decode_only = [s.attrs for s in puts if s.attrs["prefill"] == 0]
     assert decode_only and all(a["decode"] == a["seqs"] for a in decode_only)
@@ -312,3 +315,88 @@ def test_without_a_session_the_seam_changes_nothing(runs):
     assert off["seam"][2] == 0 and off["seam"][1] > 0
     assert off["seam"][3] == (len(PROMPTS) + 1) * NEW_TOKENS
     assert off["probe"] == (0, 0, 0)
+
+
+# ----------------------------------------------------------------------
+# the row writer's counters: what ``write_kv_pages`` serves a layer
+@pytest.mark.parametrize("passes", [1, 4], ids=["one_pass", "four_passes"])
+def test_put_counts_the_tiles_and_pages_the_writer_serves(passes, monkeypatch,
+                                                          tmp_path):
+    """``ragged.put`` carries ``write_tiles`` and ``write_pages``: the live
+    tiles the row writer serves in one layer's call and the page slabs it
+    moves there (a layer's count, not times the passes or the layers:
+    ``passes`` and ``kv_layers`` are on the span), equal to a count by hand
+    of the packed batch, for a chunk tick, a tick that mixes a chunk's tail
+    with a decode lane, and a decode tick; the registry's
+    ``inference/kv_pages_written`` sums pages x ``kv_layers``. The engine
+    as the TPU runs it (kernels in interpret mode, head_dim 128), with one
+    pass and with a looped stack's four, and it decodes what the gather
+    engine decodes."""
+    from deepspeed_tpu.config import TelemetryConfig
+    from deepspeed_tpu.telemetry import Telemetry, set_telemetry
+
+    model = Llama("tiny", n_layers=2, d_model=256, n_heads=2, n_kv_heads=2,
+                  vocab_size=VOCAB, max_seq_len=256, use_flash=False,
+                  remat=False, **(dict(total_ut_steps=passes,
+                                       sandwich_norm=True)
+                                  if passes > 1 else {}))
+    params = model.init(jax.random.PRNGKey(11))
+    cfg = RaggedConfig(token_budget=64, max_seqs=MAX_SEQS, kv_block_size=16,
+                       n_kv_blocks=24, max_context=128, dtype=jnp.float32)
+    seen = []
+
+    class Span:
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *a):
+            return False
+
+        def set_metadata(self, **attrs):
+            seen.append(attrs)
+
+    real = ragged_mod.annotate
+    monkeypatch.setattr(
+        ragged_mod, "annotate",
+        lambda name, **attrs: Span() if name == "ragged.put"
+        else real(name, **attrs))
+
+    def drive(engine):
+        """70 + 5 prompt tokens against 64 lanes, then two decode ticks."""
+        rows = engine.put([1, 2], [_prompt(1, 70), _prompt(2, 5)])
+        assert np.isnan(rows[0]).all() and np.isfinite(rows[1]).all()
+        ids = [int(np.argmax(rows[1]))]
+        rows = engine.put([1, 2], [[], ids[-1:]])
+        for _ in range(2):
+            ids += [int(t) for t in np.argmax(rows, -1)]
+            rows = engine.put([1, 2], [ids[-2:-1], ids[-1:]])
+        return ids
+
+    want = drive(RaggedInferenceEngine(model, cfg, params=params))
+    assert all((a["write_tiles"], a["write_pages"]) == (0, 0) for a in seen)
+    del seen[:]
+    monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+    tel = Telemetry(TelemetryConfig(enabled=True, output_dir=str(tmp_path),
+                                    jsonl_path="", stall_detection=False))
+    set_telemetry(tel)
+    try:
+        engine = RaggedInferenceEngine(model, cfg, params=params)
+        assert engine._writes_pages
+        counter = tel.registry.counter("inference/kv_pages_written")
+        before = counter.value          # the registry outlives a pipeline
+        assert drive(engine) == want
+        written = counter.value - before
+    finally:
+        set_telemetry(None)
+    counts = [(a["lanes"], a["write_tiles"], a["write_pages"]) for a in seen]
+    # tiles of 16 lanes, pages of 16 tokens; the scheduler packs the short
+    # prompt first. Tick 1: uid 2's 5 lanes at positions 0-4 (a tile, a
+    # page), then 59 lanes of uid 1 at 0-58 in tiles of 11 + 16 + 16 + 16
+    # rows: positions 0-10 (a page), 11-26, 27-42 and 43-58 (two pages
+    # each, the first shared with the tile before): 8 slabs. Tick 2: uid
+    # 2's token at 5, and uid 1's last 11 at 59-69, one tile over pages 3
+    # and 4. Then a lane each, at 6 / 70 and 7 / 71
+    assert counts == [(64, 5, 8), (64, 2, 3), (64, 2, 2), (64, 2, 2)], counts
+    assert {(a["passes"], a["kv_layers"]) for a in seen} == {(passes, 2 * passes)}
+    assert [a["q_tiles"] for a in seen] == [c[1] for c in counts]
+    assert written == sum(c[2] for c in counts) * 2 * passes

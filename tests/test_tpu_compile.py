@@ -13,8 +13,10 @@ paged attention bf16, windowed and int8-KV, at a prefill-chunk and a
 decode shape; the int4-KV refusal), one whole train step and one ragged
 serving step of the smoke model at reduced depth, the four-chip
 ZeRO-3 step ``chip_smoke.py --chips 4`` runs, and what the compiled
-serving step does to its KV pool (rows written in place, on one chip and
-with the pool sharded over two), the 12-layer Olmo-Hybrid step of the
+serving step does to its KV pool (rows written in place, on one chip by
+one ``write_kv_pages`` call a layer and with the pool sharded over two by
+the scatter; the writer alone at the cells' shapes; the benchmark's
+roofline readers tell it from the paged kernel), the 12-layer Olmo-Hybrid step of the
 benchmark's cell with both of its caches (fits, copies no leaf and no
 weight), the Mixtral cell's step (copies no expert matrix), and the Ouro
 cell's 48-layer step of four passes (one rolled loop, no pool leaf copied).
@@ -120,16 +122,35 @@ def test_flash_backward_compiles(one_chip):
 
 
 OLMO_HEADS = (30, 30)   # Olmo-Hybrid-7B's full layers: 30 KV heads, group 1
-# the kernel's result as the benchmark's readers know it: a 4-D bf16 array
-# from one custom call (benchmarks/readers/paged_attention_roofline.py)
+# the paged kernel's result as the benchmark's readers know it: a 4-D bf16
+# array from one custom call (benchmarks/readers/paged_attention_roofline.py);
+# the row writer's is a tuple of two pool leaves, which they must not count
 _KERNEL_RESULT = re.compile(r"= bf16\[\d+,\d+,\d+,\d+\]\S* custom-call\(")
+_WRITER_RESULT = re.compile(r"= \((\w+\[[\d,]+\])\S*, \1\S*\) custom-call\(")
+
+
+def _custom_calls(hlo: str) -> list:
+    return [l for l in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def _writer_calls(hlo: str) -> list:
+    """The row writer's calls (``write_kv_pages``): each returns a tuple
+    of two leaves of one shape and runs under ``attn/scatter``, the path
+    ``loop_device_ms.serve`` reads and ``attn_device_ms.serve`` counts."""
+    calls = [l for l in _custom_calls(hlo) if _WRITER_RESULT.search(l)]
+    assert all(re.search(r'op_name="[^"]*attn/scatter/[^"]*"', l)
+               for l in calls), calls
+    return calls
 
 
 def _kernel_calls(hlo: str) -> int:
-    calls = [l for l in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in l]
-    assert all(_KERNEL_RESULT.search(l) for l in calls), calls
-    return len(calls)
+    """The paged kernel's calls; every other kernel of the module is the
+    row writer."""
+    calls = _custom_calls(hlo)
+    paged = [l for l in calls if _KERNEL_RESULT.search(l)]
+    assert len(paged) + len(_writer_calls(hlo)) == len(calls), calls
+    return len(paged)
 
 
 def _paged_args(T, sharding, pool_dtype=jnp.bfloat16, hd_packed=HD,
@@ -167,6 +188,42 @@ def test_paged_attention_compiles(one_chip, variant, T):
                           a["lanes"], a["lanes"], a["scale"],
                           a["scale"]).compile()
     assert _kernel_calls(c.as_text()) == 1
+
+
+@pytest.mark.parametrize("hkv,n_seqs,T", [
+    (HKV, 64, 64), (HKV, 64, 1024), (HKV, 64, SZ.token_budget),
+    (16, 32, 64), (16, 32, 512), (30, 64, 64), (30, 64, 1024)],
+    ids=["mistral_decode", "mistral_1024", "mistral_budget", "ouro_decode",
+         "ouro_budget", "olmo_decode", "olmo_budget"])
+def test_row_writer_compiles(one_chip, hkv, n_seqs, T):
+    """``write_kv_pages`` alone at the three cells' leaf shapes and lane
+    buckets (tiles of 16, 32 and 64 rows: 2, 3 and 5 page slabs a tile,
+    32 / 64 / 120 KB each): the dynamic sublane rotation and the slab
+    copies lower, both leaves come back from one call, aliased to the
+    donated operands, and no copy of a leaf is made around it."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (work_list,
+                                                          write_kv_pages)
+
+    leaf = _sds((N_PAGES + 1, hkv, BLK, HD), jnp.bfloat16, one_chip)
+    rows = _sds((T, hkv, HD), jnp.bfloat16, one_chip)
+    lanes = _sds((T,), jnp.int32, one_chip)
+
+    def fn(k, v, nk, nv, tables, slots, pos):
+        return write_kv_pages(k, v, nk, nv, tables,
+                              work_list(slots, pos, n_seqs))
+
+    c = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        leaf, leaf, rows, rows,
+        _sds((n_seqs, PAGES_PER_SEQ), jnp.int32, one_chip), lanes,
+        lanes).compile()
+    hlo = c.as_text()
+    assert len(_custom_calls(hlo)) == 1 and _WRITER_RESULT.search(
+        _custom_calls(hlo)[0])
+    dims = f"{N_PAGES + 1},{hkv},{BLK},{HD}"
+    assert not [i for i in _instructions(hlo).values()
+                if i.dims == dims and i.op not in ("parameter", "bitcast",
+                                                   "get-tuple-element")]
+    assert c.memory_analysis().temp_size_in_bytes < 16e6
 
 
 def test_paged_int4_kv_refuses_before_the_compiler(one_chip):
@@ -330,8 +387,9 @@ def compile_ragged_step(device_sharding, n_layers: int, T: int,
                          [(SZ.token_budget, 128), (1024, 128), (64, 128)],
                          ids=["prefill_chunk", "lanes1024", "decode"])
 def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages, heads):
-    """One kernel call a layer, its result 4-D bf16, at both head shapes
-    the cells serve (Olmo's: d_model 3840 = 30 x 128, group 1)."""
+    """One kernel call a layer, its result 4-D bf16, and one call of the
+    row writer in front of it, at both head shapes the cells serve (Olmo's:
+    d_model 3840 = 30 x 128, group 1)."""
     from dataclasses import replace
 
     n_layers = 2  # reduced from chip_smoke's serve depth: compile time
@@ -339,6 +397,7 @@ def test_ragged_step_compiles(one_chip, on_tpu, T, live_pages, heads):
                  d_model=heads[0] * HD)
     c = compile_ragged_step(one_chip, n_layers, T, live_pages, sz=sz)
     assert _kernel_calls(c.as_text()) == n_layers
+    assert len(_writer_calls(c.as_text())) == n_layers
 
 
 def test_step_fn_takes_the_benchmark_warm_up_call(monkeypatch):
@@ -455,14 +514,18 @@ def _instructions(hlo: str) -> dict:
 POOL_PAGES = 4096  # the benchmark's serving cells hold 4096 pages
 
 
-def _pool_faults(hlo: str, dtype: str = "bf16", heads: int = HKV) -> list:
+def _pool_faults(hlo: str, dtype: str = "bf16", heads: int = HKV,
+                 leaf_pages: int = POOL_PAGES + 1) -> list:
     """What a compiled ragged step does to its KV pool's payload leaves
-    (``dtype[POOL_PAGES + 1, heads, BLK, HD]`` on a device) beyond writing
+    (``dtype[leaf_pages, heads, BLK, HD]`` on a device) beyond writing
     rows into them. Empty when every value of that shape keeps the layout
     of the donated parameter (the kernel's, row-major), nothing copies or
-    gathers a leaf, and every leaf the paged kernel reads is the row
-    scatter's own result on that parameter, or a bitcast of it."""
-    shape = (POOL_PAGES + 1, heads, BLK, HD)
+    gathers a leaf, the row writer (``write_kv_pages``: a Pallas call that
+    returns a tuple of both leaves; one chip, bf16) is handed that
+    parameter or a bitcast of it, and every leaf the paged kernel reads is
+    a row write's own result on the parameter: the writer's, or the
+    scatter's (a fusion: a quantized pool, tensor parallelism)."""
+    shape = (leaf_pages, heads, BLK, HD)
     dims, n_elements = ",".join(map(str, shape)), int(np.prod(shape))
     ins = _instructions(hlo)
     is_leaf = lambda i: (i.dtype, i.dims) == (dtype, dims)
@@ -483,18 +546,30 @@ def _pool_faults(hlo: str, dtype: str = "bf16", heads: int = HKV) -> list:
         return name
 
     opcode = lambda name: ins[name].op if name in ins else "tuple"
+    leaves_of = lambda names: [o for o in set(names)
+                               if o in ins and is_leaf(ins[o])]
+    # the writer's calls are tuple-valued, so ``ins`` does not hold them
+    writers = {}
+    for line in _writer_calls(hlo):
+        name, args = re.match(r"\s*(?:ROOT )?%(\S+) = .*? custom-call\("
+                              r"([^)]*)\)", line).groups()
+        writers[name] = re.findall(r"%([\w.\-]+)", args)
+        for o in leaves_of(writers[name]):
+            if opcode(source(o)) != "parameter":
+                faults.append(f"writer {name} is handed {o}: made by "
+                              f"{opcode(source(o))} {source(o)}")
     kernels = [i for i in ins.values()
                if i.op == "custom-call" and "tpu_custom_call" in i.line]
     for k in kernels:
-        for o in set(k.operands):
-            if o not in ins or not is_leaf(ins[o]):
+        for o in leaves_of(k.operands):
+            by = source(o)
+            if by in writers:
                 continue
-            writer = source(o)
-            origin = source(ins[writer].operands[0]) \
-                if ins[writer].operands else writer
-            if opcode(writer) != "fusion" or opcode(origin) != "parameter":
-                faults.append(f"kernel reads {o}: made by {opcode(writer)} "
-                              f"{writer} from {opcode(origin)} {origin}")
+            origin = source(ins[by].operands[0]) \
+                if ins[by].operands else by
+            if opcode(by) != "fusion" or opcode(origin) != "parameter":
+                faults.append(f"kernel reads {o}: made by {opcode(by)} "
+                              f"{by} from {opcode(origin)} {origin}")
     return faults + ([] if kernels else ["no paged kernel in the module"])
 
 
@@ -503,14 +578,17 @@ def _pool_faults(hlo: str, dtype: str = "bf16", heads: int = HKV) -> list:
                          ids=["decode", "prefill_chunk"])
 def test_ragged_step_writes_pool_in_place(one_chip, on_tpu, T, kv_quant):
     """No operation of the compiled step costs what the pool weighs: the
-    new rows are scattered into the donated leaves in the kernel's own
-    layout (``write_kv_rows``). With the KV-head axis a window of the
-    scatter the same compile holds two transposing copies a leaf (eight
-    at two layers, 187 MB of temporaries at the decode shape)."""
+    new rows are written into the donated leaves in the kernel's own
+    layout, by one ``write_kv_pages`` call a layer, or for the quantized
+    pool by ``write_kv_rows``, a scatter a leaf. With the KV-head axis a
+    window of the scatter the same compile holds two transposing copies a
+    leaf (eight at two layers, 187 MB of temporaries at the decode
+    shape)."""
     c = compile_ragged_step(one_chip, 2, T, 128, n_kv_blocks=POOL_PAGES,
                             kv_quant=kv_quant)
     hlo = c.as_text()
     assert _pool_faults(hlo, "s8" if kv_quant == "int8" else "bf16") == []
+    assert len(_writer_calls(hlo)) == (0 if kv_quant == "int8" else 2)
     if kv_quant == "int8":
         # a scale leaf [pages, hkv, block] (2 MB) is laid out {0,2,1} in
         # HBM by the TPU runtime itself (16 minor elements would pad to
@@ -594,8 +672,8 @@ def _entry_instructions(hlo: str):
 def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
     """Rehearsal 3 for the cell ``olmo-hybrid-7b.reason``: the 12-layer
     step at the decode shape and at a 1024-lane shape fits one chip beside
-    its pools; the paged kernel runs in the 3 full layers only; each state
-    leaf is written by one fusion a layer and nothing copies, transposes
+    its pools; the paged kernel and the row writer run in the 3 full
+    layers only; each state leaf is written by one fusion a layer and nothing copies, transposes
     or slices a pool leaf or a state leaf; and no weight matrix is copied
     out of its stack (the per-layer slices of the three stacks, common,
     ``full`` and ``linear``, are read in place by the products that use
@@ -604,7 +682,8 @@ def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
     compiled, c, e = compile_cell_step("olmo-hybrid-7b", one_chip, T, 128)
     assert _device_bytes(compiled) < 15.75e9
     hlo = compiled.as_text()
-    assert hlo.count("tpu_custom_call") == len(c.layers_of("full")) == 3
+    assert _kernel_calls(hlo) == len(c.layers_of("full")) == 3
+    assert len(_writer_calls(hlo)) == 3      # K and V of a full layer
     state = f"{e['max_seqs'] + 1},30,96,192"
     rows = f"{e['max_seqs'] + 1},3,11520"
     page = f"{e['max_kv_blocks'] + 1},30,{e['kv_block_size']},128"
@@ -657,6 +736,27 @@ def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
     assert abs(temp - 108_547_584) < 1e6, temp
 
 
+def _leaf_moves(hlo: str, leaf_dims: str) -> list:
+    """Operations that copy, transpose, slice or relay a bf16 pool leaf of
+    ``leaf_dims``, or hold one in another layout than the kernel's."""
+    return [(i.op, n) for n, i in _instructions(hlo).items()
+            if (i.dtype, i.dims) == ("bf16", leaf_dims)
+            and (i.op in ("copy", "transpose", "slice", "dynamic-slice",
+                          "gather", "concatenate", "copy-start")
+                 or i.layout != "3,2,1,0")]
+
+
+def _leaf_scatters(hlo: str, leaf_dims: str) -> list:
+    """Scatters of the compiled text that yield a payload leaf of
+    ``leaf_dims`` ([pages, heads, block, head_dim]), in that shape or seen
+    as rows of ``head_dim``: what ``write_kv_rows`` compiles to."""
+    pages, heads, block, hd = map(int, leaf_dims.split(","))
+    shapes = (leaf_dims, f"{pages * heads * block},{hd}")
+    return [l.strip()[:160] for l in hlo.splitlines()
+            if re.search(r"= \w+\[(" + "|".join(shapes) + r")\]\S* scatter\(",
+                         l)]
+
+
 OURO_PAGES = 320   # what the chip's memory leaves of the file's cap of 512
 
 
@@ -666,9 +766,10 @@ def test_ouro_step_is_one_loop_and_copies_no_leaf(one_chip, on_tpu):
     blocks once (48 kernel calls in the text, 192 a tick), not four
     bodies; the loop carries the 96 pool leaves of ``4 x (pages + 1)``
     pages and nothing copies, transposes, slices or relays one: each is
-    written in place by its row scatter, which keeps the scope ``attn``
-    (so ``attn_device_ms.serve`` counts the writes as in every other
-    cell). It fits the chip beside 320
+    written in place by the row writer, one call a block for K and V, under
+    the path ``attn/scatter`` (so ``attn_device_ms.serve`` counts the
+    writes as in every other cell and ``loop_device_ms.serve`` reads
+    them). It fits the chip beside 320
     pages (8.05 GB of pool, 5.34 GB of weights). Temporaries, stated:
     1.62 GB at 320 pages, of which 1.21 GB are the stacked ``wq``, ``wk``,
     ``wv`` leaves copied whole to a transposed layout before the loop (the
@@ -687,22 +788,108 @@ def test_ouro_step_is_one_loop_and_copies_no_leaf(one_chip, on_tpu):
                     f"{e['kv_block_size']},{c.head_dim}")
     leaves = {n: i for n, i in ins.items() if (i.dtype, i.dims) == leaf}
     assert len([i for i in leaves.values() if i.op == "parameter"]) == 96
-    moved = [(i.op, n) for n, i in leaves.items()
-             if i.op in ("copy", "transpose", "slice", "dynamic-slice",
-                         "gather", "concatenate", "copy-start")
-             or i.layout != "3,2,1,0"]
-    assert not moved, moved
-    # the 96 row writes run under the block's scope (write_kv_rows_flat)
-    rows = 4 * (OURO_PAGES + 1) * c.n_kv_heads * e["kv_block_size"]
-    writes = re.findall(
-        rf"^\s*%\S+ = bf16\[{rows},{c.head_dim}\]\S* fusion\(.*$", hlo, re.M)
-    assert len(writes) == 96
-    assert all('/while/body/closed_call/attn/scatter"' in w for w in writes)
+    assert not _leaf_moves(hlo, leaf[1])
+    # the rows are written by one call a block for both of its leaves, 48
+    # in the loop's body (192 a tick where 384 scatter fusions were),
+    # under the block's scope; no scatter of a leaf is left
+    writes = _writer_calls(hlo)
+    assert len(writes) == 48
+    assert all("/while/body/closed_call/attn/scatter/" in w for w in writes)
+    assert not _leaf_scatters(hlo, leaf[1])
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.75e9, temp
     smaller, _, _ = compile_cell_step("ouro-2.6b", one_chip, 64, 64,
                                       OURO_PAGES // 2)
     assert abs(smaller.memory_analysis().temp_size_in_bytes - temp) < 2e6
+
+
+# (config, lanes, live pages, pages of the pool or 0 for the file's, KV
+# layers): the cells' decode steps at their files' depths. Mistral's
+# token_budget shape is held at two layers of the same widths
+# (test_ragged_step_compiles, test_ragged_step_writes_pool_in_place: a
+# 16-layer compile at 2048 lanes is a minute of this file's one worker)
+CELL_STEPS = {
+    "mistral_decode": ("mistral-7b", 64, 128, 0, 16),
+    "olmo_decode": ("olmo-hybrid-7b", 64, 128, 0, 3),
+    "ouro_decode": ("ouro-2.6b", 64, 64, OURO_PAGES, 48),
+}
+
+
+def _cell_step(device_sharding, cell: str):
+    """``compile_cell_step`` of a ``CELL_STEPS`` entry, called as the other
+    tests of this file call it so that its cache answers."""
+    config, T, live_pages, pages, _ = CELL_STEPS[cell]
+    return compile_cell_step(config, device_sharding, T, live_pages,
+                             *([pages] if pages else []))
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_STEPS))
+def test_cell_step_writes_rows_by_one_call_a_layer(one_chip, on_tpu, cell):
+    """On the TPU path no payload leaf of a serving cell is written by an
+    XLA scatter: the compiled step holds one ``write_kv_pages`` call a KV
+    layer (16 / 3 / 48 in the text; Ouro's 48 run four times a tick), each
+    returning both leaves as a tuple under ``attn/scatter``, in front of
+    that layer's paged kernel; and nothing copies, transposes, slices or
+    relays a leaf."""
+    config, T, live_pages, pages, kv_layers = CELL_STEPS[cell]
+    compiled, c, e = _cell_step(one_chip, cell)
+    hlo = compiled.as_text()
+    assert _kernel_calls(hlo) == kv_layers
+    assert len(_writer_calls(hlo)) == kv_layers
+    passes = c.total_ut_steps or 1
+    leaf = (f"{passes * ((pages or e['max_kv_blocks']) + 1)},{c.n_kv_heads},"
+            f"{e['kv_block_size']},{c.head_dim}")
+    assert all(l.count(f"bf16[{leaf}]") >= 2 for l in _writer_calls(hlo))
+    assert not _leaf_scatters(hlo, leaf)
+    assert not _leaf_moves(hlo, leaf)
+    if passes == 1:     # the loop's leaves come out of its carried tuple
+        assert _pool_faults(hlo, heads=c.n_kv_heads,
+                            leaf_pages=e["max_kv_blocks"] + 1) == []
+
+
+def test_mistral_step_temporaries_are_the_parents(one_chip, on_tpu):
+    """The writer brings no temporary of the pool's size. The 16-layer
+    decode step's temporaries with the scatter (my described-chip compile
+    of PR 38's parent commit: 737,403,904 B) were the stacked attention
+    weights copied to a transposed layout (737 MB, PERF.md section 7
+    question 4) and the activations; with the writer they read 737,986,048
+    B: the new rows laid out [hkv, T, hd] for the kernel, 0.6 MB, are all
+    that is added (at 2048 lanes 831,137,792 -> 836,354,560: 5.2 MB)."""
+    compiled, _, _ = compile_cell_step("mistral-7b", one_chip, 64, 128)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 740e6 and temp - 737_403_904 < 1e6, temp
+
+
+def test_roofline_readers_do_not_count_the_writer(one_chip, on_tpu):
+    """``paged_attn_roofline_pct`` and ``loop_paged_attn_roofline_pct``
+    know the paged kernel by its result's shape in an operation's name as
+    ``trace_reduce.clean`` keeps it (``args.kernel`` of their metric
+    files, which this repo's PRs may not edit). Over the cells' compiled
+    steps that expression matches every paged-kernel call and no call of
+    the row writer, whose result is a tuple: a writer that returned one
+    bf16 leaf would be counted as attention."""
+    import json
+
+    from benchmarks import harness, trace_reduce as tr
+
+    patterns = set()
+    for metric in ("paged_attn_roofline_pct", "loop_paged_attn_roofline_pct"):
+        spec = json.load(open(os.path.join(harness.HERE, "metrics",
+                                           metric + ".json")))
+        patterns.add(spec["args"]["kernel"])
+    assert patterns
+    for cell, (*_, kv_layers) in CELL_STEPS.items():
+        compiled, _, _ = _cell_step(one_chip, cell)
+        hlo = compiled.as_text()
+        names = lambda lines: [tr.clean(l.strip().removeprefix("ROOT "))
+                               for l in lines]
+        writer = names(_writer_calls(hlo))
+        paged = names(l for l in _custom_calls(hlo)
+                      if _KERNEL_RESULT.search(l))
+        assert len(writer) == len(paged) == kv_layers
+        for pattern in map(re.compile, patterns):
+            assert all(pattern.search(n) for n in paged), paged[:1]
+            assert not any(pattern.search(n) for n in writer), writer[:1]
 
 
 @pytest.mark.parametrize("T", [64, 2048], ids=["decode", "prefill_chunk"])
