@@ -292,6 +292,7 @@ class RaggedInferenceEngine:
         self._token_ids = False     # return_token_ids
         self._step_fn = None
         self._step_ids = None       # the last step's ids, on the device
+        self._call_leaves = 0       # _count_call_leaves, when a step is built
         self._core_fn = None
         self._decode_fn = None
         self._verify_fn = None
@@ -996,10 +997,9 @@ class RaggedInferenceEngine:
             if self._step_fn is None:
                 self._step_fn = self._build_step()
             self._step_ids = None    # never a stale step's, whatever runs
-            logits, self.kv_pool = self._step_fn(
-                self.params, self.kv_pool, jnp.asarray(flat_tokens),
-                jnp.asarray(flat_slot), jnp.asarray(flat_pos),
-                jnp.asarray(block_tables), jnp.asarray(sel_idx), live_pages)
+            logits, self.kv_pool = self._launch(
+                self._step_fn, (flat_tokens, flat_slot, flat_pos,
+                                block_tables, sel_idx), live_pages)
         # only what the caller reads comes back: [max_seqs] ids, or the
         # [max_seqs, vocab] logits (which otherwise never leave the device)
         got = self._step_ids if as_ids else logits
@@ -1010,6 +1010,18 @@ class RaggedInferenceEngine:
                                   None if as_ids else got.shape[-1])
             self._record_step_telemetry(sched, got.nbytes, attrs)
         return out
+
+    def _launch(self, step, host, live_pages: int):
+        """``ragged.dispatch``'s two kinds of work, a span each: the host
+        arrays of a tick sent to the device (``ragged.h2d``), then the
+        jitted ``step`` called on them until it returns (``ragged.call``:
+        the call flattens ``leaves`` arrays of parameters and pool, and
+        returns once the program is enqueued, not when it has run)."""
+        with annotate("ragged.h2d", arrays=len(host),
+                      bytes=sum(a.nbytes for a in host)):
+            sent = [jnp.asarray(a) for a in host]
+        with annotate("ragged.call", leaves=self._call_leaves):
+            return step(self.params, self.kv_pool, *sent, live_pages)
 
     def _hand_back(self, uids, last_index, pick,
                    width: Optional[int]) -> np.ndarray:
@@ -1194,10 +1206,9 @@ class RaggedInferenceEngine:
         with annotate("ragged.dispatch"):
             if self._verify_fn is None:
                 self._verify_fn = self._build_verify()
-            logits, self.kv_pool = self._verify_fn(
-                self.params, self.kv_pool, jnp.asarray(flat_tokens),
-                jnp.asarray(flat_slot), jnp.asarray(flat_pos),
-                jnp.asarray(block_tables), jnp.asarray(sel_rows), live_pages)
+            logits, self.kv_pool = self._launch(
+                self._verify_fn, (flat_tokens, flat_slot, flat_pos,
+                                  block_tables, sel_rows), live_pages)
         # the chains' rows are read as logits by the caller, so the whole
         # result comes back in either form
         with annotate("ragged.fetch", bytes=int(logits.nbytes)):
@@ -1318,7 +1329,15 @@ class RaggedInferenceEngine:
         logits = np.asarray(logits)             # [max_seqs, k_max, vocab]
         return [logits[seq.slot, :take] for seq, take in sched]
 
+    def _count_call_leaves(self) -> None:
+        """``ragged.call``'s ``leaves``: the arrays a step call flattens
+        (parameters and pool), counted here once a built step, not by a
+        tree walk a tick."""
+        self._call_leaves = len(jax.tree_util.tree_leaves(
+            (self.params, self.kv_pool)))
+
     def _build_verify(self):
+        self._count_call_leaves()
         core = self._core
         model = self.model
 
@@ -1885,6 +1904,7 @@ class RaggedInferenceEngine:
         return self._core_fn
 
     def _build_step(self):
+        self._count_call_leaves()
         core = self._core
         model = self.model
 

@@ -41,6 +41,7 @@ PARENT = {
     "ragged.put": "serve.put", "ragged.admit": "ragged.put",
     "ragged.pack": "ragged.put", "ragged.dispatch": "ragged.put",
     "ragged.fetch": "ragged.put", "ragged.rows": "ragged.put",
+    "ragged.h2d": "ragged.dispatch", "ragged.call": "ragged.dispatch",
     "serve.tick": None, "serve.admit": "serve.tick",
     "serve.put": "serve.tick", "serve.emit": "serve.tick",
     "serve.retire": "serve.tick", "serve.wait": None,
@@ -52,6 +53,7 @@ ATTRS = {
                    "q_tiles", "kv_steps", "passes", "kv_layers",
                    "write_tiles", "write_pages"},
     "ragged.admit": {"matched", "prompt"}, "ragged.fetch": {"bytes"},
+    "ragged.h2d": {"arrays", "bytes"}, "ragged.call": {"leaves"},
     "serve.tick": {"tick", "queued", "live"},
     "serve.admit": {"admitted", "preempted"}, "serve.put": {"retries"},
     "serve.emit": {"tokens"}, "train.step": {"step", "k"},
@@ -210,7 +212,9 @@ def runs(tmp_path_factory):
     mesh_mod.reset_topology()
     return {"spans": trace.spans, "tokens": len(traced_tokens), "off": off,
             "hlo": hlo, "buckets": list(ragged._buckets),
-            "max_pages": ragged.max_pages, "step0": step0}
+            "max_pages": ragged.max_pages, "step0": step0,
+            "leaves": len(jax.tree_util.tree_leaves((ragged.params,
+                                                     ragged.kv_pool)))}
 
 
 def named(runs, name):
@@ -258,6 +262,78 @@ def test_put_attributes_agree_with_the_engine(runs):
     # behind the server a tick brings back token ids, not logits rows
     for s in named(runs, "ragged.fetch"):
         assert s.attrs["bytes"] == MAX_SEQS * 4
+
+
+def test_dispatch_is_cut_where_the_work_changes_kind(runs):
+    """Every ``ragged.dispatch`` holds one ``ragged.h2d`` (the tick's five
+    int32 arrays: three of a lane each, the tables, the selection row) and
+    then one ``ragged.call`` (the arrays the call flattens: parameters and
+    pool), and nothing of any length beside them once the step is built."""
+    spans = runs["spans"]
+    for d in named(runs, "ragged.dispatch"):
+        inside = [s for s in spans if s.parent is not None
+                  and spans[s.parent] is d]
+        assert [s.name for s in inside] == ["ragged.h2d", "ragged.call"]
+        h2d, call = inside
+        lanes = spans[d.parent].attrs["lanes"]
+        assert h2d.attrs == {"arrays": 5, "bytes": 4 * (
+            3 * lanes + MAX_SEQS * runs["max_pages"] + MAX_SEQS)}
+        assert call.attrs == {"leaves": runs["leaves"]}
+        assert h2d.end <= call.start
+    assert runs["leaves"] > 5
+
+
+@pytest.mark.parametrize("entry", ["put", "put_spec"])
+def test_h2d_and_call_nest_under_dispatch_in_either_entry(entry, monkeypatch):
+    """``put`` and ``put_spec`` alike, without a session: the two spans open
+    and close inside ``ragged.dispatch``, in that order, with ``leaves``
+    counted when the step was built (a count of ``(params, kv_pool)``) and
+    ``bytes`` the five host arrays' (the verify step's selection is
+    ``[max_seqs, k]``)."""
+    model = Llama("tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+                  vocab_size=VOCAB, max_seq_len=256, use_flash=False,
+                  remat=False)
+    engine = RaggedInferenceEngine(model, RaggedConfig(
+        token_budget=32, max_seqs=MAX_SEQS, kv_block_size=BLOCK,
+        n_kv_blocks=N_BLOCKS, max_context=128, dtype=jnp.float32),
+        params=model.init(jax.random.PRNGKey(5)))
+    log = []
+
+    class Span:
+        def __init__(self, name, attrs):
+            self.name, self.attrs = name, attrs
+
+        def __enter__(self):
+            log.append(("open", self.name, dict(self.attrs)))
+            return self
+
+        def __exit__(self, *a):
+            log.append(("close", self.name, None))
+            return False
+
+        def set_metadata(self, **attrs):
+            pass
+
+    monkeypatch.setattr(ragged_mod, "annotate",
+                        lambda name, **attrs: Span(name, attrs))
+    assert engine._call_leaves == 0              # no step built yet
+    rows = engine.put([1], [_prompt(1, 10)])
+    if entry == "put_spec":
+        del log[:]
+        nxt = int(np.argmax(rows[0]))
+        engine.put_spec([1], [[nxt]], [[3, 4]])
+    events = [(kind, name) for kind, name, _ in log
+              if name in ("ragged.dispatch", "ragged.h2d", "ragged.call")]
+    assert events == [("open", "ragged.dispatch"), ("open", "ragged.h2d"),
+                      ("close", "ragged.h2d"), ("open", "ragged.call"),
+                      ("close", "ragged.call"), ("close", "ragged.dispatch")]
+    attrs = {name: a for kind, name, a in log if kind == "open"}
+    leaves = len(jax.tree_util.tree_leaves((engine.params, engine.kv_pool)))
+    assert attrs["ragged.call"] == {"leaves": leaves} and leaves > 5
+    lanes, k = engine._buckets[0], 4 if entry == "put_spec" else 1
+    assert attrs["ragged.h2d"] == {"arrays": 5, "bytes": 4 * (
+        3 * lanes + MAX_SEQS * engine.max_pages + MAX_SEQS * k)}
+    assert all(type(v) is int for a in attrs.values() for v in a.values())
 
 
 def test_admit_attributes_count_prompts_and_prefix_hits(runs):
