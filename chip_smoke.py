@@ -43,6 +43,8 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # reference, relative to the reference's largest magnitude; and the ragged
 # engine's logits against model.apply's full forward (two bf16 programs)
 KERNEL_REL_TOL = 0.03
+# the delta-rule step is float32 throughout; only its sums' order differs
+DELTA_STEP_REL_TOL = 1e-5
 LOGITS_REL_TOL = 0.05
 # ZeRO-3 on four chips against ZeRO-0 on one: same math, different
 # reduction order in bf16 — per-step loss agreement
@@ -92,6 +94,8 @@ class Sizes:
     # KV heads of the leaves the row writer is checked at: Mistral's,
     # Ouro's, Olmo-Hybrid's
     kernel_writer_heads: Tuple[int, ...] = (8, 16, 30)
+    # the delta-rule step kernel's state: Olmo-Hybrid's (heads, key, value)
+    kernel_delta_state: Tuple[int, int, int] = (30, 96, 192)
     # --chips 4: global batch, split four ways under ZeRO-3
     zero3_layers: int = 1
     zero3_batch: int = 4
@@ -371,7 +375,38 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
             args = (*pools, *new, jnp.asarray(slots), jnp.asarray(pos), tables)
             rows_off[f"{name.replace('paged', 'rows')}_h{nkv}"] = int(
                 unequal(written(*args), scattered(*args), pools))
+    # the delta-rule step kernel against ``delta_step`` in XLA on the same
+    # rows: two slots in three decode (every fourth from zeros), the others
+    # and the sink keep their bits
+    from deepspeed_tpu.ops import gated_delta
+    from deepspeed_tpu.ops.pallas.gated_delta import delta_step_slots
+
+    dh, dk, dv = sz.kernel_delta_state
+    dkeys = jax.random.split(jax.random.fold_in(kp, 2000), 6)
+    stepping = np.flatnonzero(np.arange(ns) % 3 != 1).astype(np.int32)
+    nd = len(stepping)
+    dpos = np.where(np.arange(nd) % 4 == 0, 0, 7).astype(np.int32)
+    runs = jax.jit(gated_delta.runs_of, static_argnums=2)(
+        jnp.asarray(stepping), jnp.asarray(dpos), ns)
+    dq, dkk = (gated_delta.l2norm(jax.random.normal(a, (nd, dh, dk)))
+               for a in dkeys[:2])
+    drows = (dq * dk ** -0.5, dkk,
+             jax.random.normal(dkeys[2], (nd, dh, dv)),
+             -jax.nn.softplus(jax.random.normal(dkeys[3], (nd, dh))),
+             2 * jax.nn.sigmoid(jax.random.normal(dkeys[4], (nd, dh))))
+    dstate = jax.random.normal(dkeys[5], (ns + 1, dh, dk, dv))
+    got_o, got_s = delta_step_slots(*drows, dstate, runs.steps,
+                                    interpret=interpret)
+    want_o, want_s = jax.jit(gated_delta.delta_step)(
+        *drows, jnp.where(jnp.asarray(dpos == 0)[:, None, None, None], 0.0,
+                          dstate[stepping]))
+    delta_errs = {"delta_step_o": _rel_err(got_o, want_o),
+                  "delta_step_state": _rel_err(got_s[stepping], want_s)}
+    idle = np.setdiff1d(np.arange(ns + 1), stepping)
+    state_off = int(jnp.sum(got_s[idle] != dstate[idle]))
+    errs.update(delta_errs)
     rec.update(shape={"flash": [1, S, f"{hq}/{hkv}", hd],
+                      "delta_state": [ns + 1, dh, dk, dv],
                       "paged": {n: len(s[0]) for n, s in shapes.items()},
                       "paged_variants": {t or "bf16": list(v[:2])
                                          for t, v in variants.items()},
@@ -382,6 +417,11 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     _check(not bad, f"kernels off their jnp reference: {bad}")
     _check(not any(rows_off.values()),
            f"write_kv_pages is not bit-equal to the scatter: {rows_off}")
+    rec.update(state_unequal=state_off)
+    _check(max(delta_errs.values()) <= DELTA_STEP_REL_TOL,
+           f"the delta-rule step kernel is off delta_step: {delta_errs}")
+    _check(state_off == 0, f"the delta-rule step kernel changed {state_off} "
+           f"elements of slots that do not decode")
 
 
 # ----------------------------------------------------------------------

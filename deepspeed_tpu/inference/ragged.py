@@ -1080,11 +1080,15 @@ class RaggedInferenceEngine:
         hold pages, times the passes); and what the row writer
         (``write_kv_pages``) serves in each of those layers: its live
         tiles and the page slabs it moves (0 where a scatter writes the
-        rows)."""
+        rows). With recurrent layers: the slots whose state is live, and
+        the entries of one lane (a decode token, or a prompt's last) that
+        the delta-rule step kernel serves in each such layer
+        (``step_slots``; 0 where the step runs in XLA over every slot)."""
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
-        prefill = decode = 0
+        prefill = decode = single = 0
         for seq, take in sched:
+            single += take == 1
             if seq.seen < seq.prompt_len:
                 prefill += take
             elif take == 1:
@@ -1100,8 +1104,10 @@ class RaggedInferenceEngine:
                  "kv_layers": self._kv_layers,
                  "write_tiles": q_tiles if self._writes_pages else 0,
                  "write_pages": pages if self._writes_pages else 0}
-        if self._state_layers:    # slots whose recurrent state is live
+        if self._state_layers:
             attrs["state_slots"] = len(self.seqs)
+            attrs["step_slots"] = \
+                single if self.attention_path != "gather" else 0
         return attrs
 
     def put_spec(self, uids: Sequence[int], tokens: Sequence[Sequence[int]],
@@ -1252,6 +1258,8 @@ class RaggedInferenceEngine:
         r.gauge("inference/live_sequences").set(len(self.seqs))
         if self._state_layers:
             r.gauge("inference/state_slots_live").set(len(self.seqs))
+            r.counter("inference/state_slots_stepped").inc(
+                attrs["step_slots"] * len(self._state_layers))
 
     def _validate_sched(self, sched) -> List[int]:
         """Validate a (seq, take) schedule WITHOUT mutating anything:
@@ -1768,7 +1776,8 @@ class RaggedInferenceEngine:
             def linear_block(x, lp, own):
                 with jax.named_scope("linear_attn"):
                     attn, state, rows = gated_delta.mix_ragged(
-                        x, lp, c, own["state"], own["conv_rows"], runs)
+                        x, lp, c, own["state"], own["conv_rows"], runs,
+                        self.attention_path)
                 return after_mixer(x, attn, lp), \
                     {"state": state, "conv_rows": rows}
 

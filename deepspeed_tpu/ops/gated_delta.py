@@ -12,19 +12,31 @@ For one head, token ``t``, input ``x_t`` (``K`` = ``conv_kernel``)::
 
 The recurrence exists in three forms over the same mathematics:
 
-* :func:`delta_step`: one token a sequence, batched (a decode lane);
+* :func:`delta_step`: one token a sequence, batched (a decode lane), in
+  XLA. Off the TPU it is what the ragged step runs, over the whole state
+  leaf; everywhere it is what :func:`delta_recurrent` scans and the oracle
+  of the kernel below;
 * :func:`delta_chunk`: ``CHUNK`` tokens of one sequence at once, the WY form
   (the strictly-lower system ``(I + A) U = beta (V - exp(G) K S0)`` solved
   by one triangular solve), from a state and leaving one behind;
 * :func:`delta_recurrent`: ``lax.scan`` of the step over tokens, the oracle
   the tests hold the other two to.
 
+On the TPU the step is a Pallas call a layer,
+``ops/pallas/gated_delta.py::delta_step_slots``: the same float32
+products over the slots that decode one token this step and no others,
+each slot's state read once and written once, the leaf aliased in and out.
+
 :func:`mix_dense` ([b, s, d], what ``Transformer.apply`` and training run)
 scans chunks; :func:`mix_ragged` (a flat batch of lanes from many sequences,
 what ``RaggedInferenceEngine``'s step runs) gives each single-lane run the
 step and cuts longer runs into chunk-sized pieces, reading the slot's state
 and convolution rows from the pool leaves before a run and leaving them
-behind after it. Both call the same projections, gates and output.
+behind after it. Both call the same projections, gates and output. Which
+form of the step :func:`mix_ragged` takes is the engine's
+``attention_path``, handed down: ``pallas`` (the chip) and
+``pallas_interpret`` (CPU tests) the kernel, ``gather`` the XLA form; the
+chunk loop behind it is the same on every path.
 
 Everything between the projections and the output product is float32; the
 state products that decide the answer ask for full float32 passes on the
@@ -209,6 +221,11 @@ class Runs(NamedTuple):
     piece_at: Any    # [P] its first lane
     piece_n: Any     # [P] its live lanes (1..CHUNK)
     piece_first: Any  # [P] it is its run's first piece
+    steps: Any       # [4, N] the runs of one lane, live entries first: an
+    #                  entry's slot, its lane, whether its run starts a
+    #                  sequence, and (every column) the count of live
+    #                  entries; an entry past it names the last live slot
+    #                  again (the sink where none is live). N = min(S, T)
 
 
 def runs_of(slots, positions, n_slots: int) -> Runs:
@@ -235,8 +252,19 @@ def runs_of(slots, positions, n_slots: int) -> Runs:
     nth = p - (ends - per)[piece_slot]
     piece_at = first[piece_slot] + nth * CHUNK
     piece_n = jnp.clip(length[piece_slot] - nth * CHUNK, 0, CHUNK)
+    # the single-lane runs, compacted in slot order (all compares at once,
+    # as ``work_list``): what the step kernel's grid walks
+    count = jnp.cumsum((length == 1).astype(jnp.int32))
+    n = count[-1]
+    e = jnp.arange(min(n_slots, T), dtype=jnp.int32)
+    step_slot = jnp.searchsorted(count, jnp.minimum(e, n - 1) + 1,
+                                 side="left", method="compare_all")
+    step_slot = jnp.where(n > 0, step_slot, n_slots).astype(jnp.int32)
+    steps = jnp.stack([step_slot, jnp.minimum(first[step_slot], T - 1),
+                       slot_fresh[step_slot].astype(jnp.int32),
+                       jnp.broadcast_to(n, e.shape)])
     return Runs(slot, off, fresh, first, last, length, slot_fresh, ends[-1],
-                piece_slot, piece_at, piece_n, nth == 0)
+                piece_slot, piece_at, piece_n, nth == 0, steps)
 
 
 def conv_ragged(x, w, rows, runs: Runs):
@@ -259,24 +287,34 @@ def conv_ragged(x, w, rows, runs: Runs):
     return y, rows
 
 
-def delta_ragged(q, k, v, g, beta, state, runs: Runs):
+def delta_ragged(q, k, v, g, beta, state, runs: Runs, path: str = "gather"):
     """The recurrence over lanes. q, k [T, H, dk]; v [T, H, dv]; g, beta
-    [T, H]; state [S + 1, H, dk, dv] float32. Returns (o [T, H, dv],
-    state)."""
+    [T, H]; state [S + 1, H, dk, dv] float32. ``path`` is the engine's
+    ``attention_path``: which form the runs of one lane take (module
+    docstring). Returns (o [T, H, dv], state)."""
     T = q.shape[0]
-    n_slots = state.shape[0] - 1
     with jax.named_scope("delta_step"):
-        # a run of one lane: the step, a slot a row, over the whole leaf
-        # (a slot without such a run keeps its state)
-        one = runs.length == 1
-        lane = jnp.minimum(runs.first, T - 1)
-        old = jnp.where((one & runs.slot_fresh)[:, None, None, None], 0.0,
-                        state)
-        o1, new = delta_step(q[lane], k[lane], v[lane], g[lane], beta[lane],
-                             old)
-        state = jnp.where(one[:, None, None, None], new, state)
+        if path == "gather":
+            # the step in XLA, a slot a row, over the whole leaf (a slot
+            # without a run of one lane keeps its state)
+            one = runs.length == 1
+            lane = jnp.minimum(runs.first, T - 1)
+            old = jnp.where((one & runs.slot_fresh)[:, None, None, None], 0.0,
+                            state)
+            o1, new = delta_step(q[lane], k[lane], v[lane], g[lane],
+                                 beta[lane], old)
+            state = jnp.where(one[:, None, None, None], new, state)
+        else:
+            # the kernel, over the slots that have such a run alone
+            from .pallas.gated_delta import delta_step_slots
+
+            lane = runs.steps[1]
+            one = jnp.arange(lane.shape[0]) < runs.steps[3]
+            o1, state = delta_step_slots(
+                q[lane], k[lane], v[lane], g[lane], beta[lane], state,
+                runs.steps, interpret=path == "pallas_interpret")
         # lanes CHUNK past the end take the pieces' overhang and the rows
-        # of slots that decode nothing
+        # of slots (the kernel's: of entries) that decode nothing
         out = jnp.zeros((T + CHUNK,) + o1.shape[1:], F32) \
             .at[jnp.where(one, lane, T)].set(o1)
     with jax.named_scope("delta_chunk"):
@@ -304,13 +342,15 @@ def delta_ragged(q, k, v, g, beta, state, runs: Runs):
     return out[:T], state
 
 
-def mix_ragged(x, lp: Dict[str, Any], c, state, rows, runs: Runs):
+def mix_ragged(x, lp: Dict[str, Any], c, state, rows, runs: Runs,
+               path: str = "gather"):
     """x [T, d] -> (y [T, d], state, rows): the lanes of one step through
-    one recurrent layer and its two pool leaves."""
+    one recurrent layer and its two pool leaves; ``path`` as
+    :func:`delta_ragged` takes it."""
     qkv, g, beta, z = _project(x, lp, c)
     with jax.named_scope("conv"):
         qkv, rows = conv_ragged(qkv.astype(F32), lp["conv_w"].astype(F32),
                                 rows, runs)
     q, k, v = _heads(qkv, c)
-    o, state = delta_ragged(q, k, v, g, beta, state, runs)
+    o, state = delta_ragged(q, k, v, g, beta, state, runs, path)
     return _output(o, z, lp, c, x.dtype), state, rows

@@ -25,7 +25,7 @@ def _tiny() -> chip_smoke.Sizes:
         prompt_lens=(9, 40, 100), shared_prefix=32, probe_len=20,
         new_tokens=4,
         kernel_seq=256, kernel_pages_per_seq=8, kernel_n_seqs=8,
-        kernel_alt_heads=(3, 3),
+        kernel_alt_heads=(3, 3), kernel_delta_state=(3, 8, 16),
         zero3_layers=2, zero3_batch=4, zero3_steps=2)
 
 
@@ -43,7 +43,10 @@ def test_kernels_phase_interpret(ledger, capsys):
              for shape in ("prefill", "decode", "mixed")
              for variant in ("", "_h30", "_w64", "_int8")}
     assert set(line["rel_err"]) == {"flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dk", "flash_bwd_dv"} | paged
+                                    "flash_bwd_dk", "flash_bwd_dv",
+                                    "delta_step_o", "delta_step_state"} | paged
+    # the delta-rule step kernel leaves the slots that do not decode alone
+    assert line["state_unequal"] == 0
     # the row writer against the scatter: no element differs
     assert line["rows_unequal"] == {
         f"rows_{shape}_h{heads}": 0

@@ -114,6 +114,86 @@ def test_ragged_lanes_against_the_token_recurrence(case):
 
 
 # ----------------------------------------------------------------------
+# the step kernel (interpret mode) against ``delta_step`` on the same rows
+@pytest.mark.parametrize("n", [1, 20, 64])
+def test_step_kernel_against_delta_step(n):
+    """``n`` runs of one lane among 65 slots (every third starts a
+    sequence), one run of five lanes and, where slots are left, slots with
+    no run: the kernel gives each single-lane slot ``delta_step``'s output
+    and state (from zeros where the run starts a sequence), and leaves
+    every other slot's bits as they were: the idle slots, the sink, and the
+    slot whose run is longer, which the chunk loop serves behind it."""
+    from deepspeed_tpu.ops.pallas.gated_delta import delta_step_slots
+
+    S, T = 65, 128
+    rng = np.random.default_rng(n)
+    order = rng.permutation(S)
+    single, long_slot = order[:n], order[n]
+    slots = np.full(T, -1, np.int32)
+    positions = np.zeros(T, np.int32)
+    at = n // 2                     # the long run sits between single lanes
+    lane_of = {}
+    t = 0
+    for i, sl in enumerate(single):
+        if i == at:
+            slots[t:t + 5], positions[t:t + 5] = long_slot, np.arange(9, 14)
+            t += 5
+        slots[t], positions[t] = sl, 0 if i % 3 == 0 else 3 + i
+        lane_of[sl] = t
+        t += 1
+    state = jax.random.normal(jax.random.PRNGKey(7), (S + 1, H, DK, DV))
+    xs = _lanes(jax.random.PRNGKey(9), T)
+    runs = gd.runs_of(jnp.asarray(slots), jnp.asarray(positions), S)
+    steps = np.asarray(runs.steps)
+    assert steps[3].tolist() == [n] * min(S, T)
+    assert steps[0, :n].tolist() == sorted(single.tolist())
+    assert steps[1, :n].tolist() == [lane_of[sl] for sl in steps[0, :n]]
+    assert steps[2, :n].tolist() == [int(positions[l] == 0)
+                                     for l in steps[1, :n]]
+    assert set(steps[0, n:].tolist()) <= {steps[0, n - 1]}
+    rows = [a[steps[1]] for a in xs]
+    o, st = delta_step_slots(*rows, state, runs.steps, interpret=True)
+    old = jnp.where(jnp.asarray(steps[2, :n] > 0)[:, None, None, None], 0.0,
+                    state[steps[0, :n]])
+    want_o, want_s = gd.delta_step(*(a[:n] for a in rows), old)
+    np.testing.assert_allclose(np.asarray(o)[:n], want_o, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(st)[steps[0, :n]], want_s,
+                               atol=1e-5)
+    untouched = np.setdiff1d(np.arange(S + 1), single)
+    assert long_slot in untouched and S in untouched
+    assert np.array_equal(np.asarray(st)[untouched],
+                          np.asarray(state)[untouched])
+    # behind it the chunk loop serves the longer run, as on the XLA path
+    o1, s1 = jax.jit(gd.delta_ragged, static_argnums=7)(
+        *xs, state, runs, "gather")
+    o2, s2 = jax.jit(gd.delta_ragged, static_argnums=7)(
+        *xs, state, runs, "pallas_interpret")
+    np.testing.assert_allclose(np.asarray(o2)[:t], np.asarray(o1)[:t],
+                               atol=1e-5)
+    np.testing.assert_allclose(s2, s1, atol=1e-5)
+    assert not np.array_equal(np.asarray(s2)[long_slot],
+                              np.asarray(state)[long_slot])
+
+
+def test_step_kernel_with_no_single_lane_run_changes_nothing():
+    """An empty batch (the runner's warm-up) and a batch of one long run:
+    every entry names the sink, which comes back bit for bit."""
+    from deepspeed_tpu.ops.pallas.gated_delta import delta_step_slots
+
+    S, T = 6, 64
+    state = jax.random.normal(jax.random.PRNGKey(7), (S + 1, H, DK, DV))
+    xs = _lanes(jax.random.PRNGKey(9), T)
+    for live in (0, 40):
+        slots = np.where(np.arange(T) < live, 2, -1).astype(np.int32)
+        runs = gd.runs_of(jnp.asarray(slots), jnp.arange(T, dtype=jnp.int32),
+                          S)
+        assert np.asarray(runs.steps)[[0, 3]].tolist() == [[S] * S, [0] * S]
+        _, st = delta_step_slots(*(a[runs.steps[1]] for a in xs), state,
+                                 runs.steps, interpret=True)
+        assert np.array_equal(np.asarray(st), np.asarray(state))
+
+
+# ----------------------------------------------------------------------
 # the model against the plain reference
 @pytest.fixture(scope="module")
 def built():
@@ -275,6 +355,98 @@ def test_ragged_engine_agrees_with_the_reference(built, scenario):
     err = _rel(np.concatenate([g.reshape(len(c), -1)
                                for g, c in zip(got, cols)]), want)
     assert err.max() < 1e-4, err
+
+
+# ----------------------------------------------------------------------
+# the engine as the TPU runs it (kernels in interpret mode) against the
+# engine as the CPU runs it
+@pytest.fixture(scope="module")
+def two_paths(built):
+    """{path: (tokens fed, logits [3, 9, vocab], ``ragged.put``'s
+    attributes a tick, ``inference/state_slots_stepped``'s rise)}: 175
+    prompt tokens against a budget of 128, so a prompt's state crosses a
+    tick, then eight decode steps, then four in one ``decode_steps``."""
+    from deepspeed_tpu.config import TelemetryConfig
+    from deepspeed_tpu.inference import ragged as ragged_mod
+    from deepspeed_tpu.telemetry import Telemetry, set_telemetry
+
+    seen = []
+
+    class Span:
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *a):
+            return False
+
+        def set_metadata(self, **attrs):
+            seen.append(attrs)
+
+    def drive(eng):
+        uids, prompts = [1, 2, 3], _prompts(100, 70, 5)
+        rows, puts = _prefill(eng, uids, prompts)
+        assert puts == 2
+        fed, got = [list(p) for p in prompts], [rows]
+        for _ in range(8):
+            nxt = np.argmax(got[-1], -1)
+            for f, t in zip(fed, nxt):
+                f.append(int(t))
+            got.append(eng.put(uids, [[int(t)] for t in nxt]))
+        # and ``decode_steps``, which scans the same core: four more
+        chains = eng.decode_steps(
+            {u: int(t) for u, t in zip(uids, np.argmax(got[-1], -1))}, 4)
+        return [f + chains[u] for f, u in zip(fed, uids)], np.stack(got, 1)
+
+    out = {}
+    real = ragged_mod.annotate
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ragged_mod, "annotate",
+                   lambda name, **attrs: Span() if name == "ragged.put"
+                   else real(name, **attrs))
+        tel = Telemetry(TelemetryConfig(enabled=True, output_dir="",
+                                        jsonl_path="", stall_detection=False))
+        set_telemetry(tel)
+        try:
+            counter = tel.registry.counter("inference/state_slots_stepped")
+            for path in ("gather", "pallas_interpret"):
+                if path != "gather":
+                    mp.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+                eng = _engine(built)
+                assert eng.attention_path == path
+                before = counter.value
+                fed, got = drive(eng)
+                out[path] = (fed, got, list(seen), counter.value - before)
+                del seen[:]
+        finally:
+            set_telemetry(None)
+    return out
+
+
+def test_kernel_engine_decodes_what_the_gather_engine_decodes(two_paths):
+    """Same tokens, and logits equal to float32 reduction order, at the
+    prompts' last positions and over eight decode steps; the same four
+    tokens more from ``decode_steps`` (the kernel under its scan)."""
+    (fed_a, got_a, _, _), (fed_b, got_b, _, _) = \
+        two_paths["gather"], two_paths["pallas_interpret"]
+    assert fed_a == fed_b
+    assert np.isfinite(got_b).all()
+    assert _rel(got_b.reshape(27, -1), got_a.reshape(27, -1)).max() < 1e-5
+
+
+def test_put_counts_the_slots_the_step_kernel_serves(two_paths):
+    """``step_slots``: the schedule's entries of one lane, which the kernel
+    serves in each linear layer; 0 where the step runs in XLA. The
+    registry's ``inference/state_slots_stepped`` sums them x the 3 linear
+    layers."""
+    *_, attrs, stepped = two_paths["gather"]
+    assert [a["step_slots"] for a in attrs] == [0] * 10 and stepped == 0
+    *_, attrs, stepped = two_paths["pallas_interpret"]
+    # tick 1: the 5- and 70-token prompts whole and 53 tokens of the third;
+    # tick 2: its other 47; then three decode lanes a tick
+    assert [a["step_slots"] for a in attrs] == [0, 0] + [3] * 8
+    assert [a["decode"] for a in attrs] == [0, 0] + [3] * 8
+    assert {a["state_slots"] for a in attrs} == {3}
+    assert stepped == 3 * 8 * 3
 
 
 # ----------------------------------------------------------------------

@@ -127,6 +127,9 @@ OLMO_HEADS = (30, 30)   # Olmo-Hybrid-7B's full layers: 30 KV heads, group 1
 # the row writer's is a tuple of two pool leaves, which they must not count
 _KERNEL_RESULT = re.compile(r"= bf16\[\d+,\d+,\d+,\d+\]\S* custom-call\(")
 _WRITER_RESULT = re.compile(r"= \((\w+\[[\d,]+\])\S*, \1\S*\) custom-call\(")
+# the delta-rule step's: the entries' output rows and the state leaf
+_STEP_RESULT = re.compile(r"= \(f32\[\d+,\d+,\d+\]\S*, "
+                          r"f32\[(\d+,\d+,\d+,\d+)\]\S*\) custom-call\(")
 
 
 def _custom_calls(hlo: str) -> list:
@@ -144,12 +147,27 @@ def _writer_calls(hlo: str) -> list:
     return calls
 
 
+def _step_calls(hlo: str) -> list:
+    """The delta-rule step kernel's calls (``delta_step_slots``): each
+    returns the entries' rows and a state leaf, which is also its operand
+    (aliased: written where it lies), and runs under
+    ``linear_attn/delta_step``, the path ``delta_step_roofline_pct``
+    reads."""
+    calls = [l for l in _custom_calls(hlo) if _STEP_RESULT.search(l)]
+    for l in calls:
+        leaf = f"f32[{_STEP_RESULT.search(l).group(1)}]"
+        assert l.count(leaf) >= 2 and "output_to_operand_aliasing" in l, l
+        assert re.search(r'op_name="[^"]*linear_attn/delta_step/[^"]*"', l), l
+    return calls
+
+
 def _kernel_calls(hlo: str) -> int:
     """The paged kernel's calls; every other kernel of the module is the
-    row writer."""
+    row writer or the delta-rule step."""
     calls = _custom_calls(hlo)
     paged = [l for l in calls if _KERNEL_RESULT.search(l)]
-    assert len(paged) + len(_writer_calls(hlo)) == len(calls), calls
+    assert len(paged) + len(_writer_calls(hlo)) + len(_step_calls(hlo)) \
+        == len(calls), calls
     return len(paged)
 
 
@@ -224,6 +242,45 @@ def test_row_writer_compiles(one_chip, hkv, n_seqs, T):
                 if i.dims == dims and i.op not in ("parameter", "bitcast",
                                                    "get-tuple-element")]
     assert c.memory_analysis().temp_size_in_bytes < 16e6
+
+
+@pytest.mark.parametrize("n_slots", [64, 16], ids=["olmo_cell", "few_slots"])
+def test_delta_step_kernel_compiles(one_chip, n_slots):
+    """``delta_step_slots`` alone at Olmo-Hybrid's state shape (30 heads of
+    96 x 192 float32: a whole slot a grid step, 4 x 2.95 MB of VMEM with
+    the minor 192 padded to 256): the column picks, the dynamic row loads
+    and stores and the SMEM gates lower; the state leaf comes back aliased
+    to the donated operand, in the default tiled layout, and nothing
+    copies it."""
+    from deepspeed_tpu.ops.gated_delta import runs_of
+    from deepspeed_tpu.ops.pallas.gated_delta import (delta_step_slots,
+                                                      head_block)
+
+    H, dk, dv, T = 30, 96, 192, 64
+    assert head_block(H, dk, dv) == 30
+    N = min(n_slots, T)
+    f32 = lambda *shape: _sds(shape, jnp.float32, one_chip)
+    lanes = _sds((T,), jnp.int32, one_chip)
+
+    def fn(q, k, v, g, beta, state, slots, pos):
+        return delta_step_slots(q, k, v, g, beta, state,
+                                runs_of(slots, pos, n_slots).steps)
+
+    c = jax.jit(fn, donate_argnums=(5,)).lower(
+        f32(N, H, dk), f32(N, H, dk), f32(N, H, dv), f32(N, H), f32(N, H),
+        f32(n_slots + 1, H, dk, dv), lanes, lanes).compile()
+    hlo = c.as_text()
+    assert len(_custom_calls(hlo)) == 1
+    dims = f"{n_slots + 1},{H},{dk},{dv}"
+    call = _custom_calls(hlo)[0]
+    assert _STEP_RESULT.search(call).group(1) == dims
+    assert "output_to_operand_aliasing" in call
+    leaf = [i for i in _instructions(hlo).values() if i.dims == dims]
+    assert {i.layout for i in leaf} == {"3,2,1,0"}
+    assert not [i for i in leaf if i.op not in ("parameter", "bitcast",
+                                                "get-tuple-element",
+                                                "custom-call")]
+    assert c.memory_analysis().temp_size_in_bytes < 4e6
 
 
 def test_paged_int4_kv_refuses_before_the_compiler(one_chip):
@@ -673,8 +730,12 @@ def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
     """Rehearsal 3 for the cell ``olmo-hybrid-7b.reason``: the 12-layer
     step at the decode shape and at a 1024-lane shape fits one chip beside
     its pools; the paged kernel and the row writer run in the 3 full
-    layers only; each state leaf is written by one fusion a layer and nothing copies, transposes
-    or slices a pool leaf or a state leaf; and no weight matrix is copied
+    layers only; each state leaf is written by one call of the delta-rule
+    step kernel a layer, which takes the leaf as an operand and returns it
+    aliased (where nine ``f32`` fusions over the whole leaf were, PR 41),
+    and nothing copies, transposes, slices or selects a pool leaf or a
+    state leaf in the entry computation (the chunk loop's ``while`` updates
+    one slot in place); and no weight matrix is copied
     out of its stack (the per-layer slices of the three stacks, common,
     ``full`` and ``linear``, are read in place by the products that use
     them: what Mixtral's expert stacks pay 17 ms a tick for, PERF.md
@@ -690,12 +751,15 @@ def test_olmo_hybrid_step_fits_and_copies_no_leaf(one_chip, on_tpu, T):
     moved = [(op, dims, name) for name, dt, dims, op, line
              in _entry_instructions(hlo)
              if op in ("copy", "transpose", "slice", "dynamic-slice",
-                       "gather", "concatenate")
+                       "gather", "concatenate", "select")
              and dims in (state, rows, page)]
     assert not moved, moved
-    writes = [name for name, dt, dims, op, _ in _entry_instructions(hlo)
-              if (dt, dims, op) == ("f32", state, "fusion")]
-    assert len(writes) == len(c.layers_of("linear")) == 9, writes
+    steps = _step_calls(hlo)
+    assert len(steps) == len(c.layers_of("linear")) == 9
+    assert all(f"f32[{state}]" in l for l in steps)
+    fusions = [name for name, dt, dims, op, _ in _entry_instructions(hlo)
+               if (dt, dims, op) == ("f32", state, "fusion")]
+    assert not fusions, fusions
     # a weight matrix has at least hidden x (linear heads x key dim)
     # elements; S(1) marks the compiler's own prefetch of an operand into
     # on-chip memory, which is no copy of the layout's making
@@ -718,9 +782,10 @@ def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
     the reduction and writing them out with an asynchronous
     ``copy-start`` / ``copy-done`` in place of the head fusion's own
     write: the same bytes to HBM once, no ``copy`` operation of that
-    shape, and temporaries within 1 MB of the 108,547,584 bytes the
-    step had without the ids (my described-chip compile of PR 31's tree;
-    108,579,328 with them)."""
+    shape, and temporaries within 1 MB of what the step had without the
+    ids (108,547,584 bytes by my described-chip compile of PR 31's tree,
+    108,579,328 with them; 103,843,840 since PR 41, whose step kernel
+    took the XLA step's reductions over the state leaf with it)."""
     compiled, c, e = compile_cell_step("olmo-hybrid-7b", one_chip, 64, 128)
     hlo = compiled.as_text()
     entry = hlo[hlo.index("\nENTRY "):]
@@ -733,7 +798,7 @@ def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
               if op == "copy" and (dt, dims) == ("f32", logits[4:-1])]
     assert not copies, copies
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert abs(temp - 108_547_584) < 1e6, temp
+    assert abs(temp - 103_843_840) < 1e6, temp
 
 
 def _leaf_moves(hlo: str, leaf_dims: str) -> list:
@@ -836,6 +901,10 @@ def test_cell_step_writes_rows_by_one_call_a_layer(one_chip, on_tpu, cell):
     hlo = compiled.as_text()
     assert _kernel_calls(hlo) == kv_layers
     assert len(_writer_calls(hlo)) == kv_layers
+    # the delta-rule step kernel: a call a recurrent layer, and none in a
+    # program without such layers (Mistral's, Ouro's)
+    assert len(_step_calls(hlo)) == len(c.layers_of("linear")) \
+        == (9 if config == "olmo-hybrid-7b" else 0)
     passes = c.total_ut_steps or 1
     leaf = (f"{passes * ((pages or e['max_kv_blocks']) + 1)},{c.n_kv_heads},"
             f"{e['kv_block_size']},{c.head_dim}")
