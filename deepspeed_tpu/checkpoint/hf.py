@@ -152,6 +152,68 @@ def olmo_hybrid_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
         branch_norm=True, qk_norm=True)
 
 
+def granite_hybrid_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
+    """``model_type: granitemoehybrid`` (Granite-4.0-H) -> TransformerConfig:
+    pre-norm blocks whose mixer is by ``layer_types`` grouped-query softmax
+    attention or a Mamba-2 state-space layer (``mamba_*`` keys,
+    ops/mamba2.py) over a SwiGLU feed-forward (``shared_mlp``), with
+    Granite's four scalars: ``embedding_multiplier``,
+    ``residual_multiplier``, ``attention_multiplier`` (the softmax scale)
+    and ``logits_scaling``. ``n_layers`` keeps the first layers only. The
+    dense members of the family only: one with routed experts
+    (``num_local_experts`` > 0) adds a sparse layer beside ``shared_mlp``
+    that is not built here."""
+    from ..models.transformer import TransformerConfig
+
+    if hc.get("num_local_experts", 0) > 0:
+        raise NotImplementedError(
+            f"granitemoehybrid num_local_experts={hc['num_local_experts']} "
+            "not supported: routed experts beside the Mamba layers' shared "
+            "feed-forward are not built (the dense members of the family "
+            "only)")
+    for key in ("attention_bias", "mamba_proj_bias"):
+        if hc.get(key):
+            raise NotImplementedError(f"granitemoehybrid {key}=true not "
+                                      "supported")
+    if not hc.get("mamba_conv_bias", True):
+        raise NotImplementedError("granitemoehybrid mamba_conv_bias=false "
+                                  "not supported")
+    if hc.get("normalization_function", "rmsnorm") != "rmsnorm" \
+            or hc.get("hidden_act", "silu") != "silu":
+        raise NotImplementedError("granitemoehybrid: rmsnorm and silu only")
+    n = int(n_layers or hc["num_hidden_layers"])
+    rope = hc.get("position_embedding_type", "nope") == "rope"
+    if rope and hc.get("rope_scaling"):
+        raise NotImplementedError(
+            f"granitemoehybrid rope_scaling={hc['rope_scaling']} not "
+            "supported (plain RoPE only)")
+    kinds = {"mamba": "mamba", "attention": "full"}
+    types = hc.get("layer_types") or hc["layers_block_type"]
+    return TransformerConfig(
+        vocab_size=hc["vocab_size"], d_model=hc["hidden_size"], n_layers=n,
+        n_heads=hc["num_attention_heads"],
+        n_kv_heads=hc.get("num_key_value_heads", hc["num_attention_heads"]),
+        d_ff=hc["shared_intermediate_size"],
+        max_seq_len=hc.get("max_position_embeddings", 2048),
+        norm="rms", activation="silu_glu",
+        position="rope" if rope else "none",
+        rope_theta=float(hc.get("rope_theta", 10000.0)),
+        tie_embeddings=hc.get("tie_word_embeddings", True), use_bias=False,
+        norm_eps=hc.get("rms_norm_eps", 1e-5),
+        attn_scale=float(hc["attention_multiplier"]),
+        embedding_multiplier=float(hc.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hc.get("residual_multiplier", 1.0)),
+        logits_scaling=float(hc.get("logits_scaling", 1.0)),
+        layer_types=tuple(kinds[k] for k in types[:n]),
+        # the mixer's width is heads x head size (mamba_expand x hidden in
+        # the published members)
+        mamba_n_heads=hc["mamba_n_heads"], mamba_d_head=hc["mamba_d_head"],
+        mamba_d_state=hc["mamba_d_state"],
+        mamba_n_groups=hc.get("mamba_n_groups", 1),
+        mamba_d_conv=hc.get("mamba_d_conv", 4),
+        mamba_chunk=hc.get("mamba_chunk_size", 256))
+
+
 def ouro_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
     """``model_type: ouro`` (LoopLM) -> TransformerConfig: Llama-shaped
     attention and SwiGLU in sandwich-norm blocks (a norm before and after
@@ -202,6 +264,8 @@ def hf_config(model_dir: str):
         return family, olmo_hybrid_config(hc)
     if family == "ouro":
         return family, ouro_config(hc)
+    if family == "granitemoehybrid":
+        return family, granite_hybrid_config(hc)
     if family in ("llama", "mistral"):
         # loud failure beats silently-wrong logits for unsupported variants
         if hc.get("rope_scaling"):
@@ -501,7 +565,8 @@ def hf_config(model_dir: str):
         raise ValueError(f"unsupported HF model_type '{family}' "
                          f"(supported: llama, mistral, gpt2, opt, bloom, "
                          f"gptj, gpt_neo, gpt_neox, falcon, mixtral, bert, "
-                         f"distilbert, clip, qwen2, olmo_hybrid, ouro)")
+                         f"distilbert, clip, qwen2, olmo_hybrid, ouro, "
+                         f"granitemoehybrid)")
     return family, cfg
 
 
@@ -614,6 +679,54 @@ def _map_olmo_hybrid(state, c) -> Dict[str, Any]:
             "A_log": stack(A + "A_log", lin),
             "dt_bias": stack(A + "dt_bias", lin),
             "o_norm_w": stack(A + "o_norm.weight", lin)}
+    params = {"tok_embed": state[pre + "embed_tokens.weight"],
+              "layers": layers, "final_norm_w": state[pre + "norm.weight"]}
+    if not c.tie_embeddings:
+        params["lm_head"] = state["lm_head.weight"].T
+    return params
+
+
+def _map_granite_hybrid(state, c) -> Dict[str, Any]:
+    """``GraniteMoeHybridForCausalLM``'s names: the two block norms and
+    ``shared_mlp`` (``input_linear`` holds the gate's rows, then the up
+    projection's) in every layer, ``self_attn`` in the attention layers,
+    and ``mamba`` in the others: ``in_proj`` (z | x B C | dt), the
+    depthwise ``conv1d`` ([channels, 1, K] -> ``conv_w`` [K, channels]) and
+    its bias, ``dt_bias``, ``A_log``, ``D``, the gated ``norm``,
+    ``out_proj``. The head is the embedding (tied) unless the checkpoint
+    says otherwise."""
+    pre = "model." if "model.embed_tokens.weight" in state else ""
+    L = pre + "layers.{}."
+
+    def stack(fmt, layers, transpose=False):
+        arrs = [state.pop((L + fmt).format(i)) for i in layers]
+        return np.stack([a.T if transpose else a for a in arrs])
+
+    every = range(c.n_layers)
+    w_in = stack("shared_mlp.input_linear.weight", every, True)  # [n, d, 2 ff]
+    layers: Dict[str, Any] = {
+        "attn_norm_w": stack("input_layernorm.weight", every),
+        "mlp_norm_w": stack("post_attention_layernorm.weight", every),
+        "w_gate": w_in[..., :c.d_ff], "w_up": w_in[..., c.d_ff:],
+        "w_down": stack("shared_mlp.output_linear.weight", every, True),
+    }
+    full, mamba = c.layers_of("full"), c.layers_of("mamba")
+    if full:
+        layers["full"] = {"w" + x: stack(f"self_attn.{x}_proj.weight", full,
+                                         True) for x in "qkvo"}
+    if mamba:
+        M = "mamba."
+        layers["mamba"] = {
+            "w_in": stack(M + "in_proj.weight", mamba, True),
+            # [n, channels, 1, K] -> [n, K, channels]
+            "conv_w": np.transpose(stack(M + "conv1d.weight", mamba)[:, :, 0],
+                                   (0, 2, 1)),
+            "conv_b": stack(M + "conv1d.bias", mamba),
+            "dt_bias": stack(M + "dt_bias", mamba),
+            "A_log": stack(M + "A_log", mamba),
+            "D": stack(M + "D", mamba),
+            "ssm_norm_w": stack(M + "norm.weight", mamba),
+            "w_out": stack(M + "out_proj.weight", mamba, True)}
     params = {"tok_embed": state[pre + "embed_tokens.weight"],
               "layers": layers, "final_norm_w": state[pre + "norm.weight"]}
     if not c.tie_embeddings:
@@ -1084,6 +1197,7 @@ _MAPPERS: Dict[str, Callable] = {
     "falcon": _map_falcon, "mixtral": _map_mixtral,
     "bert": _map_bert, "distilbert": _map_distilbert,
     "clip": _map_clip, "olmo_hybrid": _map_olmo_hybrid, "ouro": _map_ouro,
+    "granitemoehybrid": _map_granite_hybrid,
 }
 
 

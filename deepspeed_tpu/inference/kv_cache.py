@@ -363,11 +363,12 @@ KV_BITS = {"none": 0, "int8": 8, "int4": 4}
 #: by slot
 PAGED = ("k", "v", "k_scale", "v_scale")
 #: the fields a kind of layer owns a leaf of (those its model has)
-OWNS = {"full": PAGED, "linear": ("state", "conv_rows")}
+OWNS = {"full": PAGED, "linear": ("state", "conv_rows"),
+        "mamba": ("state", "conv_rows")}
 
 #: what a model with recurrent layers refuses, and why, in one place
 _NO_SNAPSHOT = (
-    "{what} needs a snapshot of the recurrent state: a linear layer's state "
+    "{what} needs a snapshot of the recurrent state: a recurrent layer's state "
     "cannot be rewound to, or rebuilt from, a token position the way KV "
     "pages can, and the engine keeps no state snapshot yet")
 
@@ -405,8 +406,9 @@ class KVPool(NamedTuple):
     [P+1, hkv, bs]. The sink page's zeros dequantize to zeros, so
     masked-lane scatters stay harmless exactly as in the fp layout.
 
-    ``state`` / ``conv_rows``: the second kind of cache, for each linear
-    layer a float32 state leaf [max_seqs + 1, H, dk, dv] and the
+    ``state`` / ``conv_rows``: the second kind of cache, for each recurrent
+    layer a float32 state leaf [max_seqs + 1, ...] (the gated delta rule's
+    [H, dk, dv], Mamba-2's [H, P, N]: :func:`state_shapes`) and the
     convolution's last inputs [max_seqs + 1, K - 1, channels], keyed by
     SLOT (the last one the sink of lanes that are not live, as the scratch
     page is for KV). Nothing zeroes a slot: the step starts a run at
@@ -429,7 +431,9 @@ class Leaves(NamedTuple):
     stack axis 0 holds ``passes`` such runs end to end: pass ``t`` reads
     and writes page ``p`` at ``p + t * (shape[0] // passes)``, its own sink
     at the end of its run, so one page id is ``passes`` physical pages a
-    leaf."""
+    leaf. A rolled stack's periods (:func:`cache_periods`) are laid out
+    the same way, pages and slots alike: a leaf then serves the layer at
+    its place in every period."""
 
     n: int
     shape: Tuple[int, ...]
@@ -446,18 +450,57 @@ class Leaves(NamedTuple):
 
 def _layers_of(model_config, kind: str) -> Tuple[int, ...]:
     """Indices of the layers that hold KV pages ("full") or a recurrent
-    state ("linear"). The sizing functions take any object with the KV
-    geometry (n_layers, n_kv_heads, head_dim): one without ``layers_of``
-    is all full."""
+    state ("linear", "mamba"). The sizing functions take any object with
+    the KV geometry (n_layers, n_kv_heads, head_dim): one without
+    ``layers_of`` is all full."""
     if hasattr(model_config, "layers_of"):
         return model_config.layers_of(kind)
     return tuple(range(model_config.n_layers)) if kind == "full" else ()
+
+
+def _leaves_of(model_config, layers: Tuple[int, ...]) -> int:
+    """Leaves the pool keeps for ``layers``: one a layer, or under a rolled
+    stack one for each of them in a period."""
+    return len(layers) // cache_periods(model_config)
+
+
+def state_layers(model_config) -> Tuple[int, ...]:
+    """Indices of the layers that hold a recurrent state, whatever their
+    kind (``TransformerConfig.state_layers``; none for an object that only
+    has the KV geometry)."""
+    return tuple(getattr(model_config, "state_layers", ()))
+
+
+def cache_periods(model_config) -> int:
+    """Periods of a rolled hybrid stack (``TransformerConfig.layer_period``:
+    mamba layers whose ``layer_types`` repeat): the cache keeps
+    one leaf for each layer of ONE period and lays the periods' runs of
+    pages or slots end to end in it, as a looped stack's passes; 1 for any
+    other model."""
+    period = getattr(model_config, "layer_period", model_config.n_layers)
+    return model_config.n_layers // period
+
+
+def state_shapes(model_config) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """Per sequence and recurrent layer: (the float32 state's shape, the
+    convolution rows' [K - 1, channels]), from the layer's kind."""
+    if _layers_of(model_config, "mamba"):
+        from ..ops import mamba2 as mixer
+    else:
+        from ..ops import gated_delta as mixer
+    return mixer.state_shapes(model_config)
 
 
 def cache_passes(model_config) -> int:
     """Cache layers a weight layer has: the passes of a looped stack, each
     with K/V of its own; 1 for any other model."""
     return int(getattr(model_config, "total_ut_steps", 1))
+
+
+def cache_runs(model_config) -> int:
+    """Runs of pages (or slots) end to end in a leaf's axis 0: a looped
+    stack's passes, or a rolled stack's periods."""
+    return cache_passes(model_config) * cache_periods(model_config)
 
 
 def cache_layers(model_config) -> int:
@@ -472,8 +515,10 @@ def pool_leaves(model_config, ragged_config) -> KVPool:
     allocates from it and the byte arithmetic counts from it."""
     c, cfg = model_config, ragged_config
     bits = KV_BITS[cfg.kv_quant]
-    full, linear = (len(_layers_of(c, kind)) for kind in ("full", "linear"))
-    passes = cache_passes(c)
+    full = _leaves_of(c, _layers_of(c, "full"))
+    recurrent = _leaves_of(c, state_layers(c))
+    periods = cache_periods(c)
+    passes = cache_passes(c) * periods
     rows = (passes * (cfg.n_kv_blocks + 1), c.n_kv_heads, cfg.kv_block_size)
     payload = Leaves(
         full, rows + (c.head_dim // 2 if bits == 4 else c.head_dim,),
@@ -481,15 +526,13 @@ def pool_leaves(model_config, ragged_config) -> KVPool:
         PartitionSpec(None, "model", None, None), passes)
     scale = Leaves(full if bits else 0, rows, jnp.float32,
                    PartitionSpec(None, "model", None), passes)
-    state = conv = ()
-    if linear:
-        from ..ops.gated_delta import state_shapes
-
-        state, conv = state_shapes(c)
-    slots = (cfg.max_seqs + 1,)
+    state, conv = state_shapes(c) if recurrent else ((), ())
+    slots = (periods * (cfg.max_seqs + 1),)
     return KVPool(k=payload, v=payload, k_scale=scale, v_scale=scale,
-                  state=Leaves(linear, slots + state, jnp.float32),
-                  conv_rows=Leaves(linear, slots + conv, cfg.dtype))
+                  state=Leaves(recurrent, slots + state, jnp.float32,
+                               passes=periods),
+                  conv_rows=Leaves(recurrent, slots + conv, cfg.dtype,
+                                   passes=periods))
 
 
 def new_pool(model_config, ragged_config, topology=None) -> KVPool:
@@ -519,12 +562,18 @@ def kv_page_bytes(model_config, ragged_config) -> int:
 
 
 def state_pool_bytes(model_config, ragged_config) -> int:
-    """Bytes of the recurrent-state pool: for every linear layer and every
-    slot (and the sink), the float32 state and the convolution's rows. A
-    fixed cost of ``max_seqs``, whatever the contexts' lengths."""
+    """Bytes of the recurrent-state pool: for every recurrent layer and
+    every slot (and the sink), the float32 state and the convolution's
+    rows. A fixed cost of ``max_seqs``, whatever the contexts' lengths."""
+    return (ragged_config.max_seqs + 1) * state_slot_bytes(model_config,
+                                                           ragged_config)
+
+
+def state_slot_bytes(model_config, ragged_config) -> int:
+    """Bytes one sequence's slot takes across the recurrent layers: what a
+    live sequence costs beside its pages."""
     kinds = pool_leaves(model_config, ragged_config)
-    return sum(kind.shape[0] * kind.unit_bytes
-               for kind in (kinds.state, kinds.conv_rows))
+    return kinds.state.unit_bytes + kinds.conv_rows.unit_bytes
 
 
 def kv_blocks_for_bytes(budget_bytes: int, model_config,
@@ -542,7 +591,7 @@ def refuse_without_snapshot(model_config, what: str) -> None:
     sequence to, or rebuilds it at, a token position (prefix adoption,
     trim, speculative verification, KV export / import, the KV tier).
     Loud, as ALiBi fails at construction."""
-    if _layers_of(model_config, "linear"):
+    if state_layers(model_config):
         raise NotImplementedError(_NO_SNAPSHOT.format(what=what))
 
 
@@ -579,7 +628,7 @@ class PageMoves:
     does and nothing else."""
 
     def __init__(self, model_config):
-        self.passes = cache_passes(model_config)
+        self.passes = cache_runs(model_config)
 
     def _physical(self, pool: KVPool, ids) -> np.ndarray:
         """[passes, len(ids)]: each pass's page of every id."""

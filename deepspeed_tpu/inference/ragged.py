@@ -179,16 +179,16 @@ class RaggedInferenceEngine:
             raise NotImplementedError(
                 "RaggedInferenceEngine does not support ALiBi or parallel-"
                 "residual families yet; use InferenceEngine (dense KV cache)")
-        if getattr(c, "attn_scale", None) is not None:
-            raise NotImplementedError(
-                "RaggedInferenceEngine does not support attention-scale "
-                "overrides (GPT-Neo); use InferenceEngine (dense KV cache)")
-        # layers that hold a recurrent state (ops/gated_delta.py)
-        self._state_layers = c.layers_of("linear")
+        # layers that hold a recurrent state (ops/gated_delta.py,
+        # ops/mamba2.py), whatever their kind
+        self._state_layers = kv_cache.state_layers(c)
         # whole-page moves (export, import, copy-on-write); a looped
         # stack's passes each have K/V of their own under one page id
         self._pages = kv_cache.PageMoves(c)
-        self._passes = self._pages.passes
+        self._passes = kv_cache.cache_passes(c)
+        # a rolled hybrid stack: the step loops over its periods, and a
+        # leaf of the pool serves the layer at its place in every period
+        self._periods = kv_cache.cache_periods(c)
         # layers of K/V the cache holds for a token (an export's n_layers)
         self._kv_layers = kv_cache.cache_layers(c)
         if self.config.enable_prefix_cache:
@@ -285,9 +285,16 @@ class RaggedInferenceEngine:
         self.kv_pool = kv_cache.new_pool(c, cfg, topology)
         self.kv_bytes_per_token = \
             kv_cache.kv_page_bytes(c, cfg) // cfg.kv_block_size
+        # what a live sequence holds beside its pages: its slot of every
+        # recurrent layer's state and convolution rows
+        self.state_bytes_per_slot = kv_cache.state_slot_bytes(c, cfg)
         if self._telemetry.enabled:
             self._telemetry.registry.gauge(
                 "inference/kv_bytes_per_token").set(self.kv_bytes_per_token)
+            if self._state_layers:
+                self._telemetry.registry.gauge(
+                    "inference/state_bytes_per_slot").set(
+                        self.state_bytes_per_slot)
         self._rows_buf: Optional[np.ndarray] = None    # _rows_out
         self._token_ids = False     # return_token_ids
         self._step_fn = None
@@ -332,7 +339,7 @@ class RaggedInferenceEngine:
         log_dist(f"RaggedInferenceEngine: budget={cfg.token_budget} "
                  f"blocks={cfg.n_kv_blocks}x{cfg.kv_block_size} "
                  f"expert_bytes_in_place={self.expert_bytes_in_place} "
-                 f"passes={self._passes} "
+                 f"passes={self._passes} periods={self._periods} "
                  f"kv_bytes_per_token={self.kv_bytes_per_token}")
 
     @property
@@ -349,6 +356,14 @@ class RaggedInferenceEngine:
         return (self.attention_path != "gather" and self._tp_size == 1
                 and not self._kv_bits
                 and self.model.config.head_dim % LANES == 0)
+
+    @property
+    def _steps_live_slots(self) -> bool:
+        """Whether a recurrent layer's one-token step is a kernel over the
+        slots that decode (the delta rule's, on the kernel paths) or XLA's
+        form over every slot (off the TPU, and Mamba-2's everywhere)."""
+        return bool(self.model.config.layers_of("linear")) \
+            and self.attention_path != "gather"
 
     @property
     def _telemetry(self):
@@ -1080,10 +1095,11 @@ class RaggedInferenceEngine:
         hold pages, times the passes); and what the row writer
         (``write_kv_pages``) serves in each of those layers: its live
         tiles and the page slabs it moves (0 where a scatter writes the
-        rows). With recurrent layers: the slots whose state is live, and
-        the entries of one lane (a decode token, or a prompt's last) that
-        the delta-rule step kernel serves in each such layer
-        (``step_slots``; 0 where the step runs in XLA over every slot)."""
+        rows). With recurrent layers: how many there are
+        (``state_layers``), the slots whose state is live, and the entries
+        of one lane (a decode token, or a prompt's last) that the step
+        kernel serves in each such layer (``step_slots``; 0 where the step
+        runs in XLA over every slot: ``_steps_live_slots``)."""
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
         prefill = decode = single = 0
@@ -1105,9 +1121,9 @@ class RaggedInferenceEngine:
                  "write_tiles": q_tiles if self._writes_pages else 0,
                  "write_pages": pages if self._writes_pages else 0}
         if self._state_layers:
+            attrs["state_layers"] = len(self._state_layers)
             attrs["state_slots"] = len(self.seqs)
-            attrs["step_slots"] = \
-                single if self.attention_path != "gather" else 0
+            attrs["step_slots"] = single if self._steps_live_slots else 0
         return attrs
 
     def put_spec(self, uids: Sequence[int], tokens: Sequence[Sequence[int]],
@@ -1726,7 +1742,8 @@ class RaggedInferenceEngine:
                 quant = dict(k_scale=sc[0], v_scale=sc[1],
                              kv_bits=kv_bits) if sc else {}
                 return paged_attention(q, kp, vp, tb, pos, seq_slots=sl,
-                                       work=wk, live_pages=live_pages,
+                                       work=wk, scale=c.attn_scale,
+                                       live_pages=live_pages,
                                        window=window, interpret=interp,
                                        **quant)
 
@@ -1759,9 +1776,14 @@ class RaggedInferenceEngine:
             work = work_list(slots, positions, cfg.max_seqs) \
                 if use_pallas else None
             if state_layers:
-                from ..ops import gated_delta
+                from ..ops import gated_delta, mamba2
 
-                runs = gated_delta.runs_of(slots, positions, cfg.max_seqs)
+                # one schedule of runs for every recurrent layer, cut into
+                # the pieces its kind's chunked form takes
+                runs = gated_delta.runs_of(
+                    slots, positions, cfg.max_seqs,
+                    mamba2.piece_lanes(c, tokens.shape[0])
+                    if c.layers_of("mamba") else gated_delta.CHUNK)
 
             def after_mixer(x, attn, lp):
                 """Residual wiring and the feed-forward: the model's own
@@ -1773,13 +1795,24 @@ class RaggedInferenceEngine:
             # the step's mixers, one a kind of layer: each takes its
             # layer's leaves of the pool by name and returns them written
 
-            def linear_block(x, lp, own):
+            def linear_block(x, lp, own, base=None):
+                assert base is None   # layer_period: never rolled
                 with jax.named_scope("linear_attn"):
                     attn, state, rows = gated_delta.mix_ragged(
                         x, lp, c, own["state"], own["conv_rows"], runs,
                         self.attention_path)
                 return after_mixer(x, attn, lp), \
                     {"state": state, "conv_rows": rows}
+
+            def mamba_block(x, lp, own, base=None):
+                with jax.named_scope("ssm"):
+                    attn, state, rows = mamba2.mix_ragged(
+                        model._mixer_input(x, lp), lp, c, own["state"],
+                        own["conv_rows"], runs, base)
+                return after_mixer(x, attn, lp), \
+                    {"state": state, "conv_rows": rows}
+
+            recurrent = {"linear": linear_block, "mamba": mamba_block}
 
             def write_pages(own, kk, vv, block_tables, sink):
                 """This layer's leaves with the step's new rows in them;
@@ -1841,38 +1874,63 @@ class RaggedInferenceEngine:
                             attn = paged_attention(
                                 q, own["k"], own["v"], block_tables,
                                 positions, seq_slots=slots, work=work,
-                                live_pages=live_pages, window=window,
-                                interpret=interp, **quant)
+                                scale=c.attn_scale, live_pages=live_pages,
+                                window=window, interpret=interp, **quant)
                         else:
                             attn = paged_attention_reference(
                                 q, own["k"], own["v"], tables, positions,
-                                window=window, **quant)
+                                scale=c.attn_scale, window=window, **quant)
                     attn = model._attn_out(attn.astype(x.dtype), lp)
                 return after_mixer(x, attn, lp), own
 
-            def stack(x, leaves, at):
-                """The stack's blocks once, each on its layer's leaves of
-                the pool; python-unrolled over depth (two kinds of layer
-                have two shapes of leaves, and a scanned depth would want
-                the pool stacked: KVPool's docstring). A leaf is indexed
-                by its layer's place among the layers of its kind."""
+            def stack(x, leaves, at, period=None):
+                """The stack's blocks once (one period's, under a rolled
+                stack), each on its layer's leaves of the pool;
+                python-unrolled over depth (two kinds of layer have two
+                shapes of leaves, and a scanned depth would want the pool
+                stacked: KVPool's docstring). A leaf is indexed by its
+                layer's place among the layers of its kind; in period
+                ``period`` (traced) a layer takes its weights by that
+                index and its slots ``period`` runs into its leaf (its
+                pages likewise, through ``at``)."""
                 leaves = {f: list(ls) for f, ls in leaves.items()}
-                for li in range(c.n_layers):
+                base = None if period is None else \
+                    period * (cfg.max_seqs + 1)
+                for li in range(c.layer_period):
                     with jax.named_scope("weights"):
                         kind, lp = model.layer_params(
-                            params["layers"], li, self._experts_in_place)
+                            params["layers"], li,
+                            self._experts_in_place and period is None, period)
                     i = c.layers_of(kind).index(li)
                     own = {f: leaves[f][i] for f in kv_cache.OWNS[kind]
                            if leaves[f]}
-                    if kind == "linear":
-                        x, own = linear_block(x, lp, own)
-                    else:
+                    if kind == "full":
                         x, own = block(x, lp, own, windows[li], at)
+                    else:
+                        x, own = recurrent[kind](x, lp, own, base)
                     for f, leaf in own.items():
                         leaves[f][i] = leaf
                 return x, {f: tuple(ls) for f, ls in leaves.items()}
 
             leaves = pools._asdict()
+            stride = cfg.n_kv_blocks + 1
+            if self._periods > 1:
+                # a rolled hybrid stack: ONE loop over the periods carries
+                # x and the pool's leaves (40 unrolled layers of
+                # granite-4.0-h-micro were 955 s of a cold warm-up's 24
+                # programs and 180 s of a warm one's tracing, PERF.md
+                # section 6). Period t reads and writes page p at
+                # p + t * stride and slot s at s + t * (max_seqs + 1)
+                def one_period(t, carry):
+                    off = t * stride
+                    return stack(*carry, (
+                        block_tables + off,
+                        None if tables is None else tables + off,
+                        cfg.n_kv_blocks + off), t)
+
+                x, leaves = jax.lax.fori_loop(0, self._periods, one_period,
+                                              (x, leaves))
+                return x, kv_cache.KVPool(**leaves)
             if passes == 1:
                 x, leaves = stack(x, leaves,
                                   (block_tables, tables, cfg.n_kv_blocks))
@@ -1887,8 +1945,6 @@ class RaggedInferenceEngine:
             # at p + t * stride (kv_cache.Leaves), its own sink too: the
             # tables, the kernel's work list, the allocator's ids and the
             # prefix hashes are one pass's, as for any other model
-            stride = cfg.n_kv_blocks + 1
-
             def one_pass(t, carry):
                 x, leaves, leaving = carry
                 off = t * stride

@@ -94,11 +94,13 @@ class TransformerConfig:
     # InternLM: attention projections carry biases (incl. o_proj) while the
     # gated MLP does not — reference module_inject/containers/internlm.py:20
     attn_o_bias: Optional[bool] = None  # None -> follow use_bias
-    # Hybrid stacks (Olmo-Hybrid): a kind per layer, "full" (softmax
-    # attention) or "linear" (gated delta rule, ops/gated_delta.py) with the
-    # linear_* sizes below; None = every layer full. Two kinds of layer sit
-    # in the parameter tree as two stacks beside the common one
-    # (Transformer.init), and the depth loop is unrolled.
+    # Hybrid stacks (Olmo-Hybrid, Granite-4.0-H): a kind per layer, "full"
+    # (softmax attention), "linear" (gated delta rule, ops/gated_delta.py,
+    # with the linear_* sizes below) or "mamba" (Mamba-2 state space,
+    # ops/mamba2.py, with the mamba_* sizes); None = every layer full. The
+    # kinds of layer sit in the parameter tree as a stack each beside the
+    # common one (Transformer.init), and the depth loop is unrolled. A
+    # model has one recurrent kind at most: the state pool holds one shape.
     layer_types: Optional[Tuple[str, ...]] = None
     linear_n_k_heads: int = 0
     linear_n_v_heads: int = 0
@@ -106,6 +108,20 @@ class TransformerConfig:
     linear_v_dim: int = 0
     linear_conv_kernel: int = 4
     linear_neg_eigval: bool = False   # beta in (0, 2): negative eigenvalues
+    mamba_n_heads: int = 0            # H heads of mamba_d_head channels
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0            # N state channels a group
+    mamba_n_groups: int = 1           # B and C are shared by H / G heads
+    mamba_d_conv: int = 4
+    mamba_chunk: int = 256            # tokens a piece of the SSD form covers
+    # Granite's scalar multipliers (1 = none): the embedding's output times
+    # embedding_multiplier, each branch's output times residual_multiplier
+    # before it joins the residual stream (pre-norm wiring only), the
+    # logits divided by logits_scaling; its attention_multiplier is
+    # attn_scale above
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # OLMo-2/3 wiring: x + norm(sub(x)), no norm before a branch (not
     # prenorm=False, which is norm(x + sub(x))); the final norm stays
     branch_norm: bool = False
@@ -148,7 +164,14 @@ class TransformerConfig:
             assert len(self.layer_types) == self.n_layers, (
                 f"layer_types has {len(self.layer_types)} entries for "
                 f"{self.n_layers} layers")
-            assert set(self.layer_types) <= {"full", "linear"}, self.layer_types
+            assert set(self.layer_types) <= {"full", "linear", "mamba"}, \
+                self.layer_types
+            if "mamba" in self.layer_types:
+                assert "linear" not in self.layer_types, \
+                    "one recurrent kind a model: linear or mamba"
+                assert self.mamba_n_heads and self.mamba_d_head \
+                    and self.mamba_d_state, "mamba layers need mamba_* sizes"
+                assert self.mamba_n_heads % self.mamba_n_groups == 0
             if "linear" in self.layer_types:
                 assert self.linear_n_k_heads and self.linear_k_dim \
                     and self.linear_v_dim, "linear layers need linear_* sizes"
@@ -158,12 +181,17 @@ class TransformerConfig:
         if self.total_ut_steps < 1:
             raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
         if self.total_ut_steps > 1 and (
-                self.layers_of("linear") or not self.prenorm
-                or not self.causal):
+                self.state_layers or not self.prenorm or not self.causal):
             raise ValueError(
                 "a looped stack (total_ut_steps > 1) needs a causal pre-norm "
                 "model of softmax-attention layers: the final norm closes "
                 "every pass, and a recurrent state a pass is not kept")
+        if self.residual_multiplier != 1.0 and (
+                self.branch_norm or self.sandwich_norm or not self.prenorm
+                or self.parallel_residual):
+            raise ValueError("residual_multiplier is plain pre-norm wiring: "
+                             "not with branch_norm, sandwich_norm, post-LN "
+                             "or parallel_residual")
         if self.sandwich_norm and (self.branch_norm or not self.prenorm
                                    or self.parallel_residual):
             raise ValueError("sandwich_norm is pre-norm wiring with a second "
@@ -188,9 +216,37 @@ class TransformerConfig:
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         """Indices of the layers of that kind ("full" holds the KV pages,
-        "linear" a recurrent state), in depth order."""
+        "linear" and "mamba" a recurrent state), in depth order."""
         kinds = self.layer_types or ("full",) * self.n_layers
         return tuple(i for i, k in enumerate(kinds) if k == kind)
+
+    @property
+    def layer_period(self) -> int:
+        """Layers in one period of the stack as it is SERVED. A hybrid
+        stack with mamba layers whose layer_types repeat (Granite-4.0-H:
+        five mamba, one attention, four mamba layers, four times over) is
+        one rolled loop over its periods: RaggedInferenceEngine's step
+        traces and compiles one period's layers, takes each layer's
+        weights out of their stacks by the period's index, and the cache
+        keeps one leaf for each layer of a period, the periods' runs end
+        to end in it (inference/kv_cache.py). This is the smallest p that
+        layer_types repeats with; n_layers (one period, nothing rolled)
+        for every other model: no layer_types, per-layer windows (static
+        a layer), or the delta rule's layers, whose step kernel names
+        slots and not a period's run of them. Transformer.apply stays
+        unrolled."""
+        n, kinds = self.n_layers, self.layer_types
+        if kinds is None or "mamba" not in kinds \
+                or self.attn_windows is not None:
+            return n
+        return next(p for p in range(1, n + 1)
+                    if n % p == 0 and kinds == kinds[:p] * (n // p))
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """Indices of the layers that hold a recurrent state a sequence
+        (one kind a model, so one kind's list)."""
+        return self.layers_of("linear") or self.layers_of("mamba")
 
     @property
     def rotary_dim(self) -> int:
@@ -216,6 +272,13 @@ class TransformerConfig:
             mixers += n_lin * (
                 d * ch + self.linear_conv_kernel * ch + 2 * d * hv + 2 * hv
                 + 2 * d * hv * self.linear_v_dim + self.linear_v_dim)
+        n_mamba = len(self.layers_of("mamba"))
+        if n_mamba:  # Mamba-2's leaves (_init_mamba)
+            di = self.mamba_n_heads * self.mamba_d_head
+            ch = di + 2 * self.mamba_n_groups * self.mamba_d_state
+            mixers += n_mamba * (
+                d * (di + ch + self.mamba_n_heads) + (self.mamba_d_conv + 1) * ch
+                + 3 * self.mamba_n_heads + di + di * d)
         norms = (4 if self.sandwich_norm else 2) * d * n \
             + (d if self.prenorm else 0)
         if self.norm == "layer":
@@ -365,10 +428,14 @@ class Transformer:
         else:
             if nf:
                 layers["full"] = attn
-            nl = n - nf
+            nl = len(c.layers_of("linear"))
             if nl:
                 layers["linear"] = self._init_linear(
                     jax.random.fold_in(rng, 1), nl, dense, dtype)
+            nm = len(c.layers_of("mamba"))
+            if nm:
+                layers["mamba"] = self._init_mamba(
+                    jax.random.fold_in(rng, 2), nm, dense, dtype)
 
         params: Dict[str, Any] = {
             "tok_embed": dense(next(k), (c.vocab_size, c.d_model), scale=0.02),
@@ -429,12 +496,38 @@ class Transformer:
                         scale=1.0 / np.sqrt(d * 2 * c.n_layers)),
         }
 
+    def _init_mamba(self, rng, nm: int, dense, dtype) -> Dict[str, Any]:
+        """The Mamba-2 mixer's leaves (ops/mamba2.py), stacked over the
+        ``nm`` mamba layers, named after the published module's tensors
+        (``in_proj``, ``conv1d``, ``norm``, ``out_proj``). ``A_log``, ``D``
+        and ``dt_bias`` follow its initialisation: A = 1 .. H, D = 1, the
+        step softplus(dt_bias) log-uniform in (1e-3, 1e-1)."""
+        c = self.config
+        H, d = c.mamba_n_heads, c.d_model
+        di = H * c.mamba_d_head
+        ch = di + 2 * c.mamba_n_groups * c.mamba_d_state
+        k = iter(jax.random.split(rng, 4))
+        dt = jnp.exp(jax.random.uniform(next(k), (nm, H), jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        return {
+            "w_in": dense(next(k), (nm, d, di + ch + H)),   # z | x B C | dt
+            "conv_w": dense(next(k), (nm, c.mamba_d_conv, ch)),
+            "conv_b": jnp.zeros((nm, ch), dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, H + 1, dtype=jnp.float32)), (nm, H)).astype(dtype),
+            "D": jnp.ones((nm, H), dtype),
+            "ssm_norm_w": jnp.ones((nm, di), dtype),
+            "w_out": dense(next(k), (nm, di, d),
+                           scale=1.0 / np.sqrt(d * 2 * c.n_layers)),
+        }
+
     #: leaves of the common stack that are operands of an operation which
     #: indexes the stack itself (layer_params, ``in_place``)
     stacked_operands: Tuple[str, ...] = ()
 
-    def layer_params(self, layers, li: int, in_place: bool = False
-                     ) -> Tuple[str, Dict[str, Any]]:
+    def layer_params(self, layers, li: int, in_place: bool = False,
+                     period=None) -> Tuple[str, Dict[str, Any]]:
         """(kind, the leaves of layer ``li``) out of the stacked tree: the
         common stack at ``li`` and, with layer_types, its kind's stack at
         the layer's index among its kind. ``li`` is a python int, so the
@@ -447,19 +540,29 @@ class Transformer:
         whole, with ``lp["layer"] = li`` beside them, for the operation to
         index the stack itself. A caller passes ``in_place`` only where
         those leaves' leading two axes are unsharded (merging the layer
-        axis with a sharded expert axis is no bitcast)."""
+        axis with a sharded expert axis is no bitcast). Under a rolled
+        stack (``layer_period``) ``period`` is the period's index, traced,
+        and ``li`` the layer's place inside a period: the slices are then
+        dynamic, and a dense product reads them in place all the same."""
         c = self.config
         whole = self.stacked_operands if in_place else ()
+        assert period is None or not whole, "no rolled stack of experts"
+        take = (lambda a, i: a[i]) if period is None else (
+            lambda a, i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False))
+        p = c.layer_period
         lp = jax.tree_util.tree_map(
-            lambda a: a[li], {k: v for k, v in layers.items()
-                              if k not in whole + ("full", "linear")})
+            lambda a: take(a, li if period is None else period * p + li),
+            {k: v for k, v in layers.items()
+             if k not in whole + ("full", "linear", "mamba")})
         if whole:
             lp.update({k: layers[k] for k in whole if k in layers}, layer=li)
         if c.layer_types is None:
             return "full", lp
         kind = c.layer_types[li]
-        at = c.layers_of(kind).index(li)
-        lp.update({k: v[at] for k, v in layers[kind].items()})
+        at = c.layers_of(kind).index(li)     # its place among its kind
+        if period is not None:               # ... and the periods before it
+            at = period * sum(k == kind for k in c.layer_types[:p]) + at
+        lp.update({k: take(v, at) for k, v in layers[kind].items()})
         return kind, lp
 
     # ------------------------------------------------------------------
@@ -576,6 +679,14 @@ class Transformer:
             with jax.named_scope("linear_attn"):
                 attn = gated_delta.mix_dense(x, lp, c)
             return self._after_mixer(x, attn, None, lp, rng, training)
+        if kind == "mamba":
+            # a state-space layer: the norm before the branch is the
+            # block's, the mixer under the scope ssm (conv, ssd_chunk)
+            from ..ops import mamba2
+
+            with jax.named_scope("ssm"):
+                attn = mamba2.mix_dense(self._mixer_input(x, lp), lp, c)
+            return self._after_mixer(x, attn, None, lp, rng, training)
 
         # device scopes (jax.named_scope: metadata only) name the block's
         # parts in a profiler trace: attn (flash_attention around the
@@ -678,6 +789,14 @@ class Transformer:
 
         return self._after_mixer(x, attn, new_kv, lp, rng, training)
 
+    def _mixer_input(self, x, lp):
+        """What a mixer reads: pre-LN normalizes the branch input; post-LN
+        (BERT-era, prenorm=False) and branch_norm run the branch on x and
+        norm after it."""
+        c = self.config
+        return self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
+            if c.prenorm and not c.branch_norm else x
+
     def _qkv(self, x, lp, angles, positions):
         """The attention block from its input to (q, k, v) split by heads
         and rotated: x [..., s, d] -> q [..., s, h, hd], k and v [..., s,
@@ -688,10 +807,7 @@ class Transformer:
         then rotary."""
         c = self.config
         heads = lambda a, n: a.reshape(a.shape[:-1] + (n, c.head_dim))
-        # pre-LN normalizes the branch input; post-LN (BERT-era,
-        # prenorm=False) runs the branch on x and norms AFTER the residual
-        h = self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
-            if c.prenorm and not c.branch_norm else x
+        h = self._mixer_input(x, lp)
         q, kk, vv = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
         if c.qkv_bias:
             q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
@@ -750,11 +866,12 @@ class Transformer:
                 x = self._norm(x + down, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
             return x, new_kv, aux
 
-        x = x + attn
+        rm = c.residual_multiplier
+        x = x + (attn if rm == 1.0 else attn * rm)
         with jax.named_scope("ffn"):
             h = self._norm(x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
             down, aux = self._mlp(h, lp, rng, training)
-            return x + down, new_kv, aux
+            return x + (down if rm == 1.0 else down * rm), new_kv, aux
 
     def _mlp(self, h, lp, rng=None, training=False):
         """Dense FFN. Subclasses (MoE) override; returns (out, aux_loss)."""
@@ -1141,6 +1258,8 @@ class Transformer:
         else:
             x = params["tok_embed"][tokens]
         x = x.astype(compute_dtype)
+        if c.embedding_multiplier != 1.0:
+            x = x * c.embedding_multiplier
         if c.position == "learned":
             s = tokens.shape[-1]
             pos_emb = params["pos_embed"][:s] if positions is None else params["pos_embed"][positions]
@@ -1183,6 +1302,8 @@ class Transformer:
             logits = logits + params["mlm_bias"].astype(jnp.float32)
         if "lm_head_b" in params:  # GPT-J carries an LM-head bias
             logits = logits + params["lm_head_b"].astype(jnp.float32)
+        if c.logits_scaling != 1.0:
+            logits = logits / c.logits_scaling
         if c.logits_softcap > 0:
             logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
         return logits
@@ -1366,6 +1487,15 @@ class Transformer:
                     "w_beta": P(pipe, None, None), "A_log": rep,
                     "dt_bias": rep, "o_norm_w": rep,
                     "wo": P(pipe, "model", None)}
+            if c.layers_of("mamba"):
+                # replicated: in_proj's columns are z | x B C | dt side by
+                # side and the gated norm runs over all of y, so no one
+                # split of a leaf's columns follows the heads
+                rep2, rep3 = P(pipe, None), P(pipe, None, None)
+                layer_specs["mamba"] = {
+                    "w_in": rep3, "conv_w": rep3, "conv_b": rep2,
+                    "dt_bias": rep2, "A_log": rep2, "D": rep2,
+                    "ssm_norm_w": rep2, "w_out": rep3}
         specs: Dict[str, Any] = {
             "tok_embed": P("model", None),
             "layers": layer_specs,

@@ -219,7 +219,7 @@ class Runs(NamedTuple):
     n_pieces: Any    # [] pieces of the runs longer than one lane
     piece_slot: Any  # [P] each piece's slot
     piece_at: Any    # [P] its first lane
-    piece_n: Any     # [P] its live lanes (1..CHUNK)
+    piece_n: Any     # [P] its live lanes (1..chunk)
     piece_first: Any  # [P] it is its run's first piece
     steps: Any       # [4, N] the runs of one lane, live entries first: an
     #                  entry's slot, its lane, whether its run starts a
@@ -228,8 +228,10 @@ class Runs(NamedTuple):
     #                  again (the sink where none is live). N = min(S, T)
 
 
-def runs_of(slots, positions, n_slots: int) -> Runs:
-    """slots [T] (-1 = not live), positions [T] -> :class:`Runs`."""
+def runs_of(slots, positions, n_slots: int, chunk: int = CHUNK) -> Runs:
+    """slots [T] (-1 = not live), positions [T] -> :class:`Runs`, the runs
+    longer than one lane cut into pieces of ``chunk`` lanes (the delta
+    rule's ``CHUNK``; ``ops/mamba2.py`` asks for its own)."""
     T = slots.shape[0]
     lane = jnp.arange(T, dtype=jnp.int32)
     slot = jnp.where(slots >= 0, slots, n_slots).astype(jnp.int32)
@@ -242,16 +244,16 @@ def runs_of(slots, positions, n_slots: int) -> Runs:
     live = (first < T) & (jnp.arange(n_slots + 1) < n_slots)
     length = jnp.where(live, last - first + 1, 0)
     slot_fresh = positions[jnp.minimum(first, T - 1)] == 0
-    # pieces: every run longer than one lane, cut at multiples of CHUNK
-    per = jnp.where(length > 1, -(-length // CHUNK), 0)
+    # pieces: every run longer than one lane, cut at multiples of chunk
+    per = jnp.where(length > 1, -(-length // chunk), 0)
     ends = jnp.cumsum(per)
-    P = T // CHUNK + n_slots
+    P = T // chunk + n_slots
     p = jnp.arange(P, dtype=jnp.int32)
     piece_slot = jnp.minimum(jnp.searchsorted(ends, p, side="right"),
                              n_slots).astype(jnp.int32)
     nth = p - (ends - per)[piece_slot]
-    piece_at = first[piece_slot] + nth * CHUNK
-    piece_n = jnp.clip(length[piece_slot] - nth * CHUNK, 0, CHUNK)
+    piece_at = first[piece_slot] + nth * chunk
+    piece_n = jnp.clip(length[piece_slot] - nth * chunk, 0, chunk)
     # the single-lane runs, compacted in slot order (all compares at once,
     # as ``work_list``): what the step kernel's grid walks
     count = jnp.cumsum((length == 1).astype(jnp.int32))
@@ -267,24 +269,33 @@ def runs_of(slots, positions, n_slots: int) -> Runs:
                 piece_slot, piece_at, piece_n, nth == 0, steps)
 
 
-def conv_ragged(x, w, rows, runs: Runs):
+def conv_ragged(x, w, rows, runs: Runs, bias=None, base=None):
     """Causal depthwise convolution over lanes. x [T, ch] float32; w
     [K, ch]; rows [S + 1, K - 1, ch]: each slot's last K - 1 inputs, oldest
-    first. A lane nearer than a tap to its run's start reads the slot's
-    rows (zeros where the run starts a sequence). Returns (y [T, ch],
-    rows with every run's last K - 1 inputs left behind)."""
+    first; ``bias`` [ch] where the layer has one (Mamba-2's). A lane nearer
+    than a tap to its run's start reads the slot's rows (zeros where the
+    run starts a sequence). Under a rolled stack the leaf holds a run of
+    S + 1 slots a period and ``base`` (traced) is where this period's
+    starts. Returns (y [T, ch], rows with every run's last K - 1 inputs
+    left behind)."""
     K = w.shape[0]
+    slot = runs.slot if base is None else runs.slot + base
     taps = [x]
     for j in range(1, K):
-        kept = rows[runs.slot, jnp.clip(K - 1 - j + runs.off, 0, K - 2)]
+        kept = rows[slot, jnp.clip(K - 1 - j + runs.off, 0, K - 2)]
         kept = jnp.where(runs.fresh[:, None], 0.0, kept.astype(F32))
         taps.append(jnp.where((runs.off >= j)[:, None],
                               jnp.roll(x, j, axis=0), kept))
     y = sum(w[K - 1 - j] * taps[j] for j in range(K))
+    if bias is not None:
+        y = y + bias
     left = jnp.stack([taps[j][runs.last] for j in range(K - 2, -1, -1)], 1)
-    rows = jnp.where((runs.length > 0)[:, None, None],
-                     left.astype(rows.dtype), rows)
-    return y, rows
+    live = (runs.length > 0)[:, None, None]
+    if base is None:
+        return y, jnp.where(live, left.astype(rows.dtype), rows)
+    own = jax.lax.dynamic_slice_in_dim(rows, base, left.shape[0], 0)
+    return y, jax.lax.dynamic_update_slice_in_dim(
+        rows, jnp.where(live, left.astype(rows.dtype), own), base, 0)
 
 
 def delta_ragged(q, k, v, g, beta, state, runs: Runs, path: str = "gather"):

@@ -868,6 +868,51 @@ def test_ouro_step_is_one_loop_and_copies_no_leaf(one_chip, on_tpu):
     assert abs(smaller.memory_analysis().temp_size_in_bytes - temp) < 2e6
 
 
+def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
+                                                                 on_tpu):
+    """Rehearsal 3 for the cell ``granite-4.0-h-micro.assist``: the whole
+    model's step at the decode shape is ONE rolled loop over the four
+    periods of ten layers (unrolled, its 24 programs took 955 s of a cold
+    warm-up on the chip: PERF.md section 6) and fits one chip beside the
+    76 MB-a-sequence state pool and the 4,096 pages. One paged kernel call
+    in the text, the attention layer of a period, and at head size 64 it is
+    the grid over lanes with XLA's scatter in front of it, not the row
+    writer (``_writes_pages``). The pool has a leaf a layer of a period,
+    the periods' runs end to end in it, and nothing copies, transposes,
+    slices or selects a state leaf as an operation of its own, inside the
+    loop or outside it (the whole-run step and the chunk loop update the
+    leaf in place, through fused dynamic-update-slices). What is copied,
+    and what the cell pays until a tiled grid takes 64-wide slabs: the K
+    and the V leaf twice a tick, OUTSIDE the loop, from the layout the
+    compiler gives a parameter whose minor dimension is 64 (pages
+    minor-most) to the kernel's pinned row-major one and back (PERF.md,
+    section 7)."""
+    compiled, c, e = compile_cell_step("granite-4.0-h-micro", one_chip, 64,
+                                       128)
+    assert _device_bytes(compiled) < 15.75e9
+    hlo = compiled.as_text()
+    assert c.layer_period == 10 and len(c.layers_of("mamba")) == 36
+    assert _kernel_calls(hlo) == 1
+    assert not _writer_calls(hlo) and not _step_calls(hlo)
+    state = f"{4 * (e['max_seqs'] + 1)},64,64,128"
+    page = f"{4 * (e['max_kv_blocks'] + 1)},8,{e['kv_block_size']},64"
+    entry = list(_entry_instructions(hlo))
+    assert sum(op == "parameter" and (dt, dims) == ("f32", state)
+               for _, dt, dims, op, _ in entry) == 9
+    moved = re.findall(
+        r"= f32\[" + state + r"\]\S* (copy|transpose|slice|dynamic-slice|"
+        r"gather|concatenate|select)\(", hlo)
+    assert not moved, moved
+    copies = re.findall(r"= bf16\[" + page + r"\]\S* copy\(", hlo)
+    assert len(copies) <= 4, len(copies)
+    assert len(copies) == sum(op == "copy" and dims == page
+                              for _, _, dims, op, _ in entry)
+    # temporaries: the two K/V leaves' second copies, padded to 128 lanes,
+    # and the step's own; 1.11 GB by my described-chip compile, PR 43
+    # (2.08 GB unrolled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
 # (config, lanes, live pages, pages of the pool or 0 for the file's, KV
 # layers): the cells' decode steps at their files' depths. Mistral's
 # token_budget shape is held at two layers of the same widths
