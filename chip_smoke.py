@@ -43,8 +43,9 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # reference, relative to the reference's largest magnitude; and the ragged
 # engine's logits against model.apply's full forward (two bf16 programs)
 KERNEL_REL_TOL = 0.03
-# the delta-rule step is float32 throughout; only its sums' order differs
-DELTA_STEP_REL_TOL = 1e-5
+# a recurrent layer's step (the delta rule's, Mamba-2's) is float32
+# throughout; only its sums' order differs
+STEP_REL_TOL = 1e-5
 LOGITS_REL_TOL = 0.05
 # ZeRO-3 on four chips against ZeRO-0 on one: same math, different
 # reduction order in bf16 — per-step loss agreement
@@ -96,6 +97,10 @@ class Sizes:
     kernel_writer_heads: Tuple[int, ...] = (8, 16, 30)
     # the delta-rule step kernel's state: Olmo-Hybrid's (heads, key, value)
     kernel_delta_state: Tuple[int, int, int] = (30, 96, 192)
+    # the state-space step kernel's: granite-4.0-h-micro's (heads, channels
+    # a head, state channels, groups), on a leaf of four periods' runs
+    kernel_ssd_state: Tuple[int, int, int, int] = (64, 64, 128, 1)
+    kernel_ssd_periods: int = 4
     # --chips 4: global batch, split four ways under ZeRO-3
     zero3_layers: int = 1
     zero3_batch: int = 4
@@ -405,8 +410,39 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     idle = np.setdiff1d(np.arange(ns + 1), stepping)
     state_off = int(jnp.sum(got_s[idle] != dstate[idle]))
     errs.update(delta_errs)
+    # the state-space step kernel against ``ssd_step`` in XLA on the same
+    # rows and the same two slots in three, in the third period's run of a
+    # rolled leaf (``base`` an argument of the jitted call, so traced):
+    # every other row of the leaf, the other periods' too, keeps its bits
+    from deepspeed_tpu.ops import mamba2
+    from deepspeed_tpu.ops.pallas.mamba2 import ssd_step_slots
+
+    sh, sp, sn, sg = sz.kernel_ssd_state
+    periods = sz.kernel_ssd_periods
+    base = (periods - 2) * (ns + 1)
+    skeys = jax.random.split(jax.random.fold_in(kp, 3000), 6)
+    sdt = jax.nn.softplus(jax.random.normal(skeys[3], (nd, sh)))
+    srows = (jax.random.normal(skeys[0], (nd, sh, sp)),
+             jax.random.normal(skeys[1], (nd, sg, sn)),
+             jax.random.normal(skeys[2], (nd, sg, sn)), sdt,
+             -jnp.exp(0.3 * jax.random.normal(skeys[4], (sh,))) * sdt)
+    sD = jnp.linspace(0.5, 1.5, sh)
+    sstate = jax.random.normal(skeys[5], (periods * (ns + 1), sh, sp, sn))
+    got_y, got_ss = ssd_step_slots(*srows, sD, sstate, runs.steps,
+                                   jnp.int32(base), interpret=interpret)
+    per_head = lambda a: jnp.repeat(a, sh // sg, axis=1)
+    want_y, want_ss = jax.jit(mamba2.ssd_step)(
+        srows[0], per_head(srows[1]), per_head(srows[2]), *srows[3:], sD,
+        jnp.where(jnp.asarray(dpos == 0)[:, None, None, None], 0.0,
+                  sstate[base + stepping]))
+    ssd_errs = {"ssd_step_y": _rel_err(got_y, want_y),
+                "ssd_step_state": _rel_err(got_ss[base + stepping], want_ss)}
+    sidle = np.setdiff1d(np.arange(periods * (ns + 1)), base + stepping)
+    ssd_off = int(jnp.sum(got_ss[sidle] != sstate[sidle]))
+    errs.update(ssd_errs)
     rec.update(shape={"flash": [1, S, f"{hq}/{hkv}", hd],
                       "delta_state": [ns + 1, dh, dk, dv],
+                      "ssd_state": [periods * (ns + 1), sh, sp, sn],
                       "paged": {n: len(s[0]) for n, s in shapes.items()},
                       "paged_variants": {t or "bf16": list(v[:2])
                                          for t, v in variants.items()},
@@ -418,9 +454,14 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     _check(not any(rows_off.values()),
            f"write_kv_pages is not bit-equal to the scatter: {rows_off}")
     rec.update(state_unequal=state_off)
-    _check(max(delta_errs.values()) <= DELTA_STEP_REL_TOL,
+    _check(max(delta_errs.values()) <= STEP_REL_TOL,
            f"the delta-rule step kernel is off delta_step: {delta_errs}")
     _check(state_off == 0, f"the delta-rule step kernel changed {state_off} "
+           f"elements of slots that do not decode")
+    rec.update(ssd_state_unequal=ssd_off)
+    _check(max(ssd_errs.values()) <= STEP_REL_TOL,
+           f"the state-space step kernel is off ssd_step: {ssd_errs}")
+    _check(ssd_off == 0, f"the state-space step kernel changed {ssd_off} "
            f"elements of slots that do not decode")
 
 

@@ -360,10 +360,10 @@ class RaggedInferenceEngine:
     @property
     def _steps_live_slots(self) -> bool:
         """Whether a recurrent layer's one-token step is a kernel over the
-        slots that decode (the delta rule's, on the kernel paths) or XLA's
-        form over every slot (off the TPU, and Mamba-2's everywhere)."""
-        return bool(self.model.config.layers_of("linear")) \
-            and self.attention_path != "gather"
+        slots that decode (the delta rule's and Mamba-2's, each on the
+        kernel paths) or XLA's form over every slot (off the TPU: the
+        oracle of both)."""
+        return bool(self._state_layers) and self.attention_path != "gather"
 
     @property
     def _telemetry(self):
@@ -1098,8 +1098,8 @@ class RaggedInferenceEngine:
         rows). With recurrent layers: how many there are
         (``state_layers``), the slots whose state is live, and the entries
         of one lane (a decode token, or a prompt's last) that the step
-        kernel serves in each such layer (``step_slots``; 0 where the step
-        runs in XLA over every slot: ``_steps_live_slots``)."""
+        kernel of their kind serves in each (``step_slots``; 0 where the
+        step runs in XLA over every slot: ``_steps_live_slots``)."""
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
         prefill = decode = single = 0
@@ -1808,7 +1808,7 @@ class RaggedInferenceEngine:
                 with jax.named_scope("ssm"):
                     attn, state, rows = mamba2.mix_ragged(
                         model._mixer_input(x, lp), lp, c, own["state"],
-                        own["conv_rows"], runs, base)
+                        own["conv_rows"], runs, base, self.attention_path)
                 return after_mixer(x, attn, lp), \
                     {"state": state, "conv_rows": rows}
 

@@ -15,10 +15,10 @@ For token ``t``, input ``u_t`` (``K`` = ``mamba_d_conv``, ``H`` heads of
 The recurrence exists in three forms over the same mathematics:
 
 * :func:`ssd_step`: one token a row, batched, in XLA: elementwise products
-  and sums in float32. The ragged step runs it over the whole state leaf
-  (a slot without a run of one lane keeps its state); a Pallas call over
-  the slots that decode, as ``ops/pallas/gated_delta.py`` is for the delta
-  rule, is not written yet;
+  and sums in float32. Off the TPU it is what the ragged step runs, over a
+  period's whole run of slots (a slot without a run of one lane keeps its
+  state); everywhere it is what :func:`ssd_recurrent` scans and the oracle
+  of the kernel below;
 * :func:`ssd_chunk`: a piece of one sequence at once, the SSD form (the
   masked ``(C B^T) * decay`` product inside the piece, the state's part
   beside it), from a state and leaving one behind;
@@ -33,6 +33,17 @@ slot's state and convolution rows from the pool leaves before a run and
 leaving them behind after it. The runs and the convolution over them are
 ``ops/gated_delta.py``'s (:func:`~.gated_delta.runs_of`,
 :func:`~.gated_delta.conv_ragged`): one schedule for both recurrent kinds.
+
+On the TPU the step is a Pallas call a layer,
+``ops/pallas/mamba2.py::ssd_step_slots``: the same float32 products over
+the slots that decode one token this step and no others, each slot's state
+read once and written once where it lies in the leaf (a rolled stack's
+too: the period's ``base`` is a scalar the kernel's index map adds), the
+leaf aliased in and out. Which form :func:`mix_ragged` takes is the
+engine's ``attention_path``, handed down as ``ops/gated_delta.py`` takes
+it: ``pallas`` (the chip) and ``pallas_interpret`` (CPU tests) the kernel,
+``gather`` the XLA form; the chunk loop behind it is the same on every
+path.
 
 Everything between the input projection and the output product is float32;
 the state products ask for full float32 passes on the MXU
@@ -198,32 +209,45 @@ def piece_lanes(c, lanes: int) -> int:
     return min(c.mamba_chunk, lanes)
 
 
-def ssd_ragged(x, B, C, dt, g, D, state, runs: Runs, chunk: int, base=None):
+def ssd_ragged(x, B, C, dt, g, D, state, runs: Runs, chunk: int, base=None,
+               path: str = "gather"):
     """The recurrence over lanes. x [T, H, P]; B, C [T, G, N]; dt, g
     [T, H]; state [S + 1, H, P, N] float32; ``runs`` cut into pieces of
     ``chunk`` lanes. Under a rolled stack the leaf holds a run of S + 1
     slots a period and ``base`` (traced) is where this period's starts: the
-    leaf is read and written in place there. Returns (y [T, H, P],
-    state)."""
+    leaf is read and written in place there. ``path`` is the engine's
+    ``attention_path``: which form the runs of one lane take (module
+    docstring). Returns (y [T, H, P], state)."""
     T, H = x.shape[:2]
-    rep = H // B.shape[1]
     with jax.named_scope("ssd_step"):
-        # the step in XLA, a slot a row, over the period's whole run of
-        # slots (a slot without a run of one lane keeps its state)
-        one = runs.length == 1
-        lane = jnp.minimum(runs.first, T - 1)
-        own = state if base is None else jax.lax.dynamic_slice_in_dim(
-            state, base, one.shape[0], 0)
-        old = jnp.where((one & runs.slot_fresh)[:, None, None, None], 0.0,
-                        own)
-        heads = lambda a: jnp.repeat(a[lane], rep, axis=1)
-        y1, new = ssd_step(x[lane], heads(B), heads(C), dt[lane], g[lane], D,
-                           old)
-        own = jnp.where(one[:, None, None, None], new, own)
-        state = own if base is None else \
-            jax.lax.dynamic_update_slice_in_dim(state, own, base, 0)
+        if path == "gather":
+            # the step in XLA, a slot a row, over the period's whole run of
+            # slots (a slot without a run of one lane keeps its state)
+            rep = H // B.shape[1]
+            one = runs.length == 1
+            lane = jnp.minimum(runs.first, T - 1)
+            own = state if base is None else jax.lax.dynamic_slice_in_dim(
+                state, base, one.shape[0], 0)
+            old = jnp.where((one & runs.slot_fresh)[:, None, None, None],
+                            0.0, own)
+            heads = lambda a: jnp.repeat(a[lane], rep, axis=1)
+            y1, new = ssd_step(x[lane], heads(B), heads(C), dt[lane],
+                               g[lane], D, old)
+            own = jnp.where(one[:, None, None, None], new, own)
+            state = own if base is None else \
+                jax.lax.dynamic_update_slice_in_dim(state, own, base, 0)
+        else:
+            # the kernel, over the slots that have such a run alone, where
+            # they lie in the leaf
+            from .pallas.mamba2 import ssd_step_slots
+
+            lane = runs.steps[1]
+            one = jnp.arange(lane.shape[0]) < runs.steps[3]
+            y1, state = ssd_step_slots(
+                x[lane], B[lane], C[lane], dt[lane], g[lane], D, state,
+                runs.steps, base, interpret=path == "pallas_interpret")
         # lanes ``chunk`` past the end take the pieces' overhang and the
-        # rows of slots that decode nothing
+        # rows of slots (the kernel's: of entries) that decode nothing
         out = jnp.zeros((T + chunk,) + y1.shape[1:], F32) \
             .at[jnp.where(one, lane, T)].set(y1)
     with jax.named_scope("ssd_chunk"):
@@ -251,16 +275,17 @@ def ssd_ragged(x, B, C, dt, g, D, state, runs: Runs, chunk: int, base=None):
     return out[:T], state
 
 
-def mix_ragged(u, lp: Dict[str, Any], c, state, rows, runs: Runs, base=None):
+def mix_ragged(u, lp: Dict[str, Any], c, state, rows, runs: Runs, base=None,
+               path: str = "gather"):
     """u [T, d] -> (o [T, d], state, rows): the lanes of one step through
     one Mamba layer and its two pool leaves. ``runs`` is
-    ``gated_delta.runs_of(..., chunk=piece_lanes(c, T))``; ``base`` as
-    :func:`ssd_ragged` takes it."""
+    ``gated_delta.runs_of(..., chunk=piece_lanes(c, T))``; ``base`` and
+    ``path`` as :func:`ssd_ragged` takes them."""
     z, xBC, dt, g = _project(u, lp, c)
     with jax.named_scope("conv"):
         xBC, rows = conv_ragged(xBC.astype(F32), lp["conv_w"].astype(F32),
                                 rows, runs, lp["conv_b"].astype(F32), base)
     x, B, C = _split(xBC, c)
     y, state = ssd_ragged(x, B, C, dt, g, lp["D"].astype(F32), state, runs,
-                          piece_lanes(c, u.shape[0]), base)
+                          piece_lanes(c, u.shape[0]), base, path)
     return _output(y, z, lp, c, u.dtype), state, rows

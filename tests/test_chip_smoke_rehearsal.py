@@ -26,6 +26,7 @@ def _tiny() -> chip_smoke.Sizes:
         new_tokens=4,
         kernel_seq=256, kernel_pages_per_seq=8, kernel_n_seqs=8,
         kernel_alt_heads=(3, 3), kernel_delta_state=(3, 8, 16),
+        kernel_ssd_state=(4, 8, 16, 2), kernel_ssd_periods=3,
         zero3_layers=2, zero3_batch=4, zero3_steps=2)
 
 
@@ -44,9 +45,11 @@ def test_kernels_phase_interpret(ledger, capsys):
              for variant in ("", "_h30", "_w64", "_int8")}
     assert set(line["rel_err"]) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dk", "flash_bwd_dv",
-                                    "delta_step_o", "delta_step_state"} | paged
-    # the delta-rule step kernel leaves the slots that do not decode alone
-    assert line["state_unequal"] == 0
+                                    "delta_step_o", "delta_step_state",
+                                    "ssd_step_y", "ssd_step_state"} | paged
+    # the delta-rule step kernel leaves the slots that do not decode alone,
+    # and so does the state-space step kernel on its rolled leaf
+    assert line["state_unequal"] == 0 and line["ssd_state_unequal"] == 0
     # the row writer against the scatter: no element differs
     assert line["rows_unequal"] == {
         f"rows_{shape}_h{heads}": 0

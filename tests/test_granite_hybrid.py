@@ -2,7 +2,10 @@
 ``transformers.GraniteMoeHybridForCausalLM`` through ``checkpoint/hf.py``
 (every scalar multiplier off 1, a convolution bias that is not zero);
 Mamba-2's chunked, stepped and ragged forms against its token recurrence from
-a state that is not zero; the dense forward and the ragged engine (a prompt
+a state that is not zero; the step kernel ``ops/pallas/mamba2.py`` in
+interpret mode against ``ssd_step`` (live counts, a rolled leaf's later
+period, groups, heads in blocks; every slot that does not decode bit for
+bit); the dense forward and the ragged engine (a prompt
 split over ticks, decode, a slot reused, preempt and resume, two kinds of run
 in one tick) against the plain reference ``benchmarks/reference/
 granite_hybrid.py`` on seeded float32 weights; the pools at the cell's widths
@@ -176,8 +179,11 @@ RAGGED = {
 }
 
 
+@pytest.mark.parametrize("path", ["gather", "pallas_interpret"])
 @pytest.mark.parametrize("case", list(RAGGED))
-def test_ragged_lanes_against_the_token_recurrence(case):
+def test_ragged_lanes_against_the_token_recurrence(case, path):
+    """``path``: the runs of one lane through XLA's form over every slot,
+    or through the step kernel (interpret mode) over those slots alone."""
     S, T, K, ch, chunk = 6, 256, 4, 5, 32
     state = jax.random.normal(jax.random.PRNGKey(7), (S + 1, H, P, N))
     rows = jax.random.normal(jax.random.PRNGKey(13), (S + 1, K - 1, ch))
@@ -203,13 +209,137 @@ def test_ragged_lanes_against_the_token_recurrence(case):
         want_r[sl] = full[-(K - 1):]
         t += n
     runs = gd.runs_of(jnp.asarray(slots), jnp.asarray(positions), S, chunk)
-    y, st = jax.jit(mamba2.ssd_ragged, static_argnums=8)(
-        *xs, D, state, runs, chunk)
+    y, st = jax.jit(mamba2.ssd_ragged, static_argnums=(8, 10))(
+        *xs, D, state, runs, chunk, None, path)
     c, r = jax.jit(gd.conv_ragged)(xc, w, rows, runs, bias)
     np.testing.assert_allclose(np.asarray(y)[:t], want_y[:t], atol=1e-4)
     np.testing.assert_allclose(st, want_s, atol=1e-4)   # the sink's too
     np.testing.assert_allclose(np.asarray(c)[:t], want_c[:t], atol=1e-5)
     np.testing.assert_allclose(r, want_r, atol=1e-6)
+
+
+# ----------------------------------------------------------------------
+# the step kernel (interpret mode) against ``ssd_step`` on the same rows
+def _step_case(n, S, T, periods, period, groups, key=9):
+    """``n`` runs of one lane among ``S`` slots (every third starts a
+    sequence) and, where a slot is left, one run of five lanes between
+    them; a leaf of ``periods`` runs of ``S + 1`` slots, of which the
+    kernel is given the one at ``period``."""
+    rng = np.random.default_rng(n + 10 * period)
+    order = rng.permutation(S)
+    single, long_slot = order[:n], order[n] if n < S else None
+    slots = np.full(T, -1, np.int32)
+    positions = np.zeros(T, np.int32)
+    t = 0
+    for i, sl in enumerate(single):
+        if i == n // 2 and long_slot is not None:
+            slots[t:t + 5], positions[t:t + 5] = long_slot, np.arange(9, 14)
+            t += 5
+        slots[t], positions[t] = sl, 0 if i % 3 == 0 else 3 + i
+        t += 1
+    state = jax.random.normal(jax.random.PRNGKey(7),
+                              (periods * (S + 1), H, P, N))
+    xs = _lanes(jax.random.PRNGKey(key), T, groups)
+    runs = gd.runs_of(jnp.asarray(slots), jnp.asarray(positions), S, 32)
+    base = None if periods == 1 else jnp.int32(period * (S + 1))
+    return single, long_slot, xs, state, runs, base, t
+
+
+def _kernel_against_ssd_step(n, S, periods, period, xs, state, runs, base):
+    """The kernel's rows and leaf against ``ssd_step`` on the ``n`` live
+    entries' rows (from zeros where the run starts a sequence; B and C a
+    group's, not repeated to its heads), each slot where it lies in the
+    leaf: at ``period * (S + 1) + slot``. Every other row of the leaf keeps
+    its bits. Returns those rows' indices."""
+    from deepspeed_tpu.ops.pallas.mamba2 import ssd_step_slots
+
+    steps = np.asarray(runs.steps)
+    rows = [a[steps[1]] for a in xs]
+    y, st = ssd_step_slots(*rows, D, state, runs.steps, base, interpret=True)
+    at = period * (S + 1) + steps[0, :n]
+    old = jnp.where(jnp.asarray(steps[2, :n] > 0)[:, None, None, None], 0.0,
+                    state[at])
+    x, B, C, dt, g = (a[:n] for a in rows)
+    want_y, want_s = mamba2.ssd_step(x, _per_head(B), _per_head(C), dt, g, D,
+                                     old)
+    np.testing.assert_allclose(np.asarray(y)[:n], want_y, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(st)[at], want_s, atol=1e-6)
+    untouched = np.setdiff1d(np.arange(periods * (S + 1)), at)
+    assert np.array_equal(np.asarray(st)[untouched],
+                          np.asarray(state)[untouched])
+    return untouched
+
+
+@pytest.mark.parametrize("groups", [1, 2], ids=["one_group", "two_groups"])
+@pytest.mark.parametrize("periods,period", [(1, 0), (3, 2)],
+                         ids=["flat_leaf", "rolled_leaf_period_2"])
+@pytest.mark.parametrize("n", [0, 1, 3, 6],
+                         ids=["none_live", "one_live", "some_live",
+                              "all_live"])
+def test_step_kernel_against_ssd_step(n, periods, period, groups):
+    """The kernel gives each single-lane slot ``ssd_step``'s output and
+    state, where the slot lies in the leaf: at ``base + slot`` under a
+    rolled stack. Every other row of the leaf keeps its bits: the idle
+    slots, the sinks, the other periods' runs, and the slot whose run is
+    longer, which the chunk loop serves behind it."""
+    S, T = 6, 32
+    single, long_slot, xs, state, runs, base, t = _step_case(
+        n, S, T, periods, period, groups)
+    steps = np.asarray(runs.steps)
+    assert steps.shape == (4, S) and steps[3].tolist() == [n] * S
+    assert steps[0, :n].tolist() == sorted(single.tolist())
+    untouched = _kernel_against_ssd_step(n, S, periods, period, xs, state,
+                                         runs, base)
+    assert period * (S + 1) + S in untouched
+    # behind it the chunk loop serves the longer run, as on the XLA path
+    both = [jax.jit(mamba2.ssd_ragged, static_argnums=(8, 10))(
+        *xs, D, state, runs, 32, base, path)
+        for path in ("gather", "pallas_interpret")]
+    np.testing.assert_allclose(np.asarray(both[1][0])[:t],
+                               np.asarray(both[0][0])[:t], atol=1e-5)
+    np.testing.assert_allclose(both[1][1], both[0][1], atol=1e-6)
+    if long_slot is not None and n:
+        held = period * (S + 1) + long_slot
+        assert held in untouched
+        assert not np.array_equal(np.asarray(both[1][1])[held],
+                                  np.asarray(state)[held])
+
+
+def test_step_kernel_takes_the_heads_in_blocks(monkeypatch):
+    """A state too large for one grid step goes ``hb`` heads at a time:
+    the entry's block of ``y`` is one over its grid steps, and a later
+    block of heads finds the earlier ones' columns in it."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernels
+
+    S, T = 5, 16      # shapes of their own: the kernel is jitted by shape
+    monkeypatch.setattr(kernels, "STATE_VMEM_BYTES", 4 * 2 * 4 * P * 128)
+    assert kernels.head_block(H, P, N) == 2
+    _, _, xs, state, runs, base, _ = _step_case(4, S, T, 2, 1, 2)
+    _kernel_against_ssd_step(4, S, 2, 1, xs, state, runs, base)
+
+
+@pytest.mark.parametrize("periods,period", [(1, 0), (4, 3)],
+                         ids=["flat_leaf", "rolled_leaf_period_3"])
+@pytest.mark.parametrize("live", [0, 40], ids=["empty_batch", "one_long_run"])
+def test_step_kernel_with_no_single_lane_run_changes_nothing(live, periods,
+                                                             period):
+    """An empty batch (the runner's warm-up) and a batch of one long run:
+    every entry names the period's sink, which comes back bit for bit, as
+    does every other slot of the leaf."""
+    from deepspeed_tpu.ops.pallas.mamba2 import ssd_step_slots
+
+    S, T = 6, 64
+    state = jax.random.normal(jax.random.PRNGKey(7),
+                              (periods * (S + 1), H, P, N))
+    xs = _lanes(jax.random.PRNGKey(9), T)
+    slots = np.where(np.arange(T) < live, 2, -1).astype(np.int32)
+    runs = gd.runs_of(jnp.asarray(slots), jnp.arange(T, dtype=jnp.int32), S,
+                      32)
+    assert np.asarray(runs.steps)[[0, 3]].tolist() == [[S] * S, [0] * S]
+    base = None if periods == 1 else jnp.int32(period * (S + 1))
+    _, st = ssd_step_slots(*(a[runs.steps[1]] for a in xs), D, state,
+                           runs.steps, base, interpret=True)
+    assert np.array_equal(np.asarray(st), np.asarray(state))
 
 
 # ----------------------------------------------------------------------
@@ -455,21 +585,32 @@ def test_lane_grid_engine_decodes_what_the_gather_engine_decodes(
         for _ in range(3):
             nxt = np.argmax(got[-1], -1)
             got.append(eng.put(uids, [[int(t)] for t in nxt]))
-        return np.stack(got, 1)
+        # and ``decode_steps``, which scans the same core: three more
+        chains = eng.decode_steps(
+            {u: int(t) for u, t in zip(uids, np.argmax(got[-1], -1))}, 3)
+        return np.stack(got, 1), [chains[u] for u in uids]
 
-    a = drive(_engine(built))
+    a, tokens_a = drive(_engine(built))
     monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
     eng = _engine(built)
     assert eng.attention_path == "pallas_interpret"
-    assert not eng._writes_pages and not eng._steps_live_slots
-    b = drive(eng)
+    # ... and the Mamba layers' one-token step is the kernel over the slots
+    # that decode, in place in each period's run of the rolled leaf
+    assert not eng._writes_pages and eng._steps_live_slots
+    b, tokens_b = drive(eng)
+    assert tokens_b == tokens_a
     assert np.isfinite(b).all()
     assert _rel(b.reshape(12, -1), a.reshape(12, -1)).max() < 1e-5
 
 
 # ----------------------------------------------------------------------
 # spans and counters
-def test_put_says_how_many_layers_hold_a_state(built, monkeypatch):
+@pytest.mark.parametrize("path", ["gather", "pallas_interpret"])
+def test_put_says_how_many_layers_hold_a_state(built, monkeypatch, path):
+    """... and how many slots the step kernel serves: ``step_slots``, the
+    schedule's entries of one lane, on the kernel paths; 0 where the step
+    runs in XLA over every slot. The registry's
+    ``inference/state_slots_stepped`` sums them x the 4 Mamba layers."""
     from deepspeed_tpu.config import TelemetryConfig
     from deepspeed_tpu.inference import ragged as ragged_mod
     from deepspeed_tpu.telemetry import Telemetry, set_telemetry
@@ -493,24 +634,32 @@ def test_put_says_how_many_layers_hold_a_state(built, monkeypatch):
     tel = Telemetry(TelemetryConfig(enabled=True, output_dir="",
                                     jsonl_path="", stall_detection=False))
     set_telemetry(tel)
+    if path != "gather":
+        monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+    stepped = 2 if path != "gather" else 0    # the tick of two decode lanes
     try:
+        r = tel.registry        # one a process: counters by their growth
+        resets, steps = (r.counter("inference/state_resets"),
+                         r.counter("inference/state_slots_stepped"))
+        was = resets.value, steps.value
         eng = _engine(built)
+        assert eng.attention_path == path
+        assert eng._steps_live_slots == (path != "gather")
         rows, _ = _prefill(eng, [1, 2], _prompts(20, 9))
         eng.put([1, 2], [[int(t)] for t in np.argmax(rows, -1)])
-        r = tel.registry
         # 4 Mamba layers x (8 x 16 x 8 float32 + 3 rows of 144 float32)
         assert eng.state_bytes_per_slot == 4 * (8 * 16 * 8 * 4 + 3 * 144 * 4)
         assert r.gauge("inference/state_bytes_per_slot").value \
             == eng.state_bytes_per_slot
-        assert r.counter("inference/state_resets").value == 2
+        assert resets.value - was[0] == 2
         assert r.gauge("inference/state_slots_live").value == 2
-        # the step runs in XLA over every slot: no kernel's entries to count
-        assert r.counter("inference/state_slots_stepped").value == 0
+        # in XLA over every slot there are no kernel's entries to count
+        assert steps.value - was[1] == stepped * 4
     finally:
         set_telemetry(None)
     assert [a["state_layers"] for a in seen] == [4, 4]
     assert [a["state_slots"] for a in seen] == [2, 2]
-    assert [a["step_slots"] for a in seen] == [0, 0]
+    assert [a["step_slots"] for a in seen] == [0, stepped]
     assert [a["decode"] for a in seen] == [0, 2]
     assert [a["kv_layers"] for a in seen] == [2, 2]
     assert [a["passes"] for a in seen] == [1, 1]

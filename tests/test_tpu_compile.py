@@ -18,8 +18,11 @@ one ``write_kv_pages`` call a layer and with the pool sharded over two by
 the scatter; the writer alone at the cells' shapes; the benchmark's
 roofline readers tell it from the paged kernel), the 12-layer Olmo-Hybrid step of the
 benchmark's cell with both of its caches (fits, copies no leaf and no
-weight), the Mixtral cell's step (copies no expert matrix), and the Ouro
-cell's 48-layer step of four passes (one rolled loop, no pool leaf copied).
+weight), the Mixtral cell's step (copies no expert matrix), the Ouro
+cell's 48-layer step of four passes (one rolled loop, no pool leaf copied),
+and the two recurrent kinds' step kernels alone and in their cells' steps
+(nine calls each, the state leaf aliased; Granite's inside its rolled loop
+over periods, on a leaf of four runs of slots).
 
 One file on purpose: only the xdist worker that gets this file loads
 libtpu, inside the module-scoped ``topo`` fixture — never at import.
@@ -127,9 +130,13 @@ OLMO_HEADS = (30, 30)   # Olmo-Hybrid-7B's full layers: 30 KV heads, group 1
 # the row writer's is a tuple of two pool leaves, which they must not count
 _KERNEL_RESULT = re.compile(r"= bf16\[\d+,\d+,\d+,\d+\]\S* custom-call\(")
 _WRITER_RESULT = re.compile(r"= \((\w+\[[\d,]+\])\S*, \1\S*\) custom-call\(")
-# the delta-rule step's: the entries' output rows and the state leaf
+# a recurrent layer's step's (the delta rule's, Mamba-2's): the entries'
+# output rows and the state leaf
 _STEP_RESULT = re.compile(r"= \(f32\[\d+,\d+,\d+\]\S*, "
                           r"f32\[(\d+,\d+,\d+,\d+)\]\S*\) custom-call\(")
+# the scope each kind's step runs under: the path its roofline metric
+# (``delta_step_roofline_pct``, ``ssd_step_roofline_pct``) reads
+_STEP_SCOPES = r"(linear_attn/delta_step|ssm/ssd_step)"
 
 
 def _custom_calls(hlo: str) -> list:
@@ -148,22 +155,21 @@ def _writer_calls(hlo: str) -> list:
 
 
 def _step_calls(hlo: str) -> list:
-    """The delta-rule step kernel's calls (``delta_step_slots``): each
-    returns the entries' rows and a state leaf, which is also its operand
-    (aliased: written where it lies), and runs under
-    ``linear_attn/delta_step``, the path ``delta_step_roofline_pct``
-    reads."""
+    """The step kernels' calls (``delta_step_slots``, ``ssd_step_slots``):
+    each returns the entries' rows and a state leaf, which is also its
+    operand (aliased: written where it lies), and runs under its kind's
+    scope (``_STEP_SCOPES``)."""
     calls = [l for l in _custom_calls(hlo) if _STEP_RESULT.search(l)]
     for l in calls:
         leaf = f"f32[{_STEP_RESULT.search(l).group(1)}]"
         assert l.count(leaf) >= 2 and "output_to_operand_aliasing" in l, l
-        assert re.search(r'op_name="[^"]*linear_attn/delta_step/[^"]*"', l), l
+        assert re.search(r'op_name="[^"]*' + _STEP_SCOPES + r'/[^"]*"', l), l
     return calls
 
 
 def _kernel_calls(hlo: str) -> int:
     """The paged kernel's calls; every other kernel of the module is the
-    row writer or the delta-rule step."""
+    row writer or a recurrent layer's step."""
     calls = _custom_calls(hlo)
     paged = [l for l in calls if _KERNEL_RESULT.search(l)]
     assert len(paged) + len(_writer_calls(hlo)) + len(_step_calls(hlo)) \
@@ -272,6 +278,59 @@ def test_delta_step_kernel_compiles(one_chip, n_slots):
     hlo = c.as_text()
     assert len(_custom_calls(hlo)) == 1
     dims = f"{n_slots + 1},{H},{dk},{dv}"
+    call = _custom_calls(hlo)[0]
+    assert _STEP_RESULT.search(call).group(1) == dims
+    assert "output_to_operand_aliasing" in call
+    leaf = [i for i in _instructions(hlo).values() if i.dims == dims]
+    assert {i.layout for i in leaf} == {"3,2,1,0"}
+    assert not [i for i in leaf if i.op not in ("parameter", "bitcast",
+                                                "get-tuple-element",
+                                                "custom-call")]
+    assert c.memory_analysis().temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize("period", [None, "traced"],
+                         ids=["flat_leaf", "rolled_leaf"])
+def test_ssd_step_kernel_compiles(one_chip, period):
+    """``ssd_step_slots`` alone at granite-4.0-h-micro's state shape (64
+    heads of 64 x 128 float32: a whole slot a grid step, 4 x 2 MiB of VMEM,
+    nothing padded), on a leaf of one run of 65 slots and on the cell's
+    rolled leaf of four, the period's ``base`` a traced scalar inside a
+    ``fori_loop`` that carries the leaf: the column picks, the lane
+    reductions, the group's rows by a dynamic index and the SMEM decay
+    lower; the state leaf comes back aliased to the donated operand, in the
+    default tiled layout, and nothing copies or slices it, inside the loop
+    or outside."""
+    from deepspeed_tpu.ops.gated_delta import runs_of
+    from deepspeed_tpu.ops.pallas.gated_delta import head_block
+    from deepspeed_tpu.ops.pallas.mamba2 import ssd_step_slots
+
+    H, P, N, G, S, T = 64, 64, 128, 1, 64, 64
+    periods = 4 if period else 1
+    assert head_block(H, P, N) == 64
+    f32 = lambda *shape: _sds(shape, jnp.float32, one_chip)
+    lanes = _sds((T,), jnp.int32, one_chip)
+
+    def fn(x, B, C, dt, g, D, state, slots, pos):
+        steps = runs_of(slots, pos, S).steps
+        if not period:
+            return ssd_step_slots(x, B, C, dt, g, D, state, steps)
+
+        def one_period(t, carry):
+            y, state = ssd_step_slots(x, B, C, dt, g, D, carry[1], steps,
+                                      t * (S + 1))
+            return carry[0] + y, state
+
+        return jax.lax.fori_loop(0, periods, one_period,
+                                 (jnp.zeros_like(x), state))
+
+    c = jax.jit(fn, donate_argnums=(6,)).lower(
+        f32(T, H, P), f32(T, G, N), f32(T, G, N), f32(T, H), f32(T, H),
+        f32(H), f32(periods * (S + 1), H, P, N), lanes, lanes).compile()
+    hlo = c.as_text()
+    assert len(_custom_calls(hlo)) == 1
+    assert (len(re.findall(r" while\(", hlo)) == 1) == bool(period)
+    dims = f"{periods * (S + 1)},{H},{P},{N}"
     call = _custom_calls(hlo)[0]
     assert _STEP_RESULT.search(call).group(1) == dims
     assert "output_to_operand_aliasing" in call
@@ -878,10 +937,14 @@ def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
     in the text, the attention layer of a period, and at head size 64 it is
     the grid over lanes with XLA's scatter in front of it, not the row
     writer (``_writes_pages``). The pool has a leaf a layer of a period,
-    the periods' runs end to end in it, and nothing copies, transposes,
-    slices or selects a state leaf as an operation of its own, inside the
-    loop or outside it (the whole-run step and the chunk loop update the
-    leaf in place, through fused dynamic-update-slices). What is copied,
+    the periods' runs end to end in it; each of the nine state leaves is
+    written by one call of the state-space step kernel in the loop's body,
+    which takes the leaf as an operand and returns it aliased, the
+    period's first slot a prefetched scalar (where nine fusions over a
+    period's whole run of 65 slots were, PR 44), and nothing copies,
+    transposes, slices or selects a state leaf as an operation of its own,
+    inside the loop or outside it (the chunk loop updates one slot in
+    place, through a fused dynamic-update-slice). What is copied,
     and what the cell pays until a tiled grid takes 64-wide slabs: the K
     and the V leaf twice a tick, OUTSIDE the loop, from the layout the
     compiler gives a parameter whose minor dimension is 64 (pages
@@ -893,8 +956,15 @@ def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
     hlo = compiled.as_text()
     assert c.layer_period == 10 and len(c.layers_of("mamba")) == 36
     assert _kernel_calls(hlo) == 1
-    assert not _writer_calls(hlo) and not _step_calls(hlo)
+    assert not _writer_calls(hlo)
     state = f"{4 * (e['max_seqs'] + 1)},64,64,128"
+    # the nine Mamba layers of a period: one call of the step kernel each,
+    # in the loop's body, on its own leaf at the period's run of slots
+    steps = _step_calls(hlo)
+    assert len(steps) == len(c.layers_of("mamba")) // 4 == 9
+    assert all(f"f32[{state}]" in l
+               and "/while/body/closed_call/ssm/ssd_step/" in l
+               for l in steps)
     page = f"{4 * (e['max_kv_blocks'] + 1)},8,{e['kv_block_size']},64"
     entry = list(_entry_instructions(hlo))
     assert sum(op == "parameter" and (dt, dims) == ("f32", state)
@@ -909,7 +979,7 @@ def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
                               for _, _, dims, op, _ in entry)
     # temporaries: the two K/V leaves' second copies, padded to 128 lanes,
     # and the step's own; 1.11 GB by my described-chip compile, PR 43
-    # (2.08 GB unrolled)
+    # (2.08 GB unrolled), 1,107,248,640 B with the step kernel, PR 44
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
 
 
