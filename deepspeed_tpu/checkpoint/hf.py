@@ -253,6 +253,61 @@ def ouro_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
         early_exit_threshold=float(hc.get("early_exit_threshold", 1.0)))
 
 
+# SDAR's generation settings where the config.json does not state them: the
+# ``-Chat`` checkpoints' block length, the id a position not yet decided
+# holds, and the passes a block (``low_confidence_static`` remasking decides
+# block_length / denoising_steps positions a pass)
+SDAR_DEFAULTS = {"block_length": 4, "mask_token_id": 151669,
+                 "denoising_steps": 2}
+
+
+def sdar_moe_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
+    """``model_type: sdar_moe`` (SDAR-30B-A3B) -> MoETransformerConfig: the
+    layer is Qwen3-MoE's, key for key (``transformers``'
+    ``Qwen3MoeDecoderLayer``: pre-norm, q / k / v without bias, an RMSNorm
+    over each head of q and of k before the rotary, a router softmax over
+    all experts in float32, the ``num_experts_per_tok`` largest
+    renormalised, SwiGLU experts of ``moe_intermediate_size``, no shared
+    expert), under a mask that is causal over blocks of ``block_length``
+    and sees both ways inside one; generation is by diffusion over those
+    blocks (``attn_block``, ``mask_token_id``, ``denoise_tokens``).
+    ``n_layers`` keeps the first layers only."""
+    from ..models.moe import MoETransformerConfig
+
+    if hc.get("rope_scaling"):
+        raise NotImplementedError("sdar_moe rope_scaling not supported")
+    n = int(n_layers or hc["num_hidden_layers"])
+    if hc.get("mlp_only_layers") or hc.get("decoder_sparse_step", 1) != 1:
+        raise NotImplementedError(
+            "sdar_moe with dense layers among the sparse ones "
+            "(mlp_only_layers, decoder_sparse_step != 1) not supported")
+    if not hc.get("norm_topk_prob", True):
+        raise NotImplementedError("sdar_moe norm_topk_prob=false not "
+                                  "supported (the serving router renormalises)")
+    if hc.get("attention_bias"):
+        raise NotImplementedError("sdar_moe attention_bias not supported")
+    if hc.get("use_sliding_window"):
+        raise NotImplementedError("sdar_moe use_sliding_window not supported")
+    gen = {k: int(hc.get(k, v)) for k, v in SDAR_DEFAULTS.items()}
+    if gen["block_length"] % gen["denoising_steps"]:
+        raise ValueError(f"sdar_moe: block_length {gen['block_length']} is "
+                         f"not {gen['denoising_steps']} whole passes")
+    heads = hc["num_attention_heads"]
+    return MoETransformerConfig(
+        vocab_size=hc["vocab_size"], d_model=hc["hidden_size"], n_layers=n,
+        n_heads=heads, n_kv_heads=hc.get("num_key_value_heads", heads),
+        head_size=hc.get("head_dim"), d_ff=hc["moe_intermediate_size"],
+        max_seq_len=hc.get("max_position_embeddings", 32768),
+        norm="rms", activation="silu_glu", position="rope",
+        rope_theta=float(hc.get("rope_theta", 1e6)),
+        tie_embeddings=hc.get("tie_word_embeddings", False), use_bias=False,
+        norm_eps=hc.get("rms_norm_eps", 1e-6), qk_norm=True,
+        qk_norm_heads=True, n_experts=hc["num_experts"],
+        top_k=hc["num_experts_per_tok"], attn_block=gen["block_length"],
+        mask_token_id=gen["mask_token_id"],
+        denoise_tokens=gen["block_length"] // gen["denoising_steps"])
+
+
 def hf_config(model_dir: str):
     """Parse HF config.json -> (family, TransformerConfig)."""
     from ..models.transformer import TransformerConfig
@@ -266,6 +321,8 @@ def hf_config(model_dir: str):
         return family, ouro_config(hc)
     if family == "granitemoehybrid":
         return family, granite_hybrid_config(hc)
+    if family == "sdar_moe":
+        return family, sdar_moe_config(hc)
     if family in ("llama", "mistral"):
         # loud failure beats silently-wrong logits for unsupported variants
         if hc.get("rope_scaling"):
@@ -566,7 +623,7 @@ def hf_config(model_dir: str):
                          f"(supported: llama, mistral, gpt2, opt, bloom, "
                          f"gptj, gpt_neo, gpt_neox, falcon, mixtral, bert, "
                          f"distilbert, clip, qwen2, olmo_hybrid, ouro, "
-                         f"granitemoehybrid)")
+                         f"granitemoehybrid, sdar_moe)")
     return family, cfg
 
 
@@ -862,6 +919,44 @@ def _map_mixtral(state, c) -> Dict[str, Any]:
         "w_down": np.stack([np.stack(
             [state.pop((L + "block_sparse_moe.experts.{}.w2.weight")
                        .format(i, e)).T for e in range(E)]) for i in range(n)]),
+    }
+    params = {
+        "tok_embed": state[pre + "embed_tokens.weight"],
+        "layers": layers,
+        "final_norm_w": state[pre + "norm.weight"],
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = (state["lm_head.weight"]
+                             if "lm_head.weight" in state
+                             else state[pre + "embed_tokens.weight"]).T
+    return params
+
+
+def _map_sdar_moe(state, c) -> Dict[str, Any]:
+    """SDAR-MoE under Qwen3-MoE's weight names: Llama-style attention with
+    ``self_attn.q_norm`` / ``k_norm`` (a gain [head_dim] each), ``mlp.gate``
+    the router, ``mlp.experts.{e}.gate_proj / up_proj / down_proj``."""
+    n, E = c.n_layers, c.n_experts
+    pre = "model." if "model.embed_tokens.weight" in state else ""
+    L = pre + "layers.{}."
+
+    def experts(name):    # HF [out, in] -> native [n, E, in, out]
+        return np.stack([np.stack(
+            [state.pop((L + "mlp.experts.{}." + name + ".weight")
+                       .format(i, e)).T for e in range(E)]) for i in range(n)])
+
+    layers = {
+        "attn_norm_w": _stack(state, L + "input_layernorm.weight", n),
+        "wq": _stack(state, L + "self_attn.q_proj.weight", n, transpose=True),
+        "wk": _stack(state, L + "self_attn.k_proj.weight", n, transpose=True),
+        "wv": _stack(state, L + "self_attn.v_proj.weight", n, transpose=True),
+        "wo": _stack(state, L + "self_attn.o_proj.weight", n, transpose=True),
+        "q_norm_w": _stack(state, L + "self_attn.q_norm.weight", n),
+        "k_norm_w": _stack(state, L + "self_attn.k_norm.weight", n),
+        "mlp_norm_w": _stack(state, L + "post_attention_layernorm.weight", n),
+        "wg": _stack(state, L + "mlp.gate.weight", n, transpose=True),
+        "w_gate": experts("gate_proj"), "w_up": experts("up_proj"),
+        "w_down": experts("down_proj"),
     }
     params = {
         "tok_embed": state[pre + "embed_tokens.weight"],
@@ -1197,7 +1292,7 @@ _MAPPERS: Dict[str, Callable] = {
     "falcon": _map_falcon, "mixtral": _map_mixtral,
     "bert": _map_bert, "distilbert": _map_distilbert,
     "clip": _map_clip, "olmo_hybrid": _map_olmo_hybrid, "ouro": _map_ouro,
-    "granitemoehybrid": _map_granite_hybrid,
+    "granitemoehybrid": _map_granite_hybrid, "sdar_moe": _map_sdar_moe,
 }
 
 
@@ -1243,7 +1338,7 @@ def from_pretrained(model_dir: str, dtype=None, topology=None,
         cfg.pooler = "pooler_w" in host_params
         # an untied MLM decoder was mapped to lm_head (see _map_bert)
         cfg.tie_embeddings = "lm_head" not in host_params
-    if family == "mixtral":
+    if family in ("mixtral", "sdar_moe"):
         from ..models.moe import MoETransformer
 
         model = MoETransformer(cfg)

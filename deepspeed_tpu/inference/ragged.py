@@ -48,7 +48,7 @@ from .drafter import NgramIndex
 # cache is imported from its home, kv_cache.py
 from .kv_cache import (assert_block_balance,  # noqa: F401
                        kv_blocks_for_bytes, kv_page_bytes)
-from .sampling import sample
+from .sampling import decide_masked, sample
 
 
 def _paged_kernel_blocker(head_dim: int, block: int, dtype,
@@ -87,6 +87,9 @@ class SequenceDescriptor:
     t_admitted: Optional[float] = None
     t_created: Optional[float] = None
     prompt_len: int = 0
+    # block diffusion only: the length the caller wants the stream to reach
+    # (``limit_stream``; None = to the context bound)
+    limit: Optional[int] = None
 
     @property
     def pending(self) -> int:
@@ -155,6 +158,12 @@ class RaggedInferenceEngine:
     steps), or, for a caller that has declared greedy decoding
     (``return_token_ids``), the token ids the step chose (``-1`` there).
     ``generate`` drives put/flush to completion.
+
+    A model that generates by diffusion over blocks (``attn_block`` > 1:
+    SDAR) is served by the same scheduler, allocator, pool and step
+    builder; ``put`` then feeds a stream once and ``[]`` after it, and its
+    result is a block's (``_put_blocks``; docs/serving.md "The engine's
+    result").
     """
 
     def __init__(self, model, config: Optional[RaggedConfig] = None,
@@ -198,6 +207,24 @@ class RaggedInferenceEngine:
         if self._state_layers and tp > 1:
             raise NotImplementedError(
                 "recurrent layers are not sharded over the model axis yet")
+        # block diffusion (SDAR): a sequence that generates holds a block of
+        # ``_block`` lanes a step, the step decides several of its tokens or
+        # none, and a block's K/V is final only after a pass over the
+        # finished block (the class docstring). 1 = one token a step
+        self._block = int(getattr(c, "attn_block", 1))
+        self._limits: Dict[int, int] = {}       # limit_stream, before admission
+        self._decided_last = 0                  # tokens the last pass decided
+        if self._block > 1:
+            if tp > 1:
+                self._refuse_in_blocks("tensor-parallel serving")
+            if self.config.enable_prefix_cache:
+                self._refuse_in_blocks("enable_prefix_cache")
+            if self.config.kv_block_size % self._block:
+                raise ValueError(
+                    f"kv_block_size {self.config.kv_block_size} must be a "
+                    f"multiple of the model's attn_block {self._block}")
+            if self.config.temperature != 0.0:
+                self._refuse_in_blocks("sampling (temperature > 0)")
         if c.window_binds(self.config.max_context):
             log_dist("RaggedInferenceEngine: binding sliding window — "
                      "banded paged kernel on TPU, banded gather elsewhere")
@@ -342,6 +369,24 @@ class RaggedInferenceEngine:
                  f"passes={self._passes} periods={self._periods} "
                  f"kv_bytes_per_token={self.kv_bytes_per_token}")
 
+    def _refuse_in_blocks(self, what: str) -> None:
+        """What a model that generates by blocks (``attn_block`` > 1) does
+        not serve, until someone needs it."""
+        if self._block > 1:
+            raise NotImplementedError(
+                f"{what} is not supported for a model that generates by "
+                f"diffusion over blocks (attn_block={self._block}): a step "
+                "decides several tokens of a sequence or none, and a block's "
+                "K/V is provisional until its commit pass")
+
+    @property
+    def block_length(self) -> int:
+        """Tokens a sequence's step decides together: the model's
+        ``attn_block`` where it generates by diffusion over blocks (the
+        server then feeds streams and reads committed blocks: ``put``),
+        else 1."""
+        return self._block
+
     @property
     def _writes_pages(self) -> bool:
         """Who writes a step's new K/V rows: one Pallas call a layer over
@@ -420,6 +465,7 @@ class RaggedInferenceEngine:
         for uid in uids:
             seq = self.seqs.pop(uid, None)
             self._ngram_idx.pop(uid, None)
+            self._limits.pop(uid, None)
             if seq is not None:
                 if seq.t_created is not None:
                     # request retires here: end-to-end latency + tokens the
@@ -457,6 +503,7 @@ class RaggedInferenceEngine:
         without the scatter landing). Zero-leak either way."""
         seq = self.seqs.pop(uid, None)
         self._ngram_idx.pop(uid, None)
+        self._limits.pop(uid, None)
         if seq is None:
             return
         self.allocator.free(seq.blocks)
@@ -526,6 +573,7 @@ class RaggedInferenceEngine:
         ``preempt`` (publish into this engine's prefix cache) or
         ``discard`` the local copy afterwards."""
         kv_cache.refuse_without_snapshot(self.model.config, "export_kv")
+        self._refuse_in_blocks("export_kv")
         seq = self.seqs.get(uid)
         if seq is None:
             raise KeyError(f"uid {uid} has no live sequence to export")
@@ -580,6 +628,7 @@ class RaggedInferenceEngine:
         back to the re-prefill resume path) or ``ValueError`` on geometry
         mismatch. On any failure nothing is mutated."""
         kv_cache.refuse_without_snapshot(self.model.config, "import_kv")
+        self._refuse_in_blocks("import_kv")
         cfg = self.config
         c = self.model.config
         if uid in self.seqs:
@@ -641,6 +690,7 @@ class RaggedInferenceEngine:
         hooks are leaf-locked, so firing them under the driver's
         serving lock is legal in the documented lock order."""
         kv_cache.refuse_without_snapshot(self.model.config, "the KV tier")
+        self._refuse_in_blocks("the KV tier")
         self._kv_tier_member = str(member)
         self._cold_tier = cold_tier
         self._on_prefix_invalidate = on_invalidate
@@ -844,6 +894,7 @@ class RaggedInferenceEngine:
         sequence will keep being served (post-EOS tokens were admitted by
         that chunk and would otherwise pollute further continuations)."""
         kv_cache.refuse_without_snapshot(self.model.config, "trim")
+        self._refuse_in_blocks("trim")
         seq = self.seqs[uid]
         if not 0 <= length <= seq.seen:
             raise ValueError(
@@ -895,6 +946,10 @@ class RaggedInferenceEngine:
             matched = prompt = 0
             for uid, toks in zip(uids, tokens):
                 new = uid not in self.seqs
+                if self._block > 1 and not new and len(toks):
+                    raise ValueError(
+                        f"uid {uid}: a model that generates by blocks decides "
+                        "its own tokens in the step; continue it with []")
                 if new:
                     slot = self.cache.take_slot()    # may raise: none free
                     now = time.perf_counter()
@@ -914,6 +969,9 @@ class RaggedInferenceEngine:
                 if new:
                     seq.prompt_len = len(seq.tokens)
                     prompt += seq.prompt_len
+                    if self._block > 1:
+                        seq.limit = self._limits.pop(uid, None)
+                        self._open_block(seq)
                 if new and self.prefix_cache is not None and seq.tokens:
                     if self._cold_tier is not None:
                         # cold-tier re-admission first, so the match below
@@ -930,6 +988,48 @@ class RaggedInferenceEngine:
                         matched += shared
             span.set_metadata(matched=matched, prompt=prompt)
 
+    # -- generation by diffusion over blocks (attn_block > 1) -------------
+    def limit_stream(self, uid: int, n_tokens: int) -> None:
+        """Declared by the caller before ``uid`` is admitted: the length
+        (prompt and answer) its stream should reach. The block that holds
+        position ``n_tokens - 1`` is the last one opened, computed whole
+        and committed by a pass of its own; without a limit blocks are
+        opened up to ``max_context``. Only for a model that generates by
+        blocks, whose engine and not whose caller extends the stream."""
+        if self._block > 1:
+            self._limits[int(uid)] = int(n_tokens)  # dslint: disable=races -- the engine is driven by one thread at a time: the server calls this from its ticking thread while it builds the feed, as it calls put and flush
+
+    def _open_block(self, seq: SequenceDescriptor) -> bool:
+        """Extends ``seq``'s stream to the end of the next block with the
+        mask id: the block under way. A prompt's last ``len % block``
+        tokens open the first one. False where the stream has reached its
+        limit or the context bound."""
+        B = self._block
+        n = len(seq.tokens)
+        end = n - n % B + B
+        limit = self.config.max_context if seq.limit is None \
+            else min(seq.limit, self.config.max_context)
+        if n >= limit or end > self.config.max_context:
+            return False
+        seq.tokens.extend([self.model.config.mask_token_id] * (end - n))
+        return True
+
+    def _masked(self, seq: SequenceDescriptor) -> List[int]:
+        """Positions of the block under way that are not decided yet."""
+        mask = self.model.config.mask_token_id
+        n = len(seq.tokens)
+        return [p for p in range(n - self._block, n) if seq.tokens[p] == mask]
+
+    def _pass_of(self, seq: SequenceDescriptor, take: int
+                 ) -> Tuple[List[int], int]:
+        """What a pass over ``seq``'s next ``take`` lanes does: (the masked
+        positions it denoises, none unless it reaches the block under way;
+        the position up to which K/V is final after it: the block under
+        way stays open while a mask id is left in it)."""
+        end = seq.seen + take
+        masked = self._masked(seq) if end == len(seq.tokens) else []
+        return masked, end - (self._block if masked else 0)
+
     def _pack_splitfuse(self) -> List[Tuple[SequenceDescriptor, int]]:
         """Dynamic SplitFuse packing: decodes (and short prompt tails)
         first, then the longest-pending prefill fills the leftover
@@ -940,6 +1040,10 @@ class RaggedInferenceEngine:
                          key=lambda s: s.pending)
         for seq in pending:
             take = min(seq.pending, budget)
+            if take < seq.pending:
+                # a chunk is cut at whole blocks only: cut inside one, a
+                # query would be shown keys that are not written yet
+                take -= take % self._block
             if take == 0:
                 break
             sched.append((seq, take))
@@ -979,42 +1083,22 @@ class RaggedInferenceEngine:
     def _put(self, span, uids, tokens, as_ids: bool) -> np.ndarray:
         """:meth:`put` under its span; the phases are spans of their own
         (docs/observability.md "Program spans and device scopes")."""
-        cfg = self.config
-        self._admit_tokens(uids, tokens)
-        with annotate("ragged.pack"):
-            sched = self._pack_splitfuse()
-            if not sched:
-                raise ValueError("put() called with no pending tokens")
+        if self._block > 1:
+            return self._put_blocks(span, uids, tokens, as_ids)
+        last_index = {}  # uid -> index in flat batch of its last token
 
-            # ---- validate + allocate for the WHOLE schedule before mutating
-            # any sequence state, so an exhausted pool leaves every descriptor
-            # consistent (seen never advances without its KV being written)
-            needs = self._validate_sched(sched)
-            flat_tokens, flat_slot, flat_pos, last_idx = \
-                self._allocate_and_build(sched, needs)
-            live_pages = self._live_pages_bucket()
-            attrs = self._sched_attrs(sched, len(flat_tokens), live_pages)
-            span.set_metadata(**attrs)
-            last_index = {}  # uid -> index in flat batch of its last token
+        def select(sched, last_idx):
+            # per-slot index of the row whose logits we need (sequences not
+            # in this schedule keep a harmless 0 — their rows are never read)
+            sel_idx = np.zeros((self.config.max_seqs,), np.int32)
             for (seq, take), li in zip(sched, last_idx):
                 seq.seen += take
                 last_index[seq.uid] = int(li)
+                sel_idx[seq.slot] = li
+            return sel_idx
 
-            block_tables = self._host_tables()
-
-            # per-slot index of the row whose logits we need (sequences not
-            # in this schedule keep a harmless 0 — their rows are never read)
-            sel_idx = np.zeros((cfg.max_seqs,), np.int32)
-            for uid, idx in last_index.items():
-                sel_idx[self.seqs[uid].slot] = idx
-
-        with annotate("ragged.dispatch"):
-            if self._step_fn is None:
-                self._step_fn = self._build_step()
-            self._step_ids = None    # never a stale step's, whatever runs
-            logits, self.kv_pool = self._launch(
-                self._step_fn, (flat_tokens, flat_slot, flat_pos,
-                                block_tables, sel_idx), live_pages)
+        sched, attrs, logits = self._schedule_and_launch(span, uids, tokens,
+                                                         select)
         # only what the caller reads comes back: [max_seqs] ids, or the
         # [max_seqs, vocab] logits (which otherwise never leave the device)
         got = self._step_ids if as_ids else logits
@@ -1025,6 +1109,148 @@ class RaggedInferenceEngine:
                                   None if as_ids else got.shape[-1])
             self._record_step_telemetry(sched, got.nbytes, attrs)
         return out
+
+    def _schedule_and_launch(self, span, uids, tokens, select):
+        """A tick from the admission of ``tokens`` to the step's launch,
+        the same for every model: the schedule packed, validated and
+        allocated for WHOLE before any sequence moves, so an exhausted pool
+        leaves every descriptor consistent (``seen`` never advances without
+        its KV being written); ``ragged.put``'s attributes; then
+        ``select(sched, last_idx)``, which moves the scheduled sequences
+        and returns the rows the step's head reads, a slot each; then the
+        launch. Returns (sched, attrs, the step's logits, still on the
+        device)."""
+        self._admit_tokens(uids, tokens)
+        with annotate("ragged.pack"):
+            sched = self._pack_splitfuse()
+            if not sched:
+                raise ValueError("put() called with no pending tokens")
+            needs = self._validate_sched(sched)
+            flat_tokens, flat_slot, flat_pos, last_idx = \
+                self._allocate_and_build(sched, needs)
+            live_pages = self._live_pages_bucket()
+            attrs = self._sched_attrs(sched, len(flat_tokens), live_pages)
+            span.set_metadata(**attrs)
+            sel = select(sched, last_idx)
+            block_tables = self._host_tables()
+        with annotate("ragged.dispatch"):
+            if self._step_fn is None:
+                self._step_fn = self._build_step()
+            self._step_ids = None    # never a stale step's, whatever runs
+            logits, self.kv_pool = self._launch(
+                self._step_fn, (flat_tokens, flat_slot, flat_pos,
+                                block_tables, sel), live_pages)
+        return sched, attrs, logits
+
+    def _put_blocks(self, span, uids, tokens, as_ids: bool) -> np.ndarray:
+        """:meth:`_put` for a model that generates by diffusion over blocks
+        of ``B = attn_block`` positions (SDAR). A sequence's lanes in a
+        pass run from its first position whose K/V is not final (``seen``,
+        a multiple of B) to the end of the block under way, cut at whole
+        blocks where the budget is short: prompt blocks (their K/V final
+        once written), a finished block being committed, and the block
+        under way, whose undecided positions hold the mask id. Every
+        lane's row is written; ``seen`` moves over what is final. A pass
+        that reaches the block under way is a denoise pass: the step
+        decides ``denoise_tokens`` of its masked positions on the device
+        (``_decide``) and the host writes them into the stream; when none
+        is left the next block is opened at once, so the finished block's
+        commit rides the next block's first pass (2B lanes), or, at the
+        stream's limit, a pass of its own.
+
+        Returns, for each uid, in the ids form int32 [len(uids), B]: the
+        generated tokens whose K/V this pass made final (a block at most;
+        ``-1`` at a prompt's positions and where nothing was committed);
+        in the logits form float32 [len(uids), B, vocab]: the logits at the
+        block under way's positions as this pass fed them, NaN for a uid
+        this pass did not denoise."""
+        B = self._block
+        denoised: Dict[int, List[int]] = {}   # uid -> masked positions
+        committed: Dict[int, List[int]] = {}  # uid -> its row of tokens
+
+        def select(sched, last_idx):
+            # the step's head reads each slot's last B lanes: the block
+            # under way wherever the pass reached it
+            sel_rows = np.zeros((self.config.max_seqs, B), np.int32)
+            for (seq, take), li in zip(sched, last_idx):
+                sel_rows[seq.slot] = np.arange(int(li) - B + 1, int(li) + 1)
+                masked, end = self._pass_of(seq, take)
+                if masked:
+                    denoised[seq.uid] = masked
+                if end > max(seq.seen, seq.prompt_len):
+                    committed[seq.uid] = [
+                        seq.tokens[p] if p >= seq.prompt_len else -1
+                        for p in range(end - B, end)]
+                seq.seen = end
+            return sel_rows
+
+        sched, attrs, logits = self._schedule_and_launch(span, uids, tokens,
+                                                         select)
+        got = (self._step_ids,) if as_ids else (self._step_ids, logits)
+        n_bytes = sum(int(a.nbytes) for a in got)
+        with annotate("ragged.fetch", bytes=n_bytes):
+            got = [np.asarray(a) for a in got]
+        with annotate("ragged.rows"):
+            ids = got[0]                                   # [max_seqs, B]
+            decided = 0
+            for uid, masked in denoised.items():
+                seq = self.seqs[uid]
+                blk0 = len(seq.tokens) - B
+                for p in masked:
+                    tok = int(ids[seq.slot, p - blk0])
+                    if tok >= 0:
+                        seq.tokens[p] = tok
+                        decided += 1
+                if not self._masked(seq):
+                    self._open_block(seq)
+            self._decided_last = decided
+            if as_ids:
+                out = np.full((len(uids), B), -1, np.int32)
+            else:
+                out = np.full((len(uids), B, got[1].shape[-1]), np.nan,
+                              np.float32)
+            now = time.perf_counter()
+            for i, uid in enumerate(uids):
+                seq = self.seqs[uid]
+                if as_ids and uid in committed:
+                    out[i] = committed[uid]
+                elif not as_ids and uid in denoised:
+                    out[i] = got[1][seq.slot]
+                if seq.t_admitted is not None and (
+                        uid in denoised or uid in committed):
+                    # the prompt is through and its first block's logits
+                    # are on the host: TTFT, as in _hand_back
+                    self._telemetry.record_request(
+                        ttft_s=now - seq.t_admitted)
+                    seq.t_admitted = None
+            self._record_step_telemetry(sched, n_bytes, attrs)
+            t = self._telemetry
+            if t.enabled:
+                r = t.registry
+                r.counter("inference/denoise_passes").inc(len(denoised))
+                r.counter("inference/blocks_committed").inc(attrs["commits"])
+                r.counter("inference/tokens_decided").inc(decided)
+                r.gauge("inference/block_length").set(B)
+        return out
+
+    def warm_step(self, lanes: int, pages: int) -> None:
+        """Compiles (or loads) and runs the step program of one shape, a
+        lane bucket and a live-page bucket, on an empty batch: every lane
+        is inactive and its writes land on the scratch page. A server
+        calls it at start-up for every shape its traffic can reach, so
+        that no tick compiles."""
+        cfg = self.config
+        if self._step_fn is None:
+            self._step_fn = self._build_step()
+        sel = np.zeros((cfg.max_seqs,) + ((self._block,) if self._block > 1
+                                          else ()), np.int32)
+        tables = fill_tables([], [], cfg.max_seqs, self.max_pages)
+        tok, slot, pos, _ = build_batch([], [], [], int(lanes))
+        logits, self.kv_pool = self._step_fn(
+            self.params, self.kv_pool, jnp.asarray(tok), jnp.asarray(slot),
+            jnp.asarray(pos), jnp.asarray(tables), jnp.asarray(sel),
+            int(pages))
+        jax.block_until_ready(logits)
 
     def _launch(self, step, host, live_pages: int):
         """``ragged.dispatch``'s two kinds of work, a span each: the host
@@ -1103,15 +1329,27 @@ class RaggedInferenceEngine:
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
         prefill = decode = single = 0
+        B = self._block
+        block_seqs = commits = 0
         for seq, take in sched:
             single += take == 1
-            if seq.seen < seq.prompt_len:
+            if B > 1:
+                # lanes inside the prompt's whole blocks, and lanes of
+                # generated blocks (one being committed, the one under way)
+                whole = seq.prompt_len - seq.prompt_len % B
+                n = min(take, max(0, whole - seq.seen))
+                prefill += n
+                decode += take - n
+                masked, end = self._pass_of(seq, take)
+                block_seqs += bool(masked)
+                commits += end > max(seq.seen, seq.prompt_len)
+            elif seq.seen < seq.prompt_len:
                 prefill += take
             elif take == 1:
                 decode += 1
         q_tiles, kv_steps, pages = tile_counts(
             [(take, seq.seen) for seq, take in sched], query_tile(lanes),
-            self.config.kv_block_size)
+            self.config.kv_block_size, B)
         attrs = {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
                  "prefill": prefill, "decode": decode,
                  "free": self.allocator.free_blocks,
@@ -1124,6 +1362,12 @@ class RaggedInferenceEngine:
             attrs["state_layers"] = len(self._state_layers)
             attrs["state_slots"] = len(self.seqs)
             attrs["step_slots"] = single if self._steps_live_slots else 0
+        if B > 1:
+            # block diffusion: the block length, the sequences whose block
+            # under way this pass denoises, the tokens the pass before
+            # decided, and the generated blocks this pass commits
+            attrs.update(block=B, block_seqs=block_seqs,
+                         decided=self._decided_last, commits=commits)
         return attrs
 
     def put_spec(self, uids: Sequence[int], tokens: Sequence[Sequence[int]],
@@ -1154,6 +1398,7 @@ class RaggedInferenceEngine:
         stripped before the raise, so the recovery retry (plain ``put``
         with empty chunks) sees exactly put()'s admitted state."""
         kv_cache.refuse_without_snapshot(self.model.config, "put_spec")
+        self._refuse_in_blocks("put_spec (speculation)")
         with annotate("ragged.put") as span:
             return self._put_spec(span, uids, tokens, drafts)
 
@@ -1325,6 +1570,7 @@ class RaggedInferenceEngine:
         the longest matching prefix and trims the rest. k is pow2-bucketed
         so the jit cache stays O(log k) wide."""
         kv_cache.refuse_without_snapshot(self.model.config, "speculative verification")
+        self._refuse_in_blocks("speculative verification")
         cfg = self.config
         sched = [(self.seqs[u], len(c)) for u, c in zip(uids, chains)]
         # validate BEFORE touching seq.tokens: a failed round must not
@@ -1410,6 +1656,7 @@ class RaggedInferenceEngine:
         in-chunk EOS must first ``trim(uid, ...)`` back to the EOS
         position, or the post-EOS tokens become permanent context."""
         cfg = self.config
+        self._refuse_in_blocks("decode_steps (one token a step)")
         if k < 1:
             raise ValueError(f"decode_steps needs k >= 1, got {k}")
         # validate every uid before allocating anything (same two-phase
@@ -1503,6 +1750,7 @@ class RaggedInferenceEngine:
         same put()/decode_steps machinery as generate(); the uid is
         flushed when the stream ends — including early consumer breaks
         and mid-prefill failures (no slot/block leak)."""
+        self._refuse_in_blocks("stream (use generate, or put)")
         logits = self._put_logits([uid], [list(prompt)])
         try:
             while np.isnan(logits[0]).any():
@@ -1538,6 +1786,9 @@ class RaggedInferenceEngine:
         tokens per device call. Greedy when config.temperature == 0, else
         temperature/top-k/top-p sampling (chunk-invariant streams).
         Returns uid -> generated tokens."""
+        if self._block > 1:
+            return self._generate_blocks(prompts, max_new_tokens,
+                                         eos_token_id)
         done: Dict[int, List[int]] = {u: [] for u in prompts}
         first = self._prefill_first(prompts, done)
 
@@ -1564,6 +1815,35 @@ class RaggedInferenceEngine:
             live = nxt
         for u in done:
             done[u] = done[u][:max_new_tokens]
+        self.flush(list(prompts))
+        return done
+
+    def _generate_blocks(self, prompts, max_new_tokens: int,
+                         eos_token_id: Optional[int]) -> Dict[int, List[int]]:
+        """:meth:`generate` for a model that generates by blocks: passes
+        until every stream has its tokens committed (greedy; the last block
+        is computed whole and cut to ``max_new_tokens``, and at an EOS)."""
+        done: Dict[int, List[int]] = {u: [] for u in prompts}
+        for u, p in prompts.items():
+            self.limit_stream(u, len(p) + max_new_tokens)
+        with annotate("ragged.put") as span:
+            rows = self._put_blocks(span, list(prompts),
+                                    [list(p) for p in prompts.values()], True)
+        live = list(prompts)
+        while True:
+            for u, row in zip(live, rows):
+                done[u].extend(int(t) for t in row if t >= 0)
+            live = [u for u in live if self.seqs[u].pending
+                    and len(done[u]) < max_new_tokens
+                    and (eos_token_id is None or eos_token_id not in done[u])]
+            if not live:
+                break
+            with annotate("ragged.put") as span:
+                rows = self._put_blocks(span, live, [[] for _ in live], True)
+        for u, toks in done.items():
+            if eos_token_id is not None and eos_token_id in toks:
+                toks = toks[:toks.index(eos_token_id) + 1]
+            done[u] = toks[:max_new_tokens]
         self.flush(list(prompts))
         return done
 
@@ -1614,6 +1894,7 @@ class RaggedInferenceEngine:
         ``generate()`` — acceptance rate only changes how many device
         round trips it takes. Stats land in ``self.spec_stats``.
         """
+        self._refuse_in_blocks("generate_speculative")
         if self.config.temperature != 0.0:
             raise NotImplementedError(
                 "speculative decoding is greedy-only (temperature == 0); "
@@ -1723,6 +2004,9 @@ class RaggedInferenceEngine:
 
         kv_bits = self._kv_bits
         use_writer = self._writes_pages
+        # block diffusion: the mask's rule, an argument only where it is
+        # not today's (with attn_block 1 the programs are the ones they were)
+        blockwise = {"attn_block": self._block} if self._block > 1 else {}
 
         def _paged_attn_sharded(q, kp, vp, tables, positions, slots, work,
                                 live_pages, window, k_scale=None,
@@ -1875,11 +2159,13 @@ class RaggedInferenceEngine:
                                 q, own["k"], own["v"], block_tables,
                                 positions, seq_slots=slots, work=work,
                                 scale=c.attn_scale, live_pages=live_pages,
-                                window=window, interpret=interp, **quant)
+                                window=window, interpret=interp, **quant,
+                                **blockwise)
                         else:
                             attn = paged_attention_reference(
                                 q, own["k"], own["v"], tables, positions,
-                                scale=c.attn_scale, window=window, **quant)
+                                scale=c.attn_scale, window=window, **quant,
+                                **blockwise)
                     attn = model._attn_out(attn.astype(x.dtype), lp)
                 return after_mixer(x, attn, lp), own
 
@@ -1972,6 +2258,7 @@ class RaggedInferenceEngine:
         self._count_call_leaves()
         core = self._core
         model = self.model
+        c = model.config
 
         def step(params, pools, tokens, slots, positions, block_tables,
                  sel_idx, live_pages):
@@ -1989,6 +2276,27 @@ class RaggedInferenceEngine:
                 logits = model._head(params, x_sel[None, :])[0]    # [S, vocab]
                 ids = sample(logits, None, 0.0, 0, 1.0)            # [S] int32
             return logits, ids, pools
+
+        if self._block > 1:
+            mask_id, n_decide = c.mask_token_id, c.denoise_tokens
+
+            def step(params, pools, tokens, slots, positions, block_tables,
+                     sel_rows, live_pages):
+                # block diffusion: the head on each slot's block of lanes
+                # ([S, B] rows), and under ``decide`` the choice of which
+                # masked positions this pass decides: [S, B] ids come back,
+                # not [S, B, vocab] logits
+                x, pools = core(params, pools, tokens, slots, positions,
+                                block_tables, live_pages)
+                with jax.named_scope("head"):
+                    x_sel = x[sel_rows.reshape(-1)]            # [S * B, d]
+                    logits = model._head(params, x_sel[None, :])[0] \
+                        .reshape(sel_rows.shape + (-1,))       # [S, B, vocab]
+                    with jax.named_scope("decide"):
+                        ids = decide_masked(
+                            logits, tokens[sel_rows] == mask_id, n_decide,
+                            mask_id)
+                return logits, ids, pools
 
         return _Step(self, jax.jit(step, donate_argnums=(1,),
                                    static_argnums=(7,)))

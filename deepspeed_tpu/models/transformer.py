@@ -126,6 +126,21 @@ class TransformerConfig:
     # prenorm=False, which is norm(x + sub(x))); the final norm stays
     branch_norm: bool = False
     qk_norm: bool = False             # RMSNorm over the whole projected q, k
+    # Qwen3's form of it: over each head's head_dim numbers, one gain
+    # vector [head_dim] shared by the heads (needs qk_norm)
+    qk_norm_heads: bool = False
+    # a head's size where it is not d_model / n_heads (Qwen3-MoE: 32 heads
+    # of 128 over a hidden size of 2048); None = d_model // n_heads
+    head_size: Optional[int] = None
+    # Block diffusion (SDAR): attention is causal over blocks of attn_block
+    # positions and sees both ways inside a block, so the query at position
+    # p sees keys up to p | (attn_block - 1); a power of two, 1 = causal.
+    # The generation settings the serving engine reads beside it: the id a
+    # position not yet decided holds, and how many masked positions of a
+    # block one denoise pass decides (those of highest confidence)
+    attn_block: int = 1
+    mask_token_id: int = -1
+    denoise_tokens: int = 1
     # Looped stacks (Ouro / LoopLM): the whole stack of n_layers blocks runs
     # total_ut_steps times a token over ONE set of weights, the final norm
     # after every pass (the normed output of a pass is the next pass's
@@ -178,6 +193,20 @@ class TransformerConfig:
                 self.linear_n_v_heads = self.linear_n_v_heads \
                     or self.linear_n_k_heads
                 assert self.linear_n_v_heads % self.linear_n_k_heads == 0
+        if self.qk_norm_heads and not self.qk_norm:
+            raise ValueError("qk_norm_heads is a form of qk_norm")
+        if self.attn_block < 1 or self.attn_block & (self.attn_block - 1):
+            raise ValueError(
+                f"attn_block {self.attn_block} is not a power of two")
+        if self.attn_block > 1 and (
+                not self.causal or self.attn_windows is not None
+                or self.state_layers or self.total_ut_steps > 1
+                or self.position == "alibi" or self.mask_token_id < 0
+                or not 1 <= self.denoise_tokens <= self.attn_block):
+            raise ValueError(
+                "attn_block > 1 (block diffusion) needs a causal model of "
+                "softmax-attention layers without sliding windows, a "
+                "mask_token_id and 1 <= denoise_tokens <= attn_block")
         if self.total_ut_steps < 1:
             raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
         if self.total_ut_steps > 1 and (
@@ -212,7 +241,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         """Indices of the layers of that kind ("full" holds the KV pages,
@@ -263,7 +292,8 @@ class TransformerConfig:
         if self.attn_o_bias:
             attn += d
         if self.qk_norm:
-            attn += (self.n_heads + self.n_kv_heads) * hd
+            attn += 2 * hd if self.qk_norm_heads \
+                else (self.n_heads + self.n_kv_heads) * hd
         mixers = len(self.layers_of("full")) * attn
         n_lin = len(self.layers_of("linear"))
         if n_lin:   # the gated delta rule's leaves (_init_linear)
@@ -418,8 +448,11 @@ class Transformer:
         if c.attn_o_bias:
             attn["bo"] = jnp.zeros((nf, c.d_model), dtype)
         if c.qk_norm:
-            attn["q_norm_w"] = jnp.ones((nf, c.n_heads * hd), dtype)
-            attn["k_norm_w"] = jnp.ones((nf, c.n_kv_heads * hd), dtype)
+            per = c.qk_norm_heads
+            attn["q_norm_w"] = jnp.ones(
+                (nf, hd if per else c.n_heads * hd), dtype)
+            attn["k_norm_w"] = jnp.ones(
+                (nf, hd if per else c.n_kv_heads * hd), dtype)
         if c.use_bias:
             layers["b_up"] = jnp.zeros((n, c.d_ff), dtype)
             layers["b_down"] = jnp.zeros((n, c.d_model), dtype)
@@ -703,7 +736,19 @@ class Transformer:
                         * jnp.arange(skv, dtype=jnp.float32)[None, None, :])
 
             new_kv = None
-            if kv_cache is not None:
+            if c.attn_block > 1:
+                # block diffusion: causal over blocks, both ways inside one
+                # (the whole sequence at once; generation is the ragged
+                # engine's, which writes a block before it is read)
+                if kv_cache is not None or self._seq_size > 1:
+                    raise NotImplementedError(
+                        "attn_block > 1 has no dense KV cache and no "
+                        "sequence-parallel attention: serve it through "
+                        "RaggedInferenceEngine")
+                attn = dot_product_attention(q, kk, vv, causal=True,
+                                             scale=c.attn_scale,
+                                             attn_block=c.attn_block)
+            elif kv_cache is not None:
                 ck, cv, cache_pos = kv_cache
                 ck = jax.lax.dynamic_update_slice_in_dim(ck, kk, cache_pos, axis=1)
                 cv = jax.lax.dynamic_update_slice_in_dim(cv, vv, cache_pos, axis=1)
@@ -811,11 +856,15 @@ class Transformer:
         q, kk, vv = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
         if c.qkv_bias:
             q, kk, vv = q + lp["bq"], kk + lp["bk"], vv + lp["bv"]
-        if c.qk_norm:   # over the whole projection, heads unsplit
+        if c.qk_norm and not c.qk_norm_heads:
+            # over the whole projection, heads unsplit
             q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
             kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
         q, kk, vv = (heads(q, c.n_heads), heads(kk, c.n_kv_heads),
                      heads(vv, c.n_kv_heads))
+        if c.qk_norm_heads:   # a head at a time, one gain for all of them
+            q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
+            kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
         if c.position == "rope":
             # apply_rotary no-ops the partial slice when rotary_dim == hd
             q = apply_rotary(q, angles, positions, rotary_dim=c.rotary_dim,

@@ -44,7 +44,7 @@ def dot_product_attention(q, k, v, *, causal: bool = True,
                           bias: Optional[jnp.ndarray] = None,
                           scale: Optional[float] = None,
                           logits_dtype=jnp.float32,
-                          window: int = 0):
+                          window: int = 0, attn_block: int = 1):
     """Reference attention. q: [b, sq, hq, d]; k/v: [b, skv, hkv, d].
 
     Softmax in fp32 (the reference kernels do the same via float accumulators
@@ -52,7 +52,9 @@ def dot_product_attention(q, k, v, *, causal: bool = True,
     the *end* of the KV sequence so decode (sq=1, skv=cache_len) works.
     ``bias``: optional additive logit bias broadcastable to [b, h, sq, skv]
     (ALiBi). ``window`` > 0 bands causal attention to the trailing
-    ``window`` keys (k > q - window).
+    ``window`` keys (k > q - window). ``attn_block`` > 1 (a power of two;
+    block diffusion) makes the causal mask one over blocks of that many
+    positions: the query at position p sees keys up to p | (attn_block - 1).
     """
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -65,6 +67,8 @@ def dot_product_attention(q, k, v, *, causal: bool = True,
         logits = logits + bias.astype(logits_dtype)
     if causal:
         q_pos = jnp.arange(sq)[:, None] + (skv - sq)
+        if attn_block > 1:
+            q_pos = q_pos | (attn_block - 1)
         k_pos = jnp.arange(skv)[None, :]
         causal_mask = q_pos >= k_pos  # [sq, skv]
         if window > 0:

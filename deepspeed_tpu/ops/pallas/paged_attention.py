@@ -38,7 +38,12 @@ with the tile's own trip count, whatever the page bucket. The chunk's
 pages ([hkv, block, hd] slabs, K and V) land side by side in a
 [hkv, 256, hd] VMEM buffer, so a chunk is one batched MXU matmul of the
 tile's ``group * Tq`` rows (the GQA group folded into the rows) against
-it, with the causal mask and the window's band by ``pos0 + row``. Online
+it, with the causal mask and the window's band by ``pos0 + row``. Under
+block diffusion (``attn_block`` > 1, a power of two) the mask is causal
+over blocks of that many positions: a query at position p sees keys up to
+``p | (attn_block - 1)``, its own block whole, and a tile's last chunk is
+the one that holds its last row's block; with ``attn_block`` 1 not one
+operation differs. Online
 softmax in VMEM scratch (flash-2 style, as ops/pallas/flash_attention.py).
 bf16 operands, fp32 softmax and accumulation.
 
@@ -109,7 +114,8 @@ def _dequantize(q, k, v, ks, vs, kv_bits: int):
 
 def _lane_kernel(*refs,
             scale: float, block: int, hkv: int, group: int, ppc: int,
-            num_scalars: int, window: int = 0, kv_bits: int = 0):
+            num_scalars: int, window: int = 0, kv_bits: int = 0,
+            attn_block: int = 1):
     # scalar-prefetch refs lead; positions is always the last of them.
     # kv_bits > 0 = quantized pool: 2*ppc extra per-page SCALE inputs
     # follow the payload pages, and the payload dequantizes in VMEM
@@ -133,6 +139,8 @@ def _lane_kernel(*refs,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     pos = pos_ref[t]
+    if attn_block > 1:       # the last key this lane sees: its block's
+        pos = pos | (attn_block - 1)
     run = c * span <= pos  # chunk holds at least one visible row
     if window > 0:
         # banded: rows <= pos - window are invisible; skip chunks whose
@@ -255,27 +263,38 @@ def work_list(slots, positions, n_seqs: int, tile_rows: int | None = None):
                       zero(slots[at]), zero(positions[at])])
 
 
-def tile_counts(runs, tile_rows: int, block: int) -> tuple:
+def _check_attn_block(attn_block: int, block: int, window: int) -> None:
+    if attn_block > 1 and (attn_block & (attn_block - 1) or block % attn_block
+                           or window > 0):
+        raise ValueError(
+            f"attn_block {attn_block} must be a power of two that divides "
+            f"the page size {block}, without a window (got {window})")
+
+
+def tile_counts(runs, tile_rows: int, block: int, attn_block: int = 1
+                ) -> tuple:
     """(tiles, KV steps, pages written) :func:`work_list` and the two
     kernels make of a packed batch, on the host: ``runs`` is (lanes, first
     position) of each sequence in batch order. A KV step is one chunk of
     one tile of the paged kernel (counted without a window); a page
     written is one page slab, K and V, that :func:`write_kv_pages` moves
-    for a tile (a page two tiles share counts for each)."""
+    for a tile (a page two tiles share counts for each). ``attn_block``:
+    the paged kernel's, whose last chunk holds the last row's block."""
     span = chunk_pages(block) * block
     tiles = steps = pages = lane = 0
     for take, pos in runs:
         while take > 0:
             n = min(take, tile_rows - lane % tile_rows)
             tiles += 1
-            steps += (pos + n - 1) // span + 1
+            steps += ((pos + n - 1) | (attn_block - 1)) // span + 1
             pages += (pos + n - 1) // block - pos // block + 1
             lane, pos, take = lane + n, pos + n, take - n
     return tiles, steps, pages
 
 
 def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
-                 ppc: int, tq: int, window: int, kv_bits: int, last_page: int):
+                 ppc: int, tq: int, window: int, kv_bits: int, last_page: int,
+                 attn_block: int = 1):
     """One grid step = one query tile; its KV chunks in a loop whose trip
     count is the tile's own, pages copied by hand from the pool in HBM,
     two chunks in flight."""
@@ -291,6 +310,8 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
     r0, n = work_ref[1, i], work_ref[2, i]
     slot, pos0 = work_ref[3, i], work_ref[4, i]
     last = pos0 + n - 1
+    if attn_block > 1:       # the last row sees its block to the end
+        last = last | (attn_block - 1)
     low = jnp.maximum(pos0 - (window - 1), 0) if window > 0 else 0
     lo_page = low // block
     hi_page = jnp.minimum(last // block, last_page)
@@ -338,7 +359,8 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
         r = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) % tq
         key = c * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         qpos = pos0 + (r - r0)
-        visible = (r >= r0) & (r < r0 + n) & (key <= qpos)
+        seen_to = qpos | (attn_block - 1) if attn_block > 1 else qpos
+        visible = (r >= r0) & (r < r0 + n) & (key <= seen_to)
         if window > 0:
             visible = visible & (key > qpos - window)
         s = jnp.where(visible[None], s, NEG_INF)
@@ -375,9 +397,11 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
 # a layer (tracing a chunk's 64 copy descriptors is most of what a step
 # program's lowering costs)
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "ppc", "tq", "last_page", "window", "kv_bits", "interpret"))
+    "scale", "ppc", "tq", "last_page", "window", "kv_bits", "interpret",
+    "attn_block"))
 def _tiled(q, k_pool, v_pool, tables, positions, slots, work, *, scale, ppc,
-           tq, last_page, window, k_scale, v_scale, kv_bits, interpret):
+           tq, last_page, window, k_scale, v_scale, kv_bits, interpret,
+           attn_block=1):
     """The grid over query tiles (module docstring)."""
     T, hq, hd = q.shape
     _, hkv, block, hd_p = k_pool.shape
@@ -408,7 +432,7 @@ def _tiled(q, k_pool, v_pool, tables, positions, slots, work, *, scale, ppc,
     out = pl.pallas_call(
         functools.partial(_tile_kernel, scale=scale, block=block, ppc=ppc,
                           tq=tq, window=window, kv_bits=kv_bits if quant else 0,
-                          last_page=last_page),
+                          last_page=last_page, attn_block=attn_block),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(work.shape[1],),
@@ -434,7 +458,7 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
                     window: int = 0,
                     k_scale=None, v_scale=None, kv_bits: int = 8,
                     tile_rows: int | None = None,
-                    interpret: bool = False):
+                    interpret: bool = False, attn_block: int = 1):
     """Attention of ragged query lanes over a paged KV pool. See module
     docstring for the layout contract. Causal by construction: token t sees
     pool rows with position <= positions[t] along its own page list.
@@ -458,6 +482,11 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     the chunk its first row's band reaches, so compute and traffic are
     O(window), not O(context).
 
+    ``attn_block`` > 1 (static, a power of two that divides ``block``):
+    causal over blocks of that many positions, token t sees rows up to
+    ``positions[t] | (attn_block - 1)`` (module docstring); the caller has
+    written the whole block's rows. Not with a window.
+
     ``k_scale``/``v_scale`` [n_pages, hkv, block] switch the pools to
     quantized storage (``ops/quantizer.quantize_kv``; int8 payload, or
     nibble-packed uint8 [..., hd//2] at ``kv_bits=4``): a page's scales are
@@ -475,6 +504,7 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
             raise Int4KVKernelUnsupported()
     max_pages = tables.shape[1]
     assert hq % hkv == 0
+    _check_attn_block(attn_block, block, window)
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
     walk_pages = max_pages if live_pages is None \
         else max(1, min(live_pages, max_pages))
@@ -484,6 +514,8 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
                   k_scale=k_scale, v_scale=v_scale,
                   kv_bits=int(kv_bits),  # dslint: disable=host-sync -- kv_bits is a static Python int kernel parameter, never a tracer
                   interpret=interpret)
+    if attn_block > 1:     # a key of the jitted kernel only where it is used
+        common["attn_block"] = int(attn_block)  # dslint: disable=host-sync -- attn_block is a static Python int kernel parameter, never a tracer
     # Mosaic refuses a hand-rolled copy of a slab under 128 lanes wide, so
     # the tiled grid takes pools whose every leaf has whole lanes: head_dim
     # (a packed one too), and a quantized pool's scale rows [.., block]
@@ -504,7 +536,8 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
 
 
 def _lane_grid(q, k_pool, v_pool, tables, positions, seq_slots, *, scale, ppc,
-               walk_pages, window, k_scale, v_scale, kv_bits, interpret):
+               walk_pages, window, k_scale, v_scale, kv_bits, interpret,
+               attn_block=1):
     """The grid (lanes, chunks of the page bucket): every page a BlockSpec
     input, for pools with a leaf under 128 lanes wide."""
     T, hq, hd = q.shape
@@ -534,6 +567,7 @@ def _lane_grid(q, k_pool, v_pool, tables, positions, seq_slots, *, scale, ppc,
             # live page for the same dedup effect.
             tbl, pos = s[0], s[-1]
             j = jnp.minimum(c * ppc + i, max_pages - 1)
+            # (a block of attn_block positions lies inside one page)
             j = jnp.minimum(j, pos[t] // block)
             if window > 0:
                 lo = jnp.maximum(pos[t] - (window - 1), 0) // block
@@ -574,7 +608,8 @@ def _lane_grid(q, k_pool, v_pool, tables, positions, seq_slots, *, scale, ppc,
         functools.partial(_lane_kernel, scale=scale, block=block, hkv=hkv,
                           group=group, ppc=ppc, num_scalars=len(scalars),
                           window=window,
-                          kv_bits=kv_bits if quant else 0),
+                          kv_bits=kv_bits if quant else 0,
+                          attn_block=attn_block),
         out_shape=jax.ShapeDtypeStruct((T, hkv, group, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -789,11 +824,13 @@ def write_kv_pages(k_leaf, v_leaf, new_k, new_v, tables, work, *,
 
 def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
                               scale=None, window: int = 0,
-                              k_scale=None, v_scale=None, kv_bits: int = 8):
+                              k_scale=None, v_scale=None, kv_bits: int = 8,
+                              attn_block: int = 1):
     """jnp reference (gather-based) with identical semantics — the numerics
     oracle for the kernel and the off-TPU fallback formulation.
     ``window`` > 0 bands attention to the trailing ``window`` positions
-    (sliding-window serving: k > pos - window).
+    (sliding-window serving: k > pos - window). ``attn_block`` > 1: a lane
+    sees rows up to ``pos | (attn_block - 1)`` (block diffusion).
 
     ``k_scale``/``v_scale`` [n_pages, hkv, block] switch the pools to
     quantized storage (``ops/quantizer.quantize_kv``): int8 payloads —
@@ -806,6 +843,8 @@ def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
     n_pages, hkv, block, _ = k_pool.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
     group = hq // hkv
+    _check_attn_block(attn_block, block, window)
+    seen_to = positions | (attn_block - 1) if attn_block > 1 else positions
     if k_scale is not None:
         _check_quant_geometry(k_pool, hd, kv_bits)
         # gather first ([T, max_pages, hkv, block, hd_p]), then dequant
@@ -824,7 +863,7 @@ def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
         logits = jnp.einsum("thd,tkhd->thk", q.astype(jnp.float32),
                             keys) * scale
         kv_pos = jnp.arange(keys.shape[1])[None, :]
-        visible = kv_pos <= positions[:, None]
+        visible = kv_pos <= seen_to[:, None]
         if window > 0:
             visible = visible & (kv_pos > positions[:, None] - window)
         logits = jnp.where(visible[:, None, :], logits, NEG_INF)
@@ -840,7 +879,7 @@ def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
     logits = jnp.einsum("thd,tkhd->thk", q.astype(jnp.float32),
                         keys.astype(jnp.float32)) * scale
     kv_pos = jnp.arange(keys.shape[1])[None, :]
-    visible = kv_pos <= positions[:, None]
+    visible = kv_pos <= seen_to[:, None]
     if window > 0:
         visible = visible & (kv_pos > positions[:, None] - window)
     logits = jnp.where(visible[:, None, :], logits, NEG_INF)

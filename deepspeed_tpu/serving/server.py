@@ -37,6 +37,17 @@ taken here. ``_greedy_tokens`` reads either form, told apart by the
 result's rank (docs/serving.md "The engine's result"). Sampling belongs
 in the engine's own ``generate``/``stream`` paths.
 
+An engine whose model generates by diffusion over blocks
+(``engine.block_length`` > 1, SDAR) extends its sequences itself: the
+server feeds it a stream (with the length it should reach,
+``limit_stream``) and then empty chunks, and a tick brings back int32
+``[fed, block_length]``: for each sequence the tokens of the block whose
+K/V that pass made final, ``-1`` where there is none. A tick so yields no
+token or a block's tokens a request, delivered together and cut at
+``max_new_tokens`` (the last block is computed whole). Pages are reserved
+as for any request: a page holds whole blocks, so the block that holds the
+last token lies inside the pages ``prompt + max_new_tokens`` is charged.
+
 Telemetry: per-request spans (queue_wait, TTFT, tokens/s — see
 :class:`~deepspeed_tpu.telemetry.spans.RequestStats`) plus queue-depth /
 KV-occupancy gauges and admitted/rejected/preempted counters, all
@@ -233,6 +244,14 @@ class ServingEngine:
         # disaggregated hand-off at import time, so fail at construction.
         self._spec_on = bool(getattr(config, "speculative", False)) and \
             hasattr(engine, "put_spec") and hasattr(engine, "draft_tokens")
+        # tokens a sequence's tick can yield together: the model's block
+        # length where it generates by diffusion over blocks, else 1
+        self._block = int(getattr(engine, "block_length", 1) or 1)
+        if self._block > 1 and self._spec_on:
+            raise ValueError(
+                "serving.speculative is not supported for a model that "
+                "generates by diffusion over blocks (block_length="
+                f"{self._block}): its step already decides several tokens")
         self._spec_ema_by_class: Dict[int, float] = {}
         want_quant = str(getattr(config, "kv_quant", "none"))
         have_quant = str(getattr(engine.config, "kv_quant", "none"))
@@ -1163,7 +1182,10 @@ class ServingEngine:
             return True
         with annotate("serve.emit") as span:
             accepted = self._verify_drafts(verified)
-            chosen = self._greedy_tokens(out)
+            if self._block > 1:
+                accepted, chosen = self._committed_blocks(uids, out)
+            else:
+                chosen = self._greedy_tokens(out)
             with self._lock:
                 handoffs, emissions, finished = self._dispatch(uids, chosen,
                                                                accepted)
@@ -1470,6 +1492,10 @@ class ServingEngine:
                 toks.append(req.prompt + req.tokens)
                 drafts.append([])
                 prefill_tokens += len(req.prompt) + len(req.tokens)
+                if self._block > 1:
+                    # the engine opens blocks itself, up to this length
+                    self._engine.limit_stream(
+                        uid, len(req.prompt) + req.max_new_tokens)
             elif seq.pending > 0:
                 uids.append(uid)
                 toks.append([])
@@ -1658,10 +1684,7 @@ class ServingEngine:
             tick_acc += matched
             if req is None or seq is None:      # evicted mid-tick
                 continue
-            emitted = [int(x) for x in a[:matched + 1]]
-            emitted = emitted[:max(0, req.max_new_tokens - len(req.tokens))]
-            if req.eos_token_id is not None and req.eos_token_id in emitted:
-                emitted = emitted[:emitted.index(req.eos_token_id) + 1]
+            emitted = self._cut_run(req, [int(x) for x in a[:matched + 1]])
             # rewind to the validated context: fed = chain, validated =
             # the pending token + accepted (and emitted) proposals
             keep = seq.seen - len(chain) + len(emitted)
@@ -1726,6 +1749,34 @@ class ServingEngine:
         return [-1 if np.isnan(row[0]) else int(np.argmax(row))
                 for row in out]
 
+    @staticmethod
+    def _cut_run(req: Request, run: List[int]) -> List[int]:
+        """A run of tokens a tick yields ``req`` together (a speculative
+        chain's accepted part, a committed block), cut at the request's
+        ``max_new_tokens`` and after its EOS."""
+        run = run[:max(0, req.max_new_tokens - len(req.tokens))]
+        if req.eos_token_id is not None and req.eos_token_id in run:
+            run = run[:run.index(req.eos_token_id) + 1]
+        return run
+
+    def _committed_blocks(self, uids, out) -> Tuple[Dict[int, List[int]],
+                                                    List[int]]:
+        """A block engine's result, int32 [fed, block_length] (the tokens
+        whose K/V the pass made final, ``-1`` where none), in
+        :meth:`_dispatch`'s terms: uid -> its run of tokens, cut as a
+        speculative run is (:meth:`_cut_run`: the last block is computed
+        whole and delivered up to the limit), and a chosen id a uid that
+        is ``-1`` where the tick yields it nothing."""
+        with self._lock:
+            reqs = {uid: self._live.get(uid) for uid in uids}
+        runs: Dict[int, List[int]] = {}
+        for uid, row in zip(uids, np.asarray(out)):
+            if reqs[uid] is not None:
+                run = self._cut_run(reqs[uid], [int(t) for t in row if t >= 0])
+                if run:
+                    runs[uid] = run
+        return runs, [runs[uid][-1] if uid in runs else -1 for uid in uids]
+
     def _dispatch(self, uids, chosen: List[int],
                   accepted: Optional[Dict[int, List[int]]] = None
                   ) -> Tuple[List[Request], List[Tuple[Request, int]],
@@ -1746,10 +1797,17 @@ class ServingEngine:
             req = self._live.get(uid)
             if req is None or tok < 0:
                 continue                      # evicted mid-tick / prefilling
+            if req.state is RequestState.PREFILL:
+                req.transition(RequestState.DECODE)
+                if req.t_first_token is None:
+                    req.t_first_token = now
+                begin_request_segment(req, "decode",
+                                      track=self.replica_id)
             if accepted and uid in accepted:
-                # speculative chain: apply the whole accepted run (tokens
-                # delivered in order, before any terminal transition —
-                # the stream() drain contract holds per token)
+                # a run of tokens (a speculative chain's accepted part, or
+                # a committed block, each cut by _cut_run): applied whole
+                # (tokens delivered in order, before any terminal
+                # transition — the stream() drain contract holds per token)
                 emitted = accepted[uid]
                 self._note_served_version(req)
                 for tok in emitted:
@@ -1762,12 +1820,6 @@ class ServingEngine:
                             and emitted[-1] == req.eos_token_id)):
                     finished.append(uid)
                 continue
-            if req.state is RequestState.PREFILL:
-                req.transition(RequestState.DECODE)
-                if req.t_first_token is None:
-                    req.t_first_token = now
-                begin_request_segment(req, "decode",
-                                      track=self.replica_id)
             self._note_served_version(req)
             req.tokens.append(tok)
             req._pending_token = tok

@@ -765,7 +765,10 @@ def compile_cell_step(config: str, device_sharding, T: int, live_pages: int,
         _place(params, device_sharding), _place(pool, device_sharding),
         lanes, lanes, lanes,
         _sds((e["max_seqs"], eng.max_pages), jnp.int32, device_sharding),
-        _sds((e["max_seqs"],), jnp.int32, device_sharding),
+        # the rows the head reads: one a slot, or a block's (block diffusion)
+        _sds((e["max_seqs"],) + ((eng.block_length,)
+                                 if eng.block_length > 1 else ()),
+             jnp.int32, device_sharding),
         live_pages).compile()
     return compiled, model.config, e
 
@@ -1103,6 +1106,30 @@ def test_mixtral_step_reads_expert_stacks_in_place(one_chip, on_tpu, T):
                      for dims in re.findall(r"bf16\[([\d,]+)\]", results))]
     assert not copied, copied
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert _device_bytes(compiled) < 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("T", [256, 1024], ids=["lanes256", "lanes1024"])
+def test_sdar_step_compiles_under_the_block_mask(one_chip, on_tpu, T):
+    """Rehearsal 3 for the cell ``sdar-30b-a3b.answer``: the 7-layer step
+    with 128 experts a layer, the paged kernel under the block-causal mask
+    (``attn_block`` 4: Mosaic takes the ``|`` in the mask and in the trip
+    count), the head on a block of lanes a slot and the choice under
+    ``decide``; the expert stacks read in place, and the program beside its
+    9.97 GB of weights and 0.94 GB of pages inside the chip's memory."""
+    with jax.default_matmul_precision("default"):
+        compiled, c, e = compile_cell_step("sdar-30b-a3b", one_chip, T, 64)
+    hlo = compiled.as_text()
+    assert (c.n_layers, c.n_experts, c.top_k, c.attn_block) == (7, 128, 8, 4)
+    # beside the paged kernel and the row writer the module holds XLA:TPU's
+    # own kernels for the experts' products (custom calls too)
+    assert len([l for l in _custom_calls(hlo)
+                if _KERNEL_RESULT.search(l)]) == c.n_layers
+    assert len(_writer_calls(hlo)) == c.n_layers
+    assert "decide" in hlo
+    mem = compiled.memory_analysis()
+    assert 9.9e9 < mem.argument_size_in_bytes < 11.1e9
+    assert mem.temp_size_in_bytes < 2.0e9
     assert _device_bytes(compiled) < 15.75 * 2 ** 30
 
 
