@@ -295,6 +295,11 @@ class RaggedInferenceEngine:
                  and k in self.params.get("layers", {})]
         self.expert_bytes_in_place = sum(
             a.size * a.dtype.itemsize for a in whole)
+        # routed experts whose stacks the step reads in place: how many a
+        # token takes, how many a layer holds and their matrices' shape
+        # (what _expert_product needs)
+        self._routed = (c.top_k, c.n_experts, c.d_model, c.d_ff) \
+            if self._experts_in_place and model.stacked_operands else None
         if self._telemetry.enabled:
             self._telemetry.registry.gauge(
                 "inference/expert_bytes_in_place").set(
@@ -363,9 +368,13 @@ class RaggedInferenceEngine:
         # decode-heavy step compiles + runs at the smallest fitting width
         self._buckets = [b for b in (64, 256, 1024) if b < cfg.token_budget] \
             + [cfg.token_budget]
+        # the rule's outcome a lane bucket (None: no experts in place)
+        products = self._routed and {
+            b: self._expert_product(b) for b in self._buckets}
         log_dist(f"RaggedInferenceEngine: budget={cfg.token_budget} "
                  f"blocks={cfg.n_kv_blocks}x{cfg.kv_block_size} "
                  f"expert_bytes_in_place={self.expert_bytes_in_place} "
+                 f"expert_products={products} "
                  f"passes={self._passes} periods={self._periods} "
                  f"kv_bytes_per_token={self.kv_bytes_per_token}")
 
@@ -401,6 +410,21 @@ class RaggedInferenceEngine:
         return (self.attention_path != "gather" and self._tp_size == 1
                 and not self._kv_bits
                 and self.model.config.head_dim % LANES == 0)
+
+    def _expert_product(self, lanes: int) -> Optional[str]:
+        """Which grouped product the ``lanes``-wide step program holds for
+        its routed experts, ``"kernel"`` or ``"ragged_dot"``
+        (``parallel/moe.expert_product``, the rule ``no_drop_moe`` traces
+        by: from the path and the product's static shape); None for a
+        model without routed experts, and where the expert stacks are
+        sharded and the step slices them (GSPMD's ``ragged_dot``)."""
+        if self._routed is None:
+            return None
+        from ..parallel.moe import expert_product
+
+        top_k, *experts = self._routed
+        return expert_product(self.attention_path, lanes * top_k, *experts,
+                              self.config.dtype)
 
     @property
     def _steps_live_slots(self) -> bool:
@@ -1325,7 +1349,10 @@ class RaggedInferenceEngine:
         (``state_layers``), the slots whose state is live, and the entries
         of one lane (a decode token, or a prompt's last) that the step
         kernel of their kind serves in each (``step_slots``; 0 where the
-        step runs in XLA over every slot: ``_steps_live_slots``)."""
+        step runs in XLA over every slot: ``_steps_live_slots``). With
+        routed experts read in place: ``expert_kernel``, 1 where this
+        program's grouped products are the Pallas kernel's and 0 where
+        they are ``ragged_dot``'s (``_expert_product``)."""
         from ..ops.pallas.paged_attention import query_tile, tile_counts
 
         prefill = decode = single = 0
@@ -1362,6 +1389,11 @@ class RaggedInferenceEngine:
             attrs["state_layers"] = len(self._state_layers)
             attrs["state_slots"] = len(self.seqs)
             attrs["step_slots"] = single if self._steps_live_slots else 0
+        if self._routed:
+            # routed experts: whether this program's grouped products are
+            # the Pallas kernel's (1) or ragged_dot's (0)
+            attrs["expert_kernel"] = int(
+                self._expert_product(lanes) == "kernel")
         if B > 1:
             # block diffusion: the block length, the sequences whose block
             # under way this pass denoises, the tokens the pass before
@@ -1521,6 +1553,10 @@ class RaggedInferenceEngine:
             r.gauge("inference/state_slots_live").set(len(self.seqs))
             r.counter("inference/state_slots_stepped").inc(
                 attrs["step_slots"] * len(self._state_layers))
+        if self._routed:
+            r.counter("inference/expert_kernel_ticks"
+                      if attrs["expert_kernel"]
+                      else "inference/expert_ragged_dot_ticks").inc()
 
     def _validate_sched(self, sched) -> List[int]:
         """Validate a (seq, take) schedule WITHOUT mutating anything:
@@ -2187,6 +2223,10 @@ class RaggedInferenceEngine:
                         kind, lp = model.layer_params(
                             params["layers"], li,
                             self._experts_in_place and period is None, period)
+                    if "layer" in lp:
+                        # expert stacks handed over whole: the products'
+                        # form follows the step's path (no_drop_moe)
+                        lp["experts_path"] = self.attention_path
                     i = c.layers_of(kind).index(li)
                     own = {f: leaves[f][i] for f in kv_cache.OWNS[kind]
                            if leaves[f]}

@@ -83,8 +83,11 @@ class MoETransformer(Transformer):
     def _mlp(self, h, lp, rng=None, training=False):
         moe_params = {k: lp[k] for k in ("wg", "w_up", "w_down", "w_gate",
                                          "b_up", "b_down") if k in lp}
+        # ``layer`` and ``experts_path``: the serving step's, where it hands
+        # the expert stacks over whole (layer_params, ``in_place``)
         out, aux = self.moe.apply(moe_params, h, rng=rng, training=training,
-                                  layer=lp.get("layer"))
+                                  layer=lp.get("layer"),
+                                  path=lp.get("experts_path", "gather"))
         return out, aux * self.config.aux_loss_weight
 
     def partition_specs(self, params, topo=None) -> Dict[str, Any]:

@@ -115,10 +115,43 @@ def top_k_gating(logits: jnp.ndarray, cfg: GateConfig, cap: int,
 #: the expert leaves that are operands of ``ragged_dot`` in no_drop_moe
 RAGGED_OPERANDS = ("w_gate", "w_up", "w_down")
 
+#: rows an expert past which its product is bound by the MXU and no longer
+#: by its matrix's bytes: a v5e's 197 TFLOP/s over 819 GB/s, two operations
+#: a bfloat16 element a row (profiling/flops_profiler.DEVICE_PEAKS)
+RIDGE_ROWS = 240
+
+
+def expert_product(path: str, rows: int, n_experts: int, K: int, N: int,
+                   dtype) -> str:
+    """Which grouped product a compiled serving step holds, ``"kernel"``
+    (``ops/pallas/grouped_matmul.py``) or ``"ragged_dot"``, from what the
+    trace can see: the step's ``attention_path`` and the product's static
+    shape (``rows`` pairs over ``n_experts`` matrices of K x N). The
+    kernel where all three hold: a kernel path (``"gather"``, the path off
+    the TPU and the kernel's oracle, keeps ``ragged_dot``); a product
+    bound by the matrices' bytes, a mean of under ``RIDGE_ROWS`` rows an
+    expert; and an expert matrix that is one block of the kernel
+    (``weight_tiles``: up to 4 MiB), so that each touched expert's matrix
+    is fetched once whatever its rows. Measured on a v5e (PERF.md section
+    6, PR 46): 128 experts of 2048 x 768 at 4 / 16 / 64 rows an expert run
+    2.2 to 3.3 times ``ragged_dot``'s speed and their cell's wait a token
+    fell by 60%. Eight experts of 4096 x 14336 (tiled N, tiled K) run 1.2
+    times at 16 rows an expert and 1.6 to 2.1 at 64, 1.0 to 1.5 at 256 and
+    0.6 to 1.4 at 512; their cell's ticks gained 16% but its set-up, 28
+    programs with two more Mosaic kernels each to trace and lower, lost
+    9% against a bound of 10%: such shapes keep ``ragged_dot`` until a
+    program's kernels are lowered once a process (ROADMAP S13)."""
+    from ..ops.pallas.grouped_matmul import weight_tiles
+
+    return "kernel" if (path != "gather" and rows < RIDGE_ROWS * n_experts
+                        and weight_tiles(K, N, dtype) == (K, N)) \
+        else "ragged_dot"
+
 
 def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
                 params: Dict[str, Any], activation: str,
-                layer: Optional[int] = None) -> jnp.ndarray:
+                layer: Optional[int] = None,
+                path: str = "gather") -> jnp.ndarray:
     """Sort-based NO-DROP expert dispatch on grouped GEMMs.
 
     The TPU analog of FastGen's ``moe_gather``/``moe_scatter`` +
@@ -143,6 +176,15 @@ def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
     the tiles that hold rows, so the other layers' matrices are not read
     (on a v5e within 0.4% of the layer's own leaf at 16 to 4096 rows;
     PERF.md, PR 31).
+
+    ``path``: the serving step's ``attention_path``, handed down as to the
+    recurrent mixers. With the stack in place, :func:`expert_product`
+    decides from it and the product's static shape whether the three
+    products are ``ragged_dot``'s or calls of the Pallas kernel over the
+    same operands (``ops/pallas/grouped_matmul.py``: gate and up in one
+    call, ``silu(g) * u`` formed in float32). ``model.apply`` (training
+    without dropping, evaluation, the benchmark's references) and sharded
+    serving pass none and keep ``ragged_dot``.
     """
     S, k = idx.shape
     w = {n: params[n] for n in RAGGED_OPERANDS if n in params}
@@ -152,21 +194,42 @@ def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
     tok = jnp.repeat(jnp.arange(S), k)[order]         # source token per pair
     xs = x_flat[tok]                                  # moe_gather
     group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    kernel = layer is not None and expert_product(
+        path, S * k, E, *w["w_up"].shape[-2:], w["w_up"].dtype) == "kernel"
     if layer is not None:
         L = w["w_up"].shape[0]
         w = {n: a.reshape((L * E,) + a.shape[2:]) for n, a in w.items()}
-        group_sizes = jnp.pad(group_sizes, (layer * E, (L - 1 - layer) * E))
+    if kernel:
+        from ..ops.pallas.grouped_matmul import grouped_matmul, visits
+
+        # one schedule a layer: its products multiply the same S * k rows
+        # by the same groups
+        sched = visits(group_sizes, S * k, layer * E)
+
+        def product(rows, name, gated=None):
+            return grouped_matmul(
+                rows, w[name], sched, None if gated is None else w[gated],
+                interpret=path == "pallas_interpret")
+    else:
+        if layer is not None:
+            group_sizes = jnp.pad(group_sizes,
+                                  (layer * E, (L - 1 - layer) * E))
+
+        def product(rows, name, gated=None):
+            out = jax.lax.ragged_dot(rows, w[name], group_sizes)
+            return out if gated is None else \
+                jax.nn.silu(out) * jax.lax.ragged_dot(rows, w[gated],
+                                                      group_sizes)
 
     e_sorted = flat_e[order]                          # expert id per row
     if activation == "silu_glu":
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, w["w_gate"], group_sizes)) \
-            * jax.lax.ragged_dot(xs, w["w_up"], group_sizes)
+        h = product(xs, "w_gate", "w_up")
     else:
-        h = jax.lax.ragged_dot(xs, w["w_up"], group_sizes)
+        h = product(xs, "w_up")
         if "b_up" in params:
             h = h + params["b_up"][e_sorted].astype(h.dtype)
         h = jax.nn.gelu(h)
-    ys = jax.lax.ragged_dot(h, w["w_down"], group_sizes)  # [S*k, d]
+    ys = product(h, "w_down")                         # [S*k, d]
     if "b_down" in params:
         ys = ys + params["b_down"][e_sorted].astype(ys.dtype)
     gate = probs.reshape(-1)[order][:, None].astype(ys.dtype)
@@ -213,12 +276,13 @@ class MoELayer:
 
     def apply(self, params: Dict[str, Any], x: jnp.ndarray,
               rng: Optional[jax.Array] = None, training: bool = True,
-              layer: Optional[int] = None
+              layer: Optional[int] = None, path: str = "gather"
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """x: [b, s, d] -> (out [b, s, d], aux_loss). Token groups = batch
         rows (group-limited routing like the reference's per-group capacity).
-        Eval / no-drop uses the sort-based grouped-GEMM path; ``layer`` is
-        no_drop_moe's (the expert matrices arrive as the whole stack)."""
+        Eval / no-drop uses the sort-based grouped-GEMM path; ``layer`` and
+        ``path`` are no_drop_moe's (the expert matrices arrive as the whole
+        stack, from a serving step that says which of its paths it is)."""
         b, s, d = x.shape
         cfg = self.gate
         # device scopes (metadata only): ``router`` and ``experts`` name the
@@ -236,7 +300,7 @@ class MoELayer:
                 aux = cfg.n_experts * jnp.sum(jnp.mean(probs, axis=0) * assign)
             with jax.named_scope("experts"):
                 out = no_drop_moe(x.reshape(b * s, d), topw, topi, params,
-                                  self.activation, layer)
+                                  self.activation, layer, path)
             return out.reshape(b, s, d), aux
         with jax.named_scope("router"):
             cap = capacity(s, cfg, training)

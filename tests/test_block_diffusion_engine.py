@@ -309,6 +309,25 @@ def test_the_pallas_kernel_path_gives_the_same_stream(tiny, monkeypatch):
     assert engine.generate({1: p}, max_new_tokens=10)[1] == want
 
 
+def test_the_expert_kernel_gives_the_same_decisions(tiny, monkeypatch):
+    """On the kernel path the experts' products are the Pallas grouped
+    matmul's (8 experts, 2 a token, 32 lanes: 8 rows an expert): every
+    denoise pass's logits are the ``gather`` path's, so every decision is,
+    and the span says which product the program holds."""
+    streams, limits = {1: prompt(18, 11), 2: prompt(19, 6)}, {1: 20, 2: 18}
+    want, _ = run_passes(engine_of(tiny), streams, limits)
+    monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+    engine = engine_of(tiny)
+    assert engine._expert_product(32) == "kernel"
+    assert engine._sched_attrs([], 32, 1)["expert_kernel"] == 1
+    got, _ = run_passes(engine, streams, limits)
+    assert len(got) == len(want) > 8
+    for (u, state, logits), (u0, state0, logits0) in zip(got, want):
+        assert (u, state) == (u0, state0)       # the same decisions so far
+        np.testing.assert_allclose(logits, logits0, rtol=2e-4, atol=2e-5)
+        assert decide(logits, state) == decide(logits0, state0)
+
+
 def test_the_rule_on_the_device():
     """``decide_masked`` against hand-made logits: the two masked positions
     of highest confidence, a tie to the lower one, never a position that

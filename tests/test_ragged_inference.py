@@ -314,6 +314,68 @@ def test_expert_bytes_in_place_gauge(family, tmp_path):
         mesh_mod.reset_topology()
 
 
+@pytest.mark.parametrize("activation", ["silu_glu", "gelu"])
+def test_expert_products_follow_the_rule(activation, monkeypatch, tmp_path):
+    """A Mixtral-shaped and a GPT-MoE-shaped model served on the kernel
+    path (``DST_RAGGED_FORCE_PALLAS=interpret``): the experts' products
+    are the Pallas grouped matmul's (``parallel/moe.expert_product``: 64
+    rows over 4 experts), the tokens are the ``gather`` path's one for
+    one, and ``ragged.put``'s ``expert_kernel`` and the two tick counters
+    say which product each engine's programs hold."""
+    from deepspeed_tpu.config import TelemetryConfig
+    from deepspeed_tpu.parallel.mesh import reset_topology
+    from deepspeed_tpu.telemetry import Telemetry, set_telemetry
+
+    reset_topology()
+    model = _tiny_moe(activation)
+    params = model.init(jax.random.PRNGKey(2))
+    cfg = RaggedConfig(token_budget=32, max_seqs=4, kv_block_size=16,
+                       n_kv_blocks=32, max_context=64, dtype=jnp.float32)
+    rng = np.random.default_rng(5)
+    prompts = {1: rng.integers(1, 64, (9,)).tolist(),
+               2: rng.integers(1, 64, (21,)).tolist()}
+    got = {}
+    for path in ("gather", "pallas_interpret"):
+        if path == "pallas_interpret":
+            monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+        tel = Telemetry(TelemetryConfig(
+            enabled=True, output_dir=str(tmp_path / path), jsonl_path="",
+            stall_detection=False))
+        set_telemetry(tel)
+        try:
+            eng = RaggedInferenceEngine(model, cfg, params=params)
+            assert eng.attention_path == path
+            kernel = path != "gather"
+            assert eng._expert_product(32) == \
+                ("kernel" if kernel else "ragged_dot")
+            seen = []
+            attrs = eng._sched_attrs
+            eng._sched_attrs = lambda *a: seen.append(attrs(*a)) or seen[-1]
+            names = ("ragged_steps", "expert_kernel_ticks",
+                     "expert_ragged_dot_ticks")
+            count = lambda: np.array([tel.registry.counter(
+                f"inference/{n}").value for n in names])
+            before = count()        # the registry outlives a Telemetry
+            got[path] = eng.generate(dict(prompts), max_new_tokens=6)
+            steps, by_kernel, by_ragged_dot = count() - before
+            assert steps == len(seen) > 0
+            assert {a["expert_kernel"] for a in seen} == {int(kernel)}
+            assert (by_kernel, by_ragged_dot) == \
+                ((steps, 0) if kernel else (0, steps))
+        finally:
+            tel.close()
+            set_telemetry(None)
+    assert got["pallas_interpret"] == got["gather"]
+
+
+def test_dense_model_has_no_expert_product(tmp_path):
+    """No routed experts, no attribute and no tick counted."""
+    eng = RaggedInferenceEngine(_llama(), _cfg())
+    assert eng._expert_product(32) is None
+    eng.put([1], [[3, 4, 5]])
+    assert "expert_kernel" not in eng._sched_attrs([], 32, 1)
+
+
 def test_ragged_serves_windowed_moe():
     """Mixtral-class serving: routed experts + a BINDING sliding window
     in the ragged engine, token-exact vs the dense-KV engine."""

@@ -137,6 +137,12 @@ _STEP_RESULT = re.compile(r"= \(f32\[\d+,\d+,\d+\]\S*, "
 # the scope each kind's step runs under: the path its roofline metric
 # (``delta_step_roofline_pct``, ``ssd_step_roofline_pct``) reads
 _STEP_SCOPES = r"(linear_attn/delta_step|ssm/ssd_step)"
+# the experts' grouped product by its name (``grouped_matmul.N``, what
+# ``breakdown.device_ops`` lists): the pairs' rows, 2-D, as XLA:TPU's own
+# ``ragged-dot-none.N`` kernels return them
+_GROUPED_RESULT = re.compile(
+    r"%grouped_matmul[.\d]* = bf16\[\d+,\d+\]\S* custom-call\(")
+_RAGGED_DOT = re.compile(r"%ragged-dot[\w.\-]* = ")
 
 
 def _custom_calls(hlo: str) -> list:
@@ -167,13 +173,24 @@ def _step_calls(hlo: str) -> list:
     return calls
 
 
+def _grouped_calls(hlo: str) -> list:
+    """The experts' grouped products (``grouped_matmul``): each returns
+    the (lane, expert) pairs' rows and runs under ``ffn/experts``, the
+    scope ``experts_device_ms.serve`` reads."""
+    calls = [l for l in _custom_calls(hlo) if _GROUPED_RESULT.search(l)]
+    assert all(re.search(r'op_name="[^"]*ffn/experts/[^"]*"', l)
+               for l in calls), calls
+    return calls
+
+
 def _kernel_calls(hlo: str) -> int:
     """The paged kernel's calls; every other kernel of the module is the
-    row writer or a recurrent layer's step."""
+    row writer, a recurrent layer's step or the experts' product."""
     calls = _custom_calls(hlo)
     paged = [l for l in calls if _KERNEL_RESULT.search(l)]
     assert len(paged) + len(_writer_calls(hlo)) + len(_step_calls(hlo)) \
-        == len(calls), calls
+        + len(_grouped_calls(hlo)) \
+        + len([l for l in calls if _RAGGED_DOT.search(l)]) == len(calls), calls
     return len(paged)
 
 
@@ -340,6 +357,51 @@ def test_ssd_step_kernel_compiles(one_chip, period):
                                                 "get-tuple-element",
                                                 "custom-call")]
     assert c.memory_analysis().temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize("shape,rows,form", [
+    ("sdar", 512, "gated"), ("sdar", 2048, "gated"), ("sdar", 8192, "gated"),
+    ("sdar", 512, "down"), ("sdar", 2048, "down"), ("sdar", 8192, "down"),
+    ("mixtral", 128, "gated"), ("mixtral", 128, "down")])
+def test_grouped_matmul_compiles(one_chip, shape, rows, form):
+    """``grouped_matmul`` alone at the two cells' shapes: SDAR's 128
+    experts of 2048 x 768 whole in a block (3 MiB, gate and up 4 x 3 MiB
+    double-buffered beside a 128-row tile) for the 64 / 256 / 1,024-lane
+    programs' 512 / 2,048 / 8,192 rows, Mixtral's 8 of 4096 x 14336 in
+    blocks of 4096 x 512 (up) and 2048 x 1024 under a float32 accumulator
+    (down) at its decode step's 128 rows (the tiled forms: no serving
+    program holds them yet, ``expert_product``): Mosaic takes the prefetched
+    schedule, the index maps and the masked stores inside the VMEM the
+    call asks for, the stack is an operand as it lies (no slice of it, no
+    copy) and the call brings no temporary beside its schedule."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                         visits,
+                                                         weight_tiles)
+
+    E, K, N, L = {"sdar": (128, 2048, 768, 7),
+                  "mixtral": (8, 4096, 14336, 2)}[shape]
+    if form == "down":
+        K, N = N, K
+    want = {("sdar", "gated"): (2048, 768), ("sdar", "down"): (768, 2048),
+            ("mixtral", "gated"): (4096, 512),
+            ("mixtral", "down"): (2048, 1024)}[shape, form]
+    assert weight_tiles(K, N, jnp.bfloat16) == want
+    bf16 = lambda *dims: _sds(dims, jnp.bfloat16, one_chip)
+
+    def fn(xs, w, w2, sizes):
+        return grouped_matmul(xs, w, visits(sizes, rows, (L - 1) * E),
+                              w2 if form == "gated" else None)
+
+    c = jax.jit(fn).lower(bf16(rows, K), bf16(L * E, K, N),
+                          bf16(L * E, K, N),
+                          _sds((E,), jnp.int32, one_chip)).compile()
+    hlo = c.as_text()
+    call, = _custom_calls(hlo)
+    assert _GROUPED_RESULT.search(call) and f"bf16[{rows},{N}]" in call
+    stack = f"{L * E},{K},{N}"
+    assert {i.op for i in _instructions(hlo).values() if i.dims == stack} \
+        <= {"parameter"}
+    assert c.memory_analysis().temp_size_in_bytes < 1e6
 
 
 def test_paged_int4_kv_refuses_before_the_compiler(one_chip):
@@ -1088,13 +1150,23 @@ def test_mixtral_step_reads_expert_stacks_in_place(one_chip, on_tpu, T):
     ``slice_bitcast_fusion``s, 5.6 GB written and read again: 17 of the
     tick's 28 ms of device time before PR 31, and 5.6 GB of the program's
     temporaries); the products index the whole stack by group instead
-    (``no_drop_moe``'s ``layer``)."""
+    (``no_drop_moe``'s ``layer``). Which product: the rule's
+    (``parallel/moe.expert_product``): an expert's 4096 x 14336 matrix is
+    no single block of ``grouped_matmul``, so both programs keep
+    ``ragged_dot`` and are the parent's (PERF.md section 6, PR 46: the
+    kernel's tiled form is faster at this shape under the ridge, and its
+    two more Mosaic kernels a program cost the cell's set-up 9%)."""
+    from deepspeed_tpu.parallel.moe import expert_product
+
     # the cell runs at JAX's default matmul precision; under conftest's
     # "highest" XLA:TPU's ragged_dot kernel refuses bf16 operands
     with jax.default_matmul_precision("default"):
         compiled, c, e = compile_cell_step("mixtral-8x7b", one_chip, T, 128)
     hlo = compiled.as_text()
+    assert expert_product("pallas", T * c.top_k, c.n_experts, c.d_model,
+                          c.d_ff, jnp.bfloat16) == "ragged_dot"
     assert hlo.count("ragged-dot") >= 3 * c.n_layers
+    assert not _grouped_calls(hlo)
     leaf = c.n_experts * c.d_model * c.d_ff
     entry = hlo[hlo.index("\nENTRY "):]
     # results, tuples too (the parent's copies are multi-output fusions)
@@ -1115,17 +1187,38 @@ def test_sdar_step_compiles_under_the_block_mask(one_chip, on_tpu, T):
     with 128 experts a layer, the paged kernel under the block-causal mask
     (``attn_block`` 4: Mosaic takes the ``|`` in the mask and in the trip
     count), the head on a block of lanes a slot and the choice under
-    ``decide``; the expert stacks read in place, and the program beside its
-    9.97 GB of weights and 0.94 GB of pages inside the chip's memory."""
+    ``decide``; the expert stacks read in place by ``grouped_matmul``, two
+    calls a layer (gate and up together, then down) where six
+    ``ragged_dot`` kernels a layer stood and none is left (16 and 64 rows
+    an expert: both programs are bound by the matrices' bytes), no
+    operation yielding a layer's expert matrices, and the program beside
+    its 9.97 GB of weights and 0.94 GB of pages inside the chip's
+    memory."""
+    import json
+
+    from benchmarks import harness, trace_reduce as tr
+
     with jax.default_matmul_precision("default"):
         compiled, c, e = compile_cell_step("sdar-30b-a3b", one_chip, T, 64)
     hlo = compiled.as_text()
     assert (c.n_layers, c.n_experts, c.top_k, c.attn_block) == (7, 128, 8, 4)
-    # beside the paged kernel and the row writer the module holds XLA:TPU's
-    # own kernels for the experts' products (custom calls too)
-    assert len([l for l in _custom_calls(hlo)
-                if _KERNEL_RESULT.search(l)]) == c.n_layers
+    assert _kernel_calls(hlo) == c.n_layers
     assert len(_writer_calls(hlo)) == c.n_layers
+    grouped = _grouped_calls(hlo)
+    assert len(grouped) == 2 * c.n_layers
+    assert {re.search(r"= bf16\[(\d+,\d+)\]", l).group(1) for l in grouped} \
+        == {f"{T * c.top_k},{c.d_ff}", f"{T * c.top_k},{c.d_model}"}
+    assert "ragged-dot" not in hlo
+    # the paged kernel's roofline reader does not take them for attention
+    spec = json.load(open(os.path.join(harness.HERE, "metrics",
+                                       "paged_attn_roofline_pct.json")))
+    paged = re.compile(spec["args"]["kernel"])
+    assert not [l for l in grouped if paged.search(
+        tr.clean(l.strip().removeprefix("ROOT ")))]
+    # nothing yields a layer's expert matrices: the stacks are operands
+    layer = {f"{c.n_experts},{c.d_model},{c.d_ff}",
+             f"{c.n_experts},{c.d_ff},{c.d_model}"}
+    assert not [i for i in _instructions(hlo).values() if i.dims in layer]
     assert "decide" in hlo
     mem = compiled.memory_analysis()
     assert 9.9e9 < mem.argument_size_in_bytes < 11.1e9
