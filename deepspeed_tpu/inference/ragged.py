@@ -129,24 +129,50 @@ class RaggedConfig:
     kv_quant: str = "none"
 
 
-class _Step:
-    """The jitted SplitFuse step behind the two-result call it has always
-    answered: ``(logits, pools)``. The program has a third result, each
-    slot's greedy token id; a call leaves it on the engine (``_step_ids``)
-    for ``_put``, so whoever calls, wraps or warms ``_step_fn`` compiles
-    and runs the one program the serving tick runs. Everything else
-    (``lower``, ``_cache_size``) is the jitted function's."""
+class _Program:
+    """A jitted program built on the engine's core, called as it has
+    always been: the live-page bucket is its static argument ``pages_at``.
+    Before the jit's cache sees that argument the engine makes it the
+    value its programs are keyed by (``_program_pages``: one value on the
+    tiled attention path, whose kernel reads no bucket), so whoever calls,
+    wraps or warms the program with whatever bucket lands on the program
+    the serving tick runs. Everything else (``lower``, ``_cache_size``)
+    is the jitted function's."""
 
-    def __init__(self, engine, jitted):
+    def __init__(self, engine, jitted, pages_at: int):
         self._engine = engine
         self._jitted = jitted
+        self._pages_at = pages_at
 
     def __call__(self, *args):
-        logits, self._engine._step_ids, pools = self._jitted(*args)
-        return logits, pools
+        at = self._pages_at
+        return self._jitted(*args[:at],
+                            self._engine._program_pages(args[at]),
+                            *args[at + 1:])
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
+
+
+class _Step(_Program):
+    """The jitted SplitFuse step behind the two-result call it has always
+    answered: ``(logits, pools)``. The program has a third result, each
+    slot's greedy token id; a call leaves it on the engine (``_step_ids``)
+    for ``_put``. It also says how many step programs the engine holds
+    (gauge ``inference/step_programs``)."""
+
+    def __call__(self, *args):
+        # the base's call written out: one frame between the caller and the
+        # jitted step, as there has always been (every traced operation's
+        # location, and with it the compile cache's key, holds the stack)
+        at, eng = self._pages_at, self._engine
+        logits, eng._step_ids, pools = self._jitted(
+            *args[:at], eng._program_pages(args[at]), *args[at + 1:])
+        t = eng._telemetry
+        if t.enabled:
+            t.registry.gauge("inference/step_programs").set(
+                self._jitted._cache_size())
+        return logits, pools
 
 
 class RaggedInferenceEngine:
@@ -296,9 +322,9 @@ class RaggedInferenceEngine:
         self.expert_bytes_in_place = sum(
             a.size * a.dtype.itemsize for a in whole)
         # routed experts whose stacks the step reads in place: how many a
-        # token takes, how many a layer holds and their matrices' shape
-        # (what _expert_product needs)
-        self._routed = (c.top_k, c.n_experts, c.d_model, c.d_ff) \
+        # token takes and how many a layer holds (what _expert_product
+        # needs)
+        self._routed = (c.top_k, c.n_experts) \
             if self._experts_in_place and model.stacked_operands else None
         if self._telemetry.enabled:
             self._telemetry.registry.gauge(
@@ -315,6 +341,13 @@ class RaggedInferenceEngine:
         # serving layer's request spans carry the true end-to-end numbers
         self._resume_uids: set = set()
         self.kv_pool = kv_cache.new_pool(c, cfg, topology)
+        # whether the live-page bucket is a key of the step programs
+        # (_program_pages): not where the paged kernel walks query tiles
+        from ..ops.pallas.paged_attention import tiled_grid
+
+        self._pages_key = not (
+            self.attention_path != "gather"
+            and tiled_grid(*self.kv_pool.k, *self.kv_pool.k_scale))
         self.kv_bytes_per_token = \
             kv_cache.kv_page_bytes(c, cfg) // cfg.kv_block_size
         # what a live sequence holds beside its pages: its slot of every
@@ -375,6 +408,7 @@ class RaggedInferenceEngine:
                  f"blocks={cfg.n_kv_blocks}x{cfg.kv_block_size} "
                  f"expert_bytes_in_place={self.expert_bytes_in_place} "
                  f"expert_products={products} "
+                 f"page_bucket_keys_programs={self._pages_key} "
                  f"passes={self._passes} periods={self._periods} "
                  f"kv_bytes_per_token={self.kv_bytes_per_token}")
 
@@ -411,6 +445,21 @@ class RaggedInferenceEngine:
                 and not self._kv_bits
                 and self.model.config.head_dim % LANES == 0)
 
+    def _program_pages(self, live_pages: int) -> int:
+        """The page count a compiled program of this engine is keyed by,
+        from a tick's live-page bucket (``_live_pages_bucket``), for every
+        jitted program built on ``_core`` (:class:`_Program`). Where the
+        paged kernel takes the grid over query tiles, a tile walks its own
+        positions' chunks and the bucket bounds nothing, so every bucket
+        is one program, keyed ``max_pages``: a program a lane bucket. That
+        is the kernel paths with a pool the kernel itself sends to that
+        grid (``paged_attention.tiled_grid``: every leaf it copies a whole
+        number of 128 lanes wide), read off the pool's shapes. Elsewhere
+        the bucket is the key as it was: the lane grid's steps are lanes x
+        chunks of the bucket (head size 64, a quantized pool's scale rows),
+        and the ``gather`` path keeps its programs as they were."""
+        return int(live_pages) if self._pages_key else self.max_pages
+
     def _expert_product(self, lanes: int) -> Optional[str]:
         """Which grouped product the ``lanes``-wide step program holds for
         its routed experts, ``"kernel"`` or ``"ragged_dot"``
@@ -422,9 +471,8 @@ class RaggedInferenceEngine:
             return None
         from ..parallel.moe import expert_product
 
-        top_k, *experts = self._routed
-        return expert_product(self.attention_path, lanes * top_k, *experts,
-                              self.config.dtype)
+        top_k, n_experts = self._routed
+        return expert_product(self.attention_path, lanes * top_k, n_experts)
 
     @property
     def _steps_live_slots(self) -> bool:
@@ -1262,7 +1310,13 @@ class RaggedInferenceEngine:
         lane bucket and a live-page bucket, on an empty batch: every lane
         is inactive and its writes land on the scratch page. A server
         calls it at start-up for every shape its traffic can reach, so
-        that no tick compiles."""
+        that no tick compiles. What that is: on the tiled attention path
+        a program a lane bucket, whatever ``pages`` (``_program_pages``:
+        the bucket is no key there, so any one value warms the bucket's
+        program and the others find it compiled); on the lane grid (head
+        size 64, a quantized pool) and the ``gather`` path a program a
+        (lanes, pages) pair. ``_step_fn._cache_size()`` and the gauge
+        ``inference/step_programs`` say how many the engine holds."""
         cfg = self.config
         if self._step_fn is None:
             self._step_fn = self._build_step()
@@ -1656,7 +1710,8 @@ class RaggedInferenceEngine:
                 logits = model._head(params, x_sel[None, :])[0]
             return logits.reshape(sel_rows.shape + (-1,)), pools
 
-        return jax.jit(step, donate_argnums=(1,), static_argnums=(7,))
+        return _Program(self, jax.jit(step, donate_argnums=(1,),
+                                      static_argnums=(7,)), 7)
 
     def _host_tables(self) -> np.ndarray:
         live = list(self.seqs.values())
@@ -1666,7 +1721,9 @@ class RaggedInferenceEngine:
     def _live_pages_bucket(self) -> int:
         """Static page-walk bound for this step: smallest power of two >=
         the longest live sequence's page count (pow2-bucketed so the jit
-        cache holds O(log max_pages) variants, not one per context len)."""
+        cache holds O(log max_pages) variants, not one per context len).
+        The spans' ``pages``; a program's key only where the kernel walks
+        it (``_program_pages``)."""
         most = max((len(s.blocks) for s in self.seqs.values()), default=1)
         b = 1
         while b < most:
@@ -2075,7 +2132,8 @@ class RaggedInferenceEngine:
 
         def core(params, pools, tokens, slots, positions, block_tables,
                  live_pages):
-            # live_pages: static python int — bounds the kernel's page walk
+            # live_pages: static python int, as _program_pages gave it: the
+            # lane grid's page walk; the tiled kernel reads none
             # tokens/slots/positions: [T]; embeddings via the model's path
             # device scopes (jax.named_scope: metadata only) name the
             # step's parts in a profiler trace: embed, weights, attn with
@@ -2339,7 +2397,7 @@ class RaggedInferenceEngine:
                 return logits, ids, pools
 
         return _Step(self, jax.jit(step, donate_argnums=(1,),
-                                   static_argnums=(7,)))
+                                   static_argnums=(7,)), 7)
 
     def _build_decode(self):
         """Multi-step decode entirely on device: one token per live slot
@@ -2390,4 +2448,5 @@ class RaggedInferenceEngine:
                 one, (pools, tokens0, positions0, alive0), steps_xs)
             return gen.T, pools                                 # [S, k]
 
-        return jax.jit(decode, donate_argnums=(1,), static_argnums=(8, 9))
+        return _Program(self, jax.jit(decode, donate_argnums=(1,),
+                                      static_argnums=(8, 9)), 8)

@@ -293,11 +293,12 @@ def tile_counts(runs, tile_rows: int, block: int, attn_block: int = 1
 
 
 def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
-                 ppc: int, tq: int, window: int, kv_bits: int, last_page: int,
+                 ppc: int, tq: int, window: int, kv_bits: int,
                  attn_block: int = 1):
     """One grid step = one query tile; its KV chunks in a loop whose trip
     count is the tile's own, pages copied by hand from the pool in HBM,
-    two chunks in flight."""
+    two chunks in flight. Nothing here knows the caller's page bucket: a
+    table read is held inside the table by its own width."""
     if kv_bits:
         k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, \
             m_scr, l_scr, acc_scr = rest
@@ -314,7 +315,8 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
         last = last | (attn_block - 1)
     low = jnp.maximum(pos0 - (window - 1), 0) if window > 0 else 0
     lo_page = low // block
-    hi_page = jnp.minimum(last // block, last_page)
+    # (a no-op under the caller's contract, positions inside the table)
+    hi_page = jnp.minimum(last // block, tbl_ref.shape[1] - 1)
     c_lo, c_hi = low // span, last // span          # the tile's chunks
 
     def copies(c, buf, act):
@@ -397,12 +399,11 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
 # a layer (tracing a chunk's 64 copy descriptors is most of what a step
 # program's lowering costs)
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "ppc", "tq", "last_page", "window", "kv_bits", "interpret",
-    "attn_block"))
+    "scale", "ppc", "tq", "window", "kv_bits", "interpret", "attn_block"))
 def _tiled(q, k_pool, v_pool, tables, positions, slots, work, *, scale, ppc,
-           tq, last_page, window, k_scale, v_scale, kv_bits, interpret,
-           attn_block=1):
-    """The grid over query tiles (module docstring)."""
+           tq, window, k_scale, v_scale, kv_bits, interpret, attn_block=1):
+    """The grid over query tiles (module docstring). A tile's chunks are
+    its own positions', so no page bucket is a key of this program."""
     T, hq, hd = q.shape
     _, hkv, block, hd_p = k_pool.shape
     group = hq // hkv
@@ -432,7 +433,7 @@ def _tiled(q, k_pool, v_pool, tables, positions, slots, work, *, scale, ppc,
     out = pl.pallas_call(
         functools.partial(_tile_kernel, scale=scale, block=block, ppc=ppc,
                           tq=tq, window=window, kv_bits=kv_bits if quant else 0,
-                          last_page=last_page, attn_block=attn_block),
+                          attn_block=attn_block),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(work.shape[1],),
@@ -449,6 +450,18 @@ def _tiled(q, k_pool, v_pool, tables, positions, slots, work, *, scale, ppc,
     out = out.transpose(2, 0, 1, 3)[:T].reshape(T, hq, hd)
     # a lane that is not live belongs to no tile: nothing wrote its row
     return jnp.where((slots >= 0)[:, None, None], out, 0)
+
+
+def tiled_grid(*leaves) -> bool:
+    """Whether :func:`paged_attention` takes the grid over query tiles for
+    a pool with these leaves (``k_pool``, and a quantized pool's
+    ``k_scale``; anything with a ``shape``). Mosaic refuses a
+    hand-rolled copy of a slab under 128 lanes wide, so that grid takes
+    pools whose every leaf has whole lanes: head_dim (a packed one too),
+    and a quantized pool's scale rows [.., block]; any other keeps the lane
+    grid. A caller that keys its programs by the page bucket
+    (``inference/ragged.py``) asks here whether the bucket is read at all."""
+    return all(a.shape[-1] % LANES == 0 for a in leaves)
 
 
 def paged_attention(q, k_pool, v_pool, tables, positions, *,
@@ -475,7 +488,10 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     ``tile_rows`` overrides :func:`query_tile` (tests and tuning only).
 
     ``live_pages`` (static): caller guarantees every positions[t] <
-    live_pages * block; pages beyond are never read.
+    live_pages * block; pages beyond are never read. It makes the lane
+    grid, whose steps are lanes x chunks of that many pages; the grid over
+    query tiles (:func:`tiled_grid`) walks a tile's own chunks and does
+    not read it, so its program is the same whatever the bucket.
 
     ``window`` > 0 (static) bands attention to the trailing ``window``
     positions (Mistral/Qwen2 sliding-window serving): a tile starts at
@@ -506,8 +522,6 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     assert hq % hkv == 0
     _check_attn_block(attn_block, block, window)
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
-    walk_pages = max_pages if live_pages is None \
-        else max(1, min(live_pages, max_pages))
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     common = dict(scale=scale, window=int(window),  # dslint: disable=host-sync -- window is a static Python int kernel parameter, never a tracer
@@ -516,17 +530,14 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
                   interpret=interpret)
     if attn_block > 1:     # a key of the jitted kernel only where it is used
         common["attn_block"] = int(attn_block)  # dslint: disable=host-sync -- attn_block is a static Python int kernel parameter, never a tracer
-    # Mosaic refuses a hand-rolled copy of a slab under 128 lanes wide, so
-    # the tiled grid takes pools whose every leaf has whole lanes: head_dim
-    # (a packed one too), and a quantized pool's scale rows [.., block]
-    leaves = (k_pool,) + ((k_scale,) if quant else ())
-    if all(a.shape[-1] % LANES == 0 for a in leaves):
+    if tiled_grid(k_pool, *((k_scale,) if quant else ())):
         slots = jnp.arange(T, dtype=jnp.int32) if seq_slots is None \
             else seq_slots.astype(jnp.int32)
         return _tiled(q, k_pool, v_pool, tables, positions, slots, work,
                       ppc=pages_per_chunk or chunk_pages(block),
-                      tq=tile_rows or query_tile(T),
-                      last_page=walk_pages - 1, **common)
+                      tq=tile_rows or query_tile(T), **common)
+    walk_pages = max_pages if live_pages is None \
+        else max(1, min(live_pages, max_pages))
     return _lane_grid(q, k_pool, v_pool, tables, positions,
                       None if seq_slots is None
                       else jnp.maximum(seq_slots, 0).astype(jnp.int32),

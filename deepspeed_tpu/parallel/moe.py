@@ -121,30 +121,30 @@ RAGGED_OPERANDS = ("w_gate", "w_up", "w_down")
 RIDGE_ROWS = 240
 
 
-def expert_product(path: str, rows: int, n_experts: int, K: int, N: int,
-                   dtype) -> str:
+def expert_product(path: str, rows: int, n_experts: int) -> str:
     """Which grouped product a compiled serving step holds, ``"kernel"``
     (``ops/pallas/grouped_matmul.py``) or ``"ragged_dot"``, from what the
     trace can see: the step's ``attention_path`` and the product's static
-    shape (``rows`` pairs over ``n_experts`` matrices of K x N). The
-    kernel where all three hold: a kernel path (``"gather"``, the path off
-    the TPU and the kernel's oracle, keeps ``ragged_dot``); a product
-    bound by the matrices' bytes, a mean of under ``RIDGE_ROWS`` rows an
-    expert; and an expert matrix that is one block of the kernel
-    (``weight_tiles``: up to 4 MiB), so that each touched expert's matrix
-    is fetched once whatever its rows. Measured on a v5e (PERF.md section
-    6, PR 46): 128 experts of 2048 x 768 at 4 / 16 / 64 rows an expert run
-    2.2 to 3.3 times ``ragged_dot``'s speed and their cell's wait a token
-    fell by 60%. Eight experts of 4096 x 14336 (tiled N, tiled K) run 1.2
-    times at 16 rows an expert and 1.6 to 2.1 at 64, 1.0 to 1.5 at 256 and
-    0.6 to 1.4 at 512; their cell's ticks gained 16% but its set-up, 28
-    programs with two more Mosaic kernels each to trace and lower, lost
-    9% against a bound of 10%: such shapes keep ``ragged_dot`` until a
-    program's kernels are lowered once a process (ROADMAP S13)."""
-    from ..ops.pallas.grouped_matmul import weight_tiles
-
-    return "kernel" if (path != "gather" and rows < RIDGE_ROWS * n_experts
-                        and weight_tiles(K, N, dtype) == (K, N)) \
+    shape (``rows`` pairs over ``n_experts`` matrices). The kernel where
+    both hold: a kernel path (``"gather"``, the path off the TPU and the
+    kernel's oracle, keeps ``ragged_dot``), and a product bound by the
+    matrices' bytes, a mean of under ``RIDGE_ROWS`` rows an expert;
+    whatever the matrix's tiling (``weight_tiles``: one block where it
+    fits 4 MiB, else all of K by a tile of N, else tiles of both).
+    Measured on a v5e (PERF.md section 6, PR 46): 128 experts of 2048 x
+    768, one block each, at 4 / 16 / 64 rows an expert run 2.2 to 3.3 times
+    ``ragged_dot``'s speed and their cell's wait a token fell by 60%. Eight
+    experts of 4096 x 14336 (tiled N, tiled K) run 1.2 times at 16 rows an
+    expert and 1.6 to 2.1 at 64; past the ridge 1.0 to 1.5 at 256 and 0.6
+    to 1.4 at 512, which is why the ridge stands. Until PR 47 a third
+    clause kept such tiled shapes on ``ragged_dot``: their cell's ticks
+    gained 16% (its tail 36%) but its set-up, 28 step programs with two
+    more Mosaic kernels each to trace and lower, lost 9% against a bound
+    of 10%. Since PR 47 an engine on the tiled attention path holds one
+    step program a lane bucket (``inference/ragged.py::_program_pages``),
+    the kernels cost that cell two programs' lowering, not 28, and the
+    clause is gone (the set-up as read then: PERF.md section 6, PR 47)."""
+    return "kernel" if path != "gather" and rows < RIDGE_ROWS * n_experts \
         else "ragged_dot"
 
 
@@ -194,8 +194,8 @@ def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
     tok = jnp.repeat(jnp.arange(S), k)[order]         # source token per pair
     xs = x_flat[tok]                                  # moe_gather
     group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-    kernel = layer is not None and expert_product(
-        path, S * k, E, *w["w_up"].shape[-2:], w["w_up"].dtype) == "kernel"
+    kernel = layer is not None and \
+        expert_product(path, S * k, E) == "kernel"
     if layer is not None:
         L = w["w_up"].shape[0]
         w = {n: a.reshape((L * E,) + a.shape[2:]) for n, a in w.items()}

@@ -175,13 +175,18 @@ def test_visits(how, rows, E, tm):
     ("pallas_interpret", 8192, 128, 2048, 768, "kernel"),
     ("pallas", 128 * 240, 128, 2048, 768, "ragged_dot"),    # the ridge
     ("pallas", 128, 8, 1024, 2048, "kernel"),       # 4 MiB: one block
-    ("pallas", 128, 8, 4096, 14336, "ragged_dot"),  # Mixtral's: tiled
-    ("pallas", 512, 8, 14336, 4096, "ragged_dot"),
-    ("pallas", 4096, 8, 4096, 14336, "ragged_dot")])
+    ("pallas", 128, 8, 4096, 14336, "kernel"),      # Mixtral's: tiled
+    ("pallas", 512, 8, 14336, 4096, "kernel"),
+    ("pallas", 4096, 8, 4096, 14336, "ragged_dot")])    # past the ridge
 def test_the_rule(path, rows, E, K, N, want):
-    """Which product a program holds: the path and the static shape (rows
-    an expert under the ridge, the expert's matrix one block)."""
-    assert moe.expert_product(path, rows, E, K, N, jnp.bfloat16) == want
+    """Which product a program holds: the path and the rows an expert
+    (under the ridge), whatever the expert matrix's tiling: Mixtral's 64-
+    and 256-lane programs (128 and 512 pairs) hold the kernel over a
+    matrix that is no single block, its 2,048-lane one keeps
+    ``ragged_dot``."""
+    assert moe.expert_product(path, rows, E) == want
+    if (K, N) in ((4096, 14336), (14336, 4096)):
+        assert gm.weight_tiles(K, N, jnp.bfloat16) != (K, N)
 
 
 @pytest.mark.parametrize("activation", ["silu_glu", "gelu"])

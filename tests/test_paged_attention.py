@@ -339,6 +339,41 @@ def test_tiled_work_list_at_its_bound():
     assert work[2].sum() == T
 
 
+@pytest.mark.parametrize("window,attn_block", [(0, 1), (24, 1), (0, 4)],
+                         ids=["causal", "window24", "attn_block4"])
+def test_tiled_clamp_is_the_tables_width_not_a_bucket(window, attn_block):
+    """The tiled kernel holds a table read inside the row by the table's
+    own width. Until PR 47 it clamped at the caller's live-page bucket,
+    which made seven programs of one: under the caller's contract (every
+    position under ``live_pages * block``) neither clamp binds, so the
+    result over 12-page tables is bit for bit the result over the same
+    tables cut to the bucket's 4 pages (whose width IS the old clamp),
+    whatever ``live_pages`` says, and ``live_pages`` is no key of the
+    jitted kernel."""
+    runs = [(0, 57, 1), (2, 40, 21), (1, 3, 9), (3, 0, 5)]   # all under 64
+    q, kp, vp, tables, slots, pos = _ragged_batch(runs, 40, 4)
+    kw = dict(seq_slots=jnp.asarray(slots), window=window, tile_rows=TQ,
+              pages_per_chunk=PPC, interpret=True)
+    if attn_block > 1:
+        kw["attn_block"] = attn_block
+    run = lambda tb, **more: np.asarray(paged_attention(
+        q, kp, vp, tb, jnp.asarray(pos), **kw, **more))
+    cut = run(tables[:, :4])
+    whole = run(tables)
+    programs = pa._tiled._cache_size()
+    np.testing.assert_array_equal(whole, cut)
+    for bucket in (4, 8, 12):
+        np.testing.assert_array_equal(run(tables, live_pages=bucket), cut)
+    assert pa._tiled._cache_size() == programs
+    live = slots >= 0
+    more = {"attn_block": attn_block} if attn_block > 1 else {}
+    want = paged_attention_reference(
+        q[live], kp, vp, tables[slots[live]], jnp.asarray(pos[live]),
+        window=window, **more)
+    np.testing.assert_allclose(whole[live], np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_tile_counts_agree_with_the_work_list(seed):
     """``ragged.put``'s host counters (q_tiles, kv_steps; write_tiles,

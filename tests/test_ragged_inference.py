@@ -319,7 +319,9 @@ def test_expert_products_follow_the_rule(activation, monkeypatch, tmp_path):
     """A Mixtral-shaped and a GPT-MoE-shaped model served on the kernel
     path (``DST_RAGGED_FORCE_PALLAS=interpret``): the experts' products
     are the Pallas grouped matmul's (``parallel/moe.expert_product``: 64
-    rows over 4 experts), the tokens are the ``gather`` path's one for
+    rows over 4 experts, under the ridge; the rule reads the path and the
+    rows an expert and nothing of the matrices), the tokens are the
+    ``gather`` path's one for
     one, and ``ragged.put``'s ``expert_kernel`` and the two tick counters
     say which product each engine's programs hold."""
     from deepspeed_tpu.config import TelemetryConfig
@@ -366,6 +368,68 @@ def test_expert_products_follow_the_rule(activation, monkeypatch, tmp_path):
             tel.close()
             set_telemetry(None)
     assert got["pallas_interpret"] == got["gather"]
+
+
+@pytest.mark.parametrize("path,head_dim,programs", [
+    ("pallas_interpret", 128, 2),      # the grid over query tiles
+    ("pallas_interpret", 64, 10),      # the lane grid: lanes x page bucket
+    ("gather", 128, 10)], ids=["tiled", "lane_grid", "gather"])
+def test_step_programs_a_lane_bucket_where_no_page_bucket_is_read(
+        path, head_dim, programs, monkeypatch, tmp_path):
+    """Where the paged kernel walks query tiles the live-page bucket
+    bounds nothing, and the engine holds one step program a lane bucket
+    whatever bucket it is warmed or called at (``_program_pages``): after
+    ``warm_step`` at every (lanes, pages) pair ``_step_fn`` holds as many
+    programs as there are lane buckets, a ``put`` compiles nothing, and
+    its rows are bit for bit those of an engine warmed at the tick's own
+    shape alone. An engine on the lane grid (head size 64), whose grid is
+    made of the bucket, and one on the ``gather`` path keep a program a
+    pair. The gauge ``inference/step_programs`` says which; the span's
+    ``pages`` stays the live bucket."""
+    from deepspeed_tpu.config import TelemetryConfig
+    from deepspeed_tpu.telemetry import Telemetry, set_telemetry
+
+    if path == "pallas_interpret":
+        monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
+    model = Llama("tiny", n_layers=1, d_model=2 * head_dim, n_heads=2,
+                  n_kv_heads=1, vocab_size=128, max_seq_len=256,
+                  use_flash=False, remat=False)
+    params = model.init(jax.random.PRNGKey(3))
+    cfg = _cfg(token_budget=96, kv_block_size=16, n_kv_blocks=32,
+               max_context=256)
+    tel = Telemetry(TelemetryConfig(enabled=True, output_dir=str(tmp_path),
+                                    jsonl_path="", stall_detection=False))
+    set_telemetry(tel)
+    try:
+        eng = RaggedInferenceEngine(model, cfg, params=params)
+        assert eng.attention_path == path
+        assert eng._buckets == [64, 96] and eng.max_pages == 16
+        assert eng._pages_key == (programs == 10)
+        pairs = [(b, p) for b in eng._buckets for p in (1, 2, 4, 8, 16)]
+        for lanes, pages in pairs:
+            eng.warm_step(lanes, pages)
+        gauge = tel.registry.gauge("inference/step_programs")
+        assert eng._step_fn._cache_size() == programs == gauge.value
+        seen = []
+        attrs = eng._sched_attrs
+        eng._sched_attrs = lambda *a: seen.append(attrs(*a)) or seen[-1]
+        prompts = [list(range(1, 70)), [5, 6, 7]]     # 72 lanes, 5 pages
+        rows = [eng.put([1, 2], prompts), eng.put([1, 2], [[9], [9]])]
+        assert [(a["lanes"], a["pages"]) for a in seen] == [(96, 8), (64, 8)]
+        assert eng._step_fn._cache_size() == programs == gauge.value
+
+        alone = RaggedInferenceEngine(model, cfg, params=params)
+        alone.warm_step(96, 8)
+        alone.warm_step(64, 8)
+        assert alone._step_fn._cache_size() == 2
+        want = [alone.put([1, 2], prompts), alone.put([1, 2], [[9], [9]])]
+        for got, w in zip(rows, want):
+            assert np.isfinite(got).all()
+            np.testing.assert_array_equal(got, w)
+        assert alone._step_fn._cache_size() == 2
+    finally:
+        tel.close()
+        set_telemetry(None)
 
 
 def test_dense_model_has_no_expert_product(tmp_path):

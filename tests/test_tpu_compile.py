@@ -369,8 +369,9 @@ def test_grouped_matmul_compiles(one_chip, shape, rows, form):
     double-buffered beside a 128-row tile) for the 64 / 256 / 1,024-lane
     programs' 512 / 2,048 / 8,192 rows, Mixtral's 8 of 4096 x 14336 in
     blocks of 4096 x 512 (up) and 2048 x 1024 under a float32 accumulator
-    (down) at its decode step's 128 rows (the tiled forms: no serving
-    program holds them yet, ``expert_product``): Mosaic takes the prefetched
+    (down) at its decode step's 128 rows (the tiled forms, which its 64-
+    and 256-lane programs hold: ``expert_product``): Mosaic takes the
+    prefetched
     schedule, the index maps and the masked stores inside the VMEM the
     call asks for, the stack is an operand as it lies (no slice of it, no
     copy) and the call brings no temporary beside its schedule."""
@@ -585,7 +586,10 @@ def test_step_fn_takes_the_benchmark_warm_up_call(monkeypatch):
     on ``put`` finding every program compiled: a PR may not edit that file,
     and a program compiled inside a measured window fails the run. Run
     here on the CPU (kernel in interpret mode, head_dim 128: the grid over
-    query tiles with no tile) with the runner's own function.
+    query tiles with no tile) with the runner's own function. On that grid
+    the page bucket is no key of a program (``_program_pages``): the six
+    shapes the runner warms are the two lane buckets' programs, and the
+    ones ``put`` runs whatever its live bucket.
 
     The window's ticks are the server's, which asks the engine for token
     ids: they must run the very programs that call compiled. So the
@@ -620,7 +624,7 @@ def test_step_fn_takes_the_benchmark_warm_up_call(monkeypatch):
                     jax.tree_util.tree_leaves(eng.kv_pool)):
         np.testing.assert_array_equal(a[:-1], np.asarray(b)[:-1])
     compiled = eng._step_fn._cache_size()
-    assert compiled == len(shapes)
+    assert compiled == len(eng._buckets) < len(shapes)
     rows = eng.put([1, 2], [list(range(1, 40)), [5, 6, 7]])   # 64 lanes, 4 pages
     assert np.isfinite(rows).all()
     rows = eng.put([1, 2], [[9], [9]])                          # 64 lanes, 4 pages
@@ -1141,7 +1145,8 @@ def test_roofline_readers_do_not_count_the_writer(one_chip, on_tpu):
             assert not any(pattern.search(n) for n in writer), writer[:1]
 
 
-@pytest.mark.parametrize("T", [64, 2048], ids=["decode", "prefill_chunk"])
+@pytest.mark.parametrize("T", [64, 256, 2048],
+                         ids=["decode", "lanes256", "prefill_chunk"])
 def test_mixtral_step_reads_expert_stacks_in_place(one_chip, on_tpu, T):
     """Rehearsal 3 for the cell ``mixtral-8x7b.chat``: no operation of the
     2-layer step yields a layer's expert matrices (8 x 4096 x 14336 bf16)
@@ -1151,11 +1156,15 @@ def test_mixtral_step_reads_expert_stacks_in_place(one_chip, on_tpu, T):
     tick's 28 ms of device time before PR 31, and 5.6 GB of the program's
     temporaries); the products index the whole stack by group instead
     (``no_drop_moe``'s ``layer``). Which product: the rule's
-    (``parallel/moe.expert_product``): an expert's 4096 x 14336 matrix is
-    no single block of ``grouped_matmul``, so both programs keep
-    ``ragged_dot`` and are the parent's (PERF.md section 6, PR 46: the
-    kernel's tiled form is faster at this shape under the ridge, and its
-    two more Mosaic kernels a program cost the cell's set-up 9%)."""
+    (``parallel/moe.expert_product``). The decode program's 16 rows an
+    expert and the 256-lane program's 64 are under the ridge: two
+    ``grouped_matmul`` calls a layer in their tiled form (an expert's
+    4096 x 14336 matrix is no single block) and no ``ragged_dot`` left,
+    since PR 47, when an engine on the tiled
+    attention path came to hold one step program a lane bucket and the
+    kernels' lowering stopped costing the cell's set-up 28 programs'
+    worth (PERF.md section 6). The 2,048-lane program's 512 rows an expert
+    are past the ridge and keep ``ragged_dot``."""
     from deepspeed_tpu.parallel.moe import expert_product
 
     # the cell runs at JAX's default matmul precision; under conftest's
@@ -1163,10 +1172,19 @@ def test_mixtral_step_reads_expert_stacks_in_place(one_chip, on_tpu, T):
     with jax.default_matmul_precision("default"):
         compiled, c, e = compile_cell_step("mixtral-8x7b", one_chip, T, 128)
     hlo = compiled.as_text()
-    assert expert_product("pallas", T * c.top_k, c.n_experts, c.d_model,
-                          c.d_ff, jnp.bfloat16) == "ragged_dot"
-    assert hlo.count("ragged-dot") >= 3 * c.n_layers
-    assert not _grouped_calls(hlo)
+    rows = T * c.top_k
+    grouped = _grouped_calls(hlo)
+    if T < 2048:
+        assert expert_product("pallas", rows, c.n_experts) == "kernel"
+        assert len(grouped) == 2 * c.n_layers
+        assert {re.search(r"= bf16\[(\d+,\d+)\]", l).group(1)
+                for l in grouped} == {f"{rows},{c.d_ff}",
+                                      f"{rows},{c.d_model}"}
+        assert "ragged-dot" not in hlo
+    else:
+        assert expert_product("pallas", rows, c.n_experts) == "ragged_dot"
+        assert hlo.count("ragged-dot") >= 3 * c.n_layers
+        assert not grouped
     leaf = c.n_experts * c.d_model * c.d_ff
     entry = hlo[hlo.index("\nENTRY "):]
     # results, tuples too (the parent's copies are multi-output fusions)
