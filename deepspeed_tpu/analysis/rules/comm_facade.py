@@ -13,11 +13,7 @@ applying, and the T3 overlap schedule can't stage what it can't see.
 
 Scope (path-based, like the wall-clock rule): files named
 ``parallel/zero*.py`` or ``runtime/engine*.py`` — the ZeRO placement /
-schedule layer and the training engine — plus the kernel-backend
-modules ``comm/backends*.py`` and ``ops/pallas/fused_collectives*.py``:
-backends compose Pallas kernels with facade-routed wire hops
-(``ring_permute``, ``quantized_chunk_exchange``, ``chunked_all_reduce``)
-and must not smuggle raw collectives past the ledger either. The facade
+schedule layer and the training engine. The facade
 module itself and the low-level collective layers (``comm/comm.py``,
 ``comm/compressed.py``, ``parallel/compressed.py``, ``parallel/ring.py``,
 ...) are out of scope: they ARE the implementation the facade wraps.
@@ -44,11 +40,7 @@ from ..model import FunctionInfo, ModuleInfo, PackageModel, iter_shallow
 from ..registry import Rule, register
 
 #: ZeRO-3 hot-path modules whose collectives must flow through the facade
-#: (incl. the kernel-backend seam: backends fuse compute with facade-
-#: routed wire hops, never with raw jax.lax collectives)
-_SCOPE = re.compile(r"(^|/)(parallel/zero[^/]*\.py|runtime/engine[^/]*\.py"
-                    r"|comm/backends[^/]*\.py"
-                    r"|ops/pallas/fused_collectives[^/]*\.py)$")
+_SCOPE = re.compile(r"(^|/)(parallel/zero[^/]*\.py|runtime/engine[^/]*\.py)$")
 
 #: jax.lax collective primitives (the wire-moving set)
 _COLLECTIVES = {"psum", "pmean", "pmax", "pmin", "all_gather",
@@ -99,9 +91,7 @@ def _resolves_to_lax(mod: ModuleInfo, func: ast.AST) -> Optional[str]:
 class CommFacadeRule(Rule):
     id = "comm-facade"
     summary = ("raw jax.lax collectives in ZeRO-3 hot paths "
-               "(parallel/zero*.py, runtime/engine*.py) or kernel "
-               "backends (comm/backends*.py, ops/pallas/"
-               "fused_collectives*.py) that bypass the "
+               "(parallel/zero*.py, runtime/engine*.py) that bypass the "
                "compressed-collectives facade and its wire ledger")
 
     def run(self, pkg: PackageModel) -> Iterator[Finding]:

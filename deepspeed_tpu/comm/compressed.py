@@ -268,12 +268,10 @@ def quantized_chunk_exchange(payload: jnp.ndarray, s: jnp.ndarray, *,
     reduce-scatter hop), dense reduce of the dequantized chunk in FIXED
     rank order (axis 0 of the all_to_all result is the source rank, so
     the accumulation order is deterministic and identical on every
-    rank), re-quantize, all_gather (the broadcast hop). Shared by the
-    facade reduction (:func:`hierarchical_pmean`) and the fused kernel
-    backends (comm/backends.py) so both paths move bit-identical wire
-    payloads. ``n`` is the logical element count (payload is packed for
-    int4); ``reduce`` picks mean (gradients) or sum (the decode MLP
-    all-reduce)."""
+    rank), re-quantize, all_gather (the broadcast hop): the cross-slice
+    hops of :func:`hierarchical_pmean`. ``n`` is the logical element
+    count (payload is packed for int4); ``reduce`` picks mean
+    (gradients) or sum."""
     record_collective(f"{op_prefix}_reduce_scatter", n * 4,
                       qspec.wire_nbytes(n), axis_name, world)
     p_recv = jax.lax.all_to_all(payload.reshape(world, -1), axis_name,
@@ -412,80 +410,6 @@ def tree_hierarchical_pmean(grads: Any, *, outer_axis: str,
 
 
 # ----------------------------------------------------------------------
-# kernel-backend building blocks (comm/backends.py): the wire-moving
-# primitives the fused Pallas backend composes with its kernels. They
-# live here so the backends themselves contain no raw jax.lax
-# collectives (the dslint comm-facade rule covers backend modules too).
-
-def ring_permute(x: jnp.ndarray, axis_name: str, *, world: int,
-                 op: str = "ring_permute") -> jnp.ndarray:
-    """One ring hop: every rank sends ``x`` to its successor on
-    ``axis_name`` and receives its predecessor's. The fused all-gather
-    backend issues one of these per tile step — tile i+1's shard is in
-    flight while tile i's dequant+matmul kernel runs. Ledger-recorded
-    per hop with logical == wire == the payload bytes (the payload IS
-    the wire format here; the compression claim lives in the caller's
-    per-collective summary row, which books logical-vs-quantized)."""
-    nbytes = _nbytes(x)
-    record_collective(op, nbytes, nbytes, axis_name, world)
-    perm = [(i, (i + 1) % world) for i in range(world)]
-    return jax.lax.ppermute(x, axis_name, perm)
-
-
-def chunked_all_reduce(y: jnp.ndarray, axis_name: str, *,
-                       qspec: Optional[QuantSpec] = None,
-                       op: str = "decode_mlp_all_reduce",
-                       reduce: str = "sum",
-                       stats: Optional[List[jnp.ndarray]] = None
-                       ) -> jnp.ndarray:
-    """Deterministic chunked all-reduce over one mesh axis: all_to_all
-    chunk exchange, dense reduce of the received chunks in FIXED rank
-    order, all_gather of the reduced chunk — the qgZ wire shape applied
-    to a sum. With a ``qspec`` the exchanged chunks are blockwise-
-    quantized (the serving-decode compression lever); without one the
-    chunks move dense (wire == logical) but the rank-ordered
-    accumulation is still deterministic, so the XLA and Pallas kernel
-    backends produce bit-identical results by construction (an ordinary
-    ``psum``'s accumulation order is the compiler's choice). Tensors
-    whose size does not chunk-divide fall back to the plain dense
-    ``psum``/``pmean`` (metered)."""
-    world = jax.lax.axis_size(axis_name)
-    if world <= 1:
-        return y
-    n = y.size
-    flat = y.reshape(-1).astype(jnp.float32)
-    if qspec is not None and qspec.divides(n, world):
-        q, s, _ = quantize_blockwise(flat, bits=qspec.bits, block=qspec.block,
-                                     manual_sharding=True)
-        if stats is not None:
-            deq = dequantize_blockwise(q, s, block=qspec.block,
-                                       manual_sharding=True)
-            stats.append(_rel_err(flat, deq))
-        payload = pack_int4(q) if qspec.bits == 4 else q
-        out = quantized_chunk_exchange(
-            payload, s, n=n, axis_name=axis_name, world=world, qspec=qspec,
-            op_prefix=op, reduce=reduce, stats=stats)
-        return out.reshape(y.shape).astype(y.dtype)
-    if qspec is not None:
-        _note_fallback(op)
-    if n % world == 0:
-        record_collective(f"{op}_reduce_scatter", n * 4, n * 4,
-                          axis_name, world)
-        recv = jax.lax.all_to_all(flat.reshape(world, -1), axis_name,
-                                  0, 0, tiled=False)
-        chunk = (jnp.mean(recv, axis=0) if reduce == "mean"
-                 else jnp.sum(recv, axis=0))
-        record_collective(f"{op}_all_gather", chunk.size * 4, chunk.size * 4,
-                          axis_name, world)
-        out = jax.lax.all_gather(chunk, axis_name, axis=0, tiled=True)
-        return out.reshape(y.shape).astype(y.dtype)
-    # not even chunkable (tiny/ragged): the plain dense collective
-    record_collective(f"{op}_dense", n * 4, n * 4, axis_name, world)
-    red = jax.lax.pmean if reduce == "mean" else jax.lax.psum
-    return red(y, axis_name)
-
-
-# ----------------------------------------------------------------------
 # T3-style exposure model (shared by the NORTHSTAR projection, the
 # MULTICHIP comm lane and the quant-comm smoke gate)
 
@@ -495,8 +419,7 @@ def modeled_exposure(*, param_bytes: float, grad_bytes: float,
                      weight_qspec: Optional[QuantSpec] = None,
                      grad_qspec: Optional[QuantSpec] = None,
                      weight_itemsize: int = 2,
-                     grad_itemsize: int = 4,
-                     tiles_per_block: int = 1) -> Dict[str, float]:
+                     grad_itemsize: int = 4) -> Dict[str, float]:
     """Analytic exposed-comm model for the staged ZeRO-3 schedule.
 
     Per step, ZeRO-3 moves the parameter set through TWO all-gathers
@@ -514,21 +437,7 @@ def modeled_exposure(*, param_bytes: float, grad_bytes: float,
     with the forward window ``compute_s/3 / n_blocks`` per block and the
     backward window ``2*compute_s/3 / n_blocks`` (fwd:bwd FLOP ratio
     1:2). Compression scales the wire volume by the quantized ratio
-    before the division. All quantities are per-chip step time.
-
-    ``tiles_per_block`` models the fused kernel backend
-    (comm/backends.py) and applies to the FORWARD gather window only:
-    the fused forward splits each block's all-gather into that many
-    per-tile ring stages interleaved with slices of the same block's
-    compute (dequant+matmul tile i while tile i+1's shard is in
-    flight), so the forward fill shrinks from one block's collective to
-    one tile's. The backward is deliberately NOT tiled — the shipped
-    fused backward re-gathers the block through the plain facade (the
-    cotangent contracts over the gathered dim, which cannot
-    column-tile) and its reduce is one post-epilogue chunk exchange
-    (only the quantization is in-kernel) — so its fill/drain stays at
-    per-block granularity. At ``tiles_per_block=1`` this is exactly the
-    PR-10 per-layer block-schedule model."""
+    before the division. All quantities are per-chip step time."""
     frac = (world - 1) / world if world > 1 else 0.0
     numel_w = param_bytes / weight_itemsize
     numel_g = grad_bytes / grad_itemsize
@@ -538,17 +447,13 @@ def modeled_exposure(*, param_bytes: float, grad_bytes: float,
               if grad_qspec else grad_bytes)
     serial_dense = (2 * param_bytes + grad_bytes) * frac / link_bps
     serial_comp = (2 * w_wire + g_wire) * frac / link_bps
-    tiles = max(int(tiles_per_block), 1)
-    # per-block comm vs the compute window it hides behind; the forward
-    # gather additionally splits into `tiles` per-tile stages
+    # per-block comm vs the compute window it hides behind
     c_gather = w_wire * frac / link_bps / n_blocks
     c_reduce = g_wire * frac / link_bps / n_blocks
-    n_fwd_stages = n_blocks * tiles
-    c_gather_tile = c_gather / tiles
-    t_fwd = compute_s / 3.0 / n_fwd_stages
+    t_fwd = compute_s / 3.0 / n_blocks
     t_bwd = 2.0 * compute_s / 3.0 / n_blocks
-    fwd_exposed = (c_gather_tile
-                   + (n_fwd_stages - 1) * max(0.0, c_gather_tile - t_fwd))
+    fwd_exposed = (c_gather
+                   + (n_blocks - 1) * max(0.0, c_gather - t_fwd))
     bwd_exposed = (c_gather + c_reduce                       # fill + drain
                    + (n_blocks - 1) * max(0.0, c_gather + c_reduce - t_bwd))
     overlapped = fwd_exposed + bwd_exposed
@@ -561,44 +466,4 @@ def modeled_exposure(*, param_bytes: float, grad_bytes: float,
         "weight_wire_ratio": param_bytes / w_wire if w_wire else 1.0,
         "grad_wire_ratio": grad_bytes / g_wire if g_wire else 1.0,
         "n_blocks": float(n_blocks),
-        "tiles_per_block": float(tiles),
-    }
-
-
-def modeled_decode_ab(*, d_model: int, d_ff: int, tp: int,
-                      link_bps: float, peak_flops: float,
-                      batch: int = 1, itemsize: int = 2,
-                      qspec: Optional[QuantSpec] = None) -> Dict[str, float]:
-    """Analytic decode-latency A/B for the TP MLP down-projection: with
-    one token in flight the all-reduce of the [b, d_model] partial sums
-    is pure exposed latency after the matmul. The fused backend
-    (comm/backends.py matmul_all_reduce) splits the exchange into
-    ``tp`` per-tile chunk hops produced by the matmul kernel's epilogue,
-    so all but the pipeline fill hides behind the matmul itself:
-
-        unfused = t_matmul + t_allreduce
-        fused   = max(t_matmul, t_comm) + min(t_matmul, t_comm) / tp
-
-    (two-stage pipeline over ``tp`` tiles). A ``qspec`` additionally
-    scales the exchanged bytes by the quantized wire ratio — the
-    serving-side compression lever."""
-    flops = 2.0 * batch * d_ff * d_model / tp          # per-chip partial
-    t_matmul = flops / peak_flops
-    n = batch * d_model
-    wire = qspec.wire_nbytes(n) if qspec else n * itemsize
-    frac = 2.0 * (tp - 1) / tp if tp > 1 else 0.0      # rs + ag hops
-    t_comm = wire * frac / link_bps
-    unfused = t_matmul + t_comm
-    tiles = max(tp, 1)
-    fused = (max(t_matmul, t_comm)
-             + min(t_matmul, t_comm) / tiles) if tp > 1 else t_matmul
-    return {
-        "t_matmul_s": t_matmul,
-        "t_allreduce_s": t_comm,
-        "decode_mlp_unfused_s": unfused,
-        "decode_mlp_fused_s": fused,
-        "fused_speedup": unfused / fused if fused > 0 else 1.0,
-        "exposed_comm_unfused_s": t_comm,
-        "exposed_comm_fused_s": max(0.0, fused - t_matmul),
-        "tp": float(tp),
     }

@@ -611,12 +611,6 @@ class CommCompressionConfig:
     grad_block: int = 256
     overlap: str = "staged"    # staged | serial | off
     error_stats: bool = False
-    # kernel backend of the facade (comm/backends.py): "auto" fuses the
-    # quantize/pack bracket into the adjacent matmul via Pallas kernels
-    # on TPU and keeps the plain XLA collectives elsewhere; "pallas" /
-    # "xla" force a backend ("pallas" off-TPU runs interpret mode — the
-    # CPU evidence-lane configuration)
-    kernel_backend: str = "auto"   # auto | xla | pallas
 
     def resolve_enabled(self, dp_size: int) -> bool:
         if isinstance(self.enabled, bool):
@@ -644,7 +638,6 @@ class CommCompressionConfig:
             grad_block=int(_take(d, "grad_block", 256)),
             overlap=str(_take(d, "overlap", "staged")),
             error_stats=bool(_take(d, "error_stats", False)),
-            kernel_backend=str(_take(d, "kernel_backend", "auto")),
         )
         for name, bits in (("weight_bits", out.weight_bits),
                            ("grad_bits", out.grad_bits)):
@@ -665,10 +658,6 @@ class CommCompressionConfig:
             raise ConfigError(
                 f"comm_compression.mesh_size_threshold must be >= 1, got "
                 f"{out.mesh_size_threshold}")
-        if out.kernel_backend not in ("auto", "xla", "pallas"):
-            raise ConfigError(
-                f"comm_compression.kernel_backend must be 'auto', 'xla' or "
-                f"'pallas', got '{out.kernel_backend}'")
         _warn_unknown(d, "comm_compression")
         return out
 

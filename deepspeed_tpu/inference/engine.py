@@ -57,10 +57,6 @@ class InferenceConfig:
     top_k: int = 0                            # 0 = greedy unless temperature>0
     top_p: float = 1.0
     seed: int = 0
-    # kernel backend of the comm facade (comm/backends.py): "auto" fuses
-    # the TP decode MLP's all-reduce into the matmul on TPU (Pallas) and
-    # keeps plain GSPMD collectives elsewhere; "pallas"/"xla" force it
-    kernel_backend: str = "auto"              # auto | xla | pallas
     # ZeRO-Inference weight-only quantization (reference
     # inference/quantization/: int8/int4 weights held quantized in HBM,
     # dequantized on the fly per forward): {"enabled": bool, "bits": 8|4,
@@ -118,18 +114,6 @@ class InferenceEngine:
         set_topology(self.topo)
         if hasattr(model, "bind_topology"):
             model.bind_topology(self.topo)
-        # fused kernel backend (comm/backends.py): under TP, bind it so
-        # the decode MLP's all-reduce runs inside the matmul kernel
-        # (models/transformer.py _down_proj) instead of as exposed
-        # latency; the default XLA backend changes nothing, so it is
-        # never bound
-        from ..comm.backends import resolve_backend
-
-        self.comm_backend = resolve_backend(self.config.kernel_backend)
-        if (tp > 1 and self.comm_backend.name == "pallas"
-                and hasattr(model, "bind_comm_backend")):
-            model.bind_comm_backend(self.comm_backend)
-
         if params is None:
             params = model.init(rng if rng is not None else
                                 jax.random.PRNGKey(self.config.seed))

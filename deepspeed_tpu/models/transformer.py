@@ -369,17 +369,6 @@ class Transformer:
         self._seq_size = 1
         self._tp_size = 1
         self._pipe_size = 1
-        self._comm_backend = None
-
-    def bind_comm_backend(self, backend) -> "Transformer":
-        """Attach a fused kernel backend (comm/backends.py). The TP
-        decode path's MLP down-projection then runs its partial matmul
-        and all-reduce fused (``matmul_all_reduce``) instead of leaving
-        GSPMD's psum as pure exposed latency after the matmul — see
-        :meth:`_down_proj`. Called by the inference engine when its
-        ``kernel_backend`` resolves to a fused backend."""
-        self._comm_backend = backend
-        return self
 
     def bind_topology(self, topo) -> "Transformer":
         """Attach the device mesh; activates Ulysses/ring sequence-parallel
@@ -939,49 +928,10 @@ class Transformer:
                 up = up * jax.nn.sigmoid(1.702 * up)
             else:
                 up = jax.nn.gelu(up)             # tanh approx (GPT-2 family)
-        down = self._down_proj(up, lp["w_down"])
+        down = up @ lp["w_down"]
         if c.use_bias:
             down = down + lp["b_down"]
         return down, jnp.zeros((), jnp.float32)
-
-    def _down_proj(self, up, w_down):
-        """Row-parallel MLP down-projection. On the TP decode path (one
-        query position in flight) with a fused kernel backend bound, the
-        partial matmul and its all-reduce run fused inside a shard_map
-        (``matmul_all_reduce``: the matmul epilogue produces the chunks
-        of a deterministic rank-ordered chunked all-reduce, per-tile
-        overlapped) — at decode the all-reduce is otherwise pure exposed
-        latency after a tiny matmul (docs/performance.md). Prefill,
-        training, unwrappable shapes and the default backend keep the
-        plain matmul and let GSPMD insert the psum."""
-        backend = self._comm_backend
-        mesh = self._mesh
-        tp = self._tp_size
-        if (backend is None or tp <= 1 or mesh is None
-                or up.ndim != 3 or up.shape[1] != 1):
-            return up @ w_down
-        batch_axes = tuple(getattr(self, "_batch_axes", None) or ())
-        dp = 1
-        for a in batch_axes:
-            dp *= mesh.shape.get(a, 1)
-        b, _, f = up.shape
-        d = w_down.shape[-1]
-        if (dp > 1 and b % dp) or f % tp:
-            return up @ w_down
-        from jax.sharding import PartitionSpec as P_
-
-        def fused(u, w):
-            y = backend.matmul_all_reduce(u.reshape(-1, u.shape[-1]), w,
-                                          "model", out_dtype=u.dtype)
-            return y.reshape(u.shape[0], 1, d)
-
-        return jax.shard_map(
-            fused, mesh=mesh,
-            in_specs=(P_(batch_axes or None, None, "model"),
-                      P_("model", None)),
-            out_specs=P_(batch_axes or None, None, None),
-            axis_names=set(batch_axes) | {"model"},
-            check_vma=False)(up, w_down)
 
     def _encode(self, params, x, angles=None, positions=None, rng=None,
                 training=False, attn_mask=None):

@@ -259,39 +259,6 @@ def compute_param_bytes(param_shapes: Any) -> int:
 # collective immediately at its consumer for the A/B.
 
 @dataclass
-class MatmulBlockSpec:
-    """Optional per-block fusion hint for the kernel-backend seam
-    (comm/backends.py): declares that block i's forward is
-
-        h' = epilogue(h @ p[weight], rest_of_p_gathered, h)
-
-    with ``weight`` the key of the 2-D matmul weight inside the block's
-    (dict) param tree. A fused backend can then run the weight's
-    all-gather inside the consuming matmul (per-tile dequant+multiply)
-    and the weight gradient's reduce-scatter inside the grad matmul's
-    epilogue. The epilogue must be a pure function of its three
-    arguments — the engine differentiates through it with jax.vjp."""
-
-    weight: str
-    epilogue: Callable[[Any, Any, Any], Any]
-
-
-@dataclass
-class FusedBlockOps:
-    """Backend-fused forward/backward for one block of the staged
-    schedule (built by the engine from a :class:`MatmulBlockSpec` and a
-    CollectiveBackend). ``forward(block_shard, h) -> h'`` consumes the
-    SHARDED block params (the gather happens inside, fused);
-    ``backward(block_shard, h_in, g_out) -> (reduced_grad_tree, g_h)``
-    re-gathers what it needs and returns grads ALREADY reduced across
-    the ZeRO group (the reduce-scatter is fused into the grad matmul),
-    so the schedule skips its own gather/reduce for this block."""
-
-    forward: Callable[[Any, Any], Any]
-    backward: Callable[[Any, Any, Any], Tuple[Any, Any]]
-
-
-@dataclass
 class BlockProgram:
     """A model decomposed into sequential blocks for the staged ZeRO-3
     schedule. ``block_fns[i](p_i, h) -> h'`` consumes the FULL (gathered)
@@ -302,19 +269,13 @@ class BlockProgram:
     opts into the staged engine path by exposing
     ``zero3_blocks(params, batch, rng) -> BlockProgram``; the params
     argument must be handled structurally (the engine also calls it on a
-    PartitionSpec tree to learn per-block shardings).
-
-    ``matmul_blocks`` (optional, parallel to ``block_fns``) carries
-    :class:`MatmulBlockSpec` fusion hints; entries may be None and the
-    whole field may be None — blocks without a hint always run the
-    generic gather + ``block_fn`` path."""
+    PartitionSpec tree to learn per-block shardings)."""
 
     block_fns: List[Callable[[Any, Any], Any]]
     blocks: List[Any]
     h0: Any
     loss_tail: Callable[[Any], Any]
     merge: Callable[[List[Any]], Any]
-    matmul_blocks: Optional[List[Optional[MatmulBlockSpec]]] = None
 
 
 def _probed(probe, phase: str, i: int, fn):
@@ -352,18 +313,10 @@ class Zero3BlockSchedule:
     def __init__(self, gather: Callable[[int, Any], Any],
                  reduce: Callable[[int, Any], Any],
                  overlapped: bool = True,
-                 fused: Optional[dict] = None,
                  probe: Optional[Callable] = None):
         self.gather = gather
         self.reduce = reduce
         self.overlapped = overlapped
-        # kernel-backend seam (comm/backends.py): {block index ->
-        # FusedBlockOps}. Fused blocks run their gather INSIDE the
-        # consuming matmul (per-tile ring) and return already-reduced
-        # grads (reduce-scatter in the grad matmul's epilogue), so the
-        # schedule issues no separate collectives for them; unfused
-        # blocks keep the per-block prefetch/defer issue order.
-        self.fused = fused or {}
         # per-block phase-timing seam (see :func:`_probed`): None on
         # every jitted path; the overlap profiler installs one to time
         # gather/fwd/regather/bwd/reduce per block on the host
@@ -372,18 +325,13 @@ class Zero3BlockSchedule:
     def loss_and_grads(self, prog: BlockProgram, scale) -> Tuple[Any, List[Any]]:
         """(loss, per-block grad trees). Grads are wrt the FULL block
         params (each rank's local-batch contribution, reduced across the
-        ZeRO group by ``reduce`` — or inside a fused block's backward);
-        the loss comes back unreduced — the caller averages it over the
-        data axes."""
+        ZeRO group by ``reduce``); the loss comes back unreduced — the
+        caller averages it over the data axes."""
         L = len(prog.block_fns)
         assert L == len(prog.blocks) and L > 0
-        fused = self.fused
         probe = self.probe
 
         def _gather(i, phase="gather"):
-            # fused blocks gather inside their own kernels
-            if i in fused:
-                return None
             return _probed(probe, phase, i,
                            lambda: self.gather(i, prog.blocks[i]))
 
@@ -400,12 +348,8 @@ class Zero3BlockSchedule:
                 # prefetch: next block's gather issued BEFORE this
                 # block's compute consumes anything
                 nxt = _gather(i + 1)
-            if i in fused:
-                h = _probed(probe, "fwd", i,
-                            lambda: fused[i].forward(prog.blocks[i], h))
-            else:
-                h = _probed(probe, "fwd", i,
-                            lambda: prog.block_fns[i](full, h))
+            h = _probed(probe, "fwd", i,
+                        lambda: prog.block_fns[i](full, h))
             hs.append(h)
             if i + 1 < L:
                 full = nxt if self.overlapped else _gather(i + 1)
@@ -421,22 +365,18 @@ class Zero3BlockSchedule:
             nxt = None
             if self.overlapped and i > 0:
                 nxt = _gather(i - 1, phase="regather")
-            if i in fused:
-                grads[i], g_h = _probed(
-                    probe, "bwd", i,
-                    lambda: fused[i].backward(prog.blocks[i], hs[i], g_h))
-            else:
-                def _bwd(i=i, full=full, g=g_h):
-                    _, vjp = jax.vjp(prog.block_fns[i], full, hs[i])
-                    return vjp(g)
 
-                g_full, g_h = _probed(probe, "bwd", i, _bwd)
-                if self.overlapped:
-                    if pending is not None:
-                        grads[pending_i] = _reduce(pending_i, pending)
-                    pending, pending_i = g_full, i
-                else:
-                    grads[i] = _reduce(i, g_full)
+            def _bwd(i=i, full=full, g=g_h):
+                _, vjp = jax.vjp(prog.block_fns[i], full, hs[i])
+                return vjp(g)
+
+            g_full, g_h = _probed(probe, "bwd", i, _bwd)
+            if self.overlapped:
+                if pending is not None:
+                    grads[pending_i] = _reduce(pending_i, pending)
+                pending, pending_i = g_full, i
+            else:
+                grads[i] = _reduce(i, g_full)
             if i > 0:
                 full = nxt if self.overlapped else _gather(i - 1,
                                                            phase="regather")
@@ -502,17 +442,7 @@ class SequentialBlockModel:
         def merge(trees: List[Any]) -> Any:
             return {f"block_{i}": t for i, t in enumerate(trees)}
 
-        def epilogue(i):
-            # must mirror _apply_block exactly with y = h @ p["w"]
-            # precomputed — the fused path's bit-exactness against the
-            # generic path rides on this
-            last = i == L - 1
-            return lambda y, rest, h: (y + rest["b"] if last
-                                       else jnp.tanh(y + rest["b"]))
-
         h0 = batch["x"] if isinstance(batch, dict) else batch
         return BlockProgram(block_fns=[block_fn(i) for i in range(L)],
                             blocks=blocks, h0=h0, loss_tail=loss_tail,
-                            merge=merge,
-                            matmul_blocks=[MatmulBlockSpec("w", epilogue(i))
-                                           for i in range(L)])
+                            merge=merge)

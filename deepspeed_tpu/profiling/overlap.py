@@ -1,7 +1,7 @@
 """Measured (not modeled) ZeRO-3 comm-overlap accounting.
 
-The comm-overlap wins shipped in the compressed-collectives and fused-
-kernel PRs are certified by ``comm.compressed.modeled_exposure`` — an
+The comm-overlap claims of the compressed-collectives facade are
+certified by ``comm.compressed.modeled_exposure`` — an
 *analytic* T3 model (bytes / bandwidth vs uniform compute windows).
 This module is the layer that keeps those claims honest:
 :func:`overlap_report` drives the REAL :class:`~deepspeed_tpu.parallel
@@ -248,7 +248,6 @@ def overlap_report(engine, batch, *, repeats: int = 3,
 
     blocks = [{
         "block": i,
-        "fused": i in sched.fused,
         "gather_s": g[i], "fwd_s": f[i], "regather_s": rg[i],
         "bwd_s": b[i], "reduce_s": r[i],
         "gather_wire_bytes": wire_sum("gather", i),

@@ -210,13 +210,6 @@ class TrainEngine:
         # explicit zero_optimization knobs opt individual legs in below it.
         self._cc = config.comm_compression
         cc_on = self._cc.resolve_enabled(self.topo.data_parallel_size)
-        # kernel backend of the facade (comm/backends.py): "auto" keeps
-        # the plain XLA collectives off-TPU, so CPU meshes are unchanged;
-        # "pallas" opts the staged schedule into the fused
-        # compute-collective kernels (interpret mode off-TPU)
-        from ..comm.backends import resolve_backend
-
-        self._comm_backend = resolve_backend(self._cc.kernel_backend)
         self._qwz = ((bool(config.zero.zero_quantized_weights) or cc_on)
                      and config.zero.stage >= 3)
         self._qgz = (bool(config.zero.zero_quantized_gradients)
@@ -771,29 +764,6 @@ class TrainEngine:
         block_specs = prog_struct.blocks
         overlapped = self._staged_mode == "staged"
         wants_err = self._wants_quant_err
-        # kernel-backend seam: blocks whose MatmulBlockSpec weight is
-        # sharded exactly on the matmul's OUTPUT dim over the single
-        # quantized outer axis can fuse gather-into-matmul and
-        # reduce-into-epilogue (comm/backends.py); everything else —
-        # contraction-dim shards, multi-axis leaves, the XLA backend —
-        # keeps the generic per-block gather/reduce path below
-        fusable = {}
-        mm_specs = getattr(prog_struct, "matmul_blocks", None)
-        if (self._comm_backend.name == "pallas" and mm_specs
-                and env["outer"] and env["outer_world"] > 1):
-            for i, ms in enumerate(mm_specs):
-                if ms is None or not isinstance(block_specs[i], dict):
-                    continue
-                wspec = block_specs[i].get(ms.weight)
-                if not isinstance(wspec, PartitionSpec):
-                    continue
-                entries = [(d, e if not (isinstance(e, tuple) and
-                                         len(e) == 1) else e[0])
-                           for d, e in enumerate(tuple(wspec))
-                           if e is not None]
-                if entries == [(1, env["outer"])]:
-                    fusable[i] = ms
-
         def spmd(pc, mb, rng, scale):
             stats = [] if wants_err else None
             prog = self.model.zero3_blocks(pc, mb, rng)
@@ -814,11 +784,7 @@ class TrainEngine:
                     inner_world=env["inner_world"], qspec=env["gq"],
                     stats=stats)
 
-            fused_ops = {i: self._fused_block_ops(ms, block_specs[i], env,
-                                                  stats)
-                         for i, ms in fusable.items()}
-            sched = Zero3BlockSchedule(gather, reduce, overlapped=overlapped,
-                                       fused=fused_ops or None)
+            sched = Zero3BlockSchedule(gather, reduce, overlapped=overlapped)
             loss, block_grads = sched.loss_and_grads(prog, scale)
             grads = prog.merge(block_grads)
             loss = ccomm.pmean(loss.astype(jnp.float32), env["axes"])
@@ -831,76 +797,6 @@ class TrainEngine:
         aux_spec = {"quant_rel_err": env["rep"]} if wants_err else {}
         return self._run_facade_spmd(spmd, env, batch, rng, scale,
                                      aux_spec=aux_spec)
-
-    def _fused_block_ops(self, ms, spec_tree, env, stats):
-        """FusedBlockOps for one matmul-annotated block of the staged
-        schedule: the forward runs the weight's all-gather INSIDE the
-        consuming matmul (per-tile ring dequant+multiply), the backward
-        fuses the weight-grad reduce-scatter into the grad matmul's
-        epilogue (in-kernel blockwise quantization); non-matmul leaves
-        (biases) keep the generic facade gather/reduce. Dataflow is
-        identical to the generic path — output tiles only ever split
-        non-contraction matmul dims — so the fused engine stays
-        bit-exact to the XLA-backend engine (pinned by
-        tests/test_fused_collectives.py and the run_tests.sh gate)."""
-        from ..comm import compressed as ccomm
-        from ..parallel.zero import FusedBlockOps
-
-        backend = self._comm_backend
-        wkey = ms.weight
-        outer = env["outer"]
-        wq, gq = env["wq"], env["gq"]
-        w_spec = spec_tree[wkey]
-        rest_specs = {k: v for k, v in spec_tree.items() if k != wkey}
-        # same small-leaf floor the generic reduce path applies
-        # (tree_hierarchical_pmean), so fallbacks line up
-        min_size = 4 * env["outer_world"] * (gq.block if gq else 1)
-
-        def gather_rest(blk):
-            rest = {k: v for k, v in blk.items() if k != wkey}
-            return jax.tree_util.tree_map(
-                lambda x, sp: ccomm.gather_param_leaf(
-                    x, sp, outer_axes=(outer,), qspec=wq, stats=stats),
-                rest, rest_specs, is_leaf=env["is_spec"])
-
-        def forward(blk, h):
-            rest_full = gather_rest(blk)
-            y = backend.all_gather_matmul(h, blk[wkey], outer, dim=1,
-                                          qspec=wq, stats=stats)
-            return ms.epilogue(y, rest_full, h)
-
-        def backward(blk, h_in, g_out):
-            # the schedule's second gather: rebuild W for the data-path
-            # cotangent (bit-identical values to the fused forward
-            # gather) and recompute y for the epilogue vjp — activation
-            # checkpointing at block boundaries, same as the generic
-            # backward's recompute
-            w_full = ccomm.gather_param_leaf(
-                blk[wkey], w_spec, outer_axes=(outer,), qspec=wq,
-                stats=stats)
-            rest_full = gather_rest(blk)
-            y = jax.lax.dot_general(
-                h_in, w_full, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32).astype(h_in.dtype)
-            _, evjp = jax.vjp(ms.epilogue, y, rest_full, h_in)
-            g_y, g_rest, g_h_epi = evjp(g_out)
-            g_w = backend.matmul_reduce_scatter(
-                h_in, g_y, outer_axis=outer,
-                outer_world=env["outer_world"], inner_axis=env["inner"],
-                inner_world=env["inner_world"], qspec=gq,
-                min_quant_size=min_size, stats=stats)
-            g_rest_red = ccomm.tree_hierarchical_pmean(
-                g_rest, outer_axis=outer, outer_world=env["outer_world"],
-                inner_axis=env["inner"], inner_world=env["inner_world"],
-                qspec=gq, stats=stats)
-            g_h = g_h_epi + jax.lax.dot_general(
-                g_y, w_full, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32).astype(h_in.dtype)
-            grads = dict(g_rest_red)
-            grads[wkey] = g_w.astype(jnp.float32)
-            return grads, g_h
-
-        return FusedBlockOps(forward=forward, backward=backward)
 
     def _build_train_step(self):
         cfg = self.config

@@ -235,20 +235,6 @@ if [ "$#" -eq 0 ]; then
         smoke_rc=$qc_rc
     fi
 
-    # fused-kernel gate (CPU evidence lane, docs/communication.md
-    # "Kernel backends"): the staged engine on the fused Pallas backend
-    # (interpret mode) must be BIT-exact to the XLA backend — losses
-    # and parameters, compressed and dense — with fusion engaging and
-    # structural fallbacks metered, zero recompiles across fused-scan
-    # steps, and the modeled per-tile exposure strictly below the PR-10
-    # per-layer block-schedule number
-    env JAX_PLATFORMS=cpu \
-        python scripts/_comm_lane.py --fused
-    fused_rc=$?
-    if [ "$smoke_rc" -eq 0 ]; then
-        smoke_rc=$fused_rc
-    fi
-
     # trace lane (CPU evidence lane, docs/observability.md "Tracing &
     # flight recorder"): a seeded DST schedule run twice must produce
     # bit-identical canonical span-tree hashes; the Chrome-trace export

@@ -6,25 +6,11 @@ MULTICHIP dryrun (``__graft_entry__.py``) and the quant-comm CI gate
 (``scripts/quant_comm_smoke.py``) — so the two cannot drift into
 asserting different invariants. Callers apply their own gates to the
 returned numbers.
-
-``--fused`` runs the kernel-backend leg (comm/backends.py): the staged
-engine on the fused Pallas backend (interpret mode) must be bit-exact
-to the XLA backend with fusion actually engaging, retrace-free in the
-fused scan, and the modeled per-tile exposure must sit strictly below
-the PR-10 per-layer number; the modeled decode MLP A/B rides along.
-Exits nonzero on any violation (the run_tests.sh fused gate).
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
-
-
-#: NORTHSTAR v5p-64 7B geometry (northstar_feasibility.py) — the shared
-#: inputs of the per-layer vs per-tile exposure comparison
-NORTHSTAR_GEOM = dict(param_bytes=13.5e9, grad_bytes=13.5e9, n_blocks=32,
-                      compute_s=1.23, link_bps=300e9, world=64,
-                      weight_itemsize=2, grad_itemsize=2)
 
 
 def build_comm_engine(cc_cfg: Dict[str, Any], *, batch_size: int,
@@ -115,118 +101,3 @@ def run_comm_ab(*, batch_size: int, steps_bitexact: int = 2,
             "compressed_losses": l_cmp,
             "ratios": ratios,
             "engine": e_cmp, "batch": batch}
-
-
-def run_fused_ab(*, batch_size: int = 32, steps: int = 3,
-                 seed: int = 6) -> Dict[str, Any]:
-    """The kernel-backend A/B (comm/backends.py): (1) the staged engine
-    on the fused Pallas backend must produce bit-identical losses AND
-    parameters to the XLA backend, compressed and dense, with fusion
-    actually engaging (comm/facade/fused > 0) and structural fallbacks
-    metered; (2) the fused scan must trace once (zero recompiles); (3)
-    the modeled per-tile exposure must sit STRICTLY below the PR-10
-    per-layer block-schedule number on the NORTHSTAR geometry; the
-    modeled decode MLP A/B is returned alongside. Raises AssertionError
-    on violations; callers gate the returned numbers further."""
-    import jax
-    import numpy as np
-
-    from deepspeed_tpu.comm import compressed as cc
-    from deepspeed_tpu.telemetry import MetricsRegistry, set_registry
-
-    rng = np.random.default_rng(seed)
-    batch = {"x": rng.normal(size=(batch_size, 64)).astype(np.float32),
-             "y": rng.normal(size=(batch_size, 64)).astype(np.float32)}
-    # dims put blocks 0/1 on output-dim shards (fused) and block 2 on a
-    # contraction-dim shard (metered structural fallback) — both legs of
-    # the backend in one engine
-    dims = (64, 256, 512, 64)
-    reg = set_registry(MetricsRegistry())
-    out: Dict[str, Any] = {}
-    for enabled, tag in ((True, "compressed"), (False, "dense")):
-        cfg = {"enabled": enabled, "weight_bits": 8, "grad_bits": 4,
-               "overlap": "staged"}
-        e_x = build_comm_engine(dict(cfg, kernel_backend="xla"),
-                                batch_size=batch_size, seed=seed, dims=dims)
-        e_p = build_comm_engine(dict(cfg, kernel_backend="pallas"),
-                                batch_size=batch_size, seed=seed, dims=dims)
-        l_x = [float(e_x.train_batch(batch)["loss"]) for _ in range(steps)]
-        l_p = [float(e_p.train_batch(batch)["loss"]) for _ in range(steps)]
-        assert l_x == l_p, (
-            f"fused backend NOT bit-exact to XLA backend ({tag}): "
-            f"{l_p} vs {l_x}")
-        for a, b in zip(jax.tree_util.tree_leaves(e_x.params),
-                        jax.tree_util.tree_leaves(e_p.params)):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), (
-                f"fused backend params drifted from XLA backend ({tag})")
-        out[f"losses_{tag}"] = l_p
-    fused_calls = reg.counter("comm/facade/fused").value
-    assert fused_calls > 0, "fused backend never engaged"
-    out["fused_traced_calls"] = fused_calls
-    out["fallback_traced_calls"] = reg.counter(
-        "comm/facade/fallbacks").value
-    # zero recompiles across fused-scan steps on the Pallas backend
-    e_p.train_steps([batch, batch])
-    e_p.train_steps([batch, batch])
-    assert e_p.trace_count("train_steps_2") == 1, (
-        f"fused backend retraced the scan: "
-        f"{e_p.trace_count('train_steps_2')} traces")
-    assert reg.counter("train/recompiles").value == 0, (
-        "recompile guard tripped on the fused backend")
-    # modeled per-tile vs per-layer exposure (shared NORTHSTAR geometry)
-    qspecs = dict(weight_qspec=cc.QuantSpec(8, 256),
-                  grad_qspec=cc.QuantSpec(4, 256))
-    per_layer = cc.modeled_exposure(**NORTHSTAR_GEOM, **qspecs)
-    per_tile = cc.modeled_exposure(
-        tiles_per_block=NORTHSTAR_GEOM["world"] - 1, **NORTHSTAR_GEOM,
-        **qspecs)
-    assert (per_tile["overlapped_compressed_s"]
-            < per_layer["overlapped_compressed_s"]), (
-        "per-tile exposure not below the per-layer block-schedule number")
-    out["modeled_exposure_per_layer_s"] = per_layer[
-        "overlapped_compressed_s"]
-    out["modeled_exposure_per_tile_s"] = per_tile["overlapped_compressed_s"]
-    out["decode_mlp_ab"] = cc.modeled_decode_ab(
-        d_model=4096, d_ff=11008, tp=8, link_bps=300e9, peak_flops=459e12)
-    return out
-
-
-def _fused_main() -> int:
-    import json
-    import os
-    import subprocess
-    import sys
-
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, here)
-    child_var = "_DST_COMM_LANE_CHILD"
-    if os.environ.get(child_var) == "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        assert len(jax.devices()) >= 8, len(jax.devices())
-        try:
-            out = run_fused_ab(batch_size=32)
-        except AssertionError as e:
-            print(f"[comm-lane] FUSED GATE FAIL: {e}", flush=True)
-            return 1
-        print(json.dumps({k: (round(v, 6) if isinstance(v, float) else v)
-                          for k, v in out.items()}), flush=True)
-        print("[comm-lane] fused gate PASS", flush=True)
-        return 0
-    from __graft_entry__ import cpu_child_env
-
-    env = cpu_child_env(8)
-    env[child_var] = "1"
-    proc = subprocess.run([sys.executable, os.path.abspath(__file__)]
-                          + sys.argv[1:], env=env, cwd=here, timeout=900)
-    return proc.returncode
-
-
-if __name__ == "__main__":
-    import sys
-
-    if "--fused" in sys.argv:
-        sys.exit(_fused_main())
-    print("usage: python scripts/_comm_lane.py --fused")
-    sys.exit(2)

@@ -16,12 +16,6 @@ Writes NORTHSTAR_<round>.json (round tag via DST_ROUND, default r05):
   counts from the compiled HLO (all-gather / reduce-scatter / all-reduce
   — the ZeRO-3 schedule GSPMD emitted), and the remat plan.
 
-r07 (ISSUE 11): adds the fused kernel-backend projection — per-tile
-stage counts (``modeled_exposure(tiles_per_block=world-1)``,
-``comm_compression_fused`` / ``zero3_comm_exposed_s_fused`` per config)
-— and the serving-decode MLP all-reduce A/B (``decode_mlp_ab``), both
-gated by run_tests.sh.
-
 r05 (VERDICT r4 weak #5): pred_mfu is no longer a bare ceiling that is
 1.0 by construction. The compute term is anchored to the MEASURED
 single-chip MFU (MFU_SWEEP_r04.json's best row — kernel+XLA efficiency
@@ -220,21 +214,6 @@ def _run_child():
         exposed = cc_model["overlapped_compressed_s"]
         mfu_overlapped = compute_s / max(compute_eff_s + exposed, 1e-12)
 
-        # r07 (ISSUE 11, docs/communication.md "Kernel backends"): the
-        # fused kernel-backend projection — each per-block collective
-        # splits into per-TILE stages (the ring all-gather fused into
-        # the consuming matmul, comm/backends.py), so fill/drain
-        # shrinks from one block's collective to one ring tile's. Gated
-        # strictly below the per-layer number by the run_tests.sh
-        # fused gate.
-        cc_fused = modeled_exposure(
-            param_bytes=param_bytes, grad_bytes=param_bytes,
-            n_blocks=model.config.n_layers, compute_s=compute_eff_s,
-            link_bps=ici_eff, world=n,
-            weight_qspec=QuantSpec(8, 256), grad_qspec=QuantSpec(4, 256),
-            weight_itemsize=2, grad_itemsize=2, tiles_per_block=n - 1)
-        exposed_fused = cc_fused["overlapped_compressed_s"]
-
         # the ZeRO-3 collective schedule GSPMD emitted
         hlo = compiled.as_text()
         colls = {c: hlo.count(f" {c}(")
@@ -260,13 +239,6 @@ def _run_child():
             comm_compression={
                 k: (round(v, 4) if isinstance(v, float) else v)
                 for k, v in cc_model.items()},
-            # fused kernel backend: per-tile stages (r07)
-            zero3_comm_exposed_s_fused=round(exposed_fused, 6),
-            comm_compression_fused={
-                k: (round(v, 6) if isinstance(v, float) else v)
-                for k, v in cc_fused.items()},
-            pred_mfu_fused=round(
-                compute_s / max(compute_eff_s + exposed_fused, 1e-12), 4),
             pred_mfu_overlapped=round(mfu_overlapped, 4),
             roofline_step_s=round(step_ceiling, 4),
             tokens_per_step=tokens,
@@ -287,26 +259,6 @@ def _run_child():
               f"(budget {V5P_HBM / 1e9:.0f}), pred_mfu "
               f"{entry['pred_mfu_floor']}..{entry['pred_mfu_ceiling']}",
               flush=True)
-
-    # r07: the serving-decode MLP A/B — with one token in flight the TP
-    # all-reduce is pure exposed latency after a tiny matmul until it
-    # lives inside the MLP kernel (comm/backends.py matmul_all_reduce,
-    # models/transformer.py _down_proj). 7B MLP geometry at tp=8 against
-    # the same v5p ICI model; gated fused < unfused by quant_comm_smoke.
-    from deepspeed_tpu.comm.compressed import modeled_decode_ab
-
-    def _decode_ab(qspec=None):
-        return {k: (float(f"{v:.6g}") if isinstance(v, float) else v)
-                for k, v in modeled_decode_ab(
-                    d_model=4096, d_ff=11008, tp=8, link_bps=300e9,
-                    peak_flops=V5P_PEAK, qspec=qspec).items()}
-
-    report["decode_mlp_ab"] = {
-        "geometry": {"model": "llama-2-7b mlp", "d_model": 4096,
-                     "d_ff": 11008, "tp": 8, "link_gbps": 300.0},
-        "dense": _decode_ab(),
-        "int8": _decode_ab(QuantSpec(8, 256)),
-    }
 
     ok = [c for c in report["configs"] if c.get("feasible")]
     report["feasible_count"] = len(ok)

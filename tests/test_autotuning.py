@@ -21,7 +21,6 @@ def _constraints(**kw):
     return TuningConstraints(**base)
 
 
-@pytest.mark.slow   # AOT-compiles a full candidate grid (~30-60s on the CPU mesh); the tier-1 lane keeps the cheap sp/remat probes
 def test_autotune_returns_feasible_best():
     result = autotune(_factory, _constraints())
     assert result["mesh"]["data"] * result["mesh"]["model"] == len(jax.devices())
@@ -38,7 +37,6 @@ def test_autotune_returns_feasible_best():
         assert c["flops"] > 0 and c["peak_bytes"] > 0
 
 
-@pytest.mark.slow   # AOT-compiles a full candidate grid (~30-60s on the CPU mesh); the tier-1 lane keeps the cheap sp/remat probes
 def test_autotune_beats_or_matches_naive():
     """The tuned config's estimated step cost must not exceed the naive
     (first-enumerated) feasible candidate's."""
@@ -49,7 +47,6 @@ def test_autotune_beats_or_matches_naive():
     assert report["best"]["est_step_s"] <= naive["est_step_s"]
 
 
-@pytest.mark.slow   # AOT-compiles a full candidate grid (~30-60s on the CPU mesh); the tier-1 lane keeps the cheap sp/remat probes
 def test_memory_budget_marks_infeasible():
     """A absurdly small HBM budget must reject every candidate."""
     tuner = Autotuner(_factory, _constraints(hbm_bytes=1024.0))

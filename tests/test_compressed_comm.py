@@ -9,6 +9,7 @@ tolerance (compression on), compressed-vs-dense convergence parity,
 one-trace staged scans, and the bytes-on-wire ledger schema (v2
 wire_bytes, backward-compatible with archived v1 records)."""
 
+import logging
 import os
 
 import jax
@@ -22,7 +23,8 @@ from deepspeed_tpu.comm import compressed as cc
 from deepspeed_tpu.comm.comm import (CommsLogger, configure_comms_logger,
                                      get_comms_logger)
 from deepspeed_tpu.ops.quantizer import (dequantize_blockwise, pack_int4,
-                                         quantize_blockwise, unpack_int4)
+                                         quantize_blockwise,
+                                         quantized_nbytes, unpack_int4)
 from deepspeed_tpu.parallel import mesh as mesh_mod
 from deepspeed_tpu.parallel.mesh import Topology
 from deepspeed_tpu.parallel.zero import (BlockProgram, SequentialBlockModel,
@@ -93,6 +95,33 @@ def test_int4_pack_unpack_roundtrip_exact():
                                   np.asarray(q))
 
 
+def test_quantized_nbytes_rounds_up():
+    # even/dividing: unchanged exact accounting
+    assert quantized_nbytes(512, 8, 256) == 512 + 2 * 4
+    assert quantized_nbytes(512, 4, 256) == 256 + 2 * 4
+    # odd numel at int4 occupies the trailing half-filled byte
+    assert quantized_nbytes(511, 4, 256) == 256 + 2 * 4
+    # ragged final block still carries a full fp32 scale
+    assert quantized_nbytes(257, 8, 256) == 257 + 2 * 4
+    assert quantized_nbytes(1, 4, 256) == 1 + 4
+
+
+def test_pack_int4_odd_length_raises():
+    with pytest.raises(ValueError, match="even number of elements"):
+        pack_int4(jnp.zeros((7,), jnp.int8))
+
+
+def test_pack_int4_non_contiguous_roundtrip():
+    # a transposed (non-contiguous) view must pack its ROW-MAJOR flatten
+    # and round-trip exactly
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.integers(-8, 8, size=(6, 4)), jnp.int8)
+    qt = q.T  # [4, 6], non-contiguous view of q's buffer
+    packed = pack_int4(qt)
+    np.testing.assert_array_equal(np.asarray(unpack_int4(packed)),
+                                  np.asarray(qt).reshape(-1))
+
+
 def test_quant_spec_validation():
     with pytest.raises(ValueError):
         cc.QuantSpec(5, 256)
@@ -111,12 +140,15 @@ def _run_spmd(topo, fn, *args, axes={"data"}, in_specs=None, out_specs=None):
         in_specs=in_specs, out_specs=out_specs, check_vma=False))(*args)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("bits,tol", [(8, 0.005), (4, 0.08)])
-def test_quantized_all_gather_matches_dense(bits, tol):
+def test_quantized_all_gather_matches_dense(bits, tol, dtype):
     topo = Topology.build_virtual({"data": 4})
     n = 2048
-    xs = jnp.asarray(np.random.default_rng(2).normal(size=(4, n)),
-                     jnp.float32)
+    xs = jnp.asarray(np.random.default_rng(2).normal(size=(4, n)), dtype)
+    # the gather dequantizes into the shard's own dtype: a bf16 weight
+    # adds its rounding (2^-8 of the value) to the quantization error
+    tol += 2.0 ** -8 if dtype == jnp.bfloat16 else 0.0
 
     def spmd(x):
         g = cc.quantized_all_gather(x[0], "data", dim=0,
@@ -125,11 +157,12 @@ def test_quantized_all_gather_matches_dense(bits, tol):
 
     g = _run_spmd(topo, spmd, xs, in_specs=(P("data"),),
                   out_specs=P("data"))
-    ref = np.asarray(xs).reshape(-1)
-    got = np.asarray(g)[0]
+    assert g.dtype == dtype
+    ref = np.asarray(xs, np.float32).reshape(-1)
+    got = np.asarray(g, np.float32)[0]
     assert np.abs(got - ref).max() / np.abs(ref).max() < tol
     # rank order must be preserved exactly (rank-major concat)
-    assert np.abs(got[:n] - np.asarray(xs)[0]).max() < tol * np.abs(ref).max()
+    assert np.abs(got[:n] - ref[:n]).max() < tol * np.abs(ref).max()
 
 
 def test_quantized_all_gather_fallback_is_dense_bitexact():
@@ -193,6 +226,49 @@ def test_hierarchical_pmean_dense_equals_flat_mean():
                                atol=1e-6)
     # replicated result: every rank identical
     np.testing.assert_array_equal(np.asarray(y)[0], np.asarray(y)[-1])
+
+
+def test_hierarchical_pmean_small_leaf_stays_dense_and_is_metered():
+    """A leaf under tree_hierarchical_pmean's floor (4 * outer_world *
+    block elements) is reduced dense: bit-equal to the plain pmean, and
+    counted under comm/facade/fallbacks; the leaf over the floor is
+    quantized (close, not equal)."""
+    from deepspeed_tpu.telemetry import (MetricsRegistry, get_registry,
+                                         set_registry)
+
+    topo = Topology.build_virtual({"data": 4})
+    qspec = cc.QuantSpec(8, 32)
+    floor = 4 * 4 * qspec.block
+    rng = np.random.default_rng(9)
+    # both leaves block- and chunk-divide: only the floor tells them apart
+    grads = {"small": jnp.asarray(rng.normal(size=(4, floor // 2)),
+                                  jnp.float32),
+             "large": jnp.asarray(rng.normal(size=(4, floor)), jnp.float32)}
+    assert qspec.divides(floor // 2, 4)
+    old_reg = get_registry()
+    reg = set_registry(MetricsRegistry())
+    try:
+        def spmd(g):
+            local = jax.tree_util.tree_map(lambda x: x[0], g)
+            red = cc.tree_hierarchical_pmean(local, outer_axis="data",
+                                             outer_world=4, qspec=qspec)
+            ref = jax.tree_util.tree_map(lambda x: cc.pmean(x, "data"),
+                                         local)
+            return jax.tree_util.tree_map(lambda x: x[None], (red, ref))
+
+        red, ref = _run_spmd(topo, spmd, grads, in_specs=(P("data"),),
+                             out_specs=P("data"))
+        np.testing.assert_array_equal(np.asarray(red["small"]),
+                                      np.asarray(ref["small"]))
+        assert not np.array_equal(np.asarray(red["large"]),
+                                  np.asarray(ref["large"]))
+        np.testing.assert_allclose(np.asarray(red["large"]),
+                                   np.asarray(ref["large"]), atol=0.05)
+        assert reg.counter("comm/facade/fallbacks").value == 1
+        assert reg.counter(
+            "comm/facade/fallbacks/qgz_inter_reduce_dense").value == 1
+    finally:
+        set_registry(old_reg)
 
 
 @pytest.mark.parametrize("bits,tol", [(8, 0.02), (4, 0.25)])
@@ -449,6 +525,33 @@ def test_engine_auto_threshold():
             "stage": 3, "stage3_param_persistence_threshold": 0,
             "zero_quantized_gradients": True}})
     assert explicit._qgz and not explicit._qwz
+
+
+def test_kernel_backend_is_an_unknown_key():
+    """The facade has one implementation: a config that still names a
+    kernel backend gets what every unknown key gets — one warning, and
+    the same settings as without the key, whatever it names."""
+    import dataclasses
+
+    from deepspeed_tpu.config import CommCompressionConfig
+    from deepspeed_tpu.utils.logging import logger
+
+    records = []
+    handler = logging.Handler()
+    handler.emit = records.append  # the package logger does not propagate
+    logger.addHandler(handler)
+    try:
+        got = [CommCompressionConfig.from_dict(
+            {"grad_bits": 4, "kernel_backend": value})
+            for value in ("pallas", "bogus")]
+    finally:
+        logger.removeHandler(handler)
+    plain = CommCompressionConfig.from_dict({"grad_bits": 4})
+    assert got == [plain, plain]
+    assert "kernel_backend" not in {f.name for f in dataclasses.fields(plain)}
+    assert [r.getMessage() for r in records] == [
+        "Unknown config key 'kernel_backend' in section "
+        "'comm_compression' — ignored"] * 2
 
 
 def test_engine_staged_one_trace_in_fused_scan():

@@ -404,20 +404,32 @@ def test_record_request_series(tmp_path):
         enabled = True
         output_dir = str(tmp_path / "req")
 
-    t = Telemetry(config=Cfg())
+    r = MetricsRegistry()
+    t = Telemetry(config=Cfg(), registry=r)
     t.record_request(latency_s=0.5, ttft_s=0.1, new_tokens=8,
                      decode_tokens_per_s=17.5)
     t.record_request(latency_s=0.7)
-    r = t.registry
     assert r.counter("inference/requests").value == 2
     assert r.counter("inference/generated_tokens").value == 8
     assert r.histogram("inference/ttft_s").count == 1
     assert r.histogram("inference/request_latency_s").percentile(100) == 0.7
     t.close()
-    # the disabled global stub drops request metrics silently
-    get_telemetry().record_request(latency_s=1.0)
-    assert "inference/requests" not in get_telemetry().registry.metrics() or \
-        get_telemetry().registry.counter("inference/requests").value == 2
+
+
+def test_disabled_stub_drops_request_metrics(monkeypatch):
+    import sys
+
+    # the nothing-configured stub is a lazy process-wide singleton bound
+    # to whichever registry was the default at its first use: have it
+    # built anew over a registry of this test's own
+    reg = set_registry(MetricsRegistry())
+    monkeypatch.setattr(sys.modules["deepspeed_tpu.telemetry.telemetry"],
+                        "_DISABLED", None)
+    stub = get_telemetry()
+    assert not stub.enabled and stub.registry is reg
+    stub.record_request(latency_s=1.0, ttft_s=0.1, new_tokens=8,
+                        decode_tokens_per_s=17.5)
+    assert reg.metrics() == {}
 
 
 # ----------------------------------------------------------------------

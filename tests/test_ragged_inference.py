@@ -507,16 +507,10 @@ def test_ragged_serves_internlm_layout():
         model, params, {3: list(range(1, 9)), 5: list(range(40, 50))}, 6)
 
 
-@pytest.mark.slow
 def test_sampled_decode_chunk_invariant_and_seeded():
     """temperature>0 sampling: same engine seed -> identical streams
     regardless of decode chunking; different seed -> different tokens;
-    all tokens in-vocab.
-
-    Slow-marked (three engine builds + compiles, ~14s — the PR-7
-    budget discipline: tier-1 must fit its 870s timeout): chunk
-    invariance stays tier-1-pinned on the greedy path by
-    test_chunked_decode_matches_single_step."""
+    all tokens in-vocab."""
     rng = np.random.default_rng(21)
     prompts = {i: rng.integers(1, 128, (9 + 3 * i,)).tolist() for i in range(2)}
     model = _llama()

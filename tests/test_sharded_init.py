@@ -7,7 +7,6 @@ partitioned_param_swapper.py (param offload to CPU/NVMe between steps).
 
 import jax
 import numpy as np
-import pytest
 
 import deepspeed_tpu as dst
 from deepspeed_tpu.models import Llama
@@ -74,14 +73,7 @@ def test_param_offload_cpu_parks_between_steps():
     assert kinds == {host_memory_kind()}, kinds
 
 
-@pytest.mark.slow
 def test_param_offload_cpu_same_trajectory_as_device():
-    # slow-marked (two full engine builds + compiles, ~20s — the PR-7
-    # budget discipline: tier-1 must fit its 870s timeout): the cpu
-    # param-offload leg stays tier-1-covered by
-    # test_param_offload_cpu_parks_between_steps (placement + training),
-    # and offload-vs-device trajectory equality by
-    # test_offload.test_cpu_offload_same_trajectory_as_device
     e_off, _, _, _ = dst.initialize(
         model=_model(), config=_config(offload_param={"device": "cpu"}),
         rng=jax.random.PRNGKey(0))

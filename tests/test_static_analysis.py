@@ -84,11 +84,6 @@ def test_rule_catalog():
     # the rule is scoped to the ZeRO-3 hot-path modules by file path
     ("comm-facade", os.path.join("parallel", "zero_bad.py"),
      os.path.join("parallel", "zero_ok.py")),
-    # kernel-backend modules (comm/backends*.py) are comm-facade scope
-    # too: backends fuse compute with facade-routed wire hops, never
-    # with raw jax.lax collectives
-    ("comm-facade", os.path.join("comm", "backends_bad.py"),
-     os.path.join("comm", "backends_ok.py")),
     # dsrace lockset analysis: a worker thread + public surface racing
     # on shared attributes; the ok twin exercises every safe idiom
     # (one lock, entry-lockset inference, queue hand-off, one-shot
@@ -169,14 +164,10 @@ def test_comm_facade_out_of_scope_module_is_ignored():
 
 
 def test_comm_facade_repo_hot_paths_clean():
-    # the shipped ZeRO-3 hot paths — and the kernel-backend modules —
-    # route every collective through the facade: the repo gate
-    # invariant this rule exists to keep
+    # the shipped ZeRO-3 hot paths route every collective through the
+    # facade: the repo gate invariant this rule exists to keep
     found = live(analyze([os.path.join(PKG, "parallel", "zero.py"),
-                          os.path.join(PKG, "runtime", "engine.py"),
-                          os.path.join(PKG, "comm", "backends.py"),
-                          os.path.join(PKG, "ops", "pallas",
-                                       "fused_collectives.py")]),
+                          os.path.join(PKG, "runtime", "engine.py")]),
                  "comm-facade")
     assert found == []
 

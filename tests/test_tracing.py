@@ -502,14 +502,10 @@ def test_overlap_report_requires_staged_path():
         e.overlap_report(_batch())
 
 
-@pytest.mark.slow
 def test_overlap_report_does_not_perturb_training():
     """The measurement drive must not touch the jitted step programs:
     a train_batch after overlap_report is bit-identical to one
-    without it. Slow-marked (two full staged-engine builds); the
-    tier-1 lane keeps the probe-seam bit-exactness and fused-scan
-    one-trace pins, and the trace lane drives overlap_report every
-    run."""
+    without it."""
     batch = _batch()
     e1 = _staged_engine({"enabled": True, "weight_bits": 8,
                         "grad_bits": 4, "overlap": "staged"}, seed=3)

@@ -4,7 +4,6 @@ the offline CLI tools (reference ds_to_universal.py + zero_to_fp32.py)."""
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 
 import deepspeed_tpu as dst
 from deepspeed_tpu.checkpoint.universal import (load_universal, to_universal,
@@ -56,13 +55,9 @@ def test_resume_across_mesh_and_stage(tmp_path):
     assert np.isfinite(l5)
 
 
-@pytest.mark.slow
 def test_universal_cli_roundtrip(tmp_path):
-    # slow-marked (~10s of engine builds + conversions — the PR-7
-    # budget discipline: tier-1 must fit its 870s timeout): the
-    # universal conversion + cross-mesh load machinery stays
-    # tier-1-pinned by test_resume_across_mesh_and_stage; this adds the
-    # offline CLI surface on top and runs in the full suite
+    # the offline CLI surface on top of the conversion + cross-mesh load
+    # machinery that test_resume_across_mesh_and_stage pins
     e = _engine({"data": 8}, stage=3)
     e.train_batch(shard_batch(_batch(0), e.topo))
     e.save_checkpoint(str(tmp_path / "ck"), tag="t")
