@@ -101,6 +101,13 @@ class Sizes:
     # a head, state channels, groups), on a leaf of four periods' runs
     kernel_ssd_state: Tuple[int, int, int, int] = (64, 64, 128, 1)
     kernel_ssd_periods: int = 4
+    # the latent kernel's: A.X-K1's (heads, cache row, latent) in its
+    # absorbed form, the row 512 + 64 values padded to whole lanes
+    kernel_latent: Tuple[int, int, int] = (64, 640, 512)
+    # ... and the pages of its longest context in `a.x-k1.docs` (16,128
+    # tokens: 63 of the kernel's chunks), which kernel_pages_per_seq's 8
+    # chunks never walk
+    kernel_latent_pages: int = 1008
     # --chips 4: global batch, split four ways under ZeRO-3
     zero3_layers: int = 1
     zero3_batch: int = 4
@@ -247,8 +254,8 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     from deepspeed_tpu.ops.attention import dot_product_attention
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        paged_attention, paged_attention_reference, work_list,
-        write_kv_pages, write_kv_rows)
+        latent_attention, latent_attention_reference, paged_attention,
+        paged_attention_reference, work_list, write_kv_pages, write_kv_rows)
 
     hq, hkv, hd, S = sz.n_heads, sz.n_kv_heads, sz.head_dim, sz.kernel_seq
     kq, kk, kv, kw, kp = jax.random.split(jax.random.PRNGKey(seed), 5)
@@ -348,6 +355,45 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
             errs[name + tag] = _rel_err(got[lanes], want)
             _check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
                    f"{name + tag}: non-finite kernel output")
+    # latent attention (every head over one shared row a token, the sum
+    # over the row's latent part) at A.X-K1's shape against its gather
+    # oracle, on the same three kinds of lanes
+    lh, lrow, lv = sz.kernel_latent
+    lpool = jax.random.normal(jax.random.fold_in(kp, 4000),
+                              (n_pages + 1, 1, blk, lrow), jnp.bfloat16)
+    lscale = lrow ** -0.5
+    lkernel = jax.jit(lambda q, s, p, pool, tb: latent_attention(
+        q, pool, tb, p, s, scale=lscale, v_dim=lv, interpret=interpret))
+    loracle = jax.jit(lambda q, s, p, pool, tb: latent_attention_reference(
+        q, pool, tb[s], p, scale=lscale, v_dim=lv))
+    # ... and a tick of the cell's: decode lanes at contexts in the upper
+    # half of the longest document's, a question's chunk behind that
+    # document, and lanes of no sequence, over page lists of that length
+    lmp = sz.kernel_latent_pages
+    nl = min(8, n_pages // lmp)
+    lctx, asked = lmp * blk, min(200, lmp * blk // 8)
+    dead = -(nl - 1 + asked) % 64
+    lshapes = {name.replace("paged", "latent"): (*lanes, tables)
+               for name, lanes in shapes.items()}
+    lshapes["latent_long"] = (
+        np.concatenate([np.arange(1, nl), np.zeros(asked), -np.ones(dead)]
+                       ).astype(np.int32),
+        np.concatenate([rng.integers(lctx // 2, lctx, (nl - 1,)),
+                        lctx * 3 // 4 + np.arange(asked), np.zeros(dead)]
+                       ).astype(np.int32),
+        tables.reshape(-1)[:nl * lmp].reshape(nl, lmp))
+    for name, (slots, pos, tbl) in lshapes.items():
+        T = len(slots)
+        qd = jax.random.normal(jax.random.fold_in(kq, 4000 + T),
+                               (T, lh, lrow), jnp.bfloat16)
+        got = lkernel(qd, jnp.asarray(slots), jnp.asarray(pos), lpool, tbl)
+        live = np.flatnonzero(slots >= 0)
+        lanes = live[np.linspace(0, len(live) - 1, 32).astype(np.int32)]
+        want = loracle(qd[lanes], jnp.asarray(slots[lanes]),
+                       jnp.asarray(pos[lanes]), lpool, tbl)
+        errs[name] = _rel_err(got[lanes], want)
+        _check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
+               f"{name}: non-finite kernel output")
     # the row writer against the scatter it replaced, on a pool that held
     # other values, at the cells' KV-head counts and the same three kinds
     # of lanes: every page but the sink (which only the scatter writes)
@@ -443,6 +489,9 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     rec.update(shape={"flash": [1, S, f"{hq}/{hkv}", hd],
                       "delta_state": [ns + 1, dh, dk, dv],
                       "ssd_state": [periods * (ns + 1), sh, sp, sn],
+                      "latent": [lh, lrow, lv],
+                      "latent_long": {"lanes": len(lshapes["latent_long"][0]),
+                                      "seqs": nl, "context": lctx},
                       "paged": {n: len(s[0]) for n, s in shapes.items()},
                       "paged_variants": {t or "bf16": list(v[:2])
                                          for t, v in variants.items()},

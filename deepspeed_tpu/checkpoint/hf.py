@@ -308,6 +308,81 @@ def sdar_moe_config(hc: Dict[str, Any], n_layers: Optional[int] = None):
         denoise_tokens=gen["block_length"] // gen["denoising_steps"])
 
 
+def axk1_config(hc: Dict[str, Any], n_layers: Optional[int] = None,
+                experts_held=None):
+    """``model_type: axk1`` (SKT A.X-K1) -> MoETransformerConfig. The
+    config is DeepSeek-V3's key for key: latent attention (``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``) with YaRN frequencies, ``first_k_dense_replace``
+    leading dense layers of ``intermediate_size``, then layers of
+    ``n_routed_experts`` SwiGLU experts of ``moe_intermediate_size`` scored
+    by ``scoring_func``, ``num_experts_per_tok`` taken inside ``topk_group``
+    of ``n_group`` groups, renormalised (``norm_topk_prob``) and scaled by
+    ``routed_scaling_factor``, beside ``n_shared_experts`` shared ones.
+
+    ASSUMED (the model's own code is not on this machine): ``topk_method:
+    "none"`` beside ``n_group`` / ``topk_group`` is read by the family's
+    convention as the group-limited choice with a group scored by its
+    largest member (DeepSeek-V2's ``group_limited_greedy``); ``"noaux_tc"``
+    names the bias-corrected choice, which is not implemented and refused.
+    ``n_layers`` keeps the first layers only; ``experts_held`` a range of
+    the routed experts (an expert share)."""
+    from ..models.moe import MoETransformerConfig
+
+    method = hc.get("topk_method", "greedy")
+    if method == "noaux_tc":
+        raise NotImplementedError(
+            "axk1 topk_method='noaux_tc' (a choice corrected by a learned "
+            "bias, e_score_correction_bias) is not supported")
+    if method not in ("none", "greedy", "group_limited_greedy"):
+        raise NotImplementedError(f"axk1 topk_method={method!r}")
+    if hc.get("moe_layer_freq", 1) != 1:
+        raise NotImplementedError("axk1 moe_layer_freq != 1 not supported")
+    if hc.get("attention_bias"):
+        raise NotImplementedError("axk1 attention_bias not supported")
+    if hc.get("hidden_act", "silu") != "silu":
+        raise NotImplementedError("axk1 experts are SwiGLU (hidden_act silu)")
+    if not hc.get("norm_topk_prob", False):
+        raise NotImplementedError("axk1 norm_topk_prob=false not supported "
+                                  "(the serving router renormalises)")
+    rs = hc.get("rope_scaling") or {}
+    if rs and rs.get("type", rs.get("rope_type")) != "yarn":
+        raise NotImplementedError(f"axk1 rope_scaling {rs} not supported")
+    if rs.get("mscale", 1) != rs.get("mscale_all_dim", 0) \
+            and rs.get("factor", 1.0) > 1:
+        raise NotImplementedError(
+            "axk1 rope_scaling mscale != mscale_all_dim (cos and sin "
+            "scaled) not supported")
+    grouped = method != "greedy" and hc.get("n_group", 1) > 1
+    heads = hc["num_attention_heads"]
+    return MoETransformerConfig(
+        vocab_size=hc["vocab_size"], d_model=hc["hidden_size"],
+        n_layers=int(n_layers or hc["num_hidden_layers"]), n_heads=heads,
+        d_ff=hc["moe_intermediate_size"],
+        dense_d_ff=hc["intermediate_size"],
+        first_dense_layers=hc.get("first_k_dense_replace", 0),
+        max_seq_len=hc.get("max_position_embeddings", 4096),
+        norm="rms", activation="silu_glu", position="rope",
+        rope_theta=float(hc.get("rope_theta", 10000.0)),
+        tie_embeddings=hc.get("tie_word_embeddings", False), use_bias=False,
+        norm_eps=hc.get("rms_norm_eps", 1e-6),
+        q_lora_rank=hc["q_lora_rank"], kv_lora_rank=hc["kv_lora_rank"],
+        qk_nope_dim=hc["qk_nope_head_dim"], qk_rope_dim=hc["qk_rope_head_dim"],
+        v_head_dim=hc["v_head_dim"],
+        rope_yarn_factor=float(rs.get("factor", 1.0)),
+        rope_yarn_original=int(rs.get("original_max_position_embeddings", 0)),
+        rope_yarn_beta_fast=float(rs.get("beta_fast", 32)),
+        rope_yarn_beta_slow=float(rs.get("beta_slow", 1)),
+        rope_yarn_mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+        n_experts=hc["n_routed_experts"], top_k=hc["num_experts_per_tok"],
+        scoring=hc.get("scoring_func", "softmax"),
+        n_groups=hc["n_group"] if grouped else 1,
+        topk_groups=hc["topk_group"] if grouped else 1,
+        routed_scale=float(hc.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=hc.get("n_shared_experts") or 0,
+        experts_held=experts_held)
+
+
 def hf_config(model_dir: str):
     """Parse HF config.json -> (family, TransformerConfig)."""
     from ..models.transformer import TransformerConfig
@@ -323,6 +398,8 @@ def hf_config(model_dir: str):
         return family, granite_hybrid_config(hc)
     if family == "sdar_moe":
         return family, sdar_moe_config(hc)
+    if family == "axk1":
+        return family, axk1_config(hc)
     if family in ("llama", "mistral"):
         # loud failure beats silently-wrong logits for unsupported variants
         if hc.get("rope_scaling"):
@@ -970,6 +1047,92 @@ def _map_sdar_moe(state, c) -> Dict[str, Any]:
     return params
 
 
+def _map_axk1(state, c) -> Dict[str, Any]:
+    """A.X-K1 under DeepSeek-V3's weight names (ASSUMED: the model's own
+    code is not on this machine): ``self_attn.q_a_proj`` /
+    ``q_a_layernorm`` / ``q_b_proj``, ``kv_a_proj_with_mqa`` /
+    ``kv_a_layernorm`` / ``kv_b_proj``, ``o_proj``; the leading dense
+    layers' ``mlp.{gate,up,down}_proj``; an expert layer's ``mlp.gate``
+    (the router), ``mlp.experts.{e}.{gate,up,down}_proj`` for the experts
+    held and ``mlp.shared_experts.{gate,up,down}_proj``.
+
+    Two relabellings. ``kv_b_proj`` holds a head's un-rotated key beside its
+    value; the native tree keeps the two as ``w_uk`` / ``w_uv``. DeepSeek's
+    forward de-interleaves a rotated part before it rotates halves (pair
+    (2i, 2i + 1) becomes (i, i + 32)); the native rotary rotates halves, so
+    the rotated columns of ``q_b_proj`` (a head at a time) and of
+    ``kv_a_proj_with_mqa`` are put in that order here, once."""
+    n, nd = c.n_layers, c.first_dense_layers
+    first, held = c.gate_config().held
+    h, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
+    r = c.kv_lora_rank
+    pre = "model." if "model.embed_tokens.weight" in state else ""
+    L = pre + "layers.{}."
+    if any("e_score_correction_bias" in k for k in state):
+        raise NotImplementedError(
+            "axk1: the checkpoint holds mlp.gate.e_score_correction_bias "
+            "(the bias-corrected choice, noaux_tc), which is not supported")
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+
+    w_uq = _stack(state, L + "self_attn.q_b_proj.weight", n, transpose=True)
+    w_uq = w_uq.reshape(n, -1, h, dn + dr)
+    w_uq = np.concatenate([w_uq[..., :dn], w_uq[..., dn:][..., halves]],
+                          -1).reshape(n, -1, h * (dn + dr))
+    w_dkv = _stack(state, L + "self_attn.kv_a_proj_with_mqa.weight", n,
+                   transpose=True)
+    w_dkv = np.concatenate([w_dkv[..., :r], w_dkv[..., r:][..., halves]], -1)
+    w_ukv = _stack(state, L + "self_attn.kv_b_proj.weight", n,
+                   transpose=True).reshape(n, r, h, dn + dv)
+
+    def moe(name):        # an expert layer's leaf, over the expert layers
+        return np.stack([state.pop((L + name).format(i)).T
+                         for i in range(nd, n)])
+
+    def experts(name):    # HF [out, in] -> native [n - nd, held, in, out]
+        return np.stack([np.stack(
+            [state.pop((L + "mlp.experts.{}." + name + ".weight")
+                       .format(i, e)).T for e in range(first, first + held)])
+            for i in range(nd, n)])
+
+    layers = {
+        "attn_norm_w": _stack(state, L + "input_layernorm.weight", n),
+        "w_dq": _stack(state, L + "self_attn.q_a_proj.weight", n,
+                       transpose=True),
+        "q_lora_norm_w": _stack(state, L + "self_attn.q_a_layernorm.weight", n),
+        "w_uq": w_uq, "w_dkv": w_dkv,
+        "kv_lora_norm_w": _stack(state, L + "self_attn.kv_a_layernorm.weight",
+                                 n),
+        "w_uk": np.ascontiguousarray(w_ukv[..., :dn]).reshape(n, r, h * dn),
+        "w_uv": np.ascontiguousarray(w_ukv[..., dn:]).reshape(n, r, h * dv),
+        "wo": _stack(state, L + "self_attn.o_proj.weight", n, transpose=True),
+        "mlp_norm_w": _stack(state, L + "post_attention_layernorm.weight", n),
+        "wg": moe("mlp.gate.weight"),
+        "w_gate": experts("gate_proj"), "w_up": experts("up_proj"),
+        "w_down": experts("down_proj"),
+    }
+    if c.n_shared_experts:
+        layers.update(
+            ws_gate=moe("mlp.shared_experts.gate_proj.weight"),
+            ws_up=moe("mlp.shared_experts.up_proj.weight"),
+            ws_down=moe("mlp.shared_experts.down_proj.weight"))
+    if nd:
+        layers["dense"] = {
+            leaf: np.stack([state.pop((L + "mlp." + name + ".weight")
+                                      .format(i)).T for i in range(nd)])
+            for leaf, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                               ("w_down", "down_proj"))}
+    params = {
+        "tok_embed": state[pre + "embed_tokens.weight"],
+        "layers": layers,
+        "final_norm_w": state[pre + "norm.weight"],
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = (state["lm_head.weight"]
+                             if "lm_head.weight" in state
+                             else state[pre + "embed_tokens.weight"]).T
+    return params
+
+
 def _map_bloom(state, c) -> Dict[str, Any]:
     n, nh, hd = c.n_layers, c.n_heads, c.d_model // c.n_heads
     pre = "transformer." if "transformer.word_embeddings.weight" in state else ""
@@ -1293,6 +1456,7 @@ _MAPPERS: Dict[str, Callable] = {
     "bert": _map_bert, "distilbert": _map_distilbert,
     "clip": _map_clip, "olmo_hybrid": _map_olmo_hybrid, "ouro": _map_ouro,
     "granitemoehybrid": _map_granite_hybrid, "sdar_moe": _map_sdar_moe,
+    "axk1": _map_axk1,
 }
 
 

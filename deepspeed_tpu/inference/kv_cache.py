@@ -133,14 +133,24 @@ class PrefixCache:
         """The published prefixes, least recently used first."""
         return list(self._entries)
 
+    @staticmethod
+    def _key(tokens: Sequence[int]) -> Tuple[int, ...]:
+        """A token stream in the keys' form, made ONCE a call: a level's key
+        is then a slice of it (a copy of references) and not a fresh walk
+        over the tokens. Building every level's tuple from the list cost an
+        admission of a 4k-15k-token prompt 6 ms at the median and 0.6 s
+        where nothing matched (PERF.md section 6, PR 49)."""
+        return tokens if isinstance(tokens, tuple) else tuple(map(int, tokens))
+
     def match(self, prompt: Sequence[int]) -> Tuple[int, List[int]]:
         """Longest cached full-block prefix of ``prompt``, capped so at
         least one prompt token remains to prefill (its logits seed
         generation). Returns (shared_token_count, blocks) — blocks are NOT
         yet retained for the caller."""
         bs = self.block_size
-        for k in range((len(prompt) - 1) // bs, 0, -1):
-            key = tuple(int(t) for t in prompt[: k * bs])
+        whole = self._key(prompt)
+        for k in range((len(whole) - 1) // bs, 0, -1):
+            key = whole[: k * bs]
             ent = self._entries.get(key)
             if ent is not None:
                 self._entries.move_to_end(key)
@@ -158,8 +168,9 @@ class PrefixCache:
         recency (a donor should not evict what it is donating) but does
         not count hits/misses. Returns (key, blocks) or (None, [])."""
         bs = self.block_size
-        for k in range(len(tokens) // bs, 0, -1):
-            key = tuple(int(t) for t in tokens[: k * bs])
+        whole = self._key(tokens)
+        for k in range(len(whole) // bs, 0, -1):
+            key = whole[: k * bs]
             ent = self._entries.get(key)
             if ent is not None:
                 self._entries.move_to_end(key)
@@ -180,7 +191,7 @@ class PrefixCache:
         k = min(seen, len(tokens)) // bs
         if k <= 0:
             return
-        key = tuple(int(t) for t in tokens[: k * bs])
+        key = self._key(tokens[: k * bs])
         if key in self._entries:
             self._entries.move_to_end(key)
             return
@@ -361,8 +372,14 @@ KV_BITS = {"none": 0, "int8": 8, "int4": 4}
 
 #: the pool's fields keyed by page; ``state`` and ``conv_rows`` are keyed
 #: by slot
-PAGED = ("k", "v", "k_scale", "v_scale")
-#: the fields a kind of layer owns a leaf of (those its model has)
+PAGED = ("k", "v", "k_scale", "v_scale", "latent")
+#: those of them a hand-off carries (``KVExport``, ``PageMoves.gather`` /
+#: ``write``): K/V a head and their scales; latent pages are shared and
+#: copied (the prefix cache, copy-on-write) and not exported yet
+KV_FIELDS = PAGED[:4]
+#: the fields a kind of layer owns a leaf of (those its model has: an
+#: attention layer K/V pages a head and, quantized, their scales, or under
+#: latent attention the one ``latent`` leaf and none of the others)
 OWNS = {"full": PAGED, "linear": ("state", "conv_rows"),
         "mamba": ("state", "conv_rows")}
 
@@ -406,6 +423,18 @@ class KVPool(NamedTuple):
     [P+1, hkv, bs]. The sink page's zeros dequantize to zeros, so
     masked-lane scatters stay harmless exactly as in the fp layout.
 
+    ``latent`` (latent attention, ``TransformerConfig.kv_lora_rank``): the
+    third kind, one ``[n_blocks + 1, 1, block, latent_row]`` leaf a layer
+    and no ``k`` / ``v``: a token's row is its normed latent beside the one
+    rotated key all heads share, 512 + 64 values at A.X-K1's sizes, padded
+    with zeros to whole lanes of 128 (640: 1,280 B in bfloat16, where K and
+    V a head would be 64 x (192 + 128) x 2 = 40,960 B). The pad is what a
+    [.., 576] leaf takes in the chip's tiled layout anyway, and it makes a
+    page slab [block, 640] a copy the paged kernel can issue. Keyed by page
+    like ``k`` / ``v``, so the allocator, the prefix cache and the page
+    moves carry it unchanged. The step's rows reach it by
+    ``write_kv_rows``' scatter, one index a lane (the leaf has one "head").
+
     ``state`` / ``conv_rows``: the second kind of cache, for each recurrent
     layer a float32 state leaf [max_seqs + 1, ...] (the gated delta rule's
     [H, dk, dv], Mamba-2's [H, P, N]: :func:`state_shapes`) and the
@@ -420,8 +449,15 @@ class KVPool(NamedTuple):
     v: Tuple = ()
     k_scale: Tuple = ()
     v_scale: Tuple = ()
+    latent: Tuple = ()
     state: Tuple = ()
     conv_rows: Tuple = ()
+
+
+def paged_leaf(pool: "KVPool"):
+    """A leaf keyed by page, for what every such leaf agrees on: axis 0,
+    pages and sink, a run a pass."""
+    return (pool.k or pool.latent)[0]
 
 
 class Leaves(NamedTuple):
@@ -520,8 +556,12 @@ def pool_leaves(model_config, ragged_config) -> KVPool:
     periods = cache_periods(c)
     passes = cache_passes(c) * periods
     rows = (passes * (cfg.n_kv_blocks + 1), c.n_kv_heads, cfg.kv_block_size)
+    # latent attention: the attention layers' pages are rows of the latent
+    # leaf, and there is no K / V a head
+    row = int(getattr(c, "latent_row", 0))
     payload = Leaves(
-        full, rows + (c.head_dim // 2 if bits == 4 else c.head_dim,),
+        0 if row else full,
+        rows + (c.head_dim // 2 if bits == 4 else c.head_dim,),
         {0: cfg.dtype, 8: jnp.int8, 4: jnp.uint8}[bits],
         PartitionSpec(None, "model", None, None), passes)
     scale = Leaves(full if bits else 0, rows, jnp.float32,
@@ -529,6 +569,9 @@ def pool_leaves(model_config, ragged_config) -> KVPool:
     state, conv = state_shapes(c) if recurrent else ((), ())
     slots = (periods * (cfg.max_seqs + 1),)
     return KVPool(k=payload, v=payload, k_scale=scale, v_scale=scale,
+                  latent=Leaves(full if row else 0,
+                                (rows[0], 1, rows[2], row), cfg.dtype,
+                                passes=passes),
                   state=Leaves(recurrent, slots + state, jnp.float32,
                                passes=periods),
                   conv_rows=Leaves(recurrent, slots + conv, cfg.dtype,
@@ -586,6 +629,19 @@ def kv_blocks_for_bytes(budget_bytes: int, model_config,
     return max(1, left // max(1, kv_page_bytes(model_config, ragged_config)))
 
 
+def refuse_latent(model_config, what: str) -> None:
+    """What a cache of latent rows refuses until someone needs it: a
+    quantized pool (the row's two parts want scales of their own), a pool
+    sharded over the model axis (the row has no head to shard by: every
+    device would hold all of it), and the hand-offs that name K and V
+    pages (``KVExport``)."""
+    if getattr(model_config, "latent_row", 0):
+        raise NotImplementedError(
+            f"{what} is not supported over a latent (MLA) page pool: its "
+            "pages hold one row a token for all heads, with no K / V leaf "
+            "a head to quantize, shard by head or export")
+
+
 def refuse_without_snapshot(model_config, what: str) -> None:
     """What a cache with recurrent state refuses: anything that rewinds a
     sequence to, or rebuilds it at, a token position (prefix adoption,
@@ -609,7 +665,7 @@ def _scatter_pages(pool: KVPool, dst, pages) -> KVPool:
                      for i, leaf in enumerate(leaves))
 
     return pool._replace(**{f: put(getattr(pool, f), new)
-                            for f, new in zip(PAGED, pages)
+                            for f, new in zip(KV_FIELDS, pages)
                             if new is not None})
 
 
@@ -632,13 +688,13 @@ class PageMoves:
 
     def _physical(self, pool: KVPool, ids) -> np.ndarray:
         """[passes, len(ids)]: each pass's page of every id."""
-        run = pool.k[0].shape[0] // self.passes
+        run = paged_leaf(pool).shape[0] // self.passes
         starts = np.arange(self.passes, dtype=np.int32) * run
         return np.asarray(ids, np.int32)[None, :] + starts[:, None]
 
     def gather(self, pool: KVPool, blocks: Sequence[int]) -> Tuple:
         """Host copies of pages ``blocks``, one ``[cache layers,
-        len(blocks), ...]`` array a :data:`PAGED` field (None where the
+        len(blocks), ...]`` array a :data:`KV_FIELDS` field (None where the
         pool has no such leaves): one device gather per layer leaf, then
         the transfer. The quantized payload and its scales travel exactly
         as pooled. Under a looped stack a page id brings every pass's
@@ -651,7 +707,7 @@ class PageMoves:
             return got.reshape((-1,) + got.shape[2:])  # [passes * layers, ..]
 
         return tuple(field(getattr(pool, f)) if getattr(pool, f) else None
-                     for f in PAGED)
+                     for f in KV_FIELDS)
 
     def write(self, pool: KVPool, blocks: Sequence[int], pages: Tuple,
               max_pages: int) -> KVPool:
@@ -667,7 +723,8 @@ class PageMoves:
         while B < need:
             B *= 2
         B = min(B, max_pages)
-        dst = np.full((B,), pool.k[0].shape[0] // self.passes - 1, np.int32)
+        dst = np.full((B,), paged_leaf(pool).shape[0] // self.passes - 1,
+                      np.int32)
         dst[:need] = blocks
 
         def padded(a):

@@ -51,12 +51,14 @@ from .kv_cache import (assert_block_balance,  # noqa: F401
 from .sampling import decide_masked, sample
 
 
-def _paged_kernel_blocker(head_dim: int, block: int, dtype,
-                          scalar_ints: int = 0) -> Optional[str]:
+def _paged_kernel_blocker(row: int, block: int, dtype, scalar_ints: int = 0,
+                          latent: bool = False) -> Optional[str]:
     """Why the compiled Pallas paged kernel cannot serve this engine, or
-    None when it can: it needs a real TPU, a tileable page shape and
-    prefetched scalars (per-seq tables, slots, positions) that fit SMEM
-    (1 MB/core; keep them under half)."""
+    None when it can: it needs a real TPU, a tileable page shape (``row``:
+    the values of a page's row, head_dim or, ``latent``, the latent row,
+    which the kernel copies in whole lanes of 128) and prefetched scalars
+    (per-seq tables, slots, positions) that fit SMEM (1 MB/core; keep them
+    under half)."""
     from ..ops.attention import _on_tpu
 
     if not _on_tpu():
@@ -65,8 +67,10 @@ def _paged_kernel_blocker(head_dim: int, block: int, dtype,
         return (f"prefetched scalars ({scalar_ints * 4} B) exceed the "
                 "512 KiB SMEM bound")
     sublane = 32 // jnp.dtype(dtype).itemsize  # 8 fp32 / 16 any 16-bit dtype
-    if head_dim not in (64, 128, 256):
-        return f"head_dim {head_dim} not in (64, 128, 256)"
+    if latent and row % 128:
+        return f"latent row {row} not whole lanes of 128"
+    if not latent and row not in (64, 128, 256):
+        return f"head_dim {row} not in (64, 128, 256)"
     if block % sublane:
         return f"kv_block_size {block} not a multiple of {sublane}"
     return None
@@ -233,6 +237,21 @@ class RaggedInferenceEngine:
         if self._state_layers and tp > 1:
             raise NotImplementedError(
                 "recurrent layers are not sharded over the model axis yet")
+        # latent attention (MLA): the attention layers' pages hold one row
+        # a token for all heads (kv_cache.KVPool ``latent``), and the step
+        # runs the absorbed form over them (_build_core ``latent_block``)
+        self._latent = int(getattr(c, "latent_row", 0))
+        if tp > 1:
+            kv_cache.refuse_latent(c, "tensor-parallel serving")
+        if self.config.kv_quant != "none":
+            kv_cache.refuse_latent(c, f"kv_quant={self.config.kv_quant!r}")
+        # an expert share (experts_held): the layer routes over all the
+        # router's experts and computes its own; the step counts the held
+        # experts its live lanes reached (``ragged.put``'s experts_touched)
+        self._held = getattr(c, "experts_held", None)
+        # ... out of this many: the held experts of every expert layer
+        self._held_total = 0 if self._held is None else \
+            c.n_held * (c.n_layers - c.first_dense_layers)
         # block diffusion (SDAR): a sequence that generates holds a block of
         # ``_block`` lanes a step, the step decides several of its tokens or
         # none, and a block's K/V is final only after a pass over the
@@ -274,9 +293,19 @@ class RaggedInferenceEngine:
         # this) or "gather" (XLA gather formulation, the off-TPU oracle)
         cfg = self.config
         self.max_pages = cfg.max_context // cfg.kv_block_size
+        # the step's block table, kept between ticks (_host_tables): for
+        # each slot the descriptor its row mirrors, how many leading
+        # entries still equal that descriptor's blocks, and how many may
+        # be non-zero
+        self._table = np.zeros((cfg.max_seqs, self.max_pages), np.int32)
+        self._table_owner: List[Optional[SequenceDescriptor]] = \
+            [None] * cfg.max_seqs
+        self._table_good = [0] * cfg.max_seqs
+        self._table_filled = [0] * cfg.max_seqs
         blocker = _paged_kernel_blocker(
-            c.head_dim, cfg.kv_block_size, cfg.dtype,
-            scalar_ints=cfg.max_seqs * self.max_pages + 2 * cfg.token_budget)
+            self._latent or c.head_dim, cfg.kv_block_size, cfg.dtype,
+            scalar_ints=cfg.max_seqs * self.max_pages + 2 * cfg.token_budget,
+            latent=bool(self._latent))
         if os.environ.get("DST_RAGGED_FORCE_PALLAS", "") == "interpret":
             self.attention_path = "pallas_interpret"
         elif blocker is None:
@@ -324,7 +353,7 @@ class RaggedInferenceEngine:
         # routed experts whose stacks the step reads in place: how many a
         # token takes and how many a layer holds (what _expert_product
         # needs)
-        self._routed = (c.top_k, c.n_experts) \
+        self._routed = (c.top_k, c.n_held) \
             if self._experts_in_place and model.stacked_operands else None
         if self._telemetry.enabled:
             self._telemetry.registry.gauge(
@@ -347,7 +376,8 @@ class RaggedInferenceEngine:
 
         self._pages_key = not (
             self.attention_path != "gather"
-            and tiled_grid(*self.kv_pool.k, *self.kv_pool.k_scale))
+            and tiled_grid(*self.kv_pool.k, *self.kv_pool.k_scale,
+                           *self.kv_pool.latent))
         self.kv_bytes_per_token = \
             kv_cache.kv_page_bytes(c, cfg) // cfg.kv_block_size
         # what a live sequence holds beside its pages: its slot of every
@@ -422,6 +452,18 @@ class RaggedInferenceEngine:
                 "decides several tokens of a sequence or none, and a block's "
                 "K/V is provisional until its commit pass")
 
+    def _refuse_share(self, what: str) -> None:
+        """What an expert share (``experts_held``) and a latent pool do
+        not serve, until someone needs and tests it: the verify program
+        counts no experts, and its chains were never run over latent
+        rows."""
+        if self._held is not None or self._latent:
+            raise NotImplementedError(
+                f"{what} is not supported for a model that holds a share "
+                "of its experts (experts_held) or caches latent rows "
+                "(kv_lora_rank): only the SplitFuse step counts the "
+                "experts reached and is tested over latent pages")
+
     @property
     def block_length(self) -> int:
         """Tokens a sequence's step decides together: the model's
@@ -438,11 +480,13 @@ class RaggedInferenceEngine:
         off the TPU, under tensor parallelism (GSPMD partitions the scatter
         by its head index), for a quantized pool (its scale rows are 16
         elements wide) and for a page slab under 128 lanes wide, the two
-        shapes Mosaic refuses a copy of."""
+        shapes Mosaic refuses a copy of; and for a latent leaf, whose one
+        "head" makes the scatter an index a lane (the writer moves K and V
+        leaves in pairs)."""
         from ..ops.pallas.paged_attention import LANES
 
         return (self.attention_path != "gather" and self._tp_size == 1
-                and not self._kv_bits
+                and not self._kv_bits and not self._latent
                 and self.model.config.head_dim % LANES == 0)
 
     def _program_pages(self, live_pages: int) -> int:
@@ -645,6 +689,7 @@ class RaggedInferenceEngine:
         ``preempt`` (publish into this engine's prefix cache) or
         ``discard`` the local copy afterwards."""
         kv_cache.refuse_without_snapshot(self.model.config, "export_kv")
+        kv_cache.refuse_latent(self.model.config, "export_kv")
         self._refuse_in_blocks("export_kv")
         seq = self.seqs.get(uid)
         if seq is None:
@@ -805,6 +850,7 @@ class RaggedInferenceEngine:
 
         c = self.model.config
         cfg = self.config
+        kv_cache.refuse_latent(c, "a prefix export (the KV tier)")
         k, v, *scales = self._pages.gather(self.kv_pool, blocks)
         scales = tuple(scales) if self._kv_bits else None
         wire = sum(int(a.nbytes) for a in (k, v) + (scales or ()))
@@ -996,6 +1042,9 @@ class RaggedInferenceEngine:
         ngi = self._ngram_idx.get(uid)
         if ngi is not None:
             ngi.truncate(length)
+        # the table's row is re-read from the boundary page on (_host_tables)
+        self._table_good[seq.slot] = min(self._table_good[seq.slot],
+                                         max(keep - 1, 0))
         if keep < len(seq.blocks):
             self.allocator.free(seq.blocks[keep:])
             del seq.blocks[keep:]
@@ -1013,7 +1062,7 @@ class RaggedInferenceEngine:
         adopt the longest cached full-block prefix), existing ones append
         their chunk. Span ``ragged.admit``: ``prompt`` tokens admitted
         for fresh uids, ``matched`` of them adopted from the prefix
-        cache."""
+        cache; returns the two."""
         with annotate("ragged.admit") as span:
             matched = prompt = 0
             for uid, toks in zip(uids, tokens):
@@ -1059,6 +1108,10 @@ class RaggedInferenceEngine:
                         seq.seen = shared
                         matched += shared
             span.set_metadata(matched=matched, prompt=prompt)
+        if matched and self._telemetry.enabled:
+            self._telemetry.registry.counter(
+                "inference/prefix_tokens_matched").inc(matched)
+        return matched, prompt
 
     # -- generation by diffusion over blocks (attn_block > 1) -------------
     def limit_stream(self, uid: int, n_tokens: int) -> None:
@@ -1176,6 +1229,13 @@ class RaggedInferenceEngine:
         got = self._step_ids if as_ids else logits
         with annotate("ragged.fetch", bytes=int(got.nbytes)):
             got = np.asarray(got)
+            if self._held is not None:
+                # an expert share's count, behind the ids (_build_step)
+                tail = got[-2:] if as_ids else np.asarray(self._step_ids[-2:])
+                attrs.update(experts_touched=int(tail[0]),
+                             pairs_kept=int(tail[1]))
+                span.set_metadata(experts_touched=attrs["experts_touched"],
+                                  pairs_kept=attrs["pairs_kept"])
         with annotate("ragged.rows"):
             out = self._hand_back(uids, last_index, got.__getitem__,
                                   None if as_ids else got.shape[-1])
@@ -1192,7 +1252,7 @@ class RaggedInferenceEngine:
         and returns the rows the step's head reads, a slot each; then the
         launch. Returns (sched, attrs, the step's logits, still on the
         device)."""
-        self._admit_tokens(uids, tokens)
+        matched, prompt = self._admit_tokens(uids, tokens)
         with annotate("ragged.pack"):
             sched = self._pack_splitfuse()
             if not sched:
@@ -1202,6 +1262,10 @@ class RaggedInferenceEngine:
                 self._allocate_and_build(sched, needs)
             live_pages = self._live_pages_bucket()
             attrs = self._sched_attrs(sched, len(flat_tokens), live_pages)
+            if self.prefix_cache is not None:
+                # prompt tokens this tick admitted, and those of them whose
+                # pages came from the prefix cache (``ragged.admit``'s too)
+                attrs.update(matched=matched, prompt=prompt)
             span.set_metadata(**attrs)
             sel = select(sched, last_idx)
             block_tables = self._host_tables()
@@ -1391,7 +1455,8 @@ class RaggedInferenceEngine:
         ``seen`` advances: the lane bucket, the live-page bucket, entries
         scheduled, lanes given to sequences still inside their prompt,
         single-token entries past it, pages left free, and the work the
-        paged kernel is asked for in each layer: query tiles, and KV steps
+        paged kernel is asked for in each layer: query tiles (of
+        ``LATENT_TILE`` lanes under latent attention), and KV steps
         (a tile's live chunks, summed), to set beside the ``lanes * pages
         / 16`` steps of a grid over lanes and the page bucket; the passes
         the stack makes over a token (1 unless the model is looped) and
@@ -1406,8 +1471,16 @@ class RaggedInferenceEngine:
         step runs in XLA over every slot: ``_steps_live_slots``). With
         routed experts read in place: ``expert_kernel``, 1 where this
         program's grouped products are the Pallas kernel's and 0 where
-        they are ``ragged_dot``'s (``_expert_product``)."""
-        from ..ops.pallas.paged_attention import query_tile, tile_counts
+        they are ``ragged_dot``'s (``_expert_product``). Under latent
+        attention: ``latent_layers`` and ``ctx_rows``, the rows of the
+        latent leaf the kernel reads in each (the scheduled contexts,
+        summed). With a prefix cache: ``matched`` / ``prompt``
+        (``_schedule_and_launch``). An expert share adds
+        ``experts_held`` (the held experts of every expert layer) and, once
+        the step's result is on the host, ``experts_touched`` and
+        ``pairs_kept`` (``_put``)."""
+        from ..ops.pallas.paged_attention import (LATENT_TILE, query_tile,
+                                                  tile_counts)
 
         prefill = decode = single = 0
         B = self._block
@@ -1429,7 +1502,8 @@ class RaggedInferenceEngine:
             elif take == 1:
                 decode += 1
         q_tiles, kv_steps, pages = tile_counts(
-            [(take, seq.seen) for seq, take in sched], query_tile(lanes),
+            [(take, seq.seen) for seq, take in sched],
+            LATENT_TILE if self._latent else query_tile(lanes),
             self.config.kv_block_size, B)
         attrs = {"lanes": lanes, "pages": live_pages, "seqs": len(sched),
                  "prefill": prefill, "decode": decode,
@@ -1439,6 +1513,12 @@ class RaggedInferenceEngine:
                  "kv_layers": self._kv_layers,
                  "write_tiles": q_tiles if self._writes_pages else 0,
                  "write_pages": pages if self._writes_pages else 0}
+        if self._latent:
+            # latent attention: the layers whose pages are latent rows, and
+            # the rows the kernel reads in each: the scheduled sequences'
+            # contexts after this step, summed
+            attrs["latent_layers"] = self._kv_layers
+            attrs["ctx_rows"] = sum(seq.seen + take for seq, take in sched)
         if self._state_layers:
             attrs["state_layers"] = len(self._state_layers)
             attrs["state_slots"] = len(self.seqs)
@@ -1448,6 +1528,10 @@ class RaggedInferenceEngine:
             # the Pallas kernel's (1) or ragged_dot's (0)
             attrs["expert_kernel"] = int(
                 self._expert_product(lanes) == "kernel")
+        if self._held is not None:
+            # an expert share: the experts held, over the expert layers
+            # (what ``experts_touched`` is a share of)
+            attrs["experts_held"] = self._held_total
         if B > 1:
             # block diffusion: the block length, the sequences whose block
             # under way this pass denoises, the tokens the pass before
@@ -1485,6 +1569,7 @@ class RaggedInferenceEngine:
         with empty chunks) sees exactly put()'s admitted state."""
         kv_cache.refuse_without_snapshot(self.model.config, "put_spec")
         self._refuse_in_blocks("put_spec (speculation)")
+        self._refuse_share("put_spec (speculation)")
         with annotate("ragged.put") as span:
             return self._put_spec(span, uids, tokens, drafts)
 
@@ -1611,6 +1696,10 @@ class RaggedInferenceEngine:
             r.counter("inference/expert_kernel_ticks"
                       if attrs["expert_kernel"]
                       else "inference/expert_ragged_dot_ticks").inc()
+        if "experts_touched" in attrs:
+            r.counter("inference/experts_touched").inc(
+                attrs["experts_touched"])
+            r.counter("inference/pairs_kept").inc(attrs["pairs_kept"])
 
     def _validate_sched(self, sched) -> List[int]:
         """Validate a (seq, take) schedule WITHOUT mutating anything:
@@ -1714,9 +1803,35 @@ class RaggedInferenceEngine:
                                       static_argnums=(7,)), 7)
 
     def _host_tables(self) -> np.ndarray:
-        live = list(self.seqs.values())
-        return fill_tables([s.blocks for s in live], [s.slot for s in live],
-                           self.config.max_seqs, self.max_pages)
+        """The dense [max_seqs, max_pages] block table of the live
+        sequences (zero-padded rows, zero rows for free slots), as
+        ``fill_tables`` would build it, patched from the tick before: a
+        row takes only the pages its sequence gained since (a block list
+        grows at its end; :meth:`trim`, the one place that rewrites one,
+        says from where), so a tick's host work follows its new pages and
+        not the contexts' length (converting 26 lists of ~540 pages was
+        1.1 ms of a 5 ms gap in ``a.x-k1.docs``). A copy is handed out:
+        the transfer may still read it when the next tick patches."""
+        table, owner = self._table, self._table_owner
+        good, filled = self._table_good, self._table_filled
+        for slot, seq in enumerate(owner):
+            if seq is not None and self.seqs.get(seq.uid) is not seq:
+                table[slot, :filled[slot]] = 0
+                owner[slot], good[slot], filled[slot] = None, 0, 0
+        for seq in self.seqs.values():
+            slot, n = seq.slot, len(seq.blocks)
+            if n > self.max_pages:
+                raise ValueError(f"sequence owns {n} blocks > max_pages "
+                                 f"{self.max_pages}")
+            if owner[slot] is not seq:
+                owner[slot], good[slot] = seq, 0
+            have = min(good[slot], n)
+            if have < n:
+                table[slot, have:n] = seq.blocks[have:n]
+            if n < filled[slot]:
+                table[slot, n:filled[slot]] = 0
+            good[slot] = filled[slot] = n
+        return table.copy()
 
     def _live_pages_bucket(self) -> int:
         """Static page-walk bound for this step: smallest power of two >=
@@ -1987,6 +2102,7 @@ class RaggedInferenceEngine:
         ``generate()`` — acceptance rate only changes how many device
         round trips it takes. Stats land in ``self.spec_stats``.
         """
+        self._refuse_share("generate_speculative")
         self._refuse_in_blocks("generate_speculative")
         if self.config.temperature != 0.0:
             raise NotImplementedError(
@@ -2059,7 +2175,10 @@ class RaggedInferenceEngine:
         """The shared ragged forward: (params, pools, tokens, slots,
         positions, block_tables) -> (hidden [T, d], pools). Traced inside
         both the SplitFuse ``put`` step and the multi-step decode loop."""
-        from ..ops.pallas.paged_attention import (paged_attention,
+        from ..ops.pallas.paged_attention import (LATENT_TILE,
+                                                  latent_attention,
+                                                  latent_attention_reference,
+                                                  paged_attention,
                                                   paged_attention_reference,
                                                   work_list, write_kv_pages,
                                                   write_kv_rows)
@@ -2131,7 +2250,9 @@ class RaggedInferenceEngine:
                     *sharded, tables, positions, slots, work)
 
         def core(params, pools, tokens, slots, positions, block_tables,
-                 live_pages):
+                 live_pages, tally=None):
+            # tally: a list that an expert share's layers leave their
+            # (experts touched, pairs kept) in, for a step that counts them
             # live_pages: static python int, as _program_pages gave it: the
             # lane grid's page walk; the tiled kernel reads none
             # tokens/slots/positions: [T]; embeddings via the model's path
@@ -2141,7 +2262,8 @@ class RaggedInferenceEngine:
             # (docs/observability.md); _embed, _mlp and _head bring theirs
             x = model._embed(params, tokens[None, :],
                              positions=positions[None, :])[0]  # [T, d]
-            angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta) \
+            angles = rope_frequencies(c.rotary_dim, c.max_seq_len,
+                                      c.rope_theta, c.rope_yarn) \
                 if c.position == "rope" else None
             active = slots >= 0                                   # [T]
             safe_slot = jnp.maximum(slots, 0)
@@ -2151,7 +2273,10 @@ class RaggedInferenceEngine:
             tables = None if use_pallas else block_tables[safe_slot]
             # the kernel's query tiles, from slots and positions: once a
             # step, for every layer's call
-            work = work_list(slots, positions, cfg.max_seqs) \
+            # (latent attention folds 64 heads into a tile's rows: its
+            # tiles are LATENT_TILE lanes whatever the bucket)
+            work = work_list(slots, positions, cfg.max_seqs,
+                             LATENT_TILE if self._latent else None) \
                 if use_pallas else None
             if state_layers:
                 from ..ops import gated_delta, mamba2
@@ -2192,13 +2317,8 @@ class RaggedInferenceEngine:
 
             recurrent = {"linear": linear_block, "mamba": mamba_block}
 
-            def write_pages(own, kk, vv, block_tables, sink):
-                """This layer's leaves with the step's new rows in them;
-                pool layout [pages, hkv, block, hd], kk / vv [T, hkv, hd]."""
-                if use_writer:
-                    k, v = write_kv_pages(own["k"], own["v"], kk, vv,
-                                          block_tables, work, interpret=interp)
-                    return {"k": k, "v": v}
+            def scatter_at(block_tables, sink):
+                """(page, row) [T] a scatter writes lane t's row at."""
                 page = block_tables[safe_slot, positions // bs]       # [T]
                 row = positions % bs
                 # inactive lanes — and any lane past the context window
@@ -2206,6 +2326,16 @@ class RaggedInferenceEngine:
                 # into the scratch sink page, never a live one
                 page = jnp.where(active & (positions < cfg.max_context),
                                  page, sink)
+                return page, row
+
+            def write_pages(own, kk, vv, block_tables, sink):
+                """This layer's leaves with the step's new rows in them;
+                pool layout [pages, hkv, block, hd], kk / vv [T, hkv, hd]."""
+                if use_writer:
+                    k, v = write_kv_pages(own["k"], own["v"], kk, vv,
+                                          block_tables, work, interpret=interp)
+                    return {"k": k, "v": v}
+                page, row = scatter_at(block_tables, sink)
                 # kv_quant: quantize each head-vector on the way in (one
                 # fp32 scale per row, ops/quantizer.quantize_kv) and
                 # scatter payload + scale; reads dequantize inside the
@@ -2263,6 +2393,55 @@ class RaggedInferenceEngine:
                     attn = model._attn_out(attn.astype(x.dtype), lp)
                 return after_mixer(x, attn, lp), own
 
+            def latent_block(x, lp, own, at):
+                """Latent attention in its absorbed form, the one form of
+                every lane (a prompt chunk's too): the cache row of a token
+                is its normed latent beside the one rotated key, a head's
+                query its un-rotated part through ``w_uk`` beside its
+                rotated part, so attention is 64 query heads over ONE
+                shared row a token (scores over the whole row, the
+                weighted sum over the latent), and ``w_uv`` expands each
+                head's result after it. The same function as the expanded
+                form (``Transformer._qkv``), without K or V a head."""
+                block_tables, tables, sink = at
+                r, h = c.kv_lora_rank, c.n_heads
+                with jax.named_scope("attn"):
+                    q_nope, q_rope, latent, k_rope = model._latent_parts(
+                        x, lp, angles, positions)
+                    pad = self._latent - r - c.qk_rope_dim
+                    with jax.named_scope("scatter"):
+                        row = jnp.concatenate(
+                            [latent, k_rope.astype(latent.dtype),
+                             jnp.zeros(latent.shape[:-1] + (pad,),
+                                       latent.dtype)], axis=-1)
+                        leaf = write_kv_rows(
+                            own["latent"], *scatter_at(block_tables, sink),
+                            row[:, None, :])
+                    with jax.named_scope("absorb"):
+                        q_lat = jnp.einsum(
+                            "thn,chn->thc", q_nope,
+                            lp["w_uk"].reshape(r, h, c.qk_nope_dim))
+                        q_row = jnp.concatenate(
+                            [q_lat.astype(x.dtype), q_rope.astype(x.dtype),
+                             jnp.zeros(q_rope.shape[:-1] + (pad,), x.dtype)],
+                            axis=-1)                          # [T, h, row]
+                    with jax.named_scope("latent"):
+                        if use_pallas:
+                            o_lat = latent_attention(
+                                q_row, leaf, block_tables, positions, slots,
+                                work, scale=c.attn_scale, v_dim=r,
+                                interpret=interp)
+                        else:
+                            o_lat = latent_attention_reference(
+                                q_row, leaf, tables, positions,
+                                scale=c.attn_scale, v_dim=r)
+                    with jax.named_scope("absorb"):
+                        attn = jnp.einsum(
+                            "thc,chv->thv", o_lat.astype(x.dtype),
+                            lp["w_uv"].reshape(r, h, c.v_head_dim))
+                    attn = model._attn_out(attn.astype(x.dtype), lp)
+                return after_mixer(x, attn, lp), {"latent": leaf}
+
             def stack(x, leaves, at, period=None):
                 """The stack's blocks once (one period's, under a rolled
                 stack), each on its layer's leaves of the pool;
@@ -2285,10 +2464,16 @@ class RaggedInferenceEngine:
                         # expert stacks handed over whole: the products'
                         # form follows the step's path (no_drop_moe)
                         lp["experts_path"] = self.attention_path
+                    if self._held is not None:
+                        # an expert share: lanes that are not live reach
+                        # no expert, and the step counts what was reached
+                        lp["live"], lp["tally"] = active, tally
                     i = c.layers_of(kind).index(li)
                     own = {f: leaves[f][i] for f in kv_cache.OWNS[kind]
                            if leaves[f]}
-                    if kind == "full":
+                    if kind == "full" and self._latent:
+                        x, own = latent_block(x, lp, own, at)
+                    elif kind == "full":
                         x, own = block(x, lp, own, windows[li], at)
                     else:
                         x, own = recurrent[kind](x, lp, own, base)
@@ -2358,10 +2543,13 @@ class RaggedInferenceEngine:
         model = self.model
         c = model.config
 
+        tallies = self._held is not None
+
         def step(params, pools, tokens, slots, positions, block_tables,
                  sel_idx, live_pages):
+            tally = [] if tallies else None
             x, pools = core(params, pools, tokens, slots, positions,
-                            block_tables, live_pages)
+                            block_tables, live_pages, tally)
             # head only on each sequence's selected (last) token: the full
             # [token_budget, vocab] fp32 logits are 512 MB at T=4096 v=32k
             # and were previously fetched to host every step — select the
@@ -2373,6 +2561,13 @@ class RaggedInferenceEngine:
                 x_sel = x[sel_idx]                                 # [S, d]
                 logits = model._head(params, x_sel[None, :])[0]    # [S, vocab]
                 ids = sample(logits, None, 0.0, 0, 1.0)            # [S] int32
+            if tallies:
+                # an expert share: the held experts the step's live lanes
+                # reached and the pairs kept, summed over the layers, ride
+                # behind the ids ([S + 2] int32: one fetch brings both)
+                ids = jnp.concatenate([ids, jnp.stack(
+                    [sum(t for t, _ in tally), sum(k for _, k in tally)]
+                ).astype(ids.dtype)])
             return logits, ids, pools
 
         if self._block > 1:

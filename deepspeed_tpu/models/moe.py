@@ -13,7 +13,7 @@ ZeRO <= 2).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,26 +31,64 @@ class MoETransformerConfig(TransformerConfig):
     min_capacity: int = 4
     aux_loss_weight: float = 0.01
     noisy_gate_policy: Optional[str] = None
+    # DeepSeek-V3's family (parallel/moe.GateConfig has the rules): how the
+    # router scores, the group-limited choice, what becomes of the chosen
+    # weights, experts every token takes, leading layers whose feed-forward
+    # is dense (of width dense_d_ff), and the experts this holder holds of
+    # the n_experts the router scores (a range of router ids; None = all)
+    scoring: str = "softmax"
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scale: float = 1.0
+    n_shared_experts: int = 0
+    first_dense_layers: int = 0
+    dense_d_ff: Optional[int] = None
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        if not 0 <= self.first_dense_layers < self.n_layers:
+            raise ValueError(
+                f"first_dense_layers {self.first_dense_layers} of "
+                f"{self.n_layers} layers leaves no expert layer")
+        if self.first_dense_layers and (
+                self.layer_types is not None or self.total_ut_steps > 1):
+            raise NotImplementedError(
+                "first_dense_layers with a hybrid or looped stack")
+        self.dense_d_ff = self.dense_d_ff or self.d_ff
+        self.gate_config()     # its own checks, at construction
 
     def gate_config(self) -> GateConfig:
         return GateConfig(
             n_experts=self.n_experts, top_k=self.top_k,
             capacity_factor=self.capacity_factor, min_capacity=self.min_capacity,
             aux_loss_weight=self.aux_loss_weight,
-            noisy_gate_policy=self.noisy_gate_policy)
+            noisy_gate_policy=self.noisy_gate_policy, scoring=self.scoring,
+            n_groups=self.n_groups, topk_groups=self.topk_groups,
+            routed_scale=self.routed_scale,
+            experts_held=self.experts_held)
+
+    @property
+    def n_held(self) -> int:
+        """Experts a layer's stacks hold: the weights' leading axis."""
+        return self.gate_config().held[1]
+
+    def _ffn_param_count(self, experts: int) -> int:
+        d, f = self.d_model, self.d_ff
+        n_mats = 3 if self.activation == "silu_glu" else 2
+        n_moe = self.n_layers - self.first_dense_layers
+        moe = (experts + self.n_shared_experts) * n_mats * d * f \
+            + d * self.n_experts
+        return n_moe * moe \
+            + self.first_dense_layers * n_mats * d * self.dense_d_ff
 
     def param_count(self) -> int:
-        d, f, n = self.d_model, self.d_ff, self.n_layers
-        n_mats = 3 if self.activation == "silu_glu" else 2
-        moe = self.n_experts * n_mats * d * f + d * self.n_experts
-        return self._shared_param_count() + n * moe
+        """Parameters held: an expert share counts its own experts."""
+        return self._shared_param_count() + self._ffn_param_count(self.n_held)
 
     def active_param_count(self) -> int:
         """Parameters a single token actually exercises (top_k experts)."""
-        d, f, n = self.d_model, self.d_ff, self.n_layers
-        n_mats = 3 if self.activation == "silu_glu" else 2
-        active_moe = self.top_k * n_mats * d * f + d * self.n_experts
-        return self._shared_param_count() + n * active_moe
+        return self._shared_param_count() + self._ffn_param_count(self.top_k)
 
     def flops_per_token(self, seq_len: int) -> float:
         """MoE FLOPs count only the experts a token routes through (and
@@ -63,31 +101,78 @@ class MoETransformer(Transformer):
     """Transformer with MoE FFN in every block."""
 
     stacked_operands = RAGGED_OPERANDS
+    #: the expert layers' leaves, stacked over the expert layers alone where
+    #: the first layers are dense (those layers' own sit under "dense")
+    MOE_LEAVES = ("wg", "w_up", "w_down", "w_gate", "b_up", "b_down",
+                  "ws_gate", "ws_up", "ws_down")
 
     def __init__(self, config: MoETransformerConfig):
         super().__init__(config)
         self.moe = MoELayer(config.d_model, config.d_ff, config.gate_config(),
                             activation=config.activation,
-                            use_bias=config.use_bias)
+                            use_bias=config.use_bias,
+                            n_shared_experts=config.n_shared_experts)
 
     def init(self, rng, dtype=jnp.float32) -> Dict[str, Any]:
+        c = self.config
         k_dense, k_moe = jax.random.split(rng)
         params = super().init(k_dense, dtype)
+        layers = params["layers"]
+        nd = c.first_dense_layers
+        if nd:
+            # the leading layers keep a dense feed-forward, of its own width
+            # and under a stack of its own: with two shapes of feed-forward
+            # no leaf of either spans every layer
+            kd = jax.random.split(jax.random.fold_in(k_dense, 1), 3)
+            make = lambda k, shape, fan: (jax.random.normal(
+                k, (nd,) + shape, jnp.float32) * fan ** -0.5).astype(dtype)
+            d, f = c.d_model, c.dense_d_ff
+            layers["dense"] = {"w_up": make(kd[0], (d, f), d),
+                               "w_down": make(kd[1], (f, d), f * 2 * c.n_layers)}
+            if c.activation == "silu_glu":
+                layers["dense"]["w_gate"] = make(kd[2], (d, f), d)
         # replace dense FFN weights with the expert bank
         for key in ("w_up", "w_down", "w_gate", "b_up", "b_down"):
-            params["layers"].pop(key, None)
-        params["layers"].update(
-            self.moe.init(k_moe, dtype, n_layers=self.config.n_layers))
+            layers.pop(key, None)
+        layers.update(self.moe.init(k_moe, dtype, n_layers=c.n_layers - nd))
         return params
 
+    def layer_params(self, layers, li: int, in_place: bool = False,
+                     period=None):
+        """As the base's; with ``first_dense_layers`` a dense layer takes
+        its feed-forward from the "dense" stack and an expert layer its
+        leaves at its place among the expert layers."""
+        nd = self.config.first_dense_layers
+        if not nd:
+            return super().layer_params(layers, li, in_place, period)
+        assert period is None, "no rolled stack of experts"
+        ffn = {k: v for k, v in layers.items() if k in self.MOE_LEAVES}
+        kind, lp = super().layer_params(
+            {k: v for k, v in layers.items()
+             if k not in ffn and k != "dense"}, li)
+        if li < nd:
+            lp.update({k: v[li] for k, v in layers["dense"].items()})
+            return kind, lp
+        whole = self.stacked_operands if in_place else ()
+        lp.update({k: v if k in whole else v[li - nd]
+                   for k, v in ffn.items()})
+        if whole:
+            lp["layer"] = li - nd
+        return kind, lp
+
     def _mlp(self, h, lp, rng=None, training=False):
-        moe_params = {k: lp[k] for k in ("wg", "w_up", "w_down", "w_gate",
-                                         "b_up", "b_down") if k in lp}
+        if "wg" not in lp:         # one of the leading dense layers
+            return super()._mlp(h, lp, rng, training)
+        moe_params = {k: lp[k] for k in self.MOE_LEAVES if k in lp}
         # ``layer`` and ``experts_path``: the serving step's, where it hands
-        # the expert stacks over whole (layer_params, ``in_place``)
+        # the expert stacks over whole (layer_params, ``in_place``); ``live``
+        # and ``tally`` likewise, for an expert share
+        flat = lambda a: None if a is None else a.reshape(-1)
         out, aux = self.moe.apply(moe_params, h, rng=rng, training=training,
                                   layer=lp.get("layer"),
-                                  path=lp.get("experts_path", "gather"))
+                                  path=lp.get("experts_path", "gather"),
+                                  live=flat(lp.get("live")),
+                                  tally=lp.get("tally"))
         return out, aux * self.config.aux_loss_weight
 
     def partition_specs(self, params, topo=None) -> Dict[str, Any]:
@@ -97,9 +182,17 @@ class MoETransformer(Transformer):
         for key in ("w_up", "w_down", "w_gate", "b_up", "b_down"):
             layer_specs.pop(key, None)
         pipe_size = topo.pipe_parallel_size if topo is not None else self._pipe_size
+        pipe = "pipe" if pipe_size > 1 else None
         layer_specs.update(self.moe.partition_specs(
-            n_layers=self.config.n_layers,
-            pipe="pipe" if pipe_size > 1 else None))
+            n_layers=self.config.n_layers, pipe=pipe))
+        if self.config.first_dense_layers:
+            if pipe_size > 1:
+                raise NotImplementedError(
+                    "first_dense_layers under pipeline parallelism: the "
+                    "two feed-forward stacks do not split by stage")
+            layer_specs["dense"] = {
+                k: P(None, "model", None) if k == "w_down"
+                else P(None, None, "model") for k in params["layers"]["dense"]}
         specs["layers"] = layer_specs
         return specs
 

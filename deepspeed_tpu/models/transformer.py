@@ -154,8 +154,59 @@ class TransformerConfig:
     sandwich_norm: bool = False
     total_ut_steps: int = 1
     early_exit_threshold: float = 1.0
+    # Latent attention (MLA, DeepSeek-V2/V3's; kv_lora_rank > 0): queries
+    # through a low-rank pair with an RMS norm between (q_lora_rank), keys
+    # and values expanded from one normed latent row of kv_lora_rank values
+    # a token, a head's query and key qk_nope_dim such values beside
+    # qk_rope_dim rotated ones, the rotated key one for all heads, a head's
+    # value v_head_dim wide. head_size is then qk_nope_dim + qk_rope_dim and
+    # n_kv_heads n_heads. The served cache holds the latent row and the
+    # rotated key, nothing a head (inference/kv_cache.py "latent")
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (rope_yarn_factor > 1): each rotary frequency blended with itself
+    # over the factor by a linear ramp between the correction dimensions of
+    # beta_fast and beta_slow over rope_yarn_original positions
+    # (ops/rotary.rope_frequencies); cos and sin are unscaled (the source's
+    # mscale equal to its mscale_all_dim: the mappers refuse another) and,
+    # with no attn_scale given, the softmax scale is multiplied by
+    # (0.1 * mscale_all_dim * ln(factor) + 1) squared
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
 
     def __post_init__(self):
+        if self.kv_lora_rank:
+            if not (self.q_lora_rank and self.qk_nope_dim and self.qk_rope_dim
+                    and self.v_head_dim):
+                raise ValueError("latent attention needs q_lora_rank, "
+                                 "qk_nope_dim, qk_rope_dim and v_head_dim")
+            if (self.layer_types is not None or self.attn_windows is not None
+                    or self.qk_norm or self.qkv_bias or self.attn_o_bias
+                    or self.attn_block > 1 or self.total_ut_steps > 1
+                    or self.position != "rope" or not self.causal
+                    or self.rope_interleaved):
+                raise NotImplementedError(
+                    "latent attention is a causal rotary model of one kind "
+                    "of layer: no layer_types, windows, QK-norm, attention "
+                    "biases, attn_block or looped stack")
+            self.head_size = self.qk_nope_dim + self.qk_rope_dim
+            self.n_kv_heads = self.n_heads
+            if self.attn_scale is None:
+                m = 1.0 if self.rope_yarn_factor <= 1.0 else 1.0 + 0.1 \
+                    * self.rope_yarn_mscale_all_dim \
+                    * float(np.log(self.rope_yarn_factor))
+                self.attn_scale = self.head_size ** -0.5 * m ** 2
+        if self.rope_yarn_factor > 1.0 and (
+                self.rope_yarn_original <= 0 or not self.kv_lora_rank):
+            raise NotImplementedError(
+                "YaRN frequencies need rope_yarn_original, and are wired "
+                "for latent attention (cos and sin unscaled) alone")
         if self.n_kv_heads is None:
             self.n_kv_heads = self.n_heads
         if self.qkv_bias is None:
@@ -279,14 +330,41 @@ class TransformerConfig:
 
     @property
     def rotary_dim(self) -> int:
-        """Rotated dims per head (GPT-NeoX rope_pct), even-rounded."""
+        """Rotated dims per head (GPT-NeoX rope_pct), even-rounded; under
+        latent attention the rotated part's own size."""
+        if self.kv_lora_rank:
+            return self.qk_rope_dim
         return int(self.head_dim * self.rope_pct) // 2 * 2
+
+    @property
+    def rope_yarn(self) -> Optional[Tuple[float, int, float, float]]:
+        """``ops/rotary.rope_frequencies``'s ``yarn``: (factor, original
+        positions, beta_fast, beta_slow), or None."""
+        if self.rope_yarn_factor <= 1.0:
+            return None
+        return (self.rope_yarn_factor, self.rope_yarn_original,
+                self.rope_yarn_beta_fast, self.rope_yarn_beta_slow)
+
+    @property
+    def latent_row(self) -> int:
+        """Values the served cache holds a token a layer under latent
+        attention: the normed latent and the rotated key, padded to whole
+        lanes of 128 (512 + 64 -> 640: a page slab the paged kernel can
+        copy, and what a [.., 576] leaf takes in the chip's tiled layout
+        anyway); 0 for any other model."""
+        used = self.kv_lora_rank + self.qk_rope_dim
+        return -(-used // 128) * 128 if self.kv_lora_rank else 0
 
     def _shared_param_count(self) -> int:
         """Attention + norms + embeddings (everything but the FFN)."""
         d, v, n = self.d_model, self.vocab_size, self.n_layers
         hd = self.head_dim
         attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
+        if self.kv_lora_rank:    # the latent mixer's leaves (_init_latent)
+            rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
+            attn = d * rq + rq + rq * h * hd + d * (rkv + self.qk_rope_dim) \
+                + rkv + rkv * h * (self.qk_nope_dim + self.v_head_dim) \
+                + h * self.v_head_dim * d
         if self.qkv_bias:
             attn += self.n_heads * hd + 2 * self.n_kv_heads * hd
         if self.attn_o_bias:
@@ -413,7 +491,8 @@ class Transformer:
             "wk": dense(next(k), (nf, c.d_model, c.n_kv_heads * hd)),
             "wv": dense(next(k), (nf, c.d_model, c.n_kv_heads * hd)),
             "wo": dense(next(k), (nf, c.n_heads * hd, c.d_model), scale=1.0 / np.sqrt(c.d_model * 2 * n)),
-        }
+        } if not c.kv_lora_rank else self._init_latent(
+            jax.random.fold_in(rng, 3), nf, dense, dtype)
         layers: Dict[str, Any] = {
             "attn_norm_w": jnp.ones((n, c.d_model), dtype),
             "mlp_norm_w": jnp.ones((n, c.d_model), dtype),
@@ -489,6 +568,29 @@ class Transformer:
             params["pooler_w"] = dense(next(k), (c.d_model, c.d_model))
             params["pooler_b"] = jnp.zeros((c.d_model,), dtype)
         return params
+
+    def _init_latent(self, rng, nf: int, dense, dtype) -> Dict[str, Any]:
+        """The latent-attention mixer's leaves, stacked over the layers:
+        the two low-rank pairs with their norms' gains between, the
+        up-projection of the latent kept as its two parts (``w_uk`` a
+        head's un-rotated key, ``w_uv`` its value: the served step absorbs
+        the first into the query and applies the second after attention,
+        so neither is ever sliced), and the output projection."""
+        c = self.config
+        d, h = c.d_model, c.n_heads
+        rq, rkv = c.q_lora_rank, c.kv_lora_rank
+        k = iter(jax.random.split(rng, 6))
+        return {
+            "w_dq": dense(next(k), (nf, d, rq)),
+            "q_lora_norm_w": jnp.ones((nf, rq), dtype),
+            "w_uq": dense(next(k), (nf, rq, h * c.head_dim)),
+            "w_dkv": dense(next(k), (nf, d, rkv + c.qk_rope_dim)),
+            "kv_lora_norm_w": jnp.ones((nf, rkv), dtype),
+            "w_uk": dense(next(k), (nf, rkv, h * c.qk_nope_dim)),
+            "w_uv": dense(next(k), (nf, rkv, h * c.v_head_dim)),
+            "wo": dense(next(k), (nf, h * c.v_head_dim, d),
+                        scale=1.0 / np.sqrt(d * 2 * c.n_layers)),
+        }
 
     def _init_linear(self, rng, nl: int, dense, dtype) -> Dict[str, Any]:
         """The gated-delta-rule mixer's leaves (ops/gated_delta.py), stacked
@@ -812,10 +914,12 @@ class Transformer:
                                         | (k_pos > q_pos - attn_window))
                 attn = dot_product_attention(q, kk, vv, causal=False,
                                              mask=m[None, None], scale=c.attn_scale)
-            elif c.use_flash:
+            elif c.use_flash and not c.kv_lora_rank:
                 attn = self._local_flash(q, kk, vv, causal=c.causal,
                                          scale=c.attn_scale)
             else:
+                # (latent attention too: its values are v_head_dim wide,
+                # its keys head_dim, and the flash kernel takes one size)
                 attn = dot_product_attention(q, kk, vv, causal=c.causal,
                                              scale=c.attn_scale)
 
@@ -840,6 +944,23 @@ class Transformer:
         call it around their own attention. Order: bias, then QK-norm,
         then rotary."""
         c = self.config
+        if c.kv_lora_rank:
+            # the expanded form: a head's key is its part of the latent's
+            # up-projection beside the one rotated key, its value the other
+            # part (v_head_dim wide: a flash kernel of one head size does
+            # not take it, _block)
+            q_nope, q_rope, latent, k_rope = self._latent_parts(
+                x, lp, angles, positions)
+            h_ = c.n_heads
+            k_nope = (latent @ lp["w_uk"]).reshape(
+                latent.shape[:-1] + (h_, c.qk_nope_dim))
+            vv = (latent @ lp["w_uv"]).reshape(
+                latent.shape[:-1] + (h_, c.v_head_dim))
+            kk = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope[..., None, :],
+                                          k_nope.shape[:-1] + (c.qk_rope_dim,))],
+                axis=-1)
+            return jnp.concatenate([q_nope, q_rope], axis=-1), kk, vv
         heads = lambda a, n: a.reshape(a.shape[:-1] + (n, c.head_dim))
         h = self._mixer_input(x, lp)
         q, kk, vv = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
@@ -861,6 +982,28 @@ class Transformer:
             kk = apply_rotary(kk, angles, positions, rotary_dim=c.rotary_dim,
                               interleaved=c.rope_interleaved)
         return q, kk, vv
+
+    def _latent_parts(self, x, lp, angles, positions):
+        """Latent attention from the block's input to what both its forms
+        start from: a head's un-rotated and rotated query parts ``[..., s,
+        h, qk_nope_dim]`` / ``[..., s, h, qk_rope_dim]``, the normed latent
+        ``[..., s, kv_lora_rank]`` and the rotated key all heads share
+        ``[..., s, qk_rope_dim]``. The expanded form (:meth:`_qkv`) expands
+        the latent by head; the served step absorbs ``w_uk`` into the
+        query and caches (latent | rotated key) as one row
+        (inference/ragged.py)."""
+        c = self.config
+        h = self._mixer_input(x, lp)
+        cq = rms_norm(h @ lp["w_dq"], lp["q_lora_norm_w"], c.norm_eps)
+        q = (cq @ lp["w_uq"]).reshape(cq.shape[:-1] + (c.n_heads, c.head_dim))
+        q_nope, q_rope = q[..., :c.qk_nope_dim], q[..., c.qk_nope_dim:]
+        down = h @ lp["w_dkv"]
+        latent = rms_norm(down[..., :c.kv_lora_rank], lp["kv_lora_norm_w"],
+                          c.norm_eps)
+        k_rope = down[..., None, c.kv_lora_rank:]       # one head, for all
+        q_rope = apply_rotary(q_rope, angles, positions)
+        k_rope = apply_rotary(k_rope, angles, positions)[..., 0, :]
+        return q_nope, q_rope, latent, k_rope
 
     def _attn_out(self, attn, lp):
         """Attention's output [..., s, h, hd] through the output
@@ -964,10 +1107,13 @@ class Transformer:
                 return checkpoint_wrapper(block, policy=c.remat_policy)
             return block
 
-        if c.layer_types is not None:
-            # two kinds of layer have two shapes of leaves: no one scan body
-            # fits both, so depth is unrolled (compile time grows with it)
-            blocks = {kind: block_of(kind) for kind in set(c.layer_types)}
+        if c.layer_types is not None or getattr(c, "first_dense_layers", 0):
+            # two kinds of layer (or of feed-forward: leading dense layers
+            # before the expert layers) have two shapes of leaves: no one
+            # scan body fits both, so depth is unrolled (compile time grows
+            # with it)
+            blocks = {kind: block_of(kind)
+                      for kind in set(c.layer_types or ("full",))}
             aux_total = jnp.zeros((), jnp.float32)
             for li in range(c.n_layers):
                 kind, lp = self.layer_params(params["layers"], li)
@@ -1075,13 +1221,19 @@ class Transformer:
             raise NotImplementedError(
                 "the dense KV cache holds no recurrent state: serve a model "
                 "with linear layers through RaggedInferenceEngine")
+        if kv_caches is not None and c.kv_lora_rank:
+            raise NotImplementedError(
+                "the dense KV cache holds K and V a head: serve latent "
+                "attention through RaggedInferenceEngine, whose cache holds "
+                "the latent row")
         if kv_caches is not None and c.total_ut_steps > 1:
             raise NotImplementedError(
                 "the dense KV cache holds one K/V a layer and a looped "
                 "stack writes one a layer a pass: serve it through "
                 "RaggedInferenceEngine")
         x = self._embed(params, tokens, positions, token_type_ids)  # [b, s, d]
-        angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta) \
+        angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta,
+                                  c.rope_yarn) \
             if c.position == "rope" else None
 
         aux_total = jnp.zeros((), jnp.float32)
@@ -1372,7 +1524,8 @@ class Transformer:
         else:
             xs = jax.vmap(lambda t: self._embed(params, t))(mb["inputs"])
         # xs: [M, b/M, s, d]
-        angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta) \
+        angles = rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta,
+                                  c.rope_yarn) \
             if c.position == "rope" else jnp.zeros((1, 1), jnp.float32)
         stage_params = stack_stage_params(params["layers"], self._pipe_size)
 
@@ -1463,6 +1616,17 @@ class Transformer:
         if c.qk_norm:
             layer_specs.update({"q_norm_w": P(pipe, None),
                                 "k_norm_w": P(pipe, None)})
+        if c.kv_lora_rank:
+            # latent attention: the down-projections and their norms whole
+            # on every device, the per-head up-projections by head
+            for name in ("wq", "wk", "wv"):
+                del layer_specs[name]
+            layer_specs.update({
+                "w_dq": P(pipe, None, None), "q_lora_norm_w": P(pipe, None),
+                "w_uq": P(pipe, None, "model"), "w_dkv": P(pipe, None, None),
+                "kv_lora_norm_w": P(pipe, None),
+                "w_uk": P(pipe, None, "model"),
+                "w_uv": P(pipe, None, "model")})
         if c.sandwich_norm:
             for name in ("attn_post_norm", "mlp_post_norm"):
                 layer_specs[name + "_w"] = P(pipe, None)

@@ -55,6 +55,15 @@ grid, (T, chunks of the page bucket) with every page a BlockSpec input
 not, so its time follows lanes x bucket, not the work. The choice is made
 on those shapes alone. PERF.md (PR 29) has both grids' timings.
 
+Latent attention (MLA in its absorbed form) is multi-query attention of
+every query head over ONE shared row a token, the weighted sum taken over
+the row's first ``v_dim`` values: :func:`latent_attention`, the same grid
+over the same list of tiles with one pool leaf [n_pages, 1, block, row] for
+both operands (a chunk is copied once, not twice) and the heads, not a GQA
+group, folded into a tile's rows. The two kernels share the walk over a
+tile's chunks: :func:`_page_copies`, :func:`_walk_chunks`,
+:func:`_fold_scores`.
+
 The step's new K/V rows reach the pool through :func:`write_kv_pages`,
 one Pallas call a layer over the same list of tiles, which returns both
 leaves written in place (PERF.md, PR 38); :func:`write_kv_rows`, an XLA
@@ -292,6 +301,75 @@ def tile_counts(runs, tile_rows: int, block: int, attn_block: int = 1
     return tiles, steps, pages
 
 
+def _page_copies(tbl_ref, slot, c, buf, ppc: int, block: int, lo_page,
+                 hi_page, slabs, sem, act):
+    """``act`` on each page copy of KV chunk ``c`` of the sequence at row
+    ``slot`` of the tables into buffer ``buf``, whose semaphore they
+    signal; c None: same-shaped copies, to wait on. ``slabs(src, j, at)``:
+    the (source, destination) refs of pool page ``src`` as the chunk's
+    page ``j``, whose rows are ``at``; ``lo_page`` None: no lower clamp
+    (one scalar operation fewer a copy, and issuing the copies is what
+    bounds a walk over slabs this small). A loop, not ``ppc`` unrolled
+    copies: tracing the descriptors is most of what lowering a step
+    program costs."""
+    def page(j, carry):
+        src = 0
+        if c is not None:
+            # pages past the tile's last (or below the window's band)
+            # repeat a live one: their rows are masked by position
+            nth = c * ppc + j
+            src = tbl_ref[slot, jnp.minimum(nth, hi_page) if lo_page is None
+                          else jnp.clip(nth, lo_page, hi_page)]
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        for pool, dst in slabs(src, j, at):
+            act(pltpu.make_async_copy(pool, dst, sem.at[buf]))
+        return carry
+
+    jax.lax.fori_loop(0, ppc, page, 0)
+
+
+def _walk_chunks(c_lo, c_hi, copies, fold):
+    """A tile's KV chunks ``c_lo`` (None: 0, no window) .. ``c_hi``, two in
+    flight: ``copies(c, buf, act)`` are chunk c's page copies into buffer
+    ``buf``, chunk c + 1's start before chunk c's are waited on, and
+    ``fold(c, buf)`` folds the chunk that has landed into the running
+    softmax."""
+    start = lambda d: d.start()
+    first = 0 if c_lo is None else c_lo
+    copies(first, 0, start)
+
+    def chunk(c, carry):
+        buf = (c if c_lo is None else c - c_lo) % 2
+
+        @pl.when(c < c_hi)
+        def _next():
+            copies(c + 1, 1 - buf, start)
+
+        copies(None, buf, lambda d: d.wait())
+        fold(c, buf)
+        return carry
+
+    jax.lax.fori_loop(first, c_hi + 1, chunk, 0)
+
+
+def _fold_scores(s, weighted, m_scr, l_scr, acc_scr, at=...):
+    """One chunk's masked scores ``s`` [.., rows, span] folded into the
+    online softmax (flash-2 style) the three scratches hold at ``at``, an
+    index of their rows (all of them unless given): the running max and
+    sum, one value a row across 128 lanes, and the weighted sum of values,
+    to which ``weighted(probabilities)`` adds the chunk's."""
+    one = (at, slice(0, 1))
+    m_prev = m_scr[one]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    pr = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    lanes = m_new.shape[:-1] + (LANES,)
+    l_scr[at] = jnp.broadcast_to(
+        l_scr[one] * corr + jnp.sum(pr, axis=-1, keepdims=True), lanes)
+    acc_scr[at] = acc_scr[at] * corr + weighted(pr)
+    m_scr[at] = jnp.broadcast_to(m_new, lanes)
+
+
 def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
                  ppc: int, tq: int, window: int, kv_bits: int,
                  attn_block: int = 1):
@@ -320,37 +398,18 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
     c_lo, c_hi = low // span, last // span          # the tile's chunks
 
     def copies(c, buf, act):
-        """``act`` on each of the chunk's page copies into buffer ``buf``;
-        c None: same-shaped copies, to wait on. A loop, not ``ppc`` unrolled
-        copies: tracing the descriptors is most of what lowering a step
-        program costs."""
-        def page(j, carry):
-            src = 0
-            if c is not None:
-                # pages past the tile's last (or below the window's band)
-                # repeat a live one: their rows are masked by position
-                src = tbl_ref[slot, jnp.clip(c * ppc + j, lo_page, hi_page)]
-            at = pl.ds(pl.multiple_of(j * block, block), block)
-            pairs = [(k_hbm, kbuf.at[buf, :, at, :]),
-                     (v_hbm, vbuf.at[buf, :, at, :])]
+        def slabs(src, j, at):
+            pairs = [(k_hbm.at[src], kbuf.at[buf, :, at, :]),
+                     (v_hbm.at[src], vbuf.at[buf, :, at, :])]
             if kv_bits:
-                pairs += [(ks_hbm, ksbuf.at[buf, j]), (vs_hbm, vsbuf.at[buf, j])]
-            for pool, dst in pairs:
-                act(pltpu.make_async_copy(pool.at[src], dst, sem.at[buf]))
-            return carry
+                pairs += [(ks_hbm.at[src], ksbuf.at[buf, j]),
+                          (vs_hbm.at[src], vsbuf.at[buf, j])]
+            return pairs
 
-        jax.lax.fori_loop(0, ppc, page, 0)
+        _page_copies(tbl_ref, slot, c, buf, ppc, block, lo_page, hi_page,
+                     slabs, sem, act)
 
-    start = lambda d: d.start()
-
-    def chunk(c, carry):
-        buf = (c - c_lo) % 2
-
-        @pl.when(c < c_hi)
-        def _next():
-            copies(c + 1, 1 - buf, start)
-
-        copies(None, buf, lambda d: d.wait())
+    def fold(c, buf):
         q = q_ref[...].reshape(hkv, rows, hd)        # row = g * tq + r
         k, v = kbuf[buf], vbuf[buf]                  # [hkv, span, hd]
         if kv_bits:
@@ -365,26 +424,18 @@ def _tile_kernel(work_ref, tbl_ref, q_ref, *rest, scale: float, block: int,
         visible = (r >= r0) & (r < r0 + n) & (key <= seen_to)
         if window > 0:
             visible = visible & (key > qpos - window)
-        s = jnp.where(visible[None], s, NEG_INF)
-        m_prev = m_scr[:, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            l_scr[:, :, :1] * corr + jnp.sum(pr, axis=-1, keepdims=True),
-            l_scr.shape)
-        acc_scr[...] = acc_scr[...] * corr + _dot(
-            pr.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))))
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        return carry
+        _fold_scores(
+            jnp.where(visible[None], s, NEG_INF),
+            lambda pr: _dot(pr.astype(v.dtype), v,
+                            (((2,), (1,)), ((0,), (0,)))),
+            m_scr, l_scr, acc_scr)
 
     @pl.when(n > 0)
     def _tile():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        copies(c_lo, 0, start)
-        jax.lax.fori_loop(c_lo, c_hi + 1, chunk, 0)
+        _walk_chunks(c_lo, c_hi, copies, fold)
         # every row of a tile sees its own key, so l > 0 there; the
         # block's other rows belong to other tiles and keep what they hold
         out = (acc_scr[...] / l_scr[:, :, :1]).reshape(hkv, group, tq, hd)
@@ -831,6 +882,162 @@ def write_kv_pages(k_leaf, v_leaf, new_k, new_v, tables, work, *,
     return _write_pages(k_leaf, v_leaf, new_k, new_v,
                         tables.astype(jnp.int32), work,
                         tq=tile_rows or query_tile(T), interpret=interpret)
+
+
+# ----------------------------------------------------------------------
+# latent attention: every head over one shared row a token
+
+#: query lanes a tile of the latent kernel holds, whatever the lane bucket:
+#: with 64 heads folded into its rows a tile of 16 lanes is a 1024-row
+#: matmul against a chunk, past the ridge already, and a decode tile takes
+#: the branch of its one live lane (64 rows)
+LATENT_TILE = 16
+
+
+def _latent_kernel(work_ref, tbl_ref, q_ref, pool, o_ref, buf, sem, m_scr,
+                   l_scr, acc_scr, *, scale: float, block: int, ppc: int,
+                   tq: int, v_dim: int):
+    """One grid step = one query tile of ``tq`` lanes x ``h`` heads against
+    its own context's chunks of one leaf, two chunks in flight, as
+    ``_tile_kernel``. q and the output are [lanes, heads, values] blocks,
+    the heads the sublanes, so a lane is a leading index: a tile of one
+    live lane (a decode token) multiplies that lane's ``h`` rows alone,
+    any other all ``tq * h`` under the mask. V is the chunk's first
+    ``v_dim`` lanes."""
+    _, h, w = q_ref.shape
+    span = ppc * block
+    i = pl.program_id(0)
+    r0, n = work_ref[1, i], work_ref[2, i]
+    slot, pos0 = work_ref[3, i], work_ref[4, i]
+    last = pos0 + n - 1
+    hi_page = jnp.minimum(last // block, tbl_ref.shape[1] - 1)
+    c_hi = last // span
+
+    def copies(c, b, act):
+        _page_copies(tbl_ref, slot, c, b, ppc, block, None, hi_page,
+                     lambda src, j, at: [(pool.at[src, 0], buf.at[b, at, :])],
+                     sem, act)
+
+    def walk(rows: int, q_of, visible_of):
+        """The tile's chunks against ``rows`` query rows (the first
+        ``rows`` of each scratch)."""
+        at = slice(0, rows)
+        m_scr[:rows] = jnp.full((rows, LANES), NEG_INF, jnp.float32)
+        l_scr[:rows] = jnp.zeros((rows, LANES), jnp.float32)
+        acc_scr[:rows] = jnp.zeros((rows, v_dim), jnp.float32)
+
+        def fold(c, b):
+            kv = buf[b]                                   # [span, w]
+            s = _dot(q_of(), kv, (((1,), (1,)), ((), ()))) * scale
+            key = c * span + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, span), 1)
+            _fold_scores(
+                jnp.where(visible_of(key), s, NEG_INF),
+                lambda pr: _dot(pr.astype(kv.dtype), kv[:, :v_dim],
+                                (((1,), (0,)), ((), ()))),
+                m_scr, l_scr, acc_scr, at)
+
+        _walk_chunks(None, c_hi, copies, fold)
+        return acc_scr[:rows] / l_scr[:rows, :1]
+
+    @pl.when(n == 1)
+    def _one_lane():
+        out = walk(h, lambda: q_ref[r0], lambda key: key <= pos0)
+        o_ref[r0] = out.astype(o_ref.dtype)
+
+    @pl.when(n > 1)
+    def _tile():
+        rows = tq * h
+
+        def visible(key):
+            r = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) // h
+            return (r >= r0) & (r < r0 + n) & (key <= pos0 + (r - r0))
+
+        out = walk(rows, lambda: q_ref[...].reshape(rows, w), visible)
+        r = jax.lax.broadcasted_iota(jnp.int32, (tq, h, v_dim), 0)
+        mine = (r >= r0) & (r < r0 + n)
+        o_ref[...] = jnp.where(
+            mine, out.reshape(tq, h, v_dim).astype(o_ref.dtype), o_ref[...])
+
+
+# jitted on its own, as ``_tiled`` and for its reason
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "ppc", "tq", "v_dim", "interpret"))
+def _latent_tiled(q, pool, tables, slots, work, *, scale, ppc, tq, v_dim,
+                  interpret):
+    T, h, w = q.shape
+    block = pool.shape[2]
+    pad = (-T) % tq
+    qp = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+    span = ppc * block
+    rows = tq * h
+    spec = lambda width: pl.BlockSpec(
+        (tq, h, width), lambda i, work, tbl: (work[0, i], 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, block=block, ppc=ppc,
+                          tq=tq, v_dim=v_dim),
+        out_shape=jax.ShapeDtypeStruct((T + pad, h, v_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(work.shape[1],),
+            in_specs=[spec(w), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec(v_dim),
+            scratch_shapes=[pltpu.VMEM((2, span, w), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((rows, LANES), jnp.float32),
+                            pltpu.VMEM((rows, LANES), jnp.float32),
+                            pltpu.VMEM((rows, v_dim), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="latent_attention",
+        interpret=interpret,
+    )(work, tables, qp, pool)
+    # a lane that is not live belongs to no tile: nothing wrote its row
+    return jnp.where((slots >= 0)[:, None, None], out[:T], 0)
+
+
+def latent_attention(q, pool, tables, positions, seq_slots, work=None, *,
+                     scale: float, v_dim: int,
+                     pages_per_chunk: int | None = None,
+                     interpret: bool = False):
+    """Attention of ragged query lanes, every head over one shared row a
+    token (MLA in its absorbed form), causal along each lane's page list.
+
+      q:     [T, h, row]            a head's absorbed query beside its
+                                    rotated part, zeros where the row's pad
+      pool:  [n_pages, 1, block, row]   a token's latent, rotated key, pad
+      tables [n_seqs, max_pages], seq_slots [T] (< 0: no lane), positions [T]
+
+    Scores are over the whole row, the weighted sum over its first
+    ``v_dim`` values: returns [T, h, v_dim]. ``row`` and ``v_dim`` are
+    whole lanes of 128. ``work``: :func:`work_list` of the same slots and
+    positions cut with :data:`LATENT_TILE`; built here if absent."""
+    T, h, w = q.shape
+    assert pool.shape[1] == 1 and pool.shape[3] == w, (q.shape, pool.shape)
+    assert w % LANES == 0 and v_dim % LANES == 0 and v_dim <= w
+    slots = seq_slots.astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    if work is None:
+        work = work_list(slots, positions, tables.shape[0], LATENT_TILE)
+    return _latent_tiled(q, pool, tables, slots, work, scale=scale,
+                         ppc=pages_per_chunk or chunk_pages(pool.shape[2]),
+                         tq=LATENT_TILE, v_dim=v_dim, interpret=interpret)
+
+
+def latent_attention_reference(q, pool, tables, positions, *, scale: float,
+                               v_dim: int):
+    """jnp reference (gather-based) of :func:`latent_attention`, with
+    per-token ``tables`` [T, max_pages]: the oracle and the form off the
+    TPU."""
+    T, h, w = q.shape
+    rows = pool[tables][:, :, 0].reshape(T, -1, w).astype(jnp.float32)
+    logits = jnp.einsum("thw,tkw->thk", q.astype(jnp.float32), rows) * scale
+    visible = jnp.arange(rows.shape[1])[None, :] <= positions[:, None]
+    logits = jnp.where(visible[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("thk,tkv->thv", probs,
+                      rows[..., :v_dim]).astype(q.dtype)
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,

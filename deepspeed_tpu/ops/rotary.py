@@ -8,12 +8,39 @@ into the QK projection epilogue.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
-def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0):
-    """Precompute [max_len, head_dim/2] angle table."""
+def yarn_ramp(head_dim: int, theta: float, original: int, beta_fast: float,
+              beta_slow: float):
+    """YaRN's blend [head_dim/2], 1 where a frequency is kept and 0 where it
+    is divided by the factor: a linear ramp between the correction
+    dimensions, the dimensions whose wavelength turns ``beta_fast`` and
+    ``beta_slow`` times over ``original`` positions (Peng et al. 2023;
+    DeepSeek-V2's ``yarn_find_correction_range``)."""
+    def correction_dim(turns):
+        return head_dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) \
+        / max(high - low, 1e-3)
+    return 1.0 - jnp.clip(ramp, 0.0, 1.0)
+
+
+def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
+                     yarn=None):
+    """Precompute [max_len, head_dim/2] angle table. ``yarn``: (factor,
+    original positions, beta_fast, beta_slow), each frequency then blended
+    with itself over the factor by :func:`yarn_ramp`."""
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is not None:
+        factor, original, beta_fast, beta_slow = yarn
+        keep = yarn_ramp(head_dim, theta, original, beta_fast, beta_slow)
+        inv_freq = inv_freq / factor * (1.0 - keep) + inv_freq * keep
     t = jnp.arange(max_len, dtype=jnp.float32)
     return jnp.outer(t, inv_freq)  # [max_len, head_dim//2]
 

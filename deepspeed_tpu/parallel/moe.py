@@ -28,14 +28,110 @@ import numpy as np
 
 @dataclass
 class GateConfig:
+    """``n_experts`` is the router's width: every expert of the layer. The
+    weights' leading axis is the experts this holder HOLDS, ``experts_held``
+    (a range ``(first, past)`` of router ids; None = all of them): a layer
+    told which experts it holds routes over all ``n_experts`` and computes
+    its own experts' part (:func:`route`, :func:`no_drop_moe`).
+
+    ``top_k``: the capacity path (training with token dropping,
+    :func:`top_k_gating`) takes 1 or 2, as the reference's gates do; the
+    dropless path (evaluation and serving, :func:`route`) any k up to the
+    router's width (8 since PR 45). ``scoring``, the group-limited choice
+    (``n_groups`` / ``topk_groups``) and ``routed_scale`` are the dropless
+    path's alone: the capacity path refuses any value but
+    their defaults (:func:`check_capacity_gate`)."""
+
     n_experts: int = 8
-    top_k: int = 2                    # 1 or 2 (reference supports k in {1,2})
+    top_k: int = 2
     capacity_factor: float = 1.25     # train capacity (reference default 1.0/1.25)
     eval_capacity_factor: float = 2.0
     min_capacity: int = 4             # reference sharded_moe.py min_capacity
     noisy_gate_policy: Optional[str] = None  # None | 'RSample' | 'Jitter'
     drop_tokens: bool = True
     aux_loss_weight: float = 0.01
+    scoring: str = "softmax"          # softmax | sigmoid (DeepSeek-V3's family)
+    # group-limited choice: the experts in ``n_groups`` runs of consecutive
+    # ids, a group scored by its largest member, the top_k taken inside the
+    # ``topk_groups`` best groups; 1 = no groups
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scale: float = 1.0         # the renormalised weights times this
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r}: softmax or sigmoid")
+        if self.n_groups < 1 or self.n_experts % self.n_groups \
+                or not 1 <= self.topk_groups <= self.n_groups:
+            raise ValueError(
+                f"n_groups {self.n_groups} must divide n_experts "
+                f"{self.n_experts}, with 1 <= topk_groups "
+                f"({self.topk_groups}) <= n_groups")
+        if self.top_k > self.topk_groups * (self.n_experts // self.n_groups):
+            raise ValueError(
+                f"top_k {self.top_k} exceeds the {self.topk_groups} chosen "
+                f"groups' experts")
+        if self.experts_held is not None:
+            first, past = self.experts_held = tuple(
+                int(e) for e in self.experts_held)
+            if not 0 <= first < past <= self.n_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no range inside "
+                    f"the router's {self.n_experts} experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first held expert's router id, how many are held)."""
+        first, past = self.experts_held or (0, self.n_experts)
+        return first, past - first
+
+
+def check_capacity_gate(cfg: GateConfig) -> None:
+    """What the capacity path (:func:`top_k_gating`: training with token
+    dropping) does not implement, refused by message and not ignored."""
+    if cfg.top_k not in (1, 2):
+        raise NotImplementedError(
+            f"the capacity gate takes top_k 1 or 2, got {cfg.top_k}: train "
+            "without dropping (drop_tokens=False routes through the "
+            "dropless path, any k)")
+    if (cfg.scoring != "softmax" or cfg.n_groups != 1
+            or cfg.routed_scale != 1.0 or cfg.experts_held is not None):
+        raise NotImplementedError(
+            "the capacity gate scores by a softmax over all experts and "
+            "renormalises the chosen: scoring='sigmoid', n_groups > 1, "
+            "routed_scale != 1 and experts_held are the "
+            "dropless path's (drop_tokens=False, evaluation, serving)")
+
+
+def route(logits: jnp.ndarray, cfg: GateConfig
+          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The dropless path's choice, one function for ``MoELayer.apply`` and
+    so for the served step: ``logits`` [S, n_experts] float32 -> (scores
+    [S, n_experts], weights [S, top_k], router ids [S, top_k]).
+
+    Scores are a softmax over all experts or a sigmoid of each. With
+    ``n_groups`` > 1 a group is scored by its largest member, the
+    ``topk_groups`` best groups are kept and the choice is made among
+    their experts. The ``top_k`` largest scores are taken (ties to the
+    lower id), divided by their sum and multiplied by ``routed_scale``. The defaults are a softmax's renormalised top k,
+    operation for operation what this path always computed."""
+    scores = jax.nn.softmax(logits, axis=-1) if cfg.scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    pick = scores
+    if cfg.n_groups > 1:
+        S, G = scores.shape[0], cfg.n_groups
+        by_group = jnp.max(scores.reshape(S, G, -1), axis=-1)      # [S, G]
+        _, best = jax.lax.top_k(by_group, cfg.topk_groups)
+        kept = jnp.zeros((S, G), bool).at[
+            jnp.arange(S)[:, None], best].set(True)
+        pick = jnp.where(jnp.repeat(kept, cfg.n_experts // G, axis=1),
+                         scores, -1.0)       # scores are never negative
+    topw, topi = jax.lax.top_k(pick, cfg.top_k)
+    topw = topw / jnp.maximum(jnp.sum(topw, axis=-1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:
+        topw = topw * cfg.routed_scale
+    return scores, topw, topi
 
 
 def capacity(tokens_per_group: int, cfg: GateConfig, training: bool) -> int:
@@ -67,6 +163,7 @@ def top_k_gating(logits: jnp.ndarray, cfg: GateConfig, cap: int,
     token dimension, tokens beyond capacity dropped, load-balance loss
     = E * mean(probs_per_expert) . mean(assignment_per_expert).
     """
+    check_capacity_gate(cfg)
     S, E = logits.shape
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
 
@@ -151,7 +248,9 @@ def expert_product(path: str, rows: int, n_experts: int) -> str:
 def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
                 params: Dict[str, Any], activation: str,
                 layer: Optional[int] = None,
-                path: str = "gather") -> jnp.ndarray:
+                path: str = "gather", held_from: Optional[int] = None,
+                live: Optional[jnp.ndarray] = None,
+                tally: Optional[list] = None) -> jnp.ndarray:
     """Sort-based NO-DROP expert dispatch on grouped GEMMs.
 
     The TPU analog of FastGen's ``moe_gather``/``moe_scatter`` +
@@ -185,15 +284,38 @@ def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
     call, ``silu(g) * u`` formed in float32). ``model.apply`` (training
     without dropping, evaluation, the benchmark's references) and sharded
     serving pass none and keep ``ragged_dot``.
+
+    ``held_from`` (an expert share, ``GateConfig.experts_held``): ``idx``
+    holds router ids and the stacks hold the E experts from that id on; a
+    pair whose expert is not held, and with ``live`` [S] bool a pair of a
+    lane that is not live, goes to no group. Such pairs sort past the last
+    group, the product visits only held experts that live lanes reached,
+    and their rows add nothing. None (every expert held, every lane
+    counted) is the program this always was. ``tally``: a list that takes
+    this layer's (experts touched, pairs kept), both int32 scalars, for a
+    step that counts them (``inference/ragged.py``).
     """
     S, k = idx.shape
     w = {n: params[n] for n in RAGGED_OPERANDS if n in params}
     E = w["w_up"].shape[-3]
     flat_e = idx.reshape(-1)                          # [S*k]
+    kept = None
+    if held_from is not None:
+        flat_e = flat_e - held_from
+        kept = (flat_e >= 0) & (flat_e < E)
+        if live is not None:
+            kept &= jnp.repeat(live, k)
+        flat_e = jnp.where(kept, flat_e, E)           # E: no group, sorts last
     order = jnp.argsort(flat_e)                       # stable: tokens in order
     tok = jnp.repeat(jnp.arange(S), k)[order]         # source token per pair
     xs = x_flat[tok]                                  # moe_gather
-    group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    if kept is None:
+        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    else:
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(
+            1, mode="drop")
+    if tally is not None:
+        tally.append((jnp.sum(group_sizes > 0), jnp.sum(group_sizes)))
     kernel = layer is not None and \
         expert_product(path, S * k, E) == "kernel"
     if layer is not None:
@@ -233,7 +355,11 @@ def no_drop_moe(x_flat: jnp.ndarray, probs: jnp.ndarray, idx: jnp.ndarray,
     if "b_down" in params:
         ys = ys + params["b_down"][e_sorted].astype(ys.dtype)
     gate = probs.reshape(-1)[order][:, None].astype(ys.dtype)
-    return jnp.zeros_like(x_flat).at[tok].add((ys * gate).astype(x_flat.dtype))
+    ys = ys * gate
+    if kept is not None:
+        # rows of no group: zeros from the kernel, unspecified elsewhere
+        ys = jnp.where(kept[order][:, None], ys, 0)
+    return jnp.zeros_like(x_flat).at[tok].add(ys.astype(x_flat.dtype))
 
 
 class MoELayer:
@@ -248,14 +374,24 @@ class MoELayer:
     """
 
     def __init__(self, d_model: int, d_ff: int, gate: GateConfig,
-                 activation: str = "silu_glu", use_bias: bool = False):
+                 activation: str = "silu_glu", use_bias: bool = False,
+                 n_shared_experts: int = 0):
         self.d_model, self.d_ff, self.gate, self.activation = d_model, d_ff, gate, activation
         # per-expert biases (Megatron-DeepSpeed MoE experts carry
         # dense_h_to_4h/dense_4h_to_h biases; glu llama-style experts don't)
         self.use_bias = use_bias
+        # experts every token takes beside the routed ones (DeepSeek's
+        # shared experts): one SwiGLU of width n_shared_experts * d_ff,
+        # leaves ws_gate / ws_up / ws_down, unweighted
+        self.n_shared_experts = n_shared_experts
+        if n_shared_experts and activation != "silu_glu":
+            raise NotImplementedError("shared experts are SwiGLU")
 
     def init(self, rng, dtype=jnp.float32, n_layers: Optional[int] = None) -> Dict[str, Any]:
-        E, d, f = self.gate.n_experts, self.d_model, self.d_ff
+        # the router is as wide as the layer has experts; the stacks hold
+        # the experts held
+        R, d, f = self.gate.n_experts, self.d_model, self.d_ff
+        E = self.gate.held[1]
         lead = (n_layers,) if n_layers else ()
         k1, k2, k3, k4 = jax.random.split(rng, 4)
 
@@ -263,12 +399,18 @@ class MoELayer:
             return (jax.random.normal(key, lead + shape, jnp.float32) / np.sqrt(fan_in)).astype(dtype)
 
         p = {
-            "wg": dense(k1, (d, E), d),
+            "wg": dense(k1, (d, R), d),
             "w_up": dense(k2, (E, d, f), d),
             "w_down": dense(k3, (E, f, d), f),
         }
         if self.activation == "silu_glu":
             p["w_gate"] = dense(k4, (E, d, f), d)
+        if self.n_shared_experts:
+            fs = self.n_shared_experts * f
+            ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
+            p.update(ws_gate=dense(ks[0], (d, fs), d),
+                     ws_up=dense(ks[1], (d, fs), d),
+                     ws_down=dense(ks[2], (fs, d), fs))
         if self.use_bias:
             p["b_up"] = jnp.zeros(lead + (E, f), dtype)
             p["b_down"] = jnp.zeros(lead + (E, d), dtype)
@@ -276,32 +418,36 @@ class MoELayer:
 
     def apply(self, params: Dict[str, Any], x: jnp.ndarray,
               rng: Optional[jax.Array] = None, training: bool = True,
-              layer: Optional[int] = None, path: str = "gather"
+              layer: Optional[int] = None, path: str = "gather",
+              live: Optional[jnp.ndarray] = None,
+              tally: Optional[list] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """x: [b, s, d] -> (out [b, s, d], aux_loss). Token groups = batch
         rows (group-limited routing like the reference's per-group capacity).
         Eval / no-drop uses the sort-based grouped-GEMM path; ``layer`` and
         ``path`` are no_drop_moe's (the expert matrices arrive as the whole
-        stack, from a serving step that says which of its paths it is)."""
+        stack, from a serving step that says which of its paths it is), as
+        are ``live`` [b * s] and ``tally``, which only an expert share
+        (``experts_held``) reads."""
         b, s, d = x.shape
         cfg = self.gate
-        # device scopes (metadata only): ``router`` and ``experts`` name the
-        # layer's two parts in a profiler trace (docs/observability.md)
+        # device scopes (metadata only): ``router``, ``experts`` and
+        # ``shared`` name the layer's parts in a profiler trace
+        # (docs/observability.md)
         if not training or not cfg.drop_tokens:
             with jax.named_scope("router"):
                 logits = x.astype(jnp.float32) @ params["wg"].astype(jnp.float32)
-                probs = jax.nn.softmax(logits.reshape(b * s, -1), axis=-1)
-                topw, topi = jax.lax.top_k(probs, cfg.top_k)
-                topw = topw / jnp.maximum(
-                    jnp.sum(topw, axis=-1, keepdims=True), 1e-9)
+                probs, topw, topi = route(logits.reshape(b * s, -1), cfg)
                 # same load-balance diagnostic as the drop path
                 assign = jnp.mean(jax.nn.one_hot(topi[:, 0], cfg.n_experts),
                                   axis=0)
                 aux = cfg.n_experts * jnp.sum(jnp.mean(probs, axis=0) * assign)
+            share = {} if cfg.experts_held is None else dict(
+                held_from=cfg.held[0], live=live, tally=tally)
             with jax.named_scope("experts"):
                 out = no_drop_moe(x.reshape(b * s, d), topw, topi, params,
-                                  self.activation, layer, path)
-            return out.reshape(b, s, d), aux
+                                  self.activation, layer, path, **share)
+            return self._with_shared(out.reshape(b, s, d), params, x), aux
         with jax.named_scope("router"):
             cap = capacity(s, cfg, training)
             if cfg.noisy_gate_policy == "Jitter" and training and rng is not None:
@@ -337,7 +483,16 @@ class MoELayer:
             if "b_down" in params:
                 expert_out = expert_out + params["b_down"][:, None, None, :].astype(expert_out.dtype)
             out = jnp.einsum("bsec,ebcd->bsd", combine.astype(x.dtype), expert_out)
-        return out, aux
+        return self._with_shared(out, params, x), aux
+
+    def _with_shared(self, out, params, x):
+        """``out`` plus the shared experts' output, where the layer has
+        them."""
+        if not self.n_shared_experts:
+            return out
+        with jax.named_scope("shared"):
+            return out + (jax.nn.silu(x @ params["ws_gate"])
+                          * (x @ params["ws_up"])) @ params["ws_down"]
 
     def partition_specs(self, n_layers: Optional[int] = None,
                         pipe: Optional[str] = None):
@@ -357,4 +512,8 @@ class MoELayer:
         if self.use_bias:
             specs["b_up"] = P(*lead, "expert", "model")
             specs["b_down"] = P(*lead, "expert", None)
+        if self.n_shared_experts:
+            specs.update(ws_gate=P(*lead, None, "model"),
+                         ws_up=P(*lead, None, "model"),
+                         ws_down=P(*lead, "model", None))
         return specs

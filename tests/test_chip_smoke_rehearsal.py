@@ -27,6 +27,7 @@ def _tiny() -> chip_smoke.Sizes:
         kernel_seq=256, kernel_pages_per_seq=8, kernel_n_seqs=8,
         kernel_alt_heads=(3, 3), kernel_delta_state=(3, 8, 16),
         kernel_ssd_state=(4, 8, 16, 2), kernel_ssd_periods=3,
+        kernel_latent=(4, 256, 128), kernel_latent_pages=32,
         zero3_layers=2, zero3_batch=4, zero3_steps=2)
 
 
@@ -46,7 +47,9 @@ def test_kernels_phase_interpret(ledger, capsys):
     assert set(line["rel_err"]) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dk", "flash_bwd_dv",
                                     "delta_step_o", "delta_step_state",
-                                    "ssd_step_y", "ssd_step_state"} | paged
+                                    "ssd_step_y", "ssd_step_state",
+                                    "latent_prefill", "latent_decode",
+                                    "latent_mixed", "latent_long"} | paged
     # the delta-rule step kernel leaves the slots that do not decode alone,
     # and so does the state-space step kernel on its rolled leaf
     assert line["state_unequal"] == 0 and line["ssd_state_unequal"] == 0

@@ -51,7 +51,7 @@ PARENT = {
 ATTRS = {
     "ragged.put": {"lanes", "pages", "seqs", "prefill", "decode", "free",
                    "q_tiles", "kv_steps", "passes", "kv_layers",
-                   "write_tiles", "write_pages"},
+                   "write_tiles", "write_pages", "matched", "prompt"},
     "ragged.admit": {"matched", "prompt"}, "ragged.fetch": {"bytes"},
     "ragged.h2d": {"arrays", "bytes"}, "ragged.call": {"leaves"},
     "serve.tick": {"tick", "queued", "live"},

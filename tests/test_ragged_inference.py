@@ -916,6 +916,62 @@ def test_prefix_cache_trim_copy_on_write():
     assert out == want
 
 
+def _check_tables(eng):
+    """Every table the engine hands its step from now on is held to what
+    ``fill_tables`` builds from the live block lists; returns the count."""
+    from deepspeed_tpu.ops.ragged_host import fill_tables
+    patched, calls = eng._host_tables, []
+
+    def checked():
+        got = patched()
+        live = list(eng.seqs.values())
+        np.testing.assert_array_equal(got, fill_tables(
+            [s.blocks for s in live], [s.slot for s in live],
+            eng.config.max_seqs, eng.max_pages))
+        assert got is not eng._table    # the next tick patches the table
+        calls.append(1)
+        return got
+
+    eng._host_tables = checked
+    return calls
+
+
+@pytest.mark.parametrize("scenario", ["trim_cow_flush_reuse", "speculative"])
+def test_block_table_kept_between_ticks_is_fill_tables(scenario):
+    """The table patched from the tick before equals one built anew, over
+    what rewrites a block list or a slot: growth, a trim (with a
+    copy-on-write of the boundary page), a flush, a slot and a uid taken
+    again by a shorter sequence, speculation's trims."""
+    model = _llama()
+    params = model.init(jax.random.PRNGKey(8))
+    P = [int(t) for t in np.random.default_rng(41).integers(1, 128, (40,))]
+    if scenario == "speculative":
+        eng = RaggedInferenceEngine(model, _cfg(), params=params)
+        calls = _check_tables(eng)
+        eng.generate_speculative({1: [5, 6, 7, 8] * 6, 2: P[:17]},
+                                 max_new_tokens=12)
+        assert len(calls) > 3
+        return
+    eng = RaggedInferenceEngine(model, _pc_cfg(), params=params)
+    calls = _check_tables(eng)
+    eng.generate({1: P}, max_new_tokens=6)
+    eng.put([2, 3], [P, P[:9]])                   # 2 adopts 1's pages
+    assert eng.allocator.refcount(eng.seqs[2].blocks[0]) >= 2
+    eng.trim(2, 12)                               # into a shared page
+    logits = eng.put([2, 3], [[3, 5, 7, 9], [4]])
+    for _ in range(10):                           # 2 grows past its old end
+        logits = eng.put([2, 3], [[int(np.argmax(r))] for r in logits])
+    eng.trim(3, 2)
+    eng.put([3], [[8]])
+    slot = eng.seqs[2].slot
+    eng.flush([2])
+    eng.put([3], [[9]])                           # 2's row is zero again
+    eng.put([2, 3], [P[20:23], [1]])              # the uid again, shorter
+    assert eng.seqs[2].slot == slot
+    eng.flush([2, 3])
+    assert len(calls) > 15
+
+
 # ---------------------------------------------------------------------
 # prompt-lookup speculative decoding (beyond-reference: FastGen decodes
 # one token per step; here n-gram drafts verify as a chain in one step)

@@ -1260,3 +1260,52 @@ def test_tp2_ragged_step_gathers_no_pool_leaf(topo, on_tpu):
     c = compile_ragged_step(NamedSharding(tp.mesh, P()), 2, 64, 128,
                             n_kv_blocks=POOL_PAGES, tp_topo=tp)
     assert _pool_faults(c.as_text(), heads=HKV // 2) == []
+
+
+def _latent_calls(hlo: str) -> list:
+    """The latent kernel's calls (``latent_attention``): each returns the
+    lanes' [T, heads, latent] rows, three dimensions where the paged
+    kernel's result has four, and runs under ``attn/latent``, the scope
+    ``latent_attn_device_ms.serve`` reads."""
+    calls = [l for l in _custom_calls(hlo)
+             if re.search(r"= bf16\[\d+,\d+,\d+\]\{[\d,]+(:[^}]*)?\} custom-call", l)]
+    assert all(re.search(r'op_name="[^"]*attn/latent/[^"]*"', l)
+               for l in calls), calls
+    return calls
+
+
+@pytest.mark.parametrize("T", [64, 256, 1024],
+                         ids=["lanes64", "lanes256", "lanes1024"])
+def test_axk1_step_compiles_over_latent_pages(one_chip, on_tpu, T):
+    """Rehearsal 3 for the cell ``a.x-k1.docs``: the 6-layer step at
+    published widths: one ``latent_attention`` call a layer over the one
+    latent leaf (64 heads over rows of 640, Mosaic takes the lane-indexed
+    q block and the two branches), its rows written by the scatter (one
+    index a lane) with no copy of a leaf; the expert share's products on
+    the grouped kernel under the ridge (12 held experts: 64 and 256
+    lanes) and ``ragged_dot`` past it, no operation yielding a layer's
+    expert matrices; the program beside 8.33 GB of weights and the 4.03
+    GB pool inside the chip's memory."""
+    from deepspeed_tpu.parallel.moe import expert_product
+
+    with jax.default_matmul_precision("default"):
+        compiled, c, e = compile_cell_step("a.x-k1", one_chip, T, 1024)
+    hlo = compiled.as_text()
+    assert (c.n_layers, c.n_experts, c.n_held, c.top_k) == (6, 192, 12, 8)
+    assert len(_latent_calls(hlo)) == c.n_layers
+    grouped = _grouped_calls(hlo)
+    n_moe = c.n_layers - c.first_dense_layers
+    if expert_product("pallas", T * c.top_k, c.n_held) == "kernel":
+        assert len(grouped) == 2 * n_moe and "ragged-dot" not in hlo
+    else:
+        assert not grouped and hlo.count("ragged-dot") >= 3 * n_moe
+    # nothing yields a layer's expert matrices or a whole latent leaf
+    leaf = f"{e['max_kv_blocks'] + 1},1,{e['kv_block_size']},{c.latent_row}"
+    assert not _leaf_moves(hlo, leaf), _leaf_moves(hlo, leaf)
+    layer = {f"{c.n_held},{c.d_model},{c.d_ff}",
+             f"{c.n_held},{c.d_ff},{c.d_model}"}
+    assert not [i for i in _instructions(hlo).values() if i.dims in layer]
+    mem = compiled.memory_analysis()
+    assert 12.3e9 < mem.argument_size_in_bytes < 12.5e9
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert _device_bytes(compiled) < 15.75 * 2 ** 30
