@@ -95,6 +95,10 @@ class Sizes:
     # KV heads of the leaves the row writer is checked at: Mistral's,
     # Ouro's, Olmo-Hybrid's
     kernel_writer_heads: Tuple[int, ...] = (8, 16, 30)
+    # heads under 128 wide that share a 128-lane row of the pool:
+    # granite-4.0-h-micro's (query heads, KV heads, head size), for the
+    # paged kernel's tiled grid and the row writer
+    kernel_shared_heads: Tuple[int, int, int] = (32, 8, 64)
     # the delta-rule step kernel's state: Olmo-Hybrid's (heads, key, value)
     kernel_delta_state: Tuple[int, int, int] = (30, 96, 192)
     # the state-space step kernel's: granite-4.0-h-micro's (heads, channels
@@ -254,8 +258,9 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
     from deepspeed_tpu.ops.attention import dot_product_attention
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        latent_attention, latent_attention_reference, paged_attention,
-        paged_attention_reference, work_list, write_kv_pages, write_kv_rows)
+        heads_a_row, latent_attention, latent_attention_reference,
+        paged_attention, paged_attention_reference, share_rows, work_list,
+        write_kv_pages, write_kv_rows)
 
     hq, hkv, hd, S = sz.n_heads, sz.n_kv_heads, sz.head_dim, sz.kernel_seq
     kq, kk, kv, kw, kp = jax.random.split(jax.random.PRNGKey(seed), 5)
@@ -319,13 +324,24 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
                             np.zeros(half - n_dec - chunk)]).astype(np.int32)),
     }
     window = min(1024, ctx // 2)
-    variants = {"": (hq, hkv, 0, False), "_h30": (*sz.kernel_alt_heads, 0, False),
-                f"_w{window}": (hq, hkv, window, False),
-                "_int8": (hq, hkv, 0, True)}
-    for tag, (nq, nkv, win, quant) in variants.items():
+    sq, skv, shd = sz.kernel_shared_heads
+    variants = {"": (hq, hkv, hd, 0, False),
+                "_h30": (*sz.kernel_alt_heads, hd, 0, False),
+                f"_w{window}": (hq, hkv, hd, window, False),
+                "_int8": (hq, hkv, hd, 0, True),
+                # Granite's: the kernel reads two KV heads a 128-lane row
+                # (the pool as kv_cache.pool_leaves lays it out), the
+                # oracle the leaf a head a row
+                f"_h{skv}x{shd}": (sq, skv, shd, 0, False)}
+
+    def shared(pool):
+        nkv, w = pool.shape[1], pool.shape[3]
+        return share_rows(pool, nkv // heads_a_row(nkv, w))
+
+    for tag, (nq, nkv, vhd, win, quant) in variants.items():
         kp1, kp2 = jax.random.split(jax.random.fold_in(kp, nkv))
-        k_pool = jax.random.normal(kp1, (n_pages + 1, nkv, blk, hd), jnp.bfloat16)
-        v_pool = jax.random.normal(kp2, (n_pages + 1, nkv, blk, hd), jnp.bfloat16)
+        k_pool = jax.random.normal(kp1, (n_pages + 1, nkv, blk, vhd), jnp.bfloat16)
+        v_pool = jax.random.normal(kp2, (n_pages + 1, nkv, blk, vhd), jnp.bfloat16)
         scales = ()
         if quant:
             (k_pool, ks), (v_pool, vs) = (quantize_kv(k_pool, 8),
@@ -341,10 +357,12 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
                                                    window=win, **kw(sc)))
         for name, (slots, pos) in shapes.items():
             T = len(slots)
-            qd = jax.random.normal(jax.random.fold_in(kq, T), (T, nq, hd),
+            qd = jax.random.normal(jax.random.fold_in(kq, T), (T, nq, vhd),
                                    jnp.bfloat16)
-            got = kernel(qd, jnp.asarray(slots), jnp.asarray(pos), k_pool,
-                         v_pool, tables, scales)
+            got = kernel(qd, jnp.asarray(slots), jnp.asarray(pos),
+                         *((k_pool, v_pool) if quant
+                           else map(shared, (k_pool, v_pool))),
+                         tables, scales)
             # the gather oracle materializes [lanes, ctx, heads, hd] in
             # fp32: check a 32-lane sample spread over the live lanes
             live = np.flatnonzero(slots >= 0)
@@ -416,15 +434,16 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
                    for g, w, o in zip(got, want, was))
 
     rows_off = {}
-    for nkv in sz.kernel_writer_heads:
-        keys = jax.random.split(jax.random.fold_in(kp, 1000 + nkv), 4)
-        pools = [jax.random.normal(a, (n_pages + 1, nkv, blk, hd),
-                                   jnp.bfloat16) for a in keys[:2]]
+    for nkv, whd in [(n, hd) for n in sz.kernel_writer_heads] + [(skv, shd)]:
+        keys = jax.random.split(jax.random.fold_in(kp, 1000 + nkv + whd), 4)
+        pools = [shared(jax.random.normal(a, (n_pages + 1, nkv, blk, whd),
+                                          jnp.bfloat16)) for a in keys[:2]]
+        tag = f"_h{nkv}" + (f"x{whd}" if whd != hd else "")
         for name, (slots, pos) in shapes.items():
-            new = [jax.random.normal(a, (len(slots), nkv, hd), jnp.bfloat16)
+            new = [jax.random.normal(a, (len(slots), nkv, whd), jnp.bfloat16)
                    for a in keys[2:]]
             args = (*pools, *new, jnp.asarray(slots), jnp.asarray(pos), tables)
-            rows_off[f"{name.replace('paged', 'rows')}_h{nkv}"] = int(
+            rows_off[name.replace("paged", "rows") + tag] = int(
                 unequal(written(*args), scattered(*args), pools))
     # the delta-rule step kernel against ``delta_step`` in XLA on the same
     # rows: two slots in three decode (every fourth from zeros), the others
@@ -493,7 +512,7 @@ def phase_kernels(sz: Sizes, seed: int, rec: Dict[str, Any],
                       "latent_long": {"lanes": len(lshapes["latent_long"][0]),
                                       "seqs": nl, "context": lctx},
                       "paged": {n: len(s[0]) for n, s in shapes.items()},
-                      "paged_variants": {t or "bf16": list(v[:2])
+                      "paged_variants": {t or "bf16": list(v[:3])
                                          for t, v in variants.items()},
                       "pages_per_seq": mp, "kv_block": blk},
                rel_err={n: round(e, 5) for n, e in errs.items()},

@@ -395,7 +395,9 @@ class KVPool(NamedTuple):
     field a tuple of per-layer leaves, empty where the model has none.
 
     ``k`` / ``v``: one ``[n_blocks + 1, hkv, block, hd]`` leaf a layer that
-    holds pages (last page = scratch sink for masked-out batch lanes where
+    holds pages (heads under 128 wide share a row of 128 lanes where they
+    fill whole rows, ``[.., hkv / pack, block, pack * hd]``:
+    :func:`pool_leaves`; last page = scratch sink for masked-out batch lanes where
     a scatter writes the rows; duplicate scatters with mixed old/new
     values are undefined — inactive lanes must never alias a live page).
     (block, hd) stay minor-most so each page is a native VMEM tile for the
@@ -545,10 +547,25 @@ def cache_layers(model_config) -> int:
     return len(_layers_of(model_config, "full")) * cache_passes(model_config)
 
 
-def pool_leaves(model_config, ragged_config) -> KVPool:
+def pool_leaves(model_config, ragged_config, model_parallel: int = 1
+                ) -> KVPool:
     """The pool's description, one :class:`Leaves` a field: the one place
     a leaf's shape, dtype and sharding are written. :func:`new_pool`
-    allocates from it and the byte arithmetic counts from it."""
+    allocates from it and the byte arithmetic counts from it.
+
+    A K/V payload leaf's rows are whole lanes of 128 wherever the head
+    geometry allows: heads under 128 wide that divide it lie
+    ``paged_attention.heads_a_row`` side by side, ``[pages, hkv / pack,
+    block, pack * head_dim]`` (eight heads of 64: four rows of 128), the
+    same bytes a page and the shape class of a 128-wide head's leaf, which
+    the paged kernel's tiled grid and the row writer copy slabs of and the
+    compiler keeps in place at the step's boundary (a ``[.., 8, 16, 64]``
+    leaf it copied in and out, every tick: PERF.md, PR 50). The heads of
+    one device (``model_parallel``: the model axis the leaf shards by
+    head) have to fill whole rows; a quantized pool keeps a head a row,
+    beside its scale rows."""
+    from ..ops.pallas.paged_attention import heads_a_row
+
     c, cfg = model_config, ragged_config
     bits = KV_BITS[cfg.kv_quant]
     full = _leaves_of(c, _layers_of(c, "full"))
@@ -559,9 +576,12 @@ def pool_leaves(model_config, ragged_config) -> KVPool:
     # latent attention: the attention layers' pages are rows of the latent
     # leaf, and there is no K / V a head
     row = int(getattr(c, "latent_row", 0))
+    pack = 1 if bits else heads_a_row(c.n_kv_heads // model_parallel,
+                                      c.head_dim)
     payload = Leaves(
         0 if row else full,
-        rows + (c.head_dim // 2 if bits == 4 else c.head_dim,),
+        (rows[0], c.n_kv_heads // pack, rows[2],
+         c.head_dim // 2 if bits == 4 else pack * c.head_dim),
         {0: cfg.dtype, 8: jnp.int8, 4: jnp.uint8}[bits],
         PartitionSpec(None, "model", None, None), passes)
     scale = Leaves(full if bits else 0, rows, jnp.float32,
@@ -591,7 +611,7 @@ def new_pool(model_config, ragged_config, topology=None) -> KVPool:
 
         return tuple(one() for _ in range(kind.n))
 
-    return KVPool(*map(zeros, pool_leaves(model_config, ragged_config)))
+    return KVPool(*map(zeros, pool_leaves(model_config, ragged_config, tp)))
 
 
 def kv_page_bytes(model_config, ragged_config) -> int:
@@ -685,6 +705,10 @@ class PageMoves:
 
     def __init__(self, model_config):
         self.passes = cache_runs(model_config)
+        # K / V pages travel a head a row, [.., hkv, block, hd], however
+        # this pool lays its heads out (:func:`pool_leaves`): two engines
+        # of one model exchange them whatever their model axes
+        self.head_dim = model_config.head_dim
 
     def _physical(self, pool: KVPool, ids) -> np.ndarray:
         """[passes, len(ids)]: each pass's page of every id."""
@@ -697,16 +721,21 @@ class PageMoves:
         len(blocks), ...]`` array a :data:`KV_FIELDS` field (None where the
         pool has no such leaves): one device gather per layer leaf, then
         the transfer. The quantized payload and its scales travel exactly
-        as pooled. Under a looped stack a page id brings every pass's
+        as pooled; K / V pages whose heads share rows of the pool travel a
+        head a row (``[.., hkv, block, hd]``, what :class:`KVExport`
+        documents). Under a looped stack a page id brings every pass's
         page: cache layer ``t * layers + l`` is pass ``t`` of layer
         ``l``."""
+        from ..ops.pallas.paged_attention import unpack_heads
+
         idx = jnp.asarray(self._physical(pool, blocks))
 
-        def field(leaves):
+        def field(f, leaves):
             got = np.stack([np.asarray(leaf[idx]) for leaf in leaves], 1)
-            return got.reshape((-1,) + got.shape[2:])  # [passes * layers, ..]
+            got = got.reshape((-1,) + got.shape[2:])  # [passes * layers, ..]
+            return unpack_heads(got, self.head_dim) if f in ("k", "v") else got
 
-        return tuple(field(getattr(pool, f)) if getattr(pool, f) else None
+        return tuple(field(f, getattr(pool, f)) if getattr(pool, f) else None
                      for f in KV_FIELDS)
 
     def write(self, pool: KVPool, blocks: Sequence[int], pages: Tuple,
@@ -727,14 +756,20 @@ class PageMoves:
                       np.int32)
         dst[:need] = blocks
 
-        def padded(a):
-            if a is None or B == need:
+        from ..ops.pallas.paged_attention import share_rows
+
+        def padded(f, a):
+            if a is None:
+                return a
+            if f in ("k", "v"):  # a head a row on the wire: as pooled here
+                a = share_rows(a, getattr(pool, f)[0].shape[1])
+            if B == need:
                 return a
             pad = np.zeros((a.shape[0], B - need) + a.shape[2:], a.dtype)
             return np.concatenate([a, pad], axis=1)
 
         return _scatter_pages(pool, jnp.asarray(self._physical(pool, dst)),
-                              tuple(map(padded, pages)))
+                              tuple(map(padded, KV_FIELDS, pages)))
 
     def copy(self, pool: KVPool, src: int, dst: int) -> KVPool:
         """Device-side copy of page ``src`` onto ``dst`` across every

@@ -479,15 +479,17 @@ class RaggedInferenceEngine:
         its tiles on one chip; else a scatter a leaf (``write_kv_rows``):
         off the TPU, under tensor parallelism (GSPMD partitions the scatter
         by its head index), for a quantized pool (its scale rows are 16
-        elements wide) and for a page slab under 128 lanes wide, the two
-        shapes Mosaic refuses a copy of; and for a latent leaf, whose one
-        "head" makes the scatter an index a lane (the writer moves K and V
-        leaves in pairs)."""
-        from ..ops.pallas.paged_attention import LANES
+        elements wide) and for a page slab under 128 lanes wide (a head
+        geometry ``kv_cache.pool_leaves`` could not lay out in whole rows:
+        one KV head of 64), the two shapes Mosaic refuses a copy of; and
+        for a latent leaf, whose one "head" makes the scatter an index a
+        lane (the writer moves K and V leaves in pairs). Read off the
+        pool's shapes, as the kernel's grid is."""
+        from ..ops.pallas.paged_attention import tiled_grid
 
         return (self.attention_path != "gather" and self._tp_size == 1
                 and not self._kv_bits and not self._latent
-                and self.model.config.head_dim % LANES == 0)
+                and tiled_grid(*self.kv_pool.k))
 
     def _program_pages(self, live_pages: int) -> int:
         """The page count a compiled program of this engine is keyed by,
@@ -500,8 +502,10 @@ class RaggedInferenceEngine:
         grid (``paged_attention.tiled_grid``: every leaf it copies a whole
         number of 128 lanes wide), read off the pool's shapes. Elsewhere
         the bucket is the key as it was: the lane grid's steps are lanes x
-        chunks of the bucket (head size 64, a quantized pool's scale rows),
-        and the ``gather`` path keeps its programs as they were."""
+        chunks of the bucket (a quantized pool's scale rows; heads under
+        128 wide that do not fill whole rows, as one KV head of 64 — eight
+        of 64 do, so Granite holds a program a lane bucket: PR 50), and the
+        ``gather`` path keeps its programs as they were."""
         return int(live_pages) if self._pages_key else self.max_pages
 
     def _expert_product(self, lanes: int) -> Optional[str]:
@@ -1377,8 +1381,9 @@ class RaggedInferenceEngine:
         that no tick compiles. What that is: on the tiled attention path
         a program a lane bucket, whatever ``pages`` (``_program_pages``:
         the bucket is no key there, so any one value warms the bucket's
-        program and the others find it compiled); on the lane grid (head
-        size 64, a quantized pool) and the ``gather`` path a program a
+        program and the others find it compiled); on the lane grid (a
+        quantized pool, heads that fill no whole row of the pool: one KV
+        head of 64) and the ``gather`` path a program a
         (lanes, pages) pair. ``_step_fn._cache_size()`` and the gauge
         ``inference/step_programs`` say how many the engine holds."""
         cfg = self.config
@@ -2330,7 +2335,9 @@ class RaggedInferenceEngine:
 
             def write_pages(own, kk, vv, block_tables, sink):
                 """This layer's leaves with the step's new rows in them;
-                pool layout [pages, hkv, block, hd], kk / vv [T, hkv, hd]."""
+                pool layout [pages, hkv, block, hd] (heads under 128
+                wide side by side in rows of 128: ``kv_cache.pool_leaves``),
+                kk / vv [T, hkv, hd]."""
                 if use_writer:
                     k, v = write_kv_pages(own["k"], own["v"], kk, vv,
                                           block_tables, work, interpret=interp)

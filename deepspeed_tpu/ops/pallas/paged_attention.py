@@ -14,7 +14,7 @@ flight — no [T, ctx] gather materialization (the jnp fallback in
 Layout contract (chosen for TPU tiling):
   q:        [T, hq, hd]                 one token per ragged lane
   k_pool:   [n_pages, hkv, block, hd]   (block, hd) minor = native tiles
-  v_pool:   [n_pages, hkv, block, hd]
+  v_pool:   [n_pages, hkv, block, hd]   (hd < 128: heads share a row, below)
   tables:   [n_seqs, max_pages] int32   a sequence's page list
   seq_slots:[T] int32                   a lane's row of tables; < 0: no lane
   positions:[T] int32                   absolute position of each token
@@ -47,10 +47,25 @@ operation differs. Online
 softmax in VMEM scratch (flash-2 style, as ops/pallas/flash_attention.py).
 bf16 operands, fp32 softmax and accumulation.
 
-Mosaic refuses a hand-rolled copy of a slab under 128 lanes wide. So a
-pool whose leaves are narrower — head_dim 64, or a quantized pool's
-[.., block] scale rows at the usual 16-token pages — keeps the earlier
-grid, (T, chunks of the page bucket) with every page a BlockSpec input
+Mosaic refuses a hand-rolled copy of a slab under 128 lanes wide. A head
+size under 128 that divides it (64: Granite) therefore shares a row:
+:func:`heads_a_row` KV heads lie side by side in the 128 lanes of one row
+of the pool, ``[n_pages, hkv / pack, block, pack * hd]`` (KV head ``pack *
+r + j`` in lanes ``j * hd ..`` of row ``r``), which is what the step's new
+rows ``[T, hkv, hd]`` are when read as ``[T, hkv / pack, pack * hd]``. The
+wrapper hands the kernel each query head in its KV head's lanes of a
+128-lane row, zeros in the others, with the row's ``pack`` GQA groups as
+one folded group, and takes each head's lanes of the output: scores and
+weighted sums are exact (the padding multiplies by zero), the MXU does
+``pack`` times the work of a kernel that its page copies bound, and no
+kernel body differs (PERF.md, PR 50). :func:`paged_attention` reads the
+packing off the pool's row against the query's, so nothing is passed.
+
+A pool that still has a leaf under 128 lanes wide — a quantized pool's
+[.., block] scale rows at the usual 16-token pages, a head geometry that
+does not fill whole rows (one KV head of 64; heads a device under a model
+axis that are no multiple of ``pack``) — keeps the earlier grid, (T,
+chunks of the page bucket) with every page a BlockSpec input
 (:func:`_lane_grid`): about 2.4 us a step whether the chunk is live or
 not, so its time follows lanes x bucket, not the work. The choice is made
 on those shapes alone. PERF.md (PR 29) has both grids' timings.
@@ -223,6 +238,45 @@ def query_tile(T: int) -> int:
 def chunk_pages(block: int) -> int:
     """Pages a KV chunk holds: 256 tokens' worth."""
     return max(1, 256 // block)
+
+
+def heads_a_row(n_kv_heads: int, head_dim: int) -> int:
+    """KV heads that share one 128-lane row of a payload leaf (module
+    docstring): ``128 / head_dim`` where the head size divides 128 and the
+    heads (those of one device, under a model axis) fill whole rows,
+    else 1, the leaf a head a row."""
+    pack = LANES // head_dim if head_dim and LANES % head_dim == 0 else 1
+    return pack if n_kv_heads % pack == 0 else 1
+
+
+def unpack_heads(pages, head_dim: int):
+    """Pages of a payload leaf ``[.., rows, block, pack * hd]`` as
+    ``[.., rows * pack, block, hd]``, a KV head a row: what the oracles
+    read (the kernel reads the rows as they lie)."""
+    *lead, rows, block, width = pages.shape
+    pack = max(1, width // head_dim)
+    if pack == 1:
+        return pages
+    return pages.reshape(*lead, rows, block, pack, head_dim) \
+        .swapaxes(-3, -2).reshape(*lead, rows * pack, block, head_dim)
+
+
+def share_rows(pages, rows: int):
+    """The inverse of :func:`unpack_heads`: pages ``[.., hkv, block, hd]``
+    laid out over ``rows`` rows a page, ``hkv / rows`` heads side by side
+    in each."""
+    *lead, hkv, block, hd = pages.shape
+    pack = hkv // rows
+    if pack == 1:
+        return pages
+    return pages.reshape(*lead, rows, pack, block, hd) \
+        .swapaxes(-3, -2).reshape(*lead, rows, block, pack * hd)
+
+
+def _rows_of(new, leaf):
+    """The step's new rows ``[T, hkv, ..]`` as ``leaf`` holds a token's:
+    heads that share a row of the leaf are neighbours in ``new`` already."""
+    return new.reshape(new.shape[:1] + leaf.shape[1:2] + leaf.shape[3:])
 
 
 def work_list(slots, positions, n_seqs: int, tile_rows: int | None = None):
@@ -508,9 +562,14 @@ def tiled_grid(*leaves) -> bool:
     a pool with these leaves (``k_pool``, and a quantized pool's
     ``k_scale``; anything with a ``shape``). Mosaic refuses a
     hand-rolled copy of a slab under 128 lanes wide, so that grid takes
-    pools whose every leaf has whole lanes: head_dim (a packed one too),
-    and a quantized pool's scale rows [.., block]; any other keeps the lane
-    grid. A caller that keys its programs by the page bucket
+    pools whose every leaf has whole lanes: a payload row of head_dim 128
+    or 256, or of :func:`heads_a_row` smaller heads side by side
+    (``inference/kv_cache.pool_leaves`` lays eight heads of 64 out as four
+    rows of 128), and a quantized pool's scale rows [.., block] where a
+    page is that long. What keeps the lane grid: a quantized pool at the
+    usual 16-token pages (its scale rows are 16 wide), and a head geometry
+    that does not fill whole rows of 128 or does not divide over the model
+    axis in whole rows. A caller that keys its programs by the page bucket
     (``inference/ragged.py``) asks here whether the bucket is read at all."""
     return all(a.shape[-1] % LANES == 0 for a in leaves)
 
@@ -563,14 +622,16 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     variant does not and raises :class:`Int4KVKernelUnsupported` outside
     interpret mode."""
     T, hq, hd = q.shape
-    n_pages, hkv, block, _ = k_pool.shape
+    n_pages, rows, block, width = k_pool.shape
     quant = k_scale is not None
     if quant:
         _check_quant_geometry(k_pool, hd, kv_bits)
         if kv_bits == 4 and not interpret:
             raise Int4KVKernelUnsupported()
+    # KV heads a row of the pool holds (heads_a_row), read off the row
+    pack = 1 if quant else max(1, width // hd)
     max_pages = tables.shape[1]
-    assert hq % hkv == 0
+    assert hq % (rows * pack) == 0
     _check_attn_block(attn_block, block, window)
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
     tables = tables.astype(jnp.int32)
@@ -584,9 +645,22 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     if tiled_grid(k_pool, *((k_scale,) if quant else ())):
         slots = jnp.arange(T, dtype=jnp.int32) if seq_slots is None \
             else seq_slots.astype(jnp.int32)
-        return _tiled(q, k_pool, v_pool, tables, positions, slots, work,
-                      ppc=pages_per_chunk or chunk_pages(block),
-                      tq=tile_rows or query_tile(T), **common)
+        if pack > 1:
+            # each head's values in its KV head's lanes of a whole row and
+            # zeros in the others: [T, rows, pack (KV head), group, pack
+            # (its lanes), hd]. The kernel sees ``rows`` KV heads of 128
+            # with a GQA group of pack * group
+            own = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+            q = (q.reshape(T, rows, pack, -1, 1, hd) * own) \
+                .reshape(T, hq, width)
+        out = _tiled(q, k_pool, v_pool, tables, positions, slots, work,
+                     ppc=pages_per_chunk or chunk_pages(block),
+                     tq=tile_rows or query_tile(T), **common)
+        if pack > 1:
+            out = out.reshape(T, rows, pack, -1, pack, hd)
+            out = jnp.stack([out[:, :, j, :, j] for j in range(pack)], 2) \
+                .reshape(T, hq, hd)
+        return out
     walk_pages = max_pages if live_pages is None \
         else max(1, min(live_pages, max_pages))
     return _lane_grid(q, k_pool, v_pool, tables, positions,
@@ -688,7 +762,8 @@ def write_kv_rows(leaf, page, row, new):
     whatever is live (71 ns each on a v5e: PERF.md, PR 38).
 
     ``leaf`` is a payload leaf [n_pages, hkv, block, hd] (``new`` [T, hkv,
-    hd]) or a ``kv_quant`` scale leaf [n_pages, hkv, block] (``new`` [T,
+    hd]; heads that share a row of the leaf, :func:`heads_a_row`, are one
+    index) or a ``kv_quant`` scale leaf [n_pages, hkv, block] (``new`` [T,
     hkv]); ``page``/``row`` [T] say where lane t's row lands. The KV-head
     axis is an *index* of the scatter beside page and row, so the update
     window is ``hd`` alone (empty for a scale leaf), already minor-most.
@@ -700,7 +775,7 @@ def write_kv_rows(leaf, page, row, new):
     (``tests/test_tpu_compile.py`` guards the compiled step)."""
     heads = jnp.arange(leaf.shape[1], dtype=page.dtype)
     return leaf.at[page[:, None], heads[None, :], row[:, None]].set(
-        new.astype(leaf.dtype))
+        _rows_of(new, leaf).astype(leaf.dtype))
 
 
 # tiles the writer keeps in flight: a tile's page reads are started this
@@ -861,8 +936,10 @@ def write_kv_pages(k_leaf, v_leaf, new_k, new_v, tables, work, *,
     above reads: ONE Pallas call returns both leaves, over the same
     ``work`` list (:func:`work_list`) the paged kernel walks.
 
-    ``new_k`` / ``new_v`` [T, hkv, hd] hold a row a lane; lane t's row
-    lands in page ``tables[slot, pos // block]`` at row ``pos % block``. A
+    ``new_k`` / ``new_v`` [T, hkv, hd] hold a row a lane (heads that share
+    a 128-lane row of the leaf, :func:`heads_a_row`, are written as the
+    one row they are); lane t's row lands in page
+    ``tables[slot, pos // block]`` at row ``pos % block``. A
     tile is a stretch of one sequence's lanes at consecutive positions, so
     its rows land in at most ``tile_rows / block + 1`` pages of that
     sequence: the call brings each such page's [hkv, block, hd] slab (one
@@ -879,8 +956,8 @@ def write_kv_pages(k_leaf, v_leaf, new_k, new_v, tables, work, *,
     ``tables`` is the pass's own for a looped stack (``block_tables + t *
     stride``). ``tile_rows`` must be what ``work`` was cut with."""
     T = new_k.shape[0]
-    return _write_pages(k_leaf, v_leaf, new_k, new_v,
-                        tables.astype(jnp.int32), work,
+    return _write_pages(k_leaf, v_leaf, _rows_of(new_k, k_leaf),
+                        _rows_of(new_v, v_leaf), tables.astype(jnp.int32), work,
                         tq=tile_rows or query_tile(T), interpret=interpret)
 
 
@@ -1058,7 +1135,9 @@ def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
     from ..quantizer import dequantize_kv
 
     T, hq, hd = q.shape
-    n_pages, hkv, block, _ = k_pool.shape
+    n_pages, hkv, block, width = k_pool.shape
+    if k_scale is None:          # heads that share a row of the pool
+        hkv *= max(1, width // hd)
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
     group = hq // hkv
     _check_attn_block(attn_block, block, window)
@@ -1088,9 +1167,9 @@ def paged_attention_reference(q, k_pool, v_pool, tables, positions, *,
         probs = jax.nn.softmax(logits, axis=-1)
         return jnp.einsum("thk,tkhd->thd", probs, vals).astype(q.dtype)
     # [T, max_pages, hkv, block, hd] -> [T, ctx, hkv, hd]
-    keys = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+    keys = unpack_heads(k_pool[tables], hd).transpose(0, 2, 1, 3, 4).reshape(
         T, hkv, -1, hd).transpose(0, 2, 1, 3)
-    vals = v_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+    vals = unpack_heads(v_pool[tables], hd).transpose(0, 2, 1, 3, 4).reshape(
         T, hkv, -1, hd).transpose(0, 2, 1, 3)
     keys = jnp.repeat(keys, group, axis=2)
     vals = jnp.repeat(vals, group, axis=2)

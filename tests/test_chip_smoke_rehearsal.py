@@ -25,7 +25,8 @@ def _tiny() -> chip_smoke.Sizes:
         prompt_lens=(9, 40, 100), shared_prefix=32, probe_len=20,
         new_tokens=4,
         kernel_seq=256, kernel_pages_per_seq=8, kernel_n_seqs=8,
-        kernel_alt_heads=(3, 3), kernel_delta_state=(3, 8, 16),
+        kernel_alt_heads=(3, 3), kernel_shared_heads=(4, 2, 64),
+        kernel_delta_state=(3, 8, 16),
         kernel_ssd_state=(4, 8, 16, 2), kernel_ssd_periods=3,
         kernel_latent=(4, 256, 128), kernel_latent_pages=32,
         zero3_layers=2, zero3_batch=4, zero3_steps=2)
@@ -43,7 +44,7 @@ def test_kernels_phase_interpret(ledger, capsys):
     assert line["phase"] == "kernels"
     paged = {f"paged_{shape}{variant}"
              for shape in ("prefill", "decode", "mixed")
-             for variant in ("", "_h30", "_w64", "_int8")}
+             for variant in ("", "_h30", "_w64", "_int8", "_h2x64")}
     assert set(line["rel_err"]) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dk", "flash_bwd_dv",
                                     "delta_step_o", "delta_step_state",
@@ -56,7 +57,10 @@ def test_kernels_phase_interpret(ledger, capsys):
     # the row writer against the scatter: no element differs
     assert line["rows_unequal"] == {
         f"rows_{shape}_h{heads}": 0
-        for shape in ("prefill", "decode", "mixed") for heads in (8, 16, 30)}
+        for shape in ("prefill", "decode", "mixed")
+        for heads in (8, 16, 30, "2x64")}
+    # two KV heads of 64 a 128-lane row: the tiled grid took that pool
+    assert line["shape"]["paged_variants"]["_h2x64"] == [4, 2, 64]
 
 
 def test_train_phase(ledger):

@@ -574,10 +574,25 @@ def test_fp8_control_differs_from_the_reference(built):
 
 
 # ----------------------------------------------------------------------
-# the kernel paths in interpret mode: head size 16 takes the lane grid and
-# the scatter, as the cell's 64 does, with Granite's softmax scale
-def test_lane_grid_engine_decodes_what_the_gather_engine_decodes(
-        built, monkeypatch):
+# the kernel paths in interpret mode, with Granite's softmax scale: two KV
+# heads of 16 fill a quarter of a 128-lane row, so that pool keeps a head a
+# row, the lane grid and the scatter; two of 64 share a row, as the cell's
+# eight do, and take the tiled grid and the row writer
+def _built_with(**changed):
+    c = hf.granite_hybrid_config(dict(HC, **changed), N_LAYERS)
+    c.remat, c.use_flash = False, False
+    model = Transformer(c)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return model, weights.make(shapes, SEED, jnp.float32, N_LAYERS)
+
+
+@pytest.mark.parametrize("hidden,shared", [(64, False), (256, True)],
+                         ids=["lane_grid", "two_heads_a_row"])
+def test_kernel_path_engine_decodes_what_the_gather_engine_decodes(
+        monkeypatch, hidden, shared):
+    built = _built_with(hidden_size=hidden)
+    assert built[0].config.head_dim == hidden // 4
+
     def drive(eng):
         uids, prompts = [1, 2, 3], _prompts(100, 70, 5)
         rows, _ = _prefill(eng, uids, prompts)
@@ -596,7 +611,12 @@ def test_lane_grid_engine_decodes_what_the_gather_engine_decodes(
     assert eng.attention_path == "pallas_interpret"
     # ... and the Mamba layers' one-token step is the kernel over the slots
     # that decode, in place in each period's run of the rolled leaf
-    assert not eng._writes_pages and eng._steps_live_slots
+    assert eng._steps_live_slots
+    assert eng._writes_pages == shared == (not eng._pages_key)
+    # both periods' runs of pages in the one leaf, a row of 128 lanes a
+    # pair of heads
+    assert eng.kv_pool.k[0].shape == \
+        ((130, 1, 16, 128) if shared else (130, 2, 16, 16))
     b, tokens_b = drive(eng)
     assert tokens_b == tokens_a
     assert np.isfinite(b).all()
@@ -683,8 +703,15 @@ def test_pools_at_the_cells_widths():
     assert (kinds.state.n, kinds.state.shape, kinds.state.passes) \
         == (9, (4 * 65, 64, 64, 128), 4)
     assert (kinds.conv_rows.n, kinds.conv_rows.shape) == (9, (4 * 65, 3, 4352))
+    # eight KV heads of 64 as four rows of 128 lanes: the leaf the tiled
+    # paged kernel and the row writer take (the same bytes a page)
     assert (kinds.k.n, kinds.k.shape, kinds.k.passes) \
-        == (1, (4 * 4097, 8, 16, 64), 4)
+        == (1, (4 * 4097, 4, 16, 128), 4)
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_grid
+    assert tiled_grid(kinds.k, kinds.v)
+    # ... where the heads of one device fill whole rows: 8 / 8 do not
+    assert kv_cache.pool_leaves(c, rc, 8).k.shape == (4 * 4097, 8, 16, 64)
+    assert kv_cache.pool_leaves(c, rc, 4).k.shape == (4 * 4097, 4, 16, 128)
     assert kv_cache.cache_layers(c) == 4 and kv_cache.cache_passes(c) == 1
     # ISSUE 43: 2,097,152 B of state + 26,112 B of rows a layer a sequence
     assert kv_cache.state_slot_bytes(c, rc) == 36 * (2097152 + 26112) \

@@ -253,26 +253,83 @@ def _ragged_batch(runs, T, n_seqs, *, hq=4, hkv=1, hd=128, block=16,
     return q, kp, vp, tables, slots, pos
 
 
-def _check_tiled(runs, T, n_seqs, *, window=0, tol=2e-5, quant=None, **kw):
+def _share_rows(pool):
+    """A payload leaf [n, hkv, block, hd] as ``kv_cache.pool_leaves`` lays
+    heads under 128 wide out: ``128 / hd`` heads side by side in one row
+    of 128 lanes, [n, hkv / pack, block, 128]."""
+    n, hkv, block, hd = pool.shape
+    pack = pa.heads_a_row(hkv, hd)
+    assert pack > 1, (hkv, hd)
+    shared = pool.reshape(n, hkv // pack, pack, block, hd) \
+        .transpose(0, 1, 3, 2, 4).reshape(n, hkv // pack, block, pack * hd)
+    np.testing.assert_array_equal(np.asarray(pa.unpack_heads(shared, hd)),
+                                  np.asarray(pool))
+    return shared
+
+
+def _check_tiled(runs, T, n_seqs, *, window=0, tol=2e-5, quant=None,
+                 attn_block=1, shared=False, **kw):
+    """``shared``: the kernel reads the pool with its heads side by side
+    in rows of 128 lanes; the oracle reads the leaf a head a row."""
     q, kp, vp, tables, slots, pos = _ragged_batch(runs, T, n_seqs, **kw)
-    scales = {}
+    scales = {"attn_block": attn_block} if attn_block > 1 else {}
     if quant:
         kp, vp, sk, sv = _quantize_pools(kp, vp, quant)
         scales = dict(k_scale=sk, v_scale=sv, kv_bits=quant)
+    pools = (_share_rows(kp), _share_rows(vp)) if shared else (kp, vp)
     got = np.asarray(paged_attention(
-        q, kp, vp, tables, jnp.asarray(pos), seq_slots=jnp.asarray(slots),
+        q, *pools, tables, jnp.asarray(pos), seq_slots=jnp.asarray(slots),
         window=window, tile_rows=TQ, pages_per_chunk=PPC, interpret=True,
         **scales))
+    assert pa.tiled_grid(pools[0], *((scales["k_scale"],) if quant else ()))
     live = slots >= 0
     assert np.isfinite(got).all()
     assert not got[~live].any()            # lanes of no sequence: zeros
     if live.any():
-        want = paged_attention_reference(
-            q[live], kp, vp, tables[slots[live]], jnp.asarray(pos[live]),
-            window=window, **scales)
-        np.testing.assert_allclose(got[live], np.asarray(want), rtol=tol,
-                                   atol=tol)
+        args = (tables[slots[live]], jnp.asarray(pos[live]))
+        want = np.asarray(paged_attention_reference(
+            q[live], kp, vp, *args, window=window, **scales))
+        np.testing.assert_allclose(got[live], want, rtol=tol, atol=tol)
+        if shared:  # the oracle unpacks the same leaf: bit for bit
+            np.testing.assert_array_equal(want, np.asarray(
+                paged_attention_reference(q[live], *pools, *args,
+                                          window=window, **scales)))
     return pa.work_list(jnp.asarray(slots), jnp.asarray(pos), n_seqs, TQ)
+
+
+HEAD64_LANES = {
+    "decode": [(3, 150, 1), (0, 31, 1), (5, 64, 1), (2, 17, 1), (4, 0, 1)],
+    # a prompt chunk crossing three pages and a chunk's edge, behind a
+    # decode lane and a speculative run
+    "chunk_crossing_pages": [(1, 90, 1), (5, 64, 4), (2, 21, 37)],
+}
+
+
+@pytest.mark.parametrize("attn_block", [1, 4])
+@pytest.mark.parametrize("group,hkv", [(4, 2), (1, 4), (4, 8)],
+                         ids=["gqa4", "mha", "granite"])
+@pytest.mark.parametrize("lanes", sorted(HEAD64_LANES))
+def test_tiled_head_size_64_two_heads_a_row(lanes, group, hkv, attn_block):
+    """Head size 64 on the tiled grid: two KV heads a 128-lane row of the
+    pool, each query head in its KV head's half of a row and zeros in the
+    other, the pair's two groups one folded group. Exact against the
+    oracle over the leaf a head a row."""
+    _check_tiled(HEAD64_LANES[lanes], 64, 6, hq=group * hkv, hkv=hkv, hd=64,
+                 attn_block=attn_block, shared=True)
+
+
+@pytest.mark.parametrize("hd,hkv", [(32, 4), (16, 8)])
+def test_tiled_smaller_heads_fill_a_row(hd, hkv):
+    """128 / head_dim heads a row: four of 32, eight of 16."""
+    _check_tiled(HEAD64_LANES["chunk_crossing_pages"], 64, 6, hq=2 * hkv,
+                 hkv=hkv, hd=hd, shared=True)
+
+
+@pytest.mark.parametrize("window", [0, 33])
+def test_tiled_head_size_64_bf16_and_window(window):
+    _check_tiled([(1, 77, 1), (0, 0, 20), (2, 130, 9)], 32, 3, hq=8, hkv=2,
+                 hd=64, dtype=jnp.bfloat16, tol=2e-2, window=window,
+                 shared=True)
 
 
 @pytest.mark.parametrize("length", [1, TQ - 1, TQ, TQ + 1, 3 * TQ + 5])
@@ -396,10 +453,15 @@ def test_tile_counts_agree_with_the_work_list(seed):
         == (live.sum(), steps, pages)
 
 
-@pytest.mark.parametrize("hd,grid", [(64, "_lane_grid"), (128, "_tiled")])
-def test_grid_is_chosen_on_head_dim(monkeypatch, hd, grid):
-    """Mosaic refuses a hand-rolled copy under 128 lanes, so head_dim 64
-    keeps the (lane, chunk) grid: chosen on the pool's shape alone."""
+@pytest.mark.parametrize("hd,pool,grid", [
+    (64, "shared_rows", "_tiled"), (128, "a_head_a_row", "_tiled"),
+    (64, "a_head_a_row", "_lane_grid"), (128, "int8", "_lane_grid")])
+def test_grid_is_chosen_on_head_dim(monkeypatch, hd, pool, grid):
+    """Mosaic refuses a hand-rolled copy under 128 lanes: two heads of 64
+    side by side in a row are a slab it takes, and so the tiled grid's; a
+    leaf of 64-wide rows (one KV head of 64 cannot fill a row) and a
+    quantized pool, whose scale rows are a page's 16 tokens wide, keep the
+    (lane, chunk) grid. Chosen on the pool's shapes alone."""
     called = []
     for name in ("_lane_grid", "_tiled"):
         real = getattr(pa, name)
@@ -408,11 +470,18 @@ def test_grid_is_chosen_on_head_dim(monkeypatch, hd, grid):
     rng = np.random.default_rng(5)
     q, kp, vp, tables, positions = _random_paged(
         rng, 4, 4, 2, hd, 16, 16, 4, jnp.float32)
-    got = paged_attention(q, kp, vp, tables, positions, interpret=True)
-    assert called == [grid]
     ref = paged_attention_reference(q, kp, vp, tables, positions)
+    scales, tol = {}, 2e-5
+    if pool == "shared_rows":
+        kp, vp = _share_rows(kp), _share_rows(vp)
+    elif pool == "int8":
+        kp, vp, sk, sv = _quantize_pools(kp, vp, 8)
+        scales, tol = dict(k_scale=sk, v_scale=sv, kv_bits=8), 0.1
+    got = paged_attention(q, kp, vp, tables, positions, interpret=True,
+                          **scales)
+    assert called == [grid]
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+                               rtol=tol, atol=tol)
 
 
 # ----------------------------------------------------------------------
@@ -437,21 +506,27 @@ WRITER_LANES = {
 
 
 def _check_writer(runs, T, n_seqs, *, hkv, max_pages, pass_offset=0,
-                  block=16):
+                  block=16, hd=128):
     """``write_kv_pages`` (interpret mode) against ``write_kv_rows`` on a
     bf16 pool that held other values: every page but the sink bit-equal, K
-    and V; the sink page, which only the scatter writes, as it was."""
+    and V; the sink page, which only the scatter writes, as it was.
+    ``hd`` under 128: the leaves hold their heads side by side in rows of
+    128 lanes, and the scatter over them writes what the scatter over the
+    leaves a head a row does."""
     _, kp, vp, tables, slots, pos = _ragged_batch(
-        runs, T, n_seqs, hkv=hkv, max_pages=max_pages, block=block,
+        runs, T, n_seqs, hkv=hkv, hd=hd, max_pages=max_pages, block=block,
         dtype=jnp.bfloat16)
+    apart = (kp, vp)
+    if hd < 128:
+        kp, vp = _share_rows(kp), _share_rows(vp)
     rng = np.random.default_rng(3)
     pad = lambda a: jnp.concatenate(      # pages of the earlier passes
         [jnp.asarray(rng.standard_normal((pass_offset,) + a.shape[1:]),
                      a.dtype), a])
     kp, vp, tables = pad(kp), pad(vp), tables + pass_offset
     sink = kp.shape[0] - 1
-    nk = jnp.asarray(rng.standard_normal((T, hkv, 128)), jnp.bfloat16)
-    nv = jnp.asarray(rng.standard_normal((T, hkv, 128)), jnp.bfloat16)
+    nk = jnp.asarray(rng.standard_normal((T, hkv, hd)), jnp.bfloat16)
+    nv = jnp.asarray(rng.standard_normal((T, hkv, hd)), jnp.bfloat16)
     slots, pos = jnp.asarray(slots), jnp.asarray(pos)
     live = (slots >= 0) & (pos < max_pages * block)
     page = jnp.where(live, tables[jnp.maximum(slots, 0),
@@ -465,6 +540,11 @@ def _check_writer(runs, T, n_seqs, *, hkv, max_pages, pass_offset=0,
         np.testing.assert_array_equal(bits(g)[:sink], bits(want)[:sink])
         np.testing.assert_array_equal(bits(g)[sink], bits(was)[sink])
         assert (bits(g) != bits(was)).any() == bool(live.any())
+    if hd < 128 and not pass_offset:
+        for was, new, shared in zip(apart, (nk, nv), (kp, vp)):
+            np.testing.assert_array_equal(
+                bits(_share_rows(pa.write_kv_rows(was, page, pos % block, new))),
+                bits(pa.write_kv_rows(shared, page, pos % block, new)))
 
 
 @pytest.mark.parametrize("pass_offset", [0, 97], ids=["pass0", "pass_offset"])
@@ -477,6 +557,13 @@ def test_writer_lands_rows_where_the_scatter_did(hkv, lanes, pass_offset):
     stride."""
     _check_writer(WRITER_LANES[lanes], 64, 6, hkv=hkv, max_pages=12,
                   pass_offset=pass_offset)
+
+
+@pytest.mark.parametrize("lanes", sorted(WRITER_LANES))
+def test_writer_on_heads_that_share_a_row(lanes):
+    """Granite's leaf: eight KV heads of 64 as four rows of 128 lanes. The
+    step's rows [T, 8, 64] are [T, 4, 128] for nothing."""
+    _check_writer(WRITER_LANES[lanes], 64, 6, hkv=8, hd=64, max_pages=12)
 
 
 @pytest.mark.parametrize("T,block,runs", [

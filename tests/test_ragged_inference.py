@@ -370,21 +370,25 @@ def test_expert_products_follow_the_rule(activation, monkeypatch, tmp_path):
     assert got["pallas_interpret"] == got["gather"]
 
 
-@pytest.mark.parametrize("path,head_dim,programs", [
-    ("pallas_interpret", 128, 2),      # the grid over query tiles
-    ("pallas_interpret", 64, 10),      # the lane grid: lanes x page bucket
-    ("gather", 128, 10)], ids=["tiled", "lane_grid", "gather"])
+@pytest.mark.parametrize("path,head_dim,kv_heads,programs", [
+    ("pallas_interpret", 128, 1, 2),   # the grid over query tiles
+    ("pallas_interpret", 64, 2, 2),    # ... two heads of 64 a 128-lane row
+    ("pallas_interpret", 64, 1, 10),   # the lane grid: lanes x page bucket
+    ("gather", 128, 1, 10)],
+    ids=["tiled", "two_heads_a_row", "lane_grid", "gather"])
 def test_step_programs_a_lane_bucket_where_no_page_bucket_is_read(
-        path, head_dim, programs, monkeypatch, tmp_path):
+        path, head_dim, kv_heads, programs, monkeypatch, tmp_path):
     """Where the paged kernel walks query tiles the live-page bucket
     bounds nothing, and the engine holds one step program a lane bucket
     whatever bucket it is warmed or called at (``_program_pages``): after
     ``warm_step`` at every (lanes, pages) pair ``_step_fn`` holds as many
     programs as there are lane buckets, a ``put`` compiles nothing, and
     its rows are bit for bit those of an engine warmed at the tick's own
-    shape alone. An engine on the lane grid (head size 64), whose grid is
-    made of the bucket, and one on the ``gather`` path keep a program a
-    pair. The gauge ``inference/step_programs`` says which; the span's
+    shape alone. Two KV heads of 64 share a row of the pool and take that
+    grid and the row writer (``write_tiles`` > 0). An engine on the lane
+    grid (ONE KV head of 64 fills half a row: the pool keeps a head a
+    row), whose grid is made of the bucket, and one on the ``gather`` path
+    keep a program a pair. The gauge ``inference/step_programs`` says which; the span's
     ``pages`` stays the live bucket."""
     from deepspeed_tpu.config import TelemetryConfig
     from deepspeed_tpu.telemetry import Telemetry, set_telemetry
@@ -392,7 +396,7 @@ def test_step_programs_a_lane_bucket_where_no_page_bucket_is_read(
     if path == "pallas_interpret":
         monkeypatch.setenv("DST_RAGGED_FORCE_PALLAS", "interpret")
     model = Llama("tiny", n_layers=1, d_model=2 * head_dim, n_heads=2,
-                  n_kv_heads=1, vocab_size=128, max_seq_len=256,
+                  n_kv_heads=kv_heads, vocab_size=128, max_seq_len=256,
                   use_flash=False, remat=False)
     params = model.init(jax.random.PRNGKey(3))
     cfg = _cfg(token_budget=96, kv_block_size=16, n_kv_blocks=32,
@@ -417,6 +421,11 @@ def test_step_programs_a_lane_bucket_where_no_page_bucket_is_read(
         rows = [eng.put([1, 2], prompts), eng.put([1, 2], [[9], [9]])]
         assert [(a["lanes"], a["pages"]) for a in seen] == [(96, 8), (64, 8)]
         assert eng._step_fn._cache_size() == programs == gauge.value
+        # the row writer wherever the tiled grid: 5 + 1 tiles, then 2
+        assert eng._writes_pages == (programs == 2)
+        assert [a["write_tiles"] for a in seen] == \
+            ([6, 2] if programs == 2 else [0, 0])
+        assert eng.kv_pool.k[0].shape[1:] == (1, 16, kv_heads * head_dim)
 
         alone = RaggedInferenceEngine(model, cfg, params=params)
         alone.warm_step(96, 8)
@@ -430,6 +439,47 @@ def test_step_programs_a_lane_bucket_where_no_page_bucket_is_read(
     finally:
         tel.close()
         set_telemetry(None)
+
+
+def test_pages_travel_a_head_a_row_whatever_the_pool_shares():
+    """Two KV heads of 64 share a row of the pool; an export carries them
+    a head a row (``KVExport``'s documented shape), so the importer's
+    next step reads bit for bit what the exporter's does, and a pool that
+    keeps a head a row (the same model over a model axis of 2: one head a
+    device fills half a row) takes the same pages."""
+    from deepspeed_tpu.inference import kv_cache
+
+    model = Llama("tiny", n_layers=2, d_model=128, n_heads=2, n_kv_heads=2,
+                  vocab_size=128, max_seq_len=128, use_flash=False,
+                  remat=False)
+    params = model.init(jax.random.PRNGKey(5))
+    cfg = _cfg(kv_block_size=16, n_kv_blocks=16, token_budget=64)
+    a, b = (RaggedInferenceEngine(model, cfg, params=params) for _ in "ab")
+    assert a.kv_pool.k[0].shape == (17, 1, 16, 128)
+    rows = a.put([7], [list(range(1, 40))])
+    export = a.export_kv(7)
+    assert export.k_pages.shape == (2, 3, 2, 16, 64)
+    b.import_kv(7, export)
+    for f in ("k", "v"):
+        for x, y in zip(getattr(a.kv_pool, f), getattr(b.kv_pool, f)):
+            np.testing.assert_array_equal(
+                np.asarray(x[np.asarray(a.seqs[7].blocks)]),
+                np.asarray(y[np.asarray(b.seqs[7].blocks)]))
+    nxt = [[int(np.argmax(rows[0]))]]
+    np.testing.assert_array_equal(a.put([7], nxt), b.put([7], nxt))
+    # ... and into leaves a head a row, as pool_leaves gives a model axis
+    # of 2: the page a head a row is the wire's page
+    apart = kv_cache.KVPool(*(
+        tuple(jnp.zeros(kind.shape, kind.dtype) for _ in range(kind.n))
+        for kind in kv_cache.pool_leaves(model.config, cfg, 2)))
+    assert apart.k[0].shape == (17, 2, 16, 64)
+    moves = kv_cache.PageMoves(model.config)
+    apart = moves.write(apart, [4, 9, 2],
+                        (export.k_pages, export.v_pages, None, None), 8)
+    np.testing.assert_array_equal(np.asarray(apart.k[1][jnp.asarray([4, 9, 2])]),
+                                  export.k_pages[1])
+    np.testing.assert_array_equal(moves.gather(apart, [4, 9, 2])[1],
+                                  export.v_pages)
 
 
 def test_dense_model_has_no_expert_product(tmp_path):

@@ -1003,29 +1003,41 @@ def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
     periods of ten layers (unrolled, its 24 programs took 955 s of a cold
     warm-up on the chip: PERF.md section 6) and fits one chip beside the
     76 MB-a-sequence state pool and the 4,096 pages. One paged kernel call
-    in the text, the attention layer of a period, and at head size 64 it is
-    the grid over lanes with XLA's scatter in front of it, not the row
-    writer (``_writes_pages``). The pool has a leaf a layer of a period,
-    the periods' runs end to end in it; each of the nine state leaves is
-    written by one call of the state-space step kernel in the loop's body,
-    which takes the leaf as an operand and returns it aliased, the
-    period's first slot a prefetched scalar (where nine fusions over a
-    period's whole run of 65 slots were, PR 44), and nothing copies,
-    transposes, slices or selects a state leaf as an operation of its own,
-    inside the loop or outside it (the chunk loop updates one slot in
-    place, through a fused dynamic-update-slice). What is copied,
-    and what the cell pays until a tiled grid takes 64-wide slabs: the K
-    and the V leaf twice a tick, OUTSIDE the loop, from the layout the
-    compiler gives a parameter whose minor dimension is 64 (pages
-    minor-most) to the kernel's pinned row-major one and back (PERF.md,
-    section 7)."""
+    in the text, the attention layer of a period, with one call of the row
+    writer in front of it under ``attn/scatter``, both in the loop's body:
+    the pool lays the eight KV heads of 64 out as four rows of 128 lanes
+    (``kv_cache.pool_leaves``), a leaf of the shape class of Mistral's, so
+    the kernel takes the grid over query tiles and the engine holds a
+    program a lane bucket (``_writes_pages``, ``_pages_key``). The pool has
+    a leaf a layer of a period, the periods' runs end to end in it; each
+    of the nine state leaves is written by one call of the state-space
+    step kernel in the loop's body, which takes the leaf as an operand and
+    returns it aliased, the period's first slot a prefetched scalar (where
+    nine fusions over a period's whole run of 65 slots were, PR 44), and
+    nothing copies, transposes, slices or selects a state leaf as an
+    operation of its own, inside the loop or outside it (the chunk loop
+    updates one slot in place, through a fused dynamic-update-slice). Nor
+    is the K or the V leaf copied, transposed, scattered into or held in
+    another layout than the kernel's, anywhere in the text: until PR 50 a
+    ``bf16[16388,8,16,64]`` parameter (pages minor-most in the compiler's
+    layout) was copied to the kernel's pinned row-major one before the loop
+    and back after it, K and V, every tick (6.05 ms of a 27.6 ms step, two
+    537 MB temporaries: PERF.md section 6)."""
     compiled, c, e = compile_cell_step("granite-4.0-h-micro", one_chip, 64,
                                        128)
     assert _device_bytes(compiled) < 15.75e9
     hlo = compiled.as_text()
     assert c.layer_period == 10 and len(c.layers_of("mamba")) == 36
     assert _kernel_calls(hlo) == 1
-    assert not _writer_calls(hlo)
+    page = f"{4 * (e['max_kv_blocks'] + 1)},4,{e['kv_block_size']},128"
+    writes = _writer_calls(hlo)
+    assert len(writes) == 1 and writes[0].count(f"bf16[{page}]") >= 2
+    assert "/while/body/closed_call/attn/scatter/" in writes[0]
+    # the grid over query tiles: the kernel's leaves are operands in HBM,
+    # and the work list's 64 / 16 + 64 tiles are its grid
+    paged, = [l for l in _custom_calls(hlo) if _KERNEL_RESULT.search(l)]
+    assert "/while/body/closed_call/attn/paged_attention/" in paged
+    assert paged.count(f"bf16[{page}]") == 2
     state = f"{4 * (e['max_seqs'] + 1)},64,64,128"
     # the nine Mamba layers of a period: one call of the step kernel each,
     # in the loop's body, on its own leaf at the period's run of slots
@@ -1034,22 +1046,25 @@ def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
     assert all(f"f32[{state}]" in l
                and "/while/body/closed_call/ssm/ssd_step/" in l
                for l in steps)
-    page = f"{4 * (e['max_kv_blocks'] + 1)},8,{e['kv_block_size']},64"
     entry = list(_entry_instructions(hlo))
     assert sum(op == "parameter" and (dt, dims) == ("f32", state)
                for _, dt, dims, op, _ in entry) == 9
+    assert sum(op == "parameter" and (dt, dims) == ("bf16", page)
+               for _, dt, dims, op, _ in entry) == 2
     moved = re.findall(
         r"= f32\[" + state + r"\]\S* (copy|transpose|slice|dynamic-slice|"
         r"gather|concatenate|select)\(", hlo)
     assert not moved, moved
-    copies = re.findall(r"= bf16\[" + page + r"\]\S* copy\(", hlo)
-    assert len(copies) <= 4, len(copies)
-    assert len(copies) == sum(op == "copy" and dims == page
-                              for _, _, dims, op, _ in entry)
-    # temporaries: the two K/V leaves' second copies, padded to 128 lanes,
-    # and the step's own; 1.11 GB by my described-chip compile, PR 43
-    # (2.08 GB unrolled), 1,107,248,640 B with the step kernel, PR 44
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+    assert not _leaf_moves(hlo, page)
+    assert not _leaf_scatters(hlo, page)
+    # ... nor is there an array of a leaf's size in the old shape, or in
+    # any other with as many elements that is not the leaf
+    assert f"{4 * (e['max_kv_blocks'] + 1)},8,{e['kv_block_size']},64" \
+        not in hlo
+    # temporaries: the step's own. 1,107,248,640 B with the K/V leaves'
+    # second copies, padded to 128 lanes (my described-chip compile, PR
+    # 44); 23,866,880 B without them (mine, PR 50)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
 
 
 # (config, lanes, live pages, pages of the pool or 0 for the file's, KV
