@@ -158,20 +158,88 @@ class _Program:
         return getattr(self._jitted, name)
 
 
-class _Step(_Program):
+def pack_fields(fields) -> np.ndarray:
+    """A tick's five int32 arrays (tokens [T], slots [T], positions [T],
+    block tables [max_seqs, max_pages], the rows the head reads [max_seqs]
+    or [max_seqs, k]) end to end in one new int32 buffer, the form in which
+    they go to the device: one transfer costs what each of five did
+    (0.27-0.29 ms whatever it carries, PERF.md section 6). An array already
+    on the device is fetched first (a warm-up's)."""
+    return np.concatenate([np.asarray(a, np.int32).reshape(-1)
+                           for a in fields])
+
+
+class _Field:
+    """One of a tick's five arrays as whoever stands in a step program's
+    place sees it: its ``shape``, and ``packed``, the one device buffer
+    that holds all five (``pack_fields``). ``_launch`` hands the step five
+    of these; a wrapper reads their shapes and passes them on."""
+
+    __slots__ = ("shape", "packed")
+
+    def __init__(self, shape, packed):
+        self.shape = shape
+        self.packed = packed
+
+
+def _packed_of(tokens, slots, positions, block_tables, sel):
+    """The one device buffer behind a step call's five arrays: the one
+    ``_launch``'s handles share, or five arrays (a warm-up's, a test's:
+    numpy's or the device's) packed as ``_launch`` packs and sent."""
+    if type(tokens) is _Field:
+        return tokens.packed
+    return jnp.asarray(pack_fields((tokens, slots, positions, block_tables,
+                                    sel)))
+
+
+class _Packed(_Program):
+    """A program over a tick's batch (the SplitFuse step, the verify step)
+    behind the eight arguments it has always been called with: ``(params,
+    pools, tokens, slots, positions, block_tables, sel, live_pages)``. The
+    jitted program takes ``(params, pools, packed, lanes, live_pages)``,
+    ``lanes`` and ``live_pages`` static, and cuts the five fields out of
+    ``packed`` itself (``RaggedInferenceEngine._fields``). A call with
+    ``_launch``'s handles runs on the buffer they share; a call with five
+    arrays (a warm-up, a test: numpy's or the device's) packs them with the
+    function ``_launch`` uses and sends the buffer, so both find one
+    compiled program. ``lower`` takes the eight too, abstract or real."""
+
+    def __call__(self, params, pools, tokens, slots, positions,
+                 block_tables, sel, live_pages):
+        return self._jitted(
+            params, pools,
+            _packed_of(tokens, slots, positions, block_tables, sel),
+            int(tokens.shape[0]), self._engine._program_pages(live_pages))
+
+    def lower(self, params, pools, tokens, slots, positions, block_tables,
+              sel, live_pages):
+        size = sum(int(np.prod(a.shape)) for a in (
+            tokens, slots, positions, block_tables, sel))
+        # an abstract argument's sharding says where to compile for
+        packed = jax.ShapeDtypeStruct(
+            (size,), jnp.int32, sharding=tokens.sharding if isinstance(
+                tokens, jax.ShapeDtypeStruct) else None)
+        return self._jitted.lower(params, pools, packed,
+                                  int(tokens.shape[0]), live_pages)
+
+
+class _Step(_Packed):
     """The jitted SplitFuse step behind the two-result call it has always
     answered: ``(logits, pools)``. The program has a third result, each
     slot's greedy token id; a call leaves it on the engine (``_step_ids``)
     for ``_put``. It also says how many step programs the engine holds
     (gauge ``inference/step_programs``)."""
 
-    def __call__(self, *args):
+    def __call__(self, params, pools, tokens, slots, positions,
+                 block_tables, sel, live_pages):
         # the base's call written out: one frame between the caller and the
         # jitted step, as there has always been (every traced operation's
         # location, and with it the compile cache's key, holds the stack)
-        at, eng = self._pages_at, self._engine
+        eng = self._engine
         logits, eng._step_ids, pools = self._jitted(
-            *args[:at], eng._program_pages(args[at]), *args[at + 1:])
+            params, pools,
+            _packed_of(tokens, slots, positions, block_tables, sel),
+            int(tokens.shape[0]), eng._program_pages(live_pages))
         t = eng._telemetry
         if t.enabled:
             t.registry.gauge("inference/step_programs").set(
@@ -1394,22 +1462,27 @@ class RaggedInferenceEngine:
         tables = fill_tables([], [], cfg.max_seqs, self.max_pages)
         tok, slot, pos, _ = build_batch([], [], [], int(lanes))
         logits, self.kv_pool = self._step_fn(
-            self.params, self.kv_pool, jnp.asarray(tok), jnp.asarray(slot),
-            jnp.asarray(pos), jnp.asarray(tables), jnp.asarray(sel),
+            self.params, self.kv_pool, tok, slot, pos, tables, sel,
             int(pages))
         jax.block_until_ready(logits)
 
     def _launch(self, step, host, live_pages: int):
-        """``ragged.dispatch``'s two kinds of work, a span each: the host
-        arrays of a tick sent to the device (``ragged.h2d``), then the
-        jitted ``step`` called on them until it returns (``ragged.call``:
-        the call flattens ``leaves`` arrays of parameters and pool, and
-        returns once the program is enqueued, not when it has run)."""
-        with annotate("ragged.h2d", arrays=len(host),
+        """``ragged.dispatch``'s two kinds of work, a span each: the five
+        host arrays of a tick laid end to end and sent to the device as
+        one buffer in one transfer (``ragged.h2d``: ``arrays`` 1), then
+        the jitted ``step`` called on it until it returns (``ragged.call``:
+        the call flattens ``leaves`` arrays of parameters and pool and the
+        one buffer, and returns once the program is enqueued, not when it
+        has run). ``step`` is called with the eight arguments it has
+        always had, the five arrays as handles onto the buffer
+        (``_Field``), so a wrapper set in its place still reads a tick's
+        shapes."""
+        with annotate("ragged.h2d", arrays=1,
                       bytes=sum(a.nbytes for a in host)):
-            sent = [jnp.asarray(a) for a in host]
+            packed = jnp.asarray(pack_fields(host))
         with annotate("ragged.call", leaves=self._call_leaves):
-            return step(self.params, self.kv_pool, *sent, live_pages)
+            return step(self.params, self.kv_pool,
+                        *(_Field(a.shape, packed) for a in host), live_pages)
 
     def _hand_back(self, uids, last_index, pick,
                    width: Optional[int]) -> np.ndarray:
@@ -1776,10 +1849,8 @@ class RaggedInferenceEngine:
         if self._verify_fn is None:
             self._verify_fn = self._build_verify()
         logits, self.kv_pool = self._verify_fn(
-            self.params, self.kv_pool, jnp.asarray(flat_tokens),
-            jnp.asarray(flat_slot), jnp.asarray(flat_pos),
-            jnp.asarray(self._host_tables()), jnp.asarray(sel_rows),
-            self._live_pages_bucket())
+            self.params, self.kv_pool, flat_tokens, flat_slot, flat_pos,
+            self._host_tables(), sel_rows, self._live_pages_bucket())
         logits = np.asarray(logits)             # [max_seqs, k_max, vocab]
         return [logits[seq.slot, :take] for seq, take in sched]
 
@@ -1795,8 +1866,12 @@ class RaggedInferenceEngine:
         core = self._core
         model = self.model
 
-        def step(params, pools, tokens, slots, positions, block_tables,
-                 sel_rows, live_pages):
+        fields = self._fields
+
+        def step(params, pools, packed, lanes, live_pages):
+            tokens, slots, positions, block_tables, sel_rows = fields(
+                packed, lanes)
+            sel_rows = sel_rows.reshape(block_tables.shape[0], -1)  # [S, k]
             x, pools = core(params, pools, tokens, slots, positions,
                             block_tables, live_pages)
             with jax.named_scope("head"):
@@ -1804,8 +1879,24 @@ class RaggedInferenceEngine:
                 logits = model._head(params, x_sel[None, :])[0]
             return logits.reshape(sel_rows.shape + (-1,)), pools
 
-        return _Program(self, jax.jit(step, donate_argnums=(1,),
-                                      static_argnums=(7,)), 7)
+        return _Packed(self, jax.jit(step, donate_argnums=(1,),
+                                     static_argnums=(3, 4)), 4)
+
+    def _fields(self, packed, lanes: int):
+        """A step program's five fields cut out of its one int32 argument
+        (``pack_fields``' order) at offsets static at trace time:
+        tokens, slots, positions ``[lanes]`` each at 0, ``lanes`` and
+        ``2 * lanes``; the block tables ``[max_seqs, max_pages]`` at
+        ``3 * lanes``; and all that is behind them, flat: the rows the
+        head reads, ``[max_seqs]`` for the plain step, for the others
+        ``[max_seqs, k]`` to reshape (a block's lanes; a verify step's
+        ``k_max``, which the buffer's length gives)."""
+        S, P = self.config.max_seqs, self.max_pages
+        tables = 3 * lanes
+        return (packed[:lanes], packed[lanes:2 * lanes],
+                packed[2 * lanes:tables],
+                packed[tables:tables + S * P].reshape(S, P),
+                packed[tables + S * P:])
 
     def _host_tables(self) -> np.ndarray:
         """The dense [max_seqs, max_pages] block table of the live
@@ -2552,8 +2643,11 @@ class RaggedInferenceEngine:
 
         tallies = self._held is not None
 
-        def step(params, pools, tokens, slots, positions, block_tables,
-                 sel_idx, live_pages):
+        fields = self._fields
+
+        def step(params, pools, packed, lanes, live_pages):
+            tokens, slots, positions, block_tables, sel_idx = fields(
+                packed, lanes)
             tally = [] if tallies else None
             x, pools = core(params, pools, tokens, slots, positions,
                             block_tables, live_pages, tally)
@@ -2580,12 +2674,14 @@ class RaggedInferenceEngine:
         if self._block > 1:
             mask_id, n_decide = c.mask_token_id, c.denoise_tokens
 
-            def step(params, pools, tokens, slots, positions, block_tables,
-                     sel_rows, live_pages):
+            def step(params, pools, packed, lanes, live_pages):
                 # block diffusion: the head on each slot's block of lanes
                 # ([S, B] rows), and under ``decide`` the choice of which
                 # masked positions this pass decides: [S, B] ids come back,
                 # not [S, B, vocab] logits
+                tokens, slots, positions, block_tables, sel_rows = fields(
+                    packed, lanes)
+                sel_rows = sel_rows.reshape(block_tables.shape[0], -1)
                 x, pools = core(params, pools, tokens, slots, positions,
                                 block_tables, live_pages)
                 with jax.named_scope("head"):
@@ -2599,7 +2695,7 @@ class RaggedInferenceEngine:
                 return logits, ids, pools
 
         return _Step(self, jax.jit(step, donate_argnums=(1,),
-                                   static_argnums=(7,)), 7)
+                                   static_argnums=(3, 4)), 4)
 
     def _build_decode(self):
         """Multi-step decode entirely on device: one token per live slot

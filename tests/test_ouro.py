@@ -371,8 +371,8 @@ def test_the_published_size():
 
 # ----------------------------------------------------------------------
 # (e) with one pass, the step is the parent's program
-PARENT_STEP = {"jax": "0.9.0", "sha256": "ef4bd197ab7fb8f7caf84a9447af3d4c"
-               "083cf36b81a4509a84b3ec4a416e6abe"}
+PARENT_STEP = {"jax": "0.9.0", "sha256": "b7f4e014d2098c5c5b3903acf5956ef7"
+               "152d89eb18a1f2e3cb10feed5579475d"}
 
 
 def test_one_pass_lowers_to_the_parent_step():
@@ -380,7 +380,10 @@ def test_one_pass_lowers_to_the_parent_step():
     windowed, untied) lowers to the text it had before passes existed
     (sha256 of ``lower(...).as_text()`` at commit 5de8e75, the parent of
     PR 35, under this jax): no loop, no offset, no other shape. A later PR
-    that changes the step on purpose records its own text here."""
+    that changes the step on purpose records its own text here: PR 51's,
+    whose program takes the tick's five int32 arrays as one packed argument
+    and opens with five slices and a reshape; against 5de8e75's text
+    (``ef4bd197...``) nothing else differs but the numbering of values."""
     if jax.__version__ != PARENT_STEP["jax"]:
         pytest.skip(f"recorded under jax {PARENT_STEP['jax']}")
     from deepspeed_tpu.models import Llama

@@ -266,9 +266,11 @@ def test_put_attributes_agree_with_the_engine(runs):
 
 def test_dispatch_is_cut_where_the_work_changes_kind(runs):
     """Every ``ragged.dispatch`` holds one ``ragged.h2d`` (the tick's five
-    int32 arrays: three of a lane each, the tables, the selection row) and
-    then one ``ragged.call`` (the arrays the call flattens: parameters and
-    pool), and nothing of any length beside them once the step is built."""
+    int32 arrays, three of a lane each, the tables, the selection row, sent
+    as ONE packed buffer: ``arrays`` 1, ``bytes`` the five's) and then one
+    ``ragged.call`` (the arrays the call flattens beside that buffer:
+    parameters and pool), and nothing of any length beside them once the
+    step is built."""
     spans = runs["spans"]
     for d in named(runs, "ragged.dispatch"):
         inside = [s for s in spans if s.parent is not None
@@ -276,7 +278,7 @@ def test_dispatch_is_cut_where_the_work_changes_kind(runs):
         assert [s.name for s in inside] == ["ragged.h2d", "ragged.call"]
         h2d, call = inside
         lanes = spans[d.parent].attrs["lanes"]
-        assert h2d.attrs == {"arrays": 5, "bytes": 4 * (
+        assert h2d.attrs == {"arrays": 1, "bytes": 4 * (
             3 * lanes + MAX_SEQS * runs["max_pages"] + MAX_SEQS)}
         assert call.attrs == {"leaves": runs["leaves"]}
         assert h2d.end <= call.start
@@ -288,8 +290,8 @@ def test_h2d_and_call_nest_under_dispatch_in_either_entry(entry, monkeypatch):
     """``put`` and ``put_spec`` alike, without a session: the two spans open
     and close inside ``ragged.dispatch``, in that order, with ``leaves``
     counted when the step was built (a count of ``(params, kv_pool)``) and
-    ``bytes`` the five host arrays' (the verify step's selection is
-    ``[max_seqs, k]``)."""
+    ``bytes`` the five host arrays' in the one buffer sent (the verify
+    step's selection is ``[max_seqs, k]``)."""
     model = Llama("tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                   vocab_size=VOCAB, max_seq_len=256, use_flash=False,
                   remat=False)
@@ -331,7 +333,7 @@ def test_h2d_and_call_nest_under_dispatch_in_either_entry(entry, monkeypatch):
     leaves = len(jax.tree_util.tree_leaves((engine.params, engine.kv_pool)))
     assert attrs["ragged.call"] == {"leaves": leaves} and leaves > 5
     lanes, k = engine._buckets[0], 4 if entry == "put_spec" else 1
-    assert attrs["ragged.h2d"] == {"arrays": 5, "bytes": 4 * (
+    assert attrs["ragged.h2d"] == {"arrays": 1, "bytes": 4 * (
         3 * lanes + MAX_SEQS * engine.max_pages + MAX_SEQS * k)}
     assert all(type(v) is int for a in attrs.values() for v in a.values())
 
