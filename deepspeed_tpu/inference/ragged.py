@@ -2454,8 +2454,11 @@ class RaggedInferenceEngine:
                 block_tables, tables, sink = at
                 with jax.named_scope("attn"):
                     # the model's own projection (norm, q / k / v, bias,
-                    # QK-norm, heads, rotary), a position a lane
-                    q, kk, vv = model._qkv(x, lp, angles, positions)
+                    # QK-norm, heads, rotary), a position a lane; the
+                    # barrier keeps the head split off the weights, which
+                    # the products then read in place in their stacks
+                    q, kk, vv = model._qkv(x, lp, angles, positions,
+                                           jax.lax.optimization_barrier)
                     # write the new K/V rows into this layer's pages, in
                     # place and in the kernel's layout: page =
                     # table[pos // bs], row = pos % bs (_writes_pages says
@@ -2505,7 +2508,8 @@ class RaggedInferenceEngine:
                 r, h = c.kv_lora_rank, c.n_heads
                 with jax.named_scope("attn"):
                     q_nope, q_rope, latent, k_rope = model._latent_parts(
-                        x, lp, angles, positions)
+                        x, lp, angles, positions,
+                        jax.lax.optimization_barrier)   # as in block
                     pad = self._latent - r - c.qk_rope_dim
                     with jax.named_scope("scatter"):
                         row = jnp.concatenate(

@@ -437,6 +437,12 @@ class TransformerConfig:
         return once + self.total_ut_steps * a_pass
 
 
+def _joined(a):
+    """:meth:`Transformer._qkv`'s ``seam`` where a layer's leaves arrive
+    already sliced (``lax.scan``: the training step): nothing."""
+    return a
+
+
 class Transformer:
     """Functional model: ``init`` -> params pytree; ``apply`` -> logits;
     ``loss`` -> scalar; ``partition_specs`` -> TP placement."""
@@ -656,9 +662,12 @@ class Transformer:
         common stack at ``li`` and, with layer_types, its kind's stack at
         the layer's index among its kind. ``li`` is a python int, so the
         slice is static. A dense product fuses it into its own operand
-        read where the compiler keeps the leaf's layout, and copies the
-        slice first where it chooses another (Mistral's ``wo`` / ``wk`` /
-        ``wv`` on the TPU). An operation that cannot fuse a slice (the
+        read (``wo``, the feed-forward's matrices), unless the compiler
+        has folded a reshape of the product's result onto the weight: a
+        slice under that bitcast is written out first, every layer's on
+        every call, so a caller that takes its leaves from here keeps the
+        head split of q / k / v on the results (:meth:`_qkv`'s
+        ``seam``). An operation that cannot fuse a slice (the
         experts' ``ragged_dot``) has the layer's matrices copied on every
         call: with ``in_place`` the ``stacked_operands`` are handed over
         whole, with ``lp["layer"] = li`` beside them, for the operation to
@@ -935,14 +944,27 @@ class Transformer:
         return self._norm(x, lp["attn_norm_w"], lp.get("attn_norm_b")) \
             if c.prenorm and not c.branch_norm else x
 
-    def _qkv(self, x, lp, angles, positions):
+    def _qkv(self, x, lp, angles, positions, seam=_joined):
         """The attention block from its input to (q, k, v) split by heads
         and rotated: x [..., s, d] -> q [..., s, h, hd], k and v [..., s,
         hkv, hd]. The half of the block that does not depend on where K
         and V live, written once: :meth:`_block` (x [b, s, d]) and the
         ragged step (inference/ragged.py, x [T, d] with a position a lane)
         call it around their own attention. Order: bias, then QK-norm,
-        then rotary."""
+        then rotary. Two halves, joined by ``seam``: the three products
+        with their bias and the whole-projection QK-norm, each result
+        ``[..., s, heads * hd]``; then the head split, the per-head norm
+        and rotary. ``seam`` takes the results across. XLA folds a reshape
+        that follows a product onto the product's weight operand, and a
+        weight that is a slice of a stacked leaf (:meth:`layer_params`)
+        no longer fuses into the operand read under that bitcast: the
+        slices are written out first, every layer's on every call (768 MB
+        read and written a tick of Mistral's 16-layer step, the three
+        stacks copied whole before Ouro's loop: PERF.md section 6, PR
+        54). So a caller whose ``lp`` came out of a stack, the served
+        step, passes ``jax.lax.optimization_barrier``: the results exist
+        in the leaf's dtype, as ``h @ w`` says, before anything splits
+        them, and the weights are read in place."""
         c = self.config
         if c.kv_lora_rank:
             # the expanded form: a head's key is its part of the latent's
@@ -950,7 +972,7 @@ class Transformer:
             # part (v_head_dim wide: a flash kernel of one head size does
             # not take it, _block)
             q_nope, q_rope, latent, k_rope = self._latent_parts(
-                x, lp, angles, positions)
+                x, lp, angles, positions, seam)
             h_ = c.n_heads
             k_nope = (latent @ lp["w_uk"]).reshape(
                 latent.shape[:-1] + (h_, c.qk_nope_dim))
@@ -970,6 +992,7 @@ class Transformer:
             # over the whole projection, heads unsplit
             q = rms_norm(q, lp["q_norm_w"], c.norm_eps)
             kk = rms_norm(kk, lp["k_norm_w"], c.norm_eps)
+        q, kk, vv = seam((q, kk, vv))
         q, kk, vv = (heads(q, c.n_heads), heads(kk, c.n_kv_heads),
                      heads(vv, c.n_kv_heads))
         if c.qk_norm_heads:   # a head at a time, one gain for all of them
@@ -983,7 +1006,7 @@ class Transformer:
                               interleaved=c.rope_interleaved)
         return q, kk, vv
 
-    def _latent_parts(self, x, lp, angles, positions):
+    def _latent_parts(self, x, lp, angles, positions, seam=_joined):
         """Latent attention from the block's input to what both its forms
         start from: a head's un-rotated and rotated query parts ``[..., s,
         h, qk_nope_dim]`` / ``[..., s, h, qk_rope_dim]``, the normed latent
@@ -991,11 +1014,13 @@ class Transformer:
         ``[..., s, qk_rope_dim]``. The expanded form (:meth:`_qkv`) expands
         the latent by head; the served step absorbs ``w_uk`` into the
         query and caches (latent | rotated key) as one row
-        (inference/ragged.py)."""
+        (inference/ragged.py). ``seam``: :meth:`_qkv`'s, between the
+        query's up-projection and its head split."""
         c = self.config
         h = self._mixer_input(x, lp)
         cq = rms_norm(h @ lp["w_dq"], lp["q_lora_norm_w"], c.norm_eps)
-        q = (cq @ lp["w_uq"]).reshape(cq.shape[:-1] + (c.n_heads, c.head_dim))
+        q = seam(cq @ lp["w_uq"]).reshape(
+            cq.shape[:-1] + (c.n_heads, c.head_dim))
         q_nope, q_rope = q[..., :c.qk_nope_dim], q[..., c.qk_nope_dim:]
         down = h @ lp["w_dkv"]
         latent = rms_norm(down[..., :c.kv_lora_rank], lp["kv_lora_norm_w"],

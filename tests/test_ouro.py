@@ -371,8 +371,8 @@ def test_the_published_size():
 
 # ----------------------------------------------------------------------
 # (e) with one pass, the step is the parent's program
-PARENT_STEP = {"jax": "0.9.0", "sha256": "b7f4e014d2098c5c5b3903acf5956ef7"
-               "152d89eb18a1f2e3cb10feed5579475d"}
+PARENT_STEP = {"jax": "0.9.0", "sha256": "dd78ae1ad016a24edc4b0686ea456ec6"
+               "5f084cb6fdd2c940727dfc8b286e8cfd"}
 
 
 def test_one_pass_lowers_to_the_parent_step():
@@ -383,7 +383,11 @@ def test_one_pass_lowers_to_the_parent_step():
     that changes the step on purpose records its own text here: PR 51's,
     whose program takes the tick's five int32 arrays as one packed argument
     and opens with five slices and a reshape; against 5de8e75's text
-    (``ef4bd197...``) nothing else differs but the numbering of values."""
+    (``ef4bd197...``) nothing else differs but the numbering of values.
+    PR 54's: two ``stablehlo.optimization_barrier``, one a layer over its
+    q, k and v between the products and the head split
+    (``Transformer._qkv``'s ``seam``); against PR 51's text
+    (``b7f4e014...``) nothing else differs but that numbering."""
     if jax.__version__ != PARENT_STEP["jax"]:
         pytest.skip(f"recorded under jax {PARENT_STEP['jax']}")
     from deepspeed_tpu.models import Llama

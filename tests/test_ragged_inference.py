@@ -516,21 +516,91 @@ def test_ragged_serves_relu_activation():
     _assert_ragged_matches_dense(model, params, {1: list(range(1, 9))}, 6)
 
 
-@pytest.mark.parametrize("family", ["gpt2", "opt"])
+# the forms _qkv's first half takes before the head split (the seam the
+# served step puts its barrier at): a bias on each product, a norm
+# over the whole projection, a norm a head
+SEAM_FAMILIES = {"qkv_bias": dict(qkv_bias=True),
+                 "qk_norm": dict(qk_norm=True),
+                 "qk_norm_heads": dict(qk_norm=True, qk_norm_heads=True),
+                 "qkv_bias_qk_norm": dict(qkv_bias=True, qk_norm=True)}
+
+
+def _seam_model(family):
+    """A rotary model of ``SEAM_FAMILIES`` with its biases and norm gains
+    random, so that one applied out of order, or dropped, shows."""
+    from deepspeed_tpu.models.transformer import Transformer, TransformerConfig
+
+    model = Transformer(TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        max_seq_len=128, norm="rms", activation="silu_glu", position="rope",
+        use_bias=False, tie_embeddings=False, use_flash=False, remat=False,
+        **SEAM_FAMILIES[family]))
+    params = model.init(jax.random.PRNGKey(3))
+    names = [n for n in ("bq", "bk", "bv", "q_norm_w", "k_norm_w")
+             if n in params["layers"]]
+    for key, name in zip(jax.random.split(jax.random.PRNGKey(11), len(names)),
+                         names):
+        leaf = params["layers"][name]
+        params["layers"][name] = (1.0 if name.endswith("norm_w") else 0.0) \
+            + 0.5 * jax.random.normal(key, leaf.shape, leaf.dtype)
+    return model, params
+
+
+def _assert_step_matches_unjoined_qkv(model, params, prompt):
+    """One served step over ``prompt``: the logits of its last token are
+    the model's own (``apply``, whose ``_qkv`` runs unjoined), and the K /
+    V rows the step wrote into the first layer's pages are ``_qkv``'s k
+    and v of the embedded prompt, bias, QK-norm and rotary in the order
+    its docstring states, on both sides of the seam."""
+    from deepspeed_tpu.ops.rotary import rope_frequencies
+
+    c = model.config
+    eng = RaggedInferenceEngine(model, _cfg(), params=params)
+    logits = eng.put([5], [list(prompt)])
+    tokens = jnp.asarray([prompt], jnp.int32)
+    np.testing.assert_allclose(
+        logits[0], np.asarray(model.apply(params, tokens)[0, -1]),
+        rtol=2e-5, atol=2e-5)
+    positions = jnp.arange(len(prompt))[None]
+    _, lp = model.layer_params(params["layers"], 0)
+    _, kk, vv = model._qkv(
+        model._embed(params, tokens, positions=positions), lp,
+        rope_frequencies(c.rotary_dim, c.max_seq_len, c.rope_theta,
+                         c.rope_yarn), positions)
+    bs = eng.config.kv_block_size
+    pages = np.asarray(eng.seqs[5].blocks)
+    for leaf, want in ((eng.kv_pool.k[0], kk), (eng.kv_pool.v[0], vv)):
+        assert leaf.shape[1:] == (c.n_kv_heads, bs, c.head_dim)
+        rows = np.asarray(leaf)[pages].transpose(0, 2, 1, 3).reshape(
+            -1, c.n_kv_heads, c.head_dim)[:len(prompt)]
+        np.testing.assert_allclose(rows, np.asarray(want[0]),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "opt", *SEAM_FAMILIES])
 def test_ragged_serves_gpt2_and_opt_layouts(family):
     """Non-llama families through continuous batching (the reference's
     FastGen ships OPT support, inference/v2/model_implementations/opt/):
     learned positions via model._embed, the layernorm path, and biased
-    projections — token-exact vs the dense engine."""
+    projections — token-exact vs the dense engine. ``SEAM_FAMILIES``:
+    rotary models whose q / k / v take a bias or a QK-norm between the
+    product and the head split, where the served step joins ``_qkv``'s
+    halves with a barrier and the dense engine with nothing: token-exact
+    too, and the step's logits and written rows against the unjoined
+    ``_qkv``."""
     from deepspeed_tpu.models import GPT2, OPT
 
-    factory, size = (GPT2, "tiny") if family == "gpt2" else (OPT, "125m")
-    model = factory(size, n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
-                    vocab_size=128, max_seq_len=128, use_flash=False,
-                    remat=False)
-    params = model.init(jax.random.PRNGKey(0))
-    _assert_ragged_matches_dense(
-        model, params, {2: list(range(1, 9)), 4: list(range(30, 44))}, 6)
+    prompts = {2: list(range(1, 9)), 4: list(range(30, 44))}
+    if family in SEAM_FAMILIES:
+        model, params = _seam_model(family)
+        _assert_step_matches_unjoined_qkv(model, params, prompts[4])
+    else:
+        factory, size = (GPT2, "tiny") if family == "gpt2" else (OPT, "125m")
+        model = factory(size, n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
+                        vocab_size=128, max_seq_len=128, use_flash=False,
+                        remat=False)
+        params = model.init(jax.random.PRNGKey(0))
+    _assert_ragged_matches_dense(model, params, prompts, 6)
 
 
 def test_ragged_serves_internlm_layout():

@@ -20,6 +20,9 @@ roofline readers tell it from the paged kernel), the 12-layer Olmo-Hybrid step o
 benchmark's cell with both of its caches (fits, copies no leaf and no
 weight), the Mixtral cell's step (copies no expert matrix), the Ouro
 cell's 48-layer step of four passes (one rolled loop, no pool leaf copied),
+how the six serving configurations' decode steps read their stacked
+``wq`` / ``wk`` / ``wv`` / ``w_uq`` leaves (in place: no slice written out,
+no stack copied),
 and the two recurrent kinds' step kernels alone and in their cells' steps
 (nine calls each, the state leaf aliased; Granite's inside its rolled loop
 over periods, on a leaf of four runs of slots).
@@ -913,7 +916,9 @@ def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
     shape, and temporaries within 1 MB of what the step had without the
     ids (108,547,584 bytes by my described-chip compile of PR 31's tree,
     108,579,328 with them; 103,843,840 since PR 41, whose step kernel
-    took the XLA step's reductions over the state leaf with it)."""
+    took the XLA step's reductions over the state leaf with it;
+    43,991,040 since PR 54, which stopped the three attention layers'
+    slices of ``full.wv`` being written out, 59.9 MB)."""
     compiled, c, e = compile_cell_step("olmo-hybrid-7b", one_chip, 64, 128)
     hlo = compiled.as_text()
     entry = hlo[hlo.index("\nENTRY "):]
@@ -926,7 +931,7 @@ def test_olmo_hybrid_step_returns_token_ids(one_chip, on_tpu):
               if op == "copy" and (dt, dims) == ("f32", logits[4:-1])]
     assert not copies, copies
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert abs(temp - 103_843_840) < 1e6, temp
+    assert abs(temp - 43_991_040) < 1e6, temp
 
 
 def _leaf_moves(hlo: str, leaf_dims: str) -> list:
@@ -964,11 +969,11 @@ def test_ouro_step_is_one_loop_and_copies_no_leaf(one_chip, on_tpu):
     writes as in every other cell and ``loop_device_ms.serve`` reads
     them). It fits the chip beside 320
     pages (8.05 GB of pool, 5.34 GB of weights). Temporaries, stated:
-    1.62 GB at 320 pages, of which 1.21 GB are the stacked ``wq``, ``wk``,
-    ``wv`` leaves copied whole to a transposed layout before the loop (the
-    compiler's layout for a dense product at 64 rows: PERF.md section 7,
-    question 4) and the rest the loop body's per-layer slices of them; the
-    same at 160 pages, so they do not grow with the pool."""
+    15.7 MB at 320 pages, the step's own activations (1.62 GB before the
+    head split left the weight operand: PERF.md section 6, PR 54;
+    ``test_served_step_reads_attention_stacks_in_place`` holds how the
+    stacks are read); 18.6 MB at 160 pages, so they do not grow with the
+    pool."""
     compiled, c, e = compile_cell_step("ouro-2.6b", one_chip, 64, 64,
                                        OURO_PAGES)
     assert c.total_ut_steps == 4 and c.n_layers == 48
@@ -990,10 +995,10 @@ def test_ouro_step_is_one_loop_and_copies_no_leaf(one_chip, on_tpu):
     assert all("/while/body/closed_call/attn/scatter/" in w for w in writes)
     assert not _leaf_scatters(hlo, leaf[1])
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 1.75e9, temp
+    assert temp < 32e6, temp
     smaller, _, _ = compile_cell_step("ouro-2.6b", one_chip, 64, 64,
                                       OURO_PAGES // 2)
-    assert abs(smaller.memory_analysis().temp_size_in_bytes - temp) < 2e6
+    assert smaller.memory_analysis().temp_size_in_bytes < 32e6
 
 
 def test_granite_hybrid_step_is_one_loop_and_copies_no_state_leaf(one_chip,
@@ -1115,17 +1120,22 @@ def test_cell_step_writes_rows_by_one_call_a_layer(one_chip, on_tpu, cell):
                             leaf_pages=e["max_kv_blocks"] + 1) == []
 
 
-def test_mistral_step_temporaries_are_the_parents(one_chip, on_tpu):
-    """The writer brings no temporary of the pool's size. The 16-layer
-    decode step's temporaries with the scatter (my described-chip compile
-    of PR 38's parent commit: 737,403,904 B) were the stacked attention
-    weights copied to a transposed layout (737 MB, PERF.md section 7
-    question 4) and the activations; with the writer they read 737,986,048
-    B: the new rows laid out [hkv, T, hd] for the kernel, 0.6 MB, are all
-    that is added (at 2048 lanes 831,137,792 -> 836,354,560: 5.2 MB)."""
-    compiled, _, _ = compile_cell_step("mistral-7b", one_chip, 64, 128)
+@pytest.mark.parametrize("T,limit", [(64, 16e6), (2048, 80e6)],
+                         ids=["decode", "prefill_chunk"])
+def test_mistral_step_temporaries_are_its_activations(one_chip, on_tpu, T,
+                                                      limit):
+    """The 16-layer step holds no temporary of a weight's or the pool's
+    size: 3.9 MB at 64 lanes and 76.0 MB at 2,048 (my described-chip
+    compiles, PR 54; the larger program's follow the scheduler's
+    prefetches, 64 to 81 MB over three constructions that read in place),
+    the activations and the new rows laid out [hkv, T, hd] for the row
+    writer. Until the head split of q / k / v left the weight operand
+    they read 738.0 MB and 836.4 MB: every layer's slice of the stacked
+    ``wq``, ``wk`` and ``wv`` leaves written out a tick
+    (``Transformer._qkv``'s ``seam``; PERF.md section 6, PR 54)."""
+    compiled, _, _ = compile_cell_step("mistral-7b", one_chip, T, 128)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 740e6 and temp - 737_403_904 < 1e6, temp
+    assert temp < limit, temp
 
 
 def test_roofline_readers_do_not_count_the_writer(one_chip, on_tpu):
@@ -1324,3 +1334,105 @@ def test_axk1_step_compiles_over_latent_pages(one_chip, on_tpu, T):
     assert 12.3e9 < mem.argument_size_in_bytes < 12.5e9
     assert mem.temp_size_in_bytes < 1.5e9
     assert _device_bytes(compiled) < 15.75 * 2 ** 30
+
+
+# ----------------------------------------------------------------------
+# the served step's q / k / v products read the stacked leaves in place
+# config -> (lanes, live pages, pages of the pool or 0 for the file's): a
+# cached decode step of each serving configuration whose attention layers'
+# leaves come out of a stack by a static slice (Granite's rolled loop
+# slices dynamically and copies no matrix)
+ATTENTION_STACK_STEPS = {
+    "mistral-7b": (64, 128, 0),
+    "mixtral-8x7b": (64, 128, 0),
+    "olmo-hybrid-7b": (64, 128, 0),
+    "ouro-2.6b": (64, 64, OURO_PAGES),
+    "sdar-30b-a3b": (256, 64, 0),
+    "a.x-k1": (64, 1024, 0),
+}
+_HEAD_SPLIT_LEAF = re.compile(r"^params__layers__(?:__full__)?__"
+                              r"(?:w[qkv]|w_uq)__")
+
+
+def _computations(hlo: str) -> dict:
+    """name -> lines of each computation of a compiled module's text."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _stack_readers(hlo: str) -> list:
+    """(what, name) of every operation that runs by itself (in the entry
+    computation or a loop's body, not inside a fusion) and takes a stacked
+    ``wq`` / ``wk`` / ``wv`` / ``w_uq`` leaf as an operand: a fusion by its
+    kind, anything else by its opcode. In a loop's body, where a leaf is an
+    element of the carried tuple, a leaf is known by its shape (``wo``
+    shares ``wq``'s in Mistral and Ouro, and is read in place as well)."""
+    comps = _computations(hlo)
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+    shapes, readers = set(), []
+    for comp, lines in comps.items():     # the entry's parameters, by name
+        for n, i in _instructions("\n".join(lines)).items():
+            if i.op == "parameter" and _HEAD_SPLIT_LEAF.match(n):
+                shapes.add((i.dtype, i.dims))
+    assert shapes
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        ins = _instructions("\n".join(lines))
+        leaves = {n for n, i in ins.items()
+                  if (i.dtype, i.dims) in shapes
+                  and (_HEAD_SPLIT_LEAF.match(n) if i.op == "parameter"
+                       else i.op == "get-tuple-element")}
+        for line in lines:
+            m = re.match(r"^\s*(?:ROOT )?%(\S+) = (.*)$", line)
+            call = m and re.search(r"\s([a-z][\w\-]*)\((%[^)]*)\)", m.group(2))
+            if not call or not leaves & set(
+                    re.findall(r"%([\w.\-]+)", call.group(2))):
+                continue
+            kind = re.search(r"kind=(\w+)", line)
+            readers.append((kind.group(1) if call.group(1) == "fusion"
+                            else call.group(1), m.group(1)))
+    return readers
+
+
+@pytest.mark.parametrize("config", sorted(ATTENTION_STACK_STEPS))
+def test_served_step_reads_attention_stacks_in_place(one_chip, on_tpu,
+                                                     config):
+    """No tick copies a stacked attention weight: in each serving
+    configuration's decode step every operation that takes ``wq``, ``wk``,
+    ``wv`` (``w_uq`` under latent attention) as an operand is an output
+    fusion, the product itself with the layer's slice fused into its
+    operand read, or the compiler's own prefetch of pieces into VMEM
+    (``slice-start`` / ``copy-start``, asynchronous, in the leaf's
+    layout), or the loop that carries the leaf (Ouro's). Before
+    ``Transformer._qkv``'s ``seam`` XLA folded the head split of a
+    product's result onto its weight operand,
+    and a slice under that bitcast does not fuse: a ``kLoop``
+    ``slice_bitcast_fusion`` a leaf wrote every layer's slice out on every
+    tick (Mistral 768 MB, Mixtral's ``wq`` / ``wk``, Olmo-Hybrid's
+    ``full.wv``, SDAR's ``wq``, A.X-K1's ``w_uq`` 226 MB), and Ouro's
+    step copied the three stacks whole to another layout before its loop
+    (``copy``, 1.21 GB) and sliced the copies inside it (PERF.md section
+    6, PR 54)."""
+    T, live_pages, pages = ATTENTION_STACK_STEPS[config]
+    with jax.default_matmul_precision("default"):   # as the cells run
+        compiled, c, _ = compile_cell_step(config, one_chip, T, live_pages,
+                                           *([pages] if pages else []))
+    hlo = compiled.as_text()
+    readers = _stack_readers(hlo)
+    in_place = {"kOutput", "slice-start", "copy-start", "tuple", "while"}
+    assert readers and not [r for r in readers if r[0] not in in_place], \
+        readers
+    # the products are there: a layer's three (w_uq's one) read the leaf
+    # itself, or pieces of it that the compiler's prefetch brought
+    products = [r for r in readers if r[0] == "kOutput"]
+    prefetched = {r[0] for r in readers} & {"slice-start", "copy-start"}
+    assert products if prefetched else len(products) >= len(
+        c.layers_of("full")) * (1 if c.kv_lora_rank else 3), readers
