@@ -42,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..config import Config
 from ..parallel.mesh import Topology
 from ..parallel.zero import ZeroShardingRules
+from ..profiling import collectives as coll
 from ..profiling.trace import annotate
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
@@ -190,13 +191,13 @@ class TrainEngine:
         # -- ZeRO placement rules
         self.zero_rules = ZeroShardingRules(self.topo, config.zero)
         param_shapes = jax.eval_shape(lambda p: p, params)
-        # fp32 gradient-tree bytes: the per-step cross-'data' reduction
-        # payload the telemetry comm breakdown reports (_grad_reduce_comm)
-        self._grad_bytes = int(sum(
-            np.prod(l.shape) for l in jax.tree_util.tree_leaves(param_shapes)
-            if hasattr(l, "shape")) * 4)
         self.param_shardings = self.zero_rules.param_shardings(param_shapes, tp_specs)
         self.grad_shardings = self.zero_rules.grad_shardings(param_shapes, tp_specs)
+        # what the stage needs to move a chip a step (zero_plan), and what
+        # train.step carries beside its number: static ints, made once
+        self._zero_plan = self._make_zero_plan(param_shapes)
+        self._collectives: Tuple[Any, ...] = ()   # warmup() fills it
+        self._step_attrs: Dict[str, int] = self._make_step_attrs()
 
         # -- ZeRO++ (reference runtime/engine.py:836-845 keys):
         #   qwZ  — the stage-3 weight gather at the compute-cast boundary
@@ -405,7 +406,10 @@ class TrainEngine:
         self._peak_flops: Optional[float] = None
         self._tokens_per_batch: Optional[int] = None
         self._comm_totals_prev: Dict[str, Dict[str, float]] = {}
-        self._grad_comm_noted = False
+        # the compiled step's own traffic as the comm ledger has it
+        # (_step_comm): {op: {payload: runs a step}}, {op: (calls, bytes)}
+        self._comm_booked: Optional[Dict[str, Dict[int, int]]] = None
+        self._comm_totals: Dict[str, Tuple[int, int]] = {}
         self._closed = False
         self.ckpt_engine = CheckpointEngine(
             async_save=config.checkpoint.async_save,
@@ -546,6 +550,7 @@ class TrainEngine:
 
     # ==================================================================
     # core jitted programs
+    @jax.named_scope("zero_cast")   # ZeRO's placement point, by name
     def _compute_copy(self, params):
         """Compute-dtype copy of the fp32 master params with the ZeRO++
         transforms applied at this boundary: qwZ fake-quantizes through the
@@ -921,6 +926,7 @@ class TrainEngine:
                 self.params, self.opt_state, self.scaler_state, self.rng,
                 struct)
             self._train_step_aot = lowered.compile()  # dslint: disable=races -- warmup-join synchronization: train_batch reaches its _train_step_aot read only after _ensure_train_step_fn joined this thread
+            self._note_step_collectives()
             return True
         except Exception as e:  # noqa: BLE001 — warmup must never kill init
             logger.warning(f"AOT warmup failed (lazy jit path unaffected): {e}")
@@ -937,6 +943,128 @@ class TrainEngine:
         t.start()
         return t
 
+    # ==================================================================
+    # what the step moves (docs/observability.md "Program spans and device
+    # scopes"): the stage's plan from shapes, the compiled step's
+    # collectives from its text. Host-side bookkeeping, made once.
+    def _make_zero_plan(self, param_shapes) -> Dict[str, int]:
+        """See :meth:`zero_plan`."""
+        n = self.topo.data_parallel_size
+        stage = self.config.zero.stage
+        zero_axes = set(self.zero_rules.zero_axes)
+
+        def leaf(l, sh) -> Tuple[int, bool]:
+            """(elements of one chip's part before ZeRO cuts it, whether
+            the stage stores it cut): a leaf a tensor-parallel axis cuts is
+            gathered and reduced over the data axis a part at a time."""
+            axes = [a for e in sh.spec if e is not None
+                    for a in (e if isinstance(e, tuple) else (e,))]
+            other = int(np.prod([self.topo.axis_size(a) for a in axes
+                                 if a not in zero_axes] or [1]))
+            return (int(np.prod(l.shape)) // other,
+                    any(a in zero_axes for a in axes))
+
+        leaves = [leaf(l, sh) for l, sh in zip(
+            jax.tree_util.tree_leaves(param_shapes),
+            jax.tree_util.tree_leaves(self.param_shardings))
+            if hasattr(l, "shape")]
+        count = sum(c for c, _ in leaves)
+        # gradients are cast to float32 where grad_shardings constrain them
+        grad_bytes = count * 4
+        compute_size = jnp.dtype(self.config.compute_dtype).itemsize
+        share = lambda b: b * (n - 1) // n if n > 1 else 0
+        if stage >= 3:
+            # the compute copy of every leaf stored sharded, brought
+            # together for the forward and once more for the backward
+            param_bytes = sum(c for c, cut in leaves if cut) * compute_size
+            gather, reduce = 2 * share(param_bytes), share(grad_bytes)
+        elif stage >= 1:
+            # gradient shards in, the updated float32 parameters out
+            param_bytes = count * 4
+            gather, reduce = share(param_bytes), share(grad_bytes)
+        else:
+            param_bytes = 0
+            gather, reduce = 0, 2 * share(grad_bytes)
+        return {"chips": n, "zero_stage": stage, "param_bytes": param_bytes,
+                "grad_bytes": grad_bytes, "gather_bytes": gather,
+                "reduce_bytes": reduce, "plan_bytes": gather + reduce}
+
+    def zero_plan(self) -> Dict[str, int]:
+        """What the configured ZeRO stage needs to move, a chip a step, over
+        the data-parallel dimension of size n (``chips``), from shapes and
+        shardings alone (docs/observability.md "What a stage moves"): stage
+        0 the gradients all-reduced, ``2(n-1)/n x G``; stages 1 and 2 the
+        gradients reduce-scattered, ``(n-1)/n x G``, and the updated
+        parameters gathered, ``(n-1)/n x P``; stage 3 the compute copy
+        gathered for the forward and once more for the backward,
+        ``2(n-1)/n x P_compute``, and the gradients reduce-scattered,
+        ``(n-1)/n x G``. ``G`` (``grad_bytes``) is the gradient tree in
+        float32, the dtype it is constrained onto ``grad_shardings`` in;
+        ``param_bytes`` is ``P`` in float32 or, at stage 3, the leaves the
+        stage stores sharded in ``compute_dtype``; both count a chip's part
+        of a leaf that another mesh axis cuts (1/tp of it under a ``model``
+        axis of tp), as the compiled step's shapes do. ``plan_bytes`` =
+        ``gather_bytes`` + ``reduce_bytes``. All ints; 0 on one chip."""
+        return dict(self._zero_plan)
+
+    def _make_step_attrs(self) -> Dict[str, int]:
+        """``train.step``'s static attributes: the plan's bytes, and with a
+        catalogue what the compiled step sends a chip a step by the same
+        count (``Collective.sent_bytes``, not the comm ledger's
+        ``wire_bytes``, which is a payload)."""
+        attrs = {"plan_bytes": self._zero_plan["plan_bytes"]}
+        if self._collectives:
+            attrs["sent_bytes"] = sum(
+                t["sent_bytes"] for t in coll.totals(self._collectives).values())
+        return attrs
+
+    def step_collectives(self) -> List[Any]:
+        """The collectives of the compiled train step, one
+        :class:`profiling.collectives.Collective` an instruction (kind,
+        the name a device trace shows, bytes, replica group, asynchronous,
+        in a loop, runs a step, ``op_name``), read once from the AOT
+        program's optimized HLO at :meth:`warmup`. Empty without an AOT
+        program (the lazy ``jit`` path, an offload engine) and on one
+        chip. No profiler needed::
+
+            for c in engine.step_collectives():
+                print(c.kind, c.name, c.bytes, c.runs, c.op_name)
+        """
+        return list(self._collectives)
+
+    def _note_step_collectives(self) -> None:
+        """Catalogue the AOT step's collectives, say them on one line and
+        put their totals on ``train.step``. Off the step's path: called by
+        :meth:`warmup` once the program is compiled."""
+        t0 = time.perf_counter()
+        try:
+            found = tuple(coll.catalogue(self._train_step_aot.as_text()))
+        except Exception as e:  # noqa: BLE001 — bookkeeping never kills warmup
+            logger.warning(f"train step collectives not catalogued: {e}")
+            found = ()
+        self._collectives = found  # dslint: disable=races -- warmup-join synchronization: written on the warmup thread before _ensure_train_step_fn's join; a whole tuple swapped in, never mutated
+        self._step_attrs = self._make_step_attrs()  # dslint: disable=races -- same: one dict swapped in whole; train_batch reads either the plan's or the catalogue's
+        if self._zero_plan["chips"] > 1:
+            log_dist(coll.describe(list(found), self._zero_plan)
+                     + f" (read in {time.perf_counter() - t0:.2f} s)")
+
+    def _forget_aot(self) -> None:
+        """Drop the AOT program and what was read off it: the step that
+        runs from here on is another program."""
+        self._train_step_aot = None
+        self._collectives = ()
+        self._step_attrs = self._make_step_attrs()
+        if self._comm_booked is not None:
+            # the next step books its own; the dropped program's one-time
+            # records stay in the CommsLogger as history, not as a delta
+            from ..comm.comm import get_comms_logger
+
+            for op, entry in self._comm_entries(get_comms_logger())[0].items():
+                prev = self._comm_totals_prev.setdefault(op, {})
+                for k, v in entry.items():
+                    prev[k] = prev.get(k, 0.0) + v
+            self._comm_booked = None
+
     @jax.named_scope("optimizer")   # names clip + update in a device trace
     def _update(self, params, opt_state, scaler_state, grads, scale, *,
                 clip, fp16, dynamic, optimizer, nan_skip=False):
@@ -947,33 +1075,37 @@ class TrainEngine:
         tree keeps the old params/opt state ON DEVICE — the NaN guard
         compiles into the step and costs zero extra host syncs."""
         cfg = self.config
-        if fp16:
-            grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
-            finite = ls.grads_finite(grads)
-        elif nan_skip:
-            finite = ls.grads_finite(grads)
-        else:
-            finite = jnp.asarray(True)
-        gnorm = global_norm(grads)
-        if clip > 0:
-            factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-            grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
-        # overflow / injected NaN => keep old params/opt state
-        # (reference: skipped step)
-        if fp16 or nan_skip:
-            new_params = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(finite, n, o), new_params, params)
-            new_opt = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(finite, n, o) if hasattr(n, "dtype") else n,
-                new_opt, opt_state)
-        new_scaler = ls.update(
-            scaler_state, finite, dynamic=dynamic,
-            scale_window=cfg.fp16.loss_scale_window,
-            min_scale=cfg.fp16.min_loss_scale,
-            consecutive_hysteresis=cfg.fp16.consecutive_hysteresis,
-            init_hysteresis=cfg.fp16.hysteresis)
+        # two names inside ``optimizer`` (docs/observability.md): what a
+        # trace books under the whole scope is then told apart
+        with jax.named_scope("norm"):
+            if fp16:
+                grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
+                finite = ls.grads_finite(grads)
+            elif nan_skip:
+                finite = ls.grads_finite(grads)
+            else:
+                finite = jnp.asarray(True)
+            gnorm = global_norm(grads)
+            if clip > 0:
+                factor = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
+        with jax.named_scope("update"):
+            updates, new_opt = optimizer.update(grads, opt_state, params)
+            new_params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+            # overflow / injected NaN => keep old params/opt state
+            # (reference: skipped step)
+            if fp16 or nan_skip:
+                new_params = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(finite, n, o), new_params, params)
+                new_opt = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(finite, n, o) if hasattr(n, "dtype") else n,
+                    new_opt, opt_state)
+            new_scaler = ls.update(
+                scaler_state, finite, dynamic=dynamic,
+                scale_window=cfg.fp16.loss_scale_window,
+                min_scale=cfg.fp16.min_loss_scale,
+                consecutive_hysteresis=cfg.fp16.consecutive_hysteresis,
+                init_hysteresis=cfg.fp16.hysteresis)
         new_params = jax.lax.with_sharding_constraint(new_params, self.param_shardings)
         skipped = jnp.logical_not(finite)
         return new_params, new_opt, new_scaler, gnorm, skipped
@@ -984,7 +1116,8 @@ class TrainEngine:
         """One full optimizer step over a global batch of
         ``train_batch_size`` samples (parity with PipelineEngine.train_batch
         semantics for the non-pipelined engine)."""
-        with annotate("train.step", step=self.global_steps, k=1):
+        with annotate("train.step", step=self.global_steps, k=1,
+                      **self._step_attrs):
             return self._train_batch(batch)
 
     def _train_batch(self, batch: Any) -> Dict[str, Any]:
@@ -1024,7 +1157,7 @@ class TrainEngine:
                 except Exception as e:  # noqa: BLE001 — aval check precedes execution
                     logger.warning(f"AOT train step no longer matches the inputs "
                                    f"({e}); using the jit path")
-                    self._train_step_aot = None
+                    self._forget_aot()
             if out is None:
                 out = fn(self.params, self.opt_state, self.scaler_state, self.rng,
                          batch)
@@ -1157,7 +1290,8 @@ class TrainEngine:
                 out["losses"] = jnp.stack([jnp.asarray(metrics["loss"])])
                 return out
 
-        with annotate("train.step", step=self.global_steps, k=k):
+        with annotate("train.step", step=self.global_steps, k=k,
+                      **self._step_attrs):
             return self._train_steps_fused(batches, k)
 
     def _train_steps_fused(self, batches: List[Any], k: int
@@ -1478,7 +1612,7 @@ class TrainEngine:
         self._train_step_fn = None
         self._train_step_raw = None
         self._train_steps_fns = {}
-        self._train_step_aot = None
+        self._forget_aot()
         self._micro_grad_fn = None
         self._acc_add_fn = None
         self._eval_step_fn = None
@@ -1762,46 +1896,123 @@ class TrainEngine:
         else:
             self._tokens_per_batch = self.config.train_batch_size
 
-    def _grad_reduce_comm(self):
-        """(op, entry) for this step's gradient-reduction traffic. GSPMD
-        inserts the collective inside the compiled step where the facade's
-        wrappers cannot see it, but the op and payload are determined by
-        the grad shardings: replicated grads (stage 0) reduce with an
-        all-reduce of the full fp32 tree; sharded grads (stage >= 1) with
-        a reduce-scatter. Recorded with the CommsLogger ONCE (so
-        measure_comm_latencies can replay it and log_summary shows one
-        row, not one per step) and merged into every step's breakdown
-        here; time_s comes from the backfilled record when available."""
+    #: a catalogue's kinds under the comm facade's op names
+    _COMM_OPS = {"all-gather": "all_gather", "reduce-scatter": "reduce_scatter",
+                 "all-reduce": "all_reduce", "all-to-all": "all_to_all",
+                 "collective-permute": "ppermute",
+                 "collective-broadcast": "broadcast"}
+
+    def _axis_of(self, members: Tuple[int, ...]) -> Optional[str]:
+        """The one mesh axis a replica group runs along, None where it
+        spans several (then no one axis replays it): a partition's id is
+        its place in the mesh's devices, in order."""
+        mesh = self.topo.mesh
+        if not members or max(members) >= mesh.devices.size:
+            return None     # not this mesh's partitions: nothing to replay
+        at = np.unravel_index(list(members), mesh.devices.shape)
+        along = [name for name, row in zip(mesh.axis_names, at)
+                 if len(set(row.tolist())) > 1]
+        return along[0] if len(along) == 1 else None
+
+    def _book_step_comm(self, log) -> None:
+        """Fold the compiled step's traffic once into ``_comm_booked``,
+        {op: {payload bytes: runs a step}}, and ``_comm_totals``, {op:
+        (calls, payload bytes) a step}; each (op, payload) is recorded with
+        the CommsLogger ONCE, so that measure_comm_latencies can replay it
+        (along its group's axis, where that is one axis) and log_summary
+        shows one row a size; the exported comm/<op> counters get the whole
+        first step."""
+        from ..telemetry.registry import get_registry
+
+        booked: Dict[str, Dict[int, int]] = {}
+        where: Dict[Tuple[str, int], Tuple[int, Optional[str]]] = {}
+        if self._collectives:
+            for c in self._collectives:
+                if c.bytes:
+                    op = self._COMM_OPS[c.kind]
+                    sizes = booked.setdefault(op, {})
+                    sizes[c.bytes] = sizes.get(c.bytes, 0) + c.runs
+                    where[op, c.bytes] = (c.group, self._axis_of(c.members))
+        else:
+            op = "reduce_scatter" if self.config.zero.stage >= 1 else "all_reduce"
+            size = self._zero_plan["grad_bytes"]
+            booked[op] = {size: 1}
+            where[op, size] = (self.topo.data_parallel_size, "data")
+        reg = get_registry()
+        totals = {}
+        for op, sizes in booked.items():
+            for size in sizes:
+                log.append(op, size, 0.0, *where[op, size])
+            calls = sum(sizes.values())
+            total = sum(b * n for b, n in sizes.items())
+            reg.counter(f"comm/{op}/calls").inc(calls - len(sizes))
+            reg.counter(f"comm/{op}/bytes").inc(total - sum(sizes))
+            reg.counter(f"comm/{op}/wire_bytes").inc(total - sum(sizes))
+            totals[op] = (calls, total)
+        self._comm_booked, self._comm_totals = booked, totals
+
+    def _step_comm(self) -> Tuple[Dict[str, Dict[str, float]],
+                                  Dict[str, Dict[str, float]]]:
+        """({op: what the CommsLogger holds of it from the one-time
+        records}, {op: this step's entry}) for the traffic GSPMD places
+        inside the compiled step, where the facade's wrappers cannot see
+        it. With a catalogue of the compiled step
+        (:meth:`step_collectives`) it is what the program holds: every
+        kind, parameter gathers too, each run with its payload (``bytes``
+        and ``wire_bytes`` both: the ledger's wire is what is left of a
+        payload after compression, and nothing here is compressed; what a
+        chip sends by the ring's count is ``train.step``'s ``sent_bytes``).
+        Without one (the lazy jit path) the gradients' reduction is guessed
+        from the grad shardings: replicated grads (stage 0) reduce with an
+        all-reduce of the float32 tree (``zero_plan``'s ``grad_bytes``);
+        sharded grads (stage >= 1) with a reduce-scatter. What the first
+        step booked stands until the step is another program
+        (:meth:`_forget_aot`); time_s comes from the backfilled records
+        when available."""
         dp = self.topo.data_parallel_size
-        if dp <= 1 or not self._grad_bytes:
-            return None
+        if dp <= 1 or not self._zero_plan["grad_bytes"]:
+            return {}, {}
         if self._qgz or self._staged_mode is not None:
             # the facade paths record their own (quantized, wire-accurate)
-            # ledger entries at trace time — a synthetic dense booking on
-            # top would double-count traffic that never happens
-            return None
+            # ledger entries at trace time — a dense booking on top would
+            # double-count traffic that never happens
+            return {}, {}
         from ..comm.comm import get_comms_logger
 
         log = get_comms_logger()
         if not log.enabled:
-            return None
-        op = "reduce_scatter" if self.config.zero.stage >= 1 else "all_reduce"
-        if not self._grad_comm_noted:
-            log.append(op, self._grad_bytes, 0.0, dp, "data")
-            self._grad_comm_noted = True
+            return {}, {}
+        if self._comm_booked is None:
+            self._book_step_comm(log)
         else:
-            # append() fed the registry once at the one-time record; keep
+            # the one-time records fed the registry the first step; keep
             # the exported comm/<op> counters tracking the per-step traffic
             from ..telemetry.registry import get_registry
 
             reg = get_registry()
-            reg.counter(f"comm/{op}/calls").inc()
-            reg.counter(f"comm/{op}/bytes").inc(self._grad_bytes)
-            reg.counter(f"comm/{op}/wire_bytes").inc(self._grad_bytes)
-        durs = log.records.get(op, {}).get(self._grad_bytes, [])
-        t = durs[0] if durs and durs[0] > 0 else 0.0
-        return op, {"count": 1.0, "bytes": float(self._grad_bytes),
-                    "wire_bytes": float(self._grad_bytes), "time_s": t}
+            for op, (calls, total) in self._comm_totals.items():
+                reg.counter(f"comm/{op}/calls").inc(calls)
+                reg.counter(f"comm/{op}/bytes").inc(total)
+                reg.counter(f"comm/{op}/wire_bytes").inc(total)
+        return self._comm_entries(log)
+
+    def _comm_entries(self, log):
+        """:meth:`_step_comm`'s two dicts of what is booked, as the
+        CommsLogger's records read now."""
+        once: Dict[str, Dict[str, float]] = {}
+        step: Dict[str, Dict[str, float]] = {}
+        for op, sizes in self._comm_booked.items():
+            records = log.records.get(op, {})
+            took = {size: max((records.get(size) or [0.0])[0], 0.0)
+                    for size in sizes}
+            calls, total = self._comm_totals[op]
+            once[op] = {"count": float(len(sizes)), "bytes": float(sum(sizes)),
+                        "wire_bytes": float(sum(sizes)),
+                        "time_s": sum(took.values())}
+            step[op] = {"count": float(calls), "bytes": float(total),
+                        "wire_bytes": float(total),
+                        "time_s": sum(took[b] * n for b, n in sizes.items())}
+        return once, step
 
     def _comm_step_delta(self):
         """Per-step comm breakdown: delta of the CommsLogger's cumulative
@@ -1809,19 +2020,20 @@ class TrainEngine:
         facts; time_s becomes real once measure_comm_latencies backfills."""
         from ..comm.comm import get_comms_logger
 
-        # the engine's implied gradient reduction happens EVERY step, but
-        # its CommsLogger record is a one-time synthetic append (so
-        # measure_comm_latencies can replay it). Subtract that record from
-        # the cumulative stream — including its possibly-backfilled
-        # duration — and re-inject the entry per step below; otherwise the
-        # step after a backfill would count the measured latency twice
-        # (once via the snapshot jump, once via the merge).
-        grad = self._grad_reduce_comm()
+        # the step's own collectives run EVERY step, but their CommsLogger
+        # records are one-time appends (so measure_comm_latencies can
+        # replay them). Subtract those records from the cumulative stream
+        # — including their possibly-backfilled durations — and re-inject
+        # the entries per step below; otherwise the step after a backfill
+        # would count the measured latency twice (once via the snapshot
+        # jump, once via the merge).
+        once, implied = self._step_comm()
         totals = get_comms_logger().snapshot_totals()
-        if grad is not None and grad[0] in totals:
-            cur = totals[grad[0]]
-            for k in ("count", "bytes", "wire_bytes", "time_s"):
-                cur[k] = max(0.0, cur.get(k, 0.0) - grad[1].get(k, 0.0))
+        for op, entry in once.items():
+            if op in totals:
+                cur = totals[op]
+                for k in ("count", "bytes", "wire_bytes", "time_s"):
+                    cur[k] = max(0.0, cur.get(k, 0.0) - entry.get(k, 0.0))
         delta: Dict[str, Dict[str, float]] = {}
         comm_s = 0.0
         for op, cur in totals.items():
@@ -1836,8 +2048,7 @@ class TrainEngine:
                 delta[op] = d
                 comm_s += d["time_s"]
         self._comm_totals_prev = totals
-        if grad is not None:
-            op, entry = grad
+        for op, entry in implied.items():
             if op in delta:
                 for k in entry:
                     delta[op][k] += entry[k]

@@ -56,8 +56,17 @@ ATTRS = {
     "ragged.h2d": {"arrays", "bytes"}, "ragged.call": {"leaves"},
     "serve.tick": {"tick", "queued", "live"},
     "serve.admit": {"admitted", "preempted"}, "serve.put": {"retries"},
-    "serve.emit": {"tokens"}, "train.step": {"step", "k"},
+    "serve.emit": {"tokens"},
+    # the step's number, and what the stage plans to move (PR 55)
+    "train.step": {"step", "k", "plan_bytes"},
 }
+#: what ``train.step`` carries besides once the AOT step's collectives are
+#: catalogued (``TrainEngine.warmup`` over more than one chip)
+CATALOGUED = {"sent_bytes"}
+#: the update's parts inside ``optimizer``, found by path as
+#: ``readers/named_scope_device.py`` finds one (``zero_cast`` names nothing
+#: in this float32 trainer's step: tests/test_zero_step_trace.py has it)
+TRAIN_PATHS = (("optimizer", "norm"), ("optimizer", "update"))
 VOCAB, MAX_SEQS, BLOCK, N_BLOCKS = 128, 4, 8, 64
 PROMPTS = [40, 10, 5]          # 40 > the 32-token budget: a chunked prefill
 NEW_TOKENS = 4
@@ -384,6 +393,54 @@ def test_device_scopes_are_in_the_program(runs, program, scopes):
             assert any(program_trace.scope_of(n)[2] for n in mine), scope
     assert not any(program_trace.scope_of(n)[2] for n in names
                    if program_trace.scope_of(n)[0] == "optimizer")
+
+
+@pytest.mark.parametrize("path", TRAIN_PATHS, ids="/".join)
+def test_train_step_paths_are_in_the_program(runs, path):
+    """The update's parts name operations of the compiled train step and
+    count under ``optimizer`` as before."""
+    from benchmarks.readers.named_scope_device import under
+
+    names = set(re.findall(r'op_name="([^"]+)"', runs["hlo"]["train"]))
+    mine = [n for n in names if under(n, path)]
+    assert mine, f"no operation under {'/'.join(path)!r}"
+    assert {program_trace.scope_of(n)[0] for n in mine} == {"optimizer"}
+
+
+def test_train_step_of_a_catalogued_step_carries_its_totals(monkeypatch):
+    """Over four chips with an AOT step, ``train.step`` carries exactly the
+    table's attributes and the catalogue's one, plain ints; without the
+    AOT program (``runs``' trainer) exactly the table's."""
+    from deepspeed_tpu.runtime.dataloader import shard_batch
+
+    mesh_mod.reset_topology()
+    model = Llama("tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+                  vocab_size=VOCAB, max_seq_len=64, use_flash=False,
+                  remat=False)
+    trainer, _, _, _ = dst.initialize(
+        model=model, params=model.init(jax.random.PRNGKey(6)),
+        topology=mesh_mod.Topology.build_virtual({"data": 4}),
+        config={"train_batch_size": 4, "steps_per_print": 1_000_000,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 3}})
+    batch = shard_batch({"input_ids": jnp.asarray(
+        np.random.default_rng(0).integers(1, VOCAB, (4, 32)), jnp.int32)},
+        trainer.topo)
+    seen = []
+    monkeypatch.setattr(
+        engine_mod, "annotate",
+        lambda name, **attrs: seen.append((name, attrs))
+        or seam._NoAnnotation())
+    try:
+        assert trainer.warmup(batch)
+        trainer.train_batch(batch)
+    finally:
+        trainer.close()
+        mesh_mod.reset_topology()
+    attrs = dict(seen)["train.step"]
+    assert set(attrs) == ATTRS["train.step"] | CATALOGUED
+    assert all(type(v) is int for v in attrs.values())
+    assert attrs["sent_bytes"] > 0 and attrs["plan_bytes"] > 0
 
 
 def test_without_a_session_the_seam_changes_nothing(runs):
