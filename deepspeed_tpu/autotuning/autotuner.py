@@ -179,7 +179,7 @@ class Autotuner:
             return (jax.lax.with_sharding_constraint(params, param_sh),
                     mu, nu)
 
-        opt_sh = rules.opt_state_shardings(p32)
+        opt_sh = rules.opt_state_shardings(p32, p32, tp_specs)
         lowered = jax.jit(
             step,
             in_shardings=(param_sh, opt_sh, opt_sh, batch_sh, None),

@@ -218,20 +218,52 @@ class ZeroShardingRules:
         return jax.tree_util.tree_map(lambda sp: NamedSharding(mesh, sp), specs,
                                       is_leaf=lambda x: isinstance(x, PartitionSpec))
 
-    def opt_state_shardings(self, opt_state_shapes: Any) -> Any:
+    def opt_state_shardings(self, opt_state_shapes: Any,
+                            param_shapes: Any = None,
+                            tp_specs: Optional[Any] = None) -> Any:
         """Sharding pytree for an optax-style optimizer state.
 
-        Any leaf whose shape can host the ZeRO axes gets sharded (master
-        weights, Adam moments — the big consumers the reference partitions in
-        stage_1_and_2.py:97); scalars (step counts, loss scale) replicate.
+        A moment lies as its gradient does. Every subtree of the state
+        that is shaped like the parameters (the parameters' tree structure
+        and leaf shapes: ``AdamState.mu`` / ``.nu``, a momentum, an
+        accumulator, an ``optax`` state's likewise — found by structure,
+        not by a class's name) takes, leaf by leaf, ``state_spec(shape,
+        tp_spec)``: the rule ``grad_shardings`` applies from stage 2 up, and
+        the master's own wherever the master is partitioned (``param_spec``
+        differs only under ``stage3_param_persistence_threshold``). The
+        elementwise update then reads ``g``, ``mu``, ``nu`` and the master
+        in one layout and holds no collective of its own. A model's spec
+        occupies its dimension even where its axis has size 1, so a rule
+        that sees shapes alone cuts another dimension than the gradient's,
+        and the update pays a float32 all-to-all a disagreeing leaf a
+        moment (docs/communication.md "Where a moment lies").
+
+        Every other leaf keeps the shape-only rule: any leaf whose shape
+        can host the ZeRO axes is sharded (the big consumers the reference
+        partitions in stage_1_and_2.py:97), scalars (step counts, loss
+        scale) replicate. Without ``tp_specs`` that is the rule for every
+        leaf: there is nothing to disagree with.
         """
         mesh = self.topo.mesh
+        shape_of = lambda x: tuple(getattr(x, "shape", ()))
 
-        def leaf(s):
-            shape = tuple(getattr(s, "shape", ()))
-            return NamedSharding(mesh, self.state_spec(shape, None))
+        def place(leaf, base_spec=None):
+            return NamedSharding(mesh, self.state_spec(shape_of(leaf), base_spec))
 
-        return jax.tree_util.tree_map(leaf, opt_state_shapes)
+        if tp_specs is None or param_shapes is None:
+            return jax.tree_util.tree_map(place, opt_state_shapes)
+        treedef = jax.tree_util.tree_structure(param_shapes)
+        shapes = [shape_of(p) for p in jax.tree_util.tree_leaves(param_shapes)]
+
+        def like_params(node):
+            return (jax.tree_util.tree_structure(node) == treedef
+                    and [shape_of(x) for x in jax.tree_util.tree_leaves(node)]
+                    == shapes)
+
+        return jax.tree_util.tree_map(
+            lambda node: (jax.tree_util.tree_map(place, node, tp_specs)
+                          if like_params(node) else place(node)),
+            opt_state_shapes, is_leaf=like_params)
 
 
 def compute_param_bytes(param_shapes: Any) -> int:

@@ -300,7 +300,8 @@ class TrainEngine:
                                              lr_schedule=self.lr_schedule)
 
         opt_shape = jax.eval_shape(self.optimizer.init, params)
-        self.opt_state_shardings = self.zero_rules.opt_state_shardings(opt_shape)
+        self.opt_state_shardings = self.zero_rules.opt_state_shardings(
+            opt_shape, param_shapes, tp_specs)
 
         # -- optimizer-state offload (ZeRO-Offload / Infinity parity:
         # reference runtime/zero/offload_config.py + swap_tensor stack).

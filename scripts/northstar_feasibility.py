@@ -108,7 +108,7 @@ def _run_child():
             lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), param_struct)
         param_sh = rules.param_shardings(p32, tp_specs)
         grad_sh = rules.grad_shardings(p32, tp_specs)
-        opt_sh = rules.opt_state_shardings(p32)
+        opt_sh = rules.opt_state_shardings(p32, p32, tp_specs)
         batch_struct = {"input_ids": jax.ShapeDtypeStruct((mb * n, seq),
                                                           jnp.int32)}
         batch_sh = {"input_ids": topo.batch_sharding(2)}

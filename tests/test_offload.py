@@ -71,6 +71,45 @@ def test_cpu_offload_same_trajectory_as_device():
     np.testing.assert_allclose(l1, l2, rtol=1e-5)
 
 
+def test_cpu_offload_round_trips_a_step_in_the_gradients_layout():
+    """The host shardings are the device ones leaf by leaf, so under ZeRO-3
+    a parked moment lies as its gradient does too: up to the device for a
+    step and back, the layout holds and the trajectory is the
+    device-resident engine's."""
+    zero = lambda offload: {"zero_optimization": {
+        "stage": 3, "stage3_param_persistence_threshold": 0,
+        "offload_optimizer": offload}}
+    specs = lambda tree: [s.spec for s in jax.tree_util.tree_leaves(tree)]
+    mesh_mod.reset_topology()
+    plain, _, _, _ = dst.initialize(
+        model=_model(), config=_config({}, **zero({"device": "none"})),
+        rng=jax.random.PRNGKey(3))
+    want = _run(plain, steps=3)
+    mesh_mod.reset_topology()
+    engine, _, _, _ = dst.initialize(
+        model=_model(), config=_config({}, **zero({"device": "cpu"})),
+        rng=jax.random.PRNGKey(3))
+    assert engine._offload_device == "cpu"
+    grads = specs(engine.grad_shardings)
+    assert specs(engine.opt_state_shardings.mu) == grads \
+        == specs(engine._opt_host_shardings.nu) == specs(plain.grad_shardings)
+    # the rule that saw shapes alone cut other dimensions of some leaves
+    assert grads != [engine.zero_rules.state_spec(tuple(x.shape), None)
+                     for x in jax.tree_util.tree_leaves(engine.params)]
+
+    def parked():
+        moments = jax.tree_util.tree_leaves(
+            (engine.opt_state.mu, engine.opt_state.nu))
+        assert {x.sharding.memory_kind for x in moments} \
+            == {host_memory_kind()}
+        return [x.sharding.spec for x in moments]
+
+    assert parked() == grads + grads
+    got = _run(engine, steps=3)
+    assert parked() == grads + grads
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
 def test_nvme_offload_trains(tmp_path):
     engine, _, _, _ = dst.initialize(
         model=_model(),

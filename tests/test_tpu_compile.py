@@ -466,7 +466,7 @@ def compile_train_step(devices, n_layers: int, batch: int, zero_stage: int):
     g_sh = rules.grad_shardings(shapes, tp_specs)
     opt = build_optimizer(cfg.optimizer.type, cfg.optimizer.params)
     o_shapes = jax.eval_shape(opt.init, shapes)
-    o_sh = rules.opt_state_shardings(o_shapes)
+    o_sh = rules.opt_state_shardings(o_shapes, shapes, tp_specs)
     repl = topo.replicated()
 
     def train_step(params, opt_state, rng, batch):
@@ -476,12 +476,13 @@ def compile_train_step(devices, n_layers: int, batch: int, zero_stage: int):
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         grads = jax.lax.with_sharding_constraint(grads, g_sh)
-        gnorm = global_norm(grads)
-        factor = jnp.minimum(1.0, cfg.gradient_clipping / (gnorm + 1e-6))
-        grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
-        updates, new_opt = opt.update(grads, opt_state, params)
-        new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
-                                            updates)
+        with jax.named_scope("optimizer"):      # the engine's name for it
+            gnorm = global_norm(grads)
+            factor = jnp.minimum(1.0, cfg.gradient_clipping / (gnorm + 1e-6))
+            grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
+            updates, new_opt = opt.update(grads, opt_state, params)
+            new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                                updates)
         return new_params, new_opt, loss
 
     tokens = _sds((batch, SZ.train_seq), jnp.int32, topo.batch_sharding(2))
@@ -501,13 +502,22 @@ def test_train_step_compiles_and_fits_one_chip(topo, on_tpu):
     assert _device_bytes(c) < HBM_BYTES, _device_bytes(c)
 
 
-def test_zero3_step_on_four_chips_compiles(topo, on_tpu):
+@pytest.fixture(scope="module")
+def zero3_on_four(topo):
+    """``chip_smoke.py --chips 4``'s step, compiled once for the two tests
+    that read it."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr("deepspeed_tpu.ops.attention._on_tpu", lambda: True)
+        return compile_train_step(topo.devices, SZ.zero3_layers,
+                                  SZ.zero3_batch, zero_stage=3)
+
+
+def test_zero3_step_on_four_chips_compiles(topo, on_tpu, zero3_on_four):
     """``chip_smoke.py --chips 4``'s program: ZeRO-3 over data=4 is GSPMD
     placement — gathers for the weights, reduce-scatters for the
     gradients — with the flash kernel inside the model's shard_map, and a
     quarter of the one-chip step's state on each device."""
-    four = compile_train_step(topo.devices, SZ.zero3_layers, SZ.zero3_batch,
-                              zero_stage=3)
+    four = zero3_on_four
     hlo = four.as_text()
     assert "all-gather" in hlo and "reduce-scatter" in hlo
     assert hlo.count("tpu_custom_call") >= 3
@@ -516,6 +526,43 @@ def test_zero3_step_on_four_chips_compiles(topo, on_tpu):
     assert _device_bytes(one) < HBM_BYTES, _device_bytes(one)
     state = lambda c: c.memory_analysis().argument_size_in_bytes
     assert state(four) < 0.30 * state(one), (state(four), state(one))
+
+
+def test_zero3_update_sends_nothing_and_the_step_stays_under_its_plan(
+        zero3_on_four):
+    """The chip's own partitioner on the four-chip step: with the moments
+    where their gradients lie (``opt_state_shardings`` takes the model's
+    specs) no collective but the norm's scalar all-reduce is under
+    ``optimizer``, and what a chip sends a step is under the stage's plan
+    (the compute copy gathered twice in bfloat16, the gradients
+    reduce-scattered once, counted in float32 as ``TrainEngine.zero_plan``
+    counts them). With the moments cut by their shapes alone, PR 55's
+    rule, this step holds 26 ``all-to-all``, 24 of them float32 stacks of
+    16.8 to 131 MB under ``optimizer``, and sends 2.80 GB a chip of which
+    1.39 are all-to-alls (at this depth half the parameters are the
+    embedding and the head, which the forward gathers once or not at all,
+    so even that is under this plan of 2.88; at the benchmark's eight
+    layers it is not: 13.76 against 12.04 on the chip, PERF.md section 6,
+    PR 55). What stays is the embedding's lookup and its gradient's
+    scatter-add, an ``all-to-all`` of 16.8 MB each: 1.44 GB sent."""
+    from benchmarks.readers.named_scope_device import under
+    from deepspeed_tpu.profiling import collectives as coll
+
+    found = coll.catalogue(zero3_on_four.as_text())
+    ours = [c for c in found if under(c.op_name, ["optimizer"])]
+    assert [c.kind for c in ours] == ["all-reduce"], ours
+    assert ours[0].bytes <= 64
+    assert sum(c.kind == "all-to-all" for c in found) == 2
+    count = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(
+        jax.eval_shape(chip_smoke.smoke_model(SZ, SZ.zero3_layers).init,
+                       jax.random.PRNGKey(0))))
+    share = lambda b: b * 3 // 4                # the ring's (n - 1) / n
+    plan = 2 * share(count * 2) + share(count * 4)
+    sent = sum(c.sent_bytes * c.runs for c in found)
+    assert 0.4 * plan < sent < 0.6 * plan, (sent, plan)
+    exchanged = sum(c.sent_bytes * c.runs for c in found
+                    if c.kind == "all-to-all")
+    assert exchanged < 0.02 * sent, (exchanged, sent)
 
 
 def compile_ragged_step(device_sharding, n_layers: int, T: int,

@@ -55,6 +55,46 @@ def test_resume_across_mesh_and_stage(tmp_path):
     assert np.isfinite(l5)
 
 
+def test_state_saved_in_the_shape_only_layout_resumes_under_the_new_rule(
+        tmp_path):
+    """A checkpoint written while the moments lay as ``opt_state_shardings``
+    placed them before it took the model's specs (each leaf cut by its shape
+    alone: the layout built by hand here) loads onto the layout the rule
+    gives now, and the steps after it are the uninterrupted run's to the
+    bit."""
+    from jax.sharding import NamedSharding
+
+    e1 = _engine({"data": 8}, stage=3)
+    for i in range(2):
+        e1.train_batch(shard_batch(_batch(i), e1.topo))
+    new = e1.opt_state_shardings
+    old = jax.tree_util.tree_map(
+        lambda s, x: NamedSharding(s.mesh, e1.zero_rules.state_spec(
+            tuple(x.shape), None)), new, e1.opt_state)
+    specs = lambda tree: [s.spec for s in jax.tree_util.tree_leaves(tree)]
+    moved = sum(a != b for a, b in zip(specs(old), specs(new)))
+    assert moved >= 8, (specs(old), specs(new))   # four leaves, two moments
+    assert specs(new.mu) == specs(e1.grad_shardings) == specs(new.nu)
+    e1.opt_state = jax.device_put(e1.opt_state, old)
+    assert [x.sharding.spec for x in jax.tree_util.tree_leaves(e1.opt_state)] \
+        == specs(old)
+    e1.save_checkpoint(str(tmp_path), tag="old")
+    e1.opt_state = jax.device_put(e1.opt_state, new)
+    want = [float(e1.train_batch(shard_batch(_batch(i), e1.topo))["loss"])
+            for i in (2, 3)]
+
+    reset_topology()
+    e2 = _engine({"data": 8}, stage=3)
+    e2.load_checkpoint(str(tmp_path), tag="old")
+    assert e2.global_steps == 2
+    for leaf, sh in zip(jax.tree_util.tree_leaves(e2.opt_state),
+                        jax.tree_util.tree_leaves(e2.opt_state_shardings)):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+    got = [float(e2.train_batch(shard_batch(_batch(i), e2.topo))["loss"])
+           for i in (2, 3)]
+    assert got == want
+
+
 def test_universal_cli_roundtrip(tmp_path):
     # the offline CLI surface on top of the conversion + cross-mesh load
     # machinery that test_resume_across_mesh_and_stage pins

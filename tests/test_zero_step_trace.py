@@ -176,6 +176,46 @@ def test_catalogue_lists_what_the_compiled_step_holds(engines, stage):
     assert attrs["sent_bytes"] >= 0.5 * attrs["plan_bytes"] > 0
 
 
+@pytest.mark.parametrize("stage", [1, 2, 3])
+def test_the_update_holds_no_collective_of_its_own(engines, stage):
+    """A moment lies as its gradient does (``opt_state_shardings`` takes the
+    model's specs), so the compiled step holds no ``all-to-all`` whose scope
+    is under ``optimizer``. At stage 3 ``optimizer/update`` holds no
+    collective at all and what the optimizer sends is the norm's scalars;
+    at stages 1 and 2 it holds the stage's own gather of the updated
+    parameters, one a leaf, and nothing else. On PR 55's tree the
+    same assertion fails: with the moments cut by their shapes alone this
+    step held 24 float32 ``all-to-all`` under ``optimizer`` at stage 3 and
+    12 at stage 2 (on the chip, at Mistral-7B's widths, 24 ran of the 26 in
+    the program: PERF.md section 6, PR 55 and PR 57).
+
+    ``sent_bytes`` against the plan is held where the program is the
+    chip's: ``tests/test_tpu_compile.py::
+    test_zero3_update_sends_nothing_and_the_step_stays_under_its_plan``.
+    The CPU's partitioner all-reduces activations where the TPU's gathers
+    weights, so this step sends several times its plan whatever the
+    optimizer state does."""
+    engine, _ = engines[stage]
+    found = engine.step_collectives()
+    ours = [c for c in found if under(c.op_name, ["optimizer"])]
+    assert [c for c in ours if c.kind == "all-to-all"] == []
+    update = [c for c in found if under(c.op_name, ["optimizer", "update"])]
+    if stage == 3:
+        assert update == []
+        assert ours and sum(c.sent_bytes * c.runs for c in ours) < 1024
+    else:
+        leaves = len(jax.tree_util.tree_leaves(engine.params))
+        assert [c.kind for c in update] == ["all-gather"] * leaves
+        assert sum(c.bytes for c in update) == COUNT * 4
+    # g, mu, nu and, at stage 3 with a threshold of 0, the master: one spec
+    specs = lambda tree: [s.spec for s in jax.tree_util.tree_leaves(tree)]
+    # at stage 1 the gradients arrive whole; the moments are cut as at 2
+    grads = specs(engines[max(stage, 2)][0].grad_shardings)
+    for tree in (engine.opt_state_shardings.mu, engine.opt_state_shardings.nu,
+                 *([engine.param_shardings] if stage == 3 else [])):
+        assert specs(tree) == grads
+
+
 def test_one_chip_has_an_empty_catalogue_and_a_plan_of_zero():
     engine, batch = _engine(3, chips=1)
     try:
